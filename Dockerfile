@@ -12,7 +12,7 @@ FROM python:3.12-slim
 RUN apt-get update && apt-get install -y --no-install-recommends curl \
     && rm -rf /var/lib/apt/lists/*
 
-# jax[tpu] resolves libtpu on TPU VMs; CPU fallback works out of the box.
+# jax[tpu] resolves libtpu on TPU VMs.
 # matplotlib: the wired plot tool; orbax: native checkpoints; the serve
 # extras (confluent-kafka, pymongo, qdrant-client) are the reference-parity
 # external backends.

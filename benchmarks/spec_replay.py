@@ -14,8 +14,9 @@ carries, with connective prose between quotes.
 This is a faithful simulation of what the engine would commit if the
 model's greedy output were that answer: acceptance depends only on the
 token stream and the proposer (``NgramIndex``), not on weights. Combined
-with the measured verify-step cost envelope (PERF_r04.md: ~1.07x a
-decode step), it yields the realized speedup:
+with a verify-step cost envelope (~1.07x a decode step in the builders'
+July 2026 measurement, not reproduced since), it yields the realized
+speedup:
 
     speedup = (tokens/step) / verify_cost_ratio
 
@@ -135,8 +136,8 @@ def main() -> None:
                         "(the rest is connective prose)")
     p.add_argument("--spec-tokens", type=int, default=3)
     p.add_argument("--verify-cost", type=float, default=1.07,
-                   help="measured verify-step cost / decode-step cost "
-                        "(PERF_r04.md envelope)")
+                   help="verify-step cost / decode-step cost (default: "
+                        "the builders' July 2026 measurement)")
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args()
 

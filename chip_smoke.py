@@ -1,0 +1,611 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the serving path starts on the chip.
+
+One process drives the default serving path once at the full width of
+``tinyllama-1.1b`` (bf16, random weights from the seed) with the
+``bge-base-en`` encoder, through the entry points a user calls: it assembles
+the server exactly as ``python -m finchat_tpu`` does (``load_config`` →
+``build_app`` → ``app.start(serve_http=True)``; memory Kafka broker, in-memory
+store) and talks to it over HTTP and Kafka. Engine options stay at their
+defaults except ``max_new_tokens``, which only bounds the run's length. The
+mesh is pinned (all axes 1) so that a host with four visible chips does not
+silently become TP=4.
+
+Phases, each fatal on its first failure (nothing is downgraded to a warning):
+
+1. device: ``jax.default_backend()`` must be ``tpu`` — also when the process
+   inherits ``JAX_PLATFORMS=cpu``;
+2. kernels: the default path's Pallas kernels (paged attention for decode and
+   for a prefill chunk, the in-place KV append, ragged paged attention, flash
+   attention), Mosaic-compiled at the model's widths, against their
+   ``jax.numpy`` oracles;
+3. start-up: ``build_app`` (warm-up compiles every serving variant); the
+   engine must have resolved the compiled ``pallas`` backend;
+4. logits: one prefill → decode comparison, ``pallas`` engine vs ``ref``
+   engine, on the app's own weights — logits, not sampled tokens;
+5. serving: ``GET /health``; ``POST /transactions`` then a retrieval through
+   the agent's retriever; four concurrent ``POST /chat/stream`` requests
+   staggered so that a later one prefills while an earlier one decodes; two
+   Kafka turns of one conversation. Every stream must end in ``complete``
+   with tokens and no ``error``; the ragged round and the steady decode step
+   must both have run; no rebuild, breaker trip, shed, quant-matmul fallback,
+   failed dispatch or anomaly (watchdog fire included) may have happened; and
+   no engine step may have compiled after warm-up.
+
+The last line of standard output is one JSON object naming the device as JAX
+reports it. Warm-up seconds are printed as set-up time; nothing here is a
+speed measurement.
+"""
+
+from __future__ import annotations
+
+import asyncio
+import dataclasses
+import faulthandler
+import json
+import sys
+import threading
+import time
+import urllib.request
+
+# the driver allows 1200 s; past this the process dumps every thread's stack
+# and exits non-zero on its own instead of hanging until it is killed
+DEADLINE_SECONDS = 1150
+
+MODEL_PRESET = "tinyllama-1.1b"
+EMBED_PRESET = "bge-base-en"
+PORT = 8931
+N_STREAMS = 4
+MAX_NEW_TOKENS = 96  # long enough that stream A still decodes while B-D prefill
+
+# Kernel parity, bf16 in and out. The kernel rounds unnormalised
+# probabilities to bf16 page by page and rescales its fp32 accumulator; the
+# oracle rounds the normalised weights once. bf16 keeps 8 mantissa bits
+# (eps 2^-8 = 3.9e-3), so outputs of O(1) magnitude may differ by a few ulps.
+KERNEL_ATOL = KERNEL_RTOL = 2e-2
+# Engine logits, pallas vs ref: the attention outputs above differ by bf16
+# ulps, and each of the 2 x n_layers residual additions rounds to bf16 again,
+# so the difference random-walks to a few percent of the logits' spread. The
+# statistic is the RMS difference over the vocabulary relative to the
+# reference logits' standard deviation (a wrong page, mask or head mapping
+# decorrelates the logits and puts it near 1).
+LOGITS_RMS_TOL = 0.1
+
+CONTEXT = {"name": "Ada", "income": 90000, "savings_goal": 20000}
+TRANSACTIONS = [
+    {"text": "Blue Bottle Coffee $4.50", "amount": -4.5, "category": "coffee"},
+    {"text": "Whole Foods Market $82.17", "amount": -82.17, "category": "groceries"},
+    {"text": "Shell gas station $41.00", "amount": -41.0, "category": "transport"},
+    {"text": "Payroll deposit $3,200.00", "amount": 3200.0, "category": "income"},
+    {"text": "Philz Coffee $5.25", "amount": -5.25, "category": "coffee"},
+]
+
+
+class SmokeFailure(AssertionError):
+    """A phase's check did not hold."""
+
+
+def require(cond: bool, message: str) -> None:
+    if not cond:
+        raise SmokeFailure(message)
+
+
+def say(message: str) -> None:
+    print(message, flush=True)
+
+
+# --- phase 2: kernels against their oracles --------------------------------
+
+def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
+                  backend: str, *, prefill_chunk: int = 512) -> dict[str, float]:
+    """Each default-path kernel vs its oracle at the given widths; returns
+    the max abs error per kernel. ``backend`` is ``pallas`` (compiled) on
+    the chip; the CPU test passes ``pallas-interpret``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.engine.kv_cache import scatter_kv_chunk
+    from finchat_tpu.ops import dispatch
+    from finchat_tpu.ops.flash_attention import flash_attention
+    from finchat_tpu.ops.kv_append import paged_kv_append
+    from finchat_tpu.ops.refs import mha_reference
+
+    interpret = backend == "pallas-interpret"
+    dtype = jnp.bfloat16
+    H, D = n_heads, head_dim
+    errors: dict[str, float] = {}
+
+    def close(name: str, got, want) -> None:
+        got = np.asarray(got.astype(jnp.float32))
+        want = np.asarray(want.astype(jnp.float32))
+        require(np.isfinite(got).all(), f"kernel {name}: non-finite output")
+        diff = np.abs(got - want)
+        errors[name] = float(diff.max())
+        bad = diff > KERNEL_ATOL + KERNEL_RTOL * np.abs(want)
+        require(not bad.any(),
+                f"kernel {name}: {int(bad.sum())} of {bad.size} elements off "
+                f"the oracle (max abs err {errors[name]:.4f})")
+        say(f"kernel {name}: ok (max abs err {errors[name]:.4f})")
+
+    def cache(seed: int, n_pages: int):
+        k1, k2 = jax.random.split(jax.random.key(seed))
+        shape = (2, n_pages, page_size, n_kv * D)  # two layers; layer 1 is read
+        return jax.random.normal(k1, shape, dtype), jax.random.normal(k2, shape, dtype)
+
+    def shuffled_table(rows: int, max_pages: int, seed: int):
+        perm = np.random.RandomState(seed).permutation(rows * max_pages) + 1
+        return jnp.asarray(perm.reshape(rows, max_pages), jnp.int32)
+
+    layer = jnp.asarray([1], jnp.int32)
+    kw = dict(page_size=page_size, n_kv=n_kv)
+    max_pages = 8
+    span = max_pages * page_size
+
+    def paged(name: str, C: int, ctx: list[int]) -> None:
+        B = len(ctx)
+        k_pages, v_pages = cache(1, 1 + B * max_pages)
+        table = shuffled_table(B, max_pages, 0)
+        kv_len = jnp.asarray(ctx, jnp.int32)
+        q_offset = jnp.maximum(kv_len - C, 0)
+        q = jax.random.normal(jax.random.key(2), (B, C, H, D), dtype)
+        args = (q, k_pages, v_pages, table, q_offset, kv_len, layer)
+        got = dispatch.paged_attention(*args, backend=backend, **kw)
+        want = dispatch.paged_attention(*args, backend="ref", **kw)
+        # an empty slot is exact zeros from the kernel (the oracle's fully
+        # masked softmax averages V instead, so it is not compared there)
+        live = np.asarray(ctx) > 0
+        require(not np.asarray(got.astype(jnp.float32))[~live].any(),
+                f"kernel {name}: an empty slot produced non-zero output")
+        close(name, got[live], want[live])
+
+    # decode: one query per slot; an empty slot, one token, both sides of a
+    # page boundary, a full row
+    paged("paged_attention[decode]", 1,
+          [0, 1, page_size, page_size + 1, span // 2 + 3, span])
+    # one prefill chunk at an offset, and a first chunk
+    paged("paged_attention[prefill chunk]", prefill_chunk,
+          [prefill_chunk, min(span, prefill_chunk + page_size + 5)])
+
+    # in-place append vs the XLA scatter (a copy: exact outside the trash page)
+    B = 8
+    k_pages, v_pages = cache(3, 1 + B * max_pages)
+    table = jnp.arange(1, 1 + B * max_pages, dtype=jnp.int32).reshape(B, max_pages)
+    pos = jnp.asarray([(i * 53 + i) % span for i in range(B)], jnp.int32)
+    n_valid = jnp.asarray([0 if i == 3 else 1 for i in range(B)], jnp.int32)
+    k_new = jax.random.normal(jax.random.key(4), (B, 1, n_kv, D), dtype)
+    v_new = jax.random.normal(jax.random.key(5), (B, 1, n_kv, D), dtype)
+    want_k, want_v = scatter_kv_chunk(
+        k_pages, v_pages, k_new, v_new, table, pos, n_valid, page_size, jnp.int32(1))
+    kv_new = jnp.concatenate([k_new.reshape(B, 1, -1), v_new.reshape(B, 1, -1)], -1)
+    got_k, got_v = paged_kv_append(
+        kv_new, k_pages, v_pages, table, pos, n_valid, layer,
+        page_size=page_size, interpret=interpret)
+    exact = bool(jnp.array_equal(got_k[:, 1:], want_k[:, 1:])
+                 and jnp.array_equal(got_v[:, 1:], want_v[:, 1:]))
+    require(exact, "kernel kv_append: pages differ from the XLA scatter")
+    errors["kv_append"] = 0.0
+    say("kernel kv_append: ok (bit-equal to the scatter outside the trash page)")
+
+    # ragged: a prefill chunk at an offset, decode rows around a page
+    # boundary, a spec-verify-sized row, an empty row, buffer padding
+    rows = [(prefill_chunk // 2 + 7, page_size), (1, 0), (1, page_size - 1),
+            (1, page_size), (1, span // 2), (4, 40), (0, 0), (prefill_chunk // 4, 0)]
+    R = len(rows)
+    T = 2 * prefill_chunk
+    tok_row = np.full((T,), R, np.int32)
+    tok_pos = np.zeros((T,), np.int32)
+    kv_len = np.zeros((R,), np.int32)
+    t = 0
+    for r, (q_len, ctx_before) in enumerate(rows):
+        tok_row[t:t + q_len] = r
+        tok_pos[t:t + q_len] = ctx_before + np.arange(q_len)
+        kv_len[r] = ctx_before + q_len
+        t += q_len
+    k_pages, v_pages = cache(6, 1 + R * max_pages)
+    args = (jax.random.normal(jax.random.key(7), (T, H, D), dtype), k_pages,
+            v_pages, shuffled_table(R, max_pages, 1), jnp.asarray(tok_row),
+            jnp.asarray(tok_pos), jnp.asarray(kv_len), layer)
+    close("ragged_paged_attention",
+          dispatch.ragged_paged_attention(*args, backend=backend, **kw)[:t],
+          dispatch.ragged_paged_attention(*args, backend="ref", **kw)[:t])
+
+    # flash (contiguous KV)
+    keys = jax.random.split(jax.random.key(8), 3)
+    S = prefill_chunk
+    q = jax.random.normal(keys[0], (2, S, H, D), dtype)
+    k = jax.random.normal(keys[1], (2, S, n_kv, D), dtype)
+    v = jax.random.normal(keys[2], (2, S, n_kv, D), dtype)
+    close("flash_attention", flash_attention(q, k, v, causal=True, interpret=interpret),
+          mha_reference(q, k, v, causal=True))
+    return errors
+
+
+# --- phase 4: engine logits, compiled kernels vs the reference backend ------
+
+def check_engine_logits(config, params, mesh, engine_cfg, backend: str, *,
+                        prompt_len: int, n_decode: int = 3) -> float:
+    """Prefill a seeded prompt (more than one chunk) then decode
+    ``n_decode`` teacher-forced steps on two engines over the SAME weights,
+    one per attention backend; every step's logits must agree within
+    ``LOGITS_RMS_TOL`` of the reference logits' spread. Returns the worst
+    relative RMS difference seen."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.engine.engine import InferenceEngine
+    from finchat_tpu.engine.kv_cache import PageAllocator, pages_needed
+
+    rng = np.random.RandomState(0)
+    prompt = [int(t) for t in rng.randint(1, 256, size=prompt_len)]
+    B = engine_cfg.max_seqs
+
+    def make(attn_backend: str):
+        engine = InferenceEngine(config, params, engine_cfg, mesh=mesh,
+                                 attn_backend=attn_backend)
+        pages = PageAllocator(engine_cfg.num_pages).allocate(
+            "smoke", pages_needed(prompt_len + n_decode + 1, engine.page_size))
+        engine.set_page_table_row(0, pages)
+        return engine, np.asarray(engine.prefill(0, prompt), np.float32)
+
+    worst = 0.0
+
+    def compare(step: str, got: np.ndarray, want: np.ndarray) -> None:
+        nonlocal worst
+        require(np.isfinite(got).all(), f"logits[{step}]: non-finite")
+        require(got.shape == (config.vocab_size,),
+                f"logits[{step}]: shape {got.shape}, expected ({config.vocab_size},)")
+        rel = float(np.sqrt(np.mean((got - want) ** 2)) / np.std(want))
+        worst = max(worst, rel)
+        require(rel <= LOGITS_RMS_TOL,
+                f"logits[{step}]: {backend} differs from ref by an RMS of "
+                f"{rel:.4f} of the logits' std (limit {LOGITS_RMS_TOL})")
+
+    test, pre_test = make(backend)
+    ref, pre_ref = make("ref")
+    compare("prefill", pre_test, pre_ref)
+    active = jnp.zeros((B,), bool).at[0].set(True)
+    zeros, ones = jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32)
+    top_k = jnp.zeros((B,), jnp.int32)
+    token = int(np.argmax(pre_ref))
+    for i in range(n_decode):
+        test.set_last_token(0, token)
+        ref.set_last_token(0, token)
+        _, got = test.decode(active, zeros, ones, top_k, return_logits=True)
+        _, want = ref.decode(active, zeros, ones, top_k, return_logits=True)
+        want = np.asarray(want[0], np.float32)
+        compare(f"decode {i}", np.asarray(got[0], np.float32), want)
+        token = int(np.argmax(want))
+    say(f"logits {backend} vs ref: ok over prefill ({prompt_len} tokens) + "
+        f"{n_decode} decode steps (worst RMS diff {worst:.4f} of the logits' std)")
+    return worst
+
+
+# --- phase 5: the server, over HTTP and Kafka -------------------------------
+
+def _get(url: str, timeout: float = 60) -> bytes:
+    with urllib.request.urlopen(url, timeout=timeout) as r:
+        return r.read()
+
+
+def _post(url: str, payload: dict, timeout: float = 300) -> bytes:
+    req = urllib.request.Request(
+        url, data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json"}, method="POST")
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        return r.read()
+
+
+def _stream(base: str, payload: dict, trace_id: str,
+            responding: threading.Event | None = None,
+            timeout: float = 600) -> list[dict]:
+    """POST /chat/stream and collect the SSE events; sets ``responding``
+    once the agent reports that response generation began."""
+    req = urllib.request.Request(
+        base + "/chat/stream", data=json.dumps(payload).encode(),
+        headers={"Content-Type": "application/json", "x-trace-id": trace_id})
+    events = []
+    with urllib.request.urlopen(req, timeout=timeout) as r:
+        for line in r:
+            line = line.strip()
+            if not line.startswith(b"data:"):
+                continue
+            events.append(json.loads(line[5:]))
+            if (responding is not None and events[-1].get("type") == "status"
+                    and events[-1].get("message") == "Generating response..."):
+                responding.set()
+    return events
+
+
+def _counters(metrics_text: str) -> dict[str, float]:
+    """Prometheus text → each family's value summed over its label sets."""
+    totals: dict[str, float] = {}
+    for line in metrics_text.splitlines():
+        if not line or line.startswith("#"):
+            continue
+        series, _, value = line.rpartition(" ")
+        name = series.split("{", 1)[0]
+        totals[name] = totals.get(name, 0.0) + float(value)
+    return totals
+
+
+def _engine_step_cache_sizes() -> dict[str, int]:
+    """Compiled-variant count of every jitted engine step. Warm-up's
+    contract is that serving dispatches only what start-up compiled, so
+    none of these may grow inside the serving window. (Host-side helpers —
+    a page-table row update for a new row count, the session tier's first
+    offload — compile small programs of their own and are not steps.)"""
+    from finchat_tpu.engine import engine as engine_module
+
+    return {name: fn._cache_size() for name, fn in vars(engine_module).items()
+            if hasattr(fn, "_cache_size")}
+
+
+async def serve_and_check(cfg, *, expect_backend: str, parity_engine_cfg=None,
+                          parity_prompt_len: int = 0) -> dict:
+    """Start the server from ``cfg`` the way ``python -m finchat_tpu``
+    does, run phases 3-5 against it, stop it. Returns the outcomes; raises
+    ``SmokeFailure`` on the first check that does not hold."""
+    import jax
+
+    from finchat_tpu.io.kafka import KafkaClient
+    from finchat_tpu.serve.app import build_app
+    from finchat_tpu.utils.config import AI_RESPONSE_TOPIC, USER_MESSAGE_TOPIC
+    from finchat_tpu.utils.metrics import METRICS
+    from finchat_tpu.utils.tracing import TRACER
+
+    compiles = 0
+
+    def on_event(event: str, _duration: float, **_kw) -> None:
+        nonlocal compiles
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+
+    outcomes: dict = {}
+    # the registry and the trace ring are process-wide: failures count from
+    # here (start-up included), not from whatever ran in the process before
+    at_entry = _counters(METRICS.render_prometheus())
+    entered = time.perf_counter()
+    t0 = time.monotonic()
+    app = build_app(cfg)
+    engine = app.scheduler.engine
+    outcomes["startup_seconds"] = round(time.monotonic() - t0, 1)
+    outcomes["compiled_variants"] = engine.compiled_variants
+    say(f"start-up (set-up, not a metric): build_app took "
+        f"{outcomes['startup_seconds']} s, {engine.compiled_variants} serving "
+        f"variants warmed, {compiles} programs compiled or loaded from the cache")
+    say(f"attn_backend: {engine.attn_backend}")
+    require(engine.attn_backend == expect_backend,
+            f"engine resolved attn_backend={engine.attn_backend!r}, expected "
+            f"{expect_backend!r} (FINCHAT_ATTN set? not on a TPU?)")
+    require(engine.compiled_variants > 0, "engine warm-up did not run")
+
+    if parity_engine_cfg is not None:
+        check_engine_logits(engine.config, engine.params, engine.mesh,
+                            parity_engine_cfg, expect_backend,
+                            prompt_len=parity_prompt_len)
+
+    base = f"http://127.0.0.1:{cfg.serve.port}"
+    convs = [f"smoke-http-{i}" for i in range(N_STREAMS)] + ["smoke-kafka"]
+    questions = ["What did I spend on coffee this month?",
+                 "How big should my emergency fund be?",
+                 "Am I on track for my savings goal?",
+                 "Plot my grocery spending.",
+                 "How much did I earn last month?"]
+    for conv, question in zip(convs, questions):
+        app.store.upsert_context(conv, dict(CONTEXT, user_id=f"user-{conv}"))
+        app.store.add_user_message(conv, question, user_id=f"user-{conv}")
+
+    await app.start(serve_http=True)
+    try:
+        health = json.loads(await asyncio.to_thread(_get, base + "/health"))
+        require(health == {"status": "healthy"}, f"/health returned {health}")
+        say("GET /health: ok")
+
+        # ingest for two users, then retrieve through the agent's retriever:
+        # the rows come back for their owner and nobody else
+        owner, other = f"user-{convs[0]}", "user-someone-else"
+        for user, rows in ((owner, TRANSACTIONS), (other, TRANSACTIONS[:2])):
+            body = json.loads(await asyncio.to_thread(
+                _post, base + "/transactions", {"user_id": user, "transactions": rows}))
+            require(body == {"upserted": len(rows)}, f"/transactions returned {body}")
+        hits = await app.agent.retriever(
+            {"search_query": "coffee", "num_transactions": 3, "user_id": owner})
+        require(len(hits) == 3 and all(h in [r["text"] for r in TRANSACTIONS] for h in hits),
+                f"retrieval returned {hits}")
+        none = await app.agent.retriever(
+            {"search_query": "coffee", "user_id": "user-with-no-rows"})
+        require(none == [], f"retrieval leaked rows across users: {none}")
+        say(f"POST /transactions + retrieval: ok ({len(TRANSACTIONS)} + 2 rows "
+            f"embedded on device, top-3 returned to their owner only)")
+
+        # --- the serving window: no engine step may compile in here
+        steps_before = _engine_step_cache_sizes()
+        compiles_before = compiles
+        before = _counters((await asyncio.to_thread(_get, base + "/metrics")).decode())
+
+        responding = threading.Event()
+        payloads = [{"conversation_id": c, "message": q, "user_id": f"user-{c}"}
+                    for c, q in zip(convs[:N_STREAMS], questions)]
+        first = asyncio.create_task(asyncio.to_thread(
+            _stream, base, payloads[0], "smoke-trace-0", responding))
+        # B-D start once A is generating its response: their prompts then
+        # prefill while A decodes, which is the ragged round's population
+        while not responding.is_set() and not first.done():
+            await asyncio.sleep(0.01)
+        rest = [asyncio.create_task(asyncio.to_thread(
+            _stream, base, p, f"smoke-trace-{i}"))
+            for i, p in enumerate(payloads[1:], start=1)]
+        streams = await asyncio.gather(first, *rest)
+
+        outcomes["streams"] = []
+        for i, events in enumerate(streams):
+            kinds = [e.get("type") for e in events]
+            trace = json.loads(await asyncio.to_thread(
+                _get, f"{base}/debug/trace/smoke-trace-{i}"))
+            first_tokens = sum(e["name"] == "first_token" for e in trace["traceEvents"])
+            outcome = {
+                "request": f"POST /chat/stream #{i}", "events": len(events),
+                "ended": kinds[-1] if kinds else None,
+                "retrieved": "retrieval_complete" in kinds,
+                "sequences_with_tokens": first_tokens,
+            }
+            outcomes["streams"].append(outcome)
+            say(f"request: {json.dumps(outcome)}")
+            require("error" not in kinds, f"stream {i} carried an error event: {events}")
+            require(bool(kinds) and kinds[-1] == "complete",
+                    f"stream {i} did not end in complete: {kinds[-3:]}")
+            require(first_tokens >= 1, f"stream {i} generated no token")
+
+        # two Kafka turns of one conversation: user_message → ai_response
+        consumer = KafkaClient(cfg.kafka)
+        consumer.setup_consumer(topics=[AI_RESPONSE_TOPIC])
+        producer = KafkaClient(cfg.kafka)
+        conv = convs[-1]
+        for turn, text in enumerate([questions[-1], "And the month before?"]):
+            if turn:
+                app.store.add_user_message(conv, text, user_id=f"user-{conv}")
+            message_id = f"smoke-kafka-{turn}"
+            producer.produce_message(USER_MESSAGE_TOPIC, conv, {
+                "message": text, "conversation_id": conv, "message_id": message_id})
+            chunks = []
+            kafka_deadline = time.monotonic() + 300
+            while not (chunks and chunks[-1].get("last_message")):
+                require(time.monotonic() < kafka_deadline,
+                        f"kafka turn {turn}: no final chunk within 300 s")
+                msg = consumer.poll_message()
+                if msg is None:
+                    await asyncio.sleep(0.02)
+                    continue
+                chunks.append(json.loads(msg.value().decode()))
+            final = chunks[-1]
+            outcome = {"request": f"kafka turn {turn}", "chunks": len(chunks),
+                       "ended": final.get("type"), "error": final.get("error")}
+            outcomes["streams"].append(outcome)
+            say(f"request: {json.dumps(outcome)}")
+            require(not any(c.get("error") for c in chunks),
+                    f"kafka turn {turn} carried an error chunk: {chunks}")
+            require(final.get("type") == "complete" and final.get("message") == text,
+                    f"kafka turn {turn} ended in {final}")
+        consumer.close()
+        producer.close()
+
+        after = _counters((await asyncio.to_thread(_get, base + "/metrics")).decode())
+        steps_after = _engine_step_cache_sizes()
+    finally:
+        await app.stop()
+
+    def delta(name: str) -> float:
+        return after.get(name, 0.0) - before.get(name, 0.0)
+
+    served = {name: delta(name) for name in (
+        "finchat_mixed_dispatches_total", "finchat_decode_dispatches_total",
+        "finchat_tokens_generated_total", "finchat_prefix_hits_total",
+        "finchat_session_cache_hits_total")}
+    outcomes["served"] = served
+    say(f"served: {json.dumps(served)}")
+    n_requests = N_STREAMS + 2
+    require(served["finchat_mixed_dispatches_total"] > 0,
+            "the ragged round never ran (no prefill coexisted with a decode)")
+    require(served["finchat_decode_dispatches_total"] > 0,
+            "the steady decode step never ran")
+    require(served["finchat_tokens_generated_total"] >= n_requests,
+            f"only {served['finchat_tokens_generated_total']} tokens for "
+            f"{n_requests} requests")
+
+    for name in ("finchat_engine_rebuilds_total", "finchat_sheds_total",
+                 "finchat_quantmatmul_fallbacks_total",
+                 "finchat_dispatch_failures_total"):
+        grew = after.get(name, 0.0) - at_entry.get(name, 0.0)
+        require(grew == 0, f"{name} grew by {grew}")
+    require(after.get("finchat_breaker_state", 0.0) == 0, "the breaker is not closed")
+    anomalies = [ev[2] for ev in TRACER.snapshot()
+                 if ev[4] == "anomaly" and ev[0] >= entered]
+    require(not anomalies, f"anomalies recorded (watchdog, breaker, shed): {anomalies}")
+    grown = {n: (steps_before[n], steps_after[n]) for n in steps_after
+             if steps_after[n] != steps_before.get(n)}
+    require(not grown, f"engine steps compiled after warm-up: {grown}")
+    outcomes["other_compiles_in_window"] = compiles - compiles_before
+    say("no rebuild, breaker trip, shed, fallback, failed dispatch or anomaly; "
+        f"no engine step compiled after warm-up ({outcomes['other_compiles_in_window']} "
+        "small host-side programs did)")
+    return outcomes
+
+
+def smoke_config(model_preset: str, embed_preset: str, *, mesh_model: int = 1,
+                 port: int = PORT, max_new_tokens: int = MAX_NEW_TOKENS):
+    """The smoke's config: ``load_config`` with everything at its default
+    except the presets, the explicit mesh, the port and the length bound."""
+    from finchat_tpu.utils.config import load_config
+
+    return load_config(None, {
+        "model.preset": model_preset, "embed.preset": embed_preset,
+        "mesh.data": 1, "mesh.pipe": 1, "mesh.seq": 1, "mesh.expert": 1,
+        "mesh.model": mesh_model,
+        "serve.port": port, "engine.max_new_tokens": max_new_tokens,
+    })
+
+
+def main(mesh_model: int = 1) -> int:
+    # sys.__stderr__: a real file descriptor even when stderr is captured
+    faulthandler.dump_traceback_later(DEADLINE_SECONDS, exit=True,
+                                      file=sys.__stderr__)
+    try:
+        return _run(mesh_model)
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+
+
+def _run(mesh_model: int) -> int:
+    try:
+        import jax
+
+        from finchat_tpu.models.llama import PRESETS
+        from finchat_tpu.utils.config import EngineConfig
+        from finchat_tpu.utils.runtime import device_facts, enable_compile_cache
+    except ImportError as e:
+        print(f"chip_smoke: cannot import the program ({e}); run it from the "
+              "root of a checkout", file=sys.stderr)
+        return 2
+
+    device = device_facts()
+    say(f"platform: {device['platform']}  device_kind: {device['kind']}  "
+        f"count: {device['count']}")
+    if jax.default_backend() != "tpu":
+        print(f"chip_smoke: no TPU — jax.default_backend() is "
+              f"{jax.default_backend()!r} (JAX_PLATFORMS="
+              f"{jax.config.jax_platforms!r}); this check runs on the chip only",
+              file=sys.stderr)
+        return 2
+    say(f"compile cache: {enable_compile_cache()}")
+
+    model = PRESETS[MODEL_PRESET]
+    cfg = smoke_config(MODEL_PRESET, EMBED_PRESET, mesh_model=mesh_model)
+    require(mesh_model <= device["count"],
+            f"mesh.model={mesh_model} needs that many chips, found {device['count']}")
+    say(f"model: {MODEL_PRESET} {cfg.model.dtype} (random weights, seed "
+        f"{cfg.model.seed}); embed: {EMBED_PRESET}; mesh model={mesh_model}; "
+        f"engine defaults except max_new_tokens={cfg.engine.max_new_tokens} "
+        f"(max_seqs={cfg.engine.max_seqs}, page_size={cfg.engine.page_size}, "
+        f"prefill_chunk={cfg.engine.prefill_chunk})")
+
+    check_kernels(model.n_heads, model.n_kv_heads, model.head_dim,
+                  cfg.engine.page_size, "pallas",
+                  prefill_chunk=cfg.engine.prefill_chunk)
+    # the parity engines share the app's weights; their own KV pools are
+    # small — two slots, one prompt of a chunk and a half
+    parity_cfg = dataclasses.replace(
+        EngineConfig(), max_seqs=2, num_pages=32, max_seq_len=2048,
+        max_new_tokens=8)
+    asyncio.run(serve_and_check(
+        cfg, expect_backend="pallas", parity_engine_cfg=parity_cfg,
+        parity_prompt_len=cfg.engine.prefill_chunk * 3 // 2))
+
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -126,6 +126,16 @@ def main() -> None:
         overrides["tracing.enabled"] = False
     cfg = load_config(args.config, overrides)
 
+    if cfg.model.preset != "stub":
+        # the stub preset never compiles and runs without an accelerator
+        from finchat_tpu.utils.runtime import (
+            enable_compile_cache,
+            require_accelerator_unless_cpu_requested,
+        )
+
+        require_accelerator_unless_cpu_requested()
+        enable_compile_cache()
+
     from finchat_tpu.serve.app import build_app
 
     app = build_app(cfg)

@@ -393,6 +393,10 @@ class TokenConstraint:
         which degrades to the no-tool path downstream, never a crash).
         """
         allowed, eos_ok, ends = self.vocab.mask(self.state)
+        # a model head wider than the tokenizer's vocab (a padded
+        # checkpoint; a random-weight preset under the byte tokenizer): ids
+        # past the tokenizer carry no text and can never be picked
+        logits = logits[: allowed.shape[0]]
         if remaining is not None:
             feasible = allowed & (self.vocab._distance_np[ends] <= remaining - 2)
             if feasible.any() or eos_ok:

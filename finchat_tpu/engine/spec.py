@@ -10,9 +10,9 @@ free on the host: no draft model, no extra device memory, and the verify
 step (engine.verify_step) scores all drafts in one weights-read. On a
 miss the sequence degrades to plain one-token decode — token-for-token
 identical to the non-speculative path under greedy, and each verify step
-costs about the same device time as a decode step (measured ~1.07x, see
-PERF_r04.md). Throughput is not strictly never-worse, though: the
-scheduler's spec mode runs depth-1 (dispatch then consume serially), so
+costs about the same device time as a decode step (~1.07x in the
+builders' July 2026 measurement, not reproduced since). Throughput is not
+strictly never-worse, though: the scheduler's spec mode runs depth-1 (dispatch then consume serially), so
 on sustained all-miss traffic it gives up the depth-2 device/host
 overlap of the plain decode path. The scheduler therefore drops a
 sequence back to the pipelined non-spec path after

@@ -335,9 +335,10 @@ def forward(
     callback receives the whole cache plus the layer index (kernels index
     the layer via scalar prefetch). The alternative — slicing the cache as
     scan xs and restacking updates as ys — forces XLA to write a fresh
-    full-cache buffer every step (~22 ms/step measured for a 1.5 GB cache,
-    benchmarks/probe_cache_styles.py); carrying it lets the in-place Pallas
-    writers (ops/kv_append.py) keep the buffer aliased end to end.
+    full-cache buffer every step (~22 ms/step for a 1.5 GB cache in the
+    builders' July 2026 measurement, not reproduced since); carrying it
+    lets the in-place Pallas writers (ops/kv_append.py) keep the buffer
+    aliased end to end.
     """
     c = config
     x = params["embed"][tokens]  # [B,S,D]

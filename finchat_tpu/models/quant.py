@@ -1,10 +1,11 @@
 """Int8 / int4 weight-only quantization for serving.
 
 No reference counterpart (the reference calls an external LLM API —
-``llm_agent.py:34-45``); this exists because the measured decode step is
-weight-READ-bound on TPU (PERF_r04.md attribution: ~6 ms of the 9.6 ms
-step is the dense forward streaming bf16 weights from HBM). Storing matmul
-weights as int8 with per-output-channel scales halves that traffic; the
+``llm_agent.py:34-45``); this exists because the decode step is
+weight-READ-bound on TPU (the builders' July 2026 measurement, not
+reproduced since, put ~6 ms of a 9.6 ms step in the dense forward streaming
+bf16 weights from HBM). Storing matmul weights as int8 with
+per-output-channel scales halves that traffic; the
 MXU still computes in bf16 (int8 values up to ±127 are exact in bf16), so
 the only numeric change is the weight rounding itself — bounded by the
 per-channel max / 127 and asserted in tests/test_quant.py.

@@ -10,8 +10,11 @@ order (same for both):
    — what the CI mesh uses to exercise kernel code paths without a TPU);
 2. default: ``pallas`` when the runtime backend is TPU, else ``ref``.
 
-The reference implementations are the correctness oracles and stay the
-fallback everywhere Mosaic can't lower (CPU test meshes, odd head_dims).
+The reference implementations are the correctness oracles and the serving
+path on a CPU backend. Nothing switches backend behind the resolution: a
+``pallas`` kernel that Mosaic refuses is an error, and the process entry
+refuses a CPU backend nobody asked for (utils/runtime.py), so ``ref`` on an
+accelerator host is always somebody's explicit choice.
 """
 
 from __future__ import annotations

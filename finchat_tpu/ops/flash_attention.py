@@ -168,7 +168,7 @@ def flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     block_k: int = 256,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Array:
     """Drop-in Pallas replacement for ``ops.refs.mha_reference``."""
     B, Sq, H, D = q.shape
@@ -176,8 +176,6 @@ def flash_attention(
     assert H % Hkv == 0, (H, Hkv)
     group = H // Hkv
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     if q_offset is None:
         q_offset = jnp.zeros((B,), jnp.int32)

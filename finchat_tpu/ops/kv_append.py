@@ -1,9 +1,10 @@
 """Pallas in-place decode KV append — the write half of the decode hot path.
 
 The XLA alternative (``engine/kv_cache.py scatter_kv_chunk``) lowers to a
-scatter that rebuilds the destination buffer: ~22 ms/step measured for a
-1.5 GB TinyLlama cache on v5e (benchmarks/probe_cache_styles.py), both as
-scan xs→ys and as an in-carry scatter — XLA never does it in place. This
+scatter that rebuilds the destination buffer: ~22 ms/step for a 1.5 GB
+TinyLlama cache on v5e (builders' July 2026 measurement, not reproduced
+since), both as scan xs→ys and as an in-carry scatter — XLA never does it
+in place. This
 kernel does: ``input_output_aliases`` pins the output to the input buffer
 and each program read-modify-writes exactly ONE page, so per-step traffic is
 B pages instead of the whole cache (~0.5 ms at bench shapes).
@@ -185,14 +186,12 @@ def paged_kv_append_q8(
     *,
     page_size: int,
     n_kv: int,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[Array, Array, Array, Array]:
     """Quantizing in-place append for the int8 KV cache; returns the
     (aliased) data and scale arrays."""
     B = kv_new.shape[0]
     HD = k_pages.shape[-1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -248,14 +247,12 @@ def paged_kv_append(
     layer: Array,  # [1] int32
     *,
     page_size: int,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> tuple[Array, Array]:
     """Append one token's K/V per sequence into layer ``layer``'s pages,
     in place. Returns the (aliased) cache pair."""
     B = kv_new.shape[0]
     HD = k_pages.shape[-1]
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,

@@ -58,6 +58,21 @@ from finchat_tpu.ops.flash_attention import (
 TRASH_PAGE = 0
 
 
+def _pad_chunk(q: Array) -> tuple[Array, int]:
+    """Pad a short multi-token chunk to whole 8-row sublane tiles. The
+    kernel collapses its ``(group, bq, D)`` query block to ``(group*bq, D)``,
+    and Mosaic has no layout for that cast when ``bq`` is not a multiple of
+    8 — a 3-token spec-verify block failed on the v5e with "unsupported
+    shape cast vector<1x8x3x64xbf16> -> vector<24x64xbf16>". The padding
+    rows are computed like any query and dropped by the wrapper; C = 1
+    (decode) is a plain squeeze and stays as it is."""
+    C = q.shape[1]
+    if C == 1 or C % 8 == 0:
+        return q, C
+    padded = _round_up(C, 8)
+    return jnp.pad(q, ((0, 0), (0, padded - C), (0, 0), (0, 0))), padded
+
+
 def _paged_kernel(
     # scalar prefetch
     layer_ref,  # [1] int32
@@ -233,12 +248,12 @@ def paged_flash_attention_q8(
     n_kv: int,
     scale: float | None = None,
     block_q: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Array:
     """Attention over the int8 paged KV cache; same contract as
     ``paged_flash_attention`` with the scale arrays riding the same
     scalar-prefetched page indirection."""
-    B, C, H, D = q.shape
+    B, n_queries, H, D = q.shape
     max_pages = page_table.shape[1]
     assert H % n_kv == 0, (H, n_kv)
     assert k_pages.shape[2] == page_size, (k_pages.shape, page_size)
@@ -246,14 +261,13 @@ def paged_flash_attention_q8(
     assert k_scales.shape[3] == page_size, (k_scales.shape, page_size)
     group = H // n_kv
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     q_offset = jnp.asarray(q_offset, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
 
+    q, C = _pad_chunk(q)
     bq = _pick_block(C, block_q)
     nq = C // bq
     r_pad = _round_up(max(H * bq, 8), 8)
@@ -295,7 +309,7 @@ def paged_flash_attention_q8(
         out_shape=jax.ShapeDtypeStruct((B, H, C, D), q.dtype),
         interpret=interpret,
     )(layer, page_table, q_offset, kv_len, q_t, k_pages, v_pages, k_scales, v_scales)
-    return out_t.transpose(0, 2, 1, 3)
+    return out_t.transpose(0, 2, 1, 3)[:, :n_queries]
 
 
 @functools.partial(
@@ -315,7 +329,7 @@ def paged_flash_attention(
     n_kv: int,
     scale: float | None = None,
     block_q: int = 128,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> Array:
     """Attention over the paged KV cache; returns [B, C, H, D].
 
@@ -324,21 +338,20 @@ def paged_flash_attention(
     The current chunk's K/V must already be in the pages (the decode append
     kernel or the prefill scatter runs first).
     """
-    B, C, H, D = q.shape
+    B, n_queries, H, D = q.shape
     max_pages = page_table.shape[1]
     assert H % n_kv == 0, (H, n_kv)
     assert k_pages.shape[2] == page_size, (k_pages.shape, page_size)
     assert k_pages.shape[3] == n_kv * D, (k_pages.shape, n_kv, D)
     group = H // n_kv
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
 
     q_offset = jnp.asarray(q_offset, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32)
     page_table = jnp.asarray(page_table, jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
 
+    q, C = _pad_chunk(q)
     bq = _pick_block(C, block_q)
     nq = C // bq
     r_pad = _round_up(max(H * bq, 8), 8)
@@ -380,4 +393,4 @@ def paged_flash_attention(
         out_shape=jax.ShapeDtypeStruct((B, H, C, D), q.dtype),
         interpret=interpret,
     )(layer, page_table, q_offset, kv_len, q_t, k_pages, v_pages)
-    return out_t.transpose(0, 2, 1, 3)
+    return out_t.transpose(0, 2, 1, 3)[:, :n_queries]

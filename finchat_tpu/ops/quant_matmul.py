@@ -5,10 +5,10 @@ as int8 (per-output-column scales) or packed int4 nibbles (per-group
 scales along K), and every matmul site dequantizes INLINE —
 ``x @ dequantize(w, x.dtype)``. That contract is what keeps TP decode
 bit-identical to unsharded, but on its own it leaves the HBM win to XLA's
-mercy: whenever the fusion breaks (and on the measured decode step it
-does, per layer), the bf16 weight REMATERIALIZES and the decode step
-streams full-width weights again — the ~6 ms/step weight-read attribution
-PERF_r04.md measured is only conditionally halved/quartered.
+mercy: whenever the fusion breaks, the bf16 weight REMATERIALIZES and the
+decode step streams full-width weights again — the weight-read share of
+the step is only conditionally halved/quartered. (Whether and where the
+fusion breaks on the chip is not measured.)
 
 This module makes the packed read structural instead of incidental:
 
@@ -102,29 +102,30 @@ def _qmm_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *,
     q = q_ref[...]
     if packed:
         # nibble unpack, the models/quant._unpack_int4 arithmetic: low
-        # nibble = row 2i, high nibble = row 2i+1, sign via << 4 >> 4
-        lo = (q << 4) >> 4
+        # nibble = row 2i, high nibble = row 2i+1, sign via shift pairs —
+        # in int32, because Mosaic legalizes no 8-bit vector shift
+        q = q.astype(jnp.int32)
+        lo = (q << 28) >> 28
         hi = q >> 4
         q = jnp.stack([lo, hi], axis=-2).reshape(bk, q.shape[-1])
 
     # per-group scales: the scale block holds ALL groups' rows for this
-    # n-tile (n_groups is small — K/g); slice this k-tile's rows with
-    # static shapes (the wrapper guarantees bk % g == 0 or g % bk == 0)
-    s_all = s_ref[...]  # [n_groups_padded, bn] fp32
+    # n-tile (n_groups is small — K/g); load this k-tile's rows from the
+    # ref at a dynamic row offset with static shapes (the wrapper
+    # guarantees bk % g == 0 or g % bk == 0). A value-level
+    # ``dynamic_slice`` has no Mosaic lowering; a ``pl.ds`` ref load does.
     k_idx = pl.program_id(2)
     if bk <= g:
         # the whole tile lies inside one group
-        grp = k_idx * bk // g
-        s_rows = jax.lax.dynamic_slice_in_dim(s_all, grp, 1, 0)  # [1, bn]
-        s_tile = jnp.broadcast_to(s_rows, (bk, s_all.shape[-1]))
+        s_rows = s_ref[pl.ds(k_idx * bk // g, 1), :]  # [1, bn]
+        s_tile = jnp.broadcast_to(s_rows, (bk, bn))
     else:
         # whole groups per tile: broadcast each group row over its g rows
         npg = bk // g
-        start = k_idx * npg
-        s_rows = jax.lax.dynamic_slice_in_dim(s_all, start, npg, 0)
+        s_rows = s_ref[pl.ds(k_idx * npg, npg), :]  # [npg, bn]
         s_tile = jnp.broadcast_to(
-            s_rows[:, None, :], (npg, g, s_all.shape[-1])
-        ).reshape(bk, s_all.shape[-1])
+            s_rows[:, None, :], (npg, g, bn)
+        ).reshape(bk, bn)
 
     # in-register dequant: int values are exact in fp32; the cast to the
     # activation dtype mirrors the reference's dequantize(w, x.dtype)
@@ -194,13 +195,11 @@ def _quant_matmul_2d(x: Array, q: Array, scale: Array, *, packed: bool,
 
 
 def quant_matmul_int8(x: Array, q: Array, scale: Array, *,
-                      interpret: bool | None = None,
+                      interpret: bool = False,
                       out_dtype=None) -> Array:
     """``x @ (q * scale)`` with q int8 ``[K, N]`` streamed packed and
     per-output-column fp32 ``scale [N]`` applied in-tile. ``x`` may carry
     leading batch dims; they flatten into M."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
     out = _quant_matmul_2d(
         x.reshape(-1, x.shape[-1]), q, scale.reshape(1, -1),
@@ -211,13 +210,11 @@ def quant_matmul_int8(x: Array, q: Array, scale: Array, *,
 
 
 def quant_matmul_int4(x: Array, q: Array, scale: Array, *,
-                      interpret: bool | None = None,
+                      interpret: bool = False,
                       out_dtype=None) -> Array:
     """``x @ dequant(q, scale)`` with q nibble-packed int4 ``[K//2, N]``
     streamed AS PACKED and per-group fp32 ``scale [G, N]`` (G = 1 is
     per-channel) applied in-tile after the in-register unpack."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     K = q.shape[0] * 2
     G = scale.shape[0]
     lead = x.shape[:-1]

@@ -394,7 +394,7 @@ def ragged_flash_attention(  # finchat-lint: hot
     n_kv: int,
     scale: float | None = None,
     block_q: int = 8,
-    interpret: bool | None = None,
+    interpret: bool = False,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
 ) -> Array:
     """Ragged paged attention over the native-dtype cache; returns
@@ -410,8 +410,6 @@ def ragged_flash_attention(  # finchat-lint: hot
     assert k_pages.shape[3] == n_kv * D, (k_pages.shape, n_kv, D)
     group = H // n_kv
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     tok_pos, kv_len = _compact_window(tok_row, tok_pos, kv_len, kv_gap, R)
 
     layer = jnp.asarray(layer, jnp.int32)
@@ -491,7 +489,7 @@ def ragged_flash_attention_q8(  # finchat-lint: hot
     n_kv: int,
     scale: float | None = None,
     block_q: int = 8,
-    interpret: bool | None = None,
+    interpret: bool = False,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
 ) -> Array:
     """Int8-KV ragged paged attention; same contract as
@@ -506,8 +504,6 @@ def ragged_flash_attention_q8(  # finchat-lint: hot
     assert k_scales.shape[3] == page_size, (k_scales.shape, page_size)
     group = H // n_kv
     scale = scale if scale is not None else D ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     tok_pos, kv_len = _compact_window(tok_row, tok_pos, kv_len, kv_gap, R)
     spad = k_scales.shape[2]
 

@@ -20,8 +20,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from finchat_tpu.parallel.mesh import pcast, shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -124,9 +122,9 @@ def _ring_body(q, k0, v0, *, axis: str, varying: tuple, n_blocks: int, causal: b
 
     # mark the accumulators device-varying so the fori_loop carry types match
     # (they're combined with ring-varying k/v inside the loop)
-    m0 = pcast(jnp.full((B, H, Sq), _NEG, jnp.float32), varying, to="varying")
-    l0 = pcast(jnp.zeros((B, H, Sq), jnp.float32), varying, to="varying")
-    acc0 = pcast(jnp.zeros((B, H, Sq, D), jnp.float32), varying, to="varying")
+    m0 = lax.pcast(jnp.full((B, H, Sq), _NEG, jnp.float32), varying, to="varying")
+    l0 = lax.pcast(jnp.zeros((B, H, Sq), jnp.float32), varying, to="varying")
+    acc0 = lax.pcast(jnp.zeros((B, H, Sq, D), jnp.float32), varying, to="varying")
 
     if prefix is not None:
         kp, vp, prefix_len = prefix
@@ -163,7 +161,7 @@ def ring_attention(
     scale = q.shape[-1] ** -0.5
     spec = P(batch_axis, axis, head_axis, None)
     varying = tuple(a for a in (batch_axis, axis, head_axis) if a)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_body, axis=axis, varying=varying, n_blocks=n_blocks, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -206,7 +204,7 @@ def ring_attention_with_prefix(
             causal=causal, scale=scale, prefix=(kp, vp, plen),
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec, spec, spec, pspec, pspec, P()),
         out_specs=spec,

@@ -22,8 +22,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from finchat_tpu.parallel.mesh import pcast, shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -66,9 +64,9 @@ def _ulysses_prefix_body(q, k, v, kp, vp, prefix_len, *, axis: str, n: int,
     scale = D ** -0.5
     # fresh accumulators must be born device-varying to match the
     # seq-varying values folded into them (same pattern as _ring_body)
-    m = pcast(jnp.full((B, Hg, S), -1e30, jnp.float32), varying, to="varying")
-    l = pcast(jnp.zeros((B, Hg, S), jnp.float32), varying, to="varying")
-    acc = pcast(jnp.zeros((B, Hg, S, D), jnp.float32), varying, to="varying")
+    m = lax.pcast(jnp.full((B, Hg, S), -1e30, jnp.float32), varying, to="varying")
+    l = lax.pcast(jnp.zeros((B, Hg, S), jnp.float32), varying, to="varying")
+    acc = lax.pcast(jnp.zeros((B, Hg, S, D), jnp.float32), varying, to="varying")
     m, l, acc = fold_prefix_blocks(
         q32, kp_g, vp_g, prefix_len, m, l, acc, scale=scale, H=Hg,
     )
@@ -163,7 +161,7 @@ def ulysses_attention(
             f"H={H}, Hkv={Hkv}, mesh={dict(mesh.shape)} — use ring attention instead"
         )
     spec = P(batch_axis, axis, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_body, axis=axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -200,7 +198,7 @@ def ulysses_attention_with_prefix(
     varying = tuple(a for a in (batch_axis, axis, head_axis) if a)
     spec = P(batch_axis, axis, head_axis, None)
     pspec = P(batch_axis, None, head_axis, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_prefix_body, axis=axis, n=n, varying=varying, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec, pspec, pspec, P()),

@@ -25,7 +25,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 from finchat_tpu.utils.config import MeshConfig
 from finchat_tpu.utils.logging import get_logger
@@ -33,47 +33,6 @@ from finchat_tpu.utils.logging import get_logger
 logger = get_logger(__name__)
 
 AXES = ("data", "pipe", "seq", "expert", "model")
-
-# --- jax version compat (this image runs 0.4.x; jax 0.5 moved things) ----
-# ONE seam for the whole repo: ops/ and parallel/ import shard_map from
-# here instead of reaching for the 0.5-only ``jax.shard_map`` alias.
-try:
-    shard_map = jax.shard_map  # jax >= 0.5
-except AttributeError:  # pragma: no cover - depends on image
-    from functools import partial as _partial
-
-    from jax.experimental.shard_map import shard_map as _shard_map  # type: ignore
-
-    # 0.4's replication checker predates the varying-type machinery: it
-    # rejects lax.cond whose branches differ in inferred replication (the
-    # ring kernel's causal block skip) and its collective rewrites corrupt
-    # multi-axis compositions. The bodies here manage their own
-    # replication (explicit pcast/psum), so disable the checker — the
-    # exact workaround jax's own error message prescribes.
-    shard_map = _partial(_shard_map, check_rep=False)
-
-try:
-    pcast = jax.lax.pcast  # jax >= 0.7 explicit varying-type casts
-except AttributeError:  # pragma: no cover - depends on image
-    def pcast(x, axis_name, *, to):  # type: ignore[misc]
-        """No-op stand-in: pre-0.7 shard_map has no varying/replicated
-        value typing, so the cast is purely a type-level annotation there
-        — numerically identity on every jax version."""
-        del axis_name, to
-        return x
-
-
-def make_abstract_mesh(sizes: tuple, names: tuple):
-    """``jax.sharding.AbstractMesh`` across the 0.4→0.5 signature change:
-    0.5+ takes ``(axis_sizes, axis_names)``; 0.4 takes one
-    ``((name, size), ...)`` shape tuple. Shape-level sharding checks
-    (tests/test_parallel.py) build their device-free meshes through here."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(sizes), tuple(names))
-    except TypeError:  # jax 0.4: zips names with sizes itself
-        return AbstractMesh(tuple(zip(names, sizes)))
 
 
 @dataclass(frozen=True)
@@ -115,13 +74,9 @@ def build_mesh(spec: MeshSpec | None = None, devices=None) -> Mesh:
     spec = spec or MeshSpec()
     sizes = spec.resolve(len(devices))
     # Auto axis types = classic GSPMD propagation (the model code stays
-    # sharding-agnostic; XLA infers intermediate shardings + collectives).
-    # ``AxisType`` only exists from jax 0.5 — older jax has no explicit
-    # axis-type machinery and every axis IS Auto, so omitting the kwarg
-    # builds the identical mesh there.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {"axis_types": (axis_type.Auto,) * len(AXES)} if axis_type else {}
-    mesh = jax.make_mesh(sizes, AXES, devices=devices, **kwargs)
+    # sharding-agnostic; XLA infers intermediate shardings + collectives)
+    mesh = jax.make_mesh(sizes, AXES, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(AXES))
     logger.info("mesh: %s over %d devices", dict(zip(AXES, sizes)), len(devices))
     return mesh
 

@@ -54,8 +54,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-
-from finchat_tpu.parallel.mesh import pcast, shard_map
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -120,8 +118,8 @@ def _pipeline_body(
 
     # the carries vary over pipe (per-stage) plus whatever axes the
     # activations shard over (data / seq), passed in by the caller
-    held0 = pcast(jnp.zeros((mb, S, D), x.dtype), carry_varying, to="varying")
-    out0 = pcast(jnp.zeros((B, S, D), x.dtype), carry_varying, to="varying")
+    held0 = lax.pcast(jnp.zeros((mb, S, D), x.dtype), carry_varying, to="varying")
+    out0 = lax.pcast(jnp.zeros((B, S, D), x.dtype), carry_varying, to="varying")
 
     def tick(carry, t):
         held, outputs = carry
@@ -292,7 +290,7 @@ def pipeline_forward(
 
     x = params["embed"][tokens]
     layer_specs = _pipeline_layer_specs(params["layers"], tp)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(
             _pipeline_body,
             config=config, n_micro=n_micro, n_stages=n_stages,

@@ -162,19 +162,6 @@ def _fit_sharding(
     return NamedSharding(mesh, P(*fitted))
 
 
-def _leaf_nbytes(x) -> int:
-    """``x.nbytes``, tolerating jax 0.4 PRNG-key arrays (whose extended
-    dtype leaves ``nbytes`` abstract there) — the size only gates the
-    large-tensor replication refusal, and key leaves are tiny."""
-    try:
-        return int(x.nbytes)
-    except Exception:
-        n = 1
-        for d in x.shape:
-            n *= int(d)
-        return n * int(getattr(x.dtype, "itemsize", None) or 4)
-
-
 def shard_params(params: dict[str, Any], shardings: dict[str, Any]) -> dict[str, Any]:
     """Place a (host or single-device) param tree onto the mesh. Sharding
     entries with no matching param (e.g. ``lm_head`` under tied embeddings,
@@ -196,9 +183,9 @@ def shard_params(params: dict[str, Any], shardings: dict[str, Any]) -> dict[str,
             spec = list(s.spec) + [None] * (x.q.ndim - len(s.spec))
             scale_s = NamedSharding(s.mesh, P(*spec[:-2], spec[-1]))
             return QTensor(
-                q=jax.device_put(x.q, _fit_sharding(s, x.q.shape, _leaf_nbytes(x.q))),
+                q=jax.device_put(x.q, _fit_sharding(s, x.q.shape, x.q.nbytes)),
                 scale=jax.device_put(
-                    x.scale, _fit_sharding(scale_s, x.scale.shape, _leaf_nbytes(x.scale))
+                    x.scale, _fit_sharding(scale_s, x.scale.shape, x.scale.nbytes)
                 ),
             )
         if isinstance(x, Q4Tensor):
@@ -209,12 +196,12 @@ def shard_params(params: dict[str, Any], shardings: dict[str, Any]) -> dict[str,
             spec = list(s.spec) + [None] * (x.q.ndim - len(s.spec))
             scale_s = NamedSharding(s.mesh, P(*spec[:-2], None, spec[-1]))
             return Q4Tensor(
-                q=jax.device_put(x.q, _fit_sharding(s, x.q.shape, _leaf_nbytes(x.q))),
+                q=jax.device_put(x.q, _fit_sharding(s, x.q.shape, x.q.nbytes)),
                 scale=jax.device_put(
-                    x.scale, _fit_sharding(scale_s, x.scale.shape, _leaf_nbytes(x.scale))
+                    x.scale, _fit_sharding(scale_s, x.scale.shape, x.scale.nbytes)
                 ),
             )
-        return jax.device_put(x, _fit_sharding(s, x.shape, _leaf_nbytes(x)))
+        return jax.device_put(x, _fit_sharding(s, x.shape, x.nbytes))
 
     pruned = prune(shardings, params)
     return jax.tree.map(
@@ -233,7 +220,7 @@ def shard_decode_state(state, mesh: Mesh, n_kv_heads: int | None = None):
         **{
             f: jax.device_put(
                 getattr(state, f),
-                _fit_sharding(sh[f], getattr(state, f).shape, _leaf_nbytes(getattr(state, f))),
+                _fit_sharding(sh[f], getattr(state, f).shape, getattr(state, f).nbytes),
             )
             for f in sh
         },

@@ -322,11 +322,6 @@ class EngineConfig:
     # output-column chunks per row-parallel matmul when tp_overlap is on
     # (indivisible output dims fall back to serial with a warning)
     tp_overlap_chunks: int = 4
-    # persistent XLA compilation cache directory
-    # (jax_compilation_cache_dir): warmup's compiles land on disk and a
-    # restarted process reloads them instead of re-paying full XLA
-    # compilation; "" = off (JAX default behavior)
-    compilation_cache_dir: str = ""
     # --- resilience plane (engine/scheduler; see ROBUSTNESS.md) ---------
     # engine circuit breaker: this many CONSECUTIVE failed dispatch rounds
     # (whole-round prefill/decode/mixed/spec failures — not per-sequence
@@ -791,9 +786,6 @@ def load_config(
     cfg.engine.tp_overlap = _env_bool("FINCHAT_TP_OVERLAP", cfg.engine.tp_overlap)
     cfg.engine.tp_overlap_chunks = _env_int(
         "FINCHAT_TP_OVERLAP_CHUNKS", cfg.engine.tp_overlap_chunks
-    )
-    cfg.engine.compilation_cache_dir = _env(
-        "FINCHAT_COMPILATION_CACHE_DIR", cfg.engine.compilation_cache_dir
     )
     cfg.engine.breaker_threshold = _env_int(
         "FINCHAT_BREAKER_THRESHOLD", cfg.engine.breaker_threshold

@@ -1,0 +1,63 @@
+"""chip_smoke.py's own functions, driven on the CPU at ``tiny`` with the
+Pallas kernels in interpret mode — so that the script the driver runs on the
+chip after every PR cannot rot unnoticed — and its refusal to run off-chip."""
+
+import asyncio
+import dataclasses
+import sys
+from pathlib import Path
+
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+import chip_smoke  # noqa: E402
+
+from finchat_tpu.models.llama import PRESETS  # noqa: E402
+from finchat_tpu.utils.config import EngineConfig  # noqa: E402
+
+
+def test_main_exits_nonzero_off_chip(capsys):
+    assert chip_smoke.main() != 0
+    captured = capsys.readouterr()
+    assert "no TPU" in captured.err
+    assert '"ok"' not in captured.out  # no result line
+
+
+def test_kernels_against_oracles_interpret():
+    model = PRESETS["tiny"]
+    errors = chip_smoke.check_kernels(
+        model.n_heads, model.n_kv_heads, model.head_dim, 8,
+        "pallas-interpret", prefill_chunk=16)
+    assert set(errors) == {
+        "paged_attention[decode]", "paged_attention[prefill chunk]",
+        "kv_append", "ragged_paged_attention", "flash_attention"}
+
+
+@pytest.mark.no_stall_sanitizer
+def test_serving_function_at_tiny_interpret(monkeypatch):
+    """The whole serving phase — start-up, logits parity, HTTP, Kafka, the
+    counters — through ``pallas-interpret``. The real prompt heads are
+    ~5.5k byte-tokens, minutes of interpreter time; short heads keep the
+    same paths (prefix registration, tool decision, response) in seconds."""
+    import finchat_tpu.serve.app as app_module
+
+    monkeypatch.setenv("FINCHAT_ATTN", "pallas-interpret")
+    monkeypatch.setattr(app_module, "load_prompts", lambda: (
+        "You are Penny, a finance assistant. Today is {date}.",
+        "Decide whether to call a tool."))
+    cfg = chip_smoke.smoke_config("tiny", "bge-tiny", port=8933, max_new_tokens=8)
+    # few warm-up variants, few pages per row: interpreter time follows both
+    cfg.engine.max_seqs = 4
+    cfg.engine.page_size = 128
+    cfg.engine.num_pages = 40
+    cfg.engine.max_seq_len = 1024
+    cfg.engine.prefill_chunk = 32
+    parity = dataclasses.replace(
+        EngineConfig(), max_seqs=2, page_size=16, num_pages=16,
+        max_seq_len=128, prefill_chunk=32)
+    outcomes = asyncio.run(chip_smoke.serve_and_check(
+        cfg, expect_backend="pallas-interpret", parity_engine_cfg=parity,
+        parity_prompt_len=48))
+    assert len(outcomes["streams"]) == chip_smoke.N_STREAMS + 2
+    assert outcomes["served"]["finchat_mixed_dispatches_total"] > 0

@@ -70,18 +70,14 @@ def test_engine_env_readers(monkeypatch):
     assert cfg.engine.warmup_on_start is True
 
 
-def test_mixed_step_and_compilation_cache_env_readers(monkeypatch):
+def test_mixed_step_env_reader(monkeypatch):
     from finchat_tpu.utils.config import load_config
 
     cfg = load_config()
     assert cfg.engine.mixed_step is True  # default on for the chunked path
-    assert cfg.engine.compilation_cache_dir == ""  # default off
 
     monkeypatch.setenv("FINCHAT_MIXED_STEP", "0")
-    monkeypatch.setenv("FINCHAT_COMPILATION_CACHE_DIR", "/tmp/finchat-xla-cache")
-    cfg = load_config()
-    assert cfg.engine.mixed_step is False
-    assert cfg.engine.compilation_cache_dir == "/tmp/finchat-xla-cache"
+    assert load_config().engine.mixed_step is False
 
 
 def test_tool_streaming_and_hold_ttl_env_readers(monkeypatch):

@@ -125,6 +125,27 @@ def test_constrained_pick_greedy_forces_grammar():
     assert accepts(dfa, text), text
 
 
+def test_constrained_pick_model_head_wider_than_tokenizer():
+    """tinyllama's 32,000-wide head under the 260-id byte tokenizer: the
+    ids the tokenizer does not know are never picked, however much mass
+    they hold, at any temperature."""
+    tok = ByteTokenizer()
+    vocab = GrammarVocab.for_tokenizer(tok)
+    rng = np.random.default_rng(0)
+    logits = np.zeros((32_000,), np.float32)
+    logits[tok.vocab_size:] = 100.0
+    for temperature in (0.0, 0.5):
+        c = TokenConstraint(vocab)
+        out = []
+        for step in range(96):
+            t = c.pick(logits, temperature, rng, remaining=96 - step)
+            if t == tok.eos_id:
+                break
+            out.append(t)
+        assert all(t < tok.vocab_size for t in out)
+        assert accepts(build_tool_grammar(), tok.decode(out))
+
+
 def test_constrained_sampling_terminates_and_parses():
     """Stochastic picks (temperature 1) across many seeds: always grammatical."""
     tok = ByteTokenizer()

@@ -334,13 +334,40 @@ def test_mixed_vs_split_streams_identical(params):
     assert n_mixed >= 5
 
 
-def _demoted_combo_workload(params, mixed, recorded=None, seed=7):
+def _spec_prompt(seed, rng=None):
+    """The spec stream's prompt: the first draw of the workload's rng."""
+    rng = rng or np.random.default_rng(seed)
+    base = rng.integers(1, CONFIG.vocab_size, size=4).tolist()
+    return (base * 5)[:18]
+
+
+def _demoted_combo_workload(params, mixed, recorded=None, seed=7,
+                            spec_oracle=None):
     """The previously-demoted feature mix in ONE scheduler (satellite
     fuzz): spec decode on, decode_loop on, a grammar-constrained stream, a
     greedy bystander, and a long prompt with a short tail admitted
     mid-decode — under PR 4 any ONE of these demoted every coexist
     iteration to the split path. ``recorded`` (ragged runs) collects, per
-    ragged dispatch, which features were carried."""
+    ragged dispatch, which features were carried.
+
+    ``spec_oracle`` is the spec stream's known greedy continuation (from
+    an earlier run): the proposer then drafts the TRUE next tokens, so
+    every draft is accepted, the all-miss cooldown never engages, and a
+    spec verify row rides every dispatch the stream is live in — whether
+    one lands in a coexist dispatch stops depending on what random
+    weights happen to repeat. Greedy-exactness makes the stream the same
+    under any proposer."""
+    if spec_oracle is not None:
+        from unittest import mock
+
+        from finchat_tpu.engine.spec import NgramIndex
+
+        def oracle_propose(self, k):
+            n = len(self._h)
+            return spec_oracle[n:n + k] if self._h == spec_oracle[:n] else []
+
+        with mock.patch.object(NgramIndex, "propose", oracle_propose):
+            return _demoted_combo_workload(params, mixed, recorded, seed)
     sched = _stack(params, mixed=mixed, max_seqs=5, num_pages=256,
                    spec_tokens=2, decode_loop_depth=3)
     if recorded is not None:
@@ -369,8 +396,7 @@ def _demoted_combo_workload(params, mixed, recorded=None, seed=7):
     rng = np.random.default_rng(seed)
     # repetitive prompts: greedy decode on random tiny weights settles into
     # loops, so prompt-lookup proposals (and acceptances) actually fire
-    base = rng.integers(1, CONFIG.vocab_size, size=4).tolist()
-    spec_prompt = (base * 5)[:18]
+    spec_prompt = _spec_prompt(seed, rng)
     by_prompt = rng.integers(1, CONFIG.vocab_size, size=9).tolist()
     long_p = rng.integers(1, CONFIG.vocab_size, size=5 * CHUNK + 3).tolist()
 
@@ -430,8 +456,9 @@ def test_previously_demoted_combo_byte_identity(params, seed):
     carrying the feature mix in fused dispatches."""
     split = _demoted_combo_workload(params, mixed=False, seed=seed)
     recorded: list[dict] = []
-    ragged = _demoted_combo_workload(params, mixed=True, recorded=recorded,
-                                     seed=seed)
+    ragged = _demoted_combo_workload(
+        params, mixed=True, recorded=recorded, seed=seed,
+        spec_oracle=_spec_prompt(seed) + split["spec"])
     assert ragged == split
     assert recorded, "no ragged dispatch ran"
     assert any(r["prefill"] and r["constrained"] for r in recorded), (
@@ -584,15 +611,13 @@ def test_prefill_round_failure_spares_parked_holds(params, monkeypatch):
     breaker semantics, default on), so its stream completes too. The
     pre-fix handler evicted everything in self.prefilling, killing
     in-flight retrieval overlaps that never touched the failed dispatch."""
-    import finchat_tpu.engine.scheduler as sched_mod
-
     sched = _stack(params, mixed=False)
     rng = np.random.default_rng(11)
     prefix = rng.integers(1, CONFIG.vocab_size, size=40).tolist()
     full = prefix + rng.integers(1, CONFIG.vocab_size, size=12).tolist()
     samp = SamplingParams(temperature=0.0, max_new_tokens=5)
 
-    real = sched_mod.prefill_step
+    real = sched.engine.prefill_rows
     state = {"armed": False, "fired": False}
 
     def flaky(*args, **kwargs):
@@ -602,7 +627,7 @@ def test_prefill_round_failure_spares_parked_holds(params, monkeypatch):
             raise RuntimeError("injected whole-round failure")
         return real(*args, **kwargs)
 
-    monkeypatch.setattr(sched_mod, "prefill_step", flaky)
+    monkeypatch.setattr(sched.engine, "prefill_rows", flaky)
 
     async def go():
         await sched.start()

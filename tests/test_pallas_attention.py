@@ -2,8 +2,7 @@
 
 Runs in interpret mode on the CPU test mesh. Under ``FINCHAT_TESTS_TPU=1``
 (see conftest.py) the same matrix runs ON-CHIP with ``interpret=False`` —
-Mosaic-lowered kernels asserted against the jnp oracles on real hardware
-(benchmarks/pallas_onchip.py records the pass as PALLAS_ONCHIP_r*.json).
+Mosaic-lowered kernels asserted against the jnp oracles on real hardware.
 On-chip fp32 tolerances are looser because TPU fp32 dots lower to bf16
 multi-pass matmuls in both the kernel and the oracle, but not identically.
 """
@@ -135,10 +134,12 @@ def test_paged_decode_matches_reference():
     np.testing.assert_allclose(out[:3], ref[:3], atol=ATOL, rtol=RTOL)
 
 
-def test_paged_prefill_chunk_matches_reference():
-    """C>1 chunked prefill at an offset: chunk KV already scattered."""
+@pytest.mark.parametrize("C", [32, 3])
+def test_paged_prefill_chunk_matches_reference(C):
+    """C>1 chunked prefill at an offset: chunk KV already scattered. C=3 is
+    a spec-verify block (1 + 2 drafts): not a whole sublane tile, so the
+    wrapper pads it (Mosaic refused the unpadded block's shape cast)."""
     B, H, Hkv, D, page_size, max_pages = 2, 4, 4, 64, 16, 8
-    C = 32
     ctx_lens = [64, 96]  # total cached INCLUDING the current chunk
     q, k_pages, v_pages, page_table, k_dense, v_dense = _build_paged_case(
         jax.random.key(5), B, H, Hkv, D, page_size, max_pages, ctx_lens, C=C
@@ -268,13 +269,13 @@ def test_engine_end_to_end_pallas_backend():
     assert run("ref") == run("pallas-interpret")
 
 
-# --- int8-KV (q8) kernels: the on-chip half of ADVICE r4 finding #4 ------
+# --- int8-KV (q8) kernels -------------------------------------------------
 # test_kv_quant.py pins these kernels in interpret mode with tiny shapes;
 # these two nodes use TPU-tileable shapes (row width 128 lanes, page 128
 # so each page's fp32 scale block [pad8(Hkv)=8, 128] is exactly one tile)
-# and follow this file's INTERPRET switch, so the per-test on-chip runner
-# (benchmarks/pallas_onchip_split.py) extends Mosaic coverage to the
-# quantizing append and int8 paged attention that kv_quant serving uses.
+# and follow this file's INTERPRET switch, so an on-chip run extends Mosaic
+# coverage to the quantizing append and int8 paged attention that kv_quant
+# serving uses.
 
 _Q8_HKV, _Q8_HD, _Q8_PAGE = 2, 64, 128
 

@@ -249,9 +249,7 @@ def test_segmented_ring_prefill_matches_monolithic(sp_mode, mesh_spec):
     mono_logits, mono_tokens = run(0)
     seg_logits, seg_tokens = run(32)  # 100 tokens -> 4 segments
     # tolerance is the bf16-activation envelope: the segmented fold
-    # accumulates in a different order, and jax 0.4's shard_map lowers
-    # the all_to_all/psum chain in yet another order (1/128 elements sat
-    # at 0.03 under it), hence 4e-2 rather than 2e-2
+    # accumulates in a different order than the monolithic pass
     np.testing.assert_allclose(seg_logits, mono_logits, atol=4e-2, rtol=4e-2)
     assert seg_tokens == mono_tokens
 
@@ -629,8 +627,7 @@ def test_pipeline_sp_forward_matches_plain():
     over seq-sharded activations (the ring body runs directly inside the
     all-manual region); the function computed must still equal the plain
     scanned forward — composed with in-stage TP (data=1 on this 8-device
-    mesh; the 4-axis composition needs 16 devices and is covered by the
-    subprocess run recorded in PERF_r05.md)."""
+    mesh; the 4-axis composition needs 16 devices and is not run here)."""
     import numpy as np
 
     from finchat_tpu.models.llama import forward, make_causal_attention
@@ -781,12 +778,12 @@ def test_70b_shardings_fit_v5p16_mesh_shapes():
     import math
 
     from finchat_tpu.models.llama import PRESETS
-    from finchat_tpu.parallel.mesh import make_abstract_mesh
+    from jax.sharding import AbstractMesh
     from finchat_tpu.parallel.sharding import llama_param_shardings
 
     config = PRESETS["llama3-70b"]
     # shape-only: an abstract 16-device v5p mesh (no fabricated devices)
-    mesh = make_abstract_mesh(
+    mesh = AbstractMesh(
         (2, 1, 1, 1, 8), ("data", "pipe", "seq", "expert", "model")
     )
 
@@ -836,7 +833,7 @@ def test_70b_shardings_fit_v5p16_mesh_shapes():
 
     from finchat_tpu.parallel.pipeline import _pipeline_layer_specs, _stage_tp
 
-    pp_mesh = make_abstract_mesh(
+    pp_mesh = AbstractMesh(
         (1, 4, 1, 1, 4), ("data", "pipe", "seq", "expert", "model")
     )
     assert L % pp_mesh.shape["pipe"] == 0  # 80 layers / 4 stages
@@ -859,7 +856,6 @@ def test_tp_overlap_row_parallel_byte_identity():
     the dispatch evidence that the schedule actually engaged, not just a
     knob that fell back to serial)."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from finchat_tpu.ops.tp_overlap import row_parallel_dense
@@ -873,7 +869,7 @@ def test_tp_overlap_row_parallel_byte_identity():
         def local(x_l, w_l):
             return row_parallel_dense(x_l, w_l, "model",
                                       overlap=overlap, n_chunks=n_chunks)
-        return shard_map(local, mesh=mesh,
+        return jax.shard_map(local, mesh=mesh,
                          in_specs=(P(None, "model"), P("model", None)),
                          out_specs=P(None, None))
 
@@ -900,7 +896,6 @@ def test_tp_overlap_indivisible_falls_back_serial():
     """An output dim the chunk count does not divide must run the serial
     collective (with a warning), not crash or pad."""
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from finchat_tpu.ops.tp_overlap import row_parallel_dense
@@ -909,13 +904,13 @@ def test_tp_overlap_indivisible_falls_back_serial():
     x = jax.random.normal(jax.random.key(2), (4, 64), jnp.float32)
     w = jax.random.normal(jax.random.key(3), (64, 30), jnp.float32)  # 30 % 4 != 0
 
-    f = shard_map(
+    f = jax.shard_map(
         lambda x_l, w_l: row_parallel_dense(x_l, w_l, "model",
                                             overlap=True, n_chunks=4),
         mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
         out_specs=P(None, None))
     got = f(x, w)
-    ref = shard_map(
+    ref = jax.shard_map(
         lambda x_l, w_l: row_parallel_dense(x_l, w_l, "model"),
         mesh=mesh, in_specs=(P(None, "model"), P("model", None)),
         out_specs=P(None, None))(x, w)

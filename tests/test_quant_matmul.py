@@ -9,8 +9,10 @@ Three contracts, mirroring the attention-kernel test discipline:
    matmul site through the dispatcher must not change a single stream
    byte; this file pins the identity at the op level and the whole-model
    level (tests/test_quant.py + bench --quantmatmul-smoke pin streams).
-2. Interpret-mode kernel-vs-ref parity across the layout matrix:
-   int8/int4 x per-channel/per-group x aligned/ragged shapes. The kernel
+2. Kernel-vs-ref parity across the layout matrix: int8/int4 x
+   per-channel/per-group x aligned/ragged shapes — interpret mode on the
+   CPU test mesh, Mosaic-compiled under ``FINCHAT_TESTS_TPU=1`` (the same
+   ``INTERPRET`` switch as tests/test_pallas_attention.py). The kernel
    tiles K and accumulates fp32, so parity is allclose (tile-order
    summation), not bitwise — same contract as the flash kernels.
 3. The kernel honors parallel/sharding.py's packed-K layout: a K-sharded
@@ -38,6 +40,14 @@ from finchat_tpu.ops.quant_matmul import (
     quant_matmul_int8,
     quant_matmul_ref,
 )
+
+
+INTERPRET = jax.default_backend() != "tpu"
+# on-chip fp32 dots lower to bf16 multi-pass matmuls in kernel and oracle
+# alike, but not identically (tests/test_pallas_attention.py, same reason)
+TOL = 2e-5 if INTERPRET else 2e-2
+needs_8_devices = pytest.mark.skipif(
+    jax.device_count() < 8, reason="needs the 8-device mesh")
 
 
 def _rand(key, shape, dtype=jnp.float32):
@@ -107,7 +117,7 @@ def test_stacked_weight_falls_back_to_ref():
     assert after == before + 1
 
 
-# --- 2. interpret-mode kernel-vs-ref parity matrix -----------------------
+# --- 2. kernel-vs-ref parity matrix (INTERPRET switch) -----------------------
 
 PARITY_CASES = [
     # (name, M, K, N, quant, group)
@@ -122,18 +132,18 @@ PARITY_CASES = [
 
 @pytest.mark.parametrize("name,M,K,N,mode,group",
                          PARITY_CASES, ids=[c[0] for c in PARITY_CASES])
-def test_kernel_matches_ref_interpret(name, M, K, N, mode, group):
+def test_kernel_matches_ref(name, M, K, N, mode, group):
     x = _rand(10, (M, K))
     w = _rand(11, (K, N))
     if mode == "int8":
         qt = quantize(w)
-        out = quant_matmul_int8(x, qt.q, qt.scale, interpret=True)
+        out = quant_matmul_int8(x, qt.q, qt.scale, interpret=INTERPRET)
     else:
         qt = quantize_int4(w, group_size=group)
-        out = quant_matmul_int4(x, qt.q, qt.scale, interpret=True)
+        out = quant_matmul_int4(x, qt.q, qt.scale, interpret=INTERPRET)
     ref = quant_matmul_ref(x, qt)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
+                               rtol=TOL, atol=TOL)
 
 
 @pytest.mark.parametrize("mode", ["int8", "int4"])
@@ -144,10 +154,10 @@ def test_kernel_bf16_activations_and_leading_dims(mode):
     w = _rand(13, (128, 64))
     if mode == "int8":
         qt = quantize(w)
-        out = quant_matmul_int8(x, qt.q, qt.scale, interpret=True)
+        out = quant_matmul_int8(x, qt.q, qt.scale, interpret=INTERPRET)
     else:
         qt = quantize_int4(w, group_size=32)
-        out = quant_matmul_int4(x, qt.q, qt.scale, interpret=True)
+        out = quant_matmul_int4(x, qt.q, qt.scale, interpret=INTERPRET)
     assert out.shape == (2, 5, 64) and out.dtype == jnp.bfloat16
     ref = quant_matmul_ref(x, qt)
     np.testing.assert_allclose(
@@ -160,7 +170,8 @@ def test_kernel_out_dtype_fp32_head():
     logits directly (preferred_element_type through the dispatcher)."""
     x = _rand(14, (4, 64), jnp.bfloat16)
     qt = quantize(_rand(15, (64, 32)))
-    out = quant_matmul(x, qt, backend="pallas-interpret",
+    out = quant_matmul(x, qt,
+                       backend="pallas-interpret" if INTERPRET else "pallas",
                        preferred_element_type=jnp.float32)
     assert out.dtype == jnp.float32
     ref = quant_matmul_ref(x, qt, preferred_element_type=jnp.float32)
@@ -190,11 +201,11 @@ def test_quantized_forward_fused_tracks_ref():
 
 # --- 3. packed-K sharding: the kernel honors the local-shard layout ------
 
+@needs_8_devices
 def test_tp_sharded_int8_kernel_matches_unsharded():
     """K-sharded int8 matmul over the forced 8-device mesh: each device
     runs the fused kernel on its LOCAL [K/8, N] shard (per-output-column
     scale replicated) and the psum matches the unsharded reference."""
-    from jax.experimental.shard_map import shard_map
     from finchat_tpu.parallel.mesh import MeshSpec, build_mesh
 
     mesh = build_mesh(MeshSpec(data=1, seq=1, expert=1, model=8))
@@ -206,21 +217,21 @@ def test_tp_sharded_int8_kernel_matches_unsharded():
         out = quant_matmul_int8(x_l, q_l, s_l, interpret=True)
         return jax.lax.psum(out, "model")
 
-    f = shard_map(local, mesh=mesh,
+    f = jax.shard_map(local, mesh=mesh,
                   in_specs=(P(None, "model"), P("model", None), P(None)),
-                  out_specs=P(None, None), check_rep=False)
+                  out_specs=P(None, None), check_vma=False)
     got = f(x, qt.q, qt.scale)
     ref = quant_matmul_ref(x, qt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)
 
 
+@needs_8_devices
 def test_tp_sharded_int4_packed_shards_as_bytes():
     """Packed int4 K-sharding (parallel/sharding.py spec): the packed
     [K//2, N] byte rows shard as UNITS (a nibble pair never splits across
     devices) and per-group scales shard with their groups — each device's
     fused kernel sees a self-consistent local shard."""
-    from jax.experimental.shard_map import shard_map
     from finchat_tpu.parallel.mesh import MeshSpec, build_mesh
 
     mesh = build_mesh(MeshSpec(data=1, seq=1, expert=1, model=8))
@@ -233,10 +244,10 @@ def test_tp_sharded_int4_packed_shards_as_bytes():
         out = quant_matmul_int4(x_l, q_l, s_l, interpret=True)
         return jax.lax.psum(out, "model")
 
-    f = shard_map(local, mesh=mesh,
+    f = jax.shard_map(local, mesh=mesh,
                   in_specs=(P(None, "model"), P("model", None),
                             P("model", None)),
-                  out_specs=P(None, None), check_rep=False)
+                  out_specs=P(None, None), check_vma=False)
     got = f(x, qt.q, qt.scale)
     ref = quant_matmul_ref(x, qt)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
