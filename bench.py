@@ -502,18 +502,7 @@ def measure(preset: str, batch: int, prompt_len: int, steps: int, warmup: int,
 
     run_decode_barriered(max(warmup, 1))  # compile + steady-state warmup
 
-    # FINCHAT_PROFILE_DIR captures a jax profiler trace of ONLY the timed
-    # region (warmup/compile excluded) — TensorBoard/Perfetto via the
-    # device-trace plane of utils/tracing.py.
-    import contextlib
-
-    profile_dir = os.environ.get("FINCHAT_PROFILE_DIR")
-    with contextlib.ExitStack() as stack:
-        if profile_dir:
-            from finchat_tpu.utils.tracing import device_trace
-
-            stack.enter_context(device_trace(profile_dir))
-        elapsed = run_decode_barriered(steps)
+    elapsed = run_decode_barriered(steps)
 
     tok_s = batch * steps / elapsed
 

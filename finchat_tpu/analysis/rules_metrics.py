@@ -22,6 +22,14 @@ from ``utils/tracing.py``'s ``SPAN_MARKS``, every ``TRACER.event("...")``
 literal from the full ``TRACE_EVENT_NAMES`` registry, and every
 ``TRACER.anomaly("...")`` literal from ``ANOMALY_KINDS`` — a typo'd name
 otherwise just silently vanishes from every timeline and flight dump.
+The same holds for the names a profile is reduced by (ISSUE 24): a
+``named_scope("...")`` literal must come from ``DEVICE_SCOPES``, a
+``TRACER.phase("...")`` literal from ``ROUND_PHASES``, a
+``TRACER.startup("...")`` / ``startup_phase("...")`` literal from
+``STARTUP_PHASES``, and a ``span.finish(reason="...")`` literal — or the
+one a ``_close_span(handle, "...")`` helper forwards — from
+``FINISH_REASONS`` (each checked only where the analyzed tracing module
+declares that registry).
 Literal names are checked wherever they appear, INCLUDING through the
 repo's forwarding helpers (a call to a ``_trace``-named helper whose
 literal string argument carries the event name); a forwarding helper's
@@ -45,7 +53,9 @@ from finchat_tpu.analysis.core import Finding, ProjectIndex, Rule, dotted_name
 
 _EMITTERS = {"inc", "set_gauge", "observe"}
 # the tracing-registry names read out of utils/tracing.py
-_REGISTRY_VARS = ("SPAN_MARKS", "TRACE_EVENTS", "ANOMALY_KINDS")
+_REGISTRY_VARS = ("SPAN_MARKS", "TRACE_EVENTS", "ANOMALY_KINDS",
+                  "ROUND_PHASES", "DEVICE_SCOPES", "FINISH_REASONS",
+                  "STARTUP_PHASES")
 
 
 class MetricsDisciplineRule(Rule):
@@ -159,7 +169,8 @@ class MetricsDisciplineRule(Rule):
         registries = _tracing_registries(project)
         if registries is None:
             return []  # no tracing module in the analyzed set
-        span_marks, trace_events, anomaly_kinds = registries
+        span_marks, trace_events, anomaly_kinds = (
+            registries[v] for v in _REGISTRY_VARS[:3])
         all_names = span_marks | trace_events | anomaly_kinds
         findings: list[Finding] = []
 
@@ -180,7 +191,18 @@ class MetricsDisciplineRule(Rule):
                         continue
                     receiver = (dotted_name(func.value) or "")
                     head = receiver.split(".")[-1]
-                    if func.attr == "mark" and head == "span":
+                    declared = _registry_literals(func, head, node)
+                    if declared is not None:
+                        var, what, names = declared
+                        for name in names:
+                            # a tracing module without that registry (a
+                            # fixture's miniature one) leaves the check out
+                            if var in registries and name not in registries[var]:
+                                bad(mod, node, fn,
+                                    f"{what} `{name}` is not declared in {var} "
+                                    "(utils/tracing.py) — nothing that reads a "
+                                    "profile or a timeline would find it")
+                    elif func.attr == "mark" and head == "span":
                         for name in _name_literals(node):
                             if name not in span_marks:
                                 bad(mod, node, fn,
@@ -215,6 +237,28 @@ class MetricsDisciplineRule(Rule):
         return findings
 
 
+def _registry_literals(func: ast.Attribute, head: str, node: ast.Call):
+    """(registry, what it names, the call's literals) for the calls whose
+    name argument must come from one of the ISSUE 24 registries — scopes,
+    round phases, start-up phases, finish reasons (also through the
+    scheduler's ``_close_span(handle, "...")`` forwarding helper) — or
+    None for any other call."""
+    tracer = head.lower().endswith("tracer")
+    if func.attr == "named_scope":
+        return "DEVICE_SCOPES", "named scope", _name_literals(node)
+    if func.attr == "phase" and tracer:
+        return "ROUND_PHASES", "round phase", _name_literals(node)
+    if func.attr in ("startup", "startup_phase") and tracer:
+        return "STARTUP_PHASES", "start-up phase", _name_literals(node)
+    if func.attr == "finish" and head == "span":
+        return "FINISH_REASONS", "finish reason", [
+            name for kw in node.keywords if kw.arg == "reason"
+            for name in _const_strings(kw.value)]
+    if func.attr == "_close_span":
+        return "FINISH_REASONS", "finish reason", _name_literals(node, anywhere=True)
+    return None
+
+
 def _name_literals(node: ast.Call, anywhere: bool = False) -> list[str]:
     """The event-name string literal(s) of a tracing call: the first
     positional arg (or ``name=`` keyword); with ``anywhere``, the first
@@ -239,8 +283,9 @@ def _name_literals(node: ast.Call, anywhere: bool = False) -> list[str]:
 
 
 def _tracing_registries(project: ProjectIndex):
-    """(SPAN_MARKS, TRACE_EVENTS, ANOMALY_KINDS) string sets from the
-    analyzed set's ``utils/tracing.py``, or None when absent."""
+    """{registry name: its string set} from the analyzed set's
+    ``utils/tracing.py`` (the three event registries always, the others
+    where it assigns them), or None when there is no such module."""
     mod = next(
         (m for m in project.modules.values()
          if m.relpath.endswith("utils/tracing.py")),
@@ -248,16 +293,16 @@ def _tracing_registries(project: ProjectIndex):
     )
     if mod is None:
         return None
-    sets: dict[str, set[str]] = {name: set() for name in _REGISTRY_VARS}
+    sets: dict[str, set[str]] = {name: set() for name in _REGISTRY_VARS[:3]}
     for node in ast.walk(mod.tree):
         if not isinstance(node, ast.Assign):
             continue
         for tgt in node.targets:
-            if isinstance(tgt, ast.Name) and tgt.id in sets:
+            if isinstance(tgt, ast.Name) and tgt.id in _REGISTRY_VARS:
                 for inner in ast.walk(node.value):
                     if isinstance(inner, ast.Constant) and isinstance(inner.value, str):
-                        sets[tgt.id].add(inner.value)
-    return (sets["SPAN_MARKS"], sets["TRACE_EVENTS"], sets["ANOMALY_KINDS"])
+                        sets.setdefault(tgt.id, set()).add(inner.value)
+    return sets
 
 
 def _class_uses_labeled_view(fn) -> bool:

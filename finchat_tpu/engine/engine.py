@@ -145,8 +145,6 @@ def _paged_attention_fn(
     interpret = attn_backend == "pallas-interpret"
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
-        from finchat_tpu.utils.tracing import named_scope
-
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
         B, C = k.shape[:2]
@@ -154,7 +152,7 @@ def _paged_attention_fn(
         if (C == 1 or inplace_append) and attn_backend != "ref":
             # decode / spec verify: in-place single-page RMW appends (no
             # cache copy); token i of the chunk is valid iff i < n_valid
-            with named_scope("kv_append"):
+            with jax.named_scope("kv_append"):
                 for i in range(C):
                     kv_new = jnp.concatenate(
                         [k[:, i].reshape(B, 1, -1), v[:, i].reshape(B, 1, -1)],
@@ -179,12 +177,12 @@ def _paged_attention_fn(
         else:
             # prefill chunk (or jnp reference path): XLA scatter — one
             # cache copy amortized over the whole batched chunk
-            with named_scope("kv_scatter"):
+            with jax.named_scope("kv_scatter"):
                 k_pages, v_pages, k_scales, v_scales = _scatter_kv(
                     (k_pages, v_pages, k_scales, v_scales), k, v,
                     page_table, start_pos, n_valid, page_size, layer_idx, n_kv,
                 )
-        with named_scope("paged_attention"):
+        with jax.named_scope("paged_attention"):
             out = paged_attention(
                 q, k_pages, v_pages, page_table, start_pos, start_pos + n_valid,
                 layer, page_size=page_size, n_kv=n_kv, backend=attn_backend,
@@ -590,13 +588,11 @@ def _ragged_attention_fn(
         tok_wpos = jnp.maximum(tok_pos - row_gap[safe_row], 0)
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
-        from finchat_tpu.utils.tracing import named_scope
-
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
         T = k.shape[1]
         layer = layer_idx.reshape(1)
-        with named_scope("kv_scatter_ragged"):
+        with jax.named_scope("kv_scatter_ragged"):
             # each packed token is one (B=T, C=1) scatter row at its own
             # COMPACTED position through its own page list
             k_pages, v_pages, k_scales, v_scales = _scatter_kv(
@@ -604,7 +600,7 @@ def _ragged_attention_fn(
                 k.reshape(T, 1, n_kv, -1), v.reshape(T, 1, n_kv, -1),
                 pt_tok, tok_wpos, n_valid_tok, page_size, layer_idx, n_kv,
             )
-        with named_scope("ragged_paged_attention"):
+        with jax.named_scope("ragged_paged_attention"):
             out = ragged_paged_attention(
                 q[0], k_pages, v_pages, page_rows, tok_row, tok_pos,
                 row_kv_len, layer, page_size=page_size, n_kv=n_kv,

@@ -71,6 +71,7 @@ class SamplingParams:
     grammar: str | None = None
 
 
+@jax.named_scope("sample")
 def sample(
     logits: Array,  # [B, vocab] fp32
     rng: Array,

@@ -94,6 +94,7 @@ model replica:
 from __future__ import annotations
 
 import asyncio
+import statistics
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -116,7 +117,14 @@ from finchat_tpu.engine.sampler import SamplingParams
 from finchat_tpu.utils.faults import inject
 from finchat_tpu.utils.logging import get_logger
 from finchat_tpu.utils.metrics import METRICS, Timer
-from finchat_tpu.utils.tracing import TRACER, RequestSpan
+from finchat_tpu.utils.tracing import (
+    SLOW_ROUND_FLOOR_S,
+    SLOW_ROUND_LOG_INTERVAL_S,
+    SLOW_ROUND_MEDIANS,
+    TRACER,
+    RequestSpan,
+    RoundPhases,
+)
 
 logger = get_logger(__name__)
 
@@ -434,6 +442,16 @@ class ContinuousBatchingScheduler:
         # by the exact PR 10 attribution, not a new ad-hoc window.
         self.freerun_rounds = max(1, getattr(engine, "freerun_rounds", 1))
         self._round_tally = 0
+        # the current loop iteration on the clock (ISSUE 24): seconds per
+        # phase, the base phase it runs in, the dispatch tally when it
+        # began, the kind of its last dispatch; the last 256 rounds'
+        # lengths give the slow-round WARNING its median
+        self._phases = RoundPhases()
+        self._round_mark = 0
+        self._round_kind = "drain"  # a round that only consumed
+        self._base_phase = None
+        self._recent_rounds: deque[float] = deque(maxlen=256)
+        self._slow_round_logged = float("-inf")
         self._coexist_round_mark = 0
         if self.freerun_rounds > 1:
             # pre-seed the cap reasons (the _use_mixed demotion-counter
@@ -811,36 +829,79 @@ class ContinuousBatchingScheduler:
                 self.engine.set_page_table_rows({handle.slot: handle.page_list})
         handle.prompt_ids = list(full_ids)
         handle.history = list(full_ids)
+        if handle.slot >= 0:
+            # admitted already: what the hold prefilled is off the path
+            self._book_prompt(handle, handle.prefill_pos)
         handle.held = False
         handle.grafted = True
         self.metrics.inc("finchat_partial_grafts_total")
         self._wakeup.set()
         return True
 
-    def _tally_dispatch(self) -> None:
+    def _tally_dispatch(self, kind: str) -> None:
         """Count one enqueued device program (the PR 10 coexist
         attribution); engines whose compiled steps route quantized matmuls
         through the fused kernel also book it on
         finchat_quantmatmul_fused_dispatches_total — every model dispatch
-        in that configuration reads packed weights."""
+        in that configuration reads packed weights. ``kind`` names the
+        round in its ``round`` event and slow-round WARNING."""
         self._dispatch_tally += 1
+        self._round_kind = kind
         if self._qm_fused:
             self.metrics.inc("finchat_quantmatmul_fused_dispatches_total")
 
-    def _trace_dispatch(self, kind: str, rows: list, *,
+    def _trace_dispatch(self, kind: str, riders: list, *,
                         ts: float | None = None,
                         dur: float | None = None) -> None:
         """Record one model dispatch in the trace ring (ISSUE 12): which
         ``[slot, trace_id, mode]`` rows rode it, so a request's exported
         timeline shows every dispatch that carried its rows even when many
-        requests share one ragged dispatch. Host data only — the rows come
-        from the membership/descriptor bookkeeping the round already built,
-        so the event adds zero device syncs (finchat-lint R2). Callers
-        guard with ``TRACER.enabled`` so the row list is never built for
-        nothing."""
+        requests share one ragged dispatch. ``riders`` are ``(slot,
+        trace_id, mode, kv)`` per row; Σ kv — the context tokens the
+        attention kernel reads, each row's context after the dispatch less
+        what a bounded policy evicted — goes with ``kind`` onto the phase
+        annotation open now (ISSUE 24), where a profiler capture shows it
+        on the clock of the kernels that read them. Host data only — they
+        come from the membership/descriptor bookkeeping the round already
+        built, so the event adds zero device syncs (finchat-lint R2).
+        Callers guard with ``TRACER.enabled`` so the list is never built
+        for nothing."""
         TRACER.event("dispatch", ts=ts, dur=dur, track=self._trace_track,
                      args={"kind": kind, "n": self._dispatch_tally,
-                           "quant": self._quant_label, "rows": rows})
+                           "quant": self._quant_label,
+                           "rows": [[slot, tid, mode]
+                                    for slot, tid, mode, _kv in riders]})
+        self._phases.note(kind=kind,
+                          kv_tokens=sum(kv for *_row, kv in riders))
+
+    @staticmethod
+    def _rider(handle: SequenceHandle, mode: str) -> tuple:
+        """A sequence's row of a dispatch, read AFTER the dispatch advanced
+        its ``kv_ctx``."""
+        return (handle.slot, handle.trace_id or handle.seq_id, mode,
+                handle.kv_ctx - handle.kv_gap)
+
+    async def _fetch(self, fn):
+        """Await ``fn()`` — the device→host copies of a dispatch's results —
+        in a worker thread, so the event loop keeps serving (never
+        ``block_until_ready`` on the consume path: the finchat-lint R2
+        seam). The wait is the round's ``fetch_wait`` phase."""
+        with TRACER.phase("fetch_wait", self._phases):
+            return await asyncio.to_thread(fn)
+
+    def _book_prompt(self, handle: SequenceHandle, cached: int) -> None:
+        """The prompt's size and how much of it needs no prefill, on the
+        request's span and the ``finchat_prompt_tokens*`` counters; a later
+        call (a hold's graft) books the difference."""
+        span = handle.span
+        n = len(handle.prompt_ids)
+        self.metrics.inc("finchat_prompt_tokens_total", n - span.prompt_tokens)
+        self.metrics.inc("finchat_prompt_tokens_cached_total",
+                         cached - span.cached_tokens)
+        span.prompt_tokens, span.cached_tokens = n, cached
+
+    def _close_span(self, handle: SequenceHandle, reason: str) -> None:
+        handle.span.finish(reason=reason, generated=handle.generated)
 
     def _ring_routed(self, handle: SequenceHandle) -> bool:
         """Does this prefilling handle take the seq-sharded ring path this
@@ -894,7 +955,7 @@ class ContinuousBatchingScheduler:
                 self.metrics.inc("finchat_partial_stale_reaps_total")
                 self.pending.remove(handle)
                 handle.finished = True
-                handle.span.finish()
+                self._close_span(handle, "error")
                 handle.events.put_nowait(
                     {"type": "error", "message": "partial hold expired"}
                 )
@@ -1187,7 +1248,7 @@ class ContinuousBatchingScheduler:
                                args={"seq_id": handle.seq_id,
                                      "replica": self.replica_id})
                 handle.finished = True
-                handle.span.finish()
+                self._close_span(handle, "shed")
                 handle.events.put_nowait({
                     "type": "error",
                     "message": "deadline exceeded before admission; retry with backoff",
@@ -1413,6 +1474,8 @@ class ContinuousBatchingScheduler:
                     self.metrics.inc("finchat_prefix_tokens_saved_total", shared_len)
             handle.slot = slot
             handle.span.mark("admitted")
+            if not handle.preempted:  # a replay's history is no new prompt
+                self._book_prompt(handle, resume_pos)
             if handle.constraint is None:
                 self._temperature[slot] = handle.sampling.temperature
                 self._top_p[slot] = handle.sampling.top_p
@@ -1436,7 +1499,7 @@ class ContinuousBatchingScheduler:
 
     def _finish(self, handle: SequenceHandle, reason: str) -> None:
         handle.finished = True
-        handle.span.finish()
+        self._close_span(handle, reason)
         handle.events.put_nowait({"type": "done", "reason": reason})
 
     def _release(self, handle: SequenceHandle) -> None:
@@ -1574,7 +1637,7 @@ class ContinuousBatchingScheduler:
         self._release(handle)
         if error is not None:
             handle.finished = True
-            handle.span.finish()
+            self._close_span(handle, "error")
             handle.events.put_nowait({"type": "error", "message": error})
         else:
             self._finish(handle, reason)
@@ -2093,12 +2156,12 @@ class ContinuousBatchingScheduler:
                              handle.seq_id, e)
             self._release(handle)
             handle.finished = True
-            handle.span.finish()
+            self._close_span(handle, "drained")
             handle.events.put_nowait(dict(shutdown_error))
         for handle in list(self.pending):
             self.pending.remove(handle)
             handle.finished = True
-            handle.span.finish()
+            self._close_span(handle, "drained")
             handle.events.put_nowait(dict(shutdown_error))
         self.metrics.set_gauge("finchat_queue_depth", 0)
         self.spill_sessions()
@@ -2348,7 +2411,7 @@ class ContinuousBatchingScheduler:
                     # series, unlabeled like the rest of finchat_fleet_*
                     METRICS.inc("finchat_fleet_drain_failures_total")
                     handle.finished = True
-                    handle.span.finish()
+                    self._close_span(handle, "replica_out")
                     handle.events.put_nowait({
                         "type": "error", "message": error,
                         "code": "replica_out", "retryable": True,
@@ -2464,7 +2527,7 @@ class ContinuousBatchingScheduler:
                 if handle.preempted:
                     self.pending.remove(handle)
                     handle.finished = True
-                    handle.span.finish()
+                    self._close_span(handle, "error")
                     handle.events.put_nowait(
                         {"type": "error", "message": f"engine rebuild failed: {e}"}
                     )
@@ -2514,17 +2577,18 @@ class ContinuousBatchingScheduler:
                         # in-flight decode streams stall for the whole
                         # seq-sharded prefill — the latency trade the
                         # chunked path below exists to avoid
-                        with Timer(self.metrics, "finchat_prefill_seconds") as _pt:
+                        with (TRACER.phase("dispatch", self._phases),
+                              Timer(self.metrics, "finchat_prefill_seconds") as _pt):
                             ring_logits = eng.prefill_ring(handle.slot, handle.prompt_ids)
-                        self._tally_dispatch()
+                        self._tally_dispatch("ring")
+                        handle.prefill_pos = len(handle.prompt_ids)
+                        handle.kv_ctx = handle.prefill_pos
                         if TRACER.enabled:
                             self._trace_dispatch(
                                 "ring",
-                                [[handle.slot, handle.trace_id or handle.seq_id, "ring"]],
+                                [self._rider(handle, "ring")],
                                 ts=_pt.started, dur=_pt.elapsed,
                             )
-                        handle.prefill_pos = len(handle.prompt_ids)
-                        handle.kv_ctx = handle.prefill_pos
                         completions.append((handle, ring_logits, handle.epoch))
                         continue
                     # chunked ring: ONE segment per round — decode steps
@@ -2534,19 +2598,20 @@ class ContinuousBatchingScheduler:
                     # attention, engine.prefill_ring_segment)
                     handle.ring_path = True
                     seg = handle.prompt_ids[handle.prefill_pos : handle.prefill_pos + rc]
-                    with Timer(self.metrics, "finchat_prefill_seconds") as _pt:
+                    with (TRACER.phase("dispatch", self._phases),
+                          Timer(self.metrics, "finchat_prefill_seconds") as _pt):
                         seg_logits = eng.prefill_ring_segment(
                             handle.slot, seg, handle.prefill_pos
                         )
-                    self._tally_dispatch()
+                    self._tally_dispatch("ring_segment")
+                    handle.prefill_pos += len(seg)
+                    handle.kv_ctx = handle.prefill_pos
                     if TRACER.enabled:
                         self._trace_dispatch(
                             "ring_segment",
-                            [[handle.slot, handle.trace_id or handle.seq_id, "ring"]],
+                            [self._rider(handle, "ring")],
                             ts=_pt.started, dur=_pt.elapsed,
                         )
-                    handle.prefill_pos += len(seg)
-                    handle.kv_ctx = handle.prefill_pos
                     if handle.prefill_pos >= len(handle.prompt_ids):
                         completions.append((handle, seg_logits, handle.epoch))
                     continue
@@ -2566,19 +2631,15 @@ class ContinuousBatchingScheduler:
             rows += [(j.slot, j.ids, j.pos) for j in jobs]
             N = round_up_pow2(len(rows))
             tokens, slots, starts, n_valids = self._pack_prefill_rows(rows, N, C)
-            with Timer(self.metrics, "finchat_prefill_seconds") as _pt:
+            with (TRACER.phase("dispatch", self._phases),
+                  Timer(self.metrics, "finchat_prefill_seconds") as _pt):
                 # host-side dispatch time for the round (device work is
                 # async; steady-state it tracks the round cadence)
                 logits = eng.prefill_rows(
                     jnp.asarray(tokens), jnp.asarray(slots),
                     jnp.asarray(starts), jnp.asarray(n_valids),
                 )
-            self._tally_dispatch()
-            if TRACER.enabled:
-                trows = [[h.slot, h.trace_id or h.seq_id, "prefill"] for h in batch]
-                trows += [[j.slot, f"prefix:{j.owner}", "prefix"] for j in jobs]
-                self._trace_dispatch("prefill", trows,
-                                     ts=_pt.started, dur=_pt.elapsed)
+            self._tally_dispatch("prefill")
             for i, handle in enumerate(batch):
                 handle.prefill_pos += int(n_valids[i])
                 handle.kv_ctx = handle.prefill_pos
@@ -2589,6 +2650,13 @@ class ContinuousBatchingScheduler:
                     completions.append((handle, logits[i], handle.epoch))
             for i, job in enumerate(jobs, start=len(batch)):
                 job.pos += int(n_valids[i])
+            if TRACER.enabled:
+                riders = [self._rider(h, "prefill") for h in batch]
+                riders += [(j.slot, f"prefix:{j.owner}", "prefix", j.pos)
+                           for j in jobs]
+                self._trace_dispatch("prefill", riders,
+                                     ts=_pt.started, dur=_pt.elapsed)
+            for job in jobs:
                 if job.pos >= job.shared_len:
                     self._complete_prefix_job(job, "chunked")
 
@@ -2596,16 +2664,17 @@ class ContinuousBatchingScheduler:
             return  # dispatch-only round, no host sync needed
 
         tokens_dev = []
-        for h, row_logits, _e in completions:
-            h.span.mark("prefill_done")
-            s = h.sampling
-            eng.state, token = commit_first_token(
-                eng.state, jnp.int32(h.slot), row_logits,
-                jnp.float32(s.temperature), jnp.float32(s.top_p), jnp.int32(s.top_k),
-            )
-            tokens_dev.append(token)
+        with TRACER.phase("dispatch", self._phases):
+            for h, row_logits, _e in completions:
+                h.span.mark("prefill_done")
+                s = h.sampling
+                eng.state, token = commit_first_token(
+                    eng.state, jnp.int32(h.slot), row_logits,
+                    jnp.float32(s.temperature), jnp.float32(s.top_p), jnp.int32(s.top_k),
+                )
+                tokens_dev.append(token)
         # one host fetch for all completions (worker thread keeps loop live)
-        fetched, logits_host = await asyncio.to_thread(
+        fetched, logits_host = await self._fetch(
             lambda: (
                 [int(np.asarray(t)) for t in tokens_dev],
                 [
@@ -2614,19 +2683,20 @@ class ContinuousBatchingScheduler:
                 ],
             )
         )
-        for (handle, _lg, epoch), token_id, row_host in zip(completions, fetched, logits_host):
-            if handle.finished or handle.epoch != epoch:
-                continue  # cancelled/preempted while fetching
-            try:
-                if handle.constraint is not None:
-                    token_id = self._constrained_pick(handle, row_host)
-                self.prefilling.remove(handle)
-                self.decoding[handle.slot] = handle
-                self._deliver(handle, int(token_id))
-            except Exception as e:  # per-sequence isolation (host-side pick
-                # or delivery error must not fail the other sequences)
-                logger.error("prefill completion error for %s: %s", handle.seq_id, e)
-                self._evict(handle, "error", error=str(e))
+        with TRACER.phase("deliver", self._phases):
+            for (handle, _lg, epoch), token_id, row_host in zip(completions, fetched, logits_host):
+                if handle.finished or handle.epoch != epoch:
+                    continue  # cancelled/preempted while fetching
+                try:
+                    if handle.constraint is not None:
+                        token_id = self._constrained_pick(handle, row_host)
+                    self.prefilling.remove(handle)
+                    self.decoding[handle.slot] = handle
+                    self._deliver(handle, int(token_id))
+                except Exception as e:  # per-sequence isolation (host-side pick
+                    # or delivery error must not fail the other sequences)
+                    logger.error("prefill completion error for %s: %s", handle.seq_id, e)
+                    self._evict(handle, "error", error=str(e))
 
     @staticmethod
     def _pack_prefill_rows(rows, N: int, C: int):
@@ -2816,7 +2886,8 @@ class ContinuousBatchingScheduler:
             return None
         inject("scheduler.decode", replica=self.replica_id)
         inject("scheduler.mixed", replica=self.replica_id)
-        with Timer(self.metrics, "finchat_mixed_step_seconds") as _mt:
+        with (TRACER.phase("dispatch", self._phases),
+              Timer(self.metrics, "finchat_mixed_step_seconds") as _mt):
             ring_tok, ring_n, ring_blk = eng.ragged_multi(
                 jnp.asarray(plan.tokens), jnp.asarray(plan.tok_row),
                 jnp.asarray(plan.row_slot), jnp.asarray(plan.row_start),
@@ -2827,25 +2898,22 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(self._temperature), jnp.asarray(self._top_p),
                 jnp.asarray(self._top_k), self.eos_id,
             )
-        self._tally_dispatch()
+        self._tally_dispatch("freerun")
         self._round_tally += rounds
         self.metrics.inc("finchat_freerun_dispatches_total")
         # unit is ROUNDS, not seconds: the N-rounds-per-1-dispatch
         # attribution instrument ISSUE 13 names
         self.metrics.observe("finchat_freerun_rounds_per_dispatch", rounds)  # finchat-lint: disable=metrics-discipline -- rounds-per-dispatch histogram: the unit is rounds (ISSUE 13 names this metric); _seconds would be a lie
-        if TRACER.enabled:
-            trows = []
-            for _row, slot, owner, _epoch, kind in members:
-                tid = (f"prefix:{owner.owner}" if kind == "job"
-                       else (owner.trace_id or owner.seq_id))
-                trows.append([slot, tid, "freerun"])
-            self._trace_dispatch("freerun", trows,
-                                 ts=_mt.started, dur=_mt.elapsed)
         # prompt-cursor bookkeeping at dispatch, exactly _ragged_round's
         # discipline: the staged chunks ARE dispatched
+        traced = TRACER.enabled
+        riders = []  # a row's captured rounds count against its final context
         for row, slot, owner, _epoch, kind in members:
             adv = plan.advanced.get(row, 0)
             if kind == "job":
+                if traced:
+                    riders.append((slot, f"prefix:{owner.owner}", "freerun",
+                                   owner.pos + adv))
                 if adv:
                     owner.pos += adv
                     if owner.pos >= owner.shared_len:
@@ -2862,6 +2930,11 @@ class ContinuousBatchingScheduler:
             if row in plan.completes_at:
                 extra -= 1
             owner.kv_ctx += max(0, extra)
+            if traced:
+                riders.append(self._rider(owner, "freerun"))
+        if traced:
+            self._trace_dispatch("freerun", riders,
+                                 ts=_mt.started, dur=_mt.elapsed)
         return _InFlightRing(
             tokens=ring_tok, n_emitted=ring_n, blocks=ring_blk,
             rounds=rounds, members=members, armed=plan.row_arm,
@@ -2883,73 +2956,74 @@ class ContinuousBatchingScheduler:
         event. A round emitting where the staged plan never armed is a
         free-run divergence: flight-recorder dump, tokens not
         delivered."""
-        tok_host, n_host, blk_host = await asyncio.to_thread(
+        tok_host, n_host, blk_host = await self._fetch(
             lambda: (np.asarray(ring.tokens), np.asarray(ring.n_emitted),
                      np.asarray(ring.blocks)),
         )
-        armed = ring.armed
-        if bool(((n_host > 0) & ~armed).any()):
-            # ring replay mismatch: the device emitted outside the staged
-            # schedule — dump the black box and deliver nothing from the
-            # unarmed cells (they were never part of any stream)
-            self.metrics.inc("finchat_freerun_divergences_total")
-            TRACER.anomaly("freerun_divergence", args={
-                "replica": self.replica_id, "rounds": ring.rounds,
-                "cells": int(((n_host > 0) & ~armed).sum()),
-            })
-        K1 = int(blk_host.shape[1])
-        wasted = 0
-        epoch_break = False
-        for r in range(ring.rounds):
-            for row, slot, owner, epoch, kind in ring.members:
-                if kind == "job":
-                    continue
-                handle: SequenceHandle = owner
-                stale = (handle.finished or handle.slot != slot
-                         or handle.epoch != epoch)
-                n = int(n_host[r, row])
-                if n > 0 and armed[r, row]:
-                    if stale:
-                        # evicted/cancelled/preempted since dispatch: the
-                        # replay recomputes this token — discarding it
-                        # here is what keeps delivery exactly-once
-                        epoch_break = True
-                        wasted += n
-                    else:
-                        if ring.completes_at.get(row) == r:
-                            handle.span.mark("prefill_done")
-                            self.prefilling.remove(handle)
-                            self.decoding[handle.slot] = handle
-                        self._deliver(handle, int(tok_host[r, row]))
-                        stale = (handle.finished or handle.slot != slot
-                                 or handle.epoch != epoch)
-                if K1 and ring.loop_rounds[r, slot]:
-                    # fused tail rows: -1 marks where the device stop
-                    # mask kicked in (exactly _consume_block's drain)
-                    if stale:
-                        wasted += K1
+        with TRACER.phase("deliver", self._phases):
+            armed = ring.armed
+            if bool(((n_host > 0) & ~armed).any()):
+                # ring replay mismatch: the device emitted outside the staged
+                # schedule — dump the black box and deliver nothing from the
+                # unarmed cells (they were never part of any stream)
+                self.metrics.inc("finchat_freerun_divergences_total")
+                TRACER.anomaly("freerun_divergence", args={
+                    "replica": self.replica_id, "rounds": ring.rounds,
+                    "cells": int(((n_host > 0) & ~armed).sum()),
+                })
+            K1 = int(blk_host.shape[1])
+            wasted = 0
+            epoch_break = False
+            for r in range(ring.rounds):
+                for row, slot, owner, epoch, kind in ring.members:
+                    if kind == "job":
                         continue
-                    for j in range(K1):
-                        token = int(blk_host[r, j, slot])
-                        if token < 0:
-                            wasted += K1 - j
-                            break
-                        self._deliver(handle, token)
-                        if handle.finished:
-                            wasted += K1 - j - 1
-                            break
-        if wasted:
-            self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total",
-                             wasted)
-        if epoch_break:
-            # the membership epoch invalidated this capture mid-flight:
-            # visible on the Perfetto timeline as the capture/replay
-            # boundary (ISSUE 13)
-            self.metrics.inc("finchat_freerun_epoch_breaks_total")
-            TRACER.event("freerun_epoch_break", track=self._trace_track,
-                         args={"replica": self.replica_id,
-                               "rounds": ring.rounds})
-        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
+                    handle: SequenceHandle = owner
+                    stale = (handle.finished or handle.slot != slot
+                             or handle.epoch != epoch)
+                    n = int(n_host[r, row])
+                    if n > 0 and armed[r, row]:
+                        if stale:
+                            # evicted/cancelled/preempted since dispatch: the
+                            # replay recomputes this token — discarding it
+                            # here is what keeps delivery exactly-once
+                            epoch_break = True
+                            wasted += n
+                        else:
+                            if ring.completes_at.get(row) == r:
+                                handle.span.mark("prefill_done")
+                                self.prefilling.remove(handle)
+                                self.decoding[handle.slot] = handle
+                            self._deliver(handle, int(tok_host[r, row]))
+                            stale = (handle.finished or handle.slot != slot
+                                     or handle.epoch != epoch)
+                    if K1 and ring.loop_rounds[r, slot]:
+                        # fused tail rows: -1 marks where the device stop
+                        # mask kicked in (exactly _consume_block's drain)
+                        if stale:
+                            wasted += K1
+                            continue
+                        for j in range(K1):
+                            token = int(blk_host[r, j, slot])
+                            if token < 0:
+                                wasted += K1 - j
+                                break
+                            self._deliver(handle, token)
+                            if handle.finished:
+                                wasted += K1 - j - 1
+                                break
+            if wasted:
+                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total",
+                                 wasted)
+            if epoch_break:
+                # the membership epoch invalidated this capture mid-flight:
+                # visible on the Perfetto timeline as the capture/replay
+                # boundary (ISSUE 13)
+                self.metrics.inc("finchat_freerun_epoch_breaks_total")
+                TRACER.event("freerun_epoch_break", track=self._trace_track,
+                             args={"replica": self.replica_id,
+                                   "rounds": ring.rounds})
+            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _use_mixed(self) -> bool:
         """Can this iteration run ONE packed ragged dispatch instead of a
@@ -3145,7 +3219,8 @@ class ContinuousBatchingScheduler:
         T = eng.ragged_bucket(len(packed))
         packed += [0] * (T - len(packed))
         tok_row += [R] * (T - len(tok_row))
-        with Timer(self.metrics, "finchat_mixed_step_seconds") as _mt:
+        with (TRACER.phase("dispatch", self._phases),
+              Timer(self.metrics, "finchat_mixed_step_seconds") as _mt):
             emitted_dev, n_em_dev, row_logits_dev, block_dev = eng.ragged_mixed(
                 jnp.asarray(np.asarray(packed, np.int32)),
                 jnp.asarray(np.asarray(tok_row, np.int32)),
@@ -3157,30 +3232,32 @@ class ContinuousBatchingScheduler:
                 jnp.asarray(self._top_p), jnp.asarray(self._top_k),
                 self.eos_id,
             )
-        self._tally_dispatch()
-        if TRACER.enabled:
-            # dispatch span piggybacking on the round's own row
-            # bookkeeping (ISSUE 12): every (slot, trace, mode) row that
-            # rode this one ragged dispatch, from host data only
-            trows = [[h.slot, h.trace_id or h.seq_id, "prefill"]
-                     for _i, h in prefill_rows]
-            trows += [[j.slot, f"prefix:{j.owner}", "prefix"]
-                      for _i, j in job_rows]
-            trows += [[slot, h.trace_id or h.seq_id, "constrained"]
-                      for _i, slot, h, _e in constrained_decode]
-            trows += [[slot, h.trace_id or h.seq_id,
-                       "decode_loop" if loop_active[slot] else "decode"]
-                      for _i, slot, h, _e in plain_rows]
-            trows += [[slot, h.trace_id or h.seq_id, "spec"]
-                      for _i, slot, h, _e in spec_rows]
-            self._trace_dispatch("ragged", trows,
-                                 ts=_mt.started, dur=_mt.elapsed)
+        self._tally_dispatch("ragged")
         # prefill bookkeeping happens at dispatch: row_len is host data
         for idx, h in prefill_rows:
             h.prefill_pos += int(row_len[idx])
             h.kv_ctx = h.prefill_pos
         for idx, job in job_rows:
             job.pos += int(row_len[idx])
+        if TRACER.enabled:
+            # dispatch span piggybacking on the round's own row
+            # bookkeeping (ISSUE 12): every (slot, trace, mode) row that
+            # rode this one ragged dispatch, from host data only
+            riders = [self._rider(h, "prefill") for _i, h in prefill_rows]
+            riders += [(j.slot, f"prefix:{j.owner}", "prefix", j.pos)
+                       for _i, j in job_rows]
+            riders += [self._rider(h, "constrained")
+                       for _i, _slot, h, _e in constrained_decode]
+            riders += [
+                self._rider(h, "decode_loop" if loop_active[slot] else "decode")
+                for _i, slot, h, _e in plain_rows]
+            riders += [
+                (slot, h.trace_id or h.seq_id, "spec",
+                 h.kv_ctx - h.kv_gap + int(row_n_drafts[i]))
+                for i, slot, h, _e in spec_rows]
+            self._trace_dispatch("ragged", riders,
+                                 ts=_mt.started, dur=_mt.elapsed)
+        for _idx, job in job_rows:
             if job.pos >= job.shared_len:
                 self._complete_prefix_job(job, "ragged")
         logits_sel = None
@@ -3191,79 +3268,80 @@ class ContinuousBatchingScheduler:
         # ONE host fetch serves decode tokens, spec acceptances, first
         # tokens, the fused tail block, and the constrained rows' logits
         # (worker thread keeps the event loop live)
-        emitted, n_emitted, block, logits_host = await asyncio.to_thread(
+        emitted, n_emitted, block, logits_host = await self._fetch(
             lambda: (
                 np.asarray(emitted_dev), np.asarray(n_em_dev),
                 np.asarray(block_dev),
                 np.asarray(logits_sel) if logits_sel is not None else None,
             )
         )
-        for idx, handle, epoch in completions:
-            if handle.finished or handle.epoch != epoch:
-                continue  # cancelled/preempted while fetching
-            handle.span.mark("prefill_done")
-            try:
-                if handle.constraint is not None:
-                    token = self._constrained_pick(
-                        handle, logits_host[constrained_rows.index(idx)]
-                    )
-                else:
-                    token = int(emitted[idx, 0])
-                self.prefilling.remove(handle)
-                self.decoding[handle.slot] = handle
-                self._deliver(handle, int(token))
-            except Exception as e:  # per-sequence isolation
-                logger.error("prefill completion error for %s: %s", handle.seq_id, e)
-                self._evict(handle, "error", error=str(e))
-        for idx, slot, handle, epoch in constrained_decode:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                continue  # evicted/cancelled/preempted since dispatch
-            token = self._constrained_pick(
-                handle, logits_host[constrained_rows.index(idx)]
-            )
-            self._deliver(handle, token)
-        for idx, slot, handle, epoch in plain_rows:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                continue
-            self._deliver(handle, int(emitted[idx, 0]))
-        accepted_total = 0
-        for idx, slot, handle, epoch in spec_rows:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                continue
-            n = int(n_emitted[idx])
-            handle.kv_ctx += max(0, n - 1)  # accepted drafts' context advance
-            accepted_total += max(0, n - 1)
-            for token in emitted[idx, :n]:
-                self._deliver(handle, int(token))
-                if handle.finished:  # EOS / length inside the prefix
-                    break
-        if accepted_total:
-            self.metrics.inc("finchat_spec_tokens_accepted_total", accepted_total)
-        if spec_consulted:
-            # the all-miss demotion bookkeeping keeps its split-path
-            # cadence: a ragged round where every proposal missed (or
-            # nothing was accepted) advances the streak
-            self._spec_note_step(accepted=accepted_total)
-        # fused tail: drain each loop slot's [loop_depth-1] row — -1 marks
-        # where the device stop mask kicked in after a phase-1/tail EOS
-        wasted = 0
-        K1 = int(block.shape[0])
-        for slot, handle, epoch in loop_members:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                wasted += K1  # phase-1 EOS/length/cancel: device free-ran
-                continue
-            for j in range(K1):
-                token = int(block[j, slot])
-                if token < 0:  # device stop mask
-                    wasted += K1 - j
-                    break
+        with TRACER.phase("deliver", self._phases):
+            for idx, handle, epoch in completions:
+                if handle.finished or handle.epoch != epoch:
+                    continue  # cancelled/preempted while fetching
+                handle.span.mark("prefill_done")
+                try:
+                    if handle.constraint is not None:
+                        token = self._constrained_pick(
+                            handle, logits_host[constrained_rows.index(idx)]
+                        )
+                    else:
+                        token = int(emitted[idx, 0])
+                    self.prefilling.remove(handle)
+                    self.decoding[handle.slot] = handle
+                    self._deliver(handle, int(token))
+                except Exception as e:  # per-sequence isolation
+                    logger.error("prefill completion error for %s: %s", handle.seq_id, e)
+                    self._evict(handle, "error", error=str(e))
+            for idx, slot, handle, epoch in constrained_decode:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    continue  # evicted/cancelled/preempted since dispatch
+                token = self._constrained_pick(
+                    handle, logits_host[constrained_rows.index(idx)]
+                )
                 self._deliver(handle, token)
-                if handle.finished:  # EOS (host view) / length / cancel
-                    wasted += K1 - j - 1
-                    break
-        if wasted:
-            self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
-        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
+            for idx, slot, handle, epoch in plain_rows:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    continue
+                self._deliver(handle, int(emitted[idx, 0]))
+            accepted_total = 0
+            for idx, slot, handle, epoch in spec_rows:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    continue
+                n = int(n_emitted[idx])
+                handle.kv_ctx += max(0, n - 1)  # accepted drafts' context advance
+                accepted_total += max(0, n - 1)
+                for token in emitted[idx, :n]:
+                    self._deliver(handle, int(token))
+                    if handle.finished:  # EOS / length inside the prefix
+                        break
+            if accepted_total:
+                self.metrics.inc("finchat_spec_tokens_accepted_total", accepted_total)
+            if spec_consulted:
+                # the all-miss demotion bookkeeping keeps its split-path
+                # cadence: a ragged round where every proposal missed (or
+                # nothing was accepted) advances the streak
+                self._spec_note_step(accepted=accepted_total)
+            # fused tail: drain each loop slot's [loop_depth-1] row — -1 marks
+            # where the device stop mask kicked in after a phase-1/tail EOS
+            wasted = 0
+            K1 = int(block.shape[0])
+            for slot, handle, epoch in loop_members:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    wasted += K1  # phase-1 EOS/length/cancel: device free-ran
+                    continue
+                for j in range(K1):
+                    token = int(block[j, slot])
+                    if token < 0:  # device stop mask
+                        wasted += K1 - j
+                        break
+                    self._deliver(handle, token)
+                    if handle.finished:  # EOS (host view) / length / cancel
+                        wasted += K1 - j - 1
+                        break
+            if wasted:
+                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
+            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _deliver(self, handle: SequenceHandle, token_id: int) -> None:
         now = time.perf_counter()
@@ -3337,19 +3415,19 @@ class ContinuousBatchingScheduler:
             slot for slot, h, _e in members if h.constraint is not None
         )
         need_logits = bool(constrained_slots)
-        result = eng.decode(
-            jnp.asarray(active),
-            jnp.asarray(self._temperature),
-            jnp.asarray(self._top_p),
-            jnp.asarray(self._top_k),
-            return_logits=need_logits,
-        )
-        self._tally_dispatch()
+        with TRACER.phase("dispatch", self._phases):
+            result = eng.decode(
+                jnp.asarray(active),
+                jnp.asarray(self._temperature),
+                jnp.asarray(self._top_p),
+                jnp.asarray(self._top_k),
+                return_logits=need_logits,
+            )
+        self._tally_dispatch("decode")
         if TRACER.enabled:
             self._trace_dispatch(
                 "decode",
-                [[slot, h.trace_id or h.seq_id, "decode"]
-                 for slot, h, _e in members],
+                [self._rider(h, "decode") for _slot, h, _e in members],
             )
         next_tokens, logits = result if need_logits else (result, None)
         if logits is not None:
@@ -3444,19 +3522,20 @@ class ContinuousBatchingScheduler:
                 handle.kv_ctx += self.loop_depth
             else:
                 demoted.append((slot, handle, epoch))
-        token_block = eng.decode_loop(
-            jnp.asarray(active),
-            jnp.asarray(self._temperature),
-            jnp.asarray(self._top_p),
-            jnp.asarray(self._top_k),
-            eos_id=self.eos_id,
-        )
-        self._tally_dispatch()
+        with TRACER.phase("dispatch", self._phases):
+            token_block = eng.decode_loop(
+                jnp.asarray(active),
+                jnp.asarray(self._temperature),
+                jnp.asarray(self._top_p),
+                jnp.asarray(self._top_k),
+                eos_id=self.eos_id,
+            )
+        self._tally_dispatch("decode_loop")
         if TRACER.enabled:
+            # a fused row's K steps count against its final context
             self._trace_dispatch(
                 "decode_loop",
-                [[slot, h.trace_id or h.seq_id, "decode_loop"]
-                 for slot, h, _e in block_members],
+                [self._rider(h, "decode_loop") for _slot, h, _e in block_members],
             )
         self.metrics.inc("finchat_decode_loop_blocks_total")
         self.metrics.set_gauge("finchat_decode_loop_demoted_slots", len(demoted))
@@ -3477,26 +3556,25 @@ class ContinuousBatchingScheduler:
         or a -1 sentinel marks where the device's stop mask kicked in.
         Device iterations spent free-running past a finished slot are the
         price of the fixed-shape block — counted as wasted tail tokens."""
-        tokens_host = await asyncio.to_thread(
-            lambda: np.asarray(blk.block_tokens)
-        )
-        K = tokens_host.shape[0]
-        wasted = 0
-        for slot, handle, epoch in blk.block_members:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                wasted += K  # evicted/cancelled/preempted since dispatch
-                continue
-            for i in range(K):
-                token = int(tokens_host[i, slot])
-                if token < 0:  # device stop mask: EOS'd at i-1, free-ran
-                    wasted += K - i
-                    break
-                self._deliver(handle, token)
-                if handle.finished:  # EOS (host view) / length / cancel
-                    wasted += K - i - 1
-                    break
-        if wasted:
-            self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
+        tokens_host = await self._fetch(lambda: np.asarray(blk.block_tokens))
+        with TRACER.phase("deliver", self._phases):
+            K = tokens_host.shape[0]
+            wasted = 0
+            for slot, handle, epoch in blk.block_members:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    wasted += K  # evicted/cancelled/preempted since dispatch
+                    continue
+                for i in range(K):
+                    token = int(tokens_host[i, slot])
+                    if token < 0:  # device stop mask: EOS'd at i-1, free-ran
+                        wasted += K - i
+                        break
+                    self._deliver(handle, token)
+                    if handle.finished:  # EOS (host view) / length / cancel
+                        wasted += K - i - 1
+                        break
+            if wasted:
+                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
         if blk.step is not None:
             await self._consume_step(blk.step)
         self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
@@ -3624,75 +3702,78 @@ class ContinuousBatchingScheduler:
             slot for slot, h, _e in members if h.constraint is not None
         )
         need_logits = bool(constrained_slots)
-        result = eng.decode_spec(
-            jnp.asarray(active), jnp.asarray(drafts), jnp.asarray(n_drafts),
-            jnp.asarray(self._temperature),
-            jnp.asarray(self._top_p),
-            jnp.asarray(self._top_k),
-            return_logits=need_logits,
-        )
-        self._tally_dispatch()
-        if TRACER.enabled:
-            self._trace_dispatch(
-                "spec",
-                [[slot, h.trace_id or h.seq_id, "spec"]
-                 for slot, h, _e in members],
+        with TRACER.phase("dispatch", self._phases):
+            result = eng.decode_spec(
+                jnp.asarray(active), jnp.asarray(drafts), jnp.asarray(n_drafts),
+                jnp.asarray(self._temperature),
+                jnp.asarray(self._top_p),
+                jnp.asarray(self._top_k),
+                return_logits=need_logits,
             )
+        self._tally_dispatch("spec")
+        if TRACER.enabled:
+            # a verify row reads its context and its own drafts
+            self._trace_dispatch("spec", [
+                (slot, h.trace_id or h.seq_id, "spec",
+                 h.kv_ctx - h.kv_gap + int(n_drafts[slot]))
+                for slot, h, _e in members
+            ])
         emitted, n_emitted, logits = result if need_logits else (*result, None)
         if logits is not None:
             logits = logits[jnp.asarray(constrained_slots, jnp.int32)]
 
-        emitted_host, n_emitted_host, logits_host = await asyncio.to_thread(
+        emitted_host, n_emitted_host, logits_host = await self._fetch(
             lambda: (
                 np.asarray(emitted),
                 np.asarray(n_emitted),
                 np.asarray(logits) if logits is not None else None,
             )
         )
-        accepted_total = 0
-        for slot, handle, epoch in members:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                continue  # evicted/cancelled/preempted since dispatch
-            if handle.constraint is not None and logits_host is not None:
-                token = self._constrained_pick(
-                    handle, logits_host[constrained_slots.index(slot)]
-                )
-                self._deliver(handle, token)
-                continue
-            n = int(n_emitted_host[slot])
-            handle.kv_ctx += max(0, n - 1)  # accepted drafts' context advance
-            accepted_total += max(0, n - 1)
-            for token in emitted_host[slot, :n]:
-                self._deliver(handle, int(token))
-                if handle.finished:  # EOS / length inside the prefix
-                    break
-        if accepted_total:
-            self.metrics.inc("finchat_spec_tokens_accepted_total", accepted_total)
-        self._spec_note_step(accepted=accepted_total)
-        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
+        with TRACER.phase("deliver", self._phases):
+            accepted_total = 0
+            for slot, handle, epoch in members:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    continue  # evicted/cancelled/preempted since dispatch
+                if handle.constraint is not None and logits_host is not None:
+                    token = self._constrained_pick(
+                        handle, logits_host[constrained_slots.index(slot)]
+                    )
+                    self._deliver(handle, token)
+                    continue
+                n = int(n_emitted_host[slot])
+                handle.kv_ctx += max(0, n - 1)  # accepted drafts' context advance
+                accepted_total += max(0, n - 1)
+                for token in emitted_host[slot, :n]:
+                    self._deliver(handle, int(token))
+                    if handle.finished:  # EOS / length inside the prefix
+                        break
+            if accepted_total:
+                self.metrics.inc("finchat_spec_tokens_accepted_total", accepted_total)
+            self._spec_note_step(accepted=accepted_total)
+            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     async def _consume_step(self, step: _InFlightStep) -> None:
         """Fetch a dispatched step's tokens (in a worker thread, so the event
         loop keeps serving) and deliver them to the sequences that were in
         the batch when it was dispatched."""
-        tokens_host, logits_host = await asyncio.to_thread(
+        tokens_host, logits_host = await self._fetch(
             lambda: (
                 np.asarray(step.tokens),
                 np.asarray(step.logits) if step.logits is not None else None,
             )
         )
-        eng = self.engine
-        for slot, handle, epoch in step.members:
-            if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                continue  # evicted/cancelled/preempted since dispatch
-            if handle.constraint is not None and logits_host is not None:
-                token = self._constrained_pick(
-                    handle, logits_host[step.constrained_slots.index(slot)]
-                )
-                self._deliver(handle, token)
-            else:
-                self._deliver(handle, int(tokens_host[slot]))
-        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
+        with TRACER.phase("deliver", self._phases):
+            for slot, handle, epoch in step.members:
+                if handle.finished or handle.slot != slot or handle.epoch != epoch:
+                    continue  # evicted/cancelled/preempted since dispatch
+                if handle.constraint is not None and logits_host is not None:
+                    token = self._constrained_pick(
+                        handle, logits_host[step.constrained_slots.index(slot)]
+                    )
+                    self._deliver(handle, token)
+                else:
+                    self._deliver(handle, int(tokens_host[slot]))
+            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _pending_constrained(self, inflight) -> set[int]:
         """Constrained slots whose host-side pick lands only when
@@ -3734,10 +3815,75 @@ class ContinuousBatchingScheduler:
             await self._round_failed(scope, str(e))
         return None
 
+    def _close_round(self, *, reopen: bool = True) -> None:  # finchat-lint: hot
+        """Book the loop iteration that just ended and start the next one's
+        clock (ISSUE 24). An iteration that dispatched or consumed is a
+        round: one ``round`` event whose args are the seconds of each phase
+        (ROUND_PHASES), the kind of its last dispatch and the dispatch
+        tally; the phase counters; and one rate-limited WARNING when it
+        took longer than both SLOW_ROUND_FLOOR_S and SLOW_ROUND_MEDIANS
+        times the median of the last 256 rounds — what a stalled run
+        leaves on standard error. Host clocks only (finchat-lint R2).
+
+        Whatever the loop does outside a named phase is ``stage``: the
+        iteration runs inside one base ``stage`` phase that the others
+        carve their time out of, so a round's phases sum to its length.
+        The booking itself runs inside the next iteration's base phase."""
+        base = self._base_phase
+        if base is not None:
+            base.__exit__(None, None, None)
+        phases = self._phases.seconds
+        dispatched = self._dispatch_tally != self._round_mark
+        kind = self._round_kind
+        self._phases.reset()
+        self._round_mark = self._dispatch_tally
+        self._round_kind = "drain"
+        self._base_phase = None
+        if reopen:
+            self._base_phase = TRACER.phase("stage", self._phases)
+            self._base_phase.__enter__()
+        if base is None or not (dispatched or phases["fetch_wait"] > 0.0):
+            return  # an idle turn of the loop is no round
+        # the round is its base phase, end to end: nothing in it is unclocked
+        started, now = base.started, base.ended
+        total = now - started
+        if TRACER.enabled:
+            TRACER.event("round", ts=started, dur=total, track=self._trace_track,
+                         args={**phases, "kind": kind, "n": self._dispatch_tally})
+        self.metrics.inc("finchat_rounds_total")
+        for phase, seconds in phases.items():
+            if seconds:
+                self.metrics.inc("finchat_round_phase_seconds_total", seconds,
+                                 labels={"phase": phase})
+        if total > SLOW_ROUND_FLOOR_S:  # the only rounds that can be slow
+            median = (statistics.median(self._recent_rounds)
+                      if self._recent_rounds else 0.0)
+            if (total > SLOW_ROUND_MEDIANS * median
+                    and now - self._slow_round_logged > SLOW_ROUND_LOG_INTERVAL_S):
+                self._slow_round_logged = now
+                logger.warning(
+                    "slow scheduler round: %.3f s (median of the last %d: "
+                    "%.4f s), %s; last dispatch %s, %d decoding rows",
+                    total, len(self._recent_rounds), median,
+                    " ".join(f"{k}={v:.3f}" for k, v in phases.items()),
+                    kind, len(self.decoding),
+                )
+        self._recent_rounds.append(total)
+
+    async def _yield(self) -> None:
+        """Let every other task of the process run (producers, consumers,
+        the agent, the load) — the round's ``yield`` phase."""
+        with TRACER.phase("yield", self._phases):
+            await asyncio.sleep(0)
+
     async def _loop(self) -> None:
         logger.info("scheduler loop started (max_seqs=%d)", self.engine.engine_cfg.max_seqs)
         inflight: _InFlightStep | _InFlightBlock | None = None
+        phases = self._phases
+        phases.reset()  # a loop that died mid-round left its phases open
+        self._base_phase = None
         while self._running:
+            self._close_round()
             self._reap_stale_holds()
             # attribute the previous coexist iteration's dispatches at the
             # top of EVERY iteration (idle ones included), so the last
@@ -3761,46 +3907,50 @@ class ContinuousBatchingScheduler:
                     inflight = await self._drain_inflight(inflight)
                     continue
                 self._wakeup.clear()
-                try:
-                    await asyncio.wait_for(self._wakeup.wait(), timeout=0.5)
-                except asyncio.TimeoutError:
-                    pass
+                with TRACER.phase("yield", phases):
+                    try:
+                        await asyncio.wait_for(self._wakeup.wait(), timeout=0.5)
+                    except asyncio.TimeoutError:
+                        pass
                 continue
 
-            try:
-                # page-pressure preemption (ISSUE 5): planned BEFORE any
-                # dispatch and executed only after the in-flight step is
-                # drained, so a freed page can never still be the target of
-                # queued device writes
-                victims = self._preemption_plan()
-                if victims:
-                    if inflight is not None:
-                        inflight = await self._drain_inflight(inflight)
-                        # consuming may have retired slots / freed pages
-                        # (or, on a drain failure, preempted the victims
-                        # already) — recompute the plan either way
-                        victims = self._preemption_plan()
-                    cand = self.pending[0].seq_id if self.pending else "?"
-                    for victim in victims:
-                        logger.info(
-                            "page pressure: preempting %s (deadline %.3f) for %s",
-                            victim.seq_id, victim.deadline or float("inf"), cand,
-                        )
-                        self._preempt(victim)
-                self._admit()
-                # bounded-KV eviction wave (ISSUE 15): runs BETWEEN
-                # dispatches — the page-table/gap updates enqueue after
-                # every in-flight program and before this iteration's
-                # dispatch, so device stream order keeps each program
-                # reading the table it was staged against; the freed
-                # pages' next writers are ordered after it too
-                self._bounded_evict_wave()
-            except Exception as e:
-                # admission must never kill the loop (e.g. device state
-                # mid-rebuild-failure): log, back off, keep serving what
-                # still runs
-                logger.error("admission error: %s", e)
-                await asyncio.sleep(0.05)
+            # a drain inside the block books its own phases
+            with TRACER.phase("admit", phases):
+                try:
+                    # page-pressure preemption (ISSUE 5): planned BEFORE any
+                    # dispatch and executed only after the in-flight step is
+                    # drained, so a freed page can never still be the target of
+                    # queued device writes
+                    victims = self._preemption_plan()
+                    if victims:
+                        if inflight is not None:
+                            inflight = await self._drain_inflight(inflight)
+                            # consuming may have retired slots / freed pages
+                            # (or, on a drain failure, preempted the victims
+                            # already) — recompute the plan either way
+                            victims = self._preemption_plan()
+                        cand = self.pending[0].seq_id if self.pending else "?"
+                        for victim in victims:
+                            logger.info(
+                                "page pressure: preempting %s (deadline %.3f) for %s",
+                                victim.seq_id, victim.deadline or float("inf"), cand,
+                            )
+                            self._preempt(victim)
+                    self._admit()
+                    # bounded-KV eviction wave (ISSUE 15): runs BETWEEN
+                    # dispatches — the page-table/gap updates enqueue after
+                    # every in-flight program and before this iteration's
+                    # dispatch, so device stream order keeps each program
+                    # reading the table it was staged against; the freed
+                    # pages' next writers are ordered after it too
+                    self._bounded_evict_wave()
+                except Exception as e:
+                    # admission must never kill the loop (e.g. device state
+                    # mid-rebuild-failure): log, back off, keep serving what
+                    # still runs
+                    logger.error("admission error: %s", e)
+                    with TRACER.phase("yield", phases):  # a sleep is no work
+                        await asyncio.sleep(0.05)
 
             prefill_active = bool(self._prefix_jobs) or self._prefill_work()
             # label for the inter-token histogram, and the denominator for
@@ -3843,13 +3993,13 @@ class ContinuousBatchingScheduler:
                         if inflight is not None:
                             inflight = await self._drain_inflight(inflight)
                         await self._round_failed("mixed", str(e))
-                        await asyncio.sleep(0)
+                        await self._yield()
                         continue
                     if ring is not None:
                         prev, inflight = inflight, ring
                         if prev is not None:
                             await self._drain_inflight(prev)
-                        await asyncio.sleep(0)  # let producers/consumers run
+                        await self._yield()
                         continue
                     # staging underfilled: fall through to the host-stepped
                     # single round below
@@ -3870,7 +4020,7 @@ class ContinuousBatchingScheduler:
                         # under the breaker, legacy eviction without it)
                         logger.error("mixed step error: %s", e)
                         await self._round_failed("mixed", str(e))
-                    await asyncio.sleep(0)  # let producers/consumers run
+                    await self._yield()
                     continue
 
             if isinstance(inflight, _InFlightRing):
@@ -3904,7 +4054,8 @@ class ContinuousBatchingScheduler:
                     # kv_ctx — without it, a completion landing exactly on
                     # a full page list would trash-write its first decode
                     # KV. Idempotent; no-op when no boundary was crossed.
-                    self._bounded_evict_wave()
+                    with TRACER.phase("admit", phases):
+                        self._bounded_evict_wave()
                 except Exception as e:
                     logger.error("bounded eviction wave error: %s", e)
 
@@ -3993,5 +4144,6 @@ class ContinuousBatchingScheduler:
             elif inflight is not None:
                 inflight = await self._drain_inflight(inflight)
 
-            await asyncio.sleep(0)  # let producers/consumers run
+            await self._yield()
+        self._close_round(reopen=False)
         logger.info("scheduler loop stopped")

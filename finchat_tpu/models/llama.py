@@ -178,6 +178,7 @@ def init_params(
     return params
 
 
+@jax.named_scope("norm")
 def rms_norm(x: Array, weight: Array, eps: float) -> Array:
     x32 = x.astype(jnp.float32)
     rms = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
@@ -212,17 +213,18 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
     """
     c = config
     E = c.n_experts
-    # router in fp32 (routing decisions are precision-sensitive; the router
-    # leaf itself is kept fp32 by init_params / the checkpoint loader)
-    r = jnp.einsum("bsd,de->bse", h, layer_params["router"],
-                   preferred_element_type=jnp.float32)  # [B,S,E]
-    # exactly-k selection from top_k INDICES (threshold comparison would
-    # over-select on tied logits); softmax over the selected logits only
-    # (Mixtral renormalization), scattered back to expert positions
-    top_vals, top_idx = jax.lax.top_k(r, c.top_k_experts)  # [B,S,k]
-    w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
-    onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]
-    gates = jnp.einsum("bske,bsk->bse", onehot, w).astype(h.dtype)  # [B,S,E]
+    with jax.named_scope("moe_router"):
+        # router in fp32 (routing decisions are precision-sensitive; the router
+        # leaf itself is kept fp32 by init_params / the checkpoint loader)
+        r = jnp.einsum("bsd,de->bse", h, layer_params["router"],
+                       preferred_element_type=jnp.float32)  # [B,S,E]
+        # exactly-k selection from top_k INDICES (threshold comparison would
+        # over-select on tied logits); softmax over the selected logits only
+        # (Mixtral renormalization), scattered back to expert positions
+        top_vals, top_idx = jax.lax.top_k(r, c.top_k_experts)  # [B,S,k]
+        w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
+        onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]
+        gates = jnp.einsum("bske,bsk->bse", onehot, w).astype(h.dtype)  # [B,S,E]
 
     def expert_mm(spec: str, x: Array, w: Array | QTensor | Q4Tensor) -> Array:
         # int8/int4 serving: the stacked-expert einsums keep INLINE dequant
@@ -237,11 +239,12 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
             w = dequantize(w, x.dtype)
         return jnp.einsum(spec, x, w)
 
-    gate = expert_mm("bsd,edf->bsef", h, layer_params["moe_gate"])
-    up = expert_mm("bsd,edf->bsef", h, layer_params["moe_up"])
-    act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
-    act = act * gates[..., None]  # zero non-routed experts pre-projection
-    return expert_mm("bsef,efd->bsd", act, layer_params["moe_down"])
+    with jax.named_scope("moe_experts"):
+        gate = expert_mm("bsd,edf->bsef", h, layer_params["moe_gate"])
+        up = expert_mm("bsd,edf->bsef", h, layer_params["moe_up"])
+        act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
+        act = act * gates[..., None]  # zero non-routed experts pre-projection
+        return expert_mm("bsef,efd->bsd", act, layer_params["moe_down"])
 
 
 def _layer(
@@ -274,43 +277,49 @@ def _layer(
     hkv = c.n_kv_heads // tp_size
 
     h = rms_norm(x, layer_params["ln_attn"], c.norm_eps)
-    q = dense(h, layer_params["attn_q"], qm_backend=qm_backend).reshape(B, S, hq, c.head_dim)
-    k = dense(h, layer_params["attn_k"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
-    v = dense(h, layer_params["attn_v"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
-    q = rope(q, positions, c.rope_theta)
-    k = rope(k, positions, c.rope_theta)
+    with jax.named_scope("attn_qkv"):
+        q = dense(h, layer_params["attn_q"], qm_backend=qm_backend).reshape(B, S, hq, c.head_dim)
+        k = dense(h, layer_params["attn_k"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
+        v = dense(h, layer_params["attn_v"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
+        q = rope(q, positions, c.rope_theta)
+        k = rope(k, positions, c.rope_theta)
 
+    # the attention callback opens its own scopes (engine/engine.py)
     attn_out, new_layer_cache = attention(q, k, v, layer_cache, layer_idx)
-    if tp_axis is not None:
-        from finchat_tpu.ops.tp_overlap import row_parallel_dense
+    with jax.named_scope("attn_o"):
+        if tp_axis is not None:
+            from finchat_tpu.ops.tp_overlap import row_parallel_dense
 
-        attn_proj = row_parallel_dense(
-            attn_out.reshape(B, S, -1), layer_params["attn_o"], tp_axis,
-            overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
-        )
-    else:
-        attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
-                          qm_backend=qm_backend)
-    x = x + attn_proj
+            attn_proj = row_parallel_dense(
+                attn_out.reshape(B, S, -1), layer_params["attn_o"], tp_axis,
+                overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
+            )
+        else:
+            attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
+                              qm_backend=qm_backend)
+        x = x + attn_proj
 
     h = rms_norm(x, layer_params["ln_mlp"], c.norm_eps)
     if c.n_experts:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
-        x = x + moe_mlp(h, layer_params, c, qm_backend=qm_backend)
+        moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend)
+        with jax.named_scope("moe_experts"):
+            x = x + moe_out  # the residual add fuses into the down matmul
     else:
-        gate = dense(h, layer_params["mlp_gate"], qm_backend=qm_backend)
-        up = dense(h, layer_params["mlp_up"], qm_backend=qm_backend)
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
-        if tp_axis is not None:
-            from finchat_tpu.ops.tp_overlap import row_parallel_dense
+        with jax.named_scope("mlp"):
+            gate = dense(h, layer_params["mlp_gate"], qm_backend=qm_backend)
+            up = dense(h, layer_params["mlp_up"], qm_backend=qm_backend)
+            act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
+            if tp_axis is not None:
+                from finchat_tpu.ops.tp_overlap import row_parallel_dense
 
-            down = row_parallel_dense(
-                act, layer_params["mlp_down"], tp_axis,
-                overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
-            )
-        else:
-            down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
-        x = x + down
+                down = row_parallel_dense(
+                    act, layer_params["mlp_down"], tp_axis,
+                    overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
+                )
+            else:
+                down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
+            x = x + down
     return x, new_layer_cache
 
 
@@ -341,7 +350,8 @@ def forward(
     aliased end to end.
     """
     c = config
-    x = params["embed"][tokens]  # [B,S,D]
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]  # [B,S,D]
 
     def scan_body(carry, scanned):
         x, cache = carry
@@ -368,6 +378,7 @@ def forward(
     return logits, new_cache
 
 
+@jax.named_scope("head")
 def lm_head(params: dict[str, Any], x: Array, *, config: LlamaConfig,
             qm_backend: str | None = None) -> Array:
     """Project hidden states [..., D] to fp32 logits [..., vocab]. A

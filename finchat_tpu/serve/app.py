@@ -360,11 +360,12 @@ def make_engine_replica(
     prompt heads restore from / publish to the cluster-wide store."""
     config, params, tokenizer, mesh = artifacts
     metrics = METRICS.labeled(replica=replica_id) if replica_id is not None else None
-    engine = InferenceEngine(config, params, cfg.engine, mesh=mesh,
-                             quant=cfg.model.quant,
-                             quant_group=cfg.model.quant_group)
+    with TRACER.startup_phase("engine_init"):
+        engine = InferenceEngine(config, params, cfg.engine, mesh=mesh,
+                                 quant=cfg.model.quant,
+                                 quant_group=cfg.model.quant_group)
     if cfg.engine.warmup_on_start:
-        engine.warmup()
+        TRACER.startup("warmup", engine.warmup())
     scheduler = ContinuousBatchingScheduler(
         engine, eos_id=tokenizer.eos_id, metrics=metrics,
         replica_id=replica_id, fabric=fabric,
@@ -402,7 +403,8 @@ def build_generators(cfg: AppConfig, fabric=None) -> tuple[TextGenerator, TextGe
     if cfg.model.preset == "stub":
         stub = StubGenerator(default="I'm Penny, here to help with your finances.")
         return stub, stub, None, get_tokenizer()
-    artifacts = _load_model_artifacts(cfg)
+    with TRACER.startup_phase("artifacts"):
+        artifacts = _load_model_artifacts(cfg)
     generator, scheduler = make_engine_replica(cfg, artifacts, fabric=fabric)
     return generator, generator, scheduler, artifacts[2]
 
@@ -1331,7 +1333,8 @@ def build_app(cfg: AppConfig | None = None, *, store: ConversationStore | None =
             from finchat_tpu.serve.disagg import parse_roles
 
             roles = parse_roles(cfg.fleet.roles, cfg.fleet.replicas)
-            artifacts = _load_model_artifacts(cfg)
+            with TRACER.startup_phase("artifacts"):
+                artifacts = _load_model_artifacts(cfg)
             tokenizer = artifacts[2]
             fleet_replicas = []
             for i in range(cfg.fleet.replicas):
@@ -1351,6 +1354,7 @@ def build_app(cfg: AppConfig | None = None, *, store: ConversationStore | None =
             response_generator = response_generator or resp_gen
 
     if retriever is None:
+        embed_started = time.perf_counter()
         from finchat_tpu.embed.batcher import EmbedMicrobatcher
         from finchat_tpu.embed.encoder import EMBED_PRESETS, EmbeddingEncoder, init_bert_params
         from finchat_tpu.embed.index import DeviceVectorIndex
@@ -1415,6 +1419,7 @@ def build_app(cfg: AppConfig | None = None, *, store: ConversationStore | None =
                 encoder, index, default_limit=cfg.vector.default_limit,
                 batcher=batcher,
             )
+        TRACER.startup("embed", time.perf_counter() - embed_started)
 
     system_prompt, tool_prompt = load_prompts()
 
@@ -1451,11 +1456,12 @@ def build_app(cfg: AppConfig | None = None, *, store: ConversationStore | None =
     app = App(cfg, agent=agent, store=store, kafka=kafka, scheduler=scheduler,
               retriever=app_retriever, fleet=fleet)
     if app._prefix_cache_enabled and tokenizer is not None:
-        if fleet is not None:
-            for rep in fleet.replicas:
-                rep.registered_heads = register_prompt_prefixes(
-                    rep.agent, rep.scheduler, tokenizer
-                )
-        else:
-            app._registered_heads = register_prompt_prefixes(agent, scheduler, tokenizer)
+        with TRACER.startup_phase("heads"):
+            if fleet is not None:
+                for rep in fleet.replicas:
+                    rep.registered_heads = register_prompt_prefixes(
+                        rep.agent, rep.scheduler, tokenizer
+                    )
+            else:
+                app._registered_heads = register_prompt_prefixes(agent, scheduler, tokenizer)
     return app

@@ -550,8 +550,8 @@ class TracingConfig:
     # record structured trace events (span marks, dispatch rows, fleet
     # moves) into the bounded per-process ring and serve
     # GET /debug/trace/<trace_id>; events stamp from host data only, so
-    # the decode hot path pays < 2% with this on (bench --trace-overhead
-    # gates it). Also FINCHAT_TRACING.
+    # the decode hot path adds no device sync with this on (what it costs
+    # on the chip: OBSERVABILITY.md). Also FINCHAT_TRACING.
     enabled: bool = True
     # ring capacity in events — bounds tracing memory (~100 bytes/event);
     # the flight recorder dumps exactly this window on anomaly. Also

@@ -13,7 +13,9 @@ import os
 from pathlib import Path
 
 import jax
+from jax._src import cache_key
 
+from finchat_tpu.utils import tracing
 from finchat_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
@@ -23,6 +25,10 @@ logger = get_logger(__name__)
 _DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
+def _scope_registry_key() -> str:
+    return "finchat-scopes:" + ",".join(sorted(tracing.DEVICE_SCOPES))
+
+
 def enable_compile_cache() -> str:
     """Turn on JAX's persistent compilation cache and return its directory.
 
@@ -30,11 +36,21 @@ def enable_compile_cache() -> str:
     cache (JAX reads the variable itself) and no directory is set in code;
     otherwise the cache lives at ``<checkout>/.jax_cache``. The size and
     compile-time floors drop to zero either way: warm-up's variants are
-    exactly what a restarted process should find again, however small."""
+    exactly what a restarted process should find again, however small.
+
+    The key also covers the names of ``tracing.DEVICE_SCOPES``. JAX keeps
+    an op's metadata out of the key, so a restart after an edit that moved
+    source lines stays warm; but then a program cached before a scope
+    existed comes back without it, and the device-time-by-scope reduction
+    reads scope paths from the profile. ``cache_key.custom_hook`` is JAX's
+    seam for such an addition (tests/test_runtime.py holds it to that). A
+    scope that moves while the registry and the program stay as they were
+    keeps its old path until the entry is evicted."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", str(_DEFAULT_CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    cache_key.custom_hook = _scope_registry_key
     cache_dir = jax.config.jax_compilation_cache_dir
     logger.info("persistent compilation cache: %s", cache_dir)
     return cache_dir

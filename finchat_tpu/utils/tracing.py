@@ -27,12 +27,18 @@ The reference has none (SURVEY §5.1). Three planes here:
    anomaly storm cannot grind serving; ``flush_dumps`` joins the writers
    (the graceful drain calls it through ``asyncio.to_thread``).
 
-Plus the original device plane: ``jax.profiler`` capture and
-``jax.named_scope`` annotations, unchanged.
+Host and device share one clock only inside a ``jax.profiler`` capture
+(ISSUE 24): each phase of a scheduler round (:data:`ROUND_PHASES`) is a
+``jax.profiler.TraceAnnotation`` named ``finchat.<phase>`` — an event of
+the capture's host plane, on the clock of its device lines — and every
+part of a device step runs under a ``jax.named_scope`` from
+:data:`DEVICE_SCOPES`, which the capture shows as the operation's scope
+path. The same phases, summed per round on ``perf_counter``, go to the ring
+as one ``round`` event and to ``finchat_round_phase_seconds_total``.
 
-Every ``mark()``/event name MUST come from the registries below —
+Every ``mark()``/event/scope name MUST come from the registries below —
 finchat-lint R5's span-discipline check enforces it statically, because a
-typo'd mark name otherwise just silently vanishes from every timeline.
+typo'd name otherwise just silently vanishes from every timeline.
 """
 
 from __future__ import annotations
@@ -103,7 +109,48 @@ TRACE_EVENTS = frozenset({
     # pod plane (ISSUE 20): a conversation's session bytes were pulled
     # from a liaison peer and imported warm — args carry peer and bytes
     "pod_session_pull",
+    # one scheduler iteration that dispatched or consumed (ISSUE 24):
+    # args carry the seconds spent in each of ROUND_PHASES, the last
+    # dispatch's kind and the dispatch tally
+    "round",
+    # one phase of process start-up (STARTUP_PHASES), so an exported ring
+    # or a flight dump begins at process start
+    "startup",
 })
+
+#: The parts of one scheduler iteration, in the order the loop runs them.
+#: ``admit`` preemption plan, admission, eviction wave; ``stage`` building
+#: a dispatch's host arrays and row lists, and whatever else the loop does
+#: outside the other phases; ``dispatch`` the call into the engine's jitted
+#: step until it returns; ``fetch_wait`` awaiting the worker thread that
+#: fetches tokens; ``deliver`` handing tokens to the streams; ``yield`` the
+#: loop given to every other task of the process.
+ROUND_PHASES = ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
+
+#: ``jax.named_scope`` names inside the jitted steps (finchat-lint R5
+#: rejects a literal that is not here). A device operation's scope path in
+#: a profile ends in the operation; the first of these names on the path
+#: is the part of the step the operation belongs to.
+DEVICE_SCOPES = frozenset({
+    "embed", "norm", "attn_qkv", "attn_o", "mlp", "moe_router",
+    "moe_experts", "head", "sample",
+    # the attention callbacks (engine/engine.py)
+    "kv_append", "kv_scatter", "paged_attention",
+    "kv_scatter_ragged", "ragged_paged_attention",
+})
+
+#: Why a request's span ended (``RequestSpan.finish(reason=...)``).
+FINISH_REASONS = frozenset({
+    "eos", "length",    # the answer ended: sampled EOS / max_new_tokens
+    "cancelled",        # the client went away
+    "shed",             # deadline passed before admission
+    "error",            # a fault failed the stream
+    "drained",          # graceful shutdown ended it
+    "replica_out",      # the replica gave up and no sibling took it
+})
+
+#: Phases of process start-up (``finchat_startup_seconds{phase}``).
+STARTUP_PHASES = ("artifacts", "engine_init", "warmup", "embed", "heads")
 
 #: Anomaly kinds — each records an event AND triggers a flight dump.
 ANOMALY_KINDS = frozenset({
@@ -138,6 +185,12 @@ DISPATCH_ROW_MODES = frozenset({
 QUANT_MODES = frozenset({
     "bf16", "int8", "int4", "bf16+kv8", "int8+kv8", "int4+kv8",
 })
+
+# a round is slow when it takes longer than both of these; at most one
+# WARNING per interval, so a wedged host cannot flood the log
+SLOW_ROUND_FLOOR_S = 0.25
+SLOW_ROUND_MEDIANS = 10.0
+SLOW_ROUND_LOG_INTERVAL_S = 5.0
 
 _FLIGHT_MAGIC = "FINCHAT-FLIGHT v1"
 # per-kind dump rate limit: an anomaly storm (e.g. a shed wave) records
@@ -181,6 +234,65 @@ def _event_carries(ev: tuple, trace_id: str) -> bool:
         if rows:
             return any(r[1] == trace_id for r in rows)
     return False
+
+
+class RoundPhases:
+    """The accumulator of one scheduler loop: seconds spent in each of
+    ROUND_PHASES since ``reset()``, and the phase that is open now. Phases
+    nest as a stack through ``open``, so one task alone opens them: the
+    scheduler's loop task, from which every consume path is awaited."""
+
+    __slots__ = ("seconds", "open")
+
+    def __init__(self) -> None:
+        self.reset()
+
+    def reset(self) -> None:
+        self.seconds = dict.fromkeys(ROUND_PHASES, 0.0)
+        self.open: _Phase | None = None
+
+    def note(self, **numbers) -> None:
+        """Attach numbers to the phase that is open now: a capture shows
+        them as stats of that ``finchat.<phase>`` event, so what a dispatch
+        carried is read on the clock of the kernels that computed it."""
+        if self.open is not None:
+            self.open._annotation.set_metadata(**numbers)
+
+
+class _Phase:
+    """One phase of a scheduler round: a ``finchat.<phase>`` annotation on
+    the profiler's clock (near-free while no capture runs) around a
+    ``perf_counter`` duration added to the round's accumulator. A phase
+    opened inside another takes its time out of the outer one, so a
+    round's phases never count a second twice. A plain class, not a
+    generator: phases open a dozen times a round. ``started`` / ``ended``
+    are its own ends on ``perf_counter``."""
+
+    __slots__ = ("_name", "_acc", "_outer", "_annotation", "_t0",
+                 "started", "ended")
+
+    def __init__(self, name: str, acc: RoundPhases):
+        self._name = name
+        self._acc = acc
+
+    def __enter__(self) -> None:
+        acc = self._acc
+        self._outer = outer = acc.open
+        acc.open = self
+        # the annotation starts when it is constructed
+        self._annotation = jax.profiler.TraceAnnotation("finchat." + self._name)
+        self.started = self._t0 = now = time.perf_counter()
+        if outer is not None:
+            acc.seconds[outer._name] += now - outer._t0
+
+    def __exit__(self, *exc) -> None:
+        self.ended = now = time.perf_counter()
+        acc, outer = self._acc, self._outer
+        acc.seconds[self._name] += now - self._t0
+        if outer is not None:
+            outer._t0 = now
+        acc.open = outer
+        self._annotation.__exit__(*exc)
 
 
 class Tracer:
@@ -247,6 +359,29 @@ class Tracer:
         finally:
             self.event(name, trace_id, ts=t0,
                        dur=time.perf_counter() - t0, track=track, args=args)
+
+    @staticmethod
+    def phase(name: str, acc: RoundPhases) -> _Phase:
+        """``with TRACER.phase("stage", acc):`` — one of ROUND_PHASES,
+        annotated on the profiler's clock and added to ``acc.seconds``.
+        Always on: there is no switch between a round and its clock."""
+        return _Phase(name, acc)
+
+    def startup(self, phase: str, seconds: float) -> None:
+        """Record one phase of process start-up (STARTUP_PHASES) that just
+        ended: the ``finchat_startup_seconds{phase}`` gauge (summed where a
+        fleet runs the phase once per replica) and a ``startup`` ring event."""
+        labels = {"phase": phase}
+        total = seconds + METRICS.get("finchat_startup_seconds", labels=labels)
+        METRICS.set_gauge("finchat_startup_seconds", total, labels=labels)  # finchat-lint: disable=metrics-discipline -- a duration set once per phase, not a histogram: the unit is seconds and the name says so (ISSUE 24 names this series)
+        self.event("startup", ts=time.perf_counter() - seconds, dur=seconds,
+                   track="startup", args=labels)
+
+    @contextlib.contextmanager
+    def startup_phase(self, phase: str) -> Iterator[None]:
+        t0 = time.perf_counter()
+        yield
+        self.startup(phase, time.perf_counter() - t0)
 
     def anomaly(self, kind: str, trace_id: str | None = None,
                 args: dict | None = None) -> None:
@@ -384,7 +519,13 @@ class RequestSpan:
 
     ``mark()`` names must come from :data:`SPAN_MARKS` (finchat-lint R5).
     With a ``trace_id``, every mark also lands in the process trace ring,
-    and ``finish()`` additionally emits the whole-request "request" span.
+    and ``finish()`` additionally emits the whole-request "request" span,
+    whose args say how the request ended (``reason``, one of
+    :data:`FINISH_REASONS`), how large it was (``prompt_tokens``, of which
+    ``cached_tokens`` were not prefilled because a shared head, a session
+    entry or a partial hold supplied them; ``generated``) and how long it
+    queued (``queue_wait_s``, creation → ``admitted``; None if never
+    admitted). The scheduler sets the token fields at admission.
     ``finish()`` is IDEMPOTENT — it is invoked from many scheduler sites
     (shed, evict, drain, give-up, rebuild-failure) whose flows can
     overlap on the preempt-replay and drain-handoff paths; the first call
@@ -397,6 +538,8 @@ class RequestSpan:
     created_at: float = field(default_factory=time.perf_counter)
     marks: dict[str, float] = field(default_factory=dict)
     finished: bool = False
+    prompt_tokens: int = 0
+    cached_tokens: int = 0
 
     def mark(self, name: str) -> None:
         now = time.perf_counter()
@@ -408,7 +551,8 @@ class RequestSpan:
         """Time to first token, if the request got that far."""
         return self.marks.get("first_token")
 
-    def finish(self, registry: MetricsRegistry = METRICS) -> None:
+    def finish(self, registry: MetricsRegistry = METRICS, *, reason: str,
+               generated: int = 0) -> None:
         if self.finished:
             # second finish (preempt-replay / drain-handoff overlap):
             # first call won — count it, change nothing
@@ -420,30 +564,17 @@ class RequestSpan:
         self.mark("done")
         registry.observe("finchat_request_seconds", self.marks["done"],
                          trace_id=self.trace_id)
+        registry.inc("finchat_requests_finished_total", labels={"reason": reason})
         if self.trace_id is not None and TRACER.enabled:
             TRACER.event("request", self.trace_id, ts=self.created_at,
                          dur=self.marks["done"], track="request",
-                         args={"request_id": self.request_id})
+                         args={"request_id": self.request_id, "reason": reason,
+                               "prompt_tokens": self.prompt_tokens,
+                               "cached_tokens": self.cached_tokens,
+                               "generated": generated,
+                               "queue_wait_s": self.marks.get("admitted")})
         logger.debug(
             "span %s: %s",
             self.request_id,
             " ".join(f"{k}={v * 1e3:.1f}ms" for k, v in sorted(self.marks.items(), key=lambda kv: kv[1])),
         )
-
-
-@contextlib.contextmanager
-def named_scope(name: str) -> Iterator[None]:
-    """jax.named_scope wrapper that is a no-op outside a trace."""
-    with jax.named_scope(name):
-        yield
-
-
-@contextlib.contextmanager
-def device_trace(log_dir: str) -> Iterator[None]:
-    """Capture a jax profiler trace (view in TensorBoard / Perfetto)."""
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-        logger.info("profiler trace written to %s", log_dir)

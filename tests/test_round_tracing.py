@@ -1,0 +1,492 @@
+"""One clock for host and device (ISSUE 24).
+
+Pins what the scheduler, the jitted steps and the request span now put on
+record:
+
+- ROUND: every loop iteration that dispatched or consumed leaves one
+  ``round`` event whose phases come from ``ROUND_PHASES``, are >= 0 and sum
+  to the round's length — in every loop branch — and tracing never changes
+  the tokens.
+- DISPATCH: the ``kv_tokens`` a dispatch notes on its open phase annotation
+  is the sum of the contexts of the rows that rode it.
+- REQUEST: the ``request`` span says how it ended and how large it was.
+- SLOW ROUND: a stalled event loop leaves one WARNING that names the phase.
+- SCOPES: every ``DEVICE_SCOPES`` name is in the compiled steps' ``op_name``
+  metadata, and finchat-lint R5 rejects a scope, phase or reason literal the
+  registries do not declare.
+- START-UP: each phase sets its gauge and leaves a ``startup`` event.
+"""
+
+import asyncio
+import dataclasses
+import logging
+import re
+import textwrap
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from finchat_tpu.analysis.core import run_analysis
+from finchat_tpu.engine.engine import InferenceEngine, decode_step, ragged_mixed_step
+from finchat_tpu.engine.sampler import SamplingParams
+from finchat_tpu.engine.scheduler import ContinuousBatchingScheduler
+from finchat_tpu.models.llama import PRESETS, init_params
+from finchat_tpu.utils import faults
+from finchat_tpu.utils.config import EngineConfig
+from finchat_tpu.utils.metrics import METRICS
+from finchat_tpu.utils.tracing import (
+    DEVICE_SCOPES,
+    FINISH_REASONS,
+    ROUND_PHASES,
+    STARTUP_PHASES,
+    TRACE_EVENTS,
+    TRACER,
+    RoundPhases,
+)
+
+
+@pytest.fixture(autouse=True)
+def _tracer_reset():
+    prev_enabled = TRACER.enabled
+    TRACER.configure(enabled=True, flight_dir="")
+    TRACER.clear()
+    yield
+    TRACER.configure(enabled=prev_enabled)
+    TRACER.clear()
+    faults.disarm_all()
+
+
+def _engine(preset="tiny", **overrides):
+    config = dataclasses.replace(PRESETS[preset], dtype=jnp.float32)
+    defaults = dict(max_seqs=4, page_size=8, num_pages=128, max_seq_len=256,
+                    prefill_chunk=16, session_cache=False)
+    defaults.update(overrides)
+    return InferenceEngine(config, init_params(config, jax.random.key(0)),
+                           EngineConfig(**defaults))
+
+
+def _scheduler(eos_id=-1, **overrides):
+    return ContinuousBatchingScheduler(_engine(**overrides), eos_id=eos_id)
+
+
+async def _drain(handle):
+    tokens = []
+    while True:
+        event = await handle.events.get()
+        if event["type"] == "token":
+            tokens.append(event["token_id"])
+        else:
+            return tokens, event
+
+
+def _ring(name):
+    return [ev for ev in TRACER.snapshot() if ev[2] == name]
+
+
+PROMPT_A = [1, 2, 3, 4, 5, 6, 7, 8] * 5
+PROMPT_B = PROMPT_A[::-1] * 2
+
+# loop branch -> (engine options, the round kind that proves the branch ran,
+# the second request's arrival: "staggered" into the first one's decode,
+# "none" for one request alone)
+BRANCHES = {
+    "split_decode": (dict(mixed_step=False), "decode", "staggered"),
+    "decode_loop": (dict(mixed_step=False, decode_loop_depth=4), "decode_loop", "staggered"),
+    "spec": (dict(mixed_step=False, spec_tokens=2), "spec", "staggered"),
+    "ragged": (dict(), "ragged", "staggered"),
+    "freerun": (dict(freerun_rounds=4), "freerun", "staggered"),
+    "prefill_only": (dict(mixed_step=False), "prefill", "none"),
+}
+
+
+def _run_branch(options, arrival, traced, n_new=None):
+    """Drive the rehearsal scheduler through one loop branch; returns the
+    streams, the riders every dispatch was traced with beside what it noted
+    on its phase annotation, and the final dispatch tally."""
+    TRACER.configure(enabled=traced)
+    TRACER.clear()
+    riders_seen, notes = [], []
+
+    async def go():
+        sched = _scheduler(**options)
+        trace_dispatch = sched._trace_dispatch
+
+        def spy(kind, riders, **kw):
+            riders_seen.append((kind, list(riders)))
+            trace_dispatch(kind, riders, **kw)
+
+        sched._trace_dispatch = spy
+        await sched.start()
+        try:
+            sampling = SamplingParams(
+                temperature=0.0,
+                max_new_tokens=n_new or (1 if arrival == "none" else 24))
+            first = await sched.submit("a", PROMPT_A, sampling, trace_id="a")
+            streams = [asyncio.create_task(_drain(first))]
+            if arrival == "staggered":
+                while first.generated < 3:
+                    await asyncio.sleep(0.001)
+                second = await sched.submit("b", PROMPT_B, sampling, trace_id="b")
+                streams.append(asyncio.create_task(_drain(second)))
+            done = await asyncio.wait_for(asyncio.gather(*streams), timeout=240)
+        finally:
+            await sched.stop()
+        return [tokens for tokens, _end in done], sched._dispatch_tally
+
+    note = RoundPhases.note
+    RoundPhases.note = lambda self, **numbers: (notes.append(numbers),
+                                                note(self, **numbers))[1]
+    try:
+        tokens, tally = asyncio.run(go())
+    finally:
+        RoundPhases.note = note
+    assert len(notes) == len(riders_seen)
+    return tokens, [(*seen, noted) for seen, noted in zip(riders_seen, notes)], tally
+
+
+@pytest.mark.parametrize("branch", sorted(BRANCHES))
+def test_every_loop_branch_leaves_rounds_that_add_up(branch):
+    options, kind, arrival = BRANCHES[branch]
+    tokens_on, riders_seen, tally = _run_branch(options, arrival, traced=True)
+    rounds, dispatches = _ring("round"), _ring("dispatch")
+    assert kind in {ev[5]["kind"] for ev in rounds}, (kind, rounds)
+    # every dispatch is booked in exactly one round: the rounds' tallies
+    # rise to the scheduler's own, and a round dispatched or consumed
+    assert rounds[-1][5]["n"] == tally == len(dispatches)
+    previous = 0
+    for _ts, _tid, _name, dur, _track, args in rounds:
+        phases = {p: args[p] for p in ROUND_PHASES}
+        assert set(args) == set(ROUND_PHASES) | {"kind", "n"}
+        assert all(seconds >= 0.0 for seconds in phases.values()), phases
+        assert sum(phases.values()) == pytest.approx(dur, rel=1e-3), (phases, dur)
+        assert args["n"] > previous or phases["fetch_wait"] > 0.0
+        previous = args["n"]
+    # a dispatch notes the sum of the contexts of the rows that rode it
+    assert len(riders_seen) == len(dispatches)
+    for (kind_seen, riders, noted), ev in zip(riders_seen, dispatches):
+        args = ev[5]
+        assert args["kind"] == kind_seen
+        assert args["rows"] == [[slot, tid, mode] for slot, tid, mode, _kv in riders]
+        assert noted == {"kind": kind_seen,
+                         "kv_tokens": sum(kv for *_row, kv in riders)}
+        assert all(kv >= 1 for *_row, kv in riders), riders
+    # tracing on or off, the streams are the same tokens
+    tokens_off, _riders, _tally = _run_branch(options, arrival, traced=False)
+    assert TRACER.snapshot() == []
+    assert tokens_on == tokens_off
+
+
+def test_decode_dispatch_reads_each_row_context():
+    """One sequence on the split path: the k-th decode dispatch reads a
+    context of prompt + k tokens, and a prefill chunk the context it
+    completes."""
+    tokens, seen, _tally = _run_branch(dict(mixed_step=False), "none", traced=True)
+    assert len(tokens[0]) == 1
+    assert [noted["kv_tokens"] for kind, _r, noted in seen if kind == "prefill"] \
+        == [16, 32, 40]
+    _tokens, seen, _tally = _run_branch(dict(mixed_step=False), "none",
+                                        traced=True, n_new=6)
+    decode = [noted["kv_tokens"] for kind, _r, noted in seen if kind == "decode"]
+    assert decode == [len(PROMPT_A) + k for k in range(1, len(decode) + 1)]
+
+
+# --- the request span -------------------------------------------------------
+
+def _request_args(trace_id):
+    events = [ev for ev in _ring("request") if ev[1] == trace_id]
+    assert len(events) == 1, events
+    return events[0][5]
+
+
+def test_request_span_says_how_it_ended_and_how_large_it_was():
+    finished0 = {r: METRICS.get("finchat_requests_finished_total",
+                                labels={"reason": r}) for r in FINISH_REASONS}
+    prompt0 = METRICS.get("finchat_prompt_tokens_total")
+    cached0 = METRICS.get("finchat_prompt_tokens_cached_total")
+    head = list(range(1, 33))  # four whole pages: a shared head
+
+    async def go():
+        greedy = SamplingParams(temperature=0.0, max_new_tokens=4)
+        sched = _scheduler(mixed_step=False)
+        assert sched.register_prefix(head + [99]) == 32
+        await sched.start()
+        try:
+            # length: the answer runs to max_new_tokens; its head is cached
+            h = await sched.submit("len", head + [40, 41, 42], greedy, trace_id="len")
+            tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+            assert end == {"type": "done", "reason": "length"}
+            # cancelled mid-stream
+            h = await sched.submit("can", PROMPT_A, SamplingParams(
+                temperature=0.0, max_new_tokens=200), trace_id="can")
+            while h.generated < 2:
+                await asyncio.sleep(0.001)
+            sched.cancel(h)
+            # shed: its deadline passed before admission
+            h = await sched.submit("shed", PROMPT_A, greedy, trace_id="shed",
+                                   deadline=time.perf_counter() - 1.0)
+            _tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+            assert end["code"] == "deadline_exceeded"
+            # error: a fault fails its prefill
+            faults.arm("scheduler.prefill", faults.for_seq("err", RuntimeError("boom")))
+            h = await sched.submit("err", PROMPT_A, greedy, trace_id="err")
+            _tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+            assert end["type"] == "error"
+        finally:
+            faults.disarm_all()
+            await sched.stop()
+        # eos: the same prompt on a scheduler whose EOS is its first token
+        sched = _scheduler(eos_id=tokens[0], mixed_step=False)
+        await sched.start()
+        try:
+            h = await sched.submit("eos", head + [40, 41, 42], greedy, trace_id="eos")
+            _tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+            assert end == {"type": "done", "reason": "eos"}
+        finally:
+            await sched.stop()
+
+    asyncio.run(go())
+    length = _request_args("len")
+    assert length["reason"] == "length" and length["generated"] == 4
+    assert length["prompt_tokens"] == 35 and length["cached_tokens"] == 32
+    assert 0.0 <= length["queue_wait_s"] < 60.0
+    cancelled = _request_args("can")
+    assert cancelled["reason"] == "cancelled" and cancelled["generated"] >= 2
+    assert cancelled["prompt_tokens"] == len(PROMPT_A) and cancelled["cached_tokens"] == 0
+    shed = _request_args("shed")
+    assert shed["reason"] == "shed" and shed["queue_wait_s"] is None  # never admitted
+    assert shed["prompt_tokens"] == 0 and shed["generated"] == 0
+    error = _request_args("err")
+    assert error["reason"] == "error" and error["prompt_tokens"] == len(PROMPT_A)
+    eos = _request_args("eos")
+    assert eos["reason"] == "eos" and eos["generated"] == 1
+    assert eos["cached_tokens"] == 0  # no head registered on that scheduler
+    for reason in ("length", "cancelled", "shed", "error", "eos"):
+        assert reason in FINISH_REASONS
+        assert METRICS.get("finchat_requests_finished_total",
+                           labels={"reason": reason}) - finished0[reason] == 1
+    # admissions booked: len (35, 32 cached), can, err, eos (35) — not shed
+    assert METRICS.get("finchat_prompt_tokens_total") - prompt0 == 35 + 40 + 40 + 35
+    assert METRICS.get("finchat_prompt_tokens_cached_total") - cached0 == 32
+
+
+# --- the slow round ----------------------------------------------------------
+
+def test_stalled_event_loop_leaves_one_warning_naming_the_phase(caplog):
+    """A task that blocks the event loop for 0.5 s while the scheduler has
+    yielded: the round shows a long ``yield``, one WARNING names it, and
+    the rate limit keeps a second stall quiet."""
+    rounds0 = METRICS.get("finchat_rounds_total")
+    yield0 = METRICS.get("finchat_round_phase_seconds_total", labels={"phase": "yield"})
+
+    async def go():
+        sched = _scheduler(mixed_step=False)
+        await sched.start()
+        try:
+            h = await sched.submit("a", PROMPT_A, SamplingParams(
+                temperature=0.0, max_new_tokens=60), trace_id="a")
+            stalls = 0
+            while True:
+                event = await h.events.get()
+                if event["type"] != "token":
+                    return
+                if h.generated in (20, 30):  # compiled and warm by then
+                    if stalls == 0:
+                        sched._slow_round_logged = float("-inf")  # forget compiles
+                    stalls += 1
+                    time.sleep(0.5)  # finchat-lint: disable=event-loop-blocking -- the injected stall
+        finally:
+            await sched.stop()
+
+    with caplog.at_level(logging.WARNING, logger="finchat_tpu.engine.scheduler"):
+        asyncio.run(go())
+    slow = [r.getMessage() for r in caplog.records
+            if "slow scheduler round" in r.getMessage() and "yield=0.5" in r.getMessage()]
+    assert len(slow) == 1, [r.getMessage() for r in caplog.records]
+    assert re.search(r"last dispatch decode, 1 decoding rows", slow[0])
+    long_rounds = [ev for ev in _ring("round") if ev[5]["yield"] >= 0.5]
+    assert len(long_rounds) == 2  # both stalls are in the ring
+    assert all(ev[3] >= 0.5 and ev[5]["kind"] == "decode" for ev in long_rounds)
+    assert METRICS.get("finchat_rounds_total") - rounds0 == len(_ring("round"))
+    assert METRICS.get("finchat_round_phase_seconds_total",
+                       labels={"phase": "yield"}) - yield0 >= 1.0
+
+
+def test_admission_backoff_is_yield_not_scheduler_work():
+    """The 50 ms sleep after an admission error is time the loop gave
+    away: it reads as ``yield``, not as ``admit`` (which the host-time and
+    idle metrics count as the scheduler at work)."""
+    async def go():
+        sched = _scheduler(mixed_step=False)
+        admit, failed = sched._admit, []
+
+        def failing_admit():
+            if sched.decoding and not failed:
+                failed.append(True)
+                raise RuntimeError("injected admission fault")
+            admit()
+
+        sched._admit = failing_admit
+        await sched.start()
+        try:
+            h = await sched.submit("a", PROMPT_A, SamplingParams(
+                temperature=0.0, max_new_tokens=8), trace_id="a")
+            await asyncio.wait_for(_drain(h), timeout=120)
+        finally:
+            await sched.stop()
+        assert failed
+
+    TRACER.configure(enabled=True)
+    TRACER.clear()
+    asyncio.run(go())
+    backed_off = [ev[5] for ev in _ring("round") if ev[5]["yield"] >= 0.05]
+    assert len(backed_off) == 1 and backed_off[0]["admit"] < 0.04, backed_off
+
+
+def test_nested_phase_takes_its_time_out_of_the_outer_one():
+    acc = RoundPhases()
+    t0 = time.perf_counter()
+    with TRACER.phase("stage", acc):
+        time.sleep(0.02)
+        with TRACER.phase("dispatch", acc):
+            time.sleep(0.03)
+            acc.note(kind="decode", kv_tokens=7)  # no capture: a no-op
+        time.sleep(0.01)
+    total = time.perf_counter() - t0
+    assert acc.open is None
+    assert acc.seconds["dispatch"] == pytest.approx(0.03, abs=0.008)
+    assert acc.seconds["stage"] == pytest.approx(0.03, abs=0.008)
+    assert sum(acc.seconds.values()) == pytest.approx(total, abs=0.002)
+    acc.reset()
+    assert set(acc.seconds) == set(ROUND_PHASES) and not any(acc.seconds.values())
+
+
+# --- device scopes -----------------------------------------------------------
+
+def _op_name_parts(compiled_text):
+    parts = set()
+    for op_name in re.findall(r'op_name="([^"]+)"', compiled_text):
+        parts.update(op_name.split("/"))
+    return parts
+
+
+@pytest.mark.parametrize("preset, expected", [
+    ("moe-tiny", {"embed", "norm", "attn_qkv", "attn_o", "moe_router",
+                  "moe_experts", "head", "sample"}),
+    ("tiny", {"embed", "norm", "attn_qkv", "attn_o", "mlp", "head", "sample"}),
+])
+def test_compiled_steps_carry_every_scope(preset, expected):
+    eng = _engine(preset)
+    B = eng.engine_cfg.max_seqs
+    static = dict(config=eng.config, page_size=eng.page_size,
+                  attn_backend=eng.attn_backend, qm_backend=eng.qm_backend)
+    f32, i32 = jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32)
+    decode = decode_step.lower(
+        eng.params, eng.state, jnp.ones((B,), bool), f32, f32 + 1, i32,
+        return_logits=False, **static).compile().as_text()
+    # the jnp reference backend writes KV by scatter; the kernels' append
+    # path (kv_append) is the chip's
+    assert expected | {"paged_attention", "kv_scatter"} <= _op_name_parts(decode)
+    T = eng.ragged_token_buckets()[0]
+    ragged = ragged_mixed_step.lower(
+        eng.params, eng.state, jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32),
+        i32, i32, i32, jnp.zeros((B,), bool), jnp.zeros((B,), bool), i32,
+        f32, f32 + 1, i32, jnp.zeros((B,), bool), f32, f32 + 1, i32, jnp.int32(-1),
+        spec_width=0, loop_depth=1, **static).compile().as_text()
+    assert (expected | {"ragged_paged_attention", "kv_scatter_ragged"}
+            <= _op_name_parts(ragged))
+    assert expected <= DEVICE_SCOPES
+
+
+def test_registries_hold_the_new_names():
+    assert {"round", "startup"} <= TRACE_EVENTS
+    assert ROUND_PHASES == ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
+    assert {"moe_router", "moe_experts", "paged_attention"} <= DEVICE_SCOPES
+    assert {"eos", "length", "cancelled", "shed", "error", "drained"} <= FINISH_REASONS
+
+
+# --- finchat-lint R5 ---------------------------------------------------------
+
+_MINI_TRACING = """
+    SPAN_MARKS = frozenset({"admitted"})
+    TRACE_EVENTS = frozenset({"dispatch"})
+    ANOMALY_KINDS = frozenset({"shed"})
+    ROUND_PHASES = ("admit", "stage")
+    DEVICE_SCOPES = frozenset({"moe_experts", "head"})
+    FINISH_REASONS = frozenset({"eos", "error"})
+    STARTUP_PHASES = ("warmup",)
+"""
+
+
+def _lint(tmp_path, files):
+    for rel, src in files.items():
+        path = tmp_path / rel
+        path.parent.mkdir(parents=True, exist_ok=True)
+        path.write_text(textwrap.dedent(src))
+    return [f.message for f in
+            run_analysis(tmp_path, [tmp_path], rule_filter={"metrics-discipline"}).findings]
+
+
+def test_r5_rejects_undeclared_scope_phase_reason_and_startup_literals(tmp_path):
+    src = """
+        import jax
+        from finchat_tpu.utils.tracing import TRACER
+
+        @jax.named_scope("head")                  # declared: fine
+        def head(x):
+            with jax.named_scope("moe_expert"):   # typo: flagged
+                return x
+
+        class Sched:
+            def go(self, handle, acc):
+                with TRACER.phase("stage", acc):  # declared: fine
+                    pass
+                with TRACER.phase("stagger", acc):    # flagged
+                    pass
+                handle.span.finish(reason="eos")      # declared: fine
+                handle.span.finish(reason="gone")     # flagged
+                self._close_span(handle, "error")     # declared: fine
+                self._close_span(handle, "vanished")  # flagged
+                TRACER.startup("warmup", 1.0)         # declared: fine
+                with TRACER.startup_phase("warm_up"): # flagged
+                    pass
+    """
+    messages = _lint(tmp_path, {"finchat_tpu/utils/tracing.py": _MINI_TRACING,
+                                "finchat_tpu/sched.py": src})
+    assert len(messages) == 5, messages
+    for literal, registry in (("moe_expert", "DEVICE_SCOPES"), ("stagger", "ROUND_PHASES"),
+                              ("gone", "FINISH_REASONS"), ("vanished", "FINISH_REASONS"),
+                              ("warm_up", "STARTUP_PHASES")):
+        assert any(f"`{literal}`" in m and registry in m for m in messages), (literal, messages)
+
+
+def test_r5_leaves_a_registry_the_tracing_module_lacks_unchecked(tmp_path):
+    src = """
+        import jax
+
+        def f(x):
+            with jax.named_scope("anything"):
+                return x
+    """
+    old = 'SPAN_MARKS = frozenset({"a"})\nTRACE_EVENTS = frozenset()\nANOMALY_KINDS = frozenset()\n'
+    assert _lint(tmp_path, {"finchat_tpu/utils/tracing.py": old,
+                            "finchat_tpu/m.py": src}) == []
+
+
+# --- start-up ----------------------------------------------------------------
+
+def test_startup_phases_set_their_gauge_and_leave_an_event():
+    labels = {"phase": "warmup"}
+    before = METRICS.get("finchat_startup_seconds", labels=labels)
+    TRACER.startup("warmup", 1.5)
+    with TRACER.startup_phase("heads"):
+        time.sleep(0.01)
+    assert METRICS.get("finchat_startup_seconds", labels=labels) - before == pytest.approx(1.5)
+    assert METRICS.get("finchat_startup_seconds", labels={"phase": "heads"}) >= 0.01
+    events = _ring("startup")
+    assert [ev[5]["phase"] for ev in events] == ["warmup", "heads"]
+    assert events[0][3] == pytest.approx(1.5) and events[1][3] >= 0.01
+    assert {"warmup", "heads"} <= set(STARTUP_PHASES)
+    assert np.isfinite(events[0][0])

@@ -57,6 +57,31 @@ def test_compile_cache_env_wins_and_no_directory_is_set_in_code(tmp_path):
     assert out.stdout.strip() == placed
 
 
+_KEY_PROBE = (
+    "import sys, jax; from finchat_tpu.utils import runtime, tracing; "
+    "tracing.DEVICE_SCOPES = tracing.DEVICE_SCOPES | set(sys.argv[1:]); "
+    "runtime.enable_compile_cache(); "
+    "jax.jit(lambda x: x * 2 + 1)(1.0).block_until_ready()"
+)
+
+
+def test_compile_cache_key_covers_the_scope_registry_and_no_source_line(tmp_path):
+    """A program cached under another set of DEVICE_SCOPES is not found
+    again (its scope paths in a profile would be the old ones); one whose
+    source lines moved is (a restart after a host-only edit stays warm).
+    Fails when a JAX upgrade drops ``cache_key.custom_hook``."""
+    def keys(code):  # one directory: its path is part of every key
+        out = _run(code, {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+        assert out.returncode == 0, out.stderr
+        return {p.name.removesuffix("-atime").removesuffix("-cache")
+                for p in tmp_path.iterdir() if p.name.startswith("jit__lambda")}
+
+    first = keys(_KEY_PROBE)
+    assert len(first) == 1
+    assert keys("\n\n\n" + _KEY_PROBE) == first
+    assert len(keys(_KEY_PROBE.replace("sys.argv[1:]", "['probe_scope']"))) == 2
+
+
 def test_entry_refuses_a_cpu_backend_nobody_asked_for():
     """``python -m finchat_tpu`` with an engine preset: JAX fell back to the
     CPU (no accelerator here) and ``JAX_PLATFORMS`` did not ask for it."""

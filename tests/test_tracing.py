@@ -135,11 +135,11 @@ def test_span_finish_first_call_wins():
     reg = MetricsRegistry()
     span = RequestSpan("seq-1", trace_id="t-span")
     span.mark("admitted")
-    span.finish(reg)
+    span.finish(reg, reason="eos")
     done = span.marks["done"]
     n0 = reg.snapshot()["finchat_request_seconds_count"]
-    span.finish(reg)
-    span.finish(reg)
+    span.finish(reg, reason="eos")
+    span.finish(reg, reason="eos")
     assert span.marks["done"] == done  # untouched by later calls
     assert reg.snapshot()["finchat_request_seconds_count"] == n0  # observed once
     assert reg.get("finchat_span_double_finish_total") == 2
@@ -244,7 +244,7 @@ def test_double_finish_counted_on_preempt_and_drain_paths():
         # late cleanups on the handoff/cancel paths re-finish: counted,
         # not double-observed
         sched._finish(h, "eos")
-        h.span.finish()
+        h.span.finish(reason="eos")
         return h
 
     asyncio.run(go())
