@@ -1,0 +1,153 @@
+"""Paged decode attention alone on the chip, at the shapes of the cell
+``mixtral-report-saturated`` (PERF.md section 6, PR 25).
+
+One ``paged_flash_attention`` call a layer over a 3-layer bf16 cache of 1,600
+pages: 16 rows, 32 query heads, 8 KV heads, head 128, page 128, one query a
+row, contexts drawn between 5k and 12k tokens (mean about 7.75k, as in the
+cell's capture), the first 31 pages of every row physically shared (the
+system prompt). The page table is given at each width of ``--widths``:
+``served`` is the engine's 128 (``max_seq_len`` / page), ``live`` the widest
+row's live pages — what the table's dead entries cost is the difference.
+
+Times are device times of the kernel's own operation in a ``jax.profiler``
+capture (the ``XLA Ops`` line), so they are what ``attn_kv_roofline.sat``
+divides by; the bytes are that metric's too (every context token's K and V,
+8 heads x 128 x 2 B each, at 819 GB/s). A wall-clock figure over the same
+calls is printed beside them. Runs on the chip only:
+
+    chiprun -- python3 benchmarks/paged_attention_alone.py
+    chiprun -- python3 benchmarks/paged_attention_alone.py --tree <checkout>
+
+``--tree`` times the kernel of another checkout (the parent commit unpacked
+somewhere under the repo) with this script's inputs.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import sys
+import tempfile
+import time
+from pathlib import Path
+
+ROWS, HEADS, KV_HEADS, HEAD_DIM, PAGE = 16, 32, 8, 128, 128
+LAYERS, POOL_PAGES, SERVED_WIDTH, SHARED_PAGES = 3, 1600, 128, 31
+HBM_BYTES_PER_S = 819e9  # one v5e chip (perfbench/peaks.json)
+
+
+def contexts(seed: int):
+    """16 context lengths in [5000, 12000], mean about 7,750."""
+    import numpy as np
+
+    rng = np.random.RandomState(seed % (2 ** 32))
+    return (5000 + 7000 * rng.beta(1.2, 1.85, size=ROWS)).astype(np.int64)
+
+
+def page_table(ctx, width: int, seed: int):
+    """Each row's live pages: the shared head, then private pages drawn
+    without replacement from the pool; dead entries are the trash page 0."""
+    import numpy as np
+
+    rng = np.random.RandomState((seed + 1) % (2 ** 32))
+    private = rng.permutation(np.arange(1 + SHARED_PAGES, POOL_PAGES))
+    table = np.zeros((ROWS, width), np.int32)
+    used = 0
+    for row, n in enumerate(-(-ctx // PAGE)):
+        table[row, :SHARED_PAGES] = np.arange(1, 1 + SHARED_PAGES)
+        table[row, SHARED_PAGES:n] = private[used:used + n - SHARED_PAGES]
+        used += n - SHARED_PAGES
+    return table
+
+
+def kernel_events(trace_dir: str) -> list[float]:
+    """Device durations (us) of the kernel's operations in the capture."""
+    from jax.profiler import ProfileData
+
+    path = next(Path(trace_dir).rglob("*.xplane.pb"))
+    found = []
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/device:TPU:"):
+            continue
+        for line in plane.lines:
+            if line.name == "XLA Ops":
+                # an event's name is the whole HLO instruction, operands
+                # included: the kernel is the one the instruction is named for
+                found += [ev.duration_ns / 1e3 for ev in line.events
+                          if "paged_flash_attention" in ev.name.split(" = ")[0]]
+    return found
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tree", default=str(Path(__file__).resolve().parents[1]))
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--widths", default="served,live")
+    ap.add_argument("--steps", type=int, default=40,
+                    help="calls of the three layers in the capture")
+    args = ap.parse_args()
+    sys.path.insert(0, args.tree)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.ops.paged_attention import paged_flash_attention
+
+    if jax.default_backend() != "tpu":
+        print("paged_attention_alone: no TPU; a time comes only from the chip",
+              file=sys.stderr)
+        return 2
+
+    ctx = contexts(args.seed)
+    keys = jax.random.split(jax.random.key(args.seed % (2 ** 31)), 3)
+    shape = (LAYERS, POOL_PAGES, PAGE, KV_HEADS * HEAD_DIM)
+    k_pages = jax.random.normal(keys[0], shape, jnp.bfloat16)
+    v_pages = jax.random.normal(keys[1], shape, jnp.bfloat16)
+    q = jax.random.normal(keys[2], (ROWS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    kv_len = jnp.asarray(ctx, jnp.int32)
+    q_offset = kv_len - 1
+
+    @jax.jit
+    def three_layers(q, k_pages, v_pages, table):
+        def layer(i, acc):
+            return acc + paged_flash_attention(
+                q, k_pages, v_pages, table, q_offset, kv_len, i[None],
+                page_size=PAGE, n_kv=KV_HEADS).astype(jnp.float32)
+        return jax.lax.fori_loop(0, LAYERS, layer, jnp.zeros(q.shape, jnp.float32))
+
+    stream_us = 1e6 * int(ctx.sum()) * 2 * KV_HEADS * HEAD_DIM * 2 / HBM_BYTES_PER_S
+    live_width = int(-(-ctx.max() // PAGE))
+    result = {
+        "tree": args.tree, "seed": args.seed, "device": jax.devices()[0].device_kind,
+        "context_tokens": int(ctx.sum()), "live_pages": int((-(-ctx // PAGE)).sum()),
+        "widest_row_pages": live_width, "stream_bound_us": stream_us, "widths": {},
+    }
+    for name in args.widths.split(","):
+        width = {"served": SERVED_WIDTH, "live": live_width}.get(name) or int(name)
+        table = jnp.asarray(page_table(ctx, width, args.seed))
+        out = three_layers(q, k_pages, v_pages, table).block_until_ready()
+        assert bool(jnp.isfinite(out).all())
+        with tempfile.TemporaryDirectory() as trace_dir:
+            jax.profiler.start_trace(trace_dir)
+            t0 = time.perf_counter()
+            for _ in range(args.steps):
+                out = three_layers(q, k_pages, v_pages, table)
+            out.block_until_ready()
+            wall_us = 1e6 * (time.perf_counter() - t0) / (args.steps * LAYERS)
+            jax.profiler.stop_trace()
+            calls = kernel_events(trace_dir)
+        call_us = float(np.mean(calls)) if calls else None
+        result["widths"][name] = {
+            "table_width": width, "grid_steps_old_walk": ROWS * width,
+            "kernel_calls": len(calls), "call_us": call_us,
+            "call_us_min_max": [min(calls), max(calls)] if calls else None,
+            "wall_us_per_call": wall_us,
+            "share_of_stream_bound": 100 * stream_us / call_us if calls else None,
+        }
+    print(json.dumps(result))
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -96,6 +96,25 @@ def say(message: str) -> None:
 
 # --- phase 2: kernels against their oracles --------------------------------
 
+def kernel_error(name: str, got, want, note: str = "") -> float:
+    """Max abs error of a kernel's output against its oracle's; raises
+    ``SmokeFailure`` on a non-finite output or an element out of tolerance."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    got = np.asarray(got.astype(jnp.float32))
+    want = np.asarray(want.astype(jnp.float32))
+    require(np.isfinite(got).all(), f"kernel {name}: non-finite output")
+    diff = np.abs(got - want)
+    error = float(diff.max())
+    bad = diff > KERNEL_ATOL + KERNEL_RTOL * np.abs(want)
+    require(not bad.any(),
+            f"kernel {name}: {int(bad.sum())} of {bad.size} elements off "
+            f"the oracle (max abs err {error:.4f})")
+    say(f"kernel {name}: ok ({note}max abs err {error:.4f})")
+    return error
+
+
 def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
                   backend: str, *, prefill_chunk: int = 512) -> dict[str, float]:
     """Each default-path kernel vs its oracle at the given widths; returns
@@ -105,7 +124,7 @@ def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
     import jax.numpy as jnp
     import numpy as np
 
-    from finchat_tpu.engine.kv_cache import scatter_kv_chunk
+    from finchat_tpu.engine.kv_cache import scale_rows, scatter_kv_chunk
     from finchat_tpu.ops import dispatch
     from finchat_tpu.ops.flash_attention import flash_attention
     from finchat_tpu.ops.kv_append import paged_kv_append
@@ -117,16 +136,7 @@ def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
     errors: dict[str, float] = {}
 
     def close(name: str, got, want) -> None:
-        got = np.asarray(got.astype(jnp.float32))
-        want = np.asarray(want.astype(jnp.float32))
-        require(np.isfinite(got).all(), f"kernel {name}: non-finite output")
-        diff = np.abs(got - want)
-        errors[name] = float(diff.max())
-        bad = diff > KERNEL_ATOL + KERNEL_RTOL * np.abs(want)
-        require(not bad.any(),
-                f"kernel {name}: {int(bad.sum())} of {bad.size} elements off "
-                f"the oracle (max abs err {errors[name]:.4f})")
-        say(f"kernel {name}: ok (max abs err {errors[name]:.4f})")
+        errors[name] = kernel_error(name, got, want)
 
     def cache(seed: int, n_pages: int):
         k1, k2 = jax.random.split(jax.random.key(seed))
@@ -142,16 +152,30 @@ def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
     max_pages = 8
     span = max_pages * page_size
 
-    def paged(name: str, C: int, ctx: list[int]) -> None:
+    def cache_q8(seed: int, n_pages: int):
+        """An int8 cache with its per-token-per-head scale blocks."""
+        keys = jax.random.split(jax.random.key(seed), 4)
+        shape = (2, n_pages, page_size, n_kv * D)
+        sshape = (2, n_pages, scale_rows(n_kv), page_size)
+        return (*(jax.random.randint(k, shape, -127, 128, jnp.int8) for k in keys[:2]),
+                *(jax.random.uniform(k, sshape, jnp.float32, 0.004, 0.012)
+                  for k in keys[2:]))
+
+    def paged(name: str, C: int, ctx: list[int], *, quantized: bool = False) -> None:
         B = len(ctx)
-        k_pages, v_pages = cache(1, 1 + B * max_pages)
+        scales = {}
+        if quantized:
+            k_pages, v_pages, scales["k_scales"], scales["v_scales"] = cache_q8(
+                1, 1 + B * max_pages)
+        else:
+            k_pages, v_pages = cache(1, 1 + B * max_pages)
         table = shuffled_table(B, max_pages, 0)
         kv_len = jnp.asarray(ctx, jnp.int32)
         q_offset = jnp.maximum(kv_len - C, 0)
         q = jax.random.normal(jax.random.key(2), (B, C, H, D), dtype)
         args = (q, k_pages, v_pages, table, q_offset, kv_len, layer)
-        got = dispatch.paged_attention(*args, backend=backend, **kw)
-        want = dispatch.paged_attention(*args, backend="ref", **kw)
+        got = dispatch.paged_attention(*args, backend=backend, **kw, **scales)
+        want = dispatch.paged_attention(*args, backend="ref", **kw, **scales)
         # an empty slot is exact zeros from the kernel (the oracle's fully
         # masked softmax averages V instead, so it is not compared there)
         live = np.asarray(ctx) > 0
@@ -161,11 +185,17 @@ def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
 
     # decode: one query per slot; an empty slot, one token, both sides of a
     # page boundary, a full row
-    paged("paged_attention[decode]", 1,
-          [0, 1, page_size, page_size + 1, span // 2 + 3, span])
+    decode_ctx = [0, 1, page_size, page_size + 1, span // 2 + 3, span]
+    paged("paged_attention[decode]", 1, decode_ctx)
     # one prefill chunk at an offset, and a first chunk
-    paged("paged_attention[prefill chunk]", prefill_chunk,
-          [prefill_chunk, min(span, prefill_chunk + page_size + 5)])
+    chunk_ctx = [prefill_chunk, min(span, prefill_chunk + page_size + 5)]
+    paged("paged_attention[prefill chunk]", prefill_chunk, chunk_ctx)
+    # a spec-verify block: one token and two drafts, padded to a sublane tile
+    paged("paged_attention[spec verify]", 3, [3, page_size + 2, span // 2 + 3, span])
+    # the int8 cache (kv_quant=int8) through the same walk
+    paged("paged_attention[decode, int8]", 1, decode_ctx, quantized=True)
+    paged("paged_attention[prefill chunk, int8]", prefill_chunk, chunk_ctx,
+          quantized=True)
 
     # in-place append vs the XLA scatter (a copy: exact outside the trash page)
     B = 8
@@ -219,6 +249,53 @@ def check_kernels(n_heads: int, n_kv: int, head_dim: int, page_size: int,
     close("flash_attention", flash_attention(q, k, v, causal=True, interpret=interpret),
           mha_reference(q, k, v, causal=True))
     return errors
+
+
+def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 32,
+                               n_kv: int = 8, head_dim: int = 128,
+                               page_size: int = 128, width: int = 128,
+                               contexts: tuple[int, int] = (5000, 12000),
+                               pool_pages: int = 1600) -> float:
+    """Paged decode attention vs its oracle at the decode shape of the
+    benchmark's cell (``mixtral-report-saturated``): ragged contexts under a
+    page table far wider than any row, dead entries on the trash page. The
+    kernel's trash page holds NaN — a dead page read would show — and the
+    oracle, which gathers the table's whole width before it masks, gets the
+    same cache with that page zeroed. Returns the max abs error."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.ops import dispatch
+
+    dtype = jnp.bfloat16
+    rng = np.random.RandomState(25)
+    ctx = rng.randint(contexts[0], contexts[1] + 1, size=rows)
+    live = -(-ctx // page_size)
+    require(live.max() <= width and live.sum() < pool_pages,
+            "cell-shape case: the contexts do not fit the table or the pool")
+    table = np.zeros((rows, width), np.int32)
+    pool = rng.permutation(np.arange(1, pool_pages))
+    used = 0
+    for row, n in enumerate(live):
+        table[row, :n] = pool[used:used + n]
+        used += n
+    k1, k2, k3 = jax.random.split(jax.random.key(25), 3)
+    shape = (2, pool_pages, page_size, n_kv * head_dim)  # layer 1 is read
+    k_pages = jax.random.normal(k1, shape, dtype).at[:, 0].set(jnp.nan)
+    v_pages = jax.random.normal(k2, shape, dtype).at[:, 0].set(jnp.nan)
+    kv_len = jnp.asarray(ctx, jnp.int32)
+    q = jax.random.normal(k3, (rows, 1, n_heads, head_dim), dtype)
+    rest = (jnp.asarray(table), kv_len - 1, kv_len, jnp.asarray([1], jnp.int32))
+    kw = dict(page_size=page_size, n_kv=n_kv)
+    got = dispatch.paged_attention(q, k_pages, v_pages, *rest, backend=backend, **kw)
+    want = dispatch.paged_attention(q, k_pages.at[:, 0].set(0), v_pages.at[:, 0].set(0),
+                                    *rest, backend="ref", **kw)
+    # a non-finite output here means a dead table entry was read
+    return kernel_error(
+        "paged_attention[decode, cell shape]", got, want,
+        f"{rows} rows, {int(ctx.sum())} context tokens, {int(live.sum())} live "
+        f"of {rows * width} table entries; ")
 
 
 # --- phase 4: engine logits, compiled kernels vs the reference backend ------
@@ -594,6 +671,7 @@ def _run(mesh_model: int) -> int:
     check_kernels(model.n_heads, model.n_kv_heads, model.head_dim,
                   cfg.engine.page_size, "pallas",
                   prefill_chunk=cfg.engine.prefill_chunk)
+    check_decode_at_cell_shape("pallas")
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half
     parity_cfg = dataclasses.replace(

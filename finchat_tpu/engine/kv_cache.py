@@ -12,7 +12,8 @@ servable on fixed TPU HBM:
   ``(page_size, Hkv*hd)`` are tile-aligned, so the in-place decode append
   kernel (ops/kv_append.py) can RMW one whole page per sequence with legal
   full-extent DMAs, and the paged attention kernel (ops/paged_attention.py)
-  value-slices per-head ``[PS, hd]`` tiles out of the loaded block. The
+  copies whole pages, several a block, through the page table and
+  value-slices per-head ``[block, hd]`` tiles out of the loaded block. The
   leading layer axis exists because the cache rides the model's layer scan
   as a CARRY (not xs→ys): XLA restacks xs→ys cache updates into a fresh
   buffer every step — a full-cache copy measured at ~22 ms/step for a 1.5 GB

@@ -59,12 +59,18 @@ def _online_softmax_update(
     l_prev: Array,  # [R, 1] fp32
     acc_prev: Array,  # [R, D] fp32
     scale: float,
+    k_scale: Array | None = None,  # [1, Bk] fp32 — per-token dequant scales
+    v_scale: Array | None = None,
 ) -> tuple[Array, Array, Array]:
-    """One flash-attention block update, fp32 softmax state."""
+    """One flash-attention block update, fp32 softmax state. With
+    ``k_scale`` / ``v_scale`` the block holds quantized integers (exact in
+    the input dtype) and the per-token scales are applied to the logits and
+    the probabilities: the same products as over a dequantized block, with
+    the scale rows lying along the tile's lanes as they are stored."""
     s = jax.lax.dot_general(
         q_blk, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
-    s = s * scale
+    s = s * scale if k_scale is None else s * (k_scale * scale)
     s = jnp.where(invalid, NEG_INF, s)
 
     m_cur = jnp.max(s, axis=-1, keepdims=True)
@@ -74,8 +80,9 @@ def _online_softmax_update(
     p = jnp.where(invalid, 0.0, jnp.exp(s - m_new))
     correction = jnp.exp(m_prev - m_new)
     l_new = l_prev * correction + jnp.sum(p, axis=-1, keepdims=True)
+    pv = p if v_scale is None else p * v_scale
     acc_new = acc_prev * correction + jax.lax.dot_general(
-        p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+        pv.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     return m_new, l_new, acc_new

@@ -34,7 +34,10 @@ CPU/tier-1 oracle and the serving path on non-TPU backends):
 - grid ``(n_q_blocks, max_pages)`` with the page axis innermost; each
   q block belongs to exactly ONE row (alignment guarantees it), resolved at
   DMA time from the scalar-prefetched ``blk_row`` map, so the online-softmax
-  scratch carries across the row's pages exactly like ops/paged_attention.py.
+  scratch carries across the row's pages from one grid step to the next.
+  (ops/paged_attention.py walked its pages the same way until PR 25 gave it
+  a loop as long as the row over blocks of several pages; this kernel keeps
+  the table-wide grid until a benchmark cell runs it inside a window.)
 - K/V pages resolve through the per-row page table at DMA time
   (PrefetchScalarGridSpec); pages past ``kv_len`` or entirely in the causal
   future of the block redirect to the trash page and are skipped by the

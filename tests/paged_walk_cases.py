@@ -1,0 +1,86 @@
+"""Inputs for the tests of the paged attention walk (ops/paged_attention.py),
+shared by tests/test_pallas_attention.py (float cache) and
+tests/test_kv_quant.py (int8 cache).
+
+One call holds every edge of the walk as a row: no token, one token, one
+short of / exactly on / one past a page and a block boundary, live pages that
+are not a multiple of the block, a row filling the whole table. The page
+table is ``width`` entries wide whatever the rows hold, and every entry at or
+beyond a row's live pages points at a page that poisons whatever reads it
+(NaN values; for the int8 cache NaN scales): a dead page read is a failed
+test, not a slower one.
+"""
+
+import jax.numpy as jnp
+import numpy as np
+
+PAGE_SIZE = 64
+WIDTH = 16  # table entries a row: 1,024 tokens
+BLOCK = 512  # ops.paged_attention.BLOCK_TOKENS: 8 pages of 64
+EDGE_CONTEXTS = [0, 1, PAGE_SIZE - 1, PAGE_SIZE, PAGE_SIZE + 1, BLOCK - 1, BLOCK,
+                 BLOCK + 1, BLOCK + 5 * PAGE_SIZE + 3, WIDTH * PAGE_SIZE]
+# (group, C): every group at decode, every chunk width at group 4
+SHAPES = [(1, 1), (4, 1), (8, 1), (4, 3), (4, 8), (4, 32)]
+DEAD_PAGE = 1  # physical page 0 is the writers' trash page; both are poisoned
+
+
+def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
+              page_size=PAGE_SIZE, n_kv=2, head_dim=32, seed=0):
+    """Returns ``(q, sources, page_table, q_offset, kv_len, layer, k_dense,
+    v_dense)``: ``sources`` is ``(k_pages, v_pages)`` or, quantized, ``(k_pages,
+    v_pages, k_scales, v_scales)`` with layer 1 of 2 filled; the dense pair
+    ``[B, width*page_size, n_kv, head_dim]`` is what the oracle attends to
+    (for the int8 cache: the dequantized values)."""
+    rng = np.random.RandomState(seed)
+    B, hd_fused = len(contexts), n_kv * head_dim
+    live = [-(-n // page_size) for n in contexts]
+    num_phys = 2 + sum(live)
+    phys = rng.permutation(np.arange(2, num_phys))  # shuffled, as under churn
+    table = np.full((B, width), DEAD_PAGE, np.int32)
+    dense = np.zeros((2, B, width * page_size, hd_fused), np.float32)  # k, v
+    pages = np.zeros((2, 2, num_phys, page_size, hd_fused), np.float32)  # k/v, layer
+    used = 0
+    for b, n in enumerate(contexts):
+        table[b, :live[b]] = phys[used:used + live[b]]
+        used += live[b]
+        dense[:, b, :n] = rng.randn(2, n, hd_fused)
+    if quantized:  # integers and per-token-per-head scales; dense = dequantized
+        ints = rng.randint(-127, 128, size=dense.shape).astype(np.float32)
+        tok_scales = rng.uniform(0.004, 0.012, size=(2, B, width * page_size, n_kv))
+        valid = np.arange(width * page_size)[None, :] < np.asarray(contexts)[:, None]
+        ints *= valid[None, :, :, None]
+        dense = (ints.reshape(2, B, -1, n_kv, head_dim)
+                 * tok_scales[..., None]).reshape(dense.shape).astype(np.float32)
+        spad = -(-n_kv // 8) * 8
+        scales = np.ones((2, 2, num_phys, spad, page_size), np.float32)
+        scales[:, :, :2] = np.nan
+    stored = ints if quantized else dense
+    for b in range(B):
+        for p in range(live[b]):
+            span = slice(p * page_size, (p + 1) * page_size)
+            pages[:, 1, table[b, p]] = stored[:, b, span]
+            if quantized:  # [k/v, tokens, n_kv] -> [k/v, n_kv, tokens]
+                scales[:, 1, table[b, p], :n_kv] = tok_scales[:, b, span].transpose(0, 2, 1)
+    if quantized:
+        pages[:, :, :2] = 127
+        sources = (*jnp.asarray(pages, jnp.int8), *jnp.asarray(scales))
+    else:
+        pages[:, :, :2] = np.nan
+        sources = tuple(jnp.asarray(pages))
+    kv_len = np.asarray(contexts, np.int32)
+    q = jnp.asarray(rng.randn(B, C, n_kv * group, head_dim), jnp.float32)
+    k_dense, v_dense = (jnp.asarray(d.reshape(B, -1, n_kv, head_dim)) for d in dense)
+    return (q, sources, jnp.asarray(table), jnp.asarray(np.maximum(kv_len - C, 0)),
+            jnp.asarray(kv_len), jnp.asarray([1], jnp.int32), k_dense, v_dense)
+
+
+def assert_matches_reference(out, ref, contexts=EDGE_CONTEXTS, atol=2e-5, rtol=2e-5):
+    """Rows with tokens match the oracle; a row with none is exactly zero
+    (the oracle's fully masked softmax averages V there instead)."""
+    out, ref = np.asarray(out), np.asarray(ref)
+    assert np.isfinite(out).all(), "a poisoned (dead) page was read"
+    for b, n in enumerate(contexts):
+        if n == 0:
+            np.testing.assert_array_equal(out[b], 0.0)
+        else:
+            np.testing.assert_allclose(out[b], ref[b], atol=atol, rtol=rtol)

@@ -31,7 +31,20 @@ def test_kernels_against_oracles_interpret():
         "pallas-interpret", prefill_chunk=16)
     assert set(errors) == {
         "paged_attention[decode]", "paged_attention[prefill chunk]",
+        "paged_attention[spec verify]", "paged_attention[decode, int8]",
+        "paged_attention[prefill chunk, int8]",
         "kv_append", "ragged_paged_attention", "flash_attention"}
+
+
+def test_decode_at_cell_shape_interpret():
+    """The cell-shaped decode case at a small size: a table four times as
+    wide as the longest row, a NaN trash page under its dead entries."""
+    model = PRESETS["tiny"]
+    error = chip_smoke.check_decode_at_cell_shape(
+        "pallas-interpret", rows=4, n_heads=model.n_heads, n_kv=model.n_kv_heads,
+        head_dim=model.head_dim, page_size=8, width=32, contexts=(9, 60),
+        pool_pages=40)
+    assert error < chip_smoke.KERNEL_ATOL + chip_smoke.KERNEL_RTOL * 4
 
 
 @pytest.mark.no_stall_sanitizer

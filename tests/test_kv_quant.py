@@ -13,6 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_walk_cases import (
+    PAGE_SIZE,
+    SHAPES,
+    assert_matches_reference,
+    walk_case,
+)
+
 from finchat_tpu.engine.engine import InferenceEngine, commit_first_token
 from finchat_tpu.engine.kv_cache import (
     PagedKVCache,
@@ -174,6 +181,23 @@ def test_paged_attention_q8_matches_dequantized_reference(backend):
     )
     want = mha_reference(q, k_deq, v_deq, causal=True, q_offset=q_offset, kv_len=kv_len)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("group,C", SHAPES)
+def test_paged_walk_edges_q8_match_dequantized_reference(group, C):
+    """The int8 walk on the float walk's cases (paged_walk_cases): every
+    edge of the walk as a row, dead table entries on a page whose scales
+    are NaN, the oracle over the same dequantized values."""
+    from finchat_tpu.ops.paged_attention import paged_flash_attention_q8
+
+    q, sources, table, q_offset, kv_len, layer, k_deq, v_deq = walk_case(
+        group, C, quantized=True)
+    out = paged_flash_attention_q8(
+        q, *sources, table, q_offset, kv_len, layer,
+        page_size=PAGE_SIZE, n_kv=2, interpret=True,
+    )
+    want = mha_reference(q, k_deq, v_deq, causal=True, q_offset=q_offset, kv_len=kv_len)
+    assert_matches_reference(out, want, atol=1e-4, rtol=1e-4)
 
 
 @pytest.mark.parametrize("attn", ["ref", "pallas-interpret"])

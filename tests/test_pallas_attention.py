@@ -12,6 +12,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from paged_walk_cases import (
+    PAGE_SIZE,
+    SHAPES,
+    assert_matches_reference,
+    walk_case,
+)
+
 from finchat_tpu.engine.kv_cache import gather_kv, scatter_kv_chunk
 from finchat_tpu.ops.flash_attention import flash_attention
 from finchat_tpu.ops.paged_attention import paged_flash_attention
@@ -153,6 +160,46 @@ def test_paged_prefill_chunk_matches_reference(C):
     )
     ref = mha_reference(q, k_dense, v_dense, causal=True, q_offset=q_offset, kv_len=kv_len)
     np.testing.assert_allclose(out, ref, atol=ATOL, rtol=RTOL)
+
+
+@pytest.mark.parametrize("group,C", SHAPES)
+def test_paged_walk_edges_match_reference(group, C):
+    """Every edge of the walk as a row of one call (paged_walk_cases), under
+    a table far wider than most rows whose dead entries point at a NaN page:
+    a dead page read fails, the empty row is exactly zero."""
+    q, sources, table, q_offset, kv_len, layer, k_dense, v_dense = walk_case(group, C)
+    out = paged_flash_attention(
+        q, *sources, table, q_offset, kv_len, layer,
+        page_size=PAGE_SIZE, n_kv=2, interpret=INTERPRET,
+    )
+    ref = mha_reference(q, k_dense, v_dense, causal=True, q_offset=q_offset, kv_len=kv_len)
+    assert_matches_reference(out, ref, atol=ATOL, rtol=RTOL)
+
+
+def _pallas_grid(jaxpr):
+    """The grid of the first ``pallas_call`` in a (nested) jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return tuple(eqn.params["grid_mapping"].grid)
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns") and (grid := _pallas_grid(inner)) is not None:
+                return grid
+    return None
+
+
+def test_paged_grid_does_not_follow_the_table_width():
+    """The walk is as long as the row: the page table's width is not a grid
+    axis, so a wider table adds no grid step."""
+    def grid(width):
+        q, sources, table, q_offset, kv_len, layer, *_ = walk_case(
+            4, 1, contexts=[5, 40], width=width)
+        return _pallas_grid(jax.make_jaxpr(
+            lambda *args: paged_flash_attention(
+                *args, page_size=PAGE_SIZE, n_kv=2, interpret=True)
+        )(q, *sources, table, q_offset, kv_len, layer).jaxpr)
+
+    assert grid(8) == grid(128) == (2, 1)  # (rows, query blocks)
 
 
 def test_paged_kernel_agrees_with_scatter_gather_path():

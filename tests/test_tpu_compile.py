@@ -1,0 +1,67 @@
+"""The paged attention kernel compiled for a v5e that is described, not
+attached (the TPU's compiler is installed beside the CPU backend): what
+interpret mode cannot show — Mosaic's layout rules and the scoped-VMEM limit
+— at the widths of the benchmark's cell. Nothing runs; a result or a time
+comes only from the chip (chip_smoke.py, perfbench/).
+
+All such compiles live in this one file: only one process may hold the TPU
+library, and the worker that is given this file is that process.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from finchat_tpu.engine.kv_cache import scale_rows
+from finchat_tpu.ops.paged_attention import (
+    paged_flash_attention,
+    paged_flash_attention_q8,
+)
+
+# mixtral-8x7b-v0.1 as perfbench/configs has it: 32 / 8 heads of 128, pages
+# of 128 tokens, a table of max_seq_len / page = 128 entries, 1,600 pages
+ROWS, HEADS, KV_HEADS, HEAD_DIM, PAGE, WIDTH, LAYERS, POOL = 16, 32, 8, 128, 128, 128, 3, 1600
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep it out
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("C", [1, 3, 256], ids=["decode", "verify", "prefill"])
+def test_paged_attention_compiles_for_v5e_at_the_cell_shape(one_chip, C, quantized):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pages = shape((LAYERS, POOL, PAGE, KV_HEADS * HEAD_DIM),
+                  jnp.int8 if quantized else jnp.bfloat16)
+    sources = (pages, pages)
+    if quantized:
+        scales = shape((LAYERS, POOL, scale_rows(KV_HEADS), PAGE), jnp.float32)
+        sources += (scales, scales)
+    kernel = paged_flash_attention_q8 if quantized else paged_flash_attention
+    compiled = jax.jit(
+        lambda *args: kernel(*args, page_size=PAGE, n_kv=KV_HEADS)
+    ).lower(
+        shape((ROWS, C, HEADS, HEAD_DIM), jnp.bfloat16), *sources,
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32),
+        shape((ROWS,), jnp.int32), shape((1,), jnp.int32),
+    ).compile()
+    # the benchmark's readers find the kernel by this name (attn_share.sat,
+    # attn_kv_roofline.sat): a custom call named after the jitted wrapper
+    calls = [line.split(" = ")[0].strip() for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert calls and all(name.startswith("%paged_flash_attention") for name in calls)
