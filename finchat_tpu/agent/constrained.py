@@ -303,6 +303,7 @@ class GrammarVocab:
         self.token_strs = list(token_strs)
         self.eos_id = eos_id
         self._mask_cache: dict[int, tuple[np.ndarray, bool, np.ndarray]] = {}
+        self._candidates_cache: dict[int, tuple[np.ndarray, np.ndarray, bool]] = {}
         # token -> end-state transition cache, keyed by (state, token_id)
         self._step_cache: dict[tuple[int, int], int] = {}
         self.distance = _distance_to_accept(dfa)
@@ -319,16 +320,22 @@ class GrammarVocab:
                 table[s, b] = self._dead_row if nxt == DEAD else nxt
             nxt = dfa.step(s, _DEAD_ROW_CHAR_REP)
             table[s, 128:] = self._dead_row if nxt == DEAD else nxt
-        self._table = table
 
-        # token byte matrix [V, Lmax] + lengths; empty tokens never allowed
+        # token byte matrix [Lmax, V] + lengths; empty tokens never allowed.
+        # Past a token's end the matrix holds column 256, under which every
+        # row of the flat table steps to itself: a state's mask is then
+        # Lmax gathers over the vocabulary and nothing else (a 261k-entry
+        # vocabulary makes each pass cost a millisecond on the loop).
         encoded = [t.encode("utf-8") for t in self.token_strs]
         self._tok_lens = np.asarray([len(e) for e in encoded], np.int32)
         lmax = max(1, int(self._tok_lens.max()))
-        mat = np.zeros((len(encoded), lmax), np.uint8)
+        mat = np.full((lmax, len(encoded)), 256, np.int32)
         for i, e in enumerate(encoded):
-            mat[i, : len(e)] = np.frombuffer(e, np.uint8)
+            mat[: len(e), i] = np.frombuffer(e, np.uint8)
         self._tok_bytes = mat
+        # entries are row offsets (state * 257), so a step is one add
+        self._flat_table = 257 * np.concatenate(
+            [table, np.arange(n + 1, dtype=np.int32)[:, None]], axis=1).ravel()
 
     @classmethod
     def for_tokenizer(cls, tokenizer) -> "GrammarVocab":
@@ -344,15 +351,29 @@ class GrammarVocab:
         cached = self._mask_cache.get(state)
         if cached is not None:
             return cached
-        V, L = self._tok_bytes.shape
-        states = np.full((V,), self._dead_row if state == DEAD else state, np.int32)
+        L, V = self._tok_bytes.shape
+        rows = np.full((V,), 257 * (self._dead_row if state == DEAD else state), np.int32)
         for j in range(L):
-            live = j < self._tok_lens
-            states = np.where(live, self._table[states, self._tok_bytes[:, j]], states)
+            rows = self._flat_table[rows + self._tok_bytes[j]]
+        states = rows // 257
         allowed = (states != self._dead_row) & (self._tok_lens > 0)
         eos_ok = state != DEAD and self.dfa.eos_ok[state]
         self._mask_cache[state] = (allowed, eos_ok, states)
         return allowed, eos_ok, states
+
+    def candidates(self, state: int) -> tuple[np.ndarray, np.ndarray, bool]:
+        """(ids, distance, eos_allowed) for a state: the allowed token ids in
+        ascending order and, beside each, the chars its end state still
+        needs to reach an accepting one. What ``pick`` works on, cached per
+        state like the mask it is cut from, so that a pick costs what the
+        allowed tokens cost and not what the vocabulary does."""
+        cached = self._candidates_cache.get(state)
+        if cached is None:
+            allowed, eos_ok, ends = self.mask(state)
+            ids = np.flatnonzero(allowed)
+            cached = (ids, self._distance_np[ends[ids]], eos_ok)
+            self._candidates_cache[state] = cached
+        return cached
 
     def advance(self, state: int, token_id: int) -> int:
         key = (state, token_id)
@@ -361,6 +382,28 @@ class GrammarVocab:
             nxt = self.dfa.step_string(state, self.token_strs[token_id])
             self._step_cache[key] = nxt
         return nxt
+
+
+_DRAW_BLOCK = 512
+
+
+def _draw(weights: np.ndarray, u: float) -> int:
+    """The index ``rng.choice(len(weights), p=weights / weights.sum())``
+    draws from its one uniform ``u``: the first index whose running sum
+    passes ``u`` of the total. In two levels (block sums, then one block),
+    because the running sum over a 261k-wide row was the dearest pass of a
+    pick; an index of weight zero is never returned."""
+    starts = np.arange(0, weights.size, _DRAW_BLOCK)
+    blocks = np.add.reduceat(weights, starts).cumsum()
+    target = u * blocks[-1]
+    b = min(int(blocks.searchsorted(target, side="right")), blocks.size - 1)
+    lo = int(starts[b])
+    inner = weights[lo: lo + _DRAW_BLOCK].cumsum()
+    inner += blocks[b - 1] if b else 0.0
+    j = int(inner.searchsorted(target, side="right"))
+    if j >= inner.size or weights[lo + j] == 0.0:  # rounding at a block's edge
+        j = int(np.flatnonzero(weights[lo: lo + _DRAW_BLOCK])[-1])
+    return lo + j
 
 
 class TokenConstraint:
@@ -392,40 +435,40 @@ class TokenConstraint:
         Returns ``eos_id`` when the grammar is complete (or unsatisfiable —
         which degrades to the no-tool path downstream, never a crash).
         """
-        allowed, eos_ok, ends = self.vocab.mask(self.state)
-        # a model head wider than the tokenizer's vocab (a padded
-        # checkpoint; a random-weight preset under the byte tokenizer): ids
-        # past the tokenizer carry no text and can never be picked
-        logits = logits[: allowed.shape[0]]
-        if remaining is not None:
-            feasible = allowed & (self.vocab._distance_np[ends] <= remaining - 2)
-            if feasible.any() or eos_ok:
-                allowed = feasible
+        vocab = self.vocab
+        ids, dist, eos_ok = vocab.candidates(self.state)
+        if remaining is not None and dist.size and int(dist.max()) > remaining - 2:
+            keep = dist <= remaining - 2
+            if keep.any() or eos_ok:
+                ids = ids[keep]
             else:
                 logger.warning(
                     "no budget-feasible token at state %d (remaining=%d); forcing EOS",
                     self.state, remaining,
                 )
-                return self.vocab.eos_id
+                return vocab.eos_id
         if eos_ok:
-            allowed = allowed.copy()
-            allowed[self.vocab.eos_id] = True
-        if not allowed.any():
-            if eos_ok:
-                return self.vocab.eos_id
+            at = int(ids.searchsorted(vocab.eos_id))
+            if at == ids.size or ids[at] != vocab.eos_id:
+                ids = np.insert(ids, at, vocab.eos_id)
+        if ids.size == 0:
             logger.warning("constraint unsatisfiable at state %d; forcing EOS", self.state)
-            return self.vocab.eos_id
+            return vocab.eos_id
 
-        masked = np.where(allowed, logits.astype(np.float64), -np.inf)
+        # only the allowed ids' logits are touched: a structural state
+        # allows a handful of a 261k-wide row. ``ids`` never reach past the
+        # tokenizer, so a wider model head (a padded checkpoint; a
+        # random-weight preset under the byte tokenizer) is never picked.
+        z = logits[ids].astype(np.float64)
         if temperature <= 0.0:
-            token = int(masked.argmax())
+            token = int(ids[z.argmax()])
         else:
             # same top-k/top-p semantics as the in-jit sampler
             # (engine/sampler.py), applied to the grammar-masked logits
-            z = masked / temperature
-            if top_k and top_k > 0:
+            z /= temperature
+            if top_k and 0 < top_k < z.size:
                 kth = np.partition(z, -top_k)[-top_k]
-                z = np.where(z < kth, -np.inf, z)
+                z[z < kth] = -np.inf
             if top_p < 1.0:
                 order = np.argsort(-z)
                 zs = z[order]
@@ -434,12 +477,10 @@ class TokenConstraint:
                 cum = np.cumsum(probs)
                 keep_sorted = (cum - probs) < top_p
                 keep_sorted[0] = True
-                drop = order[~keep_sorted]
-                z[drop] = -np.inf
+                z[order[~keep_sorted]] = -np.inf
             z -= z.max()
-            p = np.exp(z)
-            p /= p.sum()
-            token = int(rng.choice(len(p), p=p))
+            np.exp(z, out=z)
+            token = int(ids[_draw(z, rng.random())])
         if token != self.vocab.eos_id:
             self.state = self.vocab.advance(self.state, token)
         return token

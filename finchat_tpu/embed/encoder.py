@@ -232,14 +232,22 @@ class EmbeddingEncoder:
         ids = [encode(t)[: self.config.max_position] for t in texts]
         lengths = [max(1, len(i)) for i in ids]
         bucket = self._bucket(max(lengths))
-        padded = np.zeros((len(ids), bucket), np.int32)
+        # the batch is padded like the length (rows of one pad token, cut
+        # from the result): coalesced queries arrive in any count, and a
+        # count that compiles its own program stalls its requests for
+        # seconds the first time it comes up
+        rows = 1
+        while rows < len(ids):
+            rows *= 2
+        padded = np.zeros((rows, bucket), np.int32)
         for row, seq in enumerate(ids):
             padded[row, : len(seq)] = seq[:bucket]
+        lengths += [1] * (rows - len(ids))
         out = encode_batch(
             self.params, jnp.asarray(padded), jnp.asarray(lengths, jnp.int32),
             config=self.config, qm_backend=self.qm_backend,
         )
-        return np.asarray(out)
+        return np.asarray(out)[: len(ids)]
 
     def embed_query(self, text: str) -> np.ndarray:
         return self.embed_batch([text])[0]

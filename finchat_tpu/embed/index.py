@@ -284,10 +284,13 @@ class DeviceVectorIndex:
             if not mask.any():
                 return []
             q = self._normalize(np.asarray(query_vector, np.float32))
-            k = min(limit, self._capacity)
+            # k is static in the compiled top-k: a power of two, cut to the
+            # limit on the host (best first, so the cut is the top-limit), or
+            # every limit a model asks for compiles a program of its own
+            k = min(self._query_bucket(limit), self._capacity)
             scores, idx = _topk_scores(self._device_vectors, jnp.asarray(mask), jnp.asarray(q), k=k)
-            scores = np.asarray(scores)
-            idx = np.asarray(idx)
+            scores = np.asarray(scores)[:limit]
+            idx = np.asarray(idx)[:limit]
             out: list[VectorPoint] = []
             for s, i in zip(scores, idx):
                 if not np.isfinite(s):
@@ -328,7 +331,9 @@ class DeviceVectorIndex:
                 if spec.date_gte is not None:
                     q_dates[i] = spec.date_gte
                 limits.append(min(int(spec.limit), self._capacity))
-            k = max(limits)
+            # a power of two, as in query_points: each row is cut to its
+            # own limit below
+            k = min(self._query_bucket(max(limits)), self._capacity)
             q_hi, q_lo = _split_f64(q_dates)
             scores, idx = _topk_scores_batch(
                 self._device_vectors, self._device_alive,

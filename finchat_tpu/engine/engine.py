@@ -49,6 +49,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import Array
 
 from finchat_tpu.engine.kv_cache import (
@@ -57,6 +58,7 @@ from finchat_tpu.engine.kv_cache import (
 )
 from finchat_tpu.engine.sampler import sample
 from finchat_tpu.models.llama import LlamaConfig, forward, lm_head
+from finchat_tpu.models.ssm import SsmRows
 from finchat_tpu.ops.dispatch import paged_attention
 from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.logging import get_logger
@@ -102,6 +104,12 @@ class DecodeState:
     last_tokens: Array  # [max_seqs] int32 — next decode input per slot
     kv_gaps: Array  # [max_seqs] int32 — evicted tokens (bounded KV; 0 = none)
     rng: Array
+    # the second kind of per-row state (a model with a mixer, models/ssm.py):
+    # held by SLOT, not by page, and never shared — a row that starts from a
+    # shared head starts from a COPY of the head's snapshot. (1, ...)
+    # placeholders for a model without a mixer, as k_scales has
+    ssm_state: Array  # [L, max_seqs, H, P, N] float32 — the recurrence's state
+    conv_state: Array  # [L, max_seqs, K-1, C] float32 — the conv's last inputs
 
 
 def create_state(
@@ -121,7 +129,65 @@ def create_state(
         last_tokens=jnp.zeros((engine_cfg.max_seqs,), jnp.int32),
         kv_gaps=jnp.zeros((engine_cfg.max_seqs,), jnp.int32),
         rng=jax.random.key(engine_cfg.max_seqs),
+        **_ssm_leaves(config, engine_cfg.max_seqs),
     )
+
+
+def _ssm_leaves(config: LlamaConfig, max_seqs: int) -> dict[str, Array]:
+    c = config
+    if not c.ssm_heads:
+        return {"ssm_state": jnp.zeros((1, 1, 1, 1, 1), jnp.float32),
+                "conv_state": jnp.zeros((1, 1, 1, 1), jnp.float32)}
+    return {
+        "ssm_state": jnp.zeros(
+            (c.n_layers, max_seqs, c.ssm_heads, c.ssm_head_dim, c.ssm_state), jnp.float32),
+        "conv_state": jnp.zeros(
+            (c.n_layers, max_seqs, c.ssm_conv - 1, c.ssm_conv_dim), jnp.float32),
+    }
+
+
+def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array, *,
+                    config: LlamaConfig, attention, ssm_rows: SsmRows | None,
+                    **kw) -> tuple[Array, DecodeState]:
+    """``forward`` over the state's caches; returns its output and the state
+    with the caches it advanced: the K/V pool and, for a model with a mixer,
+    the recurrent state (``ssm_rows`` says whose state each batch row is)."""
+    cache = (state.k_pages, state.v_pages, state.k_scales, state.v_scales)
+    if not config.ssm_heads:
+        out, cache = forward(params, tokens, positions, config=config,
+                             attention=attention, cache=cache, **kw)
+        ssm = (state.ssm_state, state.conv_state)
+    else:
+        out, (cache, ssm) = forward(
+            params, tokens, positions, config=config, attention=attention,
+            cache=cache, ssm_cache=(state.ssm_state, state.conv_state),
+            ssm_rows=ssm_rows, **kw)
+    k_pages, v_pages, k_scales, v_scales = cache
+    return out, dataclasses.replace(
+        state, k_pages=k_pages, v_pages=v_pages, k_scales=k_scales,
+        v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1])
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _ssm_clear_slots(ssm_state: Array, conv_state: Array, keep: Array):
+    """Zero the recurrent state of every slot not in ``keep`` [max_seqs], in
+    place and at one shape whatever the count (an eager ``.at[].set`` would
+    copy the whole state and compile per count)."""
+    return (jnp.where(keep[None, :, None, None, None], ssm_state, 0.0),
+            jnp.where(keep[None, :, None, None], conv_state, 0.0))
+
+
+@partial(jax.jit, donate_argnums=(0, 1))
+def _ssm_load_slot(ssm_state: Array, conv_state: Array, slot: Array, snap: tuple):
+    """Copy a snapshot (one slot's state, every layer) into ``slot``."""
+    return (jax.lax.dynamic_update_index_in_dim(ssm_state, snap[0], slot, 1),
+            jax.lax.dynamic_update_index_in_dim(conv_state, snap[1], slot, 1))
+
+
+@jax.jit
+def _ssm_read_slot(ssm_state: Array, conv_state: Array, slot: Array):
+    return (jax.lax.dynamic_index_in_dim(ssm_state, slot, 1, keepdims=False),
+            jax.lax.dynamic_index_in_dim(conv_state, slot, 1, keepdims=False))
 
 
 def _paged_attention_fn(
@@ -225,10 +291,11 @@ def prefill_step(
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB for the 8B
     # bench shape (64 x 128 x 128256) — vs 33 MB for [N, vocab]
-    hidden, (k_pages, v_pages, k_scales, v_scales) = forward(
-        params, tokens, positions,
-        config=config, attention=attention,
-        cache=(state.k_pages, state.v_pages, state.k_scales, state.v_scales),
+    # a mixer's state: each row starts from its slot's and leaves its last
+    # state there, so a prompt's chunks carry it from round to round
+    hidden, state = _forward_cached(
+        params, state, tokens, positions,
+        config=config, attention=attention, ssm_rows=SsmRows(slots, n_valid),
         return_hidden=True, qm_backend=qm_backend,
     )
     last_hidden = jnp.take_along_axis(
@@ -238,12 +305,7 @@ def prefill_step(
                           qm_backend=qm_backend)  # [N, vocab]
 
     new_state = dataclasses.replace(
-        state,
-        k_pages=k_pages,
-        v_pages=v_pages,
-        k_scales=k_scales,
-        v_scales=v_scales,
-        context_lens=state.context_lens.at[slots].add(n_valid),
+        state, context_lens=state.context_lens.at[slots].add(n_valid),
     )
     return new_state, last_logits
 
@@ -524,10 +586,11 @@ def decode_step(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
         page_size, config.n_kv_heads, attn_backend,
     )
-    logits, (k_pages, v_pages, k_scales, v_scales) = forward(
-        params, tokens, positions,
-        config=config, attention=attention,
-        cache=(state.k_pages, state.v_pages, state.k_scales, state.v_scales),
+    # a mixer's state advances one token in every active slot, in place
+    # (row i IS slot i: a slice and an update, no gather)
+    logits, state = _forward_cached(
+        params, state, tokens, positions,
+        config=config, attention=attention, ssm_rows=SsmRows(None, n_valid),
         qm_backend=qm_backend,
     )
     step_logits = logits[:, 0, :]  # [B, vocab]
@@ -537,10 +600,6 @@ def decode_step(
 
     new_state = dataclasses.replace(
         state,
-        k_pages=k_pages,
-        v_pages=v_pages,
-        k_scales=k_scales,
-        v_scales=v_scales,
         context_lens=state.context_lens + n_valid,
         last_tokens=jnp.where(active, next_tokens, state.last_tokens),
         rng=rng,
@@ -642,6 +701,7 @@ def _ragged_round_math(
     qm_backend: str = "ref",
     spec_width: int = 0,
     loop_depth: int = 1,
+    max_row_tokens: int = 0,
 ) -> tuple[DecodeState, Array, Array, Array, Array]:
     """The packed ragged round body, shared VERBATIM by the single-round
     ``ragged_mixed_step`` and the multi-round free-run capture
@@ -693,10 +753,16 @@ def _ragged_round_math(
     )
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes
-    hidden, (k_pages, v_pages, k_scales, v_scales) = forward(
-        params, tok_in[None], tok_pos[None],
-        config=config, attention=attention,
-        cache=(state.k_pages, state.v_pages, state.k_scales, state.v_scales),
+    # a mixer's conv and scan must not run across a row boundary: the packed
+    # tokens are regrouped to [R, row_width] rows, each from its slot's state
+    # (a dead or padding row rides inert), and its last state goes back there
+    ssm_rows = SsmRows(
+        row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
+        width=min(T, max_row_tokens or T),
+    ) if config.ssm_heads else None
+    hidden, state = _forward_cached(
+        params, state, tok_in[None], tok_pos[None],
+        config=config, attention=attention, ssm_rows=ssm_rows,
         return_hidden=True, qm_backend=qm_backend,
     )
     h = hidden[0]  # [T, D]
@@ -742,10 +808,6 @@ def _ragged_round_math(
     delta = jnp.where(row_arm, last_tok - row_last, 0)
     state = dataclasses.replace(
         state,
-        k_pages=k_pages,
-        v_pages=v_pages,
-        k_scales=k_scales,
-        v_scales=v_scales,
         context_lens=state.context_lens.at[row_slot].add(advance),
         last_tokens=state.last_tokens.at[row_slot].add(delta),
         rng=rng,
@@ -803,7 +865,7 @@ def _ragged_round_math(
 @partial(
     jax.jit,
     static_argnames=("config", "page_size", "attn_backend", "qm_backend",
-                     "spec_width", "loop_depth"),
+                     "spec_width", "loop_depth", "max_row_tokens"),
     donate_argnums=(1,),
 )
 def ragged_mixed_step(
@@ -833,6 +895,7 @@ def ragged_mixed_step(
     qm_backend: str = "ref",
     spec_width: int = 0,
     loop_depth: int = 1,
+    max_row_tokens: int = 0,  # a mixer's row width (0 = the buffer's length)
 ) -> tuple[DecodeState, Array, Array, Array, Array]:
     """ONE packed ragged dispatch advancing every serving population at once
     (the scheduler's mixed path, ISSUE 10 — built on
@@ -879,6 +942,7 @@ def ragged_mixed_step(
         jnp.ones((R,), bool),  # every row live: the host stepped this round
         config=config, page_size=page_size, attn_backend=attn_backend,
         qm_backend=qm_backend, spec_width=spec_width, loop_depth=loop_depth,
+        max_row_tokens=max_row_tokens,
     )
 
 
@@ -1269,6 +1333,8 @@ class InferenceEngine:
         # aligned blocks when Hkv % 8 == 0, replicated — they're ~6% of the
         # pages — otherwise), and the SP-prefill write path quantizes too
         self.kv_quant = kv_quant = engine_cfg.kv_quant
+        if config.ssm_heads:
+            self._refuse_without_state_carry(mesh)
         state = create_state(config, engine_cfg, self.max_pages_per_seq, kv_quant=kv_quant)
         if mesh is not None:
             # TP placement: params sharded Megatron-style, KV pages sharded
@@ -1295,6 +1361,64 @@ class InferenceEngine:
         self.params = params
         self.state = state
         self.sp_mode = self._resolve_sp_mode(engine_cfg.sp_mode)
+
+    def _refuse_without_state_carry(self, mesh) -> None:
+        """A model with a mixer (``config.ssm_heads``) keeps a recurrent
+        state a row cannot be rewound over and that no page holds. The steps
+        that carry it are ``prefill_step``, ``decode_step`` and
+        ``ragged_mixed_step``; every option that reaches another step, or
+        that rewinds or re-lays a row's K/V, is refused here by name — none
+        may run and be silently wrong."""
+        cfg = self.engine_cfg
+        refused = {
+            "engine.spec_tokens": cfg.spec_tokens > 0,  # rejected drafts rewind a row
+            "engine.decode_loop_depth": self.decode_loop_depth > 1,
+            "engine.freerun_rounds": self.freerun_rounds > 1,
+            "engine.kv_sink_pages / engine.kv_window_pages": self.bounded_kv is not None,
+            "mesh.* > 1": mesh is not None and mesh.devices.size > 1,
+        }
+        named = [option for option, on in refused.items() if on]
+        if named:
+            raise ValueError(
+                f"a model with a Mamba-2 mixer (ssm_heads={self.config.ssm_heads}) "
+                f"carries its recurrent state through prefill_step, decode_step and "
+                f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
+
+    @property
+    def ssm_state_bytes(self) -> int:
+        """Device bytes of the recurrent state and the conv tails (0 for a
+        model without a mixer): the second kind of per-row memory."""
+        if not self.config.ssm_heads:
+            return 0
+        return int(self.state.ssm_state.nbytes + self.state.conv_state.nbytes)
+
+    def ssm_snapshot(self, slot: int) -> tuple | None:
+        """A copy of ``slot``'s recurrent state and conv tail, every layer
+        (device arrays) — what a shared head keeps beside its pages. None
+        for a model without a mixer."""
+        if not self.config.ssm_heads:
+            return None
+        return _ssm_read_slot(self.state.ssm_state, self.state.conv_state, jnp.int32(slot))
+
+    def ssm_restore(self, slot: int, snap: tuple) -> None:
+        """Start ``slot`` from a snapshot (admission from a shared head)."""
+        ssm_state, conv_state = _ssm_load_slot(
+            self.state.ssm_state, self.state.conv_state, jnp.int32(slot), snap)
+        self.state = dataclasses.replace(
+            self.state, ssm_state=ssm_state, conv_state=conv_state)
+
+    def ssm_admit(self, rows: dict[int, tuple | None]) -> None:
+        """Admission owns the state: every admitted slot starts from its
+        head's snapshot or, with none, from zero — whatever the slot's last
+        row left and whether or not its release-time reset went through (the
+        recurrence has no page table or context length to mask a stale
+        state)."""
+        cold = [slot for slot, snap in rows.items() if snap is None]
+        if cold:
+            self._ssm_clear(cold)
+        for slot, snap in rows.items():
+            if snap is not None:
+                self.ssm_restore(slot, snap)
 
     @property
     def quant_label(self) -> str:
@@ -1334,27 +1458,54 @@ class InferenceEngine:
         """Assign several slots' page lists in ONE device update: each
         eager ``.at[].set`` is its own dispatch, and a per-slot loop at
         batch 64 pays 64 of them."""
-        import numpy as np
-
-        idx = np.asarray(list(rows), np.int32)
-        mat = np.zeros((len(rows), self.max_pages_per_seq), np.int32)
+        if not rows:
+            return
+        idx = self._slot_rows(rows)
+        mat = np.zeros((len(idx), self.max_pages_per_seq), np.int32)
         for i, pages in enumerate(rows.values()):
             mat[i, : len(pages)] = pages
+        mat[len(rows):] = mat[len(rows) - 1]
         self.state = dataclasses.replace(
             self.state,
             page_table=self.state.page_table.at[jnp.asarray(idx)].set(jnp.asarray(mat)),
         )
 
+    def _slot_rows(self, slots) -> np.ndarray:
+        """The slots of one batched eager update as an index of ``max_seqs``
+        entries, the last slot repeated (a repeated row writes the same
+        values again). One shape whatever the count, and the shape warm-up
+        runs: an update shaped by its row count compiled on the scheduler's
+        loop, for seconds, whenever a count first came up (PERF.md §6, the
+        stalls of PR 24). More slots than ``max_seqs`` keep their count."""
+        idx = np.asarray(list(slots), np.int32)
+        pad = self.engine_cfg.max_seqs - len(idx)
+        return np.concatenate([idx, np.full((pad,), idx[-1], np.int32)]) if pad > 0 else idx
+
+    def _slot_values(self, values, n: int) -> np.ndarray:
+        """``values`` padded like :meth:`_slot_rows`'s index of ``n`` entries."""
+        vals = np.asarray(list(values), np.int32)
+        return np.concatenate([vals, np.full((n - len(vals),), vals[-1], np.int32)])
+
+    def logits_rows(self, logits: Array, rows: list[int]) -> Array:
+        """``[n', vocab]``: the rows of a step's logits that the host picks
+        from (grammar-constrained rows), ``n'`` the next power of two (the
+        last row repeated; the host reads the first ``len(rows)``). A
+        handful of shapes, each run in warm-up, where a gather shaped by the
+        count compiled on the loop in the middle of a tool decision."""
+        n = min(round_up_pow2(len(rows)), logits.shape[0])
+        idx = list(rows) + [rows[-1]] * (n - len(rows))
+        return logits[jnp.asarray(idx, jnp.int32)]
+
     def set_context_lens_rows(self, rows: dict[int, int]) -> None:
         """Set several slots' context lengths in ONE device update — used by
         prefix-cache admission to start a slot at the shared prefix length
         (see set_page_table_rows for why batching matters)."""
-        import numpy as np
-
-        idx = jnp.asarray(np.asarray(list(rows), np.int32))
-        vals = jnp.asarray(np.asarray(list(rows.values()), np.int32))
+        if not rows:
+            return
+        idx = self._slot_rows(rows)
+        vals = jnp.asarray(self._slot_values(rows.values(), len(idx)))
         self.state = dataclasses.replace(
-            self.state, context_lens=self.state.context_lens.at[idx].set(vals)
+            self.state, context_lens=self.state.context_lens.at[jnp.asarray(idx)].set(vals)
         )
 
     def set_kv_gap_rows(self, rows: dict[int, int]) -> None:
@@ -1364,12 +1515,12 @@ class InferenceEngine:
         deterministic metadata: the scheduler mirrors it on the handle and
         updates both sides together between dispatches, so every enqueued
         step sees a page table and gap that agree."""
-        import numpy as np
-
-        idx = jnp.asarray(np.asarray(list(rows), np.int32))
-        vals = jnp.asarray(np.asarray(list(rows.values()), np.int32))
+        if not rows:
+            return
+        idx = self._slot_rows(rows)
+        vals = jnp.asarray(self._slot_values(rows.values(), len(idx)))
         self.state = dataclasses.replace(
-            self.state, kv_gaps=self.state.kv_gaps.at[idx].set(vals)
+            self.state, kv_gaps=self.state.kv_gaps.at[jnp.asarray(idx)].set(vals)
         )
 
     def set_last_token(self, slot: int, token: int) -> None:
@@ -1385,7 +1536,9 @@ class InferenceEngine:
     def reset_slots(self, slots: list[int]) -> None:
         """Clear several slots in one device update (see set_page_table_rows
         for why batching matters)."""
-        idx = jnp.asarray(slots, jnp.int32)
+        if not slots:
+            return
+        idx = jnp.asarray(self._slot_rows(slots))
         self.state = dataclasses.replace(
             self.state,
             page_table=self.state.page_table.at[idx].set(0),
@@ -1393,6 +1546,16 @@ class InferenceEngine:
             last_tokens=self.state.last_tokens.at[idx].set(0),
             kv_gaps=self.state.kv_gaps.at[idx].set(0),
         )
+        if self.config.ssm_heads:
+            self._ssm_clear(slots)
+
+    def _ssm_clear(self, slots: list[int]) -> None:
+        keep = np.ones((self.engine_cfg.max_seqs,), bool)
+        keep[list(slots)] = False
+        ssm_state, conv_state = _ssm_clear_slots(
+            self.state.ssm_state, self.state.conv_state, jnp.asarray(keep))
+        self.state = dataclasses.replace(
+            self.state, ssm_state=ssm_state, conv_state=conv_state)
 
     def offload_pages(self, page_ids: list[int]):
         """Snapshot physical pages device→host (all layers, K+V+scales) for
@@ -1656,7 +1819,7 @@ class InferenceEngine:
                     config=self.config, page_size=self.page_size,
                     attn_backend=self.attn_backend, qm_backend=self.qm_backend,
                     spec_width=cfg.spec_tokens,
-                    loop_depth=self.decode_loop_depth,
+                    loop_depth=self.decode_loop_depth, **self._ragged_kw(),
                 )
                 n_variants += 1
             if self.freerun_rounds > 1:
@@ -1724,6 +1887,24 @@ class InferenceEngine:
             jnp.float32(0.0), jnp.float32(1.0), jnp.int32(0),
         )
         n_variants += 1
+        # the eager updates the scheduler makes between steps, each at its
+        # one padded shape, and the constrained rows' logits gather at each
+        # of its counts: slot 0 is empty here, so nothing changes
+        self.set_page_table_rows({0: []})
+        self.set_context_lens_rows({0: 0})
+        self.set_kv_gap_rows({0: 0})
+        self.set_last_token(0, 0)
+        step_logits = jnp.zeros((B, self.config.vocab_size), jnp.float32)
+        for n in sorted({min(round_up_pow2(k), B) for k in range(1, B + 1)}):
+            self.logits_rows(step_logits, [0] * n)
+        del step_logits
+        if self.config.ssm_heads:
+            # the state's own three programs (admission from a head's
+            # snapshot, the reset of a slot, the snapshot itself): slot 0 is
+            # zero here, so reading and restoring it changes nothing
+            self.ssm_restore(0, self.ssm_snapshot(0))
+            n_variants += 3
+        self.reset_slots([0])
         # ring-prefill length buckets (seq > 1 meshes): every bucket the
         # router can produce, INCLUDING the top one covering max_seq_len
         # (stopping at max_seq_len itself would miss e.g. the 8192 bucket a
@@ -1851,10 +2032,18 @@ class InferenceEngine:
                 config=self.config, page_size=self.page_size,
                 attn_backend=self.attn_backend, qm_backend=self.qm_backend,
                 spec_width=self.engine_cfg.spec_tokens,
-                loop_depth=self.decode_loop_depth,
+                loop_depth=self.decode_loop_depth, **self._ragged_kw(),
             )
         )
         return emitted, n_emitted, row_logits, loop_block
+
+    def _ragged_kw(self) -> dict:
+        """What only a model with a mixer passes to ``ragged_mixed_step``
+        (jit keys on the keywords a call passes: the others' calls stay as
+        warm-up compiled them): no row of a round is longer than a chunk."""
+        if not self.config.ssm_heads:
+            return {}
+        return {"max_row_tokens": self.engine_cfg.prefill_chunk}
 
     def ragged_multi(self, tokens, tok_row, row_slot, row_start, row_len,  # finchat-lint: hot
                      row_from_device, row_arm, temperature, top_p, top_k,

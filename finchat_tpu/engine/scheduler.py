@@ -351,6 +351,10 @@ class _PrefixEntry:
     owner: str
     refs: int = 0
     retired: bool = False
+    # a model with a mixer: the recurrent state after the head's last token
+    # (engine.ssm_snapshot). The pages are referenced by every row that
+    # starts from the head; the state is COPIED into the row's slot
+    ssm_snap: tuple | None = None
 
 
 class ContinuousBatchingScheduler:
@@ -583,6 +587,28 @@ class ContinuousBatchingScheduler:
         # of re-prefilling per replica, and the cache keeps the fabric's
         # global holder index current. None = the per-replica PR 7 layout.
         self.fabric = fabric
+        # a model with a mixer (models/ssm.py) keeps, beside its pages, a
+        # recurrent state by slot. Whatever skips prefill by REFERENCING
+        # pages is valid for it only at a position whose state was
+        # snapshotted: shared heads keep one (_PrefixEntry.ssm_snap) and a
+        # row admitted from a head starts from a copy; a partial match of a
+        # head, a session resume and the warm fabric have no state to start
+        # from, so those rows recompute from their tokens (counted) or the
+        # option is refused here
+        self.has_ssm = bool(engine.config.ssm_heads)
+        self.metrics.set_gauge("finchat_ssm_state_bytes",
+                               getattr(engine, "ssm_state_bytes", 0))
+        if self.has_ssm:
+            if fabric is not None:
+                raise ValueError(
+                    "fabric.path: the warm-state fabric's head and session records "
+                    "hold pages only; a model with a Mamba-2 mixer cannot resume "
+                    "from them (no recurrent state in the record)")
+            for kind in ("head", "session"):
+                self.metrics.inc("finchat_ssm_snapshots_total", 0.0,
+                                 labels={"kind": kind})
+            self.metrics.inc("finchat_ssm_snapshot_restores_total", 0.0)
+            self.metrics.inc("finchat_ssm_recompute_fallbacks_total", 0.0)
         # disaggregated serving (serve/disagg.py — ISSUE 17): the fleet
         # attaches its DisaggCoordinator to SERVING-pool schedulers only;
         # submit routes cold prompt prefills through it when set
@@ -604,7 +630,16 @@ class ContinuousBatchingScheduler:
         # conversation_id; None = disabled. The on_drop hook is where entry
         # references on shared-prefix pages are released.
         self.session_cache = None
-        if cfg.session_cache and cfg.session_cache_bytes > 0:
+        # what the session tier would have resumed, a model with a mixer
+        # recomputes: an entry ends on a page boundary behind the row's last
+        # state, and a state cannot be rewound to it (_admit counts them)
+        self._ssm_session_fallback = (
+            self.has_ssm and cfg.session_cache and cfg.session_cache_bytes > 0)
+        if self._ssm_session_fallback:
+            logger.info("session cache off: its entries hold no recurrent state "
+                        "(ssm_heads=%d); resumed turns recompute from their tokens",
+                        engine.config.ssm_heads)
+        elif cfg.session_cache and cfg.session_cache_bytes > 0:
             from finchat_tpu.engine.session_cache import (
                 SessionDiskTier,
                 SessionKVCache,
@@ -871,7 +906,7 @@ class ContinuousBatchingScheduler:
                            "quant": self._quant_label,
                            "rows": [[slot, tid, mode]
                                     for slot, tid, mode, _kv in riders]})
-        self._phases.note(kind=kind,
+        self._phases.note(kind=kind, rows=len(riders),
                           kv_tokens=sum(kv for *_row, kv in riders))
 
     @staticmethod
@@ -987,6 +1022,7 @@ class ContinuousBatchingScheduler:
         try:
             self.engine.set_page_table_row(slot, pages)
             self.engine.prefill(slot, ids)  # fills exactly the shared pages
+            ssm_snap = self._head_snapshot(slot)
         except Exception:
             self.allocator.free(owner, pages)
             raise
@@ -1000,11 +1036,21 @@ class ContinuousBatchingScheduler:
                 # the original failure (finchat-lint R3)
                 logger.error("slot reset failed after prefix prefill: %s", e)
             self.free_slots.append(slot)
-        self._prefixes.append(_PrefixEntry(ids, pages, shared_len, owner))
+        self._prefixes.append(_PrefixEntry(ids, pages, shared_len, owner,
+                                           ssm_snap=ssm_snap))
         self._fabric_store_head(ids, pages)
         logger.info("prefix cache: registered %d shared tokens (%d pages)",
                     shared_len, len(pages))
         return shared_len
+
+    def _head_snapshot(self, slot: int) -> tuple | None:
+        """The recurrent state ``slot`` holds after a head's last token, to
+        keep with the head's pages (None for a model without a mixer). Taken
+        before the slot is reset."""
+        if not self.has_ssm:
+            return None
+        self.metrics.inc("finchat_ssm_snapshots_total", labels={"kind": "head"})
+        return self.engine.ssm_snapshot(slot)
 
     def _fabric_restore_head(self, ids: list[int], shared_len: int,
                              pages: list[int]) -> bool:
@@ -1179,16 +1225,29 @@ class ContinuousBatchingScheduler:
         """Longest live registered prefix usable for this prompt: whole
         shared pages only, and at least one prompt token must remain to
         prefill (the commit needs real last-token logits)."""
+        return self._scan_prefixes(prompt_ids)[:2]
+
+    def _scan_prefixes(
+        self, prompt_ids: list[int]
+    ) -> tuple["_PrefixEntry | None", int, bool]:
+        """``_match_prefix`` and, third, whether a head was passed over
+        because the prompt shares only part of it — a model with a mixer
+        keeps a head's state at the head's end only, so such a prompt
+        recomputes what it shares (``_admit`` counts it)."""
         page = self.engine.page_size
         cap = ((len(prompt_ids) - 1) // page) * page
         best: tuple[_PrefixEntry | None, int] = (None, 0)
+        partial_skipped = False
         for entry in self._prefixes:
             if entry.retired:
                 continue
             usable = min(entry.shared_len, cap)
             if usable > best[1] and prompt_ids[:usable] == entry.ids[:usable]:
+                if self.has_ssm and usable < entry.shared_len:
+                    partial_skipped = True
+                    continue
                 best = (entry, usable)
-        return best
+        return (*best, partial_skipped)
 
     def cancel(self, handle: SequenceHandle) -> None:
         """Client went away (e.g. watchdog timeout): evict and free."""
@@ -1287,9 +1346,11 @@ class ContinuousBatchingScheduler:
         admitted: dict[int, list[int]] = {}
         ctx_rows: dict[int, int] = {}
         gap_rows: dict[int, int] = {}
+        ssm_rows: dict[int, tuple | None] = {}
         page = self.engine.page_size
         while self.pending and self.free_slots:
             handle = self.pending[0]
+            partial_skipped = False
             total = self._admission_pages(handle)
             if total > self.engine.max_pages_per_seq:
                 break  # head-of-line waits for pages (rejected at submit anyway)
@@ -1324,7 +1385,8 @@ class ContinuousBatchingScheduler:
                 if ring and self.engine.ring_segment_tokens() == 0:
                     entry, shared_len = None, 0
                 else:
-                    entry, shared_len = self._match_prefix(handle.prompt_ids)
+                    entry, shared_len, partial_skipped = self._scan_prefixes(
+                        handle.prompt_ids)
                 if (self.bounded_kv is not None
                         and shared_len > self.bounded_kv.sink_tokens):
                     # bounded rows reference at most the SINK-sized lead
@@ -1472,6 +1534,15 @@ class ContinuousBatchingScheduler:
                 if s_entry is None and bsnap is None:
                     self.metrics.inc("finchat_prefix_hits_total")
                     self.metrics.inc("finchat_prefix_tokens_saved_total", shared_len)
+            if self.has_ssm:
+                # the pages are referenced; the state is copied: the row
+                # starts from the head's snapshot, or from zero (engine.
+                # ssm_admit), never from what the slot's last row left
+                ssm_rows[slot] = ref_entry.ssm_snap if resume_pos else None
+                handle.span.state_restored_tokens = resume_pos
+                if ((self._ssm_session_fallback and handle.conversation_id)
+                        or (partial_skipped and not resume_pos)):
+                    self.metrics.inc("finchat_ssm_recompute_fallbacks_total")
             handle.slot = slot
             handle.span.mark("admitted")
             if not handle.preempted:  # a replay's history is no new prompt
@@ -1495,6 +1566,10 @@ class ContinuousBatchingScheduler:
                 self.engine.set_context_lens_rows(ctx_rows)
             if gap_rows:
                 self.engine.set_kv_gap_rows(gap_rows)
+            if ssm_rows:
+                self.engine.ssm_admit(ssm_rows)
+                self.metrics.inc("finchat_ssm_snapshot_restores_total",
+                                 sum(snap is not None for snap in ssm_rows.values()))
             self.metrics.set_gauge("finchat_queue_depth", len(self.pending))
 
     def _finish(self, handle: SequenceHandle, reason: str) -> None:
@@ -1515,8 +1590,9 @@ class ContinuousBatchingScheduler:
                 # return and the prefix-ref release below, leaking the
                 # slot forever — and _release's callers (_evict via
                 # watchdog cancel, stop) don't expect a raise. Admission
-                # rewrites the page-table row and context length anyway;
-                # a wedged device trips the breaker.
+                # rewrites the page-table row, the context length and (a
+                # model with a mixer) the recurrent state anyway; a wedged
+                # device trips the breaker.
                 logger.error("slot reset failed releasing %s: %s",
                              handle.seq_id, e)
             self.decoding.pop(handle.slot, None)
@@ -1839,8 +1915,9 @@ class ContinuousBatchingScheduler:
                 try:
                     self.engine.reset_slot(slot)
                 except Exception as e:
-                    # survivable: admission rewrites the page-table row and
-                    # context length; a wedged device trips the breaker
+                    # survivable: admission rewrites the page-table row, the
+                    # context length and the mixer's state; a wedged device
+                    # trips the breaker
                     logger.error("slot reset failed preempting %s: %s",
                                  handle.seq_id, e)
         elif handle in self.pending:
@@ -2722,10 +2799,12 @@ class ContinuousBatchingScheduler:
         the entry, return the engine slot, resolve the caller's future
         (shared by both round paths — they must stay in lock-step)."""
         self._prefix_jobs.remove(job)
+        ssm_snap = self._head_snapshot(job.slot)
         self.engine.reset_slot(job.slot)
         self.free_slots.append(job.slot)
         self._prefixes.append(
-            _PrefixEntry(job.ids, job.pages, job.shared_len, job.owner)
+            _PrefixEntry(job.ids, job.pages, job.shared_len, job.owner,
+                         ssm_snap=ssm_snap)
         )
         logger.info(
             "prefix cache: registered %d shared tokens (%d pages, %s)",
@@ -3264,7 +3343,7 @@ class ContinuousBatchingScheduler:
         if constrained_rows:
             # only the constrained rows' logits cross to host — a device
             # slice [n, vocab], exactly the _dispatch_decode discipline
-            logits_sel = row_logits_dev[jnp.asarray(constrained_rows, jnp.int32)]
+            logits_sel = eng.logits_rows(row_logits_dev, constrained_rows)
         # ONE host fetch serves decode tokens, spec acceptances, first
         # tokens, the fused tail block, and the constrained rows' logits
         # (worker thread keeps the event loop live)
@@ -3431,7 +3510,7 @@ class ContinuousBatchingScheduler:
             )
         next_tokens, logits = result if need_logits else (result, None)
         if logits is not None:
-            logits = logits[jnp.asarray(constrained_slots, jnp.int32)]
+            logits = eng.logits_rows(logits, constrained_slots)
         return _InFlightStep(
             tokens=next_tokens, logits=logits,
             members=members,
@@ -3720,7 +3799,7 @@ class ContinuousBatchingScheduler:
             ])
         emitted, n_emitted, logits = result if need_logits else (*result, None)
         if logits is not None:
-            logits = logits[jnp.asarray(constrained_slots, jnp.int32)]
+            logits = eng.logits_rows(logits, constrained_slots)
 
         emitted_host, n_emitted_host, logits_host = await self._fetch(
             lambda: (

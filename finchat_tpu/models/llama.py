@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax import Array, lax
 
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
+from finchat_tpu.models.ssm import mixer, scaled
 
 # attention callback signature:
 #   fn(q[B,S,H,D], k[B,S,Hkv,D], v[B,S,Hkv,D], layer_cache, layer_idx) ->
@@ -51,10 +52,46 @@ class LlamaConfig:
     # over the mesh's `expert` axis (EP) — see moe_mlp below.
     n_experts: int = 0
     top_k_experts: int = 2
+    # a head's width where it is not dim / n_heads (0 = that quotient)
+    head_dim: int = 0
+    # scalar µP multipliers (Falcon-H1). 1 = absent: nothing is emitted for
+    # it, so a config without them compiles to the program it always was
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
+    # a Mamba-2 mixer beside attention in every layer (models/ssm.py), both
+    # on one normed input, their outputs summed into the residual.
+    # ssm_heads 0 = none: today's block
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0  # state channels a head-channel (N)
+    ssm_groups: int = 1  # groups of B/C
+    ssm_conv: int = 4  # width of the causal depthwise conv
+    ssm_chunk: int = 128  # block of the chunked scan
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: tuple[float, ...] = (1.0,) * 5  # z, xs, B, C, dt
+
+    def __post_init__(self) -> None:
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.dim // self.n_heads)
 
     @property
-    def head_dim(self) -> int:
-        return self.dim // self.n_heads
+    def d_ssm(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels through the conv: xs and the groups' B and C."""
+        return self.d_ssm + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_in_dim(self) -> int:
+        """The mixer's input projection: [z | xs | B | C | dt]."""
+        return self.d_ssm + self.ssm_conv_dim + self.ssm_heads
 
 
 # Model shapes follow the public architecture cards; "tiny"/"mini" are
@@ -97,6 +134,11 @@ def n_params(config: LlamaConfig) -> int:
     if c.n_experts:
         mlp = mlp * c.n_experts + d * c.n_experts  # experts + router
     per_layer = attn + mlp + 2 * d
+    if c.ssm_heads:
+        # in/out projections, conv weight and bias, A_log, dt_bias, D, the
+        # gated norm's weight
+        per_layer += (d * c.ssm_in_dim + c.d_ssm * d
+                      + (c.ssm_conv + 1) * c.ssm_conv_dim + 3 * c.ssm_heads + c.d_ssm)
     total = c.vocab_size * d + c.n_layers * per_layer + d
     if not c.tie_embeddings:
         total += d * c.vocab_size
@@ -122,6 +164,8 @@ def init_params(
       embed[vocab, dim]
       layers/attn_{q,k,v,o}[L, ...], layers/mlp_{gate,up,down}[L, ...],
       layers/ln_attn[L, dim], layers/ln_mlp[L, dim]
+      layers/ssm_{in,out,conv_w,conv_b,A_log,dt_bias,D,norm}[L, ...] (with
+      ``ssm_heads``; the recurrence's own A_log, dt_bias, D stay float32)
       norm[dim], lm_head[dim, vocab] (absent when tie_embeddings)
 
     ``leaf_transform(name, array)`` is applied to each MATMUL weight at
@@ -171,6 +215,29 @@ def init_params(
                 "mlp_gate": rand_init("mlp_gate", keys[4], (L, D, F), D),
                 "mlp_up": rand_init("mlp_up", keys[5], (L, D, F), D),
                 "mlp_down": rand_init("mlp_down", keys[6], (L, F, D), F),
+            }
+        )
+    if c.ssm_heads:
+        # keys of their own, so that the other leaves are the ones a config
+        # without the mixer draws. The recurrence's parameters take Mamba-2's
+        # published initialisation: with plain normal draws the state would
+        # decay in one token or never, and nothing downstream would see it
+        ks = jax.random.split(jax.random.fold_in(k_layers, 1), 6)
+        Hs, Cc, K = c.ssm_heads, c.ssm_conv_dim, c.ssm_conv
+        dt = jnp.exp(jax.random.uniform(
+            ks[4], (L, Hs), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+        params["layers"].update(
+            {
+                "ssm_in": rand_init("ssm_in", ks[0], (L, D, c.ssm_in_dim), D),
+                "ssm_out": rand_init("ssm_out", ks[1], (L, c.d_ssm, D), c.d_ssm),
+                "ssm_conv_w": jax.random.uniform(
+                    ks[2], (L, K, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
+                "ssm_conv_b": jax.random.uniform(
+                    ks[3], (L, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
+                "ssm_A_log": jnp.log(jax.random.uniform(ks[5], (L, Hs), jnp.float32, 1.0, 16.0)),
+                "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
+                "ssm_D": jnp.ones((L, Hs), jnp.float32),
+                "ssm_norm": jnp.ones((L, c.d_ssm), c.dtype),
             }
         )
     if not c.tie_embeddings:
@@ -261,7 +328,9 @@ def _layer(
     tp_overlap: bool = False,
     tp_chunks: int = 4,
     qm_backend: str | None = None,
-) -> tuple[Array, Any]:
+    ssm_cache: Any = None,
+    ssm_rows: Any = None,
+) -> tuple[Array, Any] | tuple[Array, Any, Any]:
     """One decoder layer. Under GSPMD (the usual path) ``tp_axis`` is
     None — the compiler partitions from the param shardings. Under an
     ALL-MANUAL ``shard_map`` (the stage pipeline, parallel/pipeline.py)
@@ -270,16 +339,27 @@ def _layer(
     local, and the two row-parallel outputs all-reduce over ``tp_axis`` —
     serially, or with the chunked collective–compute overlap schedule
     (``tp_overlap``, ops/tp_overlap.py — byte-identical per element).
-    ``qm_backend`` routes quantized matmul leaves (ops/dispatch)."""
+    ``qm_backend`` routes quantized matmul leaves (ops/dispatch).
+
+    With ``config.ssm_heads`` the Mamba-2 mixer (models/ssm.py) reads the
+    same normed input as attention, its output joins attention's in the
+    residual, and the layer returns ``(x, cache, ssm_cache)``: the recurrent
+    state rides beside the KV cache (``ssm_rows`` says whose it is)."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
     hkv = c.n_kv_heads // tp_size
 
     h = rms_norm(x, layer_params["ln_attn"], c.norm_eps)
+    if c.ssm_heads:
+        assert tp_axis is None, "manual-TP stage blocks have no mixer"
+        mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
+                                 qm_backend=qm_backend)
     with jax.named_scope("attn_qkv"):
+        h = scaled(h, c.attention_in_multiplier)
         q = dense(h, layer_params["attn_q"], qm_backend=qm_backend).reshape(B, S, hq, c.head_dim)
-        k = dense(h, layer_params["attn_k"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
+        k = scaled(dense(h, layer_params["attn_k"], qm_backend=qm_backend),
+                   c.key_multiplier).reshape(B, S, hkv, c.head_dim)
         v = dense(h, layer_params["attn_v"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
         q = rope(q, positions, c.rope_theta)
         k = rope(k, positions, c.rope_theta)
@@ -297,7 +377,9 @@ def _layer(
         else:
             attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
                               qm_backend=qm_backend)
-        x = x + attn_proj
+        x = x + scaled(attn_proj, c.attention_out_multiplier)
+        if c.ssm_heads:
+            x = x + mixed
 
     h = rms_norm(x, layer_params["ln_mlp"], c.norm_eps)
     if c.n_experts:
@@ -307,7 +389,8 @@ def _layer(
             x = x + moe_out  # the residual add fuses into the down matmul
     else:
         with jax.named_scope("mlp"):
-            gate = dense(h, layer_params["mlp_gate"], qm_backend=qm_backend)
+            gate = scaled(dense(h, layer_params["mlp_gate"], qm_backend=qm_backend),
+                          c.mlp_multipliers[0])
             up = dense(h, layer_params["mlp_up"], qm_backend=qm_backend)
             act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
             if tp_axis is not None:
@@ -319,7 +402,9 @@ def _layer(
                 )
             else:
                 down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
-            x = x + down
+            x = x + scaled(down, c.mlp_multipliers[1])
+    if c.ssm_heads:
+        return x, new_layer_cache, ssm_cache
     return x, new_layer_cache
 
 
@@ -334,6 +419,8 @@ def forward(
     remat: bool = False,  # checkpoint each scanned layer (training)
     return_hidden: bool = False,  # post-norm hidden states, no LM head
     qm_backend: str | None = None,  # quantized-matmul backend (ops/dispatch)
+    ssm_cache: Any = None,  # (ssm_state, conv_state) of a model with a mixer
+    ssm_rows: Any = None,  # models/ssm.py SsmRows: whose state each row is
 ) -> tuple[Array, Any]:
     """Run the decoder; returns (logits[B,S,vocab] fp32, new_cache) — or
     (hidden[B,S,D], new_cache) with ``return_hidden``, for callers that
@@ -348,20 +435,32 @@ def forward(
     builders' July 2026 measurement, not reproduced since); carrying it
     lets the in-place Pallas writers (ops/kv_append.py) keep the buffer
     aliased end to end.
+
+    A model with a mixer (``config.ssm_heads``) carries its recurrent state
+    the same way: ``ssm_cache`` rides the carry beside ``cache`` and comes
+    back as ``new_cache = (cache, ssm_cache)``; without one (the cache-less
+    forward) every row starts from zero state and ``new_cache`` is as ever.
     """
     c = config
+    if c.ssm_heads and cache is not None and ssm_cache is None:
+        # a cached row continues from its recurrent state: a step that hands
+        # in none would silently run the mixer from zero
+        raise NotImplementedError(
+            "a forward over a KV cache needs the mixer's ssm_cache too "
+            f"(ssm_heads={c.ssm_heads}): this step does not carry it")
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]  # [B,S,D]
+        x = scaled(params["embed"][tokens], c.embedding_multiplier)  # [B,S,D]
 
     def scan_body(carry, scanned):
-        x, cache = carry
+        x, cache, ssm = carry
         layer_params, layer_idx = scanned
-        x, cache = _layer(
+        out = _layer(
             x, layer_params, cache, layer_idx,
             positions=positions, config=c, attention=attention,
-            qm_backend=qm_backend,
+            qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows,
         )
-        return (x, cache), None
+        # the layer returns its ssm cache only where it has a mixer
+        return (*out[:2], out[2] if c.ssm_heads else ssm), None
 
     if remat:
         # per-layer remat: backward recomputes one layer at a time, so live
@@ -369,7 +468,10 @@ def forward(
         scan_body = jax.checkpoint(scan_body)
 
     layer_ids = jnp.arange(c.n_layers)
-    (x, new_cache), _ = lax.scan(scan_body, (x, cache), (params["layers"], layer_ids))
+    (x, new_cache, ssm_cache), _ = lax.scan(
+        scan_body, (x, cache, ssm_cache), (params["layers"], layer_ids))
+    if ssm_cache is not None:
+        new_cache = (new_cache, ssm_cache)
 
     x = rms_norm(x, params["norm"], c.norm_eps)
     if return_hidden:
@@ -389,9 +491,11 @@ def lm_head(params: dict[str, Any], x: Array, *, config: LlamaConfig,
     if isinstance(head, (QTensor, Q4Tensor)):
         from finchat_tpu.ops.dispatch import quant_matmul
 
-        return quant_matmul(x, head, backend=qm_backend,
-                            preferred_element_type=jnp.float32)
-    return jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32)
+        return scaled(quant_matmul(x, head, backend=qm_backend,
+                                   preferred_element_type=jnp.float32),
+                      config.lm_head_multiplier)
+    return scaled(jnp.einsum("...d,dv->...v", x, head, preferred_element_type=jnp.float32),
+                  config.lm_head_multiplier)
 
 
 def make_causal_attention(backend: str) -> AttentionFn:

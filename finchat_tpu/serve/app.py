@@ -32,6 +32,7 @@ Behavior parity with the reference ``main.py``:
 from __future__ import annotations
 
 import asyncio
+import gc
 import json
 import time
 import uuid
@@ -359,6 +360,24 @@ def make_engine_replica(
     replica's session tier the fleet-shared one and lets its shared
     prompt heads restore from / publish to the cluster-wide store."""
     config, params, tokenizer, mesh = artifacts
+    if config.ssm_heads:
+        # what moves a row between engines moves its pages, never a mixer's
+        # recurrent state (session, handoff and pod wire formats hold none):
+        # refused by name rather than served from a state of zero (the
+        # scheduler refuses the warm fabric itself)
+        from finchat_tpu.serve.disagg import parse_roles
+
+        refused = {
+            "fleet.replicas": cfg.fleet.replicas > 1,
+            "fleet.roles": any(r != "mixed" for r in parse_roles(
+                cfg.fleet.roles, max(cfg.fleet.replicas, 1))),
+            "pod.host_id": bool(cfg.pod.host_id),
+        }
+        named = [option for option, on in refused.items() if on]
+        if named:
+            raise ValueError(
+                f"a model with a Mamba-2 mixer (ssm_heads={config.ssm_heads}) is served "
+                f"by one engine; not supported with it: {', '.join(named)}")
     metrics = METRICS.labeled(replica=replica_id) if replica_id is not None else None
     with TRACER.startup_phase("engine_init"):
         engine = InferenceEngine(config, params, cfg.engine, mesh=mesh,
@@ -554,15 +573,30 @@ class App:
             # after setup_consumer: the coordinator snapshots this host's
             # REAL partition assignment as its adoption baseline
             await self.pod.start()
+        # the tool grammar's vocabulary tables build in a worker thread from
+        # here on, not in front of the first batch's tool decisions
+        prepare = getattr(getattr(self.agent, "tool_generator", None),
+                          "prepare_grammar", None)
+        if prepare is not None:
+            prepare("tool_call")
         self._running = True
         self._consume_task = asyncio.create_task(self.consume_messages())
         if self._prefix_cache_enabled:
             self._prefix_refresh_task = asyncio.create_task(_prefix_refresh_loop(self))
         if serve_http:
             await self.server.start()
+        # What start-up built (weights' trees, compiled programs, the
+        # tokenizer's tables: 0.8 M container objects) lives as long as the
+        # process. Left in the collector's oldest generation it is walked
+        # whole by every full collection, at a moment of the collector's
+        # choosing: 0.4 s with every stream waiting, about 17 s after a
+        # batch arrives (PERF.md §6, PR 27). Frozen, a full collection
+        # walks what requests made since (0.04 s).
+        gc.freeze()
 
     async def stop(self) -> None:
         self._running = False
+        gc.unfreeze()
         if self._prefix_refresh_task:
             self._prefix_refresh_task.cancel()
             try:

@@ -134,6 +134,8 @@ ROUND_PHASES = ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
 DEVICE_SCOPES = frozenset({
     "embed", "norm", "attn_qkv", "attn_o", "mlp", "moe_router",
     "moe_experts", "head", "sample",
+    # the Mamba-2 mixer beside attention (models/ssm.py)
+    "ssm_in", "ssm_conv", "ssm_scan", "ssm_norm", "ssm_out",
     # the attention callbacks (engine/engine.py)
     "kv_append", "kv_scatter", "paged_attention",
     "kv_scatter_ragged", "ragged_paged_attention",
@@ -540,6 +542,9 @@ class RequestSpan:
     finished: bool = False
     prompt_tokens: int = 0
     cached_tokens: int = 0
+    # of the cached tokens, those a mixer's recurrent state was restored
+    # over from a snapshot (0 for a model without a mixer)
+    state_restored_tokens: int = 0
 
     def mark(self, name: str) -> None:
         now = time.perf_counter()
@@ -571,6 +576,7 @@ class RequestSpan:
                          args={"request_id": self.request_id, "reason": reason,
                                "prompt_tokens": self.prompt_tokens,
                                "cached_tokens": self.cached_tokens,
+                               "state_restored_tokens": self.state_restored_tokens,
                                "generated": generated,
                                "queue_wait_s": self.marks.get("admitted")})
         logger.debug(

@@ -294,3 +294,76 @@ def test_plot_call_parses_with_validation():
     # bad chart type degrades to the default, never an error
     call = parse_tool_decision('create_financial_plot({"chart_type": "donut"})')
     assert call.args["chart_type"] == "bar"
+
+
+def _pick_by_the_whole_vocabulary(c, logits, temperature, rng, remaining, top_p=1.0, top_k=0):
+    """The pick as it was written before it was cut to the allowed ids: every
+    pass over the whole vocabulary, ``rng.choice`` for the draw."""
+    allowed, eos_ok, ends = c.vocab.mask(c.state)
+    logits = logits[: allowed.shape[0]]
+    feasible = allowed & (c.vocab._distance_np[ends] <= remaining - 2)
+    if not (feasible.any() or eos_ok):
+        return c.vocab.eos_id
+    allowed = feasible.copy()
+    if eos_ok:
+        allowed[c.vocab.eos_id] = True
+    if not allowed.any():
+        return c.vocab.eos_id
+    masked = np.where(allowed, logits.astype(np.float64), -np.inf)
+    if temperature <= 0.0:
+        return int(masked.argmax())
+    z = masked / temperature
+    if top_k and top_k > 0:
+        z = np.where(z < np.partition(z, -top_k)[-top_k], -np.inf, z)
+    if top_p < 1.0:
+        order = np.argsort(-z)
+        probs = np.exp(z[order] - z.max())
+        probs /= probs.sum()
+        keep = (np.cumsum(probs) - probs) < top_p
+        keep[0] = True
+        z[order[~keep]] = -np.inf
+    p = np.exp(z - z.max())
+    return int(rng.choice(len(p), p=p / p.sum()))
+
+
+@pytest.mark.parametrize("temperature,top_p,top_k", [
+    (0.0, 1.0, 0), (0.5, 1.0, 0), (1.0, 0.9, 0), (1.0, 1.0, 5), (0.7, 0.8, 40)])
+def test_pick_over_the_allowed_ids_is_the_pick_over_the_vocabulary(temperature, top_p, top_k):
+    """Cut to the allowed ids and drawn in two levels, a pick is the token the
+    whole-vocabulary pick gives from the same generator, state by state along
+    a whole decision, under a head wider than the tokenizer too."""
+    tok = ByteTokenizer()
+    vocab = GrammarVocab.for_tokenizer(tok)
+    for seed in range(4):
+        data = np.random.default_rng(100 + seed)
+        rng_new, rng_old = np.random.default_rng(seed), np.random.default_rng(seed)
+        c = TokenConstraint(vocab)
+        for step in range(96):
+            logits = np.asarray(data.normal(size=(tok.vocab_size + 40,)) * 3, np.float32)
+            want = _pick_by_the_whole_vocabulary(
+                c, logits, temperature, rng_old, 96 - step, top_p, top_k)
+            got = c.pick(logits, temperature, rng_new, remaining=96 - step,
+                         top_p=top_p, top_k=top_k)
+            assert got == want, (seed, step)
+            if got == tok.eos_id:
+                break
+        else:
+            pytest.fail("did not terminate within budget")
+
+
+@pytest.mark.parametrize("size", [1, 7, 512, 513, 5000])
+def test_draw_is_the_index_choice_draws(size):
+    from finchat_tpu.agent.constrained import _draw
+
+    rng = np.random.default_rng(size)
+    for _ in range(50):
+        w = rng.random(size) * (rng.random(size) < 0.6)  # zero weights among them
+        if not w.any():
+            w[size // 2] = 1.0
+        u = rng.random()
+        cdf = np.cumsum(w)
+        want = int((cdf / cdf[-1]).searchsorted(u, side="right"))
+        got = _draw(w, u)
+        assert w[got] > 0.0
+        assert got == want
+    assert _draw(np.asarray([0.0, 2.0, 0.0]), 0.999999) == 1

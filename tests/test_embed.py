@@ -81,3 +81,59 @@ def test_index_growth_past_capacity():
 def test_index_empty():
     index = DeviceVectorIndex(dim=4)
     assert index.query_points(np.zeros(4, np.float32), limit=5, user_id="u") == []
+
+
+@pytest.mark.parametrize("n_texts,rows", [(1, 1), (3, 4), (5, 8)])
+def test_batch_is_padded_to_a_power_of_two(encoder, n_texts, rows):
+    """Coalesced queries arrive in any count: the encoder sees a power-of-two
+    batch (one program a bucket), and each text's embedding is what it is
+    alone."""
+    from finchat_tpu.embed import encoder as enc
+
+    seen = []
+    real = enc.encode_batch
+
+    def spy(params, tokens, lengths, **kw):
+        seen.append((tokens.shape, lengths.shape))
+        return real(params, tokens, lengths, **kw)
+
+    texts = [f"payment number {i} at the corner shop" for i in range(n_texts)]
+    enc.encode_batch = spy
+    try:
+        out = encoder.embed_batch(texts)
+    finally:
+        enc.encode_batch = real
+    assert seen == [((rows, 64), (rows,))]
+    assert out.shape == (n_texts, encoder.dim)
+    for i, text in enumerate(texts):
+        np.testing.assert_allclose(out[i], encoder.embed_query(text), atol=2e-2)
+
+
+@pytest.mark.parametrize("limit", [1, 3, 5, 6, 40])
+def test_index_limit_is_cut_from_a_power_of_two_k(limit):
+    """The compiled top-k takes k as a power of two (few programs whatever a
+    model asks for); the host cuts each answer to its own limit, on both
+    query planes."""
+    from finchat_tpu.embed import index as ix
+    from finchat_tpu.embed.index import QuerySpec
+
+    idx = DeviceVectorIndex(dim=4, initial_capacity=16)
+    rng = np.random.default_rng(0)
+    vecs = rng.normal(size=(12, 4))
+    idx.upsert([_point("u1", 1000 + i, f"row {i:02d}", vecs[i]) for i in range(12)])
+    q = rng.normal(size=(4,))
+    want = [p.payload["page_content"] for p in idx.query_points(q, limit=12, user_id="u1")][:limit]
+    ks = []
+    real, real_batch = ix._topk_scores, ix._topk_scores_batch
+    ix._topk_scores = lambda *a, k: (ks.append(k), real(*a, k=k))[1]
+    ix._topk_scores_batch = lambda *a, k: (ks.append(k), real_batch(*a, k=k))[1]
+    try:
+        serial = idx.query_points(q, limit=limit, user_id="u1")
+        batch = idx.query_points_batch([
+            QuerySpec(q, limit=limit, user_id="u1"), QuerySpec(q, limit=2, user_id="u1")])
+    finally:
+        ix._topk_scores, ix._topk_scores_batch = real, real_batch
+    assert [p.payload["page_content"] for p in serial] == want
+    assert [p.payload["page_content"] for p in batch[0]] == want
+    assert len(batch[1]) == 2
+    assert all(k & (k - 1) == 0 and k <= 16 for k in ks) and len(ks) == 2

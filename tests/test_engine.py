@@ -120,3 +120,41 @@ def test_slot_reuse_after_reset(params):
     alloc.check_invariants()
     second = engine_greedy(eng, alloc, 0, [9, 8, 7, 6], 5, seq_id="two")
     assert first == second
+
+
+@pytest.mark.parametrize("n_rows", [1, 2, 3, 4])
+def test_batched_slot_updates_have_one_shape_whatever_the_count(params, n_rows):
+    """The eager updates between steps are padded to ``max_seqs`` rows (the
+    last repeated): the result is the plain update's, and every count traces
+    to the shape warm-up ran, so no count compiles on the scheduler's loop."""
+    eng = make_engine(params)
+    B = ENGINE_CFG.max_seqs
+    rows = {slot: [3 + slot, 9 + slot][: 1 + slot % 2] for slot in range(n_rows)}
+    eng.set_page_table_rows(rows)
+    eng.set_context_lens_rows({slot: 5 + slot for slot in rows})
+    eng.set_kv_gap_rows({slot: slot for slot in rows})
+    table = jax.device_get(eng.state.page_table)
+    for slot in range(B):
+        want = rows.get(slot, [])
+        assert table[slot, : len(want)].tolist() == want
+        assert not table[slot, len(want):].any()
+    assert jax.device_get(eng.state.context_lens).tolist() == [
+        5 + s if s in rows else 0 for s in range(B)]
+    assert jax.device_get(eng.state.kv_gaps).tolist() == [
+        s if s in rows else 0 for s in range(B)]
+    assert eng._slot_rows(rows).shape == (B,)
+    eng.reset_slots(list(rows)[:-1])
+    kept = list(rows)[-1]
+    assert jax.device_get(eng.state.context_lens).tolist() == [
+        5 + s if s == kept else 0 for s in range(B)]
+    eng.set_page_table_rows({})  # nothing to write, nothing raised
+    eng.reset_slots([])
+
+
+@pytest.mark.parametrize("rows,width", [([2], 1), ([3, 0], 2), ([1, 2, 3], 4), ([0, 1, 2, 3], 4)])
+def test_logits_rows_pads_the_count_to_a_power_of_two(params, rows, width):
+    eng = make_engine(params)
+    logits = jnp.arange(ENGINE_CFG.max_seqs * 6, dtype=jnp.float32).reshape(-1, 6)
+    got = eng.logits_rows(logits, rows)
+    assert got.shape == (width, 6)
+    assert jnp.array_equal(got[: len(rows)], logits[jnp.asarray(rows)])

@@ -170,7 +170,7 @@ def test_every_loop_branch_leaves_rounds_that_add_up(branch):
         args = ev[5]
         assert args["kind"] == kind_seen
         assert args["rows"] == [[slot, tid, mode] for slot, tid, mode, _kv in riders]
-        assert noted == {"kind": kind_seen,
+        assert noted == {"kind": kind_seen, "rows": len(riders),
                          "kv_tokens": sum(kv for *_row, kv in riders)}
         assert all(kv >= 1 for *_row, kv in riders), riders
     # tracing on or off, the streams are the same tokens
@@ -477,7 +477,18 @@ def test_r5_leaves_a_registry_the_tracing_module_lacks_unchecked(tmp_path):
 
 # --- start-up ----------------------------------------------------------------
 
-def test_startup_phases_set_their_gauge_and_leave_an_event():
+@pytest.fixture
+def startup_gauges_restored():
+    """The gauges are the process's own, and the benchmark's start-up readers
+    (tests/perfbench) read them in whichever test shares this worker."""
+    phases = [{"phase": "warmup"}, {"phase": "heads"}]
+    before = [METRICS.get("finchat_startup_seconds", labels=p) for p in phases]
+    yield
+    for p, seconds in zip(phases, before):
+        METRICS.set_gauge("finchat_startup_seconds", seconds, labels=p)  # finchat-lint: disable=metrics-discipline -- putting back what the test found
+
+
+def test_startup_phases_set_their_gauge_and_leave_an_event(startup_gauges_restored):
     labels = {"phase": "warmup"}
     before = METRICS.get("finchat_startup_seconds", labels=labels)
     TRACER.startup("warmup", 1.5)

@@ -65,3 +65,58 @@ def test_paged_attention_compiles_for_v5e_at_the_cell_shape(one_chip, C, quantiz
     calls = [line.split(" = ")[0].strip() for line in compiled.as_text().splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert calls and all(name.startswith("%paged_flash_attention") for name in calls)
+
+
+# falcon-h1-34b-instruct as perfbench/configs has it: 20 / 4 heads of 128 (5
+# query heads a KV head, pages 512 lanes wide), the same pool and table
+@pytest.mark.parametrize("C", [1, 256], ids=["decode", "prefill"])
+def test_paged_attention_compiles_for_v5e_at_falcon_h1s_head_counts(one_chip, C):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    heads, kv_heads, layers = 20, 4, 5
+    pages = shape((layers, POOL, PAGE, kv_heads * HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda *args: paged_flash_attention(*args, page_size=PAGE, n_kv=kv_heads)
+    ).lower(
+        shape((ROWS, C, heads, HEAD_DIM), jnp.bfloat16), pages, pages,
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32),
+        shape((ROWS,), jnp.int32), shape((1,), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip):
+    """The whole decode step at the cell's size (5 layers, 16 slots, the
+    1,600-page pool, the compiled kernels): the K/V pool AND the recurrent
+    state [5, 16, 32, 128, 256] float32 are donated and updated in place —
+    a copy of the state alone would be 0.34 GB of temporaries a step."""
+    import json
+    from pathlib import Path
+
+    from finchat_tpu.engine import engine as E
+    from finchat_tpu.models.llama import init_params
+    from finchat_tpu.utils.config import EngineConfig
+    from perfbench.models import falcon_h1
+
+    file = json.loads((Path(__file__).resolve().parents[1]
+                       / "perfbench/configs/falcon-h1-34b-instruct.json").read_text())
+    c = falcon_h1.program_config(dict(file, num_hidden_layers=5))
+    cfg = EngineConfig(**file["engine"])
+
+    def described(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(c, jax.random.key(0))))
+    state = described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH)))
+    row = lambda dtype: jax.ShapeDtypeStruct((ROWS,), dtype, sharding=one_chip)  # noqa: E731
+    compiled = E.decode_step.lower(
+        params, state, row(bool), row(jnp.float32), row(jnp.float32), row(jnp.int32),
+        config=c, page_size=PAGE, attn_backend="pallas", qm_backend="ref").compile()
+    memory = compiled.memory_analysis()
+    state_bytes = 5 * ROWS * 32 * 128 * 256 * 4
+    assert state.ssm_state.shape == (5, ROWS, 32, 128, 256)
+    assert memory.alias_size_in_bytes >= state_bytes + 2 * 5 * POOL * PAGE * 512 * 2
+    assert memory.temp_size_in_bytes < state_bytes // 4
+    assert "ssm_scan" in compiled.as_text()
