@@ -18,7 +18,9 @@ Phases, each fatal on its first failure (nothing is downgraded to a warning):
 2. kernels: the default path's Pallas kernels (paged attention for decode and
    for a prefill chunk, the in-place KV append, ragged paged attention, flash
    attention), Mosaic-compiled at the model's widths, against their
-   ``jax.numpy`` oracles;
+   ``jax.numpy`` oracles; paged decode attention and the one-token Mamba-2
+   state update also at the shapes of the benchmark's cells, the latter with
+   its own device time against its stream bound;
 3. start-up: ``build_app`` (warm-up compiles every serving variant); the
    engine must have resolved the compiled ``pallas`` backend;
 4. logits: one prefill → decode comparison, ``pallas`` engine vs ``ref``
@@ -296,6 +298,92 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
         "paged_attention[decode, cell shape]", got, want,
         f"{rows} rows, {int(ctx.sum())} context tokens, {int(live.sum())} live "
         f"of {rows * width} table entries; ")
+
+
+def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 32,
+                                 head_dim: int = 128, state: int = 256,
+                                 groups: int = 2, layers: int = 5, layer: int = 3,
+                                 timed_calls: int = 20) -> dict[str, float]:
+    """The one-token Mamba-2 state update (``ops/ssm_step.py``) vs
+    ``models/ssm.py``'s ``_step`` at the shape of the benchmark's cell
+    (``falcon-h1-report-saturated``): 16 rows of 32 heads of 128 x 256 float32
+    in layer 3 of 5, one row inert. ``y`` and the layer's new state to float32
+    round-off; the inert row and every other layer bit for bit. On the chip
+    (``pallas``) also the kernel's own device time from a profiler capture,
+    against its stream bound (every row's state read and written once at
+    819 GB/s: what ``ssm_state_roofline.sat`` divides by) — the number to
+    tune ``ops/ssm_step.py``'s block size by."""
+    import tempfile
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.models.ssm import _step
+    from finchat_tpu.ops.ssm_step import ssm_state_step
+
+    f32, hg = jnp.float32, heads // groups
+    ks = jax.random.split(jax.random.key(28), 7)
+    ssm_state = jax.random.normal(ks[0], (layers, rows, heads, head_dim, state), f32)
+    xs = jax.random.normal(ks[1], (rows, heads, head_dim), f32)
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (rows, heads), f32)).at[1].set(0.0)
+    A = -jnp.exp(jax.random.normal(ks[3], (heads,), f32))
+    Bm = jax.random.normal(ks[4], (rows, groups, state), f32)
+    Cm = jax.random.normal(ks[5], (rows, groups, state), f32)
+    D = jax.random.normal(ks[6], (heads,), f32)
+    at = jnp.asarray([layer], jnp.int32)
+
+    want_y, want_new = jax.jit(_step)(
+        ssm_state[layer].reshape(rows, groups, hg, head_dim, state),
+        xs.reshape(rows, groups, hg, head_dim), dt.reshape(rows, groups, hg),
+        A.reshape(groups, hg), Bm, Cm, D.reshape(groups, hg))
+    before = np.asarray(ssm_state)
+    y, ssm_state = ssm_state_step(ssm_state, xs, dt, A, Bm, Cm, D, at,
+                                  interpret=backend == "pallas-interpret")
+    after = np.asarray(ssm_state)
+    errors = {}
+    for name, got, want in (("y", y, want_y.reshape(y.shape)),
+                            ("state", after[layer], np.asarray(want_new).reshape(after[layer].shape))):
+        got, want = np.asarray(got), np.asarray(want)
+        require(np.isfinite(got).all(), f"kernel ssm_state_step: non-finite {name}")
+        errors[name] = float(np.abs(got - want).max())
+        # y sums 256 products of O(1) numbers in another order than _step
+        require(np.allclose(got, want, rtol=1e-5, atol=1e-4),
+                f"kernel ssm_state_step: {name} off _step (max abs err {errors[name]:.3g})")
+    untouched = [i for i in range(layers) if i != layer]
+    require(np.array_equal(after[untouched], before[untouched]),
+            "kernel ssm_state_step: a layer the grid does not visit changed")
+    require(np.array_equal(after[layer, 1], before[layer, 1]),
+            "kernel ssm_state_step: the inert row's state changed")
+    say(f"kernel ssm_state_step: ok (layer {layer} of {layers}, {rows} rows; max abs "
+        f"err y {errors['y']:.3g}, state {errors['state']:.3g})")
+    if backend != "pallas":
+        return errors
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(timed_calls):
+            y, ssm_state = ssm_state_step(ssm_state, xs, dt, A, Bm, Cm, D, at)
+        y.block_until_ready()
+        jax.profiler.stop_trace()
+        path = next(Path(trace_dir).rglob("*.xplane.pb"))
+        ops = [(ev.name.split(" = ")[0], ev.duration_ns / 1e3)
+               for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+               if plane.name.startswith("/device:TPU:")
+               for line in plane.lines if line.name == "XLA Ops" for ev in line.events]
+    kernel = [us for name, us in ops if "ssm_state_step" in name]
+    require(len(kernel) == timed_calls,
+            f"kernel ssm_state_step: {len(kernel)} custom calls in a capture of {timed_calls}")
+    small = (2 * heads * head_dim + 2 * groups * state + heads) * 4
+    bound_us = 1e6 * rows * (2 * heads * head_dim * state * 4 + small) / 819e9
+    errors.update(kernel_us=float(np.mean(kernel)),
+                  call_us=sum(us for _name, us in ops) / timed_calls, bound_us=bound_us)
+    say(f"kernel ssm_state_step: {errors['kernel_us']:.1f} us a call (min "
+        f"{min(kernel):.1f}, max {max(kernel):.1f}), {errors['call_us']:.1f} us with the "
+        f"operations around it; stream bound {bound_us:.1f} us: "
+        f"{100 * bound_us / errors['call_us']:.1f} % of it")
+    return errors
 
 
 # --- phase 4: engine logits, compiled kernels vs the reference backend ------
@@ -672,6 +760,7 @@ def _run(mesh_model: int) -> int:
                   cfg.engine.page_size, "pallas",
                   prefill_chunk=cfg.engine.prefill_chunk)
     check_decode_at_cell_shape("pallas")
+    check_ssm_step_at_cell_shape("pallas")
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half
     parity_cfg = dataclasses.replace(

@@ -295,7 +295,8 @@ def prefill_step(
     # state there, so a prompt's chunks carry it from round to round
     hidden, state = _forward_cached(
         params, state, tokens, positions,
-        config=config, attention=attention, ssm_rows=SsmRows(slots, n_valid),
+        config=config, attention=attention,
+        ssm_rows=SsmRows(slots, n_valid, backend=attn_backend),
         return_hidden=True, qm_backend=qm_backend,
     )
     last_hidden = jnp.take_along_axis(
@@ -587,10 +588,12 @@ def decode_step(
         page_size, config.n_kv_heads, attn_backend,
     )
     # a mixer's state advances one token in every active slot, in place
-    # (row i IS slot i: a slice and an update, no gather)
+    # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
+    # pass over the layer's state, on `ref` a slice, _step and an update)
     logits, state = _forward_cached(
         params, state, tokens, positions,
-        config=config, attention=attention, ssm_rows=SsmRows(None, n_valid),
+        config=config, attention=attention,
+        ssm_rows=SsmRows(None, n_valid, backend=attn_backend),
         qm_backend=qm_backend,
     )
     step_logits = logits[:, 0, :]  # [B, vocab]
@@ -758,7 +761,7 @@ def _ragged_round_math(
     # (a dead or padding row rides inert), and its last state goes back there
     ssm_rows = SsmRows(
         row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
-        width=min(T, max_row_tokens or T),
+        width=min(T, max_row_tokens or T), backend=attn_backend,
     ) if config.ssm_heads else None
     hidden, state = _forward_cached(
         params, state, tok_in[None], tok_pos[None],

@@ -609,6 +609,7 @@ class ContinuousBatchingScheduler:
                                  labels={"kind": kind})
             self.metrics.inc("finchat_ssm_snapshot_restores_total", 0.0)
             self.metrics.inc("finchat_ssm_recompute_fallbacks_total", 0.0)
+            self.metrics.inc("finchat_ssm_step_fallbacks_total", 0.0)
         # disaggregated serving (serve/disagg.py — ISSUE 17): the fleet
         # attaches its DisaggCoordinator to SERVING-pool schedulers only;
         # submit routes cold prompt prefills through it when set

@@ -8,8 +8,10 @@ Per head ``h`` of ``H`` (B/C group ``g = h // (H / G)``), state ``S`` in
     S_t = exp(dt_t A) S_{t-1} + dt_t xs_t (x) B_t^(g)
     y_t = S_t C_t^(g) + D xs_t
 
-A single token is that recurrence as written (``_step``); a chunk of tokens
-is the same recurrence in the state-space-duality form (``_chunked``):
+A single token is that recurrence as written (``_step``; the decode step's
+whole slot batch on a kernel backend: ``ops/ssm_step.py``, the same update in
+one in-place pass over the state); a chunk of tokens is the same recurrence
+in the state-space-duality form (``_chunked``):
 matmuls inside blocks of ``ssm_chunk`` tokens, the state passed from block to
 block. Padding tokens ride with ``dt = 0``: the state passes through them
 unchanged, so the state a row leaves is the state after its last REAL token,
@@ -35,6 +37,8 @@ import numpy as np
 from jax import Array, lax
 
 from finchat_tpu.models.quant import dense
+from finchat_tpu.ops.ssm_step import ssm_state_step
+from finchat_tpu.utils.metrics import METRICS
 
 _HIGHEST = lax.Precision.HIGHEST
 
@@ -44,19 +48,24 @@ class SsmRows:
     """How a dispatch's batch rows map onto the engine's slots.
 
     ``slots`` [N]: the slot each row's state is read from and written to;
-    None = row i IS slot i (the decode step's whole slot batch: a slice and
-    an in-place update instead of a gather and a scatter). ``n_valid`` [N]:
+    None = row i IS slot i (the decode step's whole slot batch: no gather
+    and no scatter — the conv's tail is sliced and updated in place, and the
+    recurrent state advances inside the carried array: ``ops/ssm_step.py``'s
+    kernel, or on ``ref`` a slice, ``_step`` and an in-place update).
+    ``n_valid`` [N]:
     the row's real tokens; a row with 0 rides inert (state and tail
     untouched, whatever its slot — padding rows repeat a live row's slot).
     ``pack``: the tokens arrive as ONE packed buffer ``[1, T]`` (the ragged
     step) and are regrouped to ``[N, width]`` rows for the conv and the scan
     — neither may run across a row boundary — ``(q_start [N], tok_row [T],
-    tok_off [T])``."""
+    tok_off [T])``. ``backend``: the kernel backend the engine resolved for
+    its step (``attn_backend``: ``pallas``, ``pallas-interpret`` or ``ref``)."""
 
     slots: Array | None
     n_valid: Array
     pack: tuple[Array, Array, Array] | None = None
     width: int = 0
+    backend: str = "ref"
 
 
 def scaled(x: Array, m: float) -> Array:
@@ -122,8 +131,10 @@ def causal_conv(x: Array, tail: Array, n_valid: Array, w: Array, b: Array
 
 def _step(state, xs, dt, A, Bm, Cm, D):
     """One token. state [N,G,Hg,P,Ns]; xs [N,G,Hg,P]; dt [N,G,Hg] (0 = inert);
-    Bm, Cm [N,G,Ns]. ``y`` is written over the OLD state so that the state is
-    read once: S_t C = exp(dt A) (S_{t-1} C) + dt (B.C) xs."""
+    Bm, Cm [N,G,Ns]. ``y`` is written over the OLD state, S_t C = exp(dt A)
+    (S_{t-1} C) + dt (B.C) xs, so that the state need be read only once; XLA
+    reads it twice all the same (two fusions), which is why the decode step
+    of a kernel backend takes ``ops/ssm_step.py`` instead."""
     dA = jnp.exp(dt * A)
     Bb, Cb = Bm[:, :, None, None, :], Cm[:, :, None, None, :]
     new = state * dA[..., None, None] + (dt[..., None] * xs)[..., None] * Bb
@@ -211,26 +222,36 @@ def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
         if cache is not None:
             conv_state = _write(cache[1], tail, layer_idx, rows)
     with jax.named_scope("ssm_scan"):
-        if cache is not None:
-            state = _read(cache[0], layer_idx, rows)
         xs, Bm, Cm = jnp.split(xbc, [c.d_ssm, c.d_ssm + gn], axis=-1)
         live = jnp.arange(S, dtype=jnp.int32)[None, :] < rows.n_valid[:, None]
         dt = jnp.where(live[..., None], jax.nn.softplus(dt + lp["ssm_dt_bias"]), 0.0)
-        A = -jnp.exp(lp["ssm_A_log"]).reshape(G, Hg)
-        D = lp["ssm_D"].reshape(G, Hg)
-        xs = xs.reshape(n, S, G, Hg, P)
-        dt = dt.reshape(n, S, G, Hg)
+        A = -jnp.exp(lp["ssm_A_log"])
         Bm, Cm = Bm.reshape(n, S, G, Ns), Cm.reshape(n, S, G, Ns)
-        state = state.reshape(n, G, Hg, P, Ns)
-        if S == 1:
-            y, state = _step(state, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D)
-            y = y[:, None]
+        one_token = S == 1 and cache is not None and rows.backend != "ref"
+        if one_token and rows.slots is None:
+            # the decode step: every slot's state advances where it lies
+            y, state = ssm_state_step(
+                cache[0], xs.reshape(n, H, P), dt[:, 0], A, Bm[:, 0], Cm[:, 0],
+                lp["ssm_D"], layer_idx.reshape(1),
+                interpret=rows.backend == "pallas-interpret")
+            cache = (state, conv_state)
         else:
-            y, state = _chunked(state, xs, dt, A, Bm, Cm, D, c.ssm_chunk)
+            if one_token:  # gathered slots: XLA's two passes over the state
+                METRICS.inc("finchat_ssm_step_fallbacks_total")
+            if cache is not None:
+                state = _read(cache[0], layer_idx, rows)
+            A, D = A.reshape(G, Hg), lp["ssm_D"].reshape(G, Hg)
+            xs = xs.reshape(n, S, G, Hg, P)
+            dt = dt.reshape(n, S, G, Hg)
+            state = state.reshape(n, G, Hg, P, Ns)
+            if S == 1:
+                y, state = _step(state, xs[:, 0], dt[:, 0], A, Bm[:, 0], Cm[:, 0], D)
+            else:
+                y, state = _chunked(state, xs, dt, A, Bm, Cm, D, c.ssm_chunk)
+            if cache is not None:
+                cache = (_write(cache[0], state.reshape(n, H, P, Ns), layer_idx, rows),
+                         conv_state)
         y = y.reshape(n, S, c.d_ssm)
-        if cache is not None:
-            cache = (_write(cache[0], state.reshape(n, H, P, Ns), layer_idx, rows),
-                     conv_state)
     if packed:
         y = _to_packed(y, rows)[None]
     with jax.named_scope("ssm_norm"):

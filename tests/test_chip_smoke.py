@@ -47,6 +47,15 @@ def test_decode_at_cell_shape_interpret():
     assert error < chip_smoke.KERNEL_ATOL + chip_smoke.KERNEL_RTOL * 4
 
 
+def test_ssm_step_at_cell_shape_interpret():
+    """The state update's check at a small size (values only: a time comes
+    from the chip): layer 1 of 3, one row inert."""
+    errors = chip_smoke.check_ssm_step_at_cell_shape(
+        "pallas-interpret", rows=4, heads=4, head_dim=16, state=128, groups=2,
+        layers=3, layer=1)
+    assert set(errors) == {"y", "state"} and errors["y"] < 1e-4
+
+
 @pytest.mark.no_stall_sanitizer
 def test_serving_function_at_tiny_interpret(monkeypatch):
     """The whole serving phase — start-up, logits parity, HTTP, Kafka, the

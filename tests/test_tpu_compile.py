@@ -8,6 +8,8 @@ All such compiles live in this one file: only one process may hold the TPU
 library, and the worker that is given this file is that process.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -90,7 +92,11 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
     """The whole decode step at the cell's size (5 layers, 16 slots, the
     1,600-page pool, the compiled kernels): the K/V pool AND the recurrent
     state [5, 16, 32, 128, 256] float32 are donated and updated in place —
-    a copy of the state alone would be 0.34 GB of temporaries a step."""
+    a copy of the state alone would be 0.34 GB of temporaries a step. The
+    state's one-token update is ``ops/ssm_step.py``'s kernel (Mosaic's layout
+    rules for its [rows, 32, 128, 256] float32 blocks are checked by this
+    compile), and it is the ONLY operation under ``ssm_scan`` that touches
+    the carried state: XLA's own two fusions both read it."""
     import json
     from pathlib import Path
 
@@ -119,4 +125,20 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
     assert state.ssm_state.shape == (5, ROWS, 32, 128, 256)
     assert memory.alias_size_in_bytes >= state_bytes + 2 * 5 * POOL * PAGE * 512 * 2
     assert memory.temp_size_in_bytes < state_bytes // 4
-    assert "ssm_scan" in compiled.as_text()
+    text = compiled.as_text()
+    scan = [line for line in text.splitlines()
+            if "/ssm_scan/" in line and " = " in line and "op_name=" in line]
+    kernels = [line for line in scan if 'custom_call_target="tpu_custom_call"' in line]
+    # the benchmark's readers find the update by its scope (ssm_state_roofline.sat,
+    # ssm_share.sat), and attn_share.sat must not: it takes custom calls by name
+    assert len(kernels) == 1 and "ssm_state_step" in kernels[0].split(" = ")[0]
+    assert "attention" not in kernels[0].split(" = ")[0]
+    # operands are printed by name: look each one's type up where it is defined
+    types = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.-]+) = (\S+)", text, re.M))
+    carried = "f32[5,16,32,128,256]"
+    readers = [name for line in scan if " fusion(" in line
+               for name, operands in [re.match(r"\s*(?:ROOT )?(%[\w.-]+) = .*? fusion\(([^)]*)\)",
+                                               line).groups()]
+               if any(types.get(operand.strip(), "").startswith(carried)
+                      for operand in [name, *operands.split(",")])]
+    assert readers == [], readers
