@@ -19,7 +19,8 @@ calls is printed beside them. Runs on the chip only:
     chiprun -- python3 benchmarks/paged_attention_alone.py --tree <checkout>
 
 ``--tree`` times the kernel of another checkout (the parent commit unpacked
-somewhere under the repo) with this script's inputs.
+somewhere under the repo) with this script's inputs. It stays because ROADMAP
+S13 (a) needs the kernel alone on the chip at 5 query heads a KV head.
 """
 
 from __future__ import annotations

@@ -3,8 +3,8 @@
 VERDICT r4 weak #5: the prompt-lookup bet (engine/spec.py) is that the
 reference's workload — retrieved transaction rows stuffed into the
 prompt (``qdrant_tool.py:145``, ``llm_agent.py:234-236``) with answers
-that quote them back — makes n-gram drafts land. The headline bench
-can't measure that (random-weight models don't quote), so this harness
+that quote them back — makes n-gram drafts land. No run on seeded random
+weights can measure that (such models don't quote), so this harness
 replays the EXACT verify-step semantics the scheduler runs
 (greedy-exact: accepted prefix + one bonus token per step, miss → 1
 token) against scripted answer streams shaped like the product's:
@@ -20,7 +20,8 @@ speedup:
 
     speedup = (tokens/step) / verify_cost_ratio
 
-Prints one JSON line (bench.py contract). Pure host: runs anywhere.
+Prints one JSON line. Pure host: runs anywhere. It stays because
+tests/test_spec_decode.py imports ``replay_stream`` from it (ROADMAP D7).
 """
 
 from __future__ import annotations

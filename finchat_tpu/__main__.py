@@ -38,8 +38,8 @@ def main() -> None:
     p.add_argument("--no-http", action="store_true", help="Kafka worker loop only")
     p.add_argument("--decode-loop-depth", type=int, default=None,
                    help="tokens per fused decode dispatch (engine "
-                        "decode_loop_step); 1 = per-token decode, bench at "
-                        "4/8 — also FINCHAT_DECODE_LOOP_DEPTH")
+                        "decode_loop_step); 1 = per-token decode "
+                        "— also FINCHAT_DECODE_LOOP_DEPTH")
     p.add_argument("--session-cache-bytes", type=int, default=None,
                    help="host-RAM byte budget for the session KV cache "
                         "(engine/session_cache.py); 0 disables cross-turn "

@@ -41,7 +41,7 @@ tracing module in scope, the span checks are skipped.
 Emission sites are found by shape, not receiver type: a call to
 ``inc`` / ``set_gauge`` / ``observe`` whose first argument is a string
 literal (or a conditional between string literals), or a ``Timer(...,
-"name")`` construction. Sites outside ``finchat_tpu/`` (tests, bench
+"name")`` construction. Sites outside ``finchat_tpu/`` (tests,
 fixtures) are ignored.
 """
 

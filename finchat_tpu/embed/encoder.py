@@ -69,7 +69,7 @@ class BertConfig:
 
 
 EMBED_PRESETS: dict[str, BertConfig] = {
-    # byte-vocab debug/bench encoder
+    # byte-vocab debug encoder
     "bge-tiny": BertConfig(),
     # bge-base-en architecture (BAAI/bge-base-en-v1.5 card): BERT-base,
     # CLS pooling + L2 norm
@@ -195,7 +195,7 @@ class EmbeddingEncoder:
         # embed.quant: the retrieval plane rides the serving quant mode —
         # int8 weight-only via the decoder's QTensor machinery (ISSUE 14);
         # quality gate: quantized-vs-fp32 top-k overlap >= 0.99
-        # (tests/test_quant_serving.py, bench --quant-sweep)
+        # (tests/test_quant_serving.py)
         self.params = quantize_bert_params(params) if quant else params
         self.quant = quant
         # resolve the fused-matmul backend ONCE (ops/dispatch discipline:

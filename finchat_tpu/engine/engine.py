@@ -6,8 +6,7 @@ point has ONE static shape per (batch-bucket) —
 
 - ``prefill_step``: ``N × prefill_chunk`` tokens — N sequences advance one
   chunk together (batched prefill; a 64-session burst is a handful of
-  steps, not 64 serial weight-reads — the round-3 bench measured 8.6 s for
-  64×128-token prompts through the old one-sequence-at-a-time path).
+  steps, not 64 serial weight-reads).
   Arbitrary prompt lengths become rounds of fixed-size chunks (chunked
   prefill, SURVEY §5.7a) so there is no bucketing recompile storm;
   exhausted prompts ride later rounds with ``n_valid = 0``.
@@ -289,8 +288,8 @@ def prefill_step(
         page_size, config.n_kv_heads, attn_backend
     )
     # hidden states only, then project just each sequence's last valid row:
-    # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB for the 8B
-    # bench shape (64 x 128 x 128256) — vs 33 MB for [N, vocab]
+    # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
+    # 64 x 128 x 128256 (an 8B model) — vs 33 MB for [N, vocab]
     # a mixer's state: each row starts from its slot's and leaves its last
     # state there, so a prompt's chunks carry it from round to round
     hidden, state = _forward_cached(
@@ -930,12 +929,12 @@ def ragged_mixed_step(
       reads the committed tokens, so a loop slot's phase-1 token chains
       into its fused tail exactly like K single steps.
 
-    Numerics contract (tests/test_mixed_step.py, bench --ragged-sweep):
-    same MATH as the split path per token; greedy streams byte-identical
-    at fp32 (CI-gated). The documented bf16 near-tie caveat of
-    ``verify_step``/PR 4 applies unchanged: a token computed at the packed
-    shape can differ in the last ulp from the ``[max_seqs, 1]`` shape and
-    flip a later near-tie argmax — either stream is a valid greedy decode.
+    Numerics contract (tests/test_mixed_step.py): same MATH as the split
+    path per token; greedy streams byte-identical at fp32. The documented
+    bf16 near-tie caveat of ``verify_step``/PR 4 applies unchanged: a
+    token computed at the packed shape can differ in the last ulp from the
+    ``[max_seqs, 1]`` shape and flip a later near-tie argmax — either
+    stream is a valid greedy decode.
     """
     R = row_slot.shape[0]
     return _ragged_round_math(
@@ -1021,8 +1020,8 @@ def ragged_multi_round(
 
     Byte-identity contract: round r of a capture is bit-identical math to
     the r'th host-stepped ``ragged_mixed_step`` over the same descriptors
-    (same body, same rng split discipline — tests/test_freerun.py and
-    bench --freerun-sweep pin the stream-level identity at fp32)."""
+    (same body, same rng split discipline — tests/test_freerun.py pins
+    the stream-level identity at fp32)."""
     R = row_slot.shape[0]
     no_drafts = jnp.zeros((R,), jnp.int32)
 
@@ -1966,7 +1965,7 @@ class InferenceEngine:
         # dtype never keys a jit cache entry — the quantized tree swaps in
         # under the same traced shapes), and qm_backend-independent too
         # (resolved once at construction, one static value per engine —
-        # bench --quantmatmul-smoke gates ref/fused counts equal), so the
+        # tests/test_quant_matmul.py holds ref/fused counts equal), so the
         # collapsed-matrix gauge stays comparable across modes; the
         # labels make mode and matmul backend visible
         logger.info(
@@ -2020,8 +2019,8 @@ class InferenceEngine:
         """One packed ragged dispatch (see ragged_mixed_step); returns
         ``(emitted, n_emitted, row_logits, loop_block)`` device arrays —
         the scheduler fetches once per round. Counted at the dispatch seam
-        like decode()/decode_loop(), so bench.py's dispatches-per-iteration
-        figure reads real enqueued device programs."""
+        like decode()/decode_loop(): one enqueued device program, one
+        count."""
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_mixed_dispatches_total")
@@ -2058,8 +2057,8 @@ class InferenceEngine:
         ``(ring_tokens, ring_n, ring_blocks)`` as device arrays — the
         scheduler drains them off-loop while the device free-runs the
         next capture. Counted ONCE at the dispatch seam (one program),
-        exactly why bench --freerun-sweep's dispatches-per-round figure
-        drops below 1."""
+        which is why dispatches per round drop below 1
+        (tests/test_freerun.py)."""
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_mixed_dispatches_total")
@@ -2074,27 +2073,24 @@ class InferenceEngine:
         )
         return ring_tokens, ring_n, ring_blocks
 
-    def decode_loop(self, active, temperature, top_p, top_k, eos_id: int,
-                    depth: int | None = None):
-        """Fused multi-step decode (see decode_loop_step): K iterations in
-        one dispatch, on-device sampling + EOS mask. Returns the
-        ``[K, max_seqs]`` token block (device array — callers fetch once).
-        ``depth`` overrides the configured ``decode_loop_depth`` (bench
-        sweeps); each distinct depth is its own compiled variant."""
+    def decode_loop(self, active, temperature, top_p, top_k, eos_id: int):
+        """Fused multi-step decode (see decode_loop_step):
+        ``decode_loop_depth`` iterations in one dispatch, on-device
+        sampling + EOS mask. Returns the ``[K, max_seqs]`` token block
+        (device array — callers fetch once)."""
         from finchat_tpu.utils.metrics import METRICS
 
-        K = depth if depth is not None else self.decode_loop_depth
-        assert K >= 1
         # counted at the DISPATCH seam (one jitted program enqueued), the
-        # same counter decode() bumps once per step — what bench.py's
-        # dispatches-per-token figure reads, so a host-side fallback that
-        # looped K single steps here would be visible, not assumed away
+        # same counter decode() bumps once per step, so a host-side
+        # fallback that looped K single steps here would be visible, not
+        # assumed away
         METRICS.inc("finchat_decode_dispatches_total")
         self.state, token_block = decode_loop_step(
             self.params, self.state, active, temperature, top_p, top_k,
             jnp.int32(eos_id),
             config=self.config, page_size=self.page_size,
-            attn_backend=self.attn_backend, qm_backend=self.qm_backend, loop_depth=K,
+            attn_backend=self.attn_backend, qm_backend=self.qm_backend,
+            loop_depth=self.decode_loop_depth,
         )
         return token_block
 
@@ -2105,10 +2101,8 @@ class InferenceEngine:
         from finchat_tpu.utils.metrics import METRICS
 
         # counted at the DISPATCH seam like decode()/decode_loop()/mixed:
-        # a verify step is one enqueued device program, and bench.py's
-        # dispatches-per-iteration figures must see the spec plane too
-        # (the split-path baseline of --ragged-sweep under-counted by the
-        # whole verify cadence before this)
+        # a verify step is one enqueued device program, and dispatches per
+        # coexist iteration on the split path must see the spec plane too
         METRICS.inc("finchat_decode_dispatches_total")
         self.state, emitted, n_emitted, logits = verify_step(
             self.params, self.state, active, drafts, n_drafts,

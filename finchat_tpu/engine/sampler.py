@@ -13,8 +13,9 @@ per-iteration math and rng discipline as K single ``decode_step`` calls,
 which is what makes the greedy block bit-reproducible against single-step
 decode (tests/test_decode_loop.py).
 
-TPU note: a full-vocab ``argsort`` costs ~26 ms/step for [64, 32000] on
-v5e (measured, benchmarks/profile_decode.py) — nearly half the decode step.
+TPU note: a full-vocab ``argsort`` cost ~26 ms/step for [64, 32000] on
+v5e in the builders' July 2026 profile (not measured since) — nearly half
+that decode step.
 Two paths, chosen at runtime inside jit (``lax.cond``):
 
 - NO truncating slot in the batch (every ``top_k == 0`` and ``top_p >= 1``
@@ -102,7 +103,7 @@ def sample(
     # top_p >= 1): full-vocab Gumbel-argmax is an exact categorical draw and
     # skips the lax.top_k partial sort (~1.5 ms of the 9.6 ms decode step at
     # [64, 32k] on v5e). This is the engine-default config (EngineConfig
-    # top_p=1.0, top_k=0), so the bench/serving hot path stays on it; any
+    # top_p=1.0, top_k=0), so the serving hot path stays on it; any
     # truncating slot in the batch falls back to the candidate-set path.
     def _full_categorical(_):
         gumbel = jax.random.gumbel(rng, scaled.shape, scaled.dtype)

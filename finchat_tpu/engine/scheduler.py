@@ -432,8 +432,8 @@ class ContinuousBatchingScheduler:
         # model dispatch this scheduler enqueues bumps _dispatch_tally,
         # and the span from one coexist iteration's start to the next
         # accounting point lands in finchat_coexist_dispatches_total — so
-        # dispatches-per-coexist-iteration (the bench --ragged-sweep
-        # headline) is exact, not a racy window over global counters
+        # dispatches-per-coexist-iteration (tests/test_mixed_step.py
+        # holds it at 1) is exact, not a racy window over global counters
         self._dispatch_tally = 0
         self._coexist_mark: int | None = None
         # free-running loop (ISSUE 13): consecutive ragged rounds captured
@@ -2340,7 +2340,7 @@ class ContinuousBatchingScheduler:
             # armable site: a chaos drill wedging this replica's device
             # keeps revive failing too (a broken device fails its rebuild),
             # so the supervisor backs off instead of rejoining a replica
-            # that would immediately re-trip (bench --fleet-sweep)
+            # that would immediately re-trip (tests/test_fleet.py)
             inject("engine.rebuild", replica=self.replica_id)
             with Timer(self.metrics, "finchat_engine_rebuild_seconds"):
                 self.engine.rebuild_device_state()
@@ -3120,8 +3120,8 @@ class ContinuousBatchingScheduler:
         prefill_chunk-sized row bounds activation memory the way the
         segmented ring schedule did. ``finchat_mixed_demotions_total``
         stays pre-seeded per reason — INCLUDING reason="ring" — so the
-        complete erasure is observable (bench --ragged-sweep /
-        --longctx-smoke gate it at zero). The split path — where
+        complete erasure is observable (tests/test_mixed_step.py and
+        tests/test_bounded_kv.py hold it at zero). The split path — where
         ring-routed rows still run their seq-sharded collective schedule
         when no decode coexists — stays the golden-identical fallback."""
         if not self.mixed_enabled or not self.decoding:
@@ -4033,9 +4033,9 @@ class ContinuousBatchingScheduler:
                         await asyncio.sleep(0.05)
 
             prefill_active = bool(self._prefix_jobs) or self._prefill_work()
-            # label for the inter-token histogram, and the denominator for
-            # the dispatches-per-iteration figure bench --ragged-sweep
-            # reports: iterations where prefill work and in-flight decodes
+            # label for the inter-token histogram, and the denominator of
+            # dispatches per coexist iteration: iterations where prefill
+            # work and in-flight decodes
             # coexist are exactly where the ragged step's >=2→1 fusion
             # applies. The mark/attribute pair books every dispatch from a
             # coexist iteration's start to the next accounting point into

@@ -92,7 +92,7 @@ def session_key(conversation_id: str, role: str) -> str:
 def conversation_of(key: str) -> str:
     """Inverse of :func:`session_key` for routing: the conversation a
     cache key (or a handle's ``conversation_id``) belongs to. Keys without
-    a recognised role suffix — direct scheduler submissions, benches —
+    a recognised role suffix — direct scheduler submissions —
     are their own conversation."""
     base, sep, role = key.rpartition("#")
     return base if sep and role in SESSION_KEY_ROLES else key

@@ -95,7 +95,7 @@ class LlamaConfig:
 
 
 # Model shapes follow the public architecture cards; "tiny"/"mini" are
-# random-weight debug/bench configs.
+# random-weight debug configs.
 PRESETS: dict[str, LlamaConfig] = {
     "tiny": LlamaConfig(),
     "mini": LlamaConfig(vocab_size=260, dim=512, n_layers=8, n_heads=8, n_kv_heads=4, hidden_dim=1536, max_seq_len=4096),
@@ -125,8 +125,8 @@ PRESETS: dict[str, LlamaConfig] = {
 
 
 def n_params(config: LlamaConfig) -> int:
-    """Analytic parameter count (no materialization) — bench.py uses it
-    to weight-bytes-normalize throughput across model sizes."""
+    """Analytic parameter count (no materialization); tests hold
+    ``init_params`` and the benchmark's adapters to it."""
     c = config
     d, hd = c.dim, c.head_dim
     attn = d * (c.n_heads * hd) + 2 * d * (c.n_kv_heads * hd) + (c.n_heads * hd) * d

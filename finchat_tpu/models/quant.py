@@ -259,7 +259,7 @@ def init_quantized_llama_params(config: Any, key: Any, mode: str = "int8",
     leaf quantizes at creation (models/llama.py ``leaf_transform``), so the
     full bf16 tree never coexists with the int8 one. This is what lets a
     random-weight llama3-8b (16 GB bf16) materialize on one 16 GB v5e chip
-    for benching; checkpoint serving gets the same effect from the loader's
+    from a seed; checkpoint serving gets the same effect from the loader's
     per-tensor path. Identical numerics to ``quantize_llama_params``
     applied after ``init_params`` (asserted in tests/test_quant.py).
 

@@ -4,8 +4,8 @@ The reference delegates tokenization to external APIs (Gemini/OpenAI); here
 it is in-tree. Two implementations behind one protocol:
 
 - ``ByteTokenizer`` — self-contained UTF-8 byte-level vocab (256 bytes +
-  specials). Used by tests, the dev harness, and the random-weight bench so
-  the whole stack runs with zero downloaded assets.
+  specials). Used by tests and wherever no tokenizer directory is
+  configured, so the whole stack runs with zero downloaded assets.
 - ``HFTokenizer`` — adapter over a local HuggingFace tokenizer directory
   (Llama/TinyLlama checkpoints), gated on files being present.
 

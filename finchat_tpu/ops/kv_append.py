@@ -7,7 +7,7 @@ since), both as scan xs→ys and as an in-carry scatter — XLA never does it
 in place. This
 kernel does: ``input_output_aliases`` pins the output to the input buffer
 and each program read-modify-writes exactly ONE page, so per-step traffic is
-B pages instead of the whole cache (~0.5 ms at bench shapes).
+B pages instead of the whole cache.
 
 Mosaic constraints that shaped the design (discovered on v5e hardware,
 round 4 — see git history for the failed variants):

@@ -125,7 +125,7 @@ def ragged_paged_attention_ref(
     ``mha_reference`` math the split-path reference backend uses (each
     packed token is one batch element with ``Sq = 1``): at fp32 a ragged
     dispatch is bitwise the split path's math per token, which is what the
-    mixed-vs-split byte-identity gate (bench --ragged-sweep) leans on.
+    mixed-vs-split byte-identity tests (tests/test_mixed_step.py) lean on.
     Padding tokens (``tok_row == R``) read the trash row with ``kv_len 0``
     and produce zeros, exactly like an inactive decode slot.
 

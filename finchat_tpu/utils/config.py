@@ -264,7 +264,7 @@ class EngineConfig:
     # round-trips and Python dispatch overhead ~K× at the cost of up to K
     # steps of inter-token burstiness (the SSE path re-paces emits).
     # Grammar-constrained, spec-decode, and within-K-of-budget slots are
-    # demoted to single-step by the scheduler. Bench at 4/8.
+    # demoted to single-step by the scheduler.
     decode_loop_depth: int = 1
     # retrieval/prefill overlap (agent/graph.py + scheduler submit_partial):
     # prefill the response prompt's static prefix (system + context +
@@ -308,7 +308,7 @@ class EngineConfig:
     # grammar-constrained or live spec-proposal rows cap the capture to 1
     # round (today's behavior). 1 = off (one host round-trip per round).
     # Streams stay byte-identical to the round-stepped path (fp32
-    # contract; bench --freerun-sweep gates it). Requires mixed_step.
+    # contract; tests/test_freerun.py). Requires mixed_step.
     freerun_rounds: int = 1
     # TP collective-compute overlap (ops/tp_overlap.py): the manual-TP
     # stage path chunks each row-parallel output projection so every

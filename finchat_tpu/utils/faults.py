@@ -100,20 +100,6 @@ def n_shot(n: int, exc: Exception) -> Handler:
     return handler
 
 
-def flaky(rate: float, exc: Exception, seed: int = 0) -> Handler:
-    """Handler that raises ``exc`` with probability ``rate`` per call —
-    the chaos-sweep fault model (bench.py --chaos-sweep)."""
-    import random
-
-    rng = random.Random(seed)
-
-    def handler(**_ctx: Any) -> None:
-        if rng.random() < rate:
-            raise exc
-
-    return handler
-
-
 def for_seq(seq_id: str, exc: Exception) -> Handler:
     """Handler that raises only for one victim sequence (ctx['seq_id'])."""
 
@@ -128,7 +114,7 @@ def for_replica(replica_id: str, inner: Handler) -> Handler:
     """Scope ``inner`` to one fleet replica (ctx['replica'] — each
     replica's scheduler stamps its id on its dispatch sites), so a chaos
     drill can wedge ONE engine while its siblings stay healthy
-    (bench.py --fleet-sweep, tests/test_fleet.py)."""
+    (tests/test_fleet.py)."""
 
     def handler(**ctx: Any) -> None:
         if ctx.get("replica") == replica_id:

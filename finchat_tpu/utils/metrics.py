@@ -31,7 +31,7 @@ round), ``finchat_coexist_iterations_total`` (scheduler iterations where
 prefill work and in-flight decodes coexist) and
 ``finchat_coexist_dispatches_total`` (model dispatches BOOKED to those
 iterations by the scheduler's own attribution — together the exact
-dispatches-per-coexist-iteration figure bench.py --ragged-sweep reports;
+dispatches-per-coexist-iteration figure tests/test_mixed_step.py holds;
 the split path pays >= 2 per such iteration, the ragged path 1),
 ``finchat_mixed_demotions_total{reason=spec|decode_loop|constrained|ring|
 other}`` (coexist iterations demoted to the split path, per reason —

@@ -3,8 +3,8 @@ where its compiled programs are kept.
 
 Library code (an ``InferenceEngine`` built in a test) calls nothing here;
 only a process entry that compiles does — ``python -m finchat_tpu``,
-``chip_smoke.py``, the bench worker, ``benchmarks/load_harness.py`` — and
-it does so before its first compile.
+``chip_smoke.py``, ``perfbench/run.py``, ``perfbench/control.py`` — and it
+does so before its first compile.
 """
 
 from __future__ import annotations

@@ -201,8 +201,15 @@ def test_long_session_bounded_occupancy_and_envelope(params):
     assert peak <= SINK + WINDOW, (peak, "occupancy exceeded the budget")
     # the unbounded requirement would have been 10 pages; eviction made
     # up the difference
-    assert after - before >= pages_needed(len(prompt) + max_new, PAGE) - (
-        SINK + WINDOW)
+    unbounded_pages = pages_needed(len(prompt) + max_new, PAGE)
+    assert after - before >= unbounded_pages - (SINK + WINDOW)
+    # the control: without the policy the same session's occupancy grows
+    # with its context, past the budget, and evicts nothing
+    _, control_peak, _ = _run_single(params, sink=0, window=0,
+                                     prompt=prompt, max_new=max_new)
+    assert SINK + WINDOW < control_peak <= unbounded_pages
+    assert METRICS.snapshot().get(
+        "finchat_boundedkv_evicted_pages_total", 0) == after
 
 
 def test_bounded_composes_with_loop_tails_and_spec(params):

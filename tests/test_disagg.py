@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from finchat_tpu.analysis.sanitizers import scheduler_leak_report
 from finchat_tpu.engine.engine import InferenceEngine
 from finchat_tpu.engine.sampler import SamplingParams
 from finchat_tpu.engine.scheduler import ContinuousBatchingScheduler
@@ -227,13 +228,18 @@ def test_empty_prefill_pool_counts_fallback_and_serves(params):
 
 # --- cross-pool handoff ----------------------------------------------------
 
-def test_cold_turn_handoff_byte_identity_and_warm_resume(params):
+@pytest.mark.parametrize("storm", [1, 3], ids=["one-turn", "storm"])
+def test_cold_turn_handoff_byte_identity_and_warm_resume(params, storm):
     """THE tentpole identity: a cold turn submitted to the serving
     replica prefills on the PREFILL replica, the KV crosses pools over
     the drain-handoff wire format, admission resumes from it
     (resumed_len > 0), and the stream is byte-identical to a mixed-fleet
-    control. The source's copy is discarded after the handoff."""
-    prompt = list(range(1, 41))  # residue 39 >= one chunk: handoff engages
+    control. The source's copy is discarded after the handoff. As a
+    storm: several cold conversations offered at once beside a stream
+    that is already decoding — every one handed off, every stream as in
+    the control, no page or slot left owned."""
+    # residue 39 >= one chunk: handoff engages
+    prompts = {f"conv-h{i}": list(range(1 + i, 41 + i)) for i in range(storm)}
 
     async def run(roles) -> dict:
         fleet = _make_fleet(roles, params)
@@ -241,32 +247,48 @@ def test_cold_turn_handoff_byte_identity_and_warm_resume(params):
         try:
             serving = _serving(fleet)
             rid = serving.replica_id
+            steady = None
+            if storm > 1:
+                # short of one chunk: never handed off, decodes through the storm
+                steady = await serving.scheduler.submit(
+                    "steady", list(range(50, 60)), _greedy(24),
+                    conversation_id="conv-steady")
+                while steady.generated < 2:
+                    await asyncio.sleep(0.002)
             h0 = _get("finchat_disagg_handoffs_total", rid)
-            h = await serving.scheduler.submit(
-                "t1", prompt, _greedy(8), conversation_id="conv-h")
-            toks, err = await _drain(h)
-            assert err is None
+            handles = {
+                conv: await serving.scheduler.submit(
+                    "t1-" + conv, prompt, _greedy(8), conversation_id=conv)
+                for conv, prompt in prompts.items()
+            }
+            if steady is not None:
+                handles["conv-steady"] = steady
+            results = await asyncio.gather(*map(_drain, handles.values()))
+            assert all(err is None for _toks, err in results)
             out = {
-                "tokens": toks,
-                "resumed": h.resumed_len,
+                "tokens": {c: toks for c, (toks, _e) in zip(handles, results)},
+                "resumed": [handles[c].resumed_len for c in prompts],
                 "handoffs": _get("finchat_disagg_handoffs_total", rid) - h0,
             }
             if roles[0] == ROLE_PREFILL:
                 # source copy discarded — a stale twin could serve
                 # diverged KV if the conversation ever re-handed-off
                 src = fleet.replicas[0].scheduler.session_cache
-                out["source_clean"] = src.get("conv-h") is None
+                out["source_clean"] = all(src.get(c) is None for c in prompts)
             for rep in fleet.replicas:
                 rep.scheduler.allocator.check_invariants()
             return out
         finally:
             await fleet.stop()
+            for rep in fleet.replicas:
+                assert scheduler_leak_report(rep.scheduler) == []
 
     disagg = asyncio.run(run([ROLE_PREFILL, ROLE_DECODE]))
     mixed = asyncio.run(run([ROLE_MIXED, ROLE_MIXED]))
     assert disagg["tokens"] == mixed["tokens"]  # byte-identical across pools
-    assert disagg["handoffs"] == 1 and mixed["handoffs"] == 0
-    assert disagg["resumed"] > 0  # admission resumed from the handed KV
+    assert disagg["handoffs"] == storm and mixed["handoffs"] == 0
+    # admission resumed from the handed KV, not a local cold prefill
+    assert all(n > 0 for n in disagg["resumed"])
     assert disagg["source_clean"]
     # the handoff detour was timed
     assert METRICS.snapshot().get(

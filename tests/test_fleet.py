@@ -225,7 +225,8 @@ def test_drain_handoff_byte_identity_and_respawn():
     sites until healed): every in-flight stream — including the victim's —
     completes on a sibling with the exact greedy tokens of an undisturbed
     run, zero errors; the victim goes OUT (gauge drops) and respawns LIVE
-    after the heal (gauge recovers)."""
+    after the heal (gauge recovers); a wave of new conversations offered
+    while it is OUT, and another after it is back, are all answered."""
     prompts = {f"conv-{i}": list(range(7 * i + 1, 7 * i + 15))
                for i in range(6)}
 
@@ -233,6 +234,20 @@ def test_drain_handoff_byte_identity_and_respawn():
         fleet = _make_fleet(3)
         await fleet.start()
         out: dict = {"errors": 0}
+
+        async def wave(tag, avoid=None, n=4) -> int:
+            handles = []
+            for i in range(n):
+                conv = f"{tag}-{i}"
+                rep = fleet.replica_for(conv)
+                assert rep is not avoid, "the router placed a turn on an OUT replica"
+                handles.append(await rep.scheduler.submit(
+                    conv, list(range(60 + i, 74 + i)), _greedy(4),
+                    conversation_id=conv))
+            results = [await asyncio.wait_for(
+                asyncio.ensure_future(_drain(h)), timeout=120) for h in handles]
+            return sum(1 for _t, e in results if e is None)
+
         try:
             victim = next(rep for rep in fleet.replicas
                           if any(fleet.replica_for(c) is rep for c in prompts))
@@ -278,6 +293,9 @@ def test_drain_handoff_byte_identity_and_respawn():
                 out["victim_out"] = victim.state != LIVE
                 out["live_during"] = int(
                     METRICS.get("finchat_fleet_replicas_live"))
+                # the outage wave: the router spreads new conversations
+                # over the survivors, and every one of them is answered
+                out["served_during"] = await wave("during", avoid=victim)
                 dead[0] = False  # heal: the supervisor's revive succeeds
                 for _ in range(2000):
                     if victim.state == LIVE:
@@ -286,6 +304,7 @@ def test_drain_handoff_byte_identity_and_respawn():
                 out["victim_respawned"] = victim.state == LIVE
                 out["live_after"] = int(
                     METRICS.get("finchat_fleet_replicas_live"))
+                out["served_after"] = await wave("after")
             for rep in fleet.replicas:
                 rep.scheduler.allocator.check_invariants()
         finally:
@@ -301,6 +320,8 @@ def test_drain_handoff_byte_identity_and_respawn():
     assert METRICS.get("finchat_fleet_drained_streams_total") > drained0
     assert chaos["victim_out"] and chaos["live_during"] == 2
     assert chaos["victim_respawned"] and chaos["live_after"] == 3
+    # goodput: every turn offered during the outage and after the respawn
+    assert chaos["served_during"] == 4 and chaos["served_after"] == 4
 
 
 def test_cancel_of_drained_handle_targets_adopter():

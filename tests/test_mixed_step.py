@@ -321,17 +321,34 @@ def _run_workload(params, mixed, with_constraint=False):
     return asyncio.run(go())
 
 
+def _dispatches_per_coexist_iteration(run):
+    """``run()``'s result and the scheduler's own attribution over it:
+    model dispatches booked to iterations where prefill work and live
+    decodes coexisted, over the count of those iterations."""
+    keys = ("finchat_coexist_dispatches_total", "finchat_coexist_iterations_total")
+    before = [METRICS.get(k) for k in keys]
+    result = run()
+    dispatches, iterations = (METRICS.get(k) - b for k, b in zip(keys, before))
+    assert iterations > 0, "prefill never coexisted with a live decode"
+    return result, dispatches / iterations
+
+
 def test_mixed_vs_split_streams_identical(params):
     """Greedy streams — two in-flight decodes, a long prompt admitted
     mid-decode, and the long prompt completing mid-batch — are
-    byte-identical ragged vs split, and the ragged run actually fused."""
-    split, n_split = _run_workload(params, mixed=False)
-    mixed, n_mixed = _run_workload(params, mixed=True)
+    byte-identical ragged vs split, and the ragged run actually fused:
+    ONE model dispatch per coexist iteration where the split path pays a
+    prefill round plus a decode dispatch."""
+    (split, n_split), dpi_split = _dispatches_per_coexist_iteration(
+        lambda: _run_workload(params, mixed=False))
+    (mixed, n_mixed), dpi_mixed = _dispatches_per_coexist_iteration(
+        lambda: _run_workload(params, mixed=True))
     assert [len(s) for s in split.values()] == [28, 22, 6]
     assert mixed == split
     assert n_split == 0
     # the long prompt spans 5+ chunks; each coexisted with live decodes
     assert n_mixed >= 5
+    assert dpi_mixed <= 1.2 and dpi_split >= 1.8, (dpi_mixed, dpi_split)
 
 
 def _spec_prompt(seed, rng=None):
@@ -454,13 +471,20 @@ def test_previously_demoted_combo_byte_identity(params, seed):
     short-tail prefill coexisting in one iteration — greedy/constrained
     streams byte-identical ragged vs split, with the ragged run actually
     carrying the feature mix in fused dispatches."""
-    split = _demoted_combo_workload(params, mixed=False, seed=seed)
+    split, dpi_split = _dispatches_per_coexist_iteration(
+        lambda: _demoted_combo_workload(params, mixed=False, seed=seed))
     recorded: list[dict] = []
-    ragged = _demoted_combo_workload(
-        params, mixed=True, recorded=recorded, seed=seed,
-        spec_oracle=_spec_prompt(seed) + split["spec"])
+    ragged, dpi_ragged = _dispatches_per_coexist_iteration(
+        lambda: _demoted_combo_workload(
+            params, mixed=True, recorded=recorded, seed=seed,
+            spec_oracle=_spec_prompt(seed) + split["spec"]))
     assert ragged == split
+    assert dpi_ragged <= 1.2 and dpi_split >= 1.8, (dpi_ragged, dpi_split)
     assert recorded, "no ragged dispatch ran"
+    assert any(r["prefill"] and r["spec"] and r["loop"] and r["constrained"]
+               for r in recorded), (
+        "no single dispatch carried every previously demoting feature",
+        recorded)
     assert any(r["prefill"] and r["constrained"] for r in recorded), (
         "constrained slot never rode a fused dispatch", recorded)
     assert any(r["prefill"] and r["loop"] for r in recorded), (

@@ -495,6 +495,77 @@ async def test_host_death_adoption_replays_inherited_journals_exactly(tmp_path):
         jb.close()
 
 
+def test_survivor_stream_completes_across_host_kill(params, tmp_path):
+    """The same kill with traffic on the survivor: a stream decoding on
+    hostB while hostA dies, is declared dead and has its partitions
+    adopted and journals replayed completes without an error,
+    byte-identical to the undisturbed run; afterwards hostB is the one
+    live host and answers a conversation of a partition it adopted."""
+    prompt = list(range(1, 14))
+
+    async def run(kill: bool):
+        broker = InMemoryBroker(num_partitions=8)
+        ka = KafkaClient(KafkaConfig(num_partitions=8), broker=broker)
+        kb = KafkaClient(KafkaConfig(num_partitions=8), broker=broker)
+        ka.setup_consumer([USER_MESSAGE_TOPIC])
+        kb.setup_consumer([USER_MESSAGE_TOPIC])
+        parts_a = {p for _t, p in ka.assignment()}
+        ja = AnsweredJournal(str(tmp_path / f"j{kill}"), num_partitions=8)
+        for p in sorted(parts_a):
+            ja.append(f"mid-a{p}", partition=p)
+        ja.close()
+        coord_a = PodCoordinator(
+            _pod_cfg("hostA", listen="inproc:hostA"), kafka=ka)
+        await coord_a.start()
+        sched_b = _make_scheduler(params, "hostB-0")
+        await sched_b.start()
+        jb = AnsweredJournal(str(tmp_path / f"j{kill}"), num_partitions=8)
+        ring_b = DedupeRing(size=64)
+        coord_b = PodCoordinator(
+            _pod_cfg("hostB", peers="hostA=inproc:hostA"),
+            fleet=_single_replica_fleet(sched_b), kafka=kb, journal=jb,
+            dedupe=ring_b,
+        )
+        sched_b.pod = coord_b
+        await coord_b.start()
+        peer = coord_b.peers["hostA"]
+        try:
+            await coord_b._heartbeat(peer)  # learns hostA's member id
+            task = asyncio.create_task(_collect(
+                sched_b, "b-s", prompt, 48, conversation_id="convS"))
+            if kill:
+                while not sched_b.decoding or any(
+                        h.generated < 2 for h in sched_b.decoding.values()):
+                    await asyncio.sleep(0.002)
+                assert not task.done()
+                coord_a.kill()  # mid-stream: no drain, no goodbye
+                await coord_b._heartbeat(peer)
+                await coord_b._heartbeat(peer)
+                assert peer.state == PEER_DEAD
+                assert METRICS.get("finchat_pod_hosts_live") == 1.0
+                assert {p for _t, p in kb.assignment()} == set(range(8))
+                assert all(f"mid-a{p}" in ring_b._ids for p in parts_a)
+            _h, tokens = await task
+            assert isinstance(tokens, list) and len(tokens) == 48, tokens
+            if kill:
+                # a conversation of an adopted partition now belongs here
+                conv = next(f"adopt-{i}" for i in range(200)
+                            if ka.partition_for(f"adopt-{i}") in parts_a)
+                _h, after = await _collect(sched_b, "b-a", prompt, 4,
+                                           conversation_id=conv)
+                assert isinstance(after, list) and len(after) == 4, after
+            return tokens
+        finally:
+            coord_a.kill()
+            await coord_b.stop()
+            await sched_b.stop()
+            jb.close()
+            sched_b.allocator.check_invariants()
+
+    clean = asyncio.run(run(False))
+    assert asyncio.run(run(True)) == clean
+
+
 # --- cross-host session transfer: wire-format compat matrix ----------------
 
 @pytest.mark.parametrize("kv_quant", ["", "int8"])
@@ -661,8 +732,11 @@ def test_single_host_no_liaison_is_bit_identical(params):
         sched = _make_scheduler(params, "solo-0")
         await sched.start()
         t1 = list(range(1, 14))
-        pulls0 = METRICS.get("finchat_pod_session_pulls_total")
-        misses0 = METRICS.get("finchat_pod_pull_misses_total")
+        silent = ("finchat_pod_session_pulls_total",
+                  "finchat_pod_pull_misses_total",
+                  "finchat_pod_heartbeats_total",
+                  "finchat_pod_peer_deaths_total")
+        before = [METRICS.get(m) for m in silent]
         assert sched.pod is None  # default: plane off
         _, toks_off = await _collect(sched, "s-1", t1, 8,
                                      conversation_id="solo1")
@@ -672,9 +746,8 @@ def test_single_host_no_liaison_is_bit_identical(params):
                                      conversation_id="solo2")
         await sched.stop()
         assert toks_pod == toks_off
-        # and the peer-less pull path never touched the liaison counters
-        assert METRICS.get("finchat_pod_session_pulls_total") == pulls0
-        assert METRICS.get("finchat_pod_pull_misses_total") == misses0
+        # and the peer-less pod moved no counter of the pod plane
+        assert [METRICS.get(m) for m in silent] == before
 
     asyncio.run(run())
 

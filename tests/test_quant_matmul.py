@@ -8,7 +8,7 @@ Three contracts, mirroring the attention-kernel test discipline:
    is the CPU/tier-1 serving path, so routing every QTensor/Q4Tensor
    matmul site through the dispatcher must not change a single stream
    byte; this file pins the identity at the op level and the whole-model
-   level (tests/test_quant.py + bench --quantmatmul-smoke pin streams).
+   level (tests/test_quant.py, and streams through the scheduler below).
 2. Kernel-vs-ref parity across the layout matrix: int8/int4 x
    per-channel/per-group x aligned/ragged shapes — interpret mode on the
    CPU test mesh, Mosaic-compiled under ``FINCHAT_TESTS_TPU=1`` (the same
@@ -197,6 +197,74 @@ def test_quantized_forward_fused_tracks_ref():
                          attn_backend="ref", qm_backend="pallas-interpret")
     np.testing.assert_allclose(np.asarray(fused), np.asarray(ref),
                                rtol=5e-2, atol=5e-2)
+
+
+def test_fused_engine_streams_match_ref_through_scheduler():
+    """The backend is resolved once at construction and multiplies
+    nothing: an int8 engine on the fused kernel (interpret mode) serves
+    greedy fp32 streams through the real scheduler byte-identical to its
+    inline-dequant twin, compiles exactly as many warm-up variants, moves
+    ``finchat_quantmatmul_fused_dispatches_total`` where the twin does
+    not, and both stopped schedulers audit leak-free."""
+    import asyncio
+    import dataclasses
+
+    from finchat_tpu.analysis.sanitizers import scheduler_leak_report
+    from finchat_tpu.engine.engine import InferenceEngine
+    from finchat_tpu.engine.sampler import SamplingParams
+    from finchat_tpu.engine.scheduler import ContinuousBatchingScheduler
+    from finchat_tpu.models.llama import PRESETS
+    from finchat_tpu.models.quant import init_quantized_llama_params
+    from finchat_tpu.utils.config import EngineConfig
+    from finchat_tpu.utils.metrics import METRICS
+
+    config = dataclasses.replace(PRESETS["tiny"], dtype=jnp.float32)
+    params = init_quantized_llama_params(config, jax.random.key(0), mode="int8")
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(1, config.vocab_size, size=n).tolist()
+               for n in (44, 23)]
+    fused_counter = "finchat_quantmatmul_fused_dispatches_total"
+
+    def run(qm_backend):
+        ecfg = EngineConfig(max_seqs=2, page_size=16, num_pages=16,
+                            max_seq_len=96, prefill_chunk=32)
+        engine = InferenceEngine(config, params, ecfg, quant="int8",
+                                 qm_backend=qm_backend)
+        engine.warmup()
+        sched = ContinuousBatchingScheduler(engine, eos_id=-1)
+        fused0 = METRICS.get(fused_counter)
+
+        async def one(i, prompt):
+            handle = await sched.submit(
+                f"{qm_backend}-{i}", prompt,
+                SamplingParams(temperature=0.0, max_new_tokens=12))
+            tokens = []
+            while True:
+                ev = await asyncio.wait_for(handle.events.get(), timeout=120)
+                if ev["type"] == "token":
+                    tokens.append(ev["token_id"])
+                elif ev["type"] == "done":
+                    return tokens
+                else:
+                    raise AssertionError(ev)
+
+        async def go():
+            await sched.start()
+            try:
+                return list(await asyncio.gather(
+                    *(one(i, p) for i, p in enumerate(prompts))))
+            finally:
+                await sched.stop()
+
+        streams = asyncio.run(go())
+        assert scheduler_leak_report(sched) == []
+        return streams, engine.compiled_variants, METRICS.get(fused_counter) - fused0
+
+    ref_streams, ref_variants, ref_fused = run("ref")
+    fused_streams, fused_variants, fused_fused = run("pallas-interpret")
+    assert fused_streams == ref_streams and all(len(s) == 12 for s in ref_streams)
+    assert fused_variants == ref_variants > 0
+    assert fused_fused > 0 and ref_fused == 0
 
 
 # --- 3. packed-K sharding: the kernel honors the local-shard layout ------

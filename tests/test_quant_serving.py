@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from finchat_tpu.analysis.sanitizers import scheduler_leak_report
 from finchat_tpu.engine.engine import InferenceEngine, commit_first_token, prefill_step
 from finchat_tpu.engine.kv_cache import (
     PageAllocator,
@@ -114,8 +115,7 @@ def test_int4_stacked_bitwise_matches_whole_leaf():
 def test_int4_forward_logits_track_fp32(params, group):
     """The quality envelope: an int4 tree's full-causal logits stay within
     a bounded relative delta of the fp32 tree's (coarser than int8 — 15
-    levels per group — but bounded; the bench --quant-sweep gates the same
-    figure per mode)."""
+    levels per group — but bounded)."""
     qparams = init_quantized_llama_params(
         CONFIG, jax.random.key(0), mode="int4", group_size=group)
     tokens = jnp.asarray([[5, 9, 2, 100, 17, 3, 44, 8]], jnp.int32)
@@ -400,19 +400,24 @@ def test_spec_verify_acceptance_parity_int8kv(params):
     assert steps == n_new - 1  # nothing accepted
 
 
-def test_scheduler_resume_byte_identity_int8kv(params, tmp_path):
-    """Scheduler-level: turn 2 resumed from the quantized session tier
-    (RAM + disk write-through) is byte-identical to a cold re-prefill on
-    a fresh int8-KV engine, and the resume dispatches fewer chunks."""
+@pytest.mark.parametrize("quant,kv_quant", [
+    ("", "int8"), ("int8", ""), ("int8", "int8"), ("int4", "")],
+    ids=["kv8", "int8", "int8+kv8", "int4"])
+def test_scheduler_resume_byte_identity_quantized(params, tmp_path, quant, kv_quant):
+    """Scheduler-level, in every quantized mode: turn 2 resumed from the
+    session tier (RAM + disk write-through) is byte-identical to a cold
+    re-prefill on a fresh engine of the same mode, the resume dispatches
+    fewer chunks, and the disk record equals the RAM entry plane for plane
+    (with an int8 cache: the scale planes too)."""
     def run(session: bool, turn2_prompt=None):
         cfg = EngineConfig(
             max_seqs=2, page_size=8, num_pages=64, max_seq_len=256,
-            prefill_chunk=16, kv_quant="int8", session_cache=session,
+            prefill_chunk=16, kv_quant=kv_quant, session_cache=session,
             session_cache_bytes=1 << 20,
             session_cache_disk_path=str(tmp_path / "skv") if session else "",
         )
         sched = ContinuousBatchingScheduler(
-            InferenceEngine(CONFIG, params, cfg), eos_id=-1)
+            InferenceEngine(CONFIG, params, cfg, quant=quant), eos_id=-1)
         rng = np.random.default_rng(3)
         p1 = rng.integers(1, CONFIG.vocab_size, size=40).tolist()
         out = {}
@@ -436,6 +441,14 @@ def test_scheduler_resume_byte_identity_int8kv(params, tmp_path):
                             raise AssertionError(ev)
 
                 t1 = await stream("t1", p1)
+                if session:
+                    cache = sched.session_cache
+                    await asyncio.to_thread(cache.disk.flush)
+                    entry, record = cache.get("conv"), cache.disk.load("conv")
+                    assert np.array_equal(entry.token_ids, record["token_ids"])
+                    assert (entry.snap[2] is not None) == (kv_quant == "int8")
+                    for a, b in zip(entry.snap, record["snap"]):
+                        assert (a is None and b is None) or np.array_equal(a, b)
                 prompt2 = turn2_prompt if turn2_prompt is not None else (
                     p1 + t1 + rng.integers(1, CONFIG.vocab_size, size=10).tolist())
                 c0 = METRICS.snapshot().get("finchat_prefill_seconds_count", 0)
@@ -445,6 +458,7 @@ def test_scheduler_resume_byte_identity_int8kv(params, tmp_path):
                 return prompt2, t2
             finally:
                 await sched.stop()
+                assert scheduler_leak_report(sched) == []
 
         return asyncio.run(go()) + (out["chunks"],)
 
@@ -452,6 +466,24 @@ def test_scheduler_resume_byte_identity_int8kv(params, tmp_path):
     _, cold_t2, cold_chunks = run(False, turn2_prompt=prompt2)
     assert warm_t2 == cold_t2
     assert warm_chunks < cold_chunks
+
+
+def test_int8_kv_pool_holds_more_pages_per_byte():
+    """What an int8 cache buys, as a count from ``page_hbm_bytes`` (the
+    sizing function ``PagedKVCache.create`` is held to in
+    tests/test_kv_cache.py): the same pool bytes hold at least 1.9 x the
+    pages at an 8B bf16 shape with 256-token pages (1.94 x: the fp32 scale
+    planes cost 3 %), and at least 1.75 x at this file's fp32 shape, where
+    2 KV heads pad to 8 scale rows."""
+    from finchat_tpu.engine.kv_cache import page_hbm_bytes
+
+    def ratio(config, page_size):
+        return (page_hbm_bytes(config, page_size)
+                / page_hbm_bytes(config, page_size, "int8"))
+
+    assert 1.9 <= ratio(PRESETS["llama3-8b"], 256) < 2.0
+    assert round(ratio(PRESETS["llama3-8b"], 256), 2) == 1.94
+    assert 1.75 <= ratio(CONFIG, 16) < 4.0
 
 
 # --- quantized embed encoder ------------------------------------------------

@@ -345,6 +345,7 @@ def test_agent_overlap_stream_identical_to_serial():
     retrieval_overlap on equals the serial path byte-for-byte, and the
     overlap run actually grafted (not silently fallen back)."""
     from finchat_tpu.agent.graph import LLMAgent
+    from finchat_tpu.analysis.sanitizers import scheduler_leak_report
     from finchat_tpu.engine.generator import EngineGenerator, StubGenerator
     from finchat_tpu.engine.sampler import SamplingParams
     from finchat_tpu.models.tokenizer import get_tokenizer
@@ -375,10 +376,17 @@ def test_agent_overlap_stream_identical_to_serial():
             return "".join(text)
         finally:
             await sched.stop()
+            # the hold taken during the decision and grafted onto the real
+            # scheduler left no page, slot or refcount behind
+            assert scheduler_leak_report(sched) == []
 
     g0 = METRICS.get("finchat_partial_grafts_total")
+    h0 = METRICS.get("finchat_partial_holds_total")
+    l0 = METRICS.get("finchat_tool_launches_total")
     on = asyncio.run(run(True))
     g1 = METRICS.get("finchat_partial_grafts_total")
+    assert METRICS.get("finchat_partial_holds_total") - h0 >= 1
+    assert METRICS.get("finchat_tool_launches_total") - l0 >= 1
     off = asyncio.run(run(False))
     g2 = METRICS.get("finchat_partial_grafts_total")
     assert on == off and on  # byte-identical, non-empty

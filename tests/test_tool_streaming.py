@@ -62,8 +62,10 @@ async def test_tool_launches_before_decode_completes():
     agent = LLMAgent(tool_gen, StubGenerator(default="ok"), retriever,
                      SYSTEM, TOOL)
     saved0 = METRICS.snapshot().get("finchat_tool_overlap_saved_seconds_sum", 0.0)
+    launches0 = METRICS.get("finchat_tool_launches_total")
     result = await agent.query("what did I spend on coffee?", "u1")
     assert result["retrieved_transactions_count"] == 1
+    assert METRICS.get("finchat_tool_launches_total") - launches0 >= 1
     # the eager launch beat the end of the decision decode ...
     assert retriever.called_at[0] < tool_gen.stream_ended_at
     # ... and the overlap-saved histogram saw nonzero hidden tool time
