@@ -55,7 +55,9 @@ def kv_bytes_per_token(config: dict) -> int:
 def decode_step_stream_bytes(config: dict, *, live_kv_tokens: float, ctx=None) -> float:
     """Bytes one decode step must read at least: every layer's weights and the
     output head once (the embedding is a gather of a few rows), and the live
-    KV of every sequence in the batch. ``ctx`` (the readers' ``Context``) is
+    KV of the batch: ``live_kv_tokens`` = tokens on distinct physical pages
+    (``live_kv.py``; a page that rows share is read once, not once a row:
+    PR 30). ``ctx`` (the readers' ``Context``) is
     for a model that counts what a step touched from a program counter; this
     count needs none."""
     p = param_counts(config)

@@ -16,8 +16,11 @@ from perfbench.costs import BYTES, head_dim
 
 def paged_attention_stream_bytes(config: dict, *, kv_tokens: float) -> float:
     """Bytes ONE layer's paged decode attention call must read at least: the
-    K and the V of every context token of every row in the batch
-    (``kv_tokens`` = Σ rows' context lengths), for each KV head. The queries
-    and the output — one token a row — are a few KiB and are left out."""
+    K and the V of every context token of the batch, for each KV head.
+    ``kv_tokens`` = tokens on distinct physical pages (``live_kv.py``): a
+    page that several rows share is in the pool once and must be read once,
+    so Σ rows' context lengths overstates it wherever rows share a head (PR
+    30; readings before it counted a shared page once a row). The queries and
+    the output — one token a row — are a few KiB and are left out."""
     return (kv_tokens * 2 * int(config["num_key_value_heads"]) * head_dim(config)
             * BYTES[config.get("dtype", "bfloat16")])

@@ -4,7 +4,8 @@
 ``kernel_share``  % of device busy time inside custom calls whose name or
                   stats contain one of ``patterns``.
 ``stream_roofline``  % — the least time a decode step could take to stream
-                  its bytes (weights + live KV, from shapes, the model's adapter) at
+                  its bytes (weights + the live KV on distinct physical pages,
+                  ``live_kv.py``, from shapes, the model's adapter) at
                   the chip's peak bandwidth (``peaks.json``), over the median
                   device time of ``module``. A stream bound of the whole step,
                   named as such; not a kernel's roofline share.

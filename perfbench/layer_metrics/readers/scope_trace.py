@@ -6,11 +6,15 @@ offset between host and device is computed here.
 
 ``scope_share``    % of device busy time in operations under one of ``scopes``.
 ``kernel_stream_roofline``  % — the bytes one call of a kernel must read
-                   (the model's adapter, from the mean ``kv_tokens`` that the
-                   capture's dispatches of ``kinds`` carried, a stat of their
-                   annotation) at the chip's peak bandwidth, over the mean
-                   device time of one call: custom calls under one of
-                   ``scopes`` whose name contains one of ``patterns``.
+                   (the model's adapter, from the tokens on DISTINCT physical
+                   pages that the capture's dispatches of ``kinds`` read:
+                   ``_decode_kv_tokens``) at the chip's peak bandwidth, over
+                   the mean device time of one call: custom calls under one
+                   of ``scopes`` whose name contains one of ``patterns``.
+``kv_distinct_share``  % — of the context tokens those dispatches read, Σ
+                   rows' contexts, the part on distinct physical pages: what
+                   a pass that reads a shared page once has left to read;
+                   100 where no row shares a page.
 ``idle_off_phases``  ms of device idle time inside the capture that none of
                    the ``on`` phases covers (less the ``off`` phases nested
                    in them): the chip waited while the scheduler was not at
@@ -22,6 +26,7 @@ program without the scopes or the annotations.
 from pathlib import Path
 
 from perfbench import costs, trace_reduce, xplane_scopes
+from perfbench.live_kv import LIVE_ANNOTATION
 from perfbench.models import adapter
 
 TRACE_DIR = Path(__file__).resolve().parents[3] / ".perfbench_work" / "trace"
@@ -42,6 +47,9 @@ def read(ctx, *, quantity: str, scopes: list[str] | None = None,
         return 100.0 * sum(table.get(s, 0.0) for s in scopes) / trace.busy_s
     if quantity == "kernel_stream_roofline":
         return _kernel_stream_roofline(ctx, path, set(scopes), patterns, set(kinds))
+    if quantity == "kv_distinct_share":
+        tokens = _decode_kv_tokens(path, set(kinds))
+        return None if tokens is None else 100.0 * tokens[1] / tokens[0]
     if quantity == "idle_off_phases":
         return _idle_off_phases(trace, path, on, off)
     raise ValueError(f"scope_trace cannot read {quantity!r}")
@@ -52,16 +60,37 @@ def _kernel_stream_roofline(ctx, path, scopes, patterns, kinds):
     calls = [dur for _dev, name, kind, _start, dur in xplane_scopes.device_ops(path)
              if kind == "custom-call" and any(p in name for p in patterns)
              and xplane_scopes.scope_of(paths.get(name), scopes) in scopes]
-    kv_tokens = [stats["kv_tokens"]
-                 for events in xplane_scopes.annotations(path).values()
-                 for _name, _start, _end, stats in events
-                 if "kv_tokens" in stats and stats.get("kind") in kinds]
-    if not calls or not kv_tokens:
+    tokens = _decode_kv_tokens(path, kinds) if calls else None
+    if tokens is None:
         return None
-    nbytes = adapter(ctx.model).attention_stream_bytes(
-        ctx.model, kv_tokens=sum(kv_tokens) / len(kv_tokens))
+    nbytes = adapter(ctx.model).attention_stream_bytes(ctx.model, kv_tokens=tokens[1])
     peak = costs.peaks(ctx.device["kind"])["hbm_bytes_per_s"]
     return 100.0 * (nbytes / peak) / (sum(calls) / len(calls) / 1e9)
+
+
+def _decode_kv_tokens(path, kinds):
+    """``(total, distinct)``: the mean ``kv_tokens`` that the capture's
+    dispatches of ``kinds`` noted on their annotation (Σ rows' contexts, the
+    program's stat), and the same less the tokens it counts more than once
+    because rows share their pages: the mean ``kv_tokens - kv_tokens_distinct``
+    of the harness's own samples in the capture (``perfbench/live_kv.py``, an
+    event every 0.25 s). None where no such dispatch noted its context. A
+    capture with the program's stat and without the harness's raises: there
+    is no falling back to a count that reads a shared page once a row."""
+    noted = [stats["kv_tokens"]
+             for events in xplane_scopes.annotations(path).values()
+             for _name, _start, _end, stats in events
+             if "kv_tokens" in stats and stats.get("kind") in kinds]
+    if not noted:
+        return None
+    repeats = [stats["kv_tokens"] - stats["kv_tokens_distinct"]
+               for events in xplane_scopes.annotations(path, LIVE_ANNOTATION).values()
+               for _name, _start, _end, stats in events if "kv_tokens_distinct" in stats]
+    if not repeats:
+        raise ValueError(f"{path}: {len(noted)} dispatches note kv_tokens and no "
+                         f"{LIVE_ANNOTATION} event notes kv_tokens_distinct")
+    total = sum(noted) / len(noted)
+    return total, total - sum(repeats) / len(repeats)
 
 
 def _idle_off_phases(trace, path, on, off):

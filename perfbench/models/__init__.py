@@ -16,6 +16,7 @@
 ``param_counts(config)``, ``kv_bytes_per_token(config)``,
 ``decode_step_stream_bytes(config, *, live_kv_tokens, ctx)``,
 ``attention_stream_bytes(config, *, kv_tokens)``   the yardstick's counts;
+    both token counts are tokens on distinct physical pages (``live_kv.py``);
     ``ctx`` is the readers' ``Context``, so that a model whose step touches
     only some of its experts can count them from a program counter;
 ``WIDTH_KEYS``   the keys of this ``model_type`` that are widths: they may

@@ -239,7 +239,8 @@ def kv_bytes_per_token(config: dict) -> int:
 
 def attention_stream_bytes(config: dict, *, kv_tokens: float) -> float:
     """One layer's paged decode attention call: K and V of every context
-    token of every row, for each of the KV heads."""
+    token of the batch, for each of the KV heads; ``kv_tokens`` = tokens on
+    distinct physical pages (``live_kv.py``), a shared page counted once."""
     return (kv_tokens * 2 * int(config["num_key_value_heads"]) * int(config["head_dim"])
             * BYTES[config.get("dtype", "bfloat16")])
 
@@ -271,8 +272,9 @@ def _rows_of(config: dict, ctx) -> float:
 
 def decode_step_stream_bytes(config: dict, *, live_kv_tokens: float, ctx=None) -> float:
     """Bytes one decode step must move at least: every layer's weights and
-    the head once, the live K/V of every row, and every row's recurrent
-    state read and written once a layer."""
+    the head once, the live K/V of the batch (``live_kv_tokens`` = tokens on
+    distinct physical pages, a shared page counted once), and every row's
+    recurrent state read and written once a layer."""
     p = param_counts(config)
     weights = (p["layers"] + (p["head"] or p["embed"])) * BYTES[config.get("dtype", "bfloat16")]
     state = (int(config["num_hidden_layers"])

@@ -99,10 +99,12 @@ async def measure_window(app, gen, seconds: float, lead_in_s: float, *,
                          drain_limit_s: float):
     """Run the lead-in, the window and the drain on a started generator.
     Returns the Window, the TRACER events inside it, the profiler's capture
-    (or None) and the live-KV samples."""
+    (or None) and the live-KV samples (``live_kv.sample``)."""
+    import jax
+
     from finchat_tpu.utils.metrics import METRICS
     from finchat_tpu.utils.tracing import TRACER
-    from perfbench import correct
+    from perfbench import correct, live_kv
 
     win = Window(gen.t0 + lead_in_s, seconds)
     await asyncio.sleep(max(0.0, win.w0 - time.perf_counter()))
@@ -112,20 +114,26 @@ async def measure_window(app, gen, seconds: float, lead_in_s: float, *,
     win.steps[0] = correct.engine_step_cache_sizes()
     win.compiles[0] = compile_count()
 
-    live_samples: list[int] = []
+    live_samples: list[dict] = []
     sync = None
 
     async def sample_live() -> None:
+        """The rows decoding now, counted by distinct physical pages; each
+        sample also goes into a running capture as an event of its own, so
+        the readers find it on the clock of the kernels."""
         sched = app.scheduler
+        page = sched.engine.page_size
         while True:
-            live_samples.append(sum(len(h.prompt_ids) + h.generated
-                                    for h in list(sched.decoding.values())))
+            s = live_kv.sample(sched.decoding.values(), page)
+            live_samples.append(s)
+            with jax.profiler.TraceAnnotation(
+                    live_kv.LIVE_ANNOTATION, rows=s["rows"], kv_tokens=s["kv_tokens"],
+                    kv_tokens_distinct=s["kv_tokens_distinct"]):
+                pass
             await asyncio.sleep(LIVE_SAMPLE_S)
 
     sampler = asyncio.create_task(sample_live()) if trace_dir is not None else None
     if trace_dir is not None:
-        import jax
-
         capture = min(TRACE_SECONDS, seconds / 3)
         await asyncio.sleep(max(0.0, win.w0 + 0.4 * seconds - time.perf_counter()))
         options = jax.profiler.ProfileOptions()
@@ -330,11 +338,20 @@ async def run_cell(args, cell, jax) -> dict:
             line["breakdown"] = {
                 "device_ops": dtrace.top_ops(10),
                 "idle_gaps": dtrace.idle_gaps(host_label(events, offset), 10)}
+        if live:  # the count beside what it counts (distinct physical pages x the page
+            # size): at the window's middle sample, and where they are closest and
+            # farthest apart — from 0 to under a page a row and an entry
+            def gap(s):
+                return s["page_tokens"] - s["kv_tokens_distinct"]
+            line["live_kv"] = {"samples": len(live), "middle": live[len(live) // 2],
+                               "narrowest_gap": min(live, key=gap), "widest_gap": max(live, key=gap)}
+            say(f"live KV, by distinct physical pages: {json.dumps(line['live_kv'])}")
         ctx = Context(
             w0=win.w0, w1=win.w1, requests=requests, tracer_events=events,
             prom_before=win.prom[0], prom_after=win.prom[1], device_trace=dtrace,
             device=device, model=cell.config,
-            extra={"mean_live_kv_tokens": (sum(live) / len(live)) if live else None})
+            extra={"mean_live_kv_tokens": (sum(s["kv_tokens_distinct"] for s in live)
+                                           / len(live)) if live else None})
         for m in cell.per_layer:
             v = read_metric(m["name"], ctx)
             if v is not None:

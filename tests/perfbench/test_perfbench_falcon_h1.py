@@ -174,14 +174,13 @@ def test_the_three_metrics_are_declared_for_the_cell_alone():
         assert declared[name]["workloads"] == [CELL] and declared[name]["moves"] == "output_tok_s"
         spec = json.loads((ROOT / f"perfbench/layer_metrics/{name}.json").read_text())
         assert (ROOT / f"perfbench/layer_metrics/readers/{spec['reader']}.py").exists()
-    assert [m["name"] for m in BENCH["per_layer"]][-3:] == OURS  # appended, in order
     assert (declared["ssm_state_gb.sat"]["layer"], declared["ssm_state_gb.sat"]["source"]) \
         == ("device", "program_counter")
     cell = next(w for w in BENCH["workloads"] if w["name"] == CELL)
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "falcon-h1-34b-instruct", "report-backlog", 1)
     # every metric without a list is read in the new cell too
-    assert sum("workloads" not in m for m in BENCH["per_layer"]) == 11
+    assert sum("workloads" not in m for m in BENCH["per_layer"]) >= 11
 
 
 def test_pr_24s_metrics_stay_declared_in_order_and_unbroken():
@@ -191,7 +190,7 @@ def test_pr_24s_metrics_stay_declared_in_order_and_unbroken():
     declared = {m["name"]: m for m in BENCH["per_layer"]}
     start = names.index(PR_24[0])
     assert names[start:start + len(PR_24)] == PR_24
-    assert set(names[start + len(PR_24):]) == set(OURS)
+    assert names[start + len(PR_24):][:len(OURS)] == OURS  # then this cell's, then later PRs'
     for name in PR_24:
         assert declared[name].get("workloads") == (
             ["mixtral-report-saturated"] if name == "moe_share.sat" else None)

@@ -2,7 +2,10 @@
 time by scope and the program's annotations on a capture recorded on a v5e
 (``decode_scoped_v5e.xplane.pb``: 0.1 s of the saturated Mixtral cell's decode,
 the device plane's modules and operations with their metadata's ``tf_op``,
-and the host plane's ``finchat.*`` and ``perfbench_sync`` events)."""
+and the host plane's ``finchat.*``, ``perfbench_live`` and ``perfbench_sync``
+events; re-recorded in PR 30 from a traced run of that tree with
+``slice_capture.py``, beside this file, so that it holds the harness's count
+of the KV on distinct physical pages beside the program's dispatch notes)."""
 
 import importlib.util
 import json
@@ -11,6 +14,7 @@ from pathlib import Path
 import pytest
 
 from perfbench import kernel_costs, trace_reduce, xplane_scopes
+from perfbench.live_kv import LIVE_ANNOTATION
 from perfbench.layer_metrics import Context, read_metric
 from perfbench.layer_metrics.readers import scope_trace, startup_gauge, tracer_round
 
@@ -56,7 +60,6 @@ def test_new_metrics_are_declared_with_a_reader_file_each():
         assert declared[name].get("workloads") == (
             ["mixtral-report-saturated"] if name == "moe_share.sat" else None)
         assert (ROOT / f"perfbench/layer_metrics/{name}.json").exists()
-    assert [m["name"] for m in BENCH["per_layer"]][-len(NEW):] == NEW  # appended, in order
     assert declared["startup_warmup_s"]["moves"] == "setup_s"
 
 
@@ -165,14 +168,76 @@ def test_kernel_roofline_reads_the_dispatch_notes_on_the_capture_clock():
     noted = [stats for events in threads.values() for *_x, stats in events
              if "kv_tokens" in stats]
     assert noted and all(s["kind"] == "decode" and s["kv_tokens"] > 16 for s in noted)
+    # the harness's own sample of the same rows, on the same clock: all 16
+    # share the system prompt's pages, which the program's stat counts 16 times
+    live = [stats for events in xplane_scopes.annotations(CAPTURE, LIVE_ANNOTATION).values()
+            for *_x, stats in events]
+    assert live and all(s["rows"] == 16 for s in live)
+    kv = sum(s["kv_tokens"] for s in noted) / len(noted)
+    for s in live:
+        assert s["kv_tokens"] == pytest.approx(kv, rel=0.01)  # one count, two readers
+        assert (s["kv_tokens"] - s["kv_tokens_distinct"]) % (15 * 128) == 0
+    repeats = sum(s["kv_tokens"] - s["kv_tokens_distinct"] for s in live) / len(live)
     value = read_metric("attn_kv_roofline.sat", ctx)
-    assert 30 < value < 100
-    # by hand: mean context tokens x 4 KiB at 819 GB/s over the mean kernel call
+    assert 20 < value < 60
+    # by hand: mean tokens on distinct pages x 4 KiB at 819 GB/s over the mean kernel call
     calls = [dur for _d, name, kind, _s, dur in xplane_scopes.device_ops(CAPTURE)
              if kind == "custom-call" and "paged_flash_attention" in name]
-    kv = sum(s["kv_tokens"] for s in noted) / len(noted)
     assert value == pytest.approx(
-        100 * (kv * 4096 / 819e9) / (sum(calls) / len(calls) / 1e9), rel=1e-6)
+        100 * ((kv - repeats) * 4096 / 819e9) / (sum(calls) / len(calls) / 1e9), rel=1e-6)
+    share = read_metric("kv_distinct_share.sat", ctx)
+    assert share == pytest.approx(100 * (kv - repeats) / kv, rel=1e-9) and 40 < share < 60
+
+
+def _notes(monkeypatch, dispatches, samples):
+    """Hand-made annotations in the capture's place: the program's dispatch
+    notes and the harness's samples."""
+    table = {"finchat.": {"t": [("finchat.stage", i, i + 1, dict(d))
+                                for i, d in enumerate(dispatches)]},
+             LIVE_ANNOTATION: {"t": [(LIVE_ANNOTATION, i, i + 1, dict(d))
+                                     for i, d in enumerate(samples)]} if samples else {}}
+    monkeypatch.setattr(scope_trace.xplane_scopes, "annotations",
+                        lambda _path, prefix="finchat.": table[prefix])
+
+
+def test_distinct_tokens_and_share_against_hand_arithmetic(monkeypatch):
+    _notes(monkeypatch,
+           [{"kind": "decode", "rows": 16, "kv_tokens": 120_000},
+            {"kind": "decode", "rows": 16, "kv_tokens": 120_016},
+            {"kind": "ragged", "rows": 3, "kv_tokens": 9_999},      # another kind: not read
+            {"kind": "decode", "rows": 16, "kv_tokens": 120_032}],
+           [{"rows": 16, "kv_tokens": 119_990, "kv_tokens_distinct": 119_990 - 57_600},
+            {"rows": 16, "kv_tokens": 120_040, "kv_tokens_distinct": 120_040 - 57_600}])
+    assert scope_trace._decode_kv_tokens(CAPTURE, {"decode"}) == (120_016, 120_016 - 57_600)
+    ctx = _context()
+    assert read_metric("kv_distinct_share.sat", ctx) == pytest.approx(100 * 62_416 / 120_016)
+    calls = [dur for _d, name, kind, _s, dur in xplane_scopes.device_ops(CAPTURE)
+             if kind == "custom-call" and "paged_flash_attention" in name]
+    assert read_metric("attn_kv_roofline.sat", ctx) == pytest.approx(
+        100 * (62_416 * 4096 / 819e9) / (sum(calls) / len(calls) / 1e9), rel=1e-9)
+    # no row shares a page: the whole of it is left to read, and 100 is not 0
+    _notes(monkeypatch, [{"kind": "decode", "rows": 2, "kv_tokens": 500}],
+           [{"rows": 2, "kv_tokens": 498, "kv_tokens_distinct": 498}])
+    assert read_metric("kv_distinct_share.sat", ctx) == 100.0
+    # no dispatch of the kind noted its context: nothing to read
+    _notes(monkeypatch, [{"kind": "ragged", "rows": 3, "kv_tokens": 9_999}], [])
+    assert read_metric("kv_distinct_share.sat", ctx) is None
+    assert read_metric("attn_kv_roofline.sat", ctx) is None
+
+
+def test_a_capture_with_the_programs_count_alone_fails_loudly(monkeypatch):
+    """PR 24-29's captures: ``kv_tokens`` on the dispatches and no sample of
+    the harness. There is no falling back to a count that reads a shared
+    page once a row."""
+    _notes(monkeypatch, [{"kind": "decode", "rows": 16, "kv_tokens": 120_000}], [])
+    for metric in ("attn_kv_roofline.sat", "kv_distinct_share.sat"):
+        with pytest.raises(ValueError, match="no perfbench_live event notes kv_tokens_distinct"):
+            read_metric(metric, _context())
+    # a sample without the stat is no sample
+    _notes(monkeypatch, [{"kind": "decode", "rows": 16, "kv_tokens": 120_000}],
+           [{"rows": 16, "kv_tokens": 120_000}])
+    with pytest.raises(ValueError, match="kv_tokens_distinct"):
+        read_metric("attn_kv_roofline.sat", _context())
 
 
 def test_idle_off_phases_counts_only_idle_the_scheduler_did_not_cover():
@@ -246,13 +311,11 @@ def test_wire_reader_agrees_with_the_generated_schema():
     """The field numbers ``op_scope_paths`` reads by hand, held to tsl's
     generated ``xplane_pb2`` — loaded from its file: importing it through
     ``tensorflow`` takes 12 s, which is why the reader does not."""
-    spec = importlib.util.find_spec("tensorflow")
-    if spec is None:
+    if importlib.util.find_spec("tensorflow") is None:
         pytest.skip("no generated xplane_pb2 in this installation")
-    source = Path(spec.origin).parent / "tsl/profiler/protobuf/xplane_pb2.py"
-    module_spec = importlib.util.spec_from_file_location("_xplane_pb2", source)
-    xplane_pb2 = importlib.util.module_from_spec(module_spec)
-    module_spec.loader.exec_module(xplane_pb2)
+    import slice_capture  # beside this file: it cut the capture with the same schema
+
+    xplane_pb2 = slice_capture.xplane_pb2()
     space = xplane_pb2.XSpace()
     space.ParseFromString(CAPTURE.read_bytes())
     expected = {}
