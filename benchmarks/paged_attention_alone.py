@@ -4,16 +4,21 @@
 One ``paged_flash_attention`` call a layer over a 3-layer bf16 cache of 1,600
 pages: 16 rows, 32 query heads, 8 KV heads, head 128, page 128, one query a
 row, contexts drawn between 5k and 12k tokens (mean about 7.75k, as in the
-cell's capture), the first 31 pages of every row physically shared (the
-system prompt). The page table is given at each width of ``--widths``:
+cell's capture), the first ``--shared-pages`` pages (31) of every row
+physically shared (the system prompt), which the kernel's shared-head pass
+reads once for all rows; ``--shared-pages 0`` is the bypass, every page a
+row's own. ``--heads 20 --kv-heads 4`` are Falcon-H1's head counts. The page
+table is given at each width of ``--widths``:
 ``served`` is the engine's 128 (``max_seq_len`` / page), ``live`` the widest
 row's live pages — what the table's dead entries cost is the difference.
 
 Times are device times of the kernel's own operation in a ``jax.profiler``
 capture (the ``XLA Ops`` line), so they are what ``attn_kv_roofline.sat``
-divides by; the bytes are that metric's too (every context token's K and V,
-8 heads x 128 x 2 B each, at 819 GB/s). A wall-clock figure over the same
-calls is printed beside them. Runs on the chip only:
+divides by; the bytes are that metric's too (the K and V of every token on a
+DISTINCT physical page, KV heads x 128 x 2 B each, at 819 GB/s:
+``share_of_distinct_stream_bound``; ``share_of_stream_bound`` counts a shared
+page once a row, as the metric did before PR 30). A wall-clock figure over
+the same calls is printed beside them. Runs on the chip only:
 
     chiprun -- python3 benchmarks/paged_attention_alone.py
     chiprun -- python3 benchmarks/paged_attention_alone.py --tree <checkout>
@@ -32,8 +37,8 @@ import tempfile
 import time
 from pathlib import Path
 
-ROWS, HEADS, KV_HEADS, HEAD_DIM, PAGE = 16, 32, 8, 128, 128
-LAYERS, POOL_PAGES, SERVED_WIDTH, SHARED_PAGES = 3, 1600, 128, 31
+ROWS, HEAD_DIM, PAGE = 16, 128, 128
+LAYERS, POOL_PAGES, SERVED_WIDTH = 3, 1600, 128
 HBM_BYTES_PER_S = 819e9  # one v5e chip (perfbench/peaks.json)
 
 
@@ -45,19 +50,19 @@ def contexts(seed: int):
     return (5000 + 7000 * rng.beta(1.2, 1.85, size=ROWS)).astype(np.int64)
 
 
-def page_table(ctx, width: int, seed: int):
+def page_table(ctx, width: int, seed: int, shared_pages: int):
     """Each row's live pages: the shared head, then private pages drawn
     without replacement from the pool; dead entries are the trash page 0."""
     import numpy as np
 
     rng = np.random.RandomState((seed + 1) % (2 ** 32))
-    private = rng.permutation(np.arange(1 + SHARED_PAGES, POOL_PAGES))
+    private = rng.permutation(np.arange(1 + shared_pages, POOL_PAGES))
     table = np.zeros((ROWS, width), np.int32)
     used = 0
     for row, n in enumerate(-(-ctx // PAGE)):
-        table[row, :SHARED_PAGES] = np.arange(1, 1 + SHARED_PAGES)
-        table[row, SHARED_PAGES:n] = private[used:used + n - SHARED_PAGES]
-        used += n - SHARED_PAGES
+        table[row, :shared_pages] = np.arange(1, 1 + shared_pages)
+        table[row, shared_pages:n] = private[used:used + n - shared_pages]
+        used += n - shared_pages
     return table
 
 
@@ -86,7 +91,12 @@ def main() -> int:
     ap.add_argument("--widths", default="served,live")
     ap.add_argument("--steps", type=int, default=40,
                     help="calls of the three layers in the capture")
+    ap.add_argument("--shared-pages", type=int, default=31,
+                    help="leading pages every row shares (0: none, the bypass)")
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=8)
     args = ap.parse_args()
+    heads, kv_heads, shared_pages = args.heads, args.kv_heads, args.shared_pages
     sys.path.insert(0, args.tree)
 
     import jax
@@ -102,10 +112,10 @@ def main() -> int:
 
     ctx = contexts(args.seed)
     keys = jax.random.split(jax.random.key(args.seed % (2 ** 31)), 3)
-    shape = (LAYERS, POOL_PAGES, PAGE, KV_HEADS * HEAD_DIM)
+    shape = (LAYERS, POOL_PAGES, PAGE, kv_heads * HEAD_DIM)
     k_pages = jax.random.normal(keys[0], shape, jnp.bfloat16)
     v_pages = jax.random.normal(keys[1], shape, jnp.bfloat16)
-    q = jax.random.normal(keys[2], (ROWS, 1, HEADS, HEAD_DIM), jnp.bfloat16)
+    q = jax.random.normal(keys[2], (ROWS, 1, heads, HEAD_DIM), jnp.bfloat16)
     kv_len = jnp.asarray(ctx, jnp.int32)
     q_offset = kv_len - 1
 
@@ -114,19 +124,24 @@ def main() -> int:
         def layer(i, acc):
             return acc + paged_flash_attention(
                 q, k_pages, v_pages, table, q_offset, kv_len, i[None],
-                page_size=PAGE, n_kv=KV_HEADS).astype(jnp.float32)
+                page_size=PAGE, n_kv=kv_heads).astype(jnp.float32)
         return jax.lax.fori_loop(0, LAYERS, layer, jnp.zeros(q.shape, jnp.float32))
 
-    stream_us = 1e6 * int(ctx.sum()) * 2 * KV_HEADS * HEAD_DIM * 2 / HBM_BYTES_PER_S
+    token_us = 1e6 * 2 * kv_heads * HEAD_DIM * 2 / HBM_BYTES_PER_S
+    distinct = int(ctx.sum()) - (ROWS - 1) * shared_pages * PAGE
+    stream_us, distinct_us = token_us * int(ctx.sum()), token_us * distinct
     live_width = int(-(-ctx.max() // PAGE))
     result = {
         "tree": args.tree, "seed": args.seed, "device": jax.devices()[0].device_kind,
-        "context_tokens": int(ctx.sum()), "live_pages": int((-(-ctx // PAGE)).sum()),
-        "widest_row_pages": live_width, "stream_bound_us": stream_us, "widths": {},
+        "heads": heads, "kv_heads": kv_heads, "shared_pages": shared_pages,
+        "context_tokens": int(ctx.sum()), "distinct_page_tokens": distinct,
+        "live_pages": int((-(-ctx // PAGE)).sum()),
+        "widest_row_pages": live_width, "stream_bound_us": stream_us,
+        "distinct_stream_bound_us": distinct_us, "widths": {},
     }
     for name in args.widths.split(","):
         width = {"served": SERVED_WIDTH, "live": live_width}.get(name) or int(name)
-        table = jnp.asarray(page_table(ctx, width, args.seed))
+        table = jnp.asarray(page_table(ctx, width, args.seed, shared_pages))
         out = three_layers(q, k_pages, v_pages, table).block_until_ready()
         assert bool(jnp.isfinite(out).all())
         with tempfile.TemporaryDirectory() as trace_dir:
@@ -145,6 +160,8 @@ def main() -> int:
             "call_us_min_max": [min(calls), max(calls)] if calls else None,
             "wall_us_per_call": wall_us,
             "share_of_stream_bound": 100 * stream_us / call_us if calls else None,
+            "share_of_distinct_stream_bound": (100 * distinct_us / call_us
+                                               if calls else None),
         }
     print(json.dumps(result))
     return 0

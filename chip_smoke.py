@@ -257,17 +257,23 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
                                n_kv: int = 8, head_dim: int = 128,
                                page_size: int = 128, width: int = 128,
                                contexts: tuple[int, int] = (5000, 12000),
-                               pool_pages: int = 1600) -> float:
+                               pool_pages: int = 1600, shared_pages: int = 0,
+                               quantized: bool = False) -> float:
     """Paged decode attention vs its oracle at the decode shape of the
-    benchmark's cell (``mixtral-report-saturated``): ragged contexts under a
-    page table far wider than any row, dead entries on the trash page. The
-    kernel's trash page holds NaN — a dead page read would show — and the
-    oracle, which gathers the table's whole width before it masks, gets the
-    same cache with that page zeroed. Returns the max abs error."""
+    benchmark's cells (``mixtral-report-saturated``; 20 / 4 heads for
+    ``falcon-h1-report-saturated``): ragged contexts under a page table far
+    wider than any row, dead entries on the trash page. The kernel's trash
+    page holds NaN (an int8 cache: NaN scales) — a dead page read would show
+    — and the oracle, which gathers the table's whole width before it masks,
+    gets the same cache with that page zeroed. With ``shared_pages`` every
+    row holds the same physical pages at the head of its table, as the cells'
+    16 rows hold the system prompt's 31: the kernel's shared-head pass reads
+    them once for all rows. Returns the max abs error."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from finchat_tpu.engine.kv_cache import scale_rows
     from finchat_tpu.ops import dispatch
 
     dtype = jnp.bfloat16
@@ -276,28 +282,44 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
     live = -(-ctx // page_size)
     require(live.max() <= width and live.sum() < pool_pages,
             "cell-shape case: the contexts do not fit the table or the pool")
+    require(shared_pages * page_size <= ctx.min(),
+            "cell-shape case: the shared head is longer than a row")
     table = np.zeros((rows, width), np.int32)
     pool = rng.permutation(np.arange(1, pool_pages))
-    used = 0
+    head, used = pool[:shared_pages], shared_pages
     for row, n in enumerate(live):
-        table[row, :n] = pool[used:used + n]
-        used += n
-    k1, k2, k3 = jax.random.split(jax.random.key(25), 3)
+        table[row, :shared_pages] = head
+        table[row, shared_pages:n] = pool[used:used + n - shared_pages]
+        used += n - shared_pages
+    keys = jax.random.split(jax.random.key(25), 5)
     shape = (2, pool_pages, page_size, n_kv * head_dim)  # layer 1 is read
-    k_pages = jax.random.normal(k1, shape, dtype).at[:, 0].set(jnp.nan)
-    v_pages = jax.random.normal(k2, shape, dtype).at[:, 0].set(jnp.nan)
+    # (pages, scales) as the kernel gets them and as the oracle does
+    if quantized:
+        pages = [jax.random.randint(k, shape, -127, 128, jnp.int8) for k in keys[:2]]
+        sshape = (2, pool_pages, scale_rows(n_kv), page_size)
+        scales = [jax.random.uniform(k, sshape, jnp.float32, 0.004, 0.012)
+                  for k in keys[2:4]]
+        names = ("k_scales", "v_scales")
+        poisoned = (pages, dict(zip(names, (x.at[:, 0].set(jnp.nan) for x in scales))))
+        clean = (pages, dict(zip(names, scales)))
+    else:
+        pages = [jax.random.normal(k, shape, dtype) for k in keys[:2]]
+        poisoned = ([x.at[:, 0].set(jnp.nan) for x in pages], {})
+        clean = ([x.at[:, 0].set(0) for x in pages], {})
     kv_len = jnp.asarray(ctx, jnp.int32)
-    q = jax.random.normal(k3, (rows, 1, n_heads, head_dim), dtype)
+    q = jax.random.normal(keys[4], (rows, 1, n_heads, head_dim), dtype)
     rest = (jnp.asarray(table), kv_len - 1, kv_len, jnp.asarray([1], jnp.int32))
     kw = dict(page_size=page_size, n_kv=n_kv)
-    got = dispatch.paged_attention(q, k_pages, v_pages, *rest, backend=backend, **kw)
-    want = dispatch.paged_attention(q, k_pages.at[:, 0].set(0), v_pages.at[:, 0].set(0),
-                                    *rest, backend="ref", **kw)
+    got = dispatch.paged_attention(q, *poisoned[0], *rest, backend=backend, **kw,
+                                   **poisoned[1])
+    want = dispatch.paged_attention(q, *clean[0], *rest, backend="ref", **kw, **clean[1])
     # a non-finite output here means a dead table entry was read
+    name = "paged_attention[decode, cell shape" + (", int8" if quantized else "") + (
+        f", {shared_pages} shared pages]" if shared_pages else "]")
     return kernel_error(
-        "paged_attention[decode, cell shape]", got, want,
-        f"{rows} rows, {int(ctx.sum())} context tokens, {int(live.sum())} live "
-        f"of {rows * width} table entries; ")
+        name, got, want,
+        f"{rows} rows, {n_heads} / {n_kv} heads, {int(ctx.sum())} context tokens, "
+        f"{int(live.sum())} live of {rows * width} table entries; ")
 
 
 def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 32,
@@ -760,6 +782,12 @@ def _run(mesh_model: int) -> int:
                   cfg.engine.page_size, "pallas",
                   prefill_chunk=cfg.engine.prefill_chunk)
     check_decode_at_cell_shape("pallas")
+    # the cells' decode batch: 16 rows on the system prompt's 31 pages, at
+    # both head shapes, float and int8 cache (the shared-head pass)
+    for heads in (dict(n_heads=32, n_kv=8), dict(n_heads=20, n_kv=4)):
+        for quantized in (False, True):
+            check_decode_at_cell_shape("pallas", shared_pages=31,
+                                       quantized=quantized, **heads)
     check_ssm_step_at_cell_shape("pallas")
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half

@@ -193,6 +193,7 @@ def _paged_attention_fn(
     page_table: Array, start_pos: Array, n_valid: Array,
     page_size: int, n_kv: int, attn_backend: str,
     inplace_append: bool = False,
+    decode: bool = False,
 ):
     """Build the model's attention callback for paged prefill/decode.
 
@@ -206,8 +207,21 @@ def _paged_attention_fn(
     verify step, whose few-token chunks would otherwise pay the scatter's
     full-cache copy every step, exactly what the append kernel exists to
     avoid.
+
+    ``decode`` (one token a slot, ``n_valid`` the active mask): the batch's
+    shared head — rows holding the same physical pages at the head of their
+    tables, as rows admitted on one prefix entry do — is read off these three
+    arrays HERE, once a step and outside the layer scan, and the kernel reads
+    those pages once for all of its rows (``ops.paged_attention``).
     """
     interpret = attn_backend == "pallas-interpret"
+    shared = None
+    if decode and attn_backend != "ref":
+        from finchat_tpu.ops.paged_attention import shared_head
+
+        with jax.named_scope("paged_attention"):
+            shared = shared_head(page_table, start_pos + n_valid, page_size,
+                                 n_valid > 0)
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
         k_pages, v_pages, k_scales, v_scales = cache
@@ -253,6 +267,7 @@ def _paged_attention_fn(
                 layer, page_size=page_size, n_kv=n_kv, backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
+                shared=shared,
             )
         return out, (k_pages, v_pages, k_scales, v_scales)
 
@@ -584,7 +599,7 @@ def decode_step(
     # reduce to the legacy absolute math bit-for-bit)
     attention = _paged_attention_fn(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
-        page_size, config.n_kv_heads, attn_backend,
+        page_size, config.n_kv_heads, attn_backend, decode=True,
     )
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
@@ -831,7 +846,7 @@ def _ragged_round_math(
 
             attn = _paged_attention_fn(
                 state.page_table, state.context_lens - state.kv_gaps, n_valid,
-                page_size, config.n_kv_heads, attn_backend,
+                page_size, config.n_kv_heads, attn_backend, decode=True,
             )
             step_logits, (kp, vp, ks, vs) = forward(
                 params, toks, positions,
@@ -1116,7 +1131,7 @@ def decode_loop_step(
         # compacted write/mask coordinates (bounded KV; see decode_step)
         attention = _paged_attention_fn(
             state.page_table, state.context_lens - state.kv_gaps, n_valid,
-            page_size, config.n_kv_heads, attn_backend,
+            page_size, config.n_kv_heads, attn_backend, decode=True,
         )
         logits, (k_pages, v_pages, k_scales, v_scales) = forward(
             params, tokens, positions,

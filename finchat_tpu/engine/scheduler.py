@@ -893,29 +893,48 @@ class ContinuousBatchingScheduler:
         ``[slot, trace_id, mode]`` rows rode it, so a request's exported
         timeline shows every dispatch that carried its rows even when many
         requests share one ragged dispatch. ``riders`` are ``(slot,
-        trace_id, mode, kv)`` per row; Σ kv — the context tokens the
+        trace_id, mode, head, kv)`` per row; Σ kv — the context tokens the
         attention kernel reads, each row's context after the dispatch less
         what a bounded policy evicted — goes with ``kind`` onto the phase
         annotation open now (ISSUE 24), where a profiler capture shows it
-        on the clock of the kernels that read them. Host data only — they
-        come from the membership/descriptor bookkeeping the round already
-        built, so the event adds zero device syncs (finchat-lint R2).
-        Callers guard with ``TRACER.enabled`` so the list is never built
-        for nothing."""
+        on the clock of the kernels that read them. Beside it
+        ``kv_tokens_distinct``, the same tokens with the head of a prefix
+        entry (``head``: the entry and the row's tokens on its read-only
+        pages, None without one) counted once an entry — what a pass that
+        reads a shared page once has to read — and ``prefix_rows``, the
+        rows on the entry whose sharing saves most, 0 where no two rows
+        share one: the decode kernel's shared-head pass (ops/
+        paged_attention.py) finds the same set on the device, from the page
+        tables. Host data only — they come from the membership/descriptor
+        bookkeeping the round already built, so the event adds zero device
+        syncs (finchat-lint R2). Callers guard with ``TRACER.enabled`` so
+        the list is never built for nothing."""
         TRACER.event("dispatch", ts=ts, dur=dur, track=self._trace_track,
                      args={"kind": kind, "n": self._dispatch_tally,
                            "quant": self._quant_label,
                            "rows": [[slot, tid, mode]
-                                    for slot, tid, mode, _kv in riders]})
-        self._phases.note(kind=kind, rows=len(riders),
-                          kv_tokens=sum(kv for *_row, kv in riders))
+                                    for slot, tid, mode, _head, _kv in riders]})
+        heads: dict[int, list[int]] = {}  # entry: its rows' tokens on its pages
+        for *_row, head, _kv in riders:
+            if head is not None:
+                heads.setdefault(head[0], []).append(head[1])
+        kv_tokens = sum(kv for *_row, kv in riders)
+        most = max(heads.values(), key=lambda t: (len(t) - 1) * max(t), default=[])
+        self._phases.note(
+            kind=kind, rows=len(riders), kv_tokens=kv_tokens,
+            kv_tokens_distinct=kv_tokens - sum(sum(t) - max(t) for t in heads.values()),
+            prefix_rows=len(most) if len(most) > 1 else 0)
 
     @staticmethod
-    def _rider(handle: SequenceHandle, mode: str) -> tuple:
+    def _rider(handle: SequenceHandle, mode: str, drafts: int = 0) -> tuple:
         """A sequence's row of a dispatch, read AFTER the dispatch advanced
-        its ``kv_ctx``."""
-        return (handle.slot, handle.trace_id or handle.seq_id, mode,
-                handle.kv_ctx - handle.kv_gap)
+        its ``kv_ctx``; a verify row also reads its own ``drafts``."""
+        kv = handle.kv_ctx - handle.kv_gap
+        head = None
+        if handle.prefix_entry is not None and handle.shared_len:
+            head = (id(handle.prefix_entry), min(handle.shared_len, kv))
+        return (handle.slot, handle.trace_id or handle.seq_id, mode, head,
+                kv + drafts)
 
     async def _fetch(self, fn):
         """Await ``fn()`` — the device→host copies of a dispatch's results —
@@ -2730,7 +2749,7 @@ class ContinuousBatchingScheduler:
                 job.pos += int(n_valids[i])
             if TRACER.enabled:
                 riders = [self._rider(h, "prefill") for h in batch]
-                riders += [(j.slot, f"prefix:{j.owner}", "prefix", j.pos)
+                riders += [(j.slot, f"prefix:{j.owner}", "prefix", None, j.pos)
                            for j in jobs]
                 self._trace_dispatch("prefill", riders,
                                      ts=_pt.started, dur=_pt.elapsed)
@@ -2993,7 +3012,7 @@ class ContinuousBatchingScheduler:
             if kind == "job":
                 if traced:
                     riders.append((slot, f"prefix:{owner.owner}", "freerun",
-                                   owner.pos + adv))
+                                   None, owner.pos + adv))
                 if adv:
                     owner.pos += adv
                     if owner.pos >= owner.shared_len:
@@ -3324,17 +3343,15 @@ class ContinuousBatchingScheduler:
             # bookkeeping (ISSUE 12): every (slot, trace, mode) row that
             # rode this one ragged dispatch, from host data only
             riders = [self._rider(h, "prefill") for _i, h in prefill_rows]
-            riders += [(j.slot, f"prefix:{j.owner}", "prefix", j.pos)
+            riders += [(j.slot, f"prefix:{j.owner}", "prefix", None, j.pos)
                        for _i, j in job_rows]
             riders += [self._rider(h, "constrained")
                        for _i, _slot, h, _e in constrained_decode]
             riders += [
                 self._rider(h, "decode_loop" if loop_active[slot] else "decode")
                 for _i, slot, h, _e in plain_rows]
-            riders += [
-                (slot, h.trace_id or h.seq_id, "spec",
-                 h.kv_ctx - h.kv_gap + int(row_n_drafts[i]))
-                for i, slot, h, _e in spec_rows]
+            riders += [self._rider(h, "spec", int(row_n_drafts[i]))
+                       for i, _slot, h, _e in spec_rows]
             self._trace_dispatch("ragged", riders,
                                  ts=_mt.started, dur=_mt.elapsed)
         for _idx, job in job_rows:
@@ -3794,8 +3811,7 @@ class ContinuousBatchingScheduler:
         if TRACER.enabled:
             # a verify row reads its context and its own drafts
             self._trace_dispatch("spec", [
-                (slot, h.trace_id or h.seq_id, "spec",
-                 h.kv_ctx - h.kv_gap + int(n_drafts[slot]))
+                self._rider(h, "spec", int(n_drafts[slot]))
                 for slot, h, _e in members
             ])
         emitted, n_emitted, logits = result if need_logits else (*result, None)

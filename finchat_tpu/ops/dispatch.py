@@ -59,10 +59,13 @@ def paged_attention(
     backend: str | None = None,
     k_scales: Array | None = None,  # int8 cache: [L, P, SPAD, page_size] fp32
     v_scales: Array | None = None,
+    shared: tuple[Array, Array] | None = None,  # decode: ``shared_head``'s
 ) -> Array:
     """Paged-KV attention via the requested (or default) backend. An int8
     cache (engine kv_quant) is detected from the page dtype; the scale
-    arrays must then be provided."""
+    arrays must then be provided. ``shared`` hands the kernel a decode
+    batch's shared head where the caller derived it once for every layer
+    (ops/paged_attention.py ``shared_head``; the reference has no use for it)."""
     backend = backend or attention_backend()
     quantized = k_pages.dtype == jnp.int8
     if quantized:
@@ -85,13 +88,13 @@ def paged_attention(
 
         return paged_flash_attention_q8(
             q, k_pages, v_pages, k_scales, v_scales, page_table,
-            q_offset, kv_len, layer,
+            q_offset, kv_len, layer, shared,
             page_size=page_size, n_kv=n_kv, interpret=interpret,
         )
     from finchat_tpu.ops.paged_attention import paged_flash_attention
 
     return paged_flash_attention(
-        q, k_pages, v_pages, page_table, q_offset, kv_len, layer,
+        q, k_pages, v_pages, page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, interpret=interpret,
     )
 

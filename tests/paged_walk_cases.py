@@ -9,6 +9,11 @@ table is ``width`` entries wide whatever the rows hold, and every entry at or
 beyond a row's live pages points at a page that poisons whatever reads it
 (NaN values; for the int8 cache NaN scales): a dead page read is a failed
 test, not a slower one.
+
+``SHARED_CASES`` are decode batches (C = 1) in which rows hold the same
+physical pages at the head of their tables, as rows admitted on one prefix
+entry do: the kernel's first pass reads such a head once for all of its rows
+(``ops.paged_attention.shared_head`` says which it takes).
 """
 
 import jax.numpy as jnp
@@ -23,14 +28,42 @@ EDGE_CONTEXTS = [0, 1, PAGE_SIZE - 1, PAGE_SIZE, PAGE_SIZE + 1, BLOCK - 1, BLOCK
 SHAPES = [(1, 1), (4, 1), (8, 1), (4, 3), (4, 8), (4, 32)]
 DEAD_PAGE = 1  # physical page 0 is the writers' trash page; both are poisoned
 
+P = PAGE_SIZE
+# name: (contexts, heads = [(rows, pages of the first row's that they all hold)],
+#        the rows shared_head takes, the pages it takes)
+SHARED_CASES = {
+    "all_rows": ([5 * P + 3, 7 * P, 4 * P + 1, 9 * P + 17], [((0, 1, 2, 3), 4)],
+                 (0, 1, 2, 3), 4),
+    "a_subset": ([3 * P, 5 * P + 9, 70, 4 * P + 2, 6 * P, 1], [((1, 3, 4), 3)],
+                 (1, 3, 4), 3),
+    # 1 x 5 pages saved against 2 x 3: the larger set is taken, the other walks alone
+    "two_heads": ([6 * P + 1, 7 * P + 5, 4 * P, 5 * P + 30, 3 * P + 1],
+                  [((0, 1), 5), ((2, 3, 4), 3)], (2, 3, 4), 3),
+    "no_sharing": ([3 * P + 1, 2 * P, 5 * P + 7], [], (), 0),
+    # a member ending exactly on the last shared page, and one token past it
+    "ends_on_the_head": ([8 * P + 11, 4 * P, 4 * P + 1], [((0, 1, 2), 4)], (0, 1, 2), 4),
+    # a member shorter than the others' common run cuts the run to its whole pages
+    "a_short_member": ([8 * P + 11, 6 * P + 2, 2 * P + 10], [((0, 1, 2), 5)],
+                       (0, 1, 2), 2),
+    # inactive rows (table row all 0, no token) beside a set
+    "inactive_rows": ([0, 5 * P - 1, 0, 4 * P + 8, 3 * P + 40], [((1, 3, 4), 3)],
+                      (1, 3, 4), 3),
+    # a head longer than one block of 8 pages, its last block partial
+    "a_long_head": ([12 * P + 5, 11 * P + 40, WIDTH * P], [((0, 1, 2), 11)],
+                    (0, 1, 2), 11),
+}
+
 
 def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
-              page_size=PAGE_SIZE, n_kv=2, head_dim=32, seed=0):
+              page_size=PAGE_SIZE, n_kv=2, head_dim=32, seed=0, heads=()):
     """Returns ``(q, sources, page_table, q_offset, kv_len, layer, k_dense,
     v_dense)``: ``sources`` is ``(k_pages, v_pages)`` or, quantized, ``(k_pages,
     v_pages, k_scales, v_scales)`` with layer 1 of 2 filled; the dense pair
     ``[B, width*page_size, n_kv, head_dim]`` is what the oracle attends to
-    (for the int8 cache: the dequantized values)."""
+    (for the int8 cache: the dequantized values). ``heads`` lists ``(rows,
+    pages)``: those rows hold the first row's first ``pages`` physical pages
+    (as many as each has live) and so its tokens there; a row without a token
+    then has a table row of zeros, as a slot that was never admitted."""
     rng = np.random.RandomState(seed)
     B, hd_fused = len(contexts), n_kv * head_dim
     live = [-(-n // page_size) for n in contexts]
@@ -44,9 +77,19 @@ def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
         table[b, :live[b]] = phys[used:used + live[b]]
         used += live[b]
         dense[:, b, :n] = rng.randn(2, n, hd_fused)
-    if quantized:  # integers and per-token-per-head scales; dense = dequantized
+    if quantized:
         ints = rng.randint(-127, 128, size=dense.shape).astype(np.float32)
         tok_scales = rng.uniform(0.004, 0.012, size=(2, B, width * page_size, n_kv))
+    own = np.ones((B, width), bool)  # the row fills this page of its table
+    if heads:
+        table[[b for b, n in enumerate(contexts) if n == 0]] = 0
+    for rows, n_pages in heads:
+        for b in rows[1:]:
+            held, n = min(n_pages, live[b]), min(contexts[b], n_pages * page_size)
+            table[b, :held], own[b, :held] = table[rows[0], :held], False
+            for values in (dense, *((ints, tok_scales) if quantized else ())):
+                values[:, b, :n] = values[:, rows[0], :n]
+    if quantized:  # integers and per-token-per-head scales; dense = dequantized
         valid = np.arange(width * page_size)[None, :] < np.asarray(contexts)[:, None]
         ints *= valid[None, :, :, None]
         dense = (ints.reshape(2, B, -1, n_kv, head_dim)
@@ -56,7 +99,7 @@ def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
         scales[:, :, :2] = np.nan
     stored = ints if quantized else dense
     for b in range(B):
-        for p in range(live[b]):
+        for p in np.flatnonzero(own[b, :live[b]]):
             span = slice(p * page_size, (p + 1) * page_size)
             pages[:, 1, table[b, p]] = stored[:, b, span]
             if quantized:  # [k/v, tokens, n_kv] -> [k/v, n_kv, tokens]

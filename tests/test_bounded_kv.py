@@ -345,7 +345,9 @@ def _two_turn(params, *, disk="", fresh_for_turn2=False):
     if fresh_for_turn2:
         # restart: a NEW scheduler over the same disk directory must
         # restore the record (RAM tier starts empty)
-        if sched.session_cache is not None and sched.session_cache.disk:
+        # `is not None`: the tier's truth value is its index size, 0 while
+        # the write-behind spill is still in flight — exactly when to flush
+        if sched.session_cache is not None and sched.session_cache.disk is not None:
             sched.session_cache.disk.flush()
         sched2 = _sched(params, session=True, disk=disk)
 

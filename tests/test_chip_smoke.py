@@ -36,14 +36,19 @@ def test_kernels_against_oracles_interpret():
         "kv_append", "ragged_paged_attention", "flash_attention"}
 
 
-def test_decode_at_cell_shape_interpret():
+@pytest.mark.parametrize("shared_pages,quantized", [(0, False), (3, False), (3, True)],
+                         ids=["no_head", "shared_head", "shared_head_int8"])
+def test_decode_at_cell_shape_interpret(shared_pages, quantized):
     """The cell-shaped decode case at a small size: a table four times as
-    wide as the longest row, a NaN trash page under its dead entries."""
+    wide as the longest row, a NaN trash page under its dead entries; and
+    with every row on the same three pages at the head of its table, as the
+    cells' rows are on the system prompt's."""
     model = PRESETS["tiny"]
     error = chip_smoke.check_decode_at_cell_shape(
         "pallas-interpret", rows=4, n_heads=model.n_heads, n_kv=model.n_kv_heads,
-        head_dim=model.head_dim, page_size=8, width=32, contexts=(9, 60),
-        pool_pages=40)
+        head_dim=model.head_dim, page_size=8, width=32,
+        contexts=(25 if shared_pages else 9, 60), pool_pages=40,
+        shared_pages=shared_pages, quantized=quantized)
     assert error < chip_smoke.KERNEL_ATOL + chip_smoke.KERNEL_RTOL * 4
 
 

@@ -13,15 +13,17 @@ import numpy as np
 import pytest
 
 from paged_walk_cases import (
+    DEAD_PAGE,
     PAGE_SIZE,
     SHAPES,
+    SHARED_CASES,
     assert_matches_reference,
     walk_case,
 )
 
 from finchat_tpu.engine.kv_cache import gather_kv, scatter_kv_chunk
 from finchat_tpu.ops.flash_attention import flash_attention
-from finchat_tpu.ops.paged_attention import paged_flash_attention
+from finchat_tpu.ops.paged_attention import paged_flash_attention, shared_head
 from finchat_tpu.ops.refs import mha_reference
 
 INTERPRET = jax.default_backend() != "tpu"
@@ -176,6 +178,66 @@ def test_paged_walk_edges_match_reference(group, C):
     assert_matches_reference(out, ref, atol=ATOL, rtol=RTOL)
 
 
+@pytest.mark.parametrize("name", SHARED_CASES)
+def test_shared_head_is_read_off_the_page_tables(name):
+    """Which rows and how many leading pages the decode pass takes, from the
+    page tables, the contexts and the active mask alone."""
+    contexts, heads, rows, pages = SHARED_CASES[name]
+    _, _, table, _, kv_len, *_ = walk_case(4, 1, contexts=contexts, heads=heads)
+    member, head = shared_head(table, kv_len, PAGE_SIZE, kv_len > 0)
+    assert tuple(np.flatnonzero(np.asarray(member))) == rows
+    assert int(head[0]) == pages
+    if rows:
+        assert int(head[1]) in rows
+
+
+def test_shared_head_leaves_out_rows_that_are_not_active():
+    """A slot that is not decoding keeps its old table row: it is no member,
+    and its short context does not cut the others' run."""
+    contexts, heads, rows, pages = SHARED_CASES["a_short_member"]
+    _, _, table, _, kv_len, *_ = walk_case(4, 1, contexts=contexts, heads=heads)
+    member, head = shared_head(table, kv_len, PAGE_SIZE, jnp.asarray([True, True, False]))
+    assert (member.tolist(), int(head[0])) == ([1, 1, 0], 5)
+
+
+@pytest.mark.parametrize("group", [1, 4, 5, 8])
+@pytest.mark.parametrize("name", SHARED_CASES)
+def test_paged_decode_with_a_shared_head_matches_reference(name, group):
+    """Rows holding the same physical pages at the head of their tables: the
+    pass that reads those pages once for all of them, then each row's own
+    walk behind them, against the dense oracle; every dead table entry is on
+    the NaN page."""
+    contexts, heads, *_ = SHARED_CASES[name]
+    q, sources, table, q_offset, kv_len, layer, k_dense, v_dense = walk_case(
+        group, 1, contexts=contexts, heads=heads)
+    out = paged_flash_attention(
+        q, *sources, table, q_offset, kv_len, layer,
+        page_size=PAGE_SIZE, n_kv=2, interpret=INTERPRET,
+    )
+    ref = mha_reference(q, k_dense, v_dense, causal=True, q_offset=q_offset, kv_len=kv_len)
+    assert_matches_reference(out, ref, contexts, atol=ATOL, rtol=RTOL)
+
+
+@pytest.mark.parametrize("name", ["all_rows", "two_heads", "a_long_head"])
+def test_paged_decode_reads_a_shared_head_through_one_row_alone(name):
+    """A member's own walk starts behind the shared pages: with every member
+    but the leading one pointing its head columns at the NaN page, the result
+    is still the oracle's."""
+    contexts, heads, rows, pages = SHARED_CASES[name]
+    q, sources, table, q_offset, kv_len, layer, k_dense, v_dense = walk_case(
+        4, 1, contexts=contexts, heads=heads)
+    member, head = shared_head(table, kv_len, PAGE_SIZE)
+    lead = int(head[1])
+    table = np.array(table)
+    table[[b for b in rows if b != lead], :pages] = DEAD_PAGE
+    out = paged_flash_attention(
+        q, *sources, jnp.asarray(table), q_offset, kv_len, layer, (member, head),
+        page_size=PAGE_SIZE, n_kv=2, interpret=INTERPRET,
+    )
+    ref = mha_reference(q, k_dense, v_dense, causal=True, q_offset=q_offset, kv_len=kv_len)
+    assert_matches_reference(out, ref, contexts, atol=ATOL, rtol=RTOL)
+
+
 def _pallas_grid(jaxpr):
     """The grid of the first ``pallas_call`` in a (nested) jaxpr."""
     for eqn in jaxpr.eqns:
@@ -314,6 +376,120 @@ def test_engine_end_to_end_pallas_backend():
         return out
 
     assert run("ref") == run("pallas-interpret")
+
+
+def _decode_logits_on_both_backends(engine, state, active):
+    """``decode_step`` from one state on ``ref`` and on the kernels
+    (interpret mode here); the step donates its state, so each gets a copy."""
+    from finchat_tpu.engine.engine import decode_step
+
+    B = active.shape[0]
+    zeros, ones, zk = jnp.zeros((B,)), jnp.ones((B,)), jnp.zeros((B,), jnp.int32)
+
+    def logits(backend):
+        _, _, out = decode_step(
+            engine.params, jax.tree.map(jnp.copy, state), active, zeros, ones, zk,
+            config=engine.config, page_size=engine.page_size,
+            attn_backend=backend, return_logits=True)
+        return np.asarray(out)[np.asarray(active)]
+
+    return logits("ref"), logits("pallas" if not INTERPRET else "pallas-interpret")
+
+
+def test_decode_step_over_a_scheduler_built_shared_head_matches_ref():
+    """Three rows admitted on ONE prefix entry, as the scheduler lays them
+    out (the same four physical pages at the head of each page table): the
+    decode step through the kernels, shared-head pass engaged, gives the
+    ``ref`` backend's logits."""
+    import asyncio
+    import dataclasses
+
+    from finchat_tpu.engine.engine import InferenceEngine
+    from finchat_tpu.engine.sampler import SamplingParams
+    from finchat_tpu.engine.scheduler import ContinuousBatchingScheduler
+    from finchat_tpu.models.llama import PRESETS, init_params
+    from finchat_tpu.utils.config import EngineConfig
+
+    page, head = 8, list(range(1, 33))  # four whole pages
+    config = dataclasses.replace(PRESETS["tiny"], dtype=jnp.float32)
+    engine = InferenceEngine(
+        config, init_params(config, jax.random.key(0)),
+        EngineConfig(max_seqs=4, page_size=page, num_pages=128, max_seq_len=256,
+                     prefill_chunk=16, session_cache=False, mixed_step=False),
+        attn_backend="ref")
+    caught = []
+    decode = engine.decode
+
+    def spy(active, *args, **kw):
+        if not caught and int(np.sum(np.asarray(active))) == 3:
+            caught.append((jax.tree.map(jnp.copy, engine.state), jnp.asarray(active)))
+        return decode(active, *args, **kw)
+
+    engine.decode = spy
+
+    async def drain(handle):
+        while (await handle.events.get())["type"] == "token":
+            pass
+
+    async def go():
+        sched = ContinuousBatchingScheduler(engine, eos_id=-1)
+        assert sched.register_prefix(head + [99]) == len(head)
+        await sched.start()
+        try:
+            sampling = SamplingParams(temperature=0.0, max_new_tokens=8)
+            handles = [await sched.submit(f"r{i}", head + tail, sampling)
+                       for i, tail in enumerate([[40, 41, 42], [50] * 9, [60, 61]])]
+            await asyncio.wait_for(asyncio.gather(*map(drain, handles)), timeout=240)
+        finally:
+            await sched.stop()
+
+    asyncio.run(go())
+    assert caught, "the three rows never decoded together"
+    state, active = caught[0]
+    member, shared = shared_head(
+        state.page_table, state.context_lens + active, page, active)
+    assert (int(member.sum()), int(shared[0])) == (3, 4)
+    want, got = _decode_logits_on_both_backends(engine, state, active)
+    np.testing.assert_allclose(got, want, atol=1e-4 if INTERPRET else 5e-2, rtol=1e-4)
+
+
+def test_decode_step_with_a_gapped_row_in_the_shared_head_matches_ref():
+    """Bounded KV: a row whose policy evicted pages behind the pinned sink
+    keeps the sink at the head of its (compacted) page list. The sink pages
+    it shares with two unbounded rows are read in the shared pass, its window
+    behind them at compacted positions, as ``ref`` reads them."""
+    import dataclasses
+
+    from finchat_tpu.engine.engine import InferenceEngine
+    from finchat_tpu.models.llama import PRESETS, init_params
+    from finchat_tpu.utils.config import EngineConfig
+
+    page = 8
+    config = dataclasses.replace(PRESETS["tiny"], dtype=jnp.float32)
+    engine = InferenceEngine(
+        config, init_params(config, jax.random.key(0)),
+        EngineConfig(max_seqs=4, page_size=page, num_pages=64, max_seq_len=128,
+                     prefill_chunk=16, session_cache=False, mixed_step=False),
+        attn_backend="ref")
+    state = engine.state
+    table = np.zeros(state.page_table.shape, np.int32)
+    table[0, :7] = [3, 4, 5, 10, 11, 12, 13]
+    table[1, :5] = [3, 4, 5, 20, 21]
+    table[3, :6] = [3, 4, 5, 30, 31, 32]  # slot 2 stays inactive
+    contexts = np.array([50, 36, 0, 41 + 3 * page], np.int32)  # absolute
+    gaps = np.array([0, 0, 0, 3 * page], np.int32)  # three pages evicted
+    pools = [jax.random.normal(jax.random.key(i), pool.shape, pool.dtype)
+             for i, pool in enumerate((state.k_pages, state.v_pages))]
+    state = dataclasses.replace(
+        state, k_pages=pools[0], v_pages=pools[1], page_table=jnp.asarray(table),
+        context_lens=jnp.asarray(contexts), kv_gaps=jnp.asarray(gaps),
+        last_tokens=jnp.asarray([5, 6, 0, 7], jnp.int32))
+    active = jnp.asarray([True, True, False, True])
+    member, shared = shared_head(
+        state.page_table, state.context_lens - state.kv_gaps + active, page, active)
+    assert (member.tolist(), int(shared[0])) == ([1, 1, 0, 1], 3)
+    want, got = _decode_logits_on_both_backends(engine, state, active)
+    np.testing.assert_allclose(got, want, atol=1e-4 if INTERPRET else 5e-2, rtol=1e-4)
 
 
 # --- int8-KV (q8) kernels -------------------------------------------------

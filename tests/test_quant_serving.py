@@ -642,6 +642,6 @@ def test_quant_metrics_preseeded_and_dispatch_traced(params):
     assert "finchat_quant_dequant_fallbacks_total" in snap
     assert "finchat_quant_envelope_exceeded_total" in snap
     TRACER.configure(enabled=True)
-    sched._trace_dispatch("decode", [(0, "tid", "decode", 8)])  # (slot, id, mode, kv)
+    sched._trace_dispatch("decode", [(0, "tid", "decode", None, 8)])  # (slot, id, mode, head, kv)
     ev = TRACER.snapshot()[-1]
     assert ev[2] == "dispatch" and ev[5]["quant"] == "int4+kv8"

@@ -8,7 +8,9 @@ record:
   to the round's length — in every loop branch — and tracing never changes
   the tokens.
 - DISPATCH: the ``kv_tokens`` a dispatch notes on its open phase annotation
-  is the sum of the contexts of the rows that rode it.
+  is the sum of the contexts of the rows that rode it; ``kv_tokens_distinct``
+  counts a prefix entry's head once (the yardstick's ``perfbench.live_kv``
+  count of the same handles) and ``prefix_rows`` the rows sharing one.
 - REQUEST: the ``request`` span says how it ended and how large it was.
 - SLOW ROUND: a stalled event loop leaves one WARNING that names the phase.
 - SCOPES: every ``DEVICE_SCOPES`` name is in the compiled steps' ``op_name``
@@ -169,9 +171,14 @@ def test_every_loop_branch_leaves_rounds_that_add_up(branch):
     for (kind_seen, riders, noted), ev in zip(riders_seen, dispatches):
         args = ev[5]
         assert args["kind"] == kind_seen
-        assert args["rows"] == [[slot, tid, mode] for slot, tid, mode, _kv in riders]
+        assert args["rows"] == [[slot, tid, mode]
+                                for slot, tid, mode, _head, _kv in riders]
+        # no prefix entry in these runs: every token is on a page of its row's own
+        assert all(head is None for *_row, head, _kv in riders)
         assert noted == {"kind": kind_seen, "rows": len(riders),
-                         "kv_tokens": sum(kv for *_row, kv in riders)}
+                         "kv_tokens": sum(kv for *_row, kv in riders),
+                         "kv_tokens_distinct": sum(kv for *_row, kv in riders),
+                         "prefix_rows": 0}
         assert all(kv >= 1 for *_row, kv in riders), riders
     # tracing on or off, the streams are the same tokens
     tokens_off, _riders, _tally = _run_branch(options, arrival, traced=False)
@@ -191,6 +198,69 @@ def test_decode_dispatch_reads_each_row_context():
                                         traced=True, n_new=6)
     decode = [noted["kv_tokens"] for kind, _r, noted in seen if kind == "decode"]
     assert decode == [len(PROMPT_A) + k for k in range(1, len(decode) + 1)]
+
+
+HEAD_A = list(range(1, 33))  # four whole pages
+HEAD_B = list(range(101, 117))  # two whole pages
+SHARED_HEADS = {
+    # name: (prompts, heads registered, the largest set of rows on one head,
+    #        tokens that Σ rows' contexts counts more than once)
+    "one_head": ([HEAD_A + [40, 41, 42], HEAD_A + [50] * 9, HEAD_A + [60, 61]],
+                 [HEAD_A], 3, 2 * 32),
+    "two_heads": ([HEAD_A + [40, 41, 42], HEAD_A + [50] * 9, HEAD_B + [60, 61],
+                   HEAD_B + [70] * 5], [HEAD_A, HEAD_B], 2, 32 + 16),
+    "a_head_of_one_row": ([HEAD_A + [40, 41, 42], [3] * 21, [4] * 9], [HEAD_A], 0, 0),
+    "no_head": ([[7, 8, 9] * 5, [9, 8, 7] * 7, [5] * 11], [], 0, 0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHARED_HEADS))
+def test_decode_dispatch_notes_the_distinct_tokens_and_the_shared_rows(case):
+    """Rows admitted on prefix entries decoding together: the annotation's
+    ``kv_tokens_distinct`` is Σ rows' contexts with each entry's head once —
+    the count the benchmark keeps for itself (``perfbench.live_kv``) on the
+    same handles — and ``prefix_rows`` the rows of the set the decode
+    kernel's shared-head pass takes."""
+    from perfbench import live_kv
+
+    prompts, heads, prefix_rows, repeats = SHARED_HEADS[case]
+    seen, notes = [], []
+
+    async def go():
+        sched = _scheduler(mixed_step=False)
+        for head in heads:
+            assert sched.register_prefix(head + [99]) == len(head)
+        trace_dispatch = sched._trace_dispatch
+
+        def spy(kind, riders, **kw):
+            trace_dispatch(kind, riders, **kw)
+            if kind == "decode" and len(riders) == len(prompts):
+                slots = {slot for slot, *_rest in riders}
+                riding = [h for h in sched.decoding.values() if h.slot in slots]
+                seen.append((notes[-1], live_kv.kv_tokens(riding)))
+
+        sched._trace_dispatch = spy
+        await sched.start()
+        try:
+            sampling = SamplingParams(temperature=0.0, max_new_tokens=12)
+            handles = [await sched.submit(f"r{i}", p, sampling, trace_id=f"r{i}")
+                       for i, p in enumerate(prompts)]
+            await asyncio.wait_for(asyncio.gather(*map(_drain, handles)), timeout=240)
+        finally:
+            await sched.stop()
+
+    note = RoundPhases.note
+    RoundPhases.note = lambda self, **numbers: (notes.append(numbers),
+                                                note(self, **numbers))[1]
+    try:
+        asyncio.run(go())
+    finally:
+        RoundPhases.note = note
+    assert len(seen) >= 4, "the rows never decoded together"
+    for noted, (total, distinct) in seen:
+        assert noted["rows"] == len(prompts) and noted["prefix_rows"] == prefix_rows
+        assert noted["kv_tokens"] == total
+        assert noted["kv_tokens_distinct"] == distinct == total - repeats
 
 
 # --- the request span -------------------------------------------------------
