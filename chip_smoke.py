@@ -49,6 +49,7 @@ import sys
 import threading
 import time
 import urllib.request
+from functools import partial
 
 # the driver allows 1200 s; past this the process dumps every thread's stack
 # and exits non-zero on its own instead of hanging until it is killed
@@ -293,26 +294,40 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
         used += n - shared_pages
     keys = jax.random.split(jax.random.key(25), 5)
     shape = (2, pool_pages, page_size, n_kv * head_dim)  # layer 1 is read
-    # (pages, scales) as the kernel gets them and as the oracle does
-    if quantized:
-        pages = [jax.random.randint(k, shape, -127, 128, jnp.int8) for k in keys[:2]]
-        sshape = (2, pool_pages, scale_rows(n_kv), page_size)
-        scales = [jax.random.uniform(k, sshape, jnp.float32, 0.004, 0.012)
-                  for k in keys[2:4]]
-        names = ("k_scales", "v_scales")
-        poisoned = (pages, dict(zip(names, (x.at[:, 0].set(jnp.nan) for x in scales))))
-        clean = (pages, dict(zip(names, scales)))
-    else:
-        pages = [jax.random.normal(k, shape, dtype) for k in keys[:2]]
-        poisoned = ([x.at[:, 0].set(jnp.nan) for x in pages], {})
-        clean = ([x.at[:, 0].set(0) for x in pages], {})
+    # the trash page set in place (at 30 KV heads K and V are 3.1 GB each: a
+    # second copy of the cache for the oracle would not fit beside the first)
+    trash = jax.jit(lambda x, value: x.at[:, 0].set(value), donate_argnums=0)
     kv_len = jnp.asarray(ctx, jnp.int32)
     q = jax.random.normal(keys[4], (rows, 1, n_heads, head_dim), dtype)
     rest = (jnp.asarray(table), kv_len - 1, kv_len, jnp.asarray([1], jnp.int32))
     kw = dict(page_size=page_size, n_kv=n_kv)
-    got = dispatch.paged_attention(q, *poisoned[0], *rest, backend=backend, **kw,
-                                   **poisoned[1])
-    want = dispatch.paged_attention(q, *clean[0], *rest, backend="ref", **kw, **clean[1])
+
+    def oracle(pages, **scales):
+        # a row at a time: the oracle gathers a row's whole table, 0.12 GB of
+        # K at 30 KV heads, 2 GB for 16 rows at once
+        return jnp.concatenate([
+            dispatch.paged_attention(q[r:r + 1], *pages, rest[0][r:r + 1], rest[1][r:r + 1],
+                                     rest[2][r:r + 1], rest[3], backend="ref", **kw, **scales)
+            for r in range(rows)])
+
+    if quantized:
+        pages = [jax.random.randint(k, shape, -127, 128, jnp.int8) for k in keys[:2]]
+        sshape = (2, pool_pages, scale_rows(n_kv), page_size)
+        poisoned = [trash(jax.random.uniform(k, sshape, jnp.float32, 0.004, 0.012), jnp.nan)
+                    for k in keys[2:4]]
+        names = ("k_scales", "v_scales")
+        got = dispatch.paged_attention(q, *pages, *rest, backend=backend, **kw,
+                                       **dict(zip(names, poisoned)))
+        # the trash page's scales are whatever the oracle likes: its int8 page
+        # is masked, and zero scales keep the product finite
+        clean = [trash(x, 0.0) for x in poisoned]
+        want = oracle(pages, **dict(zip(names, clean)))
+    else:
+        pages = [trash(jax.random.normal(k, shape, dtype), jnp.nan) for k in keys[:2]]
+        got = jax.block_until_ready(
+            dispatch.paged_attention(q, *pages, *rest, backend=backend, **kw))
+        pages = [trash(x, 0) for x in pages]
+        want = oracle(pages)
     # a non-finite output here means a dead table entry was read
     name = "paged_attention[decode, cell shape" + (", int8" if quantized else "") + (
         f", {shared_pages} shared pages]" if shared_pages else "]")
@@ -320,6 +335,28 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
         name, got, want,
         f"{rows} rows, {n_heads} / {n_kv} heads, {int(ctx.sum())} context tokens, "
         f"{int(live.sum())} live of {rows * width} table entries; ")
+
+
+def device_ops_us(run, calls: int) -> list[tuple[str, float]]:
+    """``(operation name, microseconds)`` of every device operation in a
+    profiler capture of ``calls`` calls of ``run()`` (which returns an array
+    of the last call to wait on)."""
+    import tempfile
+    from pathlib import Path
+
+    import jax
+
+    with tempfile.TemporaryDirectory() as trace_dir:
+        jax.profiler.start_trace(trace_dir)
+        for _ in range(calls):
+            out = run()
+        out.block_until_ready()
+        jax.profiler.stop_trace()
+        path = next(Path(trace_dir).rglob("*.xplane.pb"))
+        return [(ev.name.split(" = ")[0], ev.duration_ns / 1e3)
+                for plane in jax.profiler.ProfileData.from_file(str(path)).planes
+                if plane.name.startswith("/device:TPU:")
+                for line in plane.lines if line.name == "XLA Ops" for ev in line.events]
 
 
 def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 32,
@@ -335,9 +372,6 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     against its stream bound (every row's state read and written once at
     819 GB/s: what ``ssm_state_roofline.sat`` divides by) — the number to
     tune ``ops/ssm_step.py``'s block size by."""
-    import tempfile
-    from pathlib import Path
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -383,17 +417,12 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     if backend != "pallas":
         return errors
 
-    with tempfile.TemporaryDirectory() as trace_dir:
-        jax.profiler.start_trace(trace_dir)
-        for _ in range(timed_calls):
-            y, ssm_state = ssm_state_step(ssm_state, xs, dt, A, Bm, Cm, D, at)
-        y.block_until_ready()
-        jax.profiler.stop_trace()
-        path = next(Path(trace_dir).rglob("*.xplane.pb"))
-        ops = [(ev.name.split(" = ")[0], ev.duration_ns / 1e3)
-               for plane in jax.profiler.ProfileData.from_file(str(path)).planes
-               if plane.name.startswith("/device:TPU:")
-               for line in plane.lines if line.name == "XLA Ops" for ev in line.events]
+    def once():
+        nonlocal ssm_state
+        y, ssm_state = ssm_state_step(ssm_state, xs, dt, A, Bm, Cm, D, at)
+        return y
+
+    ops = device_ops_us(once, timed_calls)
     kernel = [us for name, us in ops if "ssm_state_step" in name]
     require(len(kernel) == timed_calls,
             f"kernel ssm_state_step: {len(kernel)} custom calls in a capture of {timed_calls}")
@@ -405,6 +434,86 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
         f"{min(kernel):.1f}, max {max(kernel):.1f}), {errors['call_us']:.1f} us with the "
         f"operations around it; stream bound {bound_us:.1f} us: "
         f"{100 * bound_us / errors['call_us']:.1f} % of it")
+    return errors
+
+
+def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 30,
+                                key_dim: int = 96, value_dim: int = 192,
+                                layers: int = 6, layer: int = 4,
+                                timed_calls: int = 20) -> dict[str, float]:
+    """The one-token gated-delta-rule update (``models/gdn.py`` ``_step`` over
+    the whole slot batch in place, as ``decode_step`` runs it) vs the
+    recurrence as it is written, in float64, at the shape of the benchmark's
+    cell (``olmo-hybrid-report-saturated``): 16 rows of 30 heads of 96 x 192
+    float32 in layer 4 of 6, one row inert. ``o`` and the layer's new state to
+    float32 round-off; the inert row and every other layer bit for bit. On the
+    chip (``pallas``: this update is XLA's, no kernel yet) also its device time
+    from a profiler capture against its stream bound (every row's state read
+    and written once at 819 GB/s: what ``gdn_state_roofline.sat`` divides by)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.models import gdn
+    from finchat_tpu.models.ssm import SsmRows, _read, _write
+
+    f32 = jnp.float32
+    ks = jax.random.split(jax.random.key(32), 6)
+    state = 0.3 * jax.random.normal(ks[0], (layers, rows, heads, key_dim, value_dim), f32)
+    q = gdn._l2norm(jax.random.normal(ks[1], (rows, heads, key_dim), f32)) * key_dim ** -0.5
+    k = gdn._l2norm(jax.random.normal(ks[2], (rows, heads, key_dim), f32))
+    v = jax.random.normal(ks[3], (rows, heads, value_dim), f32)
+    g = -jax.random.uniform(ks[4], (rows, heads), f32, 0.01, 1.5).at[1].set(0.0)
+    beta = jax.random.uniform(ks[5], (rows, heads), f32, 0.0, 2.0).at[1].set(0.0)
+    at = jnp.asarray([layer], jnp.int32)
+    whole = SsmRows(None, jnp.ones((rows,), jnp.int32))
+
+    @partial(jax.jit, donate_argnums=0)
+    def update(state, q, k, v, g, beta, at):
+        with jax.named_scope("gdn_scan"):
+            o, new = gdn._step(_read(state, at, whole), q, k, v, g, beta)
+            return o, _write(state, new, at, whole)
+
+    S = np.asarray(state[layer], np.float64)
+    q64, k64, v64, g64, b64 = (np.asarray(t, np.float64) for t in (q, k, v, g, beta))
+    S = np.exp(g64)[..., None, None] * S
+    u = b64[..., None] * (v64 - np.einsum("nhkv,nhk->nhv", S, k64))
+    want_new = S + k64[..., :, None] * u[..., None, :]
+    want_o = np.einsum("nhkv,nhk->nhv", want_new, q64)
+    before = np.asarray(state)
+    o, state = update(state, q, k, v, g, beta, at)
+    after = np.asarray(state)
+    errors = {}
+    for name, got, want in (("o", o, want_o), ("state", after[layer], want_new)):
+        got = np.asarray(got)
+        require(np.isfinite(got).all(), f"gdn step: non-finite {name}")
+        errors[name] = float(np.abs(got - want).max())
+        require(np.allclose(got, want, rtol=1e-5, atol=1e-4),
+                f"gdn step: {name} off the recurrence (max abs err {errors[name]:.3g})")
+    untouched = [i for i in range(layers) if i != layer]
+    require(np.array_equal(after[untouched], before[untouched]),
+            "gdn step: a layer the update does not name changed")
+    require(np.array_equal(after[layer, 1], before[layer, 1]),
+            "gdn step: the inert row's state changed")
+    say(f"gdn step: ok (layer {layer} of {layers}, {rows} rows; max abs err "
+        f"o {errors['o']:.3g}, state {errors['state']:.3g})")
+    if backend != "pallas":
+        return errors
+
+    def once():
+        nonlocal state
+        o, state = update(state, q, k, v, g, beta, at)
+        return o
+
+    small = (2 * heads * key_dim + 2 * heads * value_dim + 2 * heads) * 4
+    bound_us = 1e6 * rows * (2 * heads * key_dim * value_dim * 4 + small) / 819e9
+    by_op: dict[str, float] = {}
+    for name, us in device_ops_us(once, timed_calls):
+        by_op[name] = by_op.get(name, 0.0) + us / timed_calls
+    errors.update(call_us=sum(by_op.values()), bound_us=bound_us)
+    say(f"gdn step: {errors['call_us']:.1f} us a call in {len(by_op)} operations ("
+        + ", ".join(f"{name} {us:.1f}" for name, us in sorted(by_op.items(), key=lambda x: -x[1])[:6])
+        + f"); stream bound {bound_us:.1f} us: {100 * bound_us / errors['call_us']:.1f} % of it")
     return errors
 
 
@@ -789,6 +898,10 @@ def _run(mesh_model: int) -> int:
             check_decode_at_cell_shape("pallas", shared_pages=31,
                                        quantized=quantized, **heads)
     check_ssm_step_at_cell_shape("pallas")
+    # a layer pattern's two kinds of layer at their cell's shape: the paged
+    # kernel at one query head a KV head, and the delta rule's one-token update
+    check_decode_at_cell_shape("pallas", shared_pages=31, n_heads=30, n_kv=30)
+    check_gdn_step_at_cell_shape("pallas")
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half
     parity_cfg = dataclasses.replace(

@@ -103,12 +103,15 @@ class DecodeState:
     last_tokens: Array  # [max_seqs] int32 — next decode input per slot
     kv_gaps: Array  # [max_seqs] int32 — evicted tokens (bounded KV; 0 = none)
     rng: Array
-    # the second kind of per-row state (a model with a mixer, models/ssm.py):
-    # held by SLOT, not by page, and never shared — a row that starts from a
-    # shared head starts from a COPY of the head's snapshot. (1, ...)
-    # placeholders for a model without a mixer, as k_scales has
-    ssm_state: Array  # [L, max_seqs, H, P, N] float32 — the recurrence's state
-    conv_state: Array  # [L, max_seqs, K-1, C] float32 — the conv's last inputs
+    # the second kind of per-row state (a model with a mixer, models/ssm.py,
+    # or with linear-attention layers, models/gdn.py): held by SLOT, not by
+    # page, and never shared — a row that starts from a shared head starts
+    # from a COPY of the head's snapshot. (1, ...) placeholders for a model
+    # without either, as k_scales has. The two kinds of cache have their own
+    # depths: the pool the layers' that own pages (config.n_attn_layers),
+    # these the layers' that carry state (config.n_state_layers)
+    ssm_state: Array  # [Ls, max_seqs, *config.state_shape] float32 — the recurrence's state
+    conv_state: Array  # [Ls, max_seqs, K-1, C] float32 — the conv's last inputs
 
 
 def create_state(
@@ -134,14 +137,12 @@ def create_state(
 
 def _ssm_leaves(config: LlamaConfig, max_seqs: int) -> dict[str, Array]:
     c = config
-    if not c.ssm_heads:
+    if not c.has_state:
         return {"ssm_state": jnp.zeros((1, 1, 1, 1, 1), jnp.float32),
                 "conv_state": jnp.zeros((1, 1, 1, 1), jnp.float32)}
     return {
-        "ssm_state": jnp.zeros(
-            (c.n_layers, max_seqs, c.ssm_heads, c.ssm_head_dim, c.ssm_state), jnp.float32),
-        "conv_state": jnp.zeros(
-            (c.n_layers, max_seqs, c.ssm_conv - 1, c.ssm_conv_dim), jnp.float32),
+        "ssm_state": jnp.zeros((c.n_state_layers, max_seqs, *c.state_shape), jnp.float32),
+        "conv_state": jnp.zeros((c.n_state_layers, max_seqs, *c.conv_shape), jnp.float32),
     }
 
 
@@ -152,7 +153,7 @@ def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array,
     with the caches it advanced: the K/V pool and, for a model with a mixer,
     the recurrent state (``ssm_rows`` says whose state each batch row is)."""
     cache = (state.k_pages, state.v_pages, state.k_scales, state.v_scales)
-    if not config.ssm_heads:
+    if not config.has_state:
         out, cache = forward(params, tokens, positions, config=config,
                              attention=attention, cache=cache, **kw)
         ssm = (state.ssm_state, state.conv_state)
@@ -776,7 +777,7 @@ def _ragged_round_math(
     ssm_rows = SsmRows(
         row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
         width=min(T, max_row_tokens or T), backend=attn_backend,
-    ) if config.ssm_heads else None
+    ) if config.has_state else None
     hidden, state = _forward_cached(
         params, state, tok_in[None], tok_pos[None],
         config=config, attention=attention, ssm_rows=ssm_rows,
@@ -1350,7 +1351,7 @@ class InferenceEngine:
         # aligned blocks when Hkv % 8 == 0, replicated — they're ~6% of the
         # pages — otherwise), and the SP-prefill write path quantizes too
         self.kv_quant = kv_quant = engine_cfg.kv_quant
-        if config.ssm_heads:
+        if config.has_state:
             self._refuse_without_state_carry(mesh)
         state = create_state(config, engine_cfg, self.max_pages_per_seq, kv_quant=kv_quant)
         if mesh is not None:
@@ -1380,7 +1381,8 @@ class InferenceEngine:
         self.sp_mode = self._resolve_sp_mode(engine_cfg.sp_mode)
 
     def _refuse_without_state_carry(self, mesh) -> None:
-        """A model with a mixer (``config.ssm_heads``) keeps a recurrent
+        """A model with a mixer or with linear-attention layers
+        (``config.has_state``) keeps a recurrent
         state a row cannot be rewound over and that no page holds. The steps
         that carry it are ``prefill_step``, ``decode_step`` and
         ``ragged_mixed_step``; every option that reaches another step, or
@@ -1397,15 +1399,16 @@ class InferenceEngine:
         named = [option for option, on in refused.items() if on]
         if named:
             raise ValueError(
-                f"a model with a Mamba-2 mixer (ssm_heads={self.config.ssm_heads}) "
-                f"carries its recurrent state through prefill_step, decode_step and "
+                f"a model with recurrent state ({self.config.n_state_layers} layers: a "
+                f"Mamba-2 mixer or linear-attention layers) "
+                f"carries it through prefill_step, decode_step and "
                 f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
 
     @property
     def ssm_state_bytes(self) -> int:
         """Device bytes of the recurrent state and the conv tails (0 for a
         model without a mixer): the second kind of per-row memory."""
-        if not self.config.ssm_heads:
+        if not self.config.has_state:
             return 0
         return int(self.state.ssm_state.nbytes + self.state.conv_state.nbytes)
 
@@ -1413,7 +1416,7 @@ class InferenceEngine:
         """A copy of ``slot``'s recurrent state and conv tail, every layer
         (device arrays) — what a shared head keeps beside its pages. None
         for a model without a mixer."""
-        if not self.config.ssm_heads:
+        if not self.config.has_state:
             return None
         return _ssm_read_slot(self.state.ssm_state, self.state.conv_state, jnp.int32(slot))
 
@@ -1563,7 +1566,7 @@ class InferenceEngine:
             last_tokens=self.state.last_tokens.at[idx].set(0),
             kv_gaps=self.state.kv_gaps.at[idx].set(0),
         )
-        if self.config.ssm_heads:
+        if self.config.has_state:
             self._ssm_clear(slots)
 
     def _ssm_clear(self, slots: list[int]) -> None:
@@ -1915,7 +1918,7 @@ class InferenceEngine:
         for n in sorted({min(round_up_pow2(k), B) for k in range(1, B + 1)}):
             self.logits_rows(step_logits, [0] * n)
         del step_logits
-        if self.config.ssm_heads:
+        if self.config.has_state:
             # the state's own three programs (admission from a head's
             # snapshot, the reset of a slot, the snapshot itself): slot 0 is
             # zero here, so reading and restoring it changes nothing
@@ -2058,7 +2061,7 @@ class InferenceEngine:
         """What only a model with a mixer passes to ``ragged_mixed_step``
         (jit keys on the keywords a call passes: the others' calls stay as
         warm-up compiled them): no row of a round is longer than a chunk."""
-        if not self.config.ssm_heads:
+        if not self.config.has_state:
             return {}
         return {"max_row_tokens": self.engine_cfg.prefill_chunk}
 

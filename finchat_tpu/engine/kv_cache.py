@@ -76,14 +76,16 @@ class PagedKVCache:
     @classmethod
     def create(cls, config: LlamaConfig, num_pages: int, page_size: int,
                kv_quant: str = "") -> "PagedKVCache":
+        # the pool has the depth of the layers that own pages: every layer,
+        # or a layer pattern's full-attention layers alone
         shape = (
-            config.n_layers, num_pages, page_size,
+            config.n_attn_layers, num_pages, page_size,
             config.n_kv_heads * config.head_dim,
         )
         if kv_quant:
             if kv_quant != "int8":
                 raise ValueError(f"unknown kv_quant mode {kv_quant!r} (supported: 'int8')")
-            sshape = (config.n_layers, num_pages, scale_rows(config.n_kv_heads), page_size)
+            sshape = (shape[0], num_pages, scale_rows(config.n_kv_heads), page_size)
             return cls(
                 k_pages=jnp.zeros(shape, jnp.int8),
                 v_pages=jnp.zeros(shape, jnp.int8),
@@ -111,8 +113,8 @@ class PagedKVCache:
 
 
 def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "") -> int:
-    """HBM bytes ONE page costs across all layers (K+V, plus the int8
-    scale rows) — computed WITHOUT allocating, so harnesses can fit a KV
+    """HBM bytes ONE page costs across all layers that own pages (K+V, plus
+    the int8 scale rows) — computed WITHOUT allocating, so harnesses can fit a KV
     pool to an HBM budget before engine construction. Mirrors
     ``PagedKVCache.create``'s shapes exactly (asserted in
     tests/test_kv_cache.py)."""
@@ -120,9 +122,9 @@ def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "") -> i
 
     row = config.n_kv_heads * config.head_dim
     itemsize = 1 if kv_quant else np.dtype(config.dtype).itemsize
-    per = 2 * config.n_layers * page_size * row * itemsize
+    per = 2 * config.n_attn_layers * page_size * row * itemsize
     if kv_quant:
-        per += 2 * config.n_layers * scale_rows(config.n_kv_heads) * page_size * 4
+        per += 2 * config.n_attn_layers * scale_rows(config.n_kv_heads) * page_size * 4
     return per
 
 

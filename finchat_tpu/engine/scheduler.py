@@ -595,14 +595,14 @@ class ContinuousBatchingScheduler:
         # head, a session resume and the warm fabric have no state to start
         # from, so those rows recompute from their tokens (counted) or the
         # option is refused here
-        self.has_ssm = bool(engine.config.ssm_heads)
+        self.has_ssm = engine.config.has_state
         self.metrics.set_gauge("finchat_ssm_state_bytes",
                                getattr(engine, "ssm_state_bytes", 0))
         if self.has_ssm:
             if fabric is not None:
                 raise ValueError(
                     "fabric.path: the warm-state fabric's head and session records "
-                    "hold pages only; a model with a Mamba-2 mixer cannot resume "
+                    "hold pages only; a model with recurrent state cannot resume "
                     "from them (no recurrent state in the record)")
             for kind in ("head", "session"):
                 self.metrics.inc("finchat_ssm_snapshots_total", 0.0,
@@ -638,8 +638,8 @@ class ContinuousBatchingScheduler:
             self.has_ssm and cfg.session_cache and cfg.session_cache_bytes > 0)
         if self._ssm_session_fallback:
             logger.info("session cache off: its entries hold no recurrent state "
-                        "(ssm_heads=%d); resumed turns recompute from their tokens",
-                        engine.config.ssm_heads)
+                        "(%d layers carry it); resumed turns recompute from their tokens",
+                        engine.config.n_state_layers)
         elif cfg.session_cache and cfg.session_cache_bytes > 0:
             from finchat_tpu.engine.session_cache import (
                 SessionDiskTier,

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array, lax
 
+from finchat_tpu.models import gdn
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
 from finchat_tpu.models.ssm import mixer, scaled
 
@@ -32,6 +33,11 @@ from finchat_tpu.models.ssm import mixer, scaled
 #   fn(q[B,S,H,D], k[B,S,Hkv,D], v[B,S,Hkv,D], layer_cache, layer_idx) ->
 #   (out[B,S,H,D], new_layer_cache)
 AttentionFn = Callable[[Array, Array, Array, Any, Array], tuple[Array, Any]]
+
+# the kinds of layer a ``layer_pattern`` may name (the published configs' own
+# words): softmax attention over the paged cache, or the gated delta rule over
+# a recurrent state by slot (models/gdn.py). Each is followed by the MLP
+FULL, LINEAR = "full_attention", "linear_attention"
 
 
 @dataclass(frozen=True)
@@ -42,7 +48,7 @@ class LlamaConfig:
     n_heads: int = 4
     n_kv_heads: int = 2
     hidden_dim: int = 256
-    rope_theta: float = 10_000.0
+    rope_theta: float | None = 10_000.0  # None = q and k are not rotated
     norm_eps: float = 1e-5
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
@@ -74,10 +80,79 @@ class LlamaConfig:
     ssm_in_multiplier: float = 1.0
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple[float, ...] = (1.0,) * 5  # z, xs, B, C, dt
+    # layers of more than one kind: the kinds of ONE period, repeated down the
+    # depth (Olmo-Hybrid: three LINEAR, then one FULL). Empty = every layer
+    # alike, today's block. Parameters and caches are stacked by KIND: only
+    # FULL layers own pages, only LINEAR ones (or the mixer above) state
+    layer_pattern: tuple[str, ...] = ()
+    # RMSNorm over the whole width of q and of k, before the heads are split
+    qk_norm: bool = False
+    # the Olmo family's placement: a norm on each sub-block's OUTPUT and none
+    # on its input, x = x + Norm(f(x))
+    norm_after: bool = False
+    # the LINEAR layers' gated delta rule (models/gdn.py); gdn_heads 0 = none
+    gdn_heads: int = 0
+    gdn_key_dim: int = 0  # a head's keys and queries
+    gdn_value_dim: int = 0  # a head's values
+    gdn_conv: int = 4  # width of the causal depthwise conv over q, k, v
+    gdn_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues allowed
 
     def __post_init__(self) -> None:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        pattern = self.layer_pattern
+        if pattern:
+            if set(pattern) - {FULL, LINEAR} or self.n_layers % len(pattern):
+                raise ValueError(
+                    f"layer_pattern {pattern}: kinds are {FULL!r} and {LINEAR!r}, and "
+                    f"n_layers ({self.n_layers}) is a whole number of periods")
+            if self.ssm_heads:
+                raise ValueError("a layer_pattern and a Mamba-2 mixer in every layer "
+                                 "(ssm_heads) do not combine")
+        if (LINEAR in pattern) != bool(self.gdn_heads):
+            raise ValueError(f"gdn_heads and {LINEAR!r} layers in layer_pattern go together")
+
+    def n_of(self, kind: str) -> int:
+        """Layers of ``kind``: the depth of that kind's stacks."""
+        if not self.layer_pattern:
+            return self.n_layers if kind == FULL else 0
+        return self.layer_pattern.count(kind) * (self.n_layers // len(self.layer_pattern))
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that own K/V pages: the depth of the page pool."""
+        return self.n_of(FULL)
+
+    @property
+    def n_state_layers(self) -> int:
+        """Layers that carry recurrent state by slot: the depth of
+        ``DecodeState.ssm_state`` / ``conv_state``."""
+        return self.n_layers if self.ssm_heads else self.n_of(LINEAR)
+
+    @property
+    def has_state(self) -> bool:
+        """This model carries recurrent state (a mixer in every layer, or
+        LINEAR layers): what no page holds and no row can be rewound over."""
+        return self.n_state_layers > 0
+
+    @property
+    def state_shape(self) -> tuple[int, ...]:
+        """One slot's recurrent state in one layer (float32)."""
+        if self.gdn_heads:
+            return (self.gdn_heads, self.gdn_key_dim, self.gdn_value_dim)
+        return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
+    def conv_shape(self) -> tuple[int, ...]:
+        """One slot's conv tail in one layer (float32): the last K-1 inputs."""
+        if self.gdn_heads:
+            return (self.gdn_conv - 1, self.gdn_conv_dim)
+        return (self.ssm_conv - 1, self.ssm_conv_dim)
+
+    @property
+    def gdn_conv_dim(self) -> int:
+        """Channels through the LINEAR layer's conv: [q | k | v]."""
+        return self.gdn_heads * (2 * self.gdn_key_dim + self.gdn_value_dim)
 
     @property
     def d_ssm(self) -> int:
@@ -133,13 +208,20 @@ def n_params(config: LlamaConfig) -> int:
     mlp = 3 * d * c.hidden_dim
     if c.n_experts:
         mlp = mlp * c.n_experts + d * c.n_experts  # experts + router
-    per_layer = attn + mlp + 2 * d
+    if c.qk_norm:
+        attn += (c.n_heads + c.n_kv_heads) * hd
+    per_layer = mlp + 2 * d
     if c.ssm_heads:
         # in/out projections, conv weight and bias, A_log, dt_bias, D, the
         # gated norm's weight
         per_layer += (d * c.ssm_in_dim + c.d_ssm * d
                       + (c.ssm_conv + 1) * c.ssm_conv_dim + 3 * c.ssm_heads + c.d_ssm)
-    total = c.vocab_size * d + c.n_layers * per_layer + d
+    # [q | k | v | gate] and [b | a] in, out, the conv, A_log, dt_bias, the norm
+    d_v = c.gdn_heads * c.gdn_value_dim
+    linear = (d * (c.gdn_conv_dim + d_v + 2 * c.gdn_heads) + d_v * d
+              + c.gdn_conv * c.gdn_conv_dim + 2 * c.gdn_heads + c.gdn_value_dim)
+    total = (c.vocab_size * d + c.n_layers * per_layer + c.n_attn_layers * attn
+             + c.n_of(LINEAR) * linear + d)
     if not c.tie_embeddings:
         total += d * c.vocab_size
     return total
@@ -167,6 +249,10 @@ def init_params(
       layers/ssm_{in,out,conv_w,conv_b,A_log,dt_bias,D,norm}[L, ...] (with
       ``ssm_heads``; the recurrence's own A_log, dt_bias, D stay float32)
       norm[dim], lm_head[dim, vocab] (absent when tie_embeddings)
+    With a ``layer_pattern`` the stacks are by KIND (``_stack_kind``): the
+    ``attn_*`` leaves (``attn_{q,k}_norm`` with ``qk_norm``) have the FULL
+    layers' depth, the ``gdn_*`` leaves the LINEAR layers', the MLP and the
+    two norms every layer's; without one this is the tree it always was.
 
     ``leaf_transform(name, array)`` is applied to each MATMUL weight at
     creation, before the next leaf materializes — so e.g. int8 quantization
@@ -186,13 +272,14 @@ def init_params(
 
     keys = jax.random.split(k_layers, 8)
     L, D, H, Hkv, hd, F = c.n_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
+    La = c.n_attn_layers
     params: dict[str, Any] = {
         "embed": rand_init("embed", k_embed, (c.vocab_size, D), D),
         "layers": {
-            "attn_q": rand_init("attn_q", keys[0], (L, D, H * hd), D),
-            "attn_k": rand_init("attn_k", keys[1], (L, D, Hkv * hd), D),
-            "attn_v": rand_init("attn_v", keys[2], (L, D, Hkv * hd), D),
-            "attn_o": rand_init("attn_o", keys[3], (L, H * hd, D), H * hd),
+            "attn_q": rand_init("attn_q", keys[0], (La, D, H * hd), D),
+            "attn_k": rand_init("attn_k", keys[1], (La, D, Hkv * hd), D),
+            "attn_v": rand_init("attn_v", keys[2], (La, D, Hkv * hd), D),
+            "attn_o": rand_init("attn_o", keys[3], (La, H * hd, D), H * hd),
             "ln_attn": jnp.ones((L, D), c.dtype),
             "ln_mlp": jnp.ones((L, D), c.dtype),
         },
@@ -240,9 +327,23 @@ def init_params(
                 "ssm_norm": jnp.ones((L, c.d_ssm), c.dtype),
             }
         )
+    if c.qk_norm:
+        params["layers"].update({"attn_q_norm": jnp.ones((La, H * hd), c.dtype),
+                                 "attn_k_norm": jnp.ones((La, Hkv * hd), c.dtype)})
+    if c.gdn_heads:
+        params["layers"].update(gdn.init_params(
+            c, jax.random.fold_in(k_layers, 2), c.n_of(LINEAR), rand_init))
     if not c.tie_embeddings:
         params["lm_head"] = rand_init("lm_head", k_head, (D, c.vocab_size), D)
     return params
+
+
+def _stack_kind(name: str) -> str | None:
+    """Whose depth the stacked leaf ``name`` has under a ``layer_pattern``:
+    one kind's layers, or (None) every layer."""
+    if name.startswith("gdn_"):
+        return LINEAR
+    return FULL if name.startswith("attn_") else None
 
 
 @jax.named_scope("norm")
@@ -330,6 +431,7 @@ def _layer(
     qm_backend: str | None = None,
     ssm_cache: Any = None,
     ssm_rows: Any = None,
+    kind: str = FULL,
 ) -> tuple[Array, Any] | tuple[Array, Any, Any]:
     """One decoder layer. Under GSPMD (the usual path) ``tp_axis`` is
     None — the compiler partitions from the param shardings. Under an
@@ -344,49 +446,78 @@ def _layer(
     With ``config.ssm_heads`` the Mamba-2 mixer (models/ssm.py) reads the
     same normed input as attention, its output joins attention's in the
     residual, and the layer returns ``(x, cache, ssm_cache)``: the recurrent
-    state rides beside the KV cache (``ssm_rows`` says whose it is)."""
+    state rides beside the KV cache (``ssm_rows`` says whose it is).
+
+    ``kind`` is the layer's place in ``config.layer_pattern``: a LINEAR layer
+    runs the gated delta rule (models/gdn.py) in attention's place, over the
+    recurrent state alone; ``layer_idx`` is the layer's index among its OWN
+    kind, which is how the caches are stacked. With ``config.norm_after`` the
+    two norms stand on the sub-blocks' outputs instead of their inputs."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
     hkv = c.n_kv_heads // tp_size
 
-    h = rms_norm(x, layer_params["ln_attn"], c.norm_eps)
-    if c.ssm_heads:
-        assert tp_axis is None, "manual-TP stage blocks have no mixer"
-        mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
-                                 qm_backend=qm_backend)
-    with jax.named_scope("attn_qkv"):
-        h = scaled(h, c.attention_in_multiplier)
-        q = dense(h, layer_params["attn_q"], qm_backend=qm_backend).reshape(B, S, hq, c.head_dim)
-        k = scaled(dense(h, layer_params["attn_k"], qm_backend=qm_backend),
-                   c.key_multiplier).reshape(B, S, hkv, c.head_dim)
-        v = dense(h, layer_params["attn_v"], qm_backend=qm_backend).reshape(B, S, hkv, c.head_dim)
-        q = rope(q, positions, c.rope_theta)
-        k = rope(k, positions, c.rope_theta)
+    def norm_in(x: Array, weight: Array) -> Array:
+        return x if c.norm_after else rms_norm(x, weight, c.norm_eps)
 
-    # the attention callback opens its own scopes (engine/engine.py)
-    attn_out, new_layer_cache = attention(q, k, v, layer_cache, layer_idx)
-    with jax.named_scope("attn_o"):
-        if tp_axis is not None:
-            from finchat_tpu.ops.tp_overlap import row_parallel_dense
+    def norm_out(y: Array, weight: Array) -> Array:
+        return rms_norm(y, weight, c.norm_eps) if c.norm_after else y
 
-            attn_proj = row_parallel_dense(
-                attn_out.reshape(B, S, -1), layer_params["attn_o"], tp_axis,
-                overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
-            )
-        else:
-            attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
-                              qm_backend=qm_backend)
-        x = x + scaled(attn_proj, c.attention_out_multiplier)
+    h = norm_in(x, layer_params["ln_attn"])
+    if kind == LINEAR:
+        assert tp_axis is None, "manual-TP stage blocks have no linear-attention layers"
+        mixed, ssm_cache = gdn.mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
+                                     qm_backend=qm_backend)
+        with jax.named_scope("gdn_out"):
+            x = x + norm_out(mixed, layer_params["ln_attn"])
+        new_layer_cache = layer_cache
+    else:
         if c.ssm_heads:
-            x = x + mixed
+            assert tp_axis is None, "manual-TP stage blocks have no mixer"
+            mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
+                                     qm_backend=qm_backend)
+        with jax.named_scope("attn_qkv"):
+            h = scaled(h, c.attention_in_multiplier)
 
-    h = rms_norm(x, layer_params["ln_mlp"], c.norm_eps)
+            def heads(t: Array, n: int, norm: str = "") -> Array:
+                if norm and c.qk_norm:  # over the whole width, before the split
+                    t = rms_norm(t, layer_params[norm], c.norm_eps)
+                return t.reshape(B, S, n, c.head_dim)
+
+            q = heads(dense(h, layer_params["attn_q"], qm_backend=qm_backend), hq, "attn_q_norm")
+            k = heads(scaled(dense(h, layer_params["attn_k"], qm_backend=qm_backend),
+                             c.key_multiplier), hkv, "attn_k_norm")
+            v = heads(dense(h, layer_params["attn_v"], qm_backend=qm_backend), hkv)
+            if c.rope_theta is not None:
+                q = rope(q, positions, c.rope_theta)
+                k = rope(k, positions, c.rope_theta)
+
+        # the attention callback opens its own scopes (engine/engine.py)
+        attn_out, new_layer_cache = attention(q, k, v, layer_cache, layer_idx)
+        with jax.named_scope("attn_o"):
+            if tp_axis is not None:
+                from finchat_tpu.ops.tp_overlap import row_parallel_dense
+
+                attn_proj = row_parallel_dense(
+                    attn_out.reshape(B, S, -1), layer_params["attn_o"], tp_axis,
+                    overlap=tp_overlap, n_chunks=tp_chunks, qm_backend=qm_backend,
+                )
+            else:
+                attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
+                                  qm_backend=qm_backend)
+            x = x + norm_out(scaled(attn_proj, c.attention_out_multiplier),
+                             layer_params["ln_attn"])
+            if c.ssm_heads:
+                x = x + mixed
+
+    h = norm_in(x, layer_params["ln_mlp"])
     if c.n_experts:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
         moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend)
         with jax.named_scope("moe_experts"):
-            x = x + moe_out  # the residual add fuses into the down matmul
+            # the residual add fuses into the down matmul
+            x = x + norm_out(moe_out, layer_params["ln_mlp"])
     else:
         with jax.named_scope("mlp"):
             gate = scaled(dense(h, layer_params["mlp_gate"], qm_backend=qm_backend),
@@ -402,8 +533,8 @@ def _layer(
                 )
             else:
                 down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
-            x = x + scaled(down, c.mlp_multipliers[1])
-    if c.ssm_heads:
+            x = x + norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"])
+    if c.has_state:
         return x, new_layer_cache, ssm_cache
     return x, new_layer_cache
 
@@ -442,34 +573,63 @@ def forward(
     forward) every row starts from zero state and ``new_cache`` is as ever.
     """
     c = config
-    if c.ssm_heads and cache is not None and ssm_cache is None:
+    if c.has_state and cache is not None and ssm_cache is None:
         # a cached row continues from its recurrent state: a step that hands
         # in none would silently run the mixer from zero
         raise NotImplementedError(
             "a forward over a KV cache needs the mixer's ssm_cache too "
-            f"(ssm_heads={c.ssm_heads}): this step does not carry it")
+            f"({c.n_state_layers} layers carry recurrent state): this step "
+            "does not carry it")
     with jax.named_scope("embed"):
         x = scaled(params["embed"][tokens], c.embedding_multiplier)  # [B,S,D]
 
-    def scan_body(carry, scanned):
+    # the scan runs over PERIODS of the layer pattern; inside one the kinds
+    # are static and its layers stand one after another in the body. A period
+    # of one layer scans the stacks as they lie; a longer one indexes each
+    # layer's leaves out of the WHOLE stacks (a slice of a period's slice
+    # would copy the period's weights every step). Do not make a run of
+    # layers of one kind a scan of its own inside the period's: on the v5e a
+    # ragged round with that nested loop hung one time in ten (PERF.md §6,
+    # PR 32)
+    pattern = c.layer_pattern or (FULL,)
+    n_periods = c.n_layers // len(pattern)
+    stacks = params["layers"]
+
+    def one_layer(carry, layer_params, layer_idx, kind):
         x, cache, ssm = carry
-        layer_params, layer_idx = scanned
         out = _layer(
             x, layer_params, cache, layer_idx,
             positions=positions, config=c, attention=attention,
-            qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows,
+            qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows, kind=kind,
         )
-        # the layer returns its ssm cache only where it has a mixer
-        return (*out[:2], out[2] if c.ssm_heads else ssm), None
+        # the layer returns its ssm cache only where the model has state
+        return (*out[:2], out[2] if c.has_state else ssm)
+
+    def scan_body(carry, scanned):
+        layer_params, period_idx = scanned
+        if len(pattern) == 1:
+            return one_layer(carry, layer_params, period_idx, pattern[0]), None
+        for j, kind in enumerate(pattern):
+            # the layer's index among its own kind (what the caches, stacked
+            # by kind, are indexed by) and down the whole depth
+            at = {kind: period_idx * pattern.count(kind) + pattern[:j].count(kind),
+                  None: period_idx * len(pattern) + j}
+            layer_params = {
+                name: jax.tree.map(
+                    lambda a, i=at[_stack_kind(name)]: lax.dynamic_index_in_dim(
+                        a, i, 0, keepdims=False), leaf)
+                for name, leaf in stacks.items() if _stack_kind(name) in at}
+            carry = one_layer(carry, layer_params, at[kind], kind)
+        return carry, None
 
     if remat:
         # per-layer remat: backward recomputes one layer at a time, so live
         # residuals stay O(one layer) instead of O(n_layers)
         scan_body = jax.checkpoint(scan_body)
 
-    layer_ids = jnp.arange(c.n_layers)
     (x, new_cache, ssm_cache), _ = lax.scan(
-        scan_body, (x, cache, ssm_cache), (params["layers"], layer_ids))
+        scan_body, (x, cache, ssm_cache),
+        (stacks if len(pattern) == 1 else None, jnp.arange(n_periods)))
     if ssm_cache is not None:
         new_cache = (new_cache, ssm_cache)
 

@@ -115,15 +115,16 @@ def _write(leaf: Array, new: Array, layer_idx: Array, rows: SsmRows) -> Array:
     return leaf.at[layer_idx.reshape(()), slot].set(new, mode="drop")
 
 
-def causal_conv(x: Array, tail: Array, n_valid: Array, w: Array, b: Array
+def causal_conv(x: Array, tail: Array, n_valid: Array, w: Array, b: Array | None
                 ) -> tuple[Array, Array]:
-    """Depthwise causal conv of width K with bias, then SiLU. ``x`` [N,S,C];
-    ``tail`` [N,K-1,C] the row's last K-1 inputs before ``x``; ``w`` [K,C]
-    with ``w[K-1]`` on the current token. Returns (out [N,S,C], the K-1
-    inputs ending at the row's last real token)."""
+    """Depthwise causal conv of width K with bias (None = none), then SiLU.
+    ``x`` [N,S,C]; ``tail`` [N,K-1,C] the row's last K-1 inputs before ``x``;
+    ``w`` [K,C] with ``w[K-1]`` on the current token. Returns (out [N,S,C],
+    the K-1 inputs ending at the row's last real token)."""
     K, S = w.shape[0], x.shape[1]
     full = jnp.concatenate([tail, x], axis=1)  # [N, K-1+S, C]
-    out = b[None, None, :] + sum(full[:, k:k + S] * w[k][None, None, :] for k in range(K))
+    bias = 0.0 if b is None else b[None, None, :]
+    out = bias + sum(full[:, k:k + S] * w[k][None, None, :] for k in range(K))
     idx = n_valid[:, None] + jnp.arange(K - 1, dtype=jnp.int32)[None, :]
     new_tail = jnp.take_along_axis(full, idx[:, :, None], axis=1)
     return jax.nn.silu(out), new_tail

@@ -87,6 +87,7 @@ from finchat_tpu.ops.flash_attention import (
 BLOCK_TOKENS = 512  # KV tokens per online-softmax update, where they fit
 SCORE_TILE_BYTES = 1 << 19  # one kv head's fp32 [rows, block] logit tile
 KV_BUFFER_BYTES = 8 << 20  # both slots of the K and the V block
+VMEM_BYTES = 31 << 19  # 15.5 MiB of the v5e's 16 MiB of scoped VMEM: blocks, state, buffers
 
 
 def _pad_chunk(q: Array) -> tuple[Array, int]:
@@ -105,16 +106,23 @@ def _pad_chunk(q: Array) -> tuple[Array, int]:
 
 
 def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
-                     max_pages: int) -> int:
+                     max_pages: int, reserved: int = 0) -> int:
     """Pages copied and computed together: ``BLOCK_TOKENS`` tokens, halved
     while one kv head's logit tile (``head_rows = group * block_q`` query
     rows) or the double-buffered K and V blocks (``width = Hkv * hd``
-    elements a token) outgrow their VMEM budgets; never under one page nor
+    elements a token) outgrow their VMEM budgets — their own, and what the
+    call's query and output blocks and its softmax state (``reserved``
+    bytes) leave of the whole: at 30 KV heads a token row is 7.5 KiB and a
+    128-query prefill block's state alone 5.6 MiB; never under one page nor
     over the table."""
     tokens = BLOCK_TOKENS
+    # an int8 block also stands dequantized beside its buffers, head by head
+    # (float32, then the query dtype: 6 bytes an element; Mosaic keeps every
+    # head's copy of the static unroll)
+    token_bytes = width * (4 * itemsize + (6 if itemsize == 1 else 0))
     while tokens > page_size and (
             head_rows * tokens * 4 > SCORE_TILE_BYTES
-            or 4 * tokens * width * itemsize > KV_BUFFER_BYTES):
+            or tokens * token_bytes > min(KV_BUFFER_BYTES, VMEM_BYTES - reserved)):
         tokens //= 2
     return max(1, min(tokens // page_size, max_pages))
 
@@ -377,8 +385,6 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     # of query heads padded to whole 8-row tiles of the stacked block
     gp = _round_up(group, 8)
     shared_rows = B * gp if C == 1 and B > 1 else 0
-    ppb = _pages_per_block(page_size, max(group * bq, shared_rows), n_kv * D,
-                           k_pages.dtype.itemsize, page_table.shape[1])
 
     q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, D]
     q_spec = pl.BlockSpec((1, H, bq, D), lambda b, qi, *_: (b, 0, qi, 0))
@@ -398,6 +404,12 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
                   pltpu.VMEM((n_kv * shared_rows, 128), jnp.float32),
                   pltpu.VMEM((n_kv * shared_rows, D), jnp.float32),
                   pltpu.SMEM((1,), jnp.int32)]
+    # what stands in VMEM beside the K and V buffers: the query and output
+    # blocks (the pipeline keeps two of each) and the softmax state
+    reserved = (2 * q.dtype.itemsize * (2 * H * max(bq, 8) * D + n_kv * shared_rows * D)
+                + 4 * (r_pad + n_kv * shared_rows) * (2 * 128 + D))
+    ppb = _pages_per_block(page_size, max(group * bq, shared_rows), n_kv * D,
+                           k_pages.dtype.itemsize, page_table.shape[1], reserved)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),

@@ -360,7 +360,7 @@ def make_engine_replica(
     replica's session tier the fleet-shared one and lets its shared
     prompt heads restore from / publish to the cluster-wide store."""
     config, params, tokenizer, mesh = artifacts
-    if config.ssm_heads:
+    if config.has_state:
         # what moves a row between engines moves its pages, never a mixer's
         # recurrent state (session, handoff and pod wire formats hold none):
         # refused by name rather than served from a state of zero (the
@@ -376,7 +376,7 @@ def make_engine_replica(
         named = [option for option, on in refused.items() if on]
         if named:
             raise ValueError(
-                f"a model with a Mamba-2 mixer (ssm_heads={config.ssm_heads}) is served "
+                f"a model with recurrent state ({config.n_state_layers} layers) is served "
                 f"by one engine; not supported with it: {', '.join(named)}")
     metrics = METRICS.labeled(replica=replica_id) if replica_id is not None else None
     with TRACER.startup_phase("engine_init"):

@@ -61,6 +61,15 @@ def test_ssm_step_at_cell_shape_interpret():
     assert set(errors) == {"y", "state"} and errors["y"] < 1e-4
 
 
+def test_gdn_step_at_cell_shape_values():
+    """The delta rule's one-token update at a small size against the
+    recurrence as written (values only: a time comes from the chip): layer 1
+    of 3, one row inert."""
+    errors = chip_smoke.check_gdn_step_at_cell_shape(
+        "ref", rows=4, heads=3, key_dim=8, value_dim=16, layers=3, layer=1)
+    assert set(errors) == {"o", "state"} and errors["o"] < 1e-5
+
+
 @pytest.mark.no_stall_sanitizer
 def test_serving_function_at_tiny_interpret(monkeypatch):
     """The whole serving phase — start-up, logits parity, HTTP, Kafka, the

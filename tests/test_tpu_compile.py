@@ -87,26 +87,15 @@ def test_paged_attention_compiles_for_v5e_at_falcon_h1s_head_counts(one_chip, C,
     assert len(calls) == 1 and calls[0].startswith("%paged_flash_attention")
 
 
-def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip):
-    """The whole decode step at the cell's size (5 layers, 16 slots, the
-    1,600-page pool, the compiled kernels): the K/V pool AND the recurrent
-    state [5, 16, 32, 128, 256] float32 are donated and updated in place —
-    a copy of the state alone would be 0.34 GB of temporaries a step. The
-    state's one-token update is ``ops/ssm_step.py``'s kernel (Mosaic's layout
-    rules for its [rows, 32, 128, 256] float32 blocks are checked by this
-    compile), and it is the ONLY operation under ``ssm_scan`` that touches
-    the carried state: XLA's own two fusions both read it."""
-    import json
-    from pathlib import Path
-
+def _compiled_decode_step(one_chip, model, file):
+    """``decode_step`` of ``file``'s configuration (``model``: its adapter)
+    compiled for the described chip at the file's engine options, from shapes
+    alone; returns ``(compiled, state shapes)``."""
     from finchat_tpu.engine import engine as E
     from finchat_tpu.models.llama import init_params
     from finchat_tpu.utils.config import EngineConfig
-    from perfbench.models import falcon_h1
 
-    file = json.loads((Path(__file__).resolve().parents[1]
-                       / "perfbench/configs/falcon-h1-34b-instruct.json").read_text())
-    c = falcon_h1.program_config(dict(file, num_hidden_layers=5))
+    c = model.program_config(file)
     cfg = EngineConfig(**file["engine"])
 
     def described(tree):
@@ -119,6 +108,30 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
     compiled = E.decode_step.lower(
         params, state, row(bool), row(jnp.float32), row(jnp.float32), row(jnp.int32),
         config=c, page_size=PAGE, attn_backend="pallas", qm_backend="ref").compile()
+    return compiled, state
+
+
+def _config_file(name):
+    import json
+    from pathlib import Path
+
+    return json.loads((Path(__file__).resolve().parents[1]
+                       / f"perfbench/configs/{name}.json").read_text())
+
+
+def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip):
+    """The whole decode step at the cell's size (5 layers, 16 slots, the
+    1,600-page pool, the compiled kernels): the K/V pool AND the recurrent
+    state [5, 16, 32, 128, 256] float32 are donated and updated in place —
+    a copy of the state alone would be 0.34 GB of temporaries a step. The
+    state's one-token update is ``ops/ssm_step.py``'s kernel (Mosaic's layout
+    rules for its [rows, 32, 128, 256] float32 blocks are checked by this
+    compile), and it is the ONLY operation under ``ssm_scan`` that touches
+    the carried state: XLA's own two fusions both read it."""
+    from perfbench.models import falcon_h1
+
+    compiled, state = _compiled_decode_step(
+        one_chip, falcon_h1, dict(_config_file("falcon-h1-34b-instruct"), num_hidden_layers=5))
     memory = compiled.memory_analysis()
     state_bytes = 5 * ROWS * 32 * 128 * 256 * 4
     assert state.ssm_state.shape == (5, ROWS, 32, 128, 256)
@@ -147,3 +160,141 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
                if any(types.get(operand.strip(), "").startswith(carried)
                       for operand in [name, *operands.split(",")])]
     assert readers == [], readers
+
+
+# olmo-hybrid-7b as perfbench/configs has it: 30 / 30 heads of 128 — ONE query
+# head a KV head (7 of the 8 query rows of each KV head's tile are padding) and
+# a token row of K 3,840 wide, 7.5 KiB: the widest the kernel's blocks hold
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("C", [1, 256], ids=["decode", "prefill"])
+def test_paged_attention_compiles_for_v5e_at_olmo_hybrids_head_counts(one_chip, C, quantized):
+    calls = _compiled_kernel_calls(one_chip, C, quantized, 30, 30, 2)
+    assert len(calls) == 1 and calls[0].startswith("%paged_flash_attention")
+
+
+def test_kv_append_compiles_for_v5e_at_olmo_hybrids_row_width(one_chip):
+    """The decode step's in-place append at a 3,840-wide row: a page of 128
+    tokens is 0.94 MiB, read, patched and written back whole, K and V."""
+    from finchat_tpu.ops.kv_append import paged_kv_append
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pages = shape((2, POOL, PAGE, 30 * HEAD_DIM), jnp.bfloat16)
+    compiled = jax.jit(lambda *args: paged_kv_append(*args, page_size=PAGE)).lower(
+        shape((ROWS, 1, 2 * 30 * HEAD_DIM), jnp.bfloat16), pages, pages,
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32), shape((ROWS,), jnp.int32),
+        shape((1,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_olmo_hybrid_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_chip):
+    """The whole decode step at the cell's size (two periods of three linear
+    layers and a full one, 16 slots, the 1,600-page pool, the compiled
+    kernels): the pool has the FULL layers' depth and the recurrent state the
+    LINEAR layers', both are donated and updated in place, and a period's
+    layers stand one after another in the ONE scan's body (no loop inside
+    it: PERF.md section 6, PR 32) — three in-place updates of the carried
+    state under ``gdn_scan``, a period's, which is what
+    ``gdn_state_roofline.sat`` times and the adapter counts."""
+    from perfbench.models import olmo_hybrid
+
+    file = _config_file("olmo-hybrid-7b")
+    compiled, state = _compiled_decode_step(
+        one_chip, olmo_hybrid,
+        dict(file, num_hidden_layers=8, layer_types=file["layer_types"][:4] * 2))
+    assert state.k_pages.shape == (2, POOL, PAGE, 30 * HEAD_DIM)
+    assert state.ssm_state.shape == (6, ROWS, 30, 96, 192)
+    assert state.conv_state.shape == (6, ROWS, 3, 11520)
+    memory = compiled.memory_analysis()
+    state_bytes = 6 * ROWS * 30 * 96 * 192 * 4
+    pool_bytes = 2 * 2 * POOL * PAGE * 30 * HEAD_DIM * 2
+    assert memory.alias_size_in_bytes >= state_bytes + pool_bytes
+    assert memory.temp_size_in_bytes < state_bytes // 4
+    text = compiled.as_text()
+    # one custom call a FULL layer under each scope, by the names the
+    # benchmark's readers find them by
+    for scope, name in (("paged_attention", "paged_flash_attention"), ("kv_append", "kv_append")):
+        calls = [line.split(" = ")[0] for line in text.splitlines()
+                 if f"/{scope}/" in line and " = " in line
+                 and 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 1 and name in calls[0], (scope, calls)
+    # the carried state is written three times in the program: a period's
+    # linear layers, each in place
+    updates = [line for line in text.splitlines()
+               if "/gdn_scan/" in line and re.match(r"\s*(?:ROOT )?%[\w.-]+ = f32\[6,16,30,96,192\]", line)
+               and " fusion(" in line]
+    assert len(updates) == 3, updates
+    assert text.count(" while(") == 1  # the scan over periods and no other loop
+
+
+def _loop_nest(text):
+    """``(depth, op_name)`` of every ``while`` of a compiled module, from the
+    entry computation down (a loop's depth is the number of loops around it)."""
+    bodies, at = {}, None
+    for line in text.splitlines():
+        opened = re.match(r"^(?:ENTRY )?(%[\w.-]+) .*\{\s*$", line)
+        if opened:
+            at = opened.group(1)
+            bodies[at] = []
+        elif at is not None:
+            bodies[at].append(line)
+    entry = re.search(r"^ENTRY (%[\w.-]+)", text, re.M).group(1)
+    found, seen = [], set()
+
+    def walk(name, depth):
+        for line in bodies.get(name, ()):
+            if " while(" in line:
+                op = re.search(r'op_name="([^"]*)"', line)
+                found.append((depth, op.group(1) if op else ""))
+                walk(re.search(r"body=(%[\w.-]+)", line).group(1), depth + 1)
+                continue
+            for callee in re.findall(r"(?:calls|to_apply|\w+_computations?)=\{?(%[\w.-]+)", line):
+                if callee not in seen:
+                    seen.add(callee)
+                    walk(callee, depth)
+
+    walk(entry, 0)
+    return found
+
+
+@pytest.mark.parametrize("T", [512])
+def test_olmo_hybrid_ragged_round_has_no_loop_over_layers_inside_the_period_scan(one_chip, T):
+    """``ragged_mixed_step`` at the cell's size and the window round's bucket
+    (a prompt's 256-token chunk beside 15 decode rows). With a ``lax.scan``
+    over a period's run of linear layers nested in the scan over periods this
+    step hung a v5e one round in ten (PERF.md section 6, PR 32; cause not
+    found; ``benchmarks/ragged_round_soak.py`` is the probe). What ran clean:
+    ONE loop over periods, and inside it, a layer after another, only the
+    chunked form's scan over blocks (the longest rows'; the other rows' one
+    block stands outside any loop) and the loops XLA makes of the state's
+    gathers by slot. So: one loop at the top, three block scans at depth 1,
+    nothing deeper."""
+    from finchat_tpu.engine import engine as E
+    from finchat_tpu.models.llama import init_params
+    from finchat_tpu.utils.config import EngineConfig
+    from perfbench.models import olmo_hybrid
+
+    file = _config_file("olmo-hybrid-7b")
+    c = olmo_hybrid.program_config(file)
+    cfg = EngineConfig(**file["engine"])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), tree)
+
+    rows = lambda dtype: shape((ROWS,), dtype)  # noqa: E731
+    compiled = E.ragged_mixed_step.lower(
+        described(jax.eval_shape(lambda: init_params(c, jax.random.key(0)))),
+        described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH))),
+        shape((T,), jnp.int32), shape((T,), jnp.int32), rows(jnp.int32), rows(jnp.int32),
+        rows(jnp.int32), rows(bool), rows(bool), rows(jnp.int32), rows(jnp.float32),
+        rows(jnp.float32), rows(jnp.int32), rows(bool), rows(jnp.float32), rows(jnp.float32),
+        rows(jnp.int32), shape((), jnp.int32), config=c, page_size=PAGE, attn_backend="pallas",
+        qm_backend="ref", max_row_tokens=cfg.prefill_chunk).compile()
+    nest = _loop_nest(compiled.as_text())
+    assert [op for depth, op in nest if depth == 0] == ["jit(ragged_mixed_step)/while"], nest
+    assert max(depth for depth, _op in nest) == 1, nest
+    assert sum(op.endswith("/gdn_scan/while") for _depth, op in nest) == 3, nest
