@@ -1,14 +1,16 @@
 """Paged decode attention alone on the chip, at the shapes of the cell
 ``mixtral-report-saturated`` (PERF.md section 6, PR 25).
 
-One ``paged_flash_attention`` call a layer over a 3-layer bf16 cache of 1,600
-pages: 16 rows, 32 query heads, 8 KV heads, head 128, page 128, one query a
+One ``paged_flash_attention`` call a layer over a bf16 cache of ``--layers``
+layers (3) and 1,600 pages: 16 rows, 32 query heads, 8 KV heads, head 128, page 128, one query a
 row, contexts drawn between 5k and 12k tokens (mean about 7.75k, as in the
 cell's capture), the first ``--shared-pages`` pages (31) of every row
 physically shared (the system prompt), which the kernel's shared-head pass
 reads once for all rows; ``--shared-pages 0`` is the bypass, every page a
-row's own. ``--heads 20 --kv-heads 4`` are Falcon-H1's head counts. The page
-table is given at each width of ``--widths``:
+row's own. ``--heads 20 --kv-heads 4`` are Falcon-H1's head counts, ``--heads
+30 --kv-heads 30 --layers 2`` Olmo-Hybrid's cell (ONE query head a KV head, a
+token row of 7.5 KiB: two full-attention layers' pool is 6.3 GB, a third does
+not fit beside it). The page table is given at each width of ``--widths``:
 ``served`` is the engine's 128 (``max_seq_len`` / page), ``live`` the widest
 row's live pages — what the table's dead entries cost is the difference.
 
@@ -24,8 +26,10 @@ the same calls is printed beside them. Runs on the chip only:
     chiprun -- python3 benchmarks/paged_attention_alone.py --tree <checkout>
 
 ``--tree`` times the kernel of another checkout (the parent commit unpacked
-somewhere under the repo) with this script's inputs. It stays because ROADMAP
-S13 (a) needs the kernel alone on the chip at 5 query heads a KV head.
+somewhere under the repo) with this script's inputs. The script runs in no
+cell. It stays because a change to the kernel is judged alone first, parent
+against change at every cell's head counts (PERF.md section 6, PRs 25, 31,
+33), and ROADMAP S13 (a) still needs it at 5 query heads a KV head.
 """
 
 from __future__ import annotations
@@ -38,7 +42,7 @@ import time
 from pathlib import Path
 
 ROWS, HEAD_DIM, PAGE = 16, 128, 128
-LAYERS, POOL_PAGES, SERVED_WIDTH = 3, 1600, 128
+POOL_PAGES, SERVED_WIDTH = 1600, 128
 HBM_BYTES_PER_S = 819e9  # one v5e chip (perfbench/peaks.json)
 
 
@@ -90,13 +94,16 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--widths", default="served,live")
     ap.add_argument("--steps", type=int, default=40,
-                    help="calls of the three layers in the capture")
+                    help="calls of all the layers in the capture")
     ap.add_argument("--shared-pages", type=int, default=31,
                     help="leading pages every row shares (0: none, the bypass)")
     ap.add_argument("--heads", type=int, default=32)
     ap.add_argument("--kv-heads", type=int, default=8)
+    ap.add_argument("--layers", type=int, default=3,
+                    help="depth of the cache: a cell's pool (2 at 30 KV heads)")
     args = ap.parse_args()
     heads, kv_heads, shared_pages = args.heads, args.kv_heads, args.shared_pages
+    layers = args.layers
     sys.path.insert(0, args.tree)
 
     import jax
@@ -112,7 +119,7 @@ def main() -> int:
 
     ctx = contexts(args.seed)
     keys = jax.random.split(jax.random.key(args.seed % (2 ** 31)), 3)
-    shape = (LAYERS, POOL_PAGES, PAGE, kv_heads * HEAD_DIM)
+    shape = (layers, POOL_PAGES, PAGE, kv_heads * HEAD_DIM)
     k_pages = jax.random.normal(keys[0], shape, jnp.bfloat16)
     v_pages = jax.random.normal(keys[1], shape, jnp.bfloat16)
     q = jax.random.normal(keys[2], (ROWS, 1, heads, HEAD_DIM), jnp.bfloat16)
@@ -120,12 +127,12 @@ def main() -> int:
     q_offset = kv_len - 1
 
     @jax.jit
-    def three_layers(q, k_pages, v_pages, table):
+    def all_layers(q, k_pages, v_pages, table):
         def layer(i, acc):
             return acc + paged_flash_attention(
                 q, k_pages, v_pages, table, q_offset, kv_len, i[None],
                 page_size=PAGE, n_kv=kv_heads).astype(jnp.float32)
-        return jax.lax.fori_loop(0, LAYERS, layer, jnp.zeros(q.shape, jnp.float32))
+        return jax.lax.fori_loop(0, layers, layer, jnp.zeros(q.shape, jnp.float32))
 
     token_us = 1e6 * 2 * kv_heads * HEAD_DIM * 2 / HBM_BYTES_PER_S
     distinct = int(ctx.sum()) - (ROWS - 1) * shared_pages * PAGE
@@ -133,7 +140,8 @@ def main() -> int:
     live_width = int(-(-ctx.max() // PAGE))
     result = {
         "tree": args.tree, "seed": args.seed, "device": jax.devices()[0].device_kind,
-        "heads": heads, "kv_heads": kv_heads, "shared_pages": shared_pages,
+        "heads": heads, "kv_heads": kv_heads, "layers": layers,
+        "shared_pages": shared_pages,
         "context_tokens": int(ctx.sum()), "distinct_page_tokens": distinct,
         "live_pages": int((-(-ctx // PAGE)).sum()),
         "widest_row_pages": live_width, "stream_bound_us": stream_us,
@@ -142,15 +150,15 @@ def main() -> int:
     for name in args.widths.split(","):
         width = {"served": SERVED_WIDTH, "live": live_width}.get(name) or int(name)
         table = jnp.asarray(page_table(ctx, width, args.seed, shared_pages))
-        out = three_layers(q, k_pages, v_pages, table).block_until_ready()
+        out = all_layers(q, k_pages, v_pages, table).block_until_ready()
         assert bool(jnp.isfinite(out).all())
         with tempfile.TemporaryDirectory() as trace_dir:
             jax.profiler.start_trace(trace_dir)
             t0 = time.perf_counter()
             for _ in range(args.steps):
-                out = three_layers(q, k_pages, v_pages, table)
+                out = all_layers(q, k_pages, v_pages, table)
             out.block_until_ready()
-            wall_us = 1e6 * (time.perf_counter() - t0) / (args.steps * LAYERS)
+            wall_us = 1e6 * (time.perf_counter() - t0) / (args.steps * layers)
             jax.profiler.stop_trace()
             calls = kernel_events(trace_dir)
         call_us = float(np.mean(calls)) if calls else None

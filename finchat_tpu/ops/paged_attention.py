@@ -33,7 +33,36 @@ Design — a walk as long as the row, several pages a block:
   score / value products run over ``[rows, block]`` tiles. All KV heads are
   processed in ONE program (a static inner unroll), each on a VALUE slice
   ``buf[slot, :, :, h*hd:(h+1)*hd]`` of the loaded block.
-- ``pages_per_block`` follows from static shapes alone (``_pages_per_block``).
+- ``pages_per_block`` follows from static shapes alone (``_pages_per_block``):
+  512 tokens, halved while a K block is over 2 MiB (both slots of K and V
+  over ``KV_BUFFER_BYTES``) or the call over the 16 MiB of scoped VMEM a
+  kernel has on the v5e without asking — 4 pages of 128 at 8 or 4 KV heads,
+  2 at 30. No ``vmem_limit_bytes`` is asked: at 30 heads 512-token blocks
+  under a 26 MiB limit ran SLOWER than 256 (the copies alone 1,383 against
+  1,337 us a call: a walk's partial last block copies its last page once
+  more for every page it lacks, 1.5 pages of 0.94 MB a walk against 0.5).
+- a TILE (PERF.md section 6, PR 33). A block update works on tiles of 8
+  sublanes whatever they hold, and its max / exp / sum chain, its result
+  pops and its three state read-modify-writes are paid a head: at 30 KV
+  heads of ONE query row each (Olmo-Hybrid) that, not the copies, was the
+  call's time (58 % of its stream bound where 8 heads of 4 rows read 81).
+  Where a head's ``group * block_q`` rows are fewer than half a tile and
+  divide it (1 or 2 rows, at decode only), ``pack = 8 // rows`` KV heads
+  share ONE update (``_heads_per_tile``): the queries come BLOCK-DIAGONAL
+  (``_block_diagonal``: row ``i`` of a tile holds its head's query at lanes
+  ``i*hd .. (i+1)*hd`` of ``pack*hd``, zeros elsewhere), so one product
+  with the tile's lanes ``buf[slot, :, :, t*pack*hd:(t+1)*pack*hd]`` of
+  the K block gives each row its own head's logits (the same bf16 products
+  in the same float32 sums, plus zeros), one chain and one aligned
+  read-modify-write serve 8 rows, and the product with the same lanes of
+  the V block leaves each row's result at its head's lanes of a
+  ``pack*hd``-wide acc (the other lanes are dropped when the row is
+  written out). 30 = 3 x 8 + 6: the last tile has the lanes of 6 heads and
+  two rows of zeros. The weight pushes are the K and V bytes and do not
+  change; the chains a block fall from 30 to 4, and the call is as long as
+  its copies (88-91 % of the bound). At 4 rows a head (half a tile) a head
+  keeps its own tile, as at 5 and at 8 or more: those bodies are traced
+  as they were.
 - GQA: each kv head's ``group = H // Hkv`` query heads ride in the same
   q block, so each page is fetched once per (b, q-block).
 - the int8 cache (``paged_flash_attention_q8``) is the same walk with the
@@ -49,7 +78,9 @@ Design — a walk as long as the row, several pages a block:
   and the kernel adapts on it inside the ONE call: program 0 first walks
   the head's pages with every row's queries stacked (a ``[rows * 8, D]``
   tile a kv head, each row's group of query heads padded to 8 so that a
-  row's partial result is whole sublane tiles of the scratch), through the
+  row's partial result is whole sublane tiles of the scratch; where KV
+  heads share a tile, a ``[rows * 8, pack * D]`` tile a tile of heads,
+  every row real), through the
   same double-buffered copies and the same block update; then each member's
   own walk starts at the table column behind the head, from its rows of that
   partial m / l / acc instead of from nothing. No member, or a head of no
@@ -88,6 +119,7 @@ BLOCK_TOKENS = 512  # KV tokens per online-softmax update, where they fit
 SCORE_TILE_BYTES = 1 << 19  # one kv head's fp32 [rows, block] logit tile
 KV_BUFFER_BYTES = 8 << 20  # both slots of the K and the V block
 VMEM_BYTES = 31 << 19  # 15.5 MiB of the v5e's 16 MiB of scoped VMEM: blocks, state, buffers
+SUBLANES = 8  # rows of a float32 tile: the least a block update works on
 
 
 def _pad_chunk(q: Array) -> tuple[Array, int]:
@@ -105,16 +137,34 @@ def _pad_chunk(q: Array) -> tuple[Array, int]:
     return jnp.pad(q, ((0, 0), (0, padded - C), (0, 0), (0, 0))), padded
 
 
+def _heads_per_tile(group: int, block_q: int) -> int:
+    """KV heads whose query rows take ONE block update together
+    (``_paged_kernel``). An update works on tiles of 8 sublanes whatever they
+    hold, and at 30 heads of one row each the 30 dependent chains a block, not
+    the copies, set the call's time (PERF.md section 6, PR 33): where a head's
+    ``group * block_q`` rows are fewer than half a tile (1 or 2, at decode)
+    and divide it, as many heads as fill it share one. Half a tile (4 rows:
+    Mistral, Mixtral) stays a head a tile here, as 5 rows (they do not divide)
+    and 8 or more (a tile of their own) must."""
+    rows = group * block_q
+    return SUBLANES // rows if 2 * rows < SUBLANES and SUBLANES % rows == 0 else 1
+
+
 def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
                      max_pages: int, reserved: int = 0) -> int:
     """Pages copied and computed together: ``BLOCK_TOKENS`` tokens, halved
-    while one kv head's logit tile (``head_rows = group * block_q`` query
-    rows) or the double-buffered K and V blocks (``width = Hkv * hd``
-    elements a token) outgrow their VMEM budgets — their own, and what the
-    call's query and output blocks and its softmax state (``reserved``
-    bytes) leave of the whole: at 30 KV heads a token row is 7.5 KiB and a
-    128-query prefill block's state alone 5.6 MiB; never under one page nor
-    over the table."""
+    while one tile's logits (``head_rows`` query rows: a KV head's, or those
+    of the heads that share a tile) or the double-buffered K and V blocks
+    (``width = Hkv * hd`` elements a token) outgrow their VMEM budgets —
+    their own, and what the call's query and output blocks and its softmax
+    state (``reserved`` bytes) leave of the whole: at 30 KV heads a token row
+    is 7.5 KiB and a 128-query prefill block's state alone 5.6 MiB; never
+    under one page nor over the table. ``KV_BUFFER_BYTES`` bounds a block by
+    its BYTES (2 MiB of K: 512 tokens at 8 KV heads, 256 at 30), and that is
+    the better block, not only the one that fits: with more VMEM asked for
+    (``vmem_limit_bytes``) 512 tokens at 30 heads ran 3.5 % slower, copies
+    alone, than 256 — a walk's partial last block copies its last page again
+    for every page it lacks (PERF.md section 6, PR 33)."""
     tokens = BLOCK_TOKENS
     # an int8 block also stands dequantized beside its buffers, head by head
     # (float32, then the query dtype: 6 bytes an element; Mosaic keeps every
@@ -168,6 +218,7 @@ def _paged_kernel(
     pages_per_block: int,
     n_kv: int,
     group: int,
+    pack: int,
     scale: float,
     quantized: bool,
     shared_rows: int,
@@ -185,7 +236,21 @@ def _paged_kernel(
     queries ``[Hkv, shared_rows, D]`` (row ``b * (shared_rows / B) + g`` is
     query head ``g`` of sequence ``b``'s group), and the scratch a second
     m / l / acc of ``shared_rows`` rows a kv head, filled by program 0, and
-    one SMEM word: the buffer slot the next walk of the call starts in."""
+    one SMEM word: the buffer slot the next walk of the call starts in.
+
+    A TILE is ``pack`` KV heads (``_heads_per_tile``) whose query rows take
+    ONE block update together. With ``pack`` 1 it is a KV head, as above.
+    With more, the query block is ``[1, tiles, 8, pack * D]``, BLOCK-DIAGONAL
+    (``_block_diagonal``): row ``i * group + g`` of a tile holds query head
+    ``g`` of the tile's ``i``-th KV head at lanes ``i * D .. (i + 1) * D`` and
+    zeros elsewhere, so ONE product with the tile's ``pack * D`` lanes of the
+    K block gives each row its own head's logits (the zeros add nothing), one
+    max / exp / sum chain and one aligned read-modify-write of m / l / acc
+    serve 8 rows, and the product of the probabilities with the same lanes of
+    the V block leaves each row's result at its head's lanes of a ``pack * D``
+    wide acc; the other lanes hold other heads' values under this row's
+    weights and are dropped at the end. The stacked queries are ``[tiles,
+    shared_rows, pack * D]`` likewise, a sequence's 8 rows one tile."""
     n_src = 4 if quantized else 2
     layer_ref, page_table_ref, q_offset_ref, kv_len_ref, *refs = refs
     if shared_rows:
@@ -202,8 +267,9 @@ def _paged_kernel(
     b = pl.program_id(0)
     qi = pl.program_id(1)
     Bq, ppb = block_q, pages_per_block
-    D = q_ref.shape[-1]
-    Rh = group * Bq  # scratch rows per kv head
+    D = o_ref.shape[-1]
+    Rt = pack * group * Bq  # scratch rows per tile
+    n_tiles = pl.cdiv(n_kv, pack)
     T = ppb * page_size  # tokens per block
     layer = layer_ref[0]
     q_off = q_offset_ref[b]
@@ -211,8 +277,8 @@ def _paged_kernel(
 
     def walk(row, first, n_pages, limit, causal, q_of, state, R,
              slot0=0, primed=False, then=None):
-        """Online softmax of ``R`` query rows a kv head (``q_of(h)``, state in
-        ``state``'s rows ``h*R .. (h+1)*R``) over table columns ``first ..
+        """Online softmax of ``R`` query rows a tile (``q_of(t)``, state in
+        ``state``'s rows ``t*R .. (t+1)*R``) over table columns ``first ..
         first + n_pages`` of ``row``; positions at or beyond ``limit`` are
         masked, and with ``causal`` those after a query row's own. Block j
         lands in buffer slot ``(slot0 + j) % 2``. ``primed``: the walk before
@@ -272,32 +338,48 @@ def _paged_kernel(
                 q_pos = q_off + qi * Bq + rows % Bq
                 invalid = jnp.logical_or(invalid, kv_pos > q_pos)
 
-            for h in range(n_kv):  # static unroll over kv heads
-                q_blk = q_of(h)
-                k_blk = buffers[0][slot, :, :, h * D:(h + 1) * D].reshape(T, D)
-                v_blk = buffers[1][slot, :, :, h * D:(h + 1) * D].reshape(T, D)
+            for t in range(n_tiles):  # static unroll over tiles of kv heads
+                h0 = t * pack
+                W = (min(h0 + pack, n_kv) - h0) * D  # the tile's lanes
+                q_blk = q_of(t, W)
+                k_blk = buffers[0][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
+                v_blk = buffers[1][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
                 k_scale = v_scale = None
                 if quantized:  # int8 is exact in the query dtype
                     k_blk = k_blk.astype(jnp.float32).astype(q_blk.dtype)
                     v_blk = v_blk.astype(jnp.float32).astype(q_blk.dtype)
                     k_scale, v_scale = (
-                        jnp.concatenate([buf[slot, i, h:h + 1, :] for i in range(ppb)],
-                                        axis=1)  # [1, T] per-token scales
+                        jnp.concatenate([buf[slot, i, h0:h0 + pack, :] for i in range(ppb)],
+                                        axis=1)  # [pack, T] per-token scales
                         for buf in buffers[2:])
-                r0 = h * R
+                    if pack > 1:  # a row of scales a query row of the tile
+                        k_scale, v_scale = (
+                            own_head(R, T, lambda i, s=s: s[i:i + 1, :])
+                            for s in (k_scale, v_scale))
+                r0 = t * R
 
                 m_new, l_new, acc_new = _online_softmax_update(
                     q_blk, k_blk, v_blk, invalid,
                     m_ref[r0:r0 + R, :1], l_ref[r0:r0 + R, :1],
-                    acc_ref[r0:r0 + R], scale, k_scale, v_scale,
+                    acc_ref[r0:r0 + R, :W], scale, k_scale, v_scale,
                 )
                 m_ref[r0:r0 + R, :1] = m_new
                 l_ref[r0:r0 + R, :1] = l_new
-                acc_ref[r0:r0 + R] = acc_new
+                acc_ref[r0:r0 + R, :W] = acc_new
             return carry
 
         jax.lax.fori_loop(0, n_blocks, block, None)
         return (slot0 + n_blocks) % 2
+
+    def own_head(rows, cols, piece):
+        """``[rows, cols]`` float32 in which each query row of a tile (a
+        sequence's ``Rt`` rows one tile, each head's rows one after another)
+        has ``piece(i)`` of its own head, the tile's ``i``-th."""
+        row_head = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0) % Rt // (Rt // pack)
+        out = jnp.zeros((rows, cols), jnp.float32)
+        for i in range(pack):
+            out = jnp.where(row_head == i, piece(i), out)
+        return out
 
     def reset(state):
         m_ref, l_ref, acc_ref = state
@@ -323,7 +405,7 @@ def _paged_kernel(
         # starts behind them from its rows of that partial result. The walks
         # of one call are a chain: each starts the first block of the next
         # beside its own last one, so only the call's first copy is uncovered
-        n_shared, gp, B = head_ref[0], _round_up(group, 8), pl.num_programs(0)
+        n_shared, gp, B = head_ref[0], _round_up(pack * group, 8), pl.num_programs(0)
 
         @pl.when(b == 0)
         def _call():
@@ -334,30 +416,53 @@ def _paged_kernel(
             reset(shared_state)
             slot_ref[0] = walk(
                 head_ref[1], 0, n_shared, n_shared * page_size, False,
-                lambda h: qs_ref[h], shared_state, shared_rows, then=own_walk(0))
+                lambda t, W: qs_ref[t, :, :W], shared_state, shared_rows,
+                then=own_walk(0))
 
         @pl.when(member_ref[b] != 0)
         def _resume():
-            for h in range(n_kv):
-                at = pl.ds(pl.multiple_of(h * shared_rows + b * gp, 8), gp)
+            for t in range(n_tiles):
+                at = pl.ds(pl.multiple_of(t * shared_rows + b * gp, 8), gp)
                 for own, shared in zip(own_state, shared_state):
-                    own[h * Rh:(h + 1) * Rh] = shared[at, :][:group]
+                    own[t * Rt:(t + 1) * Rt] = shared[at, :][:Rt]
 
         row, first, n_pages = own_walk(jnp.minimum(b + 1, B - 1))
         chain = dict(slot0=slot_ref[0], primed=jnp.logical_or(b > 0, n_shared > 0),
                      then=(row, first, jnp.where(b + 1 < B, n_pages, 0)))
 
     _row, first, n_pages = own_walk(b)
-    slot = walk(b, first, n_pages, kv_len, True,
-                lambda h: q_ref[0, h * group:(h + 1) * group].reshape(Rh, D),
-                own_state, Rh, **chain)
+    if pack == 1:
+        def own_q(t, W):
+            return q_ref[0, t * group:(t + 1) * group].reshape(Rt, D)
+    else:
+        def own_q(t, W):
+            return q_ref[0, t, :, :W]
+    slot = walk(b, first, n_pages, kv_len, True, own_q, own_state, Rt, **chain)
     if shared_rows:
         slot_ref[0] = slot
 
     m_scr, l_scr, acc_scr = own_state
-    R = n_kv * Rh
-    out = acc_scr[:R] / jnp.maximum(l_scr[:R, :1], 1e-30)
+    R = n_kv * group * Bq
+    if pack == 1:
+        out = acc_scr[:R] / jnp.maximum(l_scr[:R, :1], 1e-30)
+    else:  # a row's own head is at lanes i * D .. (i + 1) * D of its acc
+        full = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
+        out = own_head(full.shape[0], D, lambda i: full[:, i * D:(i + 1) * D])[:R]
     o_ref[0] = out.reshape(n_kv * group, Bq, D).astype(o_ref.dtype)
+
+
+def _block_diagonal(q: Array, n_kv: int, pack: int) -> Array:
+    """Decode queries ``[B, 1, H, D]`` as ``[B, tiles, 8, pack * D]``: a tile's
+    row ``i * group + g`` is query head ``g`` of its ``i``-th KV head at lanes
+    ``i * D .. (i + 1) * D``, zeros elsewhere (and in the rows of a last tile
+    that has fewer heads) — see ``_paged_kernel``."""
+    B, _, H, D = q.shape
+    group, n_tiles = H // n_kv, -(-n_kv // pack)
+    heads = jnp.pad(q.reshape(B, n_kv, group, D),
+                    ((0, 0), (0, n_tiles * pack - n_kv), (0, 0), (0, 0)))
+    own = jnp.eye(pack, dtype=q.dtype).reshape(1, 1, pack, 1, pack, 1)
+    return (heads.reshape(B, n_tiles, pack, group, 1, D) * own).reshape(
+        B, n_tiles, pack * group, pack * D)
 
 
 def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
@@ -380,35 +485,48 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     q, C = _pad_chunk(q)
     bq = _pick_block(C, block_q)
     nq = C // bq
-    r_pad = _round_up(max(H * bq, 8), 8)
-    # decode over more than one row: the shared-head pass, each row's group
-    # of query heads padded to whole 8-row tiles of the stacked block
-    gp = _round_up(group, 8)
+    # a tile: the KV heads of one block update, their lanes of K, V and acc
+    pack = _heads_per_tile(group, bq)
+    n_tiles, Wt = -(-n_kv // pack), pack * D
+    r_pad = _round_up(max(n_tiles * pack * group * bq, 8), 8)
+    # decode over more than one row: the shared-head pass, each row's query
+    # heads of a tile padded to whole 8-row tiles of the stacked block
+    gp = _round_up(pack * group, 8)
     shared_rows = B * gp if C == 1 and B > 1 else 0
 
-    q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, D]
-    q_spec = pl.BlockSpec((1, H, bq, D), lambda b, qi, *_: (b, 0, qi, 0))
+    if pack == 1:
+        q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, D]
+        q_spec = pl.BlockSpec((1, H, bq, D), lambda b, qi, *_: (b, 0, qi, 0))
+        q_bytes = H * max(bq, 8) * D
+    else:
+        q_t = _block_diagonal(q, n_kv, pack)  # [B, tiles, 8, Wt]
+        q_spec = pl.BlockSpec((1, n_tiles, gp, Wt), lambda b, qi, *_: (b, 0, 0, 0))
+        q_bytes = n_tiles * gp * Wt
     prefetch, blocks, in_specs = [layer, page_table, q_offset, kv_len], [q_t], [q_spec]
     state = [pltpu.VMEM((r_pad, 128), jnp.float32),
              pltpu.VMEM((r_pad, 128), jnp.float32),
-             pltpu.VMEM((r_pad, D), jnp.float32)]
+             pltpu.VMEM((r_pad, Wt), jnp.float32)]
     if shared_rows:
         if shared is None:  # a decode query sees what lies below its own position
             shared = shared_head(page_table, jnp.minimum(kv_len, q_offset + 1), page_size)
         prefetch += [jnp.asarray(x, jnp.int32) for x in shared]
-        stacked = jnp.pad(q.reshape(B, n_kv, group, D),
-                          ((0, 0), (0, 0), (0, gp - group), (0, 0)))
-        blocks.append(stacked.transpose(1, 0, 2, 3).reshape(n_kv, shared_rows, D))
-        in_specs.append(pl.BlockSpec((n_kv, shared_rows, D), lambda b, qi, *_: (0, 0, 0)))
-        state += [pltpu.VMEM((n_kv * shared_rows, 128), jnp.float32),
-                  pltpu.VMEM((n_kv * shared_rows, 128), jnp.float32),
-                  pltpu.VMEM((n_kv * shared_rows, D), jnp.float32),
+        if pack == 1:
+            stacked = jnp.pad(q.reshape(B, n_kv, group, D),
+                              ((0, 0), (0, 0), (0, gp - group), (0, 0)))
+        else:
+            stacked = q_t
+        blocks.append(stacked.transpose(1, 0, 2, 3).reshape(n_tiles, shared_rows, Wt))
+        in_specs.append(pl.BlockSpec((n_tiles, shared_rows, Wt), lambda b, qi, *_: (0, 0, 0)))
+        state += [pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
+                  pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
+                  pltpu.VMEM((n_tiles * shared_rows, Wt), jnp.float32),
                   pltpu.SMEM((1,), jnp.int32)]
     # what stands in VMEM beside the K and V buffers: the query and output
     # blocks (the pipeline keeps two of each) and the softmax state
-    reserved = (2 * q.dtype.itemsize * (2 * H * max(bq, 8) * D + n_kv * shared_rows * D)
-                + 4 * (r_pad + n_kv * shared_rows) * (2 * 128 + D))
-    ppb = _pages_per_block(page_size, max(group * bq, shared_rows), n_kv * D,
+    reserved = (2 * q.dtype.itemsize * (q_bytes + H * max(bq, 8) * D
+                                        + n_tiles * shared_rows * Wt)
+                + 4 * (r_pad + n_tiles * shared_rows) * (2 * 128 + Wt))
+    ppb = _pages_per_block(page_size, max(pack * group * bq, shared_rows), n_kv * D,
                            k_pages.dtype.itemsize, page_table.shape[1], reserved)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -425,7 +543,7 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     kernel = functools.partial(
         _paged_kernel,
         block_q=bq, page_size=page_size, pages_per_block=ppb, n_kv=n_kv,
-        group=group, scale=scale, quantized=len(sources) == 4,
+        group=group, pack=pack, scale=scale, quantized=len(sources) == 4,
         shared_rows=shared_rows,
     )
     out_t = pl.pallas_call(

@@ -14,6 +14,10 @@ test, not a slower one.
 physical pages at the head of their tables, as rows admitted on one prefix
 entry do: the kernel's first pass reads such a head once for all of its rows
 (``ops.paged_attention.shared_head`` says which it takes).
+
+``PACKED_SHAPES`` are head counts at which several KV heads' query rows share
+one 8-row softmax tile of the decode kernel (``_heads_per_tile``: one or two
+query heads a KV head), ``PACKED_CASES`` the shared-head cases they run on.
 """
 
 import jax.numpy as jnp
@@ -27,6 +31,13 @@ EDGE_CONTEXTS = [0, 1, PAGE_SIZE - 1, PAGE_SIZE, PAGE_SIZE + 1, BLOCK - 1, BLOCK
 # (group, C): every group at decode, every chunk width at group 4
 SHAPES = [(1, 1), (4, 1), (8, 1), (4, 3), (4, 8), (4, 32)]
 DEAD_PAGE = 1  # physical page 0 is the writers' trash page; both are poisoned
+# (group, KV heads) at decode: Olmo-Hybrid's 30 x 1 (three whole tiles of 8
+# heads and one of 6), one partial tile alone, exactly one tile, and two query
+# heads a KV head (4 heads a tile: one whole, one of a single head)
+PACKED_SHAPES = [(1, 30), (1, 3), (1, 8), (2, 5)]
+# rows of unequal length on one head with rows that are no member; no head at
+# all; inactive slots beside a set; a head of more than one block
+PACKED_CASES = ["a_subset", "no_sharing", "inactive_rows", "a_long_head"]
 
 P = PAGE_SIZE
 # name: (contexts, heads = [(rows, pages of the first row's that they all hold)],

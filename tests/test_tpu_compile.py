@@ -163,8 +163,10 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
 
 
 # olmo-hybrid-7b as perfbench/configs has it: 30 / 30 heads of 128 — ONE query
-# head a KV head (7 of the 8 query rows of each KV head's tile are padding) and
-# a token row of K 3,840 wide, 7.5 KiB: the widest the kernel's blocks hold
+# head a KV head (at decode 8 KV heads share a softmax tile off block-diagonal
+# queries 1,024 lanes wide, the last tile 6 heads: Mosaic's layout rules for
+# that body are checked here) and a token row of K 3,840 wide, 7.5 KiB: the
+# widest the kernel's blocks hold
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("C", [1, 256], ids=["decode", "prefill"])
 def test_paged_attention_compiles_for_v5e_at_olmo_hybrids_head_counts(one_chip, C, quantized):
