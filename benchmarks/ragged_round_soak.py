@@ -5,7 +5,11 @@ tokens; `decode_step` never. Cause not found; the program has no such loop
 any more. Run this again after a compiler upgrade, and before any change
 that puts a loop over layers back inside the period scan.)
 
-    chiprun --timeout 900 -- python3 benchmarks/ragged_round_soak.py scan 120 600
+    chiprun --timeout 900 -- python3 benchmarks/ragged_round_soak.py scan 120 600 [configuration]
+
+(``configuration``: a file of perfbench/configs by name, `olmo-hybrid-7b` if
+left out; PR 34 ran `granite-4.0-h-small`, ten layers unrolled in the period's
+body: 120 iterations and no hang.)
 
 One prompt's two rounds (256 tokens, then 128) at the cell's size, `iters`
 times, under a watchdog that dumps every thread's stack and exits after
@@ -44,7 +48,8 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.runtime import enable_compile_cache
 from perfbench.models import adapter
 enable_compile_cache()
-file = json.loads(Path("perfbench/configs/olmo-hybrid-7b.json").read_text())
+name = sys.argv[4] if len(sys.argv) > 4 else "olmo-hybrid-7b"
+file = json.loads(Path(f"perfbench/configs/{name}.json").read_text())
 c = adapter(file).program_config(file)
 cfg = EngineConfig(**file["engine"])
 t0 = time.time()

@@ -437,6 +437,87 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     return errors
 
 
+def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
+                            timed_calls: int = 10) -> dict[str, float]:
+    """``moe_mlp`` at the shape of ``granite-h-small-report-saturated`` (36 held
+    experts of 768, fused [gate | up], of a router of 72, 10 a token, beside a
+    shared expert of 1,536; hidden 4,096) against the plain reference's loop
+    over the held experts (``perfbench/models/granitemoehybrid.py``), twice:
+    the one-token step's ``rows`` tokens (dense dispatch over the held stacks)
+    and the top ragged bucket's ``tokens`` (the grouped form: 10 pairs a token
+    sorted by expert through ``lax.ragged_dot``). On the chip (``pallas``) also
+    each form's device time from a profiler capture against its bound: the
+    step's against the bytes of the experts it TOUCHED
+    (``moe_step_stream_bytes``, what ``moe_expert_roofline.sat`` divides by),
+    the bucket's against its pairs' FLOPs at 197 TF/s and its bytes."""
+    import json
+    from pathlib import Path
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from finchat_tpu.models.llama import moe_mlp
+    from perfbench.models import granitemoehybrid as granite
+
+    file = json.loads((Path(__file__).resolve().parent
+                       / "perfbench/configs/granite-4.0-h-small.json").read_text())
+    c, s = granite.program_config(file), granite._sizes(file)
+    D, E, F, Fs, R = c.dim, c.n_experts, c.hidden_dim, c.moe_shared_dim, c.moe_router_width
+    ks = jax.random.split(jax.random.key(34), 7)
+    bf16 = jnp.bfloat16
+
+    def normal(k, shape, fan_in, dtype=bf16):
+        return (jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
+
+    lp = {"router": normal(ks[0], (D, R), D, jnp.float32),
+          "moe_in": normal(ks[1], (E, D, 2 * F), D), "moe_out": normal(ks[2], (E, F, D), F),
+          "shared_in": normal(ks[3], (D, 2 * Fs), D), "shared_out": normal(ks[4], (Fs, D), Fs)}
+    stacked = {name: leaf[None] for name, leaf in lp.items()}
+    results = {}
+    for form, n, key in (("dense", rows, ks[5]), ("grouped", tokens, ks[6])):
+        h = jax.random.normal(key, (1, n, D), jnp.float32).astype(bf16)
+        run = jax.jit(lambda h, lp: moe_mlp(h, lp, c, live=jnp.ones(h.shape[:2], bool)))
+        got, touched = run(h, lp)
+        with jax.default_matmul_precision("highest"):
+            want = granite._experts(h[0].astype(jnp.float32), stacked, 0, s, lambda w: w,
+                                    swap=False)
+        got, want = np.asarray(got[0].astype(jnp.float32)), np.asarray(want)
+        require(np.isfinite(got).all(), f"moe_mlp {form}: non-finite output")
+        rel = float(np.sqrt(np.mean((got - want) ** 2)) / np.std(want))
+        # bf16 inputs and one bf16 rounding of every expert's output; a pick
+        # that flips at a margin of a bf16 step exchanges near-equal gates
+        require(rel < 0.03, f"moe_mlp {form}: off the reference by {rel:.4f} of its spread")
+        results[f"{form}_rel"] = rel
+        say(f"moe_mlp {form}: ok ({n} tokens, {int(touched)} of {E} held experts touched; "
+            f"rms error {rel:.4f} of the reference's spread)")
+        if backend != "pallas":
+            continue
+        ops = device_ops_us(lambda: run(h, lp)[0], timed_calls)
+        call_us = sum(us for _name, us in ops) / timed_calls
+        if form == "dense":
+            period = granite.moe_step_stream_bytes(file, rows=n, experts_touched=float(touched))
+            bound_us = 1e6 * period / len(s["kinds"]) / 819e9
+            note = f"stream bound of the {int(touched)} touched experts"
+        else:
+            held_pairs = float(np.sum(np.asarray(jax.lax.top_k(
+                h[0].astype(jnp.float32) @ lp["router"], c.top_k_experts)[1]) < E))
+            flops = 2 * held_pairs * D * 3 * F + 2 * n * D * 3 * Fs
+            nbytes = 2 * (E * 3 * D * F + 3 * D * Fs + 2 * n * D)
+            bound_us = 1e6 * max(flops / 197e12, nbytes / 819e9)
+            note = (f"bound of {held_pairs:.0f} held pairs ({flops / 1e12:.2f} TFLOP at 197 TF/s, "
+                    f"{nbytes / 1e9:.2f} GB at 819 GB/s)")
+        results.update({f"{form}_us": call_us, f"{form}_bound_us": bound_us})
+        by_op = {}
+        for name, us in ops:
+            by_op[name] = by_op.get(name, 0.0) + us / timed_calls
+        top = sorted(by_op.items(), key=lambda kv: -kv[1])[:6]
+        say(f"moe_mlp {form}: {call_us:.1f} us a call; {note} {bound_us:.1f} us: "
+            f"{100 * bound_us / call_us:.1f} % of it; largest operations "
+            + ", ".join(f"{name} {us:.0f}" for name, us in top))
+    return results
+
+
 def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 30,
                                 key_dim: int = 96, value_dim: int = 192,
                                 layers: int = 6, layer: int = 4,
@@ -902,6 +983,13 @@ def _run(mesh_model: int) -> int:
     # kernel at one query head a KV head, and the delta rule's one-token update
     check_decode_at_cell_shape("pallas", shared_pages=31, n_heads=30, n_kv=30)
     check_gdn_step_at_cell_shape("pallas")
+    # many small experts and the mixer as a layer kind at their cell's shape:
+    # the state kernel at 128 heads of 64 x 128, attention with a softmax scale
+    # of its own is check_kernels' and the cell's logits check's, the expert
+    # layer in both of its forms
+    check_ssm_step_at_cell_shape("pallas", heads=128, head_dim=64, state=128, groups=1,
+                                 layers=9, layer=5)
+    check_moe_at_cell_shape("pallas")
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half
     parity_cfg = dataclasses.replace(

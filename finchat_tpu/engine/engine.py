@@ -148,24 +148,25 @@ def _ssm_leaves(config: LlamaConfig, max_seqs: int) -> dict[str, Array]:
 
 def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array, *,
                     config: LlamaConfig, attention, ssm_rows: SsmRows | None,
-                    **kw) -> tuple[Array, DecodeState]:
+                    **kw) -> tuple[Array, DecodeState] | tuple[Array, DecodeState, Array]:
     """``forward`` over the state's caches; returns its output and the state
     with the caches it advanced: the K/V pool and, for a model with a mixer,
-    the recurrent state (``ssm_rows`` says whose state each batch row is)."""
+    the recurrent state (``ssm_rows`` says whose state each batch row is).
+    With ``moe_live`` among ``kw``, ``forward``'s count comes back third."""
     cache = (state.k_pages, state.v_pages, state.k_scales, state.v_scales)
     if not config.has_state:
-        out, cache = forward(params, tokens, positions, config=config,
-                             attention=attention, cache=cache, **kw)
+        out, cache, *count = forward(params, tokens, positions, config=config,
+                                     attention=attention, cache=cache, **kw)
         ssm = (state.ssm_state, state.conv_state)
     else:
-        out, (cache, ssm) = forward(
+        out, (cache, ssm), *count = forward(
             params, tokens, positions, config=config, attention=attention,
             cache=cache, ssm_cache=(state.ssm_state, state.conv_state),
             ssm_rows=ssm_rows, **kw)
     k_pages, v_pages, k_scales, v_scales = cache
     return out, dataclasses.replace(
         state, k_pages=k_pages, v_pages=v_pages, k_scales=k_scales,
-        v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1])
+        v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1]), *count
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -195,6 +196,7 @@ def _paged_attention_fn(
     page_size: int, n_kv: int, attn_backend: str,
     inplace_append: bool = False,
     decode: bool = False,
+    scale: float | None = None,
 ):
     """Build the model's attention callback for paged prefill/decode.
 
@@ -214,6 +216,9 @@ def _paged_attention_fn(
     tables, as rows admitted on one prefix entry do — is read off these three
     arrays HERE, once a step and outside the layer scan, and the kernel reads
     those pages once for all of its rows (``ops.paged_attention``).
+
+    ``scale``: the model's softmax scale (``LlamaConfig.attention_scale``;
+    None = head_dim ** -0.5), handed to the kernel as it is.
     """
     interpret = attn_backend == "pallas-interpret"
     shared = None
@@ -268,7 +273,7 @@ def _paged_attention_fn(
                 layer, page_size=page_size, n_kv=n_kv, backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
-                shared=shared,
+                shared=shared, scale=scale,
             )
         return out, (k_pages, v_pages, k_scales, v_scales)
 
@@ -301,7 +306,7 @@ def prefill_step(
     # the rotary positions above stay absolute. Zero gaps = identity.
     attention = _paged_attention_fn(
         page_rows, start_pos - state.kv_gaps[slots], n_valid,
-        page_size, config.n_kv_heads, attn_backend
+        page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
     )
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
@@ -581,8 +586,9 @@ def decode_step(
     attn_backend: str = "ref",
     qm_backend: str = "ref",
     return_logits: bool = False,
-) -> tuple[DecodeState, Array, Array | None]:
-    """One decode step for ALL slots; returns (state, next_tokens [max_seqs]).
+) -> tuple[DecodeState, Array, Array | None, Array | None]:
+    """One decode step for ALL slots; returns (state, next_tokens [max_seqs],
+    logits?, experts_touched?).
 
     Each active slot's ``last_token`` KV is appended at ``context_lens`` and
     the next token sampled from its logits. Inactive slots write to the
@@ -591,6 +597,11 @@ def decode_step(
     ``return_logits=True`` additionally returns the step logits [B, vocab]
     (fp32) — the host-side path for grammar-constrained sampling
     (agent/constrained.py), which overrides ``last_tokens`` afterwards.
+
+    A model that routes sparsely (``config.moe_sparse``) also returns, as one
+    int32 beside the tokens, the number of distinct held experts that ACTIVE
+    rows picked, summed over the layers: the expert weights this step had to
+    read (None for every other model: no output, no operation).
     """
     tokens = state.last_tokens[:, None]  # [B, 1]
     positions = state.context_lens[:, None]  # [B, 1] — absolute (rotary)
@@ -600,16 +611,17 @@ def decode_step(
     # reduce to the legacy absolute math bit-for-bit)
     attention = _paged_attention_fn(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
-        page_size, config.n_kv_heads, attn_backend, decode=True,
+        page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
     )
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
     # pass over the layer's state, on `ref` a slice, _step and an update)
-    logits, state = _forward_cached(
+    logits, state, *touched = _forward_cached(
         params, state, tokens, positions,
         config=config, attention=attention,
         ssm_rows=SsmRows(None, n_valid, backend=attn_backend),
         qm_backend=qm_backend,
+        **({"moe_live": active[:, None]} if config.moe_sparse else {}),
     )
     step_logits = logits[:, 0, :]  # [B, vocab]
 
@@ -622,7 +634,8 @@ def decode_step(
         last_tokens=jnp.where(active, next_tokens, state.last_tokens),
         rng=rng,
     )
-    return new_state, next_tokens, (step_logits if return_logits else None)
+    return (new_state, next_tokens, (step_logits if return_logits else None),
+            touched[0] if touched else None)
 
 
 def _ragged_attention_fn(
@@ -635,6 +648,7 @@ def _ragged_attention_fn(
     n_kv: int,
     attn_backend: str,
     row_gap: Array | None = None,  # [R] int32 — bounded-KV eviction gap
+    scale: float | None = None,  # the model's softmax scale (None = D ** -0.5)
 ):
     """Attention callback for the packed ragged step (``ragged_mixed_step``):
     per-token KV writes through the chunk scatter (one full-cache copy per
@@ -684,7 +698,7 @@ def _ragged_attention_fn(
                 backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
-                kv_gap=row_gap,
+                kv_gap=row_gap, scale=scale,
             )
         return out[None], (k_pages, v_pages, k_scales, v_scales)
 
@@ -767,7 +781,7 @@ def _ragged_round_math(
 
     attention = _ragged_attention_fn(
         page_rows, tok_row, tok_pos, row_kv_len, tok_valid,
-        page_size, config.n_kv_heads, attn_backend, row_gap=row_gap,
+        page_size, config.n_kv_heads, attn_backend, row_gap=row_gap, scale=config.attention_scale,
     )
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes
@@ -847,7 +861,7 @@ def _ragged_round_math(
 
             attn = _paged_attention_fn(
                 state.page_table, state.context_lens - state.kv_gaps, n_valid,
-                page_size, config.n_kv_heads, attn_backend, decode=True,
+                page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
             )
             step_logits, (kp, vp, ks, vs) = forward(
                 params, toks, positions,
@@ -1132,7 +1146,7 @@ def decode_loop_step(
         # compacted write/mask coordinates (bounded KV; see decode_step)
         attention = _paged_attention_fn(
             state.page_table, state.context_lens - state.kv_gaps, n_valid,
-            page_size, config.n_kv_heads, attn_backend, decode=True,
+            page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
         )
         logits, (k_pages, v_pages, k_scales, v_scales) = forward(
             params, tokens, positions,
@@ -1227,7 +1241,7 @@ def verify_step(
     # is overwritten when those positions are reached for real
     attention = _paged_attention_fn(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
-        page_size, config.n_kv_heads, attn_backend, inplace_append=True,
+        page_size, config.n_kv_heads, attn_backend, inplace_append=True, scale=config.attention_scale,
     )
     logits, (k_pages, v_pages, k_scales, v_scales) = forward(
         params, tokens, positions,
@@ -1322,6 +1336,10 @@ class InferenceEngine:
         # the scheduler emits it as the finchat_warmup_compiled_variants
         # gauge — the ISSUE 10 warmup-matrix-collapse instrument
         self.compiled_variants = 0
+        # the last decode step's count of held experts touched, on the device
+        # (a model that routes sparsely; else None): the scheduler fetches it
+        # with the step's tokens
+        self.experts_touched = None
         self.max_pages_per_seq = min(
             engine_cfg.num_pages - 1,
             -(-engine_cfg.max_seq_len // engine_cfg.page_size),
@@ -1353,6 +1371,8 @@ class InferenceEngine:
         self.kv_quant = kv_quant = engine_cfg.kv_quant
         if config.has_state:
             self._refuse_without_state_carry(mesh)
+        if config.moe_fused_glu:
+            self._refuse_with_held_experts(mesh, quant)
         state = create_state(config, engine_cfg, self.max_pages_per_seq, kv_quant=kv_quant)
         if mesh is not None:
             # TP placement: params sharded Megatron-style, KV pages sharded
@@ -1403,6 +1423,23 @@ class InferenceEngine:
                 f"Mamba-2 mixer or linear-attention layers) "
                 f"carries it through prefill_step, decode_step and "
                 f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
+
+    def _refuse_with_held_experts(self, mesh, quant: str) -> None:
+        """A model whose expert stacks are a held range of a wider router
+        (``moe_mlp``): where its experts live is said ONCE, by the range or by
+        a mesh's ``expert`` axis; and no quantized matmul runs over the
+        grouped stacks (ROADMAP R1)."""
+        c = self.config
+        if (mesh is not None and mesh.shape.get("expert", 1) > 1
+                and c.moe_router_width != c.n_experts):
+            raise ValueError(
+                f"mesh.expert={mesh.shape['expert']} together with a held range of experts "
+                f"(the first {c.n_experts} of {c.moe_router_width}): one or the other says "
+                "where experts live")
+        if quant:
+            raise ValueError(
+                f"model.quant={quant!r} is not supported for a model with fused-GLU expert "
+                "stacks: the grouped matmul (lax.ragged_dot) takes no quantized operand")
 
     @property
     def ssm_state_bytes(self) -> int:
@@ -1870,7 +1907,7 @@ class InferenceEngine:
         top_p = jnp.ones((B,), jnp.float32)
         top_k = jnp.zeros((B,), jnp.int32)
         for return_logits in (False, True):
-            self.state, _, _ = decode_step(
+            self.state, *_ = decode_step(
                 self.params, self.state, inactive, temp, top_p, top_k,
                 config=self.config, page_size=self.page_size,
                 attn_backend=self.attn_backend, qm_backend=self.qm_backend, return_logits=return_logits,
@@ -1998,7 +2035,7 @@ class InferenceEngine:
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_decode_dispatches_total")
-        self.state, next_tokens, logits = decode_step(
+        self.state, next_tokens, logits, self.experts_touched = decode_step(
             self.params, self.state, active, temperature, top_p, top_k,
             config=self.config, page_size=self.page_size,
             attn_backend=self.attn_backend, qm_backend=self.qm_backend, return_logits=return_logits,

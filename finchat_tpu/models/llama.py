@@ -19,7 +19,7 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 from functools import partial
-from typing import Any, Callable
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -36,8 +36,20 @@ AttentionFn = Callable[[Array, Array, Array, Any, Array], tuple[Array, Any]]
 
 # the kinds of layer a ``layer_pattern`` may name (the published configs' own
 # words): softmax attention over the paged cache, or the gated delta rule over
-# a recurrent state by slot (models/gdn.py). Each is followed by the MLP
-FULL, LINEAR = "full_attention", "linear_attention"
+# a recurrent state by slot (models/gdn.py), or the Mamba-2 mixer alone
+# (models/ssm.py) over its own state by slot. Each is followed by the MLP
+FULL, LINEAR, MAMBA = "full_attention", "linear_attention", "mamba"
+
+# moe_mlp's one rule (see there). Dense dispatch computes every held expert
+# over every token: router width / picks a token times the FLOPs the picks
+# need. Up to this factor it is taken (static shapes, no sort, no gather);
+# past it a model "routes sparsely" (``LlamaConfig.moe_sparse``) ...
+MOE_DENSE_WASTE_MAX = 4
+# ... and its calls of more than this many tokens take the grouped form. Up to
+# it a dense pass over the held stacks is bound by the weights' bytes, which it
+# reads once, and not by its FLOPs (a token is one FLOP a weight byte; a v5e
+# has 240 of them a byte)
+MOE_DENSE_TOKENS_MAX = 128
 
 
 @dataclass(frozen=True)
@@ -58,8 +70,26 @@ class LlamaConfig:
     # over the mesh's `expert` axis (EP) — see moe_mlp below.
     n_experts: int = 0
     top_k_experts: int = 2
+    # a chip's share of a wider layer: the router scores ``moe_router_width``
+    # experts (0 = n_experts) of which THIS process holds the first
+    # ``n_experts``; a pick that falls on an absent expert adds nothing here
+    # (its chip adds it), and the gates stay normalised over all picks.
+    # Nothing stands in for the absent chip or its exchange
+    moe_router_width: int = 0
+    # a shared expert of this width beside the routed ones: every token,
+    # unweighted. 0 = none
+    moe_shared_dim: int = 0
+    # the GLU's two halves as one matrix, [gate | up]: ``moe_in [E, D, 2F]``
+    # and ``moe_out [E, F, D]`` instead of ``moe_gate`` / ``moe_up`` /
+    # ``moe_down`` (and ``shared_in`` / ``shared_out`` beside them)
+    moe_fused_glu: bool = False
     # a head's width where it is not dim / n_heads (0 = that quotient)
     head_dim: int = 0
+    # the softmax scale where it is not head_dim ** -0.5 (None = that): handed
+    # to the attention kernels' ``scale`` as it is, never folded into q
+    attention_scale: float | None = None
+    # one scalar on BOTH sub-blocks' outputs before the residual addition
+    residual_multiplier: float = 1.0
     # scalar µP multipliers (Falcon-H1). 1 = absent: nothing is emitted for
     # it, so a config without them compiles to the program it always was
     embedding_multiplier: float = 1.0
@@ -68,9 +98,10 @@ class LlamaConfig:
     attention_out_multiplier: float = 1.0
     key_multiplier: float = 1.0
     mlp_multipliers: tuple[float, float] = (1.0, 1.0)  # gate, down
-    # a Mamba-2 mixer beside attention in every layer (models/ssm.py), both
-    # on one normed input, their outputs summed into the residual.
-    # ssm_heads 0 = none: today's block
+    # a Mamba-2 mixer (models/ssm.py). Without a ``layer_pattern``: beside
+    # attention in every layer, both on one normed input, their outputs summed
+    # into the residual (Falcon-H1). With one: the MAMBA layers' mixer, alone
+    # in attention's place. ssm_heads 0 = none: today's block
     ssm_heads: int = 0
     ssm_head_dim: int = 0
     ssm_state: int = 0  # state channels a head-channel (N)
@@ -81,9 +112,10 @@ class LlamaConfig:
     ssm_out_multiplier: float = 1.0
     ssm_multipliers: tuple[float, ...] = (1.0,) * 5  # z, xs, B, C, dt
     # layers of more than one kind: the kinds of ONE period, repeated down the
-    # depth (Olmo-Hybrid: three LINEAR, then one FULL). Empty = every layer
-    # alike, today's block. Parameters and caches are stacked by KIND: only
-    # FULL layers own pages, only LINEAR ones (or the mixer above) state
+    # depth (Olmo-Hybrid: three LINEAR, then one FULL; Granite-4.0-H: five
+    # MAMBA, one FULL, four MAMBA). Empty = every layer alike, today's block.
+    # Parameters and caches are stacked by KIND: only FULL layers own pages,
+    # only LINEAR or MAMBA ones (or the mixer in every layer, above) state
     layer_pattern: tuple[str, ...] = ()
     # RMSNorm over the whole width of q and of k, before the heads are split
     qk_norm: bool = False
@@ -102,15 +134,29 @@ class LlamaConfig:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
         pattern = self.layer_pattern
         if pattern:
-            if set(pattern) - {FULL, LINEAR} or self.n_layers % len(pattern):
+            if set(pattern) - {FULL, LINEAR, MAMBA} or self.n_layers % len(pattern):
                 raise ValueError(
-                    f"layer_pattern {pattern}: kinds are {FULL!r} and {LINEAR!r}, and "
-                    f"n_layers ({self.n_layers}) is a whole number of periods")
-            if self.ssm_heads:
-                raise ValueError("a layer_pattern and a Mamba-2 mixer in every layer "
-                                 "(ssm_heads) do not combine")
+                    f"layer_pattern {pattern}: kinds are {FULL!r}, {LINEAR!r} and {MAMBA!r}, "
+                    f"and n_layers ({self.n_layers}) is a whole number of periods")
+            if (MAMBA in pattern) != bool(self.ssm_heads):
+                raise ValueError(f"under a layer_pattern, ssm_heads and {MAMBA!r} layers go "
+                                 "together (the mixer beside attention in EVERY layer is "
+                                 "ssm_heads without a pattern)")
+            if MAMBA in pattern and LINEAR in pattern:
+                raise ValueError(f"{MAMBA!r} and {LINEAR!r} layers in one layer_pattern: the "
+                                 "recurrent state by slot has one shape")
         if (LINEAR in pattern) != bool(self.gdn_heads):
             raise ValueError(f"gdn_heads and {LINEAR!r} layers in layer_pattern go together")
+        if not self.moe_router_width:
+            object.__setattr__(self, "moe_router_width", self.n_experts)
+        if self.moe_router_width < self.n_experts:
+            raise ValueError(
+                f"the {self.n_experts} held experts are not among the router's "
+                f"{self.moe_router_width}")
+        if (self.moe_shared_dim
+                or self.moe_router_width != self.n_experts) and not self.moe_fused_glu:
+            raise ValueError("a shared expert and a held range of experts come with the "
+                             "fused GLU layout (moe_fused_glu)")
 
     def n_of(self, kind: str) -> int:
         """Layers of ``kind``: the depth of that kind's stacks."""
@@ -127,7 +173,18 @@ class LlamaConfig:
     def n_state_layers(self) -> int:
         """Layers that carry recurrent state by slot: the depth of
         ``DecodeState.ssm_state`` / ``conv_state``."""
-        return self.n_layers if self.ssm_heads else self.n_of(LINEAR)
+        if self.ssm_heads and not self.layer_pattern:
+            return self.n_layers
+        return self.n_of(LINEAR) + self.n_of(MAMBA)
+
+    @property
+    def moe_sparse(self) -> bool:
+        """Many small experts: dense dispatch over the held stacks would
+        compute more than ``MOE_DENSE_WASTE_MAX`` times what the picks need,
+        and a step touches only some of them — ``moe_mlp`` groups the tokens
+        of a large call by expert, and ``decode_step`` counts the held
+        experts a step touched."""
+        return self.moe_router_width > MOE_DENSE_WASTE_MAX * self.top_k_experts
 
     @property
     def has_state(self) -> bool:
@@ -207,21 +264,23 @@ def n_params(config: LlamaConfig) -> int:
     attn = d * (c.n_heads * hd) + 2 * d * (c.n_kv_heads * hd) + (c.n_heads * hd) * d
     mlp = 3 * d * c.hidden_dim
     if c.n_experts:
-        mlp = mlp * c.n_experts + d * c.n_experts  # experts + router
+        # the held experts, the router at its whole width, the shared expert
+        mlp = mlp * c.n_experts + d * c.moe_router_width + 3 * d * c.moe_shared_dim
     if c.qk_norm:
         attn += (c.n_heads + c.n_kv_heads) * hd
     per_layer = mlp + 2 * d
-    if c.ssm_heads:
-        # in/out projections, conv weight and bias, A_log, dt_bias, D, the
-        # gated norm's weight
-        per_layer += (d * c.ssm_in_dim + c.d_ssm * d
-                      + (c.ssm_conv + 1) * c.ssm_conv_dim + 3 * c.ssm_heads + c.d_ssm)
+    # in/out projections, conv weight and bias, A_log, dt_bias, D, the gated
+    # norm's weight: in every layer, or in the MAMBA layers of a pattern
+    ssm = (d * c.ssm_in_dim + c.d_ssm * d
+           + (c.ssm_conv + 1) * c.ssm_conv_dim + 3 * c.ssm_heads + c.d_ssm)
+    if c.ssm_heads and not c.layer_pattern:
+        per_layer += ssm
     # [q | k | v | gate] and [b | a] in, out, the conv, A_log, dt_bias, the norm
     d_v = c.gdn_heads * c.gdn_value_dim
     linear = (d * (c.gdn_conv_dim + d_v + 2 * c.gdn_heads) + d_v * d
               + c.gdn_conv * c.gdn_conv_dim + 2 * c.gdn_heads + c.gdn_value_dim)
     total = (c.vocab_size * d + c.n_layers * per_layer + c.n_attn_layers * attn
-             + c.n_of(LINEAR) * linear + d)
+             + c.n_of(LINEAR) * linear + c.n_of(MAMBA) * ssm + d)
     if not c.tie_embeddings:
         total += d * c.vocab_size
     return total
@@ -235,6 +294,18 @@ def n_params(config: LlamaConfig) -> int:
 # path so pinned golden decode sequences are unchanged. Module-level so
 # tests can patch it to exercise the large-leaf branch at small shapes.
 FP32_INIT_MAX_ELEMS = 1 << 28
+# Leaves with more elements than this are drawn one index of their leading
+# axis at a time into a buffer that is updated in place: a stack of held
+# experts [10, 36, 4096, 1536] is 4.5 GB in bf16, and its random bits and the
+# normal transform's temporaries beside it and the tree drawn so far do not
+# fit a 16 GB chip. Above every leaf an older configuration draws (their
+# values are as they were). Module-level, as the one above, for tests
+SLICED_INIT_MIN_ELEMS = 1 << 31
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _set_leading(leaf: Array, part: Array, i: Array) -> Array:
+    return lax.dynamic_update_index_in_dim(leaf, part, i, 0)
 
 
 def init_params(
@@ -268,6 +339,12 @@ def init_params(
 
         # see FP32_INIT_MAX_ELEMS: large leaves skip the fp32 intermediate
         gen_dtype = c.dtype if math.prod(shape) > FP32_INIT_MAX_ELEMS else jnp.float32
+        if math.prod(shape) > SLICED_INIT_MIN_ELEMS:  # see there: layer by layer, in place
+            leaf = jnp.zeros(shape, c.dtype)
+            for i in range(shape[0]):
+                part = jax.random.normal(jax.random.fold_in(k, i), shape[1:], gen_dtype)
+                leaf = _set_leading(leaf, (part * fan_in ** -0.5).astype(c.dtype), jnp.int32(i))
+            return tf(name, leaf)
         return tf(name, (jax.random.normal(k, shape, gen_dtype) * fan_in ** -0.5).astype(c.dtype))
 
     keys = jax.random.split(k_layers, 8)
@@ -285,7 +362,25 @@ def init_params(
         },
         "norm": jnp.ones((D,), c.dtype),
     }
-    if c.n_experts:
+    if c.n_experts and c.moe_fused_glu:
+        E, Fs = c.n_experts, c.moe_shared_dim
+        params["layers"].update(
+            {
+                "router": jax.random.normal(
+                    keys[7], (L, D, c.moe_router_width), jnp.float32) * D ** -0.5,
+                "moe_in": rand_init("moe_in", keys[4], (L, E, D, 2 * F), D),
+                "moe_out": rand_init("moe_out", keys[6], (L, E, F, D), F),
+            }
+        )
+        if Fs:
+            ks = jax.random.split(keys[5])
+            params["layers"].update(
+                {
+                    "shared_in": rand_init("shared_in", ks[0], (L, D, 2 * Fs), D),
+                    "shared_out": rand_init("shared_out", ks[1], (L, Fs, D), Fs),
+                }
+            )
+    elif c.n_experts:
         E = c.n_experts
         params["layers"].update(
             {
@@ -311,20 +406,21 @@ def init_params(
         # decay in one token or never, and nothing downstream would see it
         ks = jax.random.split(jax.random.fold_in(k_layers, 1), 6)
         Hs, Cc, K = c.ssm_heads, c.ssm_conv_dim, c.ssm_conv
+        Lm = c.n_state_layers  # every layer, or the MAMBA layers of a pattern
         dt = jnp.exp(jax.random.uniform(
-            ks[4], (L, Hs), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
+            ks[4], (Lm, Hs), jnp.float32, jnp.log(1e-3), jnp.log(1e-1)))
         params["layers"].update(
             {
-                "ssm_in": rand_init("ssm_in", ks[0], (L, D, c.ssm_in_dim), D),
-                "ssm_out": rand_init("ssm_out", ks[1], (L, c.d_ssm, D), c.d_ssm),
+                "ssm_in": rand_init("ssm_in", ks[0], (Lm, D, c.ssm_in_dim), D),
+                "ssm_out": rand_init("ssm_out", ks[1], (Lm, c.d_ssm, D), c.d_ssm),
                 "ssm_conv_w": jax.random.uniform(
-                    ks[2], (L, K, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
+                    ks[2], (Lm, K, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
                 "ssm_conv_b": jax.random.uniform(
-                    ks[3], (L, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
-                "ssm_A_log": jnp.log(jax.random.uniform(ks[5], (L, Hs), jnp.float32, 1.0, 16.0)),
+                    ks[3], (Lm, Cc), jnp.float32, -1.0, 1.0).astype(c.dtype) * K ** -0.5,
+                "ssm_A_log": jnp.log(jax.random.uniform(ks[5], (Lm, Hs), jnp.float32, 1.0, 16.0)),
                 "ssm_dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt)
-                "ssm_D": jnp.ones((L, Hs), jnp.float32),
-                "ssm_norm": jnp.ones((L, c.d_ssm), c.dtype),
+                "ssm_D": jnp.ones((Lm, Hs), jnp.float32),
+                "ssm_norm": jnp.ones((Lm, c.d_ssm), c.dtype),
             }
         )
     if c.qk_norm:
@@ -343,6 +439,8 @@ def _stack_kind(name: str) -> str | None:
     one kind's layers, or (None) every layer."""
     if name.startswith("gdn_"):
         return LINEAR
+    if name.startswith("ssm_"):
+        return MAMBA
     return FULL if name.startswith("attn_") else None
 
 
@@ -367,32 +465,74 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
     return rotated.astype(x.dtype)
 
 
+class StackedLeaf(NamedTuple):
+    """A layer's leaf left where it lies in its stack ``[L, ...]``, with the
+    layer's index. A grouped matmul is a custom call, whose operand has to be
+    a buffer of its own: handed a layer's slice of the held experts it would
+    be handed a COPY (0.45 GB a layer at Granite's size, all ten alive at once
+    where the compiler unrolls a scan of one period: 4.5 GB). Handed the whole
+    stack with the other layers' groups empty it copies nothing."""
+    stack: Array
+    index: Array
+
+    def take(self) -> Array:
+        return lax.dynamic_index_in_dim(self.stack, self.index, 0, keepdims=False)
+
+
 def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
-            qm_backend: str | None = None) -> Array:
-    """Mixtral-style top-k routed SwiGLU experts, expert-parallel the GSPMD
-    way: expert weights carry a leading E axis sharded over the mesh's
-    ``expert`` axis (parallel/sharding.py), every expert computes over all
-    tokens with its gate weight zeroed where not routed, and XLA turns the
-    expert-sum into a psum over the EP shards. Dense dispatch — no token
-    dropping / capacity factor; per-token FLOPs scale with E rather than
-    top_k, the classic trade for static shapes at small E. A
-    capacity-bucketed all_to_all dispatch is the upgrade path when E is
-    large enough for dense dispatch to dominate the profile.
-    """
+            qm_backend: str | None = None, live: Array | None = None
+            ) -> Array | tuple[Array, Array]:
+    """Top-k routed SwiGLU experts: the router scores ``moe_router_width``
+    experts in float32, the ``top_k_experts`` largest are a token's picks, and
+    their gates are the softmax over the picked logits alone.
+
+    This process holds the first ``n_experts`` of them (Mixtral: all of
+    them). A pick on an absent expert adds nothing here — the
+    chip that holds it adds it — and the gates stay normalised over ALL picks,
+    so the shares of the chips that split a layer sum to the whole layer; a
+    shared expert (``moe_shared_dim``) is added to every token, unweighted.
+
+    Which form a call takes is ONE rule on static shapes (``LlamaConfig.
+    moe_sparse``, ``MOE_DENSE_WASTE_MAX``, ``MOE_DENSE_TOKENS_MAX``):
+
+    - dense dispatch — every held expert computes over all tokens with its
+      gate zeroed where not routed; expert weights carry a leading E axis
+      that shards over the mesh's ``expert`` axis (parallel/sharding.py) and
+      XLA turns the expert-sum into a psum over the EP shards. Static shapes,
+      no token dropping; FLOPs scale with E rather than with the picks. Taken
+      where that waste is at most ``MOE_DENSE_WASTE_MAX`` x (Mixtral's 8 / 2),
+      and by a model that routes sparsely for a call of at most
+      ``MOE_DENSE_TOKENS_MAX`` tokens (the one-token step: bound by the
+      weights' bytes, which it reads once, all held experts of them);
+    - grouped — the (token, pick) pairs sorted by expert, pairs on absent
+      experts behind the last group and not computed, one grouped (ragged)
+      matmul over the held stacks each way (``lax.ragged_dot``, static
+      capacity tokens x top_k), scattered back with the gates: a model that
+      routes sparsely, for more tokens than that.
+
+    ``live`` [B, S] bool (a model that routes sparsely, the decode step): also
+    returns the number of distinct held experts that live tokens picked — the
+    expert weights this layer's step had to read."""
     c = config
-    E = c.n_experts
+    E, k = c.n_experts, c.top_k_experts
     with jax.named_scope("moe_router"):
         # router in fp32 (routing decisions are precision-sensitive; the router
         # leaf itself is kept fp32 by init_params / the checkpoint loader)
         r = jnp.einsum("bsd,de->bse", h, layer_params["router"],
-                       preferred_element_type=jnp.float32)  # [B,S,E]
+                       preferred_element_type=jnp.float32)  # [B,S,R]
         # exactly-k selection from top_k INDICES (threshold comparison would
         # over-select on tied logits); softmax over the selected logits only
         # (Mixtral renormalization), scattered back to expert positions
-        top_vals, top_idx = jax.lax.top_k(r, c.top_k_experts)  # [B,S,k]
+        top_vals, top_idx = jax.lax.top_k(r, k)  # [B,S,k]
         w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
-        onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]
-        gates = jnp.einsum("bske,bsk->bse", onehot, w).astype(h.dtype)  # [B,S,E]
+        grouped = c.moe_sparse and h.shape[0] * h.shape[1] > MOE_DENSE_TOKENS_MAX
+        if not grouped or live is not None:
+            onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]; absent (>= E): zeros
+        if not grouped:
+            gates = jnp.einsum("bske,bsk->bse", onehot, w).astype(h.dtype)  # [B,S,E]
+        if live is not None:
+            picked = jnp.any((onehot > 0) & live[:, :, None, None], axis=(0, 1, 2))
+            touched = jnp.sum(picked.astype(jnp.int32))
 
     def expert_mm(spec: str, x: Array, w: Array | QTensor | Q4Tensor) -> Array:
         # int8/int4 serving: the stacked-expert einsums keep INLINE dequant
@@ -407,12 +547,70 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
             w = dequantize(w, x.dtype)
         return jnp.einsum(spec, x, w)
 
+    def glu(u: Array) -> Array:  # [gate | up] -> SiLU(gate) * up
+        gate, up = jnp.split(u, 2, axis=-1)
+        return jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
+
+    if grouped:
+        out = _moe_grouped(h, top_idx, w, layer_params, c, glu)
+    else:
+        with jax.named_scope("moe_experts"):
+            def held(name: str) -> Array:  # the layer's own slice fuses into the dot
+                leaf = layer_params[name]
+                return leaf.take() if isinstance(leaf, StackedLeaf) else leaf
+
+            if c.moe_fused_glu:
+                act = glu(expert_mm("bsd,edf->bsef", h, held("moe_in")))
+            else:
+                gate = expert_mm("bsd,edf->bsef", h, layer_params["moe_gate"])
+                up = expert_mm("bsd,edf->bsef", h, layer_params["moe_up"])
+                act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
+            act = act * gates[..., None]  # zero non-routed experts pre-projection
+            out = expert_mm("bsef,efd->bsd", act,
+                            held("moe_out") if c.moe_fused_glu else layer_params["moe_down"])
+    if c.moe_shared_dim:
+        with jax.named_scope("moe_shared"):
+            out = out + dense(glu(dense(h, layer_params["shared_in"], qm_backend=qm_backend)),
+                              layer_params["shared_out"], qm_backend=qm_backend)
+    return out if live is None else (out, touched)
+
+
+def _moe_grouped(h: Array, top_idx: Array, w: Array, layer_params: dict[str, Array],
+                 config: LlamaConfig, glu: Callable[[Array], Array]) -> Array:
+    """``moe_mlp``'s grouped form: ``top_idx`` [B,S,k] are the picks as
+    indices into the HELD stacks (outside [0, E): an absent expert), ``w``
+    their gates."""
+    E, k = config.n_experts, top_idx.shape[-1]
+    B, S, D = h.shape
+    with jax.named_scope("moe_group"):
+        expert = top_idx.reshape(-1)  # [N*k], pair p is token p // k
+        held = (expert >= 0) & (expert < E)
+        # a stable sort by expert, the pairs on absent experts last: behind
+        # the last group, where the grouped matmul computes nothing
+        order = jnp.argsort(jnp.where(held, expert, E), stable=True)
+        sizes = jnp.sum(jax.nn.one_hot(expert, E, dtype=jnp.int32), axis=0)  # absent: no group
+        x = h.reshape(B * S, D)[order // k]  # [N*k, D], grouped by expert
+
+        def groups(leaf: Array | StackedLeaf) -> tuple[Array, Array]:
+            if not isinstance(leaf, StackedLeaf):
+                return leaf, sizes
+            # the whole stack as [L * E, ...] (no copy): this layer's groups
+            # at their place, every other layer's empty
+            every = jnp.zeros((leaf.stack.shape[0] * E,), jnp.int32)
+            return (leaf.stack.reshape(-1, *leaf.stack.shape[2:]),
+                    lax.dynamic_update_slice(every, sizes, (leaf.index * E,)))
     with jax.named_scope("moe_experts"):
-        gate = expert_mm("bsd,edf->bsef", h, layer_params["moe_gate"])
-        up = expert_mm("bsd,edf->bsef", h, layer_params["moe_up"])
-        act = jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
-        act = act * gates[..., None]  # zero non-routed experts pre-projection
-        return expert_mm("bsef,efd->bsd", act, layer_params["moe_down"])
+        act = glu(lax.ragged_dot(x, *groups(layer_params["moe_in"])))
+        y = lax.ragged_dot(act, *groups(layer_params["moe_out"]))  # [N*k, D]
+    with jax.named_scope("moe_group"):
+        # back to the pairs' own order, a token's picks side by side; rows
+        # behind the last group are not the matmul's to define: gate 0, and out
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(order.shape[0], dtype=order.dtype))
+        gate = jnp.where(held, w.reshape(-1), 0.0).reshape(B * S, k)
+        y = jnp.where(gate[..., None] != 0, y[back].reshape(B * S, k, D), 0)
+        out = jnp.einsum("nkd,nk->nd", y, gate.astype(y.dtype),
+                         preferred_element_type=jnp.float32)
+        return out.astype(h.dtype).reshape(B, S, D)
 
 
 def _layer(
@@ -432,7 +630,8 @@ def _layer(
     ssm_cache: Any = None,
     ssm_rows: Any = None,
     kind: str = FULL,
-) -> tuple[Array, Any] | tuple[Array, Any, Any]:
+    moe_live: Array | None = None,
+) -> tuple[Array, ...]:
     """One decoder layer. Under GSPMD (the usual path) ``tp_axis`` is
     None — the compiler partitions from the param shardings. Under an
     ALL-MANUAL ``shard_map`` (the stage pipeline, parallel/pipeline.py)
@@ -451,8 +650,10 @@ def _layer(
     ``kind`` is the layer's place in ``config.layer_pattern``: a LINEAR layer
     runs the gated delta rule (models/gdn.py) in attention's place, over the
     recurrent state alone; ``layer_idx`` is the layer's index among its OWN
-    kind, which is how the caches are stacked. With ``config.norm_after`` the
-    two norms stand on the sub-blocks' outputs instead of their inputs."""
+    kind, which is how the caches are stacked; a MAMBA layer runs the Mamba-2
+    mixer alone there. With ``config.norm_after`` the two norms stand on the
+    sub-blocks' outputs instead of their inputs. With ``moe_live`` (see
+    ``moe_mlp``) the count of held experts touched is the last element."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
@@ -472,8 +673,15 @@ def _layer(
         with jax.named_scope("gdn_out"):
             x = x + norm_out(mixed, layer_params["ln_attn"])
         new_layer_cache = layer_cache
+    elif kind == MAMBA:
+        assert tp_axis is None, "manual-TP stage blocks have no mixer"
+        mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
+                                 qm_backend=qm_backend)
+        with jax.named_scope("ssm_out"):
+            x = x + scaled(norm_out(mixed, layer_params["ln_attn"]), c.residual_multiplier)
+        new_layer_cache = layer_cache
     else:
-        if c.ssm_heads:
+        if c.ssm_heads and not c.layer_pattern:
             assert tp_axis is None, "manual-TP stage blocks have no mixer"
             mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
                                      qm_backend=qm_backend)
@@ -506,18 +714,20 @@ def _layer(
             else:
                 attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
                                   qm_backend=qm_backend)
-            x = x + norm_out(scaled(attn_proj, c.attention_out_multiplier),
-                             layer_params["ln_attn"])
-            if c.ssm_heads:
+            x = x + scaled(norm_out(scaled(attn_proj, c.attention_out_multiplier),
+                                    layer_params["ln_attn"]), c.residual_multiplier)
+            if c.ssm_heads and not c.layer_pattern:
                 x = x + mixed
 
     h = norm_in(x, layer_params["ln_mlp"])
     if c.n_experts:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
-        moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend)
+        moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend, live=moe_live)
+        if moe_live is not None:
+            moe_out, touched = moe_out
         with jax.named_scope("moe_experts"):
             # the residual add fuses into the down matmul
-            x = x + norm_out(moe_out, layer_params["ln_mlp"])
+            x = x + scaled(norm_out(moe_out, layer_params["ln_mlp"]), c.residual_multiplier)
     else:
         with jax.named_scope("mlp"):
             gate = scaled(dense(h, layer_params["mlp_gate"], qm_backend=qm_backend),
@@ -533,10 +743,10 @@ def _layer(
                 )
             else:
                 down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
-            x = x + norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"])
-    if c.has_state:
-        return x, new_layer_cache, ssm_cache
-    return x, new_layer_cache
+            x = x + scaled(norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"]),
+                           c.residual_multiplier)
+    out = (x, new_layer_cache, ssm_cache) if c.has_state else (x, new_layer_cache)
+    return out if moe_live is None else (*out, touched)
 
 
 def forward(
@@ -552,7 +762,8 @@ def forward(
     qm_backend: str | None = None,  # quantized-matmul backend (ops/dispatch)
     ssm_cache: Any = None,  # (ssm_state, conv_state) of a model with a mixer
     ssm_rows: Any = None,  # models/ssm.py SsmRows: whose state each row is
-) -> tuple[Array, Any]:
+    moe_live: Array | None = None,  # [B, S] bool: count the experts these tokens touch
+) -> tuple[Array, Any] | tuple[Array, Any, Array]:
     """Run the decoder; returns (logits[B,S,vocab] fp32, new_cache) — or
     (hidden[B,S,D], new_cache) with ``return_hidden``, for callers that
     project only a subset of positions (the seq-sharded long prefill keeps
@@ -571,6 +782,11 @@ def forward(
     the same way: ``ssm_cache`` rides the carry beside ``cache`` and comes
     back as ``new_cache = (cache, ssm_cache)``; without one (the cache-less
     forward) every row starts from zero state and ``new_cache`` is as ever.
+
+    With ``moe_live`` (a model that routes sparsely, ``config.moe_sparse``)
+    a third element comes back: the number of distinct held experts that the
+    live tokens picked, summed over the layers (int32) — the expert weights
+    this step had to read.
     """
     c = config
     if c.has_state and cache is not None and ssm_cache is None:
@@ -596,14 +812,17 @@ def forward(
     stacks = params["layers"]
 
     def one_layer(carry, layer_params, layer_idx, kind):
-        x, cache, ssm = carry
+        x, cache, ssm, touched = carry
         out = _layer(
             x, layer_params, cache, layer_idx,
             positions=positions, config=c, attention=attention,
             qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows, kind=kind,
+            moe_live=moe_live,
         )
+        if moe_live is not None:
+            touched = touched + out[-1]
         # the layer returns its ssm cache only where the model has state
-        return (*out[:2], out[2] if c.has_state else ssm)
+        return (*out[:2], out[2] if c.has_state else ssm, touched)
 
     def scan_body(carry, scanned):
         layer_params, period_idx = scanned
@@ -614,8 +833,10 @@ def forward(
             # by kind, are indexed by) and down the whole depth
             at = {kind: period_idx * pattern.count(kind) + pattern[:j].count(kind),
                   None: period_idx * len(pattern) + j}
+            # the grouped matmul takes the experts' whole stacks (StackedLeaf)
+            whole = ("moe_in", "moe_out") if c.moe_sparse else ()
             layer_params = {
-                name: jax.tree.map(
+                name: StackedLeaf(leaf, at[None]) if name in whole else jax.tree.map(
                     lambda a, i=at[_stack_kind(name)]: lax.dynamic_index_in_dim(
                         a, i, 0, keepdims=False), leaf)
                 for name, leaf in stacks.items() if _stack_kind(name) in at}
@@ -627,17 +848,16 @@ def forward(
         # residuals stay O(one layer) instead of O(n_layers)
         scan_body = jax.checkpoint(scan_body)
 
-    (x, new_cache, ssm_cache), _ = lax.scan(
-        scan_body, (x, cache, ssm_cache),
+    (x, new_cache, ssm_cache, touched), _ = lax.scan(
+        scan_body, (x, cache, ssm_cache, None if moe_live is None else jnp.int32(0)),
         (stacks if len(pattern) == 1 else None, jnp.arange(n_periods)))
     if ssm_cache is not None:
         new_cache = (new_cache, ssm_cache)
 
     x = rms_norm(x, params["norm"], c.norm_eps)
-    if return_hidden:
-        return x, new_cache
-    logits = lm_head(params, x, config=c, qm_backend=qm_backend)
-    return logits, new_cache
+    if not return_hidden:
+        x = lm_head(params, x, config=c, qm_backend=qm_backend)
+    return (x, new_cache) if moe_live is None else (x, new_cache, touched)
 
 
 @jax.named_scope("head")
@@ -658,16 +878,17 @@ def lm_head(params: dict[str, Any], x: Array, *, config: LlamaConfig,
                   config.lm_head_multiplier)
 
 
-def make_causal_attention(backend: str) -> AttentionFn:
+def make_causal_attention(backend: str, scale: float | None = None) -> AttentionFn:
     """Cache-less causal attention over the whole sequence (training, tests,
     one-shot prefill) on an explicitly-resolved backend. Callers that jit
     must resolve the backend OUTSIDE the traced function and key their jit
     cache on it — resolving env state at trace time bakes the first answer
-    into the cache (see ops/dispatch.py)."""
+    into the cache (see ops/dispatch.py). ``scale``: the model's softmax
+    scale (``LlamaConfig.attention_scale``; None = head_dim ** -0.5)."""
     from finchat_tpu.ops.dispatch import causal_attention
 
     def attention(q: Array, k: Array, v: Array, layer_cache: Any, layer_idx: Array) -> tuple[Array, Any]:
-        return causal_attention(q, k, v, backend=backend), layer_cache
+        return causal_attention(q, k, v, backend=backend, scale=scale), layer_cache
 
     return attention
 
@@ -687,7 +908,7 @@ def _forward_full_jit(
 ) -> Array:
     logits, _ = forward(
         params, tokens, positions, config=config,
-        attention=make_causal_attention(attn_backend), cache=None,
+        attention=make_causal_attention(attn_backend, config.attention_scale), cache=None,
         qm_backend=qm_backend,
     )
     return logits

@@ -60,6 +60,7 @@ def paged_attention(
     k_scales: Array | None = None,  # int8 cache: [L, P, SPAD, page_size] fp32
     v_scales: Array | None = None,
     shared: tuple[Array, Array] | None = None,  # decode: ``shared_head``'s
+    scale: float | None = None,  # the softmax scale; None = D ** -0.5
 ) -> Array:
     """Paged-KV attention via the requested (or default) backend. An int8
     cache (engine kv_quant) is detected from the page dtype; the scale
@@ -80,7 +81,7 @@ def paged_attention(
             lay, n_kv, dtype=q.dtype,
         )
         return mha_reference(
-            q, k_all, v_all, causal=True, q_offset=q_offset, kv_len=kv_len
+            q, k_all, v_all, causal=True, q_offset=q_offset, kv_len=kv_len, scale=scale
         )
     interpret = backend == "pallas-interpret"
     if quantized:
@@ -89,13 +90,13 @@ def paged_attention(
         return paged_flash_attention_q8(
             q, k_pages, v_pages, k_scales, v_scales, page_table,
             q_offset, kv_len, layer, shared,
-            page_size=page_size, n_kv=n_kv, interpret=interpret,
+            page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
         )
     from finchat_tpu.ops.paged_attention import paged_flash_attention
 
     return paged_flash_attention(
         q, k_pages, v_pages, page_table, q_offset, kv_len, layer, shared,
-        page_size=page_size, n_kv=n_kv, interpret=interpret,
+        page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
     )
 
 
@@ -115,6 +116,7 @@ def ragged_paged_attention(
     k_scales: Array | None = None,  # int8 cache: [L, P, SPAD, page_size] fp32
     v_scales: Array | None = None,
     kv_gap: Array | None = None,  # [R] — bounded-KV window offset per row
+    scale: float | None = None,  # the softmax scale; None = D ** -0.5
 ) -> Array:
     """Ragged paged-KV attention (ops/ragged_paged_attention.py): prefill
     chunks, decode tokens, and spec verify blocks as rows of ONE packed
@@ -137,7 +139,7 @@ def ragged_paged_attention(
             page_size=page_size, n_kv=n_kv,
             k_scales=k_scales if quantized else None,
             v_scales=v_scales if quantized else None,
-            kv_gap=kv_gap,
+            kv_gap=kv_gap, scale=scale,
         )
     interpret = backend == "pallas-interpret"
     if quantized:
@@ -148,14 +150,14 @@ def ragged_paged_attention(
         return ragged_flash_attention_q8(
             q, k_pages, v_pages, k_scales, v_scales, page_table,
             tok_row, tok_pos, kv_len, layer,
-            page_size=page_size, n_kv=n_kv, interpret=interpret,
+            page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
             kv_gap=kv_gap,
         )
     from finchat_tpu.ops.ragged_paged_attention import ragged_flash_attention
 
     return ragged_flash_attention(
         q, k_pages, v_pages, page_table, tok_row, tok_pos, kv_len, layer,
-        page_size=page_size, n_kv=n_kv, interpret=interpret,
+        page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
         kv_gap=kv_gap,
     )
 
@@ -227,13 +229,15 @@ def quant_matmul(
     )
 
 
-def causal_attention(q: Array, k: Array, v: Array, *, backend: str | None = None) -> Array:
+def causal_attention(q: Array, k: Array, v: Array, *, backend: str | None = None,
+                     scale: float | None = None) -> Array:
     """Full contiguous causal attention (training / one-shot prefill)."""
     backend = backend or attention_backend()
     if backend == "ref":
         from finchat_tpu.ops.refs import mha_reference
 
-        return mha_reference(q, k, v, causal=True)
+        return mha_reference(q, k, v, causal=True, scale=scale)
     from finchat_tpu.ops.flash_attention import flash_attention
 
-    return flash_attention(q, k, v, causal=True, interpret=(backend == "pallas-interpret"))
+    return flash_attention(q, k, v, causal=True, scale=scale,
+                           interpret=(backend == "pallas-interpret"))

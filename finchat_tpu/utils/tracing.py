@@ -134,6 +134,10 @@ ROUND_PHASES = ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
 DEVICE_SCOPES = frozenset({
     "embed", "norm", "attn_qkv", "attn_o", "mlp", "moe_router",
     "moe_experts", "head", "sample",
+    # a model that routes sparsely (models/llama.py moe_mlp): sorting the
+    # (token, pick) pairs by expert and bringing the results back; the shared
+    # expert beside the routed ones
+    "moe_group", "moe_shared",
     # the Mamba-2 mixer beside attention (models/ssm.py)
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_norm", "ssm_out",
     # the gated delta rule of a linear-attention layer (models/gdn.py)

@@ -235,7 +235,7 @@ def test_engine_ragged_step_accepts_matching_drafts(params):
     ref_tokens = []
     act = jnp.zeros((B,), bool).at[0].set(True)
     for _ in range(3):
-        ref_state, toks, _ = decode_step(
+        ref_state, toks, *_ = decode_step(
             eng.params, ref_state, act, zB, oB, kB,
             config=eng.config, page_size=8, attn_backend=eng.attn_backend,
         )

@@ -509,7 +509,7 @@ def _decode_logits_on_both_backends(engine, state, active):
     zeros, ones, zk = jnp.zeros((B,)), jnp.ones((B,)), jnp.zeros((B,), jnp.int32)
 
     def logits(backend):
-        _, _, out = decode_step(
+        _, _, out, _ = decode_step(
             engine.params, jax.tree.map(jnp.copy, state), active, zeros, ones, zk,
             config=engine.config, page_size=engine.page_size,
             attn_backend=backend, return_logits=True)

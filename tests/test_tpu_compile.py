@@ -300,3 +300,106 @@ def test_olmo_hybrid_ragged_round_has_no_loop_over_layers_inside_the_period_scan
     assert [op for depth, op in nest if depth == 0] == ["jit(ragged_mixed_step)/while"], nest
     assert max(depth for depth, _op in nest) == 1, nest
     assert sum(op.endswith("/gdn_scan/while") for _depth, op in nest) == 3, nest
+
+
+# granite-4.0-h-small as perfbench/configs has it (PR 34): 128 mixer heads of
+# 64 with 128 state channels, 32 / 8 attention heads with a softmax scale of
+# 2^-7, 36 held experts of 768 (fused [gate | up]: 1,536) of a router of 72
+def test_ssm_state_step_compiles_for_v5e_at_granites_heads(one_chip):
+    """``ops/ssm_step.py`` at [9, 16, 128, 64, 128]: the same 4 MiB a row as
+    Falcon-H1's [32, 128, 256], another tiling (P 64 on sublanes, Ns 128 on
+    lanes, [r, 64, 128] columns of dt x and y); Mosaic's layout rules and the
+    VMEM limit for it are checked by this compile."""
+    from finchat_tpu.ops.ssm_step import rows_per_block, ssm_state_step
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, H, P, N = 9, 128, 64, 128
+    assert rows_per_block(ROWS, H * P * N * 4) == 2
+    compiled = ssm_state_step.lower(
+        shape((L, ROWS, H, P, N)), shape((ROWS, H, P)), shape((ROWS, H)), shape((H,)),
+        shape((ROWS, 1, N)), shape((ROWS, 1, N)), shape((H,)), shape((1,), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= L * ROWS * H * P * N * 4  # in place
+    assert memory.temp_size_in_bytes < 4 * 1024 * 1024
+
+
+def test_paged_attention_compiles_for_v5e_with_a_scale_of_its_own(one_chip):
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pages = shape((1, POOL, PAGE, KV_HEADS * HEAD_DIM), jnp.bfloat16)
+    text = jax.jit(
+        lambda *args: paged_flash_attention(*args, page_size=PAGE, n_kv=KV_HEADS, scale=2.0 ** -7)
+    ).lower(
+        shape((ROWS, 1, HEADS, HEAD_DIM), jnp.bfloat16), pages, pages,
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32),
+        shape((ROWS,), jnp.int32), shape((1,), jnp.int32),
+    ).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and calls[0].split(" = ")[0].strip().startswith("%paged_flash_attention")
+
+
+def test_the_grouped_matmul_compiles_for_v5e_at_the_top_bucket(one_chip):
+    """``moe_mlp``'s grouped form at the 4,096-token ragged bucket: 40,960
+    (token, pick) pairs over the 36 held stacks; each grouped matmul is ONE
+    operation whose FLOPs are the pairs', not pairs x experts."""
+    from finchat_tpu.models.llama import moe_mlp
+    from perfbench.models import granitemoehybrid
+
+    c = granitemoehybrid.program_config(_config_file("granite-4.0-h-small"))
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    lp = {"router": shape((4096, 72), jnp.float32), "moe_in": shape((36, 4096, 1536)),
+          "moe_out": shape((36, 768, 4096)), "shared_in": shape((4096, 3072)),
+          "shared_out": shape((1536, 4096))}
+    compiled = jax.jit(lambda h, lp: moe_mlp(h, lp, c)).lower(shape((1, 4096, 4096)), lp).compile()
+    text = compiled.as_text()
+    ragged = [line.split(" = ")[0].strip() for line in text.splitlines()
+              if "ragged" in line.lower() and " = " in line
+              and ("custom-call(" in line or "ragged-dot(" in line)]
+    # the two grouped matmuls (they share one pass that lays out the groups)
+    assert len([name for name in ragged if "metadata" not in name]) == 2, ragged
+    assert "/moe_group/" in text and "/moe_shared/" in text and "/moe_experts/" in text
+    pairs = 4096 * 10
+    grouped_flops = 2 * pairs * 4096 * (1536 + 768)
+    shared_flops = 2 * 4096 * 4096 * (3072 + 1536)
+    assert compiled.cost_analysis()["flops"] < 1.5 * (grouped_flops + shared_flops)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_granite_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_chip):
+    """The whole decode step at the cell's size (one period of ten layers, 16
+    slots, the 1,600-page pool of ONE layer, nine layers of [16, 128, 64, 128]
+    state): pool and state donated and updated in place, the state's update
+    ``ops/ssm_step.py``'s kernel in each of the nine mamba layers, attention
+    one custom call, dense dispatch over the 36 held stacks (no grouped
+    matmul at 16 tokens), and the count of experts touched one int32 out."""
+    from perfbench.models import granitemoehybrid
+
+    file = _config_file("granite-4.0-h-small")
+    compiled, state = _compiled_decode_step(one_chip, granitemoehybrid, file)
+    memory = compiled.memory_analysis()
+    state_bytes = 9 * ROWS * 128 * 64 * 128 * 4
+    assert state.ssm_state.shape == (9, ROWS, 128, 64, 128)
+    assert state.k_pages.shape == (1, POOL, PAGE, 8 * 128)
+    assert memory.alias_size_in_bytes >= state_bytes + 2 * POOL * PAGE * 1024 * 2
+    assert memory.temp_size_in_bytes < state_bytes // 4
+    text = compiled.as_text()
+    kernels = [line.split(" = ")[0] for line in text.splitlines()
+               if "/ssm_scan/" in line and " = " in line and "op_name=" in line
+               and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 9 and all("ssm_state_step" in k for k in kernels)
+    attention = [line.split(" = ")[0] for line in text.splitlines()
+                 if "/paged_attention/" in line and " = " in line
+                 and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(attention) == 1 and "paged_flash_attention" in attention[0]
+    assert "ragged" not in text.lower()
+    for scope in ("moe_router", "moe_experts", "moe_shared"):
+        assert f"/{scope}/" in text, scope
+    out_shapes = [x.shape for x in jax.tree.leaves(compiled.out_info)]
+    assert out_shapes.count(()) >= 1  # the count, a scalar beside the tokens
