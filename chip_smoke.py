@@ -437,19 +437,43 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     return errors
 
 
+def _router_picking(h, picks, width: int):
+    """A router ``[D, width]`` float32 under which row ``i`` of ``h`` [T, D]
+    picks exactly ``picks[i]`` (in that order): the least-norm solution of
+    ``h @ router = Z`` with ``Z`` 4 - 0.2 j on the row's j-th pick and -4
+    elsewhere (T <= D; the margins are far above a bf16 matmul's error)."""
+    import numpy as np
+
+    h = np.asarray(h, np.float64)
+    Z = np.full((h.shape[0], width), -4.0)
+    for i, row in enumerate(picks):
+        Z[i, list(row)] = 4.0 - 0.2 * np.arange(len(row))
+    return (h.T @ np.linalg.solve(h @ h.T, Z)).astype(np.float32)
+
+
 def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
-                            timed_calls: int = 10) -> dict[str, float]:
+                            touched: tuple[int, ...] = (30, 36), timed_calls: int = 10,
+                            shrink: dict | None = None) -> dict[str, float]:
     """``moe_mlp`` at the shape of ``granite-h-small-report-saturated`` (36 held
     experts of 768, fused [gate | up], of a router of 72, 10 a token, beside a
-    shared expert of 1,536; hidden 4,096) against the plain reference's loop
-    over the held experts (``perfbench/models/granitemoehybrid.py``), twice:
-    the one-token step's ``rows`` tokens (dense dispatch over the held stacks)
-    and the top ragged bucket's ``tokens`` (the grouped form: 10 pairs a token
-    sorted by expert through ``lax.ragged_dot``). On the chip (``pallas``) also
-    each form's device time from a profiler capture against its bound: the
-    step's against the bytes of the experts it TOUCHED
-    (``moe_step_stream_bytes``, what ``moe_expert_roofline.sat`` divides by),
-    the bucket's against its pairs' FLOPs at 197 TF/s and its bytes."""
+    shared expert of 1,536; hidden 4,096; ``shrink`` replaces keys of the
+    file, for a CPU run) against the plain reference's loop over the held
+    experts (``perfbench/models/granitemoehybrid.py``):
+
+    - the one-token step's ``rows`` tokens under a router built so that the
+      rows pick exactly ``touched`` of the held experts (5 held and 5 absent
+      picks a row), in BOTH of its forms: ``backend``'s (``ops/moe_step.py``'s
+      pass over the touched experts on a kernel backend) and dense dispatch
+      over every held stack (``ref``), with the counts each returns;
+    - the top ragged bucket's ``tokens`` (the grouped form: 10 pairs a token
+      sorted by expert through ``lax.ragged_dot``; 0 = not this leg).
+
+    On the chip (``pallas``) also each form's device time from a profiler
+    capture against its bound: the step's, without the shared expert, against
+    the bytes of the experts it TOUCHED (``moe_step_stream_bytes``, what
+    ``moe_expert_roofline.sat`` divides by), the bucket's against its pairs'
+    FLOPs at 197 TF/s and its bytes."""
+    import dataclasses
     import json
     from pathlib import Path
 
@@ -462,59 +486,91 @@ def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
 
     file = json.loads((Path(__file__).resolve().parent
                        / "perfbench/configs/granite-4.0-h-small.json").read_text())
+    file.update(shrink or {})
     c, s = granite.program_config(file), granite._sizes(file)
     D, E, F, Fs, R = c.dim, c.n_experts, c.hidden_dim, c.moe_shared_dim, c.moe_router_width
+    k = c.top_k_experts
     ks = jax.random.split(jax.random.key(34), 7)
     bf16 = jnp.bfloat16
 
-    def normal(k, shape, fan_in, dtype=bf16):
-        return (jax.random.normal(k, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
+    def normal(key, shape, fan_in, dtype=bf16):
+        return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
 
     lp = {"router": normal(ks[0], (D, R), D, jnp.float32),
           "moe_in": normal(ks[1], (E, D, 2 * F), D), "moe_out": normal(ks[2], (E, F, D), F),
           "shared_in": normal(ks[3], (D, 2 * Fs), D), "shared_out": normal(ks[4], (Fs, D), Fs)}
-    stacked = {name: leaf[None] for name, leaf in lp.items()}
     results = {}
-    for form, n, key in (("dense", rows, ks[5]), ("grouped", tokens, ks[6])):
-        h = jax.random.normal(key, (1, n, D), jnp.float32).astype(bf16)
-        run = jax.jit(lambda h, lp: moe_mlp(h, lp, c, live=jnp.ones(h.shape[:2], bool)))
-        got, touched = run(h, lp)
+
+    def against_reference(label, got, h, lp):
         with jax.default_matmul_precision("highest"):
-            want = granite._experts(h[0].astype(jnp.float32), stacked, 0, s, lambda w: w,
-                                    swap=False)
+            want = granite._experts(h[0].astype(jnp.float32),
+                                    {name: leaf[None] for name, leaf in lp.items()}, 0, s,
+                                    lambda w: w, swap=False)
         got, want = np.asarray(got[0].astype(jnp.float32)), np.asarray(want)
-        require(np.isfinite(got).all(), f"moe_mlp {form}: non-finite output")
+        require(np.isfinite(got).all(), f"moe_mlp {label}: non-finite output")
         rel = float(np.sqrt(np.mean((got - want) ** 2)) / np.std(want))
         # bf16 inputs and one bf16 rounding of every expert's output; a pick
         # that flips at a margin of a bf16 step exchanges near-equal gates
-        require(rel < 0.03, f"moe_mlp {form}: off the reference by {rel:.4f} of its spread")
-        results[f"{form}_rel"] = rel
-        say(f"moe_mlp {form}: ok ({n} tokens, {int(touched)} of {E} held experts touched; "
-            f"rms error {rel:.4f} of the reference's spread)")
-        if backend != "pallas":
-            continue
-        ops = device_ops_us(lambda: run(h, lp)[0], timed_calls)
+        require(rel < 0.03, f"moe_mlp {label}: off the reference by {rel:.4f} of its spread")
+        return rel
+
+    def timed(label, run, bound_us, note):
+        ops = device_ops_us(run, timed_calls)
         call_us = sum(us for _name, us in ops) / timed_calls
-        if form == "dense":
-            period = granite.moe_step_stream_bytes(file, rows=n, experts_touched=float(touched))
-            bound_us = 1e6 * period / len(s["kinds"]) / 819e9
-            note = f"stream bound of the {int(touched)} touched experts"
-        else:
-            held_pairs = float(np.sum(np.asarray(jax.lax.top_k(
-                h[0].astype(jnp.float32) @ lp["router"], c.top_k_experts)[1]) < E))
-            flops = 2 * held_pairs * D * 3 * F + 2 * n * D * 3 * Fs
-            nbytes = 2 * (E * 3 * D * F + 3 * D * Fs + 2 * n * D)
-            bound_us = 1e6 * max(flops / 197e12, nbytes / 819e9)
-            note = (f"bound of {held_pairs:.0f} held pairs ({flops / 1e12:.2f} TFLOP at 197 TF/s, "
-                    f"{nbytes / 1e9:.2f} GB at 819 GB/s)")
-        results.update({f"{form}_us": call_us, f"{form}_bound_us": bound_us})
         by_op = {}
         for name, us in ops:
             by_op[name] = by_op.get(name, 0.0) + us / timed_calls
         top = sorted(by_op.items(), key=lambda kv: -kv[1])[:6]
-        say(f"moe_mlp {form}: {call_us:.1f} us a call; {note} {bound_us:.1f} us: "
+        say(f"moe_mlp {label}: {call_us:.1f} us a call; {note} {bound_us:.1f} us: "
             f"{100 * bound_us / call_us:.1f} % of it; largest operations "
             + ", ".join(f"{name} {us:.0f}" for name, us in top))
+        return call_us
+
+    # the one-token step: the rows' 5 held picks walk round the touched set
+    h = jax.random.normal(ks[5], (1, rows, D), jnp.float32).astype(bf16)
+    live = jnp.ones((1, rows), bool)
+    routed = dataclasses.replace(c, moe_shared_dim=0)  # what is timed: the routed experts alone
+    for t in touched:
+        held = np.sort(np.random.RandomState(t).permutation(E)[:t])
+        picks = [[*(held[(k // 2 * i + j) % t] for j in range(k // 2)),
+                  *(E + (k // 2 * i + j) % (R - E) for j in range(k - k // 2))]
+                 for i in range(rows)]
+        lp_t = {**lp, "router": jnp.asarray(_router_picking(h[0].astype(jnp.float32), picks, R))}
+        for form, form_backend in (("touched", backend), ("dense", "ref")):
+            label = f"{form} at {t} of {E}"
+            run = jax.jit(lambda h, lp, b=form_backend: moe_mlp(h, lp, c, live=live, backend=b))
+            got, counts = run(h, lp_t)
+            counts = tuple(int(n) for n in counts)
+            want_read = t if form == "touched" and form_backend != "ref" else E
+            require(counts == (t, want_read),
+                    f"moe_mlp {label}: counts (touched, read) {counts}, not {(t, want_read)}")
+            rel = results[f"{form}_{t}_rel"] = against_reference(label, got, h, lp_t)
+            say(f"moe_mlp {label}: ok ({rows} tokens, touched / read {counts}; rms error "
+                f"{rel:.4f} of the reference's spread)")
+            if backend == "pallas":
+                period = granite.moe_step_stream_bytes(file, rows=rows, experts_touched=float(t))
+                bound_us = results[f"bound_{t}_us"] = 1e6 * period / len(s["kinds"]) / 819e9
+                alone = jax.jit(lambda h, lp, b=form_backend: moe_mlp(h, lp, routed, backend=b))
+                results[f"{form}_{t}_us"] = timed(
+                    label + ", without the shared expert", lambda: alone(h, lp_t), bound_us,
+                    f"stream bound of the {t} touched experts")
+
+    if not tokens:
+        return results
+    h = jax.random.normal(ks[6], (1, tokens, D), jnp.float32).astype(bf16)
+    run = jax.jit(lambda h, lp: moe_mlp(h, lp, c, backend=backend))
+    rel = results["grouped_rel"] = against_reference("grouped", run(h, lp), h, lp)
+    say(f"moe_mlp grouped: ok ({tokens} tokens; rms error {rel:.4f} of the reference's spread)")
+    if backend == "pallas":
+        held_pairs = float(np.sum(np.asarray(jax.lax.top_k(
+            h[0].astype(jnp.float32) @ lp["router"], k)[1]) < E))
+        flops = 2 * held_pairs * D * 3 * F + 2 * tokens * D * 3 * Fs
+        nbytes = 2 * (E * 3 * D * F + 3 * D * Fs + 2 * tokens * D)
+        results["grouped_bound_us"] = bound_us = 1e6 * max(flops / 197e12, nbytes / 819e9)
+        results["grouped_us"] = timed(
+            "grouped", lambda: run(h, lp), bound_us,
+            f"bound of {held_pairs:.0f} held pairs ({flops / 1e12:.2f} TFLOP at 197 TF/s, "
+            f"{nbytes / 1e9:.2f} GB at 819 GB/s)")
     return results
 
 

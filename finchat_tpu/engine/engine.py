@@ -317,7 +317,7 @@ def prefill_step(
         params, state, tokens, positions,
         config=config, attention=attention,
         ssm_rows=SsmRows(slots, n_valid, backend=attn_backend),
-        return_hidden=True, qm_backend=qm_backend,
+        return_hidden=True, qm_backend=qm_backend, moe_backend=attn_backend,
     )
     last_hidden = jnp.take_along_axis(
         hidden, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1
@@ -588,7 +588,7 @@ def decode_step(
     return_logits: bool = False,
 ) -> tuple[DecodeState, Array, Array | None, Array | None]:
     """One decode step for ALL slots; returns (state, next_tokens [max_seqs],
-    logits?, experts_touched?).
+    logits?, moe_experts?).
 
     Each active slot's ``last_token`` KV is appended at ``context_lens`` and
     the next token sampled from its logits. Inactive slots write to the
@@ -598,10 +598,13 @@ def decode_step(
     (fp32) — the host-side path for grammar-constrained sampling
     (agent/constrained.py), which overrides ``last_tokens`` afterwards.
 
-    A model that routes sparsely (``config.moe_sparse``) also returns, as one
-    int32 beside the tokens, the number of distinct held experts that ACTIVE
-    rows picked, summed over the layers: the expert weights this step had to
-    read (None for every other model: no output, no operation).
+    A model that routes sparsely (``config.moe_sparse``) also returns, as
+    int32 ``[2]`` beside the tokens, both summed over the layers: the number
+    of distinct held experts that ACTIVE rows picked — the expert weights this
+    step had to read — and the number whose weights the step's form DID read
+    (``moe_mlp``: every held one under dense dispatch, the touched ones where
+    ``ops/moe_step.py``'s pass ran). None for every other model: no output,
+    no operation.
     """
     tokens = state.last_tokens[:, None]  # [B, 1]
     positions = state.context_lens[:, None]  # [B, 1] — absolute (rotary)
@@ -616,11 +619,11 @@ def decode_step(
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
     # pass over the layer's state, on `ref` a slice, _step and an update)
-    logits, state, *touched = _forward_cached(
+    logits, state, *experts = _forward_cached(
         params, state, tokens, positions,
         config=config, attention=attention,
         ssm_rows=SsmRows(None, n_valid, backend=attn_backend),
-        qm_backend=qm_backend,
+        qm_backend=qm_backend, moe_backend=attn_backend,
         **({"moe_live": active[:, None]} if config.moe_sparse else {}),
     )
     step_logits = logits[:, 0, :]  # [B, vocab]
@@ -635,7 +638,7 @@ def decode_step(
         rng=rng,
     )
     return (new_state, next_tokens, (step_logits if return_logits else None),
-            touched[0] if touched else None)
+            experts[0] if experts else None)
 
 
 def _ragged_attention_fn(
@@ -795,7 +798,7 @@ def _ragged_round_math(
     hidden, state = _forward_cached(
         params, state, tok_in[None], tok_pos[None],
         config=config, attention=attention, ssm_rows=ssm_rows,
-        return_hidden=True, qm_backend=qm_backend,
+        return_hidden=True, qm_backend=qm_backend, moe_backend=attn_backend,
     )
     h = hidden[0]  # [T, D]
 
@@ -1336,10 +1339,10 @@ class InferenceEngine:
         # the scheduler emits it as the finchat_warmup_compiled_variants
         # gauge — the ISSUE 10 warmup-matrix-collapse instrument
         self.compiled_variants = 0
-        # the last decode step's count of held experts touched, on the device
-        # (a model that routes sparsely; else None): the scheduler fetches it
-        # with the step's tokens
-        self.experts_touched = None
+        # the last decode step's counts of held experts [touched, read], on the
+        # device (a model that routes sparsely; else None): the scheduler
+        # fetches them with the step's tokens
+        self.moe_experts = None
         self.max_pages_per_seq = min(
             engine_cfg.num_pages - 1,
             -(-engine_cfg.max_seq_len // engine_cfg.page_size),
@@ -2035,7 +2038,7 @@ class InferenceEngine:
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_decode_dispatches_total")
-        self.state, next_tokens, logits, self.experts_touched = decode_step(
+        self.state, next_tokens, logits, self.moe_experts = decode_step(
             self.params, self.state, active, temperature, top_p, top_k,
             config=self.config, page_size=self.page_size,
             attn_backend=self.attn_backend, qm_backend=self.qm_backend, return_logits=return_logits,

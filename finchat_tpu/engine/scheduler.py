@@ -280,9 +280,10 @@ class _InFlightStep:
     logits: object | None  # [n_constrained, vocab] fp32 device slice, or None
     members: list[tuple[int, SequenceHandle, int]]
     constrained_slots: list[int]
-    # int32 scalar, device: the held experts the step's live rows touched,
-    # summed over the layers (a model that routes sparsely; else None)
-    experts_touched: object | None = None
+    # int32 [2], device: the held experts the step's live rows touched and
+    # those the step's form read, each summed over the layers (a model that
+    # routes sparsely; else None)
+    moe_experts: object | None = None
 
 
 @dataclass
@@ -614,11 +615,13 @@ class ContinuousBatchingScheduler:
             self.metrics.inc("finchat_ssm_recompute_fallbacks_total", 0.0)
             self.metrics.inc("finchat_ssm_step_fallbacks_total", 0.0)
         # a model that routes sparsely (models/llama.py moe_mlp): its decode
-        # step counts, on the device, the held experts its live rows touched;
-        # booked where the step's tokens are delivered
-        self._round_experts_touched: int | None = None
+        # step counts, on the device, the held experts its live rows touched
+        # and those whose weights its form read; booked where the step's
+        # tokens are delivered
+        self._round_moe_experts: tuple[int, int] | None = None
         if engine.config.moe_sparse:
             self.metrics.inc("finchat_moe_experts_touched_total", 0.0)
+            self.metrics.inc("finchat_moe_experts_read_total", 0.0)
             self.metrics.inc("finchat_moe_layer_steps_total", 0.0)
         # disaggregated serving (serve/disagg.py — ISSUE 17): the fleet
         # attaches its DisaggCoordinator to SERVING-pool schedulers only;
@@ -3543,7 +3546,7 @@ class ContinuousBatchingScheduler:
             tokens=next_tokens, logits=logits,
             members=members,
             constrained_slots=constrained_slots,
-            experts_touched=eng.experts_touched,
+            moe_experts=eng.moe_experts,
         )
 
     def _undelivered(self, inflight) -> dict[int, int]:
@@ -3863,19 +3866,21 @@ class ContinuousBatchingScheduler:
         """Fetch a dispatched step's tokens (in a worker thread, so the event
         loop keeps serving) and deliver them to the sequences that were in
         the batch when it was dispatched."""
-        tokens_host, logits_host, touched = await self._fetch(
+        tokens_host, logits_host, experts = await self._fetch(
             lambda: (
                 np.asarray(step.tokens),
                 np.asarray(step.logits) if step.logits is not None else None,
-                int(step.experts_touched) if step.experts_touched is not None else None,
+                np.asarray(step.moe_experts) if step.moe_experts is not None else None,
             )
         )
         with TRACER.phase("deliver", self._phases):
-            if touched is not None:
+            if experts is not None:
+                touched, read = (int(count) for count in experts)
                 self.metrics.inc("finchat_moe_experts_touched_total", touched)
+                self.metrics.inc("finchat_moe_experts_read_total", read)
                 self.metrics.inc("finchat_moe_layer_steps_total",
                                  self.engine.config.n_layers)
-                self._round_experts_touched = touched
+                self._round_moe_experts = touched, read
             for slot, handle, epoch in step.members:
                 if handle.finished or handle.slot != slot or handle.epoch != epoch:
                     continue  # evicted/cancelled/preempted since dispatch
@@ -3960,11 +3965,11 @@ class ContinuousBatchingScheduler:
         # the round is its base phase, end to end: nothing in it is unclocked
         started, now = base.started, base.ended
         total = now - started
-        touched, self._round_experts_touched = self._round_experts_touched, None
+        experts, self._round_moe_experts = self._round_moe_experts, None
         if TRACER.enabled:
             args = {**phases, "kind": kind, "n": self._dispatch_tally}
-            if touched is not None:  # of the decode step this round delivered
-                args["experts_touched"] = touched
+            if experts is not None:  # of the decode step this round delivered
+                args["experts_touched"], args["experts_read"] = experts
             TRACER.event("round", ts=started, dur=total, track=self._trace_track, args=args)
         self.metrics.inc("finchat_rounds_total")
         for phase, seconds in phases.items():

@@ -28,6 +28,7 @@ from jax import Array, lax
 from finchat_tpu.models import gdn
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
 from finchat_tpu.models.ssm import mixer, scaled
+from finchat_tpu.ops import moe_step
 
 # attention callback signature:
 #   fn(q[B,S,H,D], k[B,S,Hkv,D], v[B,S,Hkv,D], layer_cache, layer_idx) ->
@@ -40,15 +41,21 @@ AttentionFn = Callable[[Array, Array, Array, Any, Array], tuple[Array, Any]]
 # (models/ssm.py) over its own state by slot. Each is followed by the MLP
 FULL, LINEAR, MAMBA = "full_attention", "linear_attention", "mamba"
 
-# moe_mlp's one rule (see there). Dense dispatch computes every held expert
-# over every token: router width / picks a token times the FLOPs the picks
-# need. Up to this factor it is taken (static shapes, no sort, no gather);
-# past it a model "routes sparsely" (``LlamaConfig.moe_sparse``) ...
+# moe_mlp's one rule among its THREE forms (see there; ``_moe_form``). Dense
+# dispatch computes every held expert over every token: router width / picks
+# a token times the FLOPs the picks need. Up to this factor it is taken
+# (static shapes, no sort, no gather; Mixtral's 8 / 2: a step touches every
+# expert anyway); past it a model "routes sparsely"
+# (``LlamaConfig.moe_sparse``) ...
 MOE_DENSE_WASTE_MAX = 4
 # ... and its calls of more than this many tokens take the grouped form. Up to
-# it a dense pass over the held stacks is bound by the weights' bytes, which it
-# reads once, and not by its FLOPs (a token is one FLOP a weight byte; a v5e
-# has 240 of them a byte)
+# it a pass over the held stacks is bound by the weights' bytes and not by its
+# FLOPs (a token is one FLOP a weight byte; a v5e has 240 of them a byte): the
+# touched pass (ops/moe_step.py) reads the experts the call touched, once, and
+# dense dispatch — its reference, and the form where the pass does not apply —
+# all held ones. On the chip the pass is never the slower of the two: a layer
+# of Granite's at 16 / 64 / 128 tokens with all 36 touched 906 / 909 / 914 us
+# against 910 / 912 / 918 (PERF.md section 6, PR 35), so no second threshold
 MOE_DENSE_TOKENS_MAX = 128
 
 
@@ -182,8 +189,9 @@ class LlamaConfig:
         """Many small experts: dense dispatch over the held stacks would
         compute more than ``MOE_DENSE_WASTE_MAX`` times what the picks need,
         and a step touches only some of them — ``moe_mlp`` groups the tokens
-        of a large call by expert, and ``decode_step`` counts the held
-        experts a step touched."""
+        of a large call by expert and reads only the touched experts in a
+        small one, and ``decode_step`` counts the held experts a step
+        touched and read."""
         return self.moe_router_width > MOE_DENSE_WASTE_MAX * self.top_k_experts
 
     @property
@@ -480,8 +488,8 @@ class StackedLeaf(NamedTuple):
 
 
 def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
-            qm_backend: str | None = None, live: Array | None = None
-            ) -> Array | tuple[Array, Array]:
+            qm_backend: str | None = None, live: Array | None = None,
+            backend: str = "ref") -> Array | tuple[Array, Array]:
     """Top-k routed SwiGLU experts: the router scores ``moe_router_width``
     experts in float32, the ``top_k_experts`` largest are a token's picks, and
     their gates are the softmax over the picked logits alone.
@@ -492,18 +500,29 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
     so the shares of the chips that split a layer sum to the whole layer; a
     shared expert (``moe_shared_dim``) is added to every token, unweighted.
 
-    Which form a call takes is ONE rule on static shapes (``LlamaConfig.
-    moe_sparse``, ``MOE_DENSE_WASTE_MAX``, ``MOE_DENSE_TOKENS_MAX``):
+    Which of THREE forms a call takes is ONE rule (``_moe_form``) on static
+    shapes and leaf types (``LlamaConfig.moe_sparse``, ``MOE_DENSE_WASTE_MAX``,
+    ``MOE_DENSE_TOKENS_MAX``), never an option or a model's name:
 
     - dense dispatch — every held expert computes over all tokens with its
       gate zeroed where not routed; expert weights carry a leading E axis
       that shards over the mesh's ``expert`` axis (parallel/sharding.py) and
       XLA turns the expert-sum into a psum over the EP shards. Static shapes,
-      no token dropping; FLOPs scale with E rather than with the picks. Taken
-      where that waste is at most ``MOE_DENSE_WASTE_MAX`` x (Mixtral's 8 / 2),
-      and by a model that routes sparsely for a call of at most
-      ``MOE_DENSE_TOKENS_MAX`` tokens (the one-token step: bound by the
-      weights' bytes, which it reads once, all held experts of them);
+      no token dropping; FLOPs scale with E rather than with the picks, and
+      every held expert's weights are read. Taken where that waste is at most
+      ``MOE_DENSE_WASTE_MAX`` x (Mixtral's 8 / 2: its steps touch every
+      expert), and by a model that routes sparsely for a call of at most
+      ``MOE_DENSE_TOKENS_MAX`` tokens where the touched pass does not apply
+      (the ``ref`` backend: this form is that kernel's reference; quantized
+      stacks; separate gate / up / down leaves, the only ones an ``expert``
+      mesh axis shards);
+    - touched — the same sum with the all-zero terms not computed: ONE Pallas
+      pass over the held stacks that brings in only the experts that the
+      call's (live) tokens picked (``ops/moe_step.py``; bound by the weights'
+      bytes, of which it reads the touched experts' alone). A model that
+      routes sparsely, a call of at most ``MOE_DENSE_TOKENS_MAX`` tokens on a
+      kernel ``backend`` (``pallas`` / ``pallas-interpret``: the engine's,
+      resolved once, as ``SsmRows.backend`` is), unquantized fused stacks;
     - grouped — the (token, pick) pairs sorted by expert, pairs on absent
       experts behind the last group and not computed, one grouped (ragged)
       matmul over the held stacks each way (``lax.ragged_dot``, static
@@ -511,10 +530,14 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
       routes sparsely, for more tokens than that.
 
     ``live`` [B, S] bool (a model that routes sparsely, the decode step): also
-    returns the number of distinct held experts that live tokens picked — the
-    expert weights this layer's step had to read."""
+    returns int32 ``[2]``: the number of distinct held experts that live
+    tokens picked — the expert weights this layer's step had to read — and
+    the number whose weights the call's form DID read (the touched ones in
+    the touched pass, else every held one: the other forms' operands are the
+    whole stacks). Without ``live`` every token counts as live."""
     c = config
     E, k = c.n_experts, c.top_k_experts
+    form = _moe_form(c, h.shape[0] * h.shape[1], layer_params, backend)
     with jax.named_scope("moe_router"):
         # router in fp32 (routing decisions are precision-sensitive; the router
         # leaf itself is kept fp32 by init_params / the checkpoint loader)
@@ -525,14 +548,18 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
         # (Mixtral renormalization), scattered back to expert positions
         top_vals, top_idx = jax.lax.top_k(r, k)  # [B,S,k]
         w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
-        grouped = c.moe_sparse and h.shape[0] * h.shape[1] > MOE_DENSE_TOKENS_MAX
-        if not grouped or live is not None:
+        if form != "grouped" or live is not None:
             onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]; absent (>= E): zeros
-        if not grouped:
+        if form != "grouped":
             gates = jnp.einsum("bske,bsk->bse", onehot, w).astype(h.dtype)  # [B,S,E]
+        if live is not None or form == "touched":
+            on = onehot > 0 if live is None else (onehot > 0) & live[:, :, None, None]
+            picked = jnp.any(on, axis=(0, 1, 2))
         if live is not None:
-            picked = jnp.any((onehot > 0) & live[:, :, None, None], axis=(0, 1, 2))
             touched = jnp.sum(picked.astype(jnp.int32))
+            read = touched if form == "touched" else jnp.int32(E)
+        if form == "touched":
+            ids, n, gate_cols = moe_step.plan(picked, gates.reshape(-1, E))
 
     def expert_mm(spec: str, x: Array, w: Array | QTensor | Q4Tensor) -> Array:
         # int8/int4 serving: the stacked-expert einsums keep INLINE dequant
@@ -551,8 +578,16 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
         gate, up = jnp.split(u, 2, axis=-1)
         return jax.nn.silu(gate.astype(jnp.float32)).astype(up.dtype) * up
 
-    if grouped:
+    if form == "grouped":
         out = _moe_grouped(h, top_idx, w, layer_params, c, glu)
+    elif form == "touched":
+        with jax.named_scope("moe_experts"):
+            # the stacks as they lie, with the layer's index: no slice is cut
+            w_in, w_out = (leaf if isinstance(leaf, StackedLeaf) else StackedLeaf(leaf[None], 0)
+                           for leaf in (layer_params["moe_in"], layer_params["moe_out"]))
+            out = moe_step.moe_experts_step(
+                h.reshape(-1, h.shape[-1]), gate_cols, ids, n, w_in.stack, w_out.stack,
+                w_in.index, interpret=backend == "pallas-interpret").reshape(h.shape)
     else:
         with jax.named_scope("moe_experts"):
             def held(name: str) -> Array:  # the layer's own slice fuses into the dot
@@ -572,7 +607,24 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
         with jax.named_scope("moe_shared"):
             out = out + dense(glu(dense(h, layer_params["shared_in"], qm_backend=qm_backend)),
                               layer_params["shared_out"], qm_backend=qm_backend)
-    return out if live is None else (out, touched)
+    return out if live is None else (out, jnp.stack([touched, read]))
+
+
+def _moe_form(config: LlamaConfig, tokens: int, layer_params: dict[str, Any],
+              backend: str) -> str:
+    """``moe_mlp``'s one rule: ``dense``, ``touched`` or ``grouped`` for a
+    call of ``tokens`` tokens, from static shapes, the expert leaves' types
+    and the kernel backend the caller resolved."""
+    if not config.moe_sparse:
+        return "dense"
+    if tokens > MOE_DENSE_TOKENS_MAX:
+        return "grouped"
+    if backend == "ref" or not config.moe_fused_glu:
+        return "dense"
+    stacks = [leaf.stack if isinstance(leaf, StackedLeaf) else leaf
+              for leaf in (layer_params["moe_in"], layer_params["moe_out"])]
+    quantized = any(isinstance(stack, (QTensor, Q4Tensor)) for stack in stacks)
+    return "dense" if quantized else "touched"
 
 
 def _moe_grouped(h: Array, top_idx: Array, w: Array, layer_params: dict[str, Array],
@@ -631,6 +683,7 @@ def _layer(
     ssm_rows: Any = None,
     kind: str = FULL,
     moe_live: Array | None = None,
+    moe_backend: str = "ref",
 ) -> tuple[Array, ...]:
     """One decoder layer. Under GSPMD (the usual path) ``tp_axis`` is
     None — the compiler partitions from the param shardings. Under an
@@ -653,7 +706,8 @@ def _layer(
     kind, which is how the caches are stacked; a MAMBA layer runs the Mamba-2
     mixer alone there. With ``config.norm_after`` the two norms stand on the
     sub-blocks' outputs instead of their inputs. With ``moe_live`` (see
-    ``moe_mlp``) the count of held experts touched is the last element."""
+    ``moe_mlp``) the counts of held experts touched and read are the last
+    element; ``moe_backend`` is ``moe_mlp``'s ``backend``."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
@@ -722,9 +776,10 @@ def _layer(
     h = norm_in(x, layer_params["ln_mlp"])
     if c.n_experts:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
-        moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend, live=moe_live)
+        moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend, live=moe_live,
+                          backend=moe_backend)
         if moe_live is not None:
-            moe_out, touched = moe_out
+            moe_out, experts = moe_out
         with jax.named_scope("moe_experts"):
             # the residual add fuses into the down matmul
             x = x + scaled(norm_out(moe_out, layer_params["ln_mlp"]), c.residual_multiplier)
@@ -746,7 +801,7 @@ def _layer(
             x = x + scaled(norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"]),
                            c.residual_multiplier)
     out = (x, new_layer_cache, ssm_cache) if c.has_state else (x, new_layer_cache)
-    return out if moe_live is None else (*out, touched)
+    return out if moe_live is None else (*out, experts)
 
 
 def forward(
@@ -763,6 +818,7 @@ def forward(
     ssm_cache: Any = None,  # (ssm_state, conv_state) of a model with a mixer
     ssm_rows: Any = None,  # models/ssm.py SsmRows: whose state each row is
     moe_live: Array | None = None,  # [B, S] bool: count the experts these tokens touch
+    moe_backend: str = "ref",  # the kernel backend of moe_mlp's touched pass
 ) -> tuple[Array, Any] | tuple[Array, Any, Array]:
     """Run the decoder; returns (logits[B,S,vocab] fp32, new_cache) — or
     (hidden[B,S,D], new_cache) with ``return_hidden``, for callers that
@@ -784,9 +840,10 @@ def forward(
     forward) every row starts from zero state and ``new_cache`` is as ever.
 
     With ``moe_live`` (a model that routes sparsely, ``config.moe_sparse``)
-    a third element comes back: the number of distinct held experts that the
-    live tokens picked, summed over the layers (int32) — the expert weights
-    this step had to read.
+    a third element comes back, int32 ``[2]``, both summed over the layers:
+    the number of distinct held experts that the live tokens picked — the
+    expert weights this step had to read — and the number whose weights the
+    form that ``moe_mlp`` took did read.
     """
     c = config
     if c.has_state and cache is not None and ssm_cache is None:
@@ -812,17 +869,17 @@ def forward(
     stacks = params["layers"]
 
     def one_layer(carry, layer_params, layer_idx, kind):
-        x, cache, ssm, touched = carry
+        x, cache, ssm, experts = carry
         out = _layer(
             x, layer_params, cache, layer_idx,
             positions=positions, config=c, attention=attention,
             qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows, kind=kind,
-            moe_live=moe_live,
+            moe_live=moe_live, moe_backend=moe_backend,
         )
         if moe_live is not None:
-            touched = touched + out[-1]
+            experts = experts + out[-1]
         # the layer returns its ssm cache only where the model has state
-        return (*out[:2], out[2] if c.has_state else ssm, touched)
+        return (*out[:2], out[2] if c.has_state else ssm, experts)
 
     def scan_body(carry, scanned):
         layer_params, period_idx = scanned
@@ -833,7 +890,8 @@ def forward(
             # by kind, are indexed by) and down the whole depth
             at = {kind: period_idx * pattern.count(kind) + pattern[:j].count(kind),
                   None: period_idx * len(pattern) + j}
-            # the grouped matmul takes the experts' whole stacks (StackedLeaf)
+            # the grouped matmul and the touched pass take the experts' whole
+            # stacks (StackedLeaf)
             whole = ("moe_in", "moe_out") if c.moe_sparse else ()
             layer_params = {
                 name: StackedLeaf(leaf, at[None]) if name in whole else jax.tree.map(
@@ -848,8 +906,8 @@ def forward(
         # residuals stay O(one layer) instead of O(n_layers)
         scan_body = jax.checkpoint(scan_body)
 
-    (x, new_cache, ssm_cache, touched), _ = lax.scan(
-        scan_body, (x, cache, ssm_cache, None if moe_live is None else jnp.int32(0)),
+    (x, new_cache, ssm_cache, experts), _ = lax.scan(
+        scan_body, (x, cache, ssm_cache, None if moe_live is None else jnp.zeros((2,), jnp.int32)),
         (stacks if len(pattern) == 1 else None, jnp.arange(n_periods)))
     if ssm_cache is not None:
         new_cache = (new_cache, ssm_cache)
@@ -857,7 +915,7 @@ def forward(
     x = rms_norm(x, params["norm"], c.norm_eps)
     if not return_hidden:
         x = lm_head(params, x, config=c, qm_backend=qm_backend)
-    return (x, new_cache) if moe_live is None else (x, new_cache, touched)
+    return (x, new_cache) if moe_live is None else (x, new_cache, experts)
 
 
 @jax.named_scope("head")

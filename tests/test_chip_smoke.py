@@ -61,6 +61,23 @@ def test_ssm_step_at_cell_shape_interpret():
     assert set(errors) == {"y", "state"} and errors["y"] < 1e-4
 
 
+def test_moe_at_cell_shape_interpret():
+    """The expert layer's check at a small size (values and counts only: a
+    time comes from the chip): 6 held experts of a router of 12, 2 a token,
+    a router built so that 8 rows touch exactly 3 and exactly 6 of them —
+    the touched pass (interpreted) and dense dispatch each against the
+    reference, with what each read — and the grouped form at 160 tokens."""
+    results = chip_smoke.check_moe_at_cell_shape(
+        "pallas-interpret", rows=8, tokens=160, touched=(3, 6),
+        shrink={"hidden_size": 128, "intermediate_size": 128, "shared_intermediate_size": 64,
+                "num_local_experts": 6, "num_experts_per_tok": 2,
+                "reduced": {"num_local_experts": {"from": 12, "to": 6, "why": "a test's size"}},
+                "mamba_n_heads": 4, "mamba_d_head": 64})
+    assert set(results) == {"touched_3_rel", "dense_3_rel", "touched_6_rel", "dense_6_rel",
+                            "grouped_rel"}
+    assert max(results.values()) < 0.01
+
+
 def test_gdn_step_at_cell_shape_values():
     """The delta rule's one-token update at a small size against the
     recurrence as written (values only: a time comes from the chip): layer 1

@@ -86,10 +86,10 @@ def _forward(tokens, config=CONFIG, params=PARAMS):
                                    config=config)[0])
 
 
-def _engine(**options) -> InferenceEngine:
+def _engine(attn_backend="ref", **options) -> InferenceEngine:
     cfg = EngineConfig(max_seqs=SLOTS, page_size=PAGE, num_pages=64, max_seq_len=256,
                        prefill_chunk=CHUNK, **options)
-    return InferenceEngine(CONFIG, PARAMS, cfg, attn_backend="ref")
+    return InferenceEngine(CONFIG, PARAMS, cfg, attn_backend=attn_backend)
 
 
 def _decode(engine, slot_tokens: dict[int, int]) -> np.ndarray:
@@ -212,16 +212,17 @@ def test_the_rule_that_picks_the_form_is_on_static_shapes():
 def test_the_experts_touched_count_equals_a_count_by_hand_and_leaves_inert_rows_out():
     lp, h = _one_layer()
     live = np.array([[True, False, True, True, False], [False] * 5])
-    out, touched = moe_mlp(h, lp, CONFIG, live=jnp.asarray(live))
+    out, (touched, read) = moe_mlp(h, lp, CONFIG, live=jnp.asarray(live))
+    assert int(read) == 6  # dense dispatch (the `ref` backend) reads every held expert
     np.testing.assert_allclose(np.asarray(out), np.asarray(moe_mlp(h, lp, CONFIG)), atol=0)
     r = np.asarray(h) @ np.asarray(lp["router"])
     picks = np.argsort(-r, axis=-1)[..., :2]
     by_hand = {int(e) for e in picks[live].ravel() if e < 6}  # held: [0, 6)
     assert int(touched) == len(by_hand) and 0 < len(by_hand) <= 6
     everyone = {int(e) for e in picks.ravel() if e < 6}
-    assert int(moe_mlp(h, lp, CONFIG, live=jnp.ones((2, 5), bool))[1]) == len(everyone) \
+    assert int(moe_mlp(h, lp, CONFIG, live=jnp.ones((2, 5), bool))[1][0]) == len(everyone) \
         > len(by_hand)
-    assert int(moe_mlp(h, lp, CONFIG, live=jnp.zeros((2, 5), bool))[1]) == 0
+    assert int(moe_mlp(h, lp, CONFIG, live=jnp.zeros((2, 5), bool))[1][0]) == 0
 
 
 # --- FORWARD ---------------------------------------------------------------------
@@ -529,7 +530,9 @@ def test_the_decode_step_counts_the_held_experts_of_live_rows_over_the_layers():
         for slot in range(SLOTS):
             engine.set_last_token(slot, others)
         _decode(engine, slot_tokens)
-        return int(engine.experts_touched)
+        touched, read = (int(count) for count in engine.moe_experts)
+        assert read == 10 * 6  # the `ref` backend: dense dispatch over the 6 held, ten layers
+        return touched
 
     one = count({2: 5})
     assert 0 <= one <= 10 * 2  # ten layers, two picks a token, half of them held on average
@@ -540,7 +543,7 @@ def test_the_decode_step_counts_the_held_experts_of_live_rows_over_the_layers():
                             jax.random.key(0)), EngineConfig(max_seqs=2, page_size=8, num_pages=8,
                             max_seq_len=64, prefill_chunk=8), attn_backend="ref")
     dense.decode(jnp.zeros((2,), bool), jnp.zeros((2,)), jnp.ones((2,)), jnp.zeros((2,), jnp.int32))
-    assert dense.experts_touched is None  # emitted only where the model routes sparsely
+    assert dense.moe_experts is None  # emitted only where the model routes sparsely
 
 
 def test_the_scheduler_books_the_count_on_deliver_and_on_the_rounds_event():
@@ -562,6 +565,45 @@ def test_the_scheduler_books_the_count_on_deliver_and_on_the_rounds_event():
     assert 10 * len(noted) == steps and sum(noted) == touched
     TRACER.configure(enabled=False)
     TRACER.clear()
+
+
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_the_scheduler_books_the_experts_a_steps_form_read_beside_those_touched(backend):
+    """``finchat_moe_experts_read_total``: through the touched pass (a kernel
+    backend) a step read what it touched; through dense dispatch (``ref``)
+    every held expert, 6 a layer a step. The `round` event carries both."""
+    TRACER.configure(enabled=True)
+    TRACER.clear()
+    sched = _scheduler(attn_backend=backend)
+    names = ("finchat_moe_experts_touched_total", "finchat_moe_experts_read_total",
+             "finchat_moe_layer_steps_total")
+    before = {name: METRICS.get(name) for name in names}
+    _run(sched, _tokens(15, seed=4), n_new=4)
+    touched, read, steps = (METRICS.get(name) - before[name] for name in names)
+    assert steps > 0 and 0 < touched <= 2 * steps
+    assert read == (touched if backend == "pallas-interpret" else 6 * steps)
+    noted = [(args["experts_touched"], args["experts_read"])
+             for _ts, _tid, name, _dur, _track, args in TRACER.snapshot()
+             if name == "round" and "experts_read" in (args or {})]
+    assert 10 * len(noted) == steps
+    assert (sum(t for t, _r in noted), sum(r for _t, r in noted)) == (touched, read)
+    TRACER.configure(enabled=False)
+    TRACER.clear()
+
+
+def test_the_read_counter_is_absent_for_a_model_that_does_not_route_sparsely():
+    dense = llama.PRESETS["moe-tiny"]
+    engine = InferenceEngine(dense, init_params(dense, jax.random.key(0)),
+                             EngineConfig(max_seqs=2, page_size=8, num_pages=8, max_seq_len=64,
+                                          prefill_chunk=8), attn_backend="ref")
+    view = METRICS.labeled(replica="not-sparse")
+    ContinuousBatchingScheduler(engine, eos_id=-1, metrics=view)
+    sparse = METRICS.labeled(replica="sparse")
+    ContinuousBatchingScheduler(_engine(), eos_id=-1, metrics=sparse)
+    rendered = METRICS.render_prometheus()
+    for name in ("finchat_moe_experts_read_total", "finchat_moe_experts_touched_total"):
+        assert f'{name}{{replica="sparse"}} 0' in rendered
+        assert f'{name}{{replica="not-sparse"}}' not in rendered
 
 
 # --- REFUSED ---------------------------------------------------------------------

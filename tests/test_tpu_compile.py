@@ -372,13 +372,39 @@ def test_the_grouped_matmul_compiles_for_v5e_at_the_top_bucket(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
 
 
+@pytest.mark.parametrize("tokens", [16, 64, 128])
+def test_the_touched_expert_pass_compiles_for_v5e_at_granites_stacks(one_chip, tokens):
+    """``ops/moe_step.py`` over the whole stacks [10, 36, 4096, 1536] and
+    [10, 36, 768, 4096] in bf16, three tiles of 256 columns an expert (each of
+    a step's three blocks 2 MiB, double-buffered under the VMEM limit it
+    asks for): one custom call, nothing copied and no temporary beside it."""
+    from finchat_tpu.ops.moe_step import moe_experts_step, width_tile
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, E, D, F = 10, 36, 4096, 768
+    assert width_tile(F) == 256
+    compiled = moe_experts_step.lower(
+        shape((tokens, D)), shape((E, tokens, 1), jnp.float32), shape((E,), jnp.int32),
+        shape((1,), jnp.int32), shape((L, E, D, 2 * F)), shape((L, E, F, D)),
+        shape((1,), jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "%moe_experts_step" in calls[0].split(" = ")[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
 def test_granite_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_chip):
     """The whole decode step at the cell's size (one period of ten layers, 16
     slots, the 1,600-page pool of ONE layer, nine layers of [16, 128, 64, 128]
     state): pool and state donated and updated in place, the state's update
     ``ops/ssm_step.py``'s kernel in each of the nine mamba layers, attention
-    one custom call, dense dispatch over the 36 held stacks (no grouped
-    matmul at 16 tokens), and the count of experts touched one int32 out."""
+    one custom call, the routed experts ``ops/moe_step.py``'s pass in each of
+    the ten layers (one custom call under ``moe_experts`` that takes the
+    WHOLE stacks: no layer's slice is copied, no ``[16, 36, 1536]``
+    intermediate; no grouped matmul at 16 tokens), and the counts of experts
+    touched and read two int32 out."""
     from perfbench.models import granitemoehybrid
 
     file = _config_file("granite-4.0-h-small")
@@ -401,5 +427,10 @@ def test_granite_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_c
     assert "ragged" not in text.lower()
     for scope in ("moe_router", "moe_experts", "moe_shared"):
         assert f"/{scope}/" in text, scope
+    experts = [line.split(" = ")[0] for line in text.splitlines()
+               if "/moe_experts/" in line and " = " in line
+               and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(experts) == 10 and all("moe_experts_step" in k for k in experts)
+    assert "bf16[16,36,1536]" not in text and "bf16[36,4096,1536]" not in text
     out_shapes = [x.shape for x in jax.tree.leaves(compiled.out_info)]
-    assert out_shapes.count(()) >= 1  # the count, a scalar beside the tokens
+    assert out_shapes.count((2,)) == 1  # the counts (touched, read) beside the tokens
