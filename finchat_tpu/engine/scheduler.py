@@ -460,6 +460,9 @@ class ContinuousBatchingScheduler:
         self._base_phase = None
         self._recent_rounds: deque[float] = deque(maxlen=256)
         self._slow_round_logged = float("-inf")
+        # the tracer's running totals as the last round saw them
+        self._seen_compile_s = TRACER.serving_compile_s
+        self._seen_frozen_s = TRACER.frozen_s
         self._coexist_round_mark = 0
         if self.freerun_rounds > 1:
             # pre-seed the cap reasons (the _use_mixed demotion-counter
@@ -3135,7 +3138,6 @@ class ContinuousBatchingScheduler:
                 TRACER.event("freerun_epoch_break", track=self._trace_track,
                              args={"replica": self.replica_id,
                                    "rounds": ring.rounds})
-            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _use_mixed(self) -> bool:
         """Can this iteration run ONE packed ragged dispatch instead of a
@@ -3451,7 +3453,6 @@ class ContinuousBatchingScheduler:
                         break
             if wasted:
                 self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
-            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _deliver(self, handle: SequenceHandle, token_id: int) -> None:
         now = time.perf_counter()
@@ -3688,7 +3689,6 @@ class ContinuousBatchingScheduler:
                 self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
         if blk.step is not None:
             await self._consume_step(blk.step)
-        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     @staticmethod
     def _spec_eligible(handle: SequenceHandle) -> bool:
@@ -3860,7 +3860,6 @@ class ContinuousBatchingScheduler:
             if accepted_total:
                 self.metrics.inc("finchat_spec_tokens_accepted_total", accepted_total)
             self._spec_note_step(accepted=accepted_total)
-            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     async def _consume_step(self, step: _InFlightStep) -> None:
         """Fetch a dispatched step's tokens (in a worker thread, so the event
@@ -3891,7 +3890,6 @@ class ContinuousBatchingScheduler:
                     self._deliver(handle, token)
                 else:
                     self._deliver(handle, int(tokens_host[slot]))
-            self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
 
     def _pending_constrained(self, inflight) -> set[int]:
         """Constrained slots whose host-side pick lands only when
@@ -3942,6 +3940,12 @@ class ContinuousBatchingScheduler:
         took longer than both SLOW_ROUND_FLOOR_S and SLOW_ROUND_MEDIANS
         times the median of the last 256 rounds — what a stalled run
         leaves on standard error. Host clocks only (finchat-lint R2).
+        What the process lost outside a device step while the round ran
+        rides with it (ISSUE 38): the tracer's running totals of seconds
+        spent compiling or loading a program while serving and of seconds
+        the process stood frozen, each less what the round before saw —
+        ``compile_s`` / ``frozen_s`` in the event's args where non-zero,
+        named in the WARNING with the last program compiled.
 
         Whatever the loop does outside a named phase is ``stage``: the
         iteration runs inside one base ``stage`` phase that the others
@@ -3966,12 +3970,20 @@ class ContinuousBatchingScheduler:
         started, now = base.started, base.ended
         total = now - started
         experts, self._round_moe_experts = self._round_moe_experts, None
+        compiled, frozen = TRACER.serving_compile_s, TRACER.frozen_s
+        compile_s, frozen_s = compiled - self._seen_compile_s, frozen - self._seen_frozen_s
+        self._seen_compile_s, self._seen_frozen_s = compiled, frozen
         if TRACER.enabled:
             args = {**phases, "kind": kind, "n": self._dispatch_tally}
             if experts is not None:  # of the decode step this round delivered
                 args["experts_touched"], args["experts_read"] = experts
+            if compile_s:
+                args["compile_s"] = compile_s
+            if frozen_s:
+                args["frozen_s"] = frozen_s
             TRACER.event("round", ts=started, dur=total, track=self._trace_track, args=args)
         self.metrics.inc("finchat_rounds_total")
+        self.metrics.set_gauge("finchat_batch_occupancy", len(self.decoding))
         for phase, seconds in phases.items():
             if seconds:
                 self.metrics.inc("finchat_round_phase_seconds_total", seconds,
@@ -3984,10 +3996,13 @@ class ContinuousBatchingScheduler:
                 self._slow_round_logged = now
                 logger.warning(
                     "slow scheduler round: %.3f s (median of the last %d: "
-                    "%.4f s), %s; last dispatch %s, %d decoding rows",
+                    "%.4f s), %s; last dispatch %s, %d decoding rows; "
+                    "compiling or loading %.3f s (last program %s), "
+                    "process frozen %.3f s",
                     total, len(self._recent_rounds), median,
                     " ".join(f"{k}={v:.3f}" for k, v in phases.items()),
                     kind, len(self.decoding),
+                    compile_s, TRACER.last_compiled if compile_s else "none", frozen_s,
                 )
         self._recent_rounds.append(total)
 

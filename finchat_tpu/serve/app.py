@@ -34,6 +34,7 @@ from __future__ import annotations
 import asyncio
 import gc
 import json
+import threading
 import time
 import uuid
 from pathlib import Path
@@ -384,12 +385,59 @@ def make_engine_replica(
                                  quant=cfg.model.quant,
                                  quant_group=cfg.model.quant_group)
     if cfg.engine.warmup_on_start:
-        TRACER.startup("warmup", engine.warmup())
+        with TRACER.startup_phase("warmup"):
+            _on_a_fresh_stack(engine.warmup)
     scheduler = ContinuousBatchingScheduler(
         engine, eos_id=tokenizer.eos_id, metrics=metrics,
         replica_id=replica_id, fabric=fabric,
     )
     return EngineGenerator(scheduler, tokenizer), scheduler
+
+
+def _roomy_caller(slots: int = 66_000):
+    """``call(fn)`` → ``fn()``, from a function with ``slots`` local names
+    it never binds: its frame alone is over half a MiB, so CPython gives it a
+    frame chunk of 1 MiB, and the frames of whatever ``fn`` calls — a couple
+    of thousand deep — sit in that ONE chunk behind it."""
+    names = " = ".join(f"v{i}" for i in range(slots))
+    source = f"def call(fn):\n    if fn is None:\n        {names} = None\n    return fn()\n"
+    scope: dict = {}
+    exec(compile(source, "<one frame chunk>", "exec"), scope)  # noqa: S102 -- generated from a number, above
+    return scope["call"]
+
+
+def _on_a_fresh_stack(fn):
+    """Run ``fn`` to its end on a thread of its own, its frames in one
+    chunk; return what it returned, raise what it raised.
+
+    Warm-up is tracing and lowering in Python (PERF.md §6, PR 38). CPython
+    3.11 / 3.12 keeps a thread's frames in 16 KiB chunks and frees a chunk as
+    its first frame returns, so a call made where a chunk happens to end
+    allocates and frees one each time: about 100 x the cost of the call
+    (``benchmarks/frame_chunk_cliff.py``). Where a chunk ends is set by every
+    frame BELOW — the entry script, asyncio, ``build_app``'s locals — and
+    when it fell among JAX's per-equation lowering calls Granite's warm
+    warm-up took 162-171 s instead of 127-131 (one more ``with`` in this file
+    did it, and so did starting the parent through ``python3 -c``). A thread
+    of its own starts from an empty chunk whoever called ``build_app``
+    (Granite 66-69 s); alone it only moves the cliff somewhere else
+    (Mistral 28 s where the caller's thread read 20.6), so the work runs
+    above ``_roomy_caller``'s frame, where no chunk ends within reach."""
+    result: list = []
+
+    def run() -> None:
+        try:
+            result.append((_roomy_caller()(fn), None))
+        except BaseException as e:  # handed to the caller, below
+            result.append((None, e))
+
+    thread = threading.Thread(target=run, name="finchat-warmup")
+    thread.start()
+    thread.join()
+    value, error = result[0]
+    if error is not None:
+        raise error
+    return value
 
 
 def make_warm_fabric(cfg: AppConfig):
@@ -458,6 +506,7 @@ class App:
         self.server.route("POST", "/transactions", self.upsert_transactions)
         self._consume_task: asyncio.Task | None = None
         self._running = False
+        self._tracing_serving = False  # between start and stop (TRACER's stage)
         # Kafka-driven concurrency: one task per in-flight message so many
         # conversations batch onto the engine together, with a per-
         # conversation ordering chain (same conversation stays serial —
@@ -593,9 +642,16 @@ class App:
         # batch arrives (PERF.md §6, PR 27). Frozen, a full collection
         # walks what requests made since (0.04 s).
         gc.freeze()
+        # from here a program that compiles is an unwarmed shape of the
+        # serving path, and a sleeper that wakes late is a frozen process
+        self._tracing_serving = True
+        TRACER.serving_started()
 
     async def stop(self) -> None:
         self._running = False
+        if self._tracing_serving:
+            self._tracing_serving = False
+            TRACER.serving_stopped()
         gc.unfreeze()
         if self._prefix_refresh_task:
             self._prefix_refresh_task.cancel()
@@ -1388,72 +1444,71 @@ def build_app(cfg: AppConfig | None = None, *, store: ConversationStore | None =
             response_generator = response_generator or resp_gen
 
     if retriever is None:
-        embed_started = time.perf_counter()
-        from finchat_tpu.embed.batcher import EmbedMicrobatcher
-        from finchat_tpu.embed.encoder import EMBED_PRESETS, EmbeddingEncoder, init_bert_params
-        from finchat_tpu.embed.index import DeviceVectorIndex
+        with TRACER.startup_phase("embed"):
+            from finchat_tpu.embed.batcher import EmbedMicrobatcher
+            from finchat_tpu.embed.encoder import EMBED_PRESETS, EmbeddingEncoder, init_bert_params
+            from finchat_tpu.embed.index import DeviceVectorIndex
 
-        embed_cfg = EMBED_PRESETS[cfg.embed.preset]
-        if cfg.embed.checkpoint_path:
-            from finchat_tpu.checkpoints.bert_loader import load_bert_params
-
-            embed_params = load_bert_params(cfg.embed.checkpoint_path, embed_cfg)
-        else:
-            logger.warning(
-                "no embedding checkpoint configured; using RANDOM weights "
-                "(preset=%s) — retrieval rankings will be meaningless", cfg.embed.preset,
-            )
-            embed_params = init_bert_params(embed_cfg, jax.random.key(1))
-        if cfg.embed.tokenizer_path:
-            embed_tokenizer = get_tokenizer(cfg.embed.tokenizer_path)
-        else:
+            embed_cfg = EMBED_PRESETS[cfg.embed.preset]
             if cfg.embed.checkpoint_path:
-                logger.warning(
-                    "embed.checkpoint_path is set but embed.tokenizer_path is "
-                    "not; falling back to the LLM/byte tokenizer, whose ids "
-                    "will NOT match the BERT vocab — retrieval rankings will "
-                    "be meaningless. Set FINCHAT_EMBED_TOKENIZER."
-                )
-            embed_tokenizer = tokenizer or get_tokenizer()
-        encoder = EmbeddingEncoder(
-            embed_cfg, embed_params, embed_tokenizer,
-            batch_size=cfg.embed.batch_size, quant=cfg.embed.quant,
-        )
-        if cfg.vector.api_key and not cfg.vector.url:
-            logger.warning(
-                "QDRANT_API_KEY is set but QDRANT_URL is not; using the "
-                "on-device vector index — set QDRANT_URL to select the "
-                "external Qdrant backend"
-            )
-        if cfg.vector.url:
-            # deployments with an existing populated Qdrant cluster drop
-            # in via QDRANT_URL (reference qdrant_tool.py:24-37); the
-            # embeddings still run on-device, only ANN search is external
-            from finchat_tpu.tools.qdrant_retriever import QdrantRetriever
+                from finchat_tpu.checkpoints.bert_loader import load_bert_params
 
-            retriever = QdrantRetriever(
-                encoder, url=cfg.vector.url, api_key=cfg.vector.api_key,
-                collection=cfg.vector.collection,
-                default_limit=cfg.vector.default_limit,
-            )
-        else:
-            base = cfg.vector.snapshot_base()
-            if base:
-                index = DeviceVectorIndex.load(base, dim=embed_cfg.dim)
+                embed_params = load_bert_params(cfg.embed.checkpoint_path, embed_cfg)
             else:
-                index = DeviceVectorIndex(dim=embed_cfg.dim)
-            # the embedding microbatcher coalesces concurrent query embeds
-            # and ingest upserts into shared encode_batch dispatches; it
-            # binds to the serving event loop at App.start
-            batcher = EmbedMicrobatcher(
-                encoder, window_ms=cfg.embed.batch_window_ms,
-                max_batch=cfg.embed.batch_max,
+                logger.warning(
+                    "no embedding checkpoint configured; using RANDOM weights "
+                    "(preset=%s) — retrieval rankings will be meaningless", cfg.embed.preset,
+                )
+                embed_params = init_bert_params(embed_cfg, jax.random.key(1))
+            if cfg.embed.tokenizer_path:
+                embed_tokenizer = get_tokenizer(cfg.embed.tokenizer_path)
+            else:
+                if cfg.embed.checkpoint_path:
+                    logger.warning(
+                        "embed.checkpoint_path is set but embed.tokenizer_path is "
+                        "not; falling back to the LLM/byte tokenizer, whose ids "
+                        "will NOT match the BERT vocab — retrieval rankings will "
+                        "be meaningless. Set FINCHAT_EMBED_TOKENIZER."
+                    )
+                embed_tokenizer = tokenizer or get_tokenizer()
+            encoder = EmbeddingEncoder(
+                embed_cfg, embed_params, embed_tokenizer,
+                batch_size=cfg.embed.batch_size, quant=cfg.embed.quant,
             )
-            retriever = TransactionRetriever(
-                encoder, index, default_limit=cfg.vector.default_limit,
-                batcher=batcher,
-            )
-        TRACER.startup("embed", time.perf_counter() - embed_started)
+            if cfg.vector.api_key and not cfg.vector.url:
+                logger.warning(
+                    "QDRANT_API_KEY is set but QDRANT_URL is not; using the "
+                    "on-device vector index — set QDRANT_URL to select the "
+                    "external Qdrant backend"
+                )
+            if cfg.vector.url:
+                # deployments with an existing populated Qdrant cluster drop
+                # in via QDRANT_URL (reference qdrant_tool.py:24-37); the
+                # embeddings still run on-device, only ANN search is external
+                from finchat_tpu.tools.qdrant_retriever import QdrantRetriever
+
+                retriever = QdrantRetriever(
+                    encoder, url=cfg.vector.url, api_key=cfg.vector.api_key,
+                    collection=cfg.vector.collection,
+                    default_limit=cfg.vector.default_limit,
+                )
+            else:
+                base = cfg.vector.snapshot_base()
+                if base:
+                    index = DeviceVectorIndex.load(base, dim=embed_cfg.dim)
+                else:
+                    index = DeviceVectorIndex(dim=embed_cfg.dim)
+                # the embedding microbatcher coalesces concurrent query embeds
+                # and ingest upserts into shared encode_batch dispatches; it
+                # binds to the serving event loop at App.start
+                batcher = EmbedMicrobatcher(
+                    encoder, window_ms=cfg.embed.batch_window_ms,
+                    max_batch=cfg.embed.batch_max,
+                )
+                retriever = TransactionRetriever(
+                    encoder, index, default_limit=cfg.vector.default_limit,
+                    batcher=batcher,
+                )
 
     system_prompt, tool_prompt = load_prompts()
 

@@ -36,6 +36,12 @@ part of a device step runs under a ``jax.named_scope`` from
 path. The same phases, summed per round on ``perf_counter``, go to the ring
 as one ``round`` event and to ``finchat_round_phase_seconds_total``.
 
+Time lost OUTSIDE a device step has two more events on the same ring and
+clock (ISSUE 38): ``compile``, one per program XLA compiled or loaded
+(``jax.monitoring``'s own events, heard by :func:`listen_for_compiles`), and
+``freeze``, one per late tick of a thread that only sleeps
+(:class:`Heartbeat`). A ``round`` event says how much of either it carried.
+
 Every ``mark()``/event/scope name MUST come from the registries below —
 finchat-lint R5's span-discipline check enforces it statically, because a
 typo'd name otherwise just silently vanishes from every timeline.
@@ -116,6 +122,14 @@ TRACE_EVENTS = frozenset({
     # one phase of process start-up (STARTUP_PHASES), so an exported ring
     # or a flight dump begins at process start
     "startup",
+    # one program compiled, or retrieved from the persistent cache and
+    # loaded (ISSUE 38): args carry ``fun_name``, ``cache`` (COMPILE_CACHE),
+    # ``stage`` (a STARTUP_PHASES phase, ``serving`` or ``idle``) and the
+    # ``trace_s`` / ``lower_s`` Python spent on the program before it
+    "compile",
+    # the process could not run a thread that only sleeps (ISSUE 38): args
+    # carry ``process_cpu_s`` over the gap and ``owner`` (FREEZE_OWNERS)
+    "freeze",
 })
 
 #: The parts of one scheduler iteration, in the order the loop runs them.
@@ -160,6 +174,21 @@ FINISH_REASONS = frozenset({
 #: Phases of process start-up (``finchat_startup_seconds{phase}``).
 STARTUP_PHASES = ("artifacts", "engine_init", "warmup", "embed", "heads")
 
+#: Where a ``compile`` event's program came from: the persistent cache held
+#: it, it was compiled and written there, or no cache event came with it.
+COMPILE_CACHE = ("hit", "miss", "off")
+
+#: A ``compile`` event's ``stage`` outside STARTUP_PHASES: between
+#: ``App.start`` and ``App.stop``, and any other time (a test, the
+#: benchmark's reference model).
+STAGE_SERVING, STAGE_IDLE = "serving", "idle"
+
+#: Who held the process while a heartbeat tick was late: ``machine`` when
+#: the process's own CPU clock stood still meanwhile (it was not scheduled:
+#: the host's), ``process`` when some thread of ours ran (the interpreter
+#: lock was held, the collector ran: the program's).
+FREEZE_OWNERS = ("machine", "process")
+
 #: Anomaly kinds — each records an event AND triggers a flight dump.
 ANOMALY_KINDS = frozenset({
     "breaker_trip", "watchdog_timeout", "shed", "replica_give_up",
@@ -199,6 +228,22 @@ QUANT_MODES = frozenset({
 SLOW_ROUND_FLOOR_S = 0.25
 SLOW_ROUND_MEDIANS = 10.0
 SLOW_ROUND_LOG_INTERVAL_S = 5.0
+
+# the heartbeat sleeps this long at a time; a tick later than the second
+# is a ``freeze``; below this share of the gap on the process's CPU clock
+# nothing of ours ran in it
+HEARTBEAT_INTERVAL_S = 0.05
+HEARTBEAT_LATE_S = 0.1
+FREEZE_MACHINE_CPU_SHARE = 0.2
+
+# jax.monitoring's names (jax/_src/dispatch.py, compiler.py): the backend's
+# span covers compiling OR retrieving and loading, and the cache's hit /
+# miss event fires inside it on the compiling thread
+_JAX_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                     "/jax/compilation_cache/cache_misses": "miss"}
 
 _FLIGHT_MAGIC = "FINCHAT-FLIGHT v1"
 # per-kind dump rate limit: an anomaly storm (e.g. a shed wave) records
@@ -248,11 +293,14 @@ class RoundPhases:
     """The accumulator of one scheduler loop: seconds spent in each of
     ROUND_PHASES since ``reset()``, and the phase that is open now. Phases
     nest as a stack through ``open``, so one task alone opens them: the
-    scheduler's loop task, from which every consume path is awaited."""
+    scheduler's loop task, from which every consume path is awaited.
+    ``clock`` is ``perf_counter`` (the ring's clock) unless a test injects
+    its own."""
 
-    __slots__ = ("seconds", "open")
+    __slots__ = ("seconds", "open", "clock")
 
-    def __init__(self) -> None:
+    def __init__(self, clock=time.perf_counter) -> None:
+        self.clock = clock
         self.reset()
 
     def reset(self) -> None:
@@ -270,11 +318,11 @@ class RoundPhases:
 class _Phase:
     """One phase of a scheduler round: a ``finchat.<phase>`` annotation on
     the profiler's clock (near-free while no capture runs) around a
-    ``perf_counter`` duration added to the round's accumulator. A phase
+    duration on the accumulator's clock added to the round's. A phase
     opened inside another takes its time out of the outer one, so a
     round's phases never count a second twice. A plain class, not a
     generator: phases open a dozen times a round. ``started`` / ``ended``
-    are its own ends on ``perf_counter``."""
+    are its own ends on that clock."""
 
     __slots__ = ("_name", "_acc", "_outer", "_annotation", "_t0",
                  "started", "ended")
@@ -289,18 +337,65 @@ class _Phase:
         acc.open = self
         # the annotation starts when it is constructed
         self._annotation = jax.profiler.TraceAnnotation("finchat." + self._name)
-        self.started = self._t0 = now = time.perf_counter()
+        self.started = self._t0 = now = acc.clock()
         if outer is not None:
             acc.seconds[outer._name] += now - outer._t0
 
     def __exit__(self, *exc) -> None:
-        self.ended = now = time.perf_counter()
         acc, outer = self._acc, self._outer
+        self.ended = now = acc.clock()
         acc.seconds[self._name] += now - self._t0
         if outer is not None:
             outer._t0 = now
         acc.open = outer
         self._annotation.__exit__(*exc)
+
+
+class _Heard(threading.local):
+    """What one thread heard from ``jax.monitoring`` since its last backend
+    span: a program's trace, lowering and cache events all come on the
+    thread that compiles it, in that order."""
+    trace_s = 0.0
+    lower_s = 0.0
+    cache = "off"
+
+
+class Heartbeat:
+    """A thread that only sleeps, HEARTBEAT_INTERVAL_S at a time. A tick more
+    than HEARTBEAT_LATE_S late is a ``freeze`` event from when it was due to
+    when it came: nothing else explains a sleeper that was not woken. The
+    process's own CPU clock over the gap says whose it was (FREEZE_OWNERS).
+    ``clock``, ``cpu_clock`` and ``sleep`` are injected by tests; ``sleep``
+    defaults to waiting on the stop flag, so ``stop`` does not wait a tick
+    out."""
+
+    def __init__(self, tracer: "Tracer", *, clock=time.perf_counter,
+                 cpu_clock=time.process_time, sleep=None):
+        self._tracer = tracer
+        self._clock, self._cpu_clock = clock, cpu_clock
+        self._stopped = threading.Event()
+        self._sleep = sleep if sleep is not None else self._stopped.wait
+        self._thread: threading.Thread | None = None
+
+    def start(self) -> None:
+        self._thread = threading.Thread(target=self.run, daemon=True,
+                                        name="finchat-heartbeat")
+        self._thread.start()
+
+    def stop(self) -> None:
+        self._stopped.set()
+        if self._thread is not None:
+            self._thread.join()
+            self._thread = None
+
+    def run(self) -> None:
+        clock, cpu_clock = self._clock, self._cpu_clock
+        while not self._stopped.is_set():
+            due, cpu = clock() + HEARTBEAT_INTERVAL_S, cpu_clock()
+            self._sleep(HEARTBEAT_INTERVAL_S)
+            late = clock() - due
+            if late > HEARTBEAT_LATE_S and not self._stopped.is_set():
+                self._tracer.freeze(due, late, cpu_clock() - cpu)
 
 
 class Tracer:
@@ -320,6 +415,16 @@ class Tracer:
         self._dump_seq = 0
         self._last_dump: dict[str, float] = {}
         self._dump_threads: list[threading.Thread] = []
+        # which part of the process's life a program compiles in
+        self._startup_open: str | None = None
+        self._serving = 0  # Apps started and not yet stopped
+        self._heartbeat: Heartbeat | None = None
+        self._heard = _Heard()
+        # running totals; a scheduler books the difference since its last
+        # round (plain attribute reads on the loop: no call, no lock)
+        self.serving_compile_s = 0.0
+        self.frozen_s = 0.0
+        self.last_compiled = ""
 
     # --- configuration ---------------------------------------------------
     def configure(self, enabled: bool | None = None,
@@ -387,9 +492,87 @@ class Tracer:
 
     @contextlib.contextmanager
     def startup_phase(self, phase: str) -> Iterator[None]:
+        """Time the with-block as ``phase``; a program compiled inside it
+        has the phase as its ``compile`` event's ``stage``."""
         t0 = time.perf_counter()
-        yield
+        outer, self._startup_open = self._startup_open, phase
+        try:
+            yield
+        finally:
+            self._startup_open = outer
         self.startup(phase, time.perf_counter() - t0)
+
+    # --- time lost outside a device step (ISSUE 38) ------------------------
+    @property
+    def stage(self) -> str:
+        return self._startup_open or (STAGE_SERVING if self._serving else STAGE_IDLE)
+
+    def serving_started(self) -> None:
+        """An App started: programs compile at stage ``serving`` from here
+        on, and the process's first App starts the heartbeat."""
+        with self._lock:
+            self._serving += 1
+            if self._serving == 1:
+                self._heartbeat = Heartbeat(self)
+                self._heartbeat.start()
+
+    def serving_stopped(self) -> None:
+        """An App stopped; the last one joins the heartbeat."""
+        with self._lock:
+            self._serving = max(0, self._serving - 1)
+            heartbeat = None
+            if not self._serving:
+                heartbeat, self._heartbeat = self._heartbeat, None
+        if heartbeat is not None:
+            heartbeat.stop()
+
+    def freeze(self, due: float, late: float, process_cpu_s: float) -> None:
+        """Book a heartbeat tick that came ``late`` seconds after ``due``."""
+        owner = FREEZE_OWNERS[process_cpu_s >= FREEZE_MACHINE_CPU_SHARE * late]
+        self.frozen_s += late
+        METRICS.inc("finchat_process_frozen_seconds_total", late,
+                    labels={"owner": owner})
+        self.event("freeze", ts=due, dur=late, track="host",
+                   args={"process_cpu_s": process_cpu_s, "owner": owner})
+
+    def on_jax_event(self, event: str, **_kw) -> None:
+        """``jax.monitoring``'s plain events: the persistent cache's hit or
+        miss belongs to the backend span open on this thread."""
+        cache = _JAX_CACHE_EVENTS.get(event)
+        if cache is not None:
+            self._heard.cache = cache
+
+    def on_jax_duration(self, event: str, seconds: float, fun_name: str = "",
+                        **_kw) -> None:
+        """``jax.monitoring``'s durations. Tracing and lowering are kept for
+        the backend span that follows them on this thread: the LONGEST of
+        each heard since the last span, because a jitted callee (every
+        ``jax.numpy`` call is one) is traced inside its caller, and a
+        lowering rule may trace small functions of its own after the
+        program's trace has ended. The backend span becomes the ``compile``
+        event and the counters, which are booked whether or not the ring is
+        enabled. Nothing else: no logging, no call into JAX."""
+        heard = self._heard
+        if event == _JAX_TRACE_EVENT:
+            heard.trace_s = max(heard.trace_s, seconds)
+        elif event == _JAX_LOWER_EVENT:
+            heard.lower_s = max(heard.lower_s, seconds)
+        elif event == _JAX_BACKEND_EVENT:
+            stage, cache = self.stage, heard.cache
+            trace_s, lower_s = heard.trace_s, heard.lower_s
+            heard.cache, heard.trace_s, heard.lower_s = "off", 0.0, 0.0
+            labels = {"stage": stage, "cache": cache}
+            METRICS.inc("finchat_compiles_total", labels=labels)
+            METRICS.inc("finchat_compile_seconds_total", seconds, labels=labels)
+            METRICS.inc("finchat_compile_trace_seconds_total", trace_s + lower_s,
+                        labels={"stage": stage})
+            if stage == STAGE_SERVING:
+                self.serving_compile_s += trace_s + lower_s + seconds
+                self.last_compiled = fun_name
+            self.event("compile", ts=time.perf_counter() - seconds, dur=seconds,
+                       track="compile",
+                       args={"fun_name": fun_name, "cache": cache, "stage": stage,
+                             "trace_s": trace_s, "lower_s": lower_s})
 
     def anomaly(self, kind: str, trace_id: str | None = None,
                 args: dict | None = None) -> None:
@@ -410,11 +593,17 @@ class Tracer:
         """One request's correlated timeline as Chrome trace-event JSON
         (``{"traceEvents": [...]}`` — open in Perfetto / chrome://tracing):
         every event stamped with ``trace_id`` plus every dispatch whose
-        row list carried it."""
-        events = [
-            _chrome_event(ev) for ev in self.snapshot()
-            if _event_carries(ev, trace_id)
-        ]
+        row list carried it, plus the ``compile`` and ``freeze`` spans that
+        overlap those: what the request waited for that was not its own."""
+        ring = self.snapshot()
+        own = [ev for ev in ring if _event_carries(ev, trace_id)]
+        if own:
+            first = min(ev[0] for ev in own)
+            last = max(ev[0] + (ev[3] or 0.0) for ev in own)
+            own += [ev for ev in ring if ev[2] in ("compile", "freeze")
+                    and ev[0] < last and ev[0] + ev[3] > first]
+            own.sort(key=lambda ev: ev[0])
+        events = [_chrome_event(ev) for ev in own]
         return {
             "traceEvents": events,
             "displayTimeUnit": "ms",
@@ -519,6 +708,25 @@ def load_flight_dump(path: str) -> dict:
 
 # Process-global tracer (one worker process = one ring, matching METRICS).
 TRACER = Tracer()
+
+_listening = False
+
+
+def listen_for_compiles() -> bool:
+    """Register ``TRACER``'s two listeners with ``jax.monitoring``, once a
+    process however often it is asked (JAX keeps no listener apart from
+    another: a second registration would book every program twice). True
+    when this call registered them."""
+    global _listening
+    if _listening:
+        return False
+    _listening = True
+    jax.monitoring.register_event_listener(TRACER.on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(TRACER.on_jax_duration)
+    return True
+
+
+listen_for_compiles()
 
 
 @dataclass

@@ -341,3 +341,43 @@ async def test_same_conversation_messages_stay_ordered():
         ), events
     finally:
         await app.stop()
+
+
+# --- warm-up on a stack of its own (ISSUE 38) ---------------------------------
+
+@pytest.mark.parametrize("outcome", ["returns", "raises", "base_exception"])
+def test_on_a_fresh_stack_runs_on_its_own_thread_and_hands_back_the_outcome(outcome):
+    """``engine.warmup`` runs through this: on a thread whose frames start
+    from an empty chunk (so how deep ``build_app``'s caller is cannot move
+    the warm-up's seconds), with the caller seeing exactly what a plain call
+    would have given."""
+    import threading
+
+    from finchat_tpu.serve.app import _on_a_fresh_stack
+    from finchat_tpu.utils.tracing import TRACER
+
+    seen = {}
+
+    def work():
+        seen["thread"] = threading.current_thread()
+        seen["depth"] = len(__import__("inspect").stack())
+        seen["stage"] = TRACER.stage  # the phase open in the caller is the worker's too
+        if outcome == "raises":
+            raise ValueError("warm-up failed")
+        if outcome == "base_exception":
+            raise KeyboardInterrupt
+        return 12.5
+
+    TRACER._startup_open = "engine_init"  # held directly: no start-up gauge moves
+    try:
+        if outcome == "returns":
+            assert _on_a_fresh_stack(work) == 12.5
+        else:
+            with pytest.raises(ValueError if outcome == "raises" else KeyboardInterrupt):
+                _on_a_fresh_stack(work)
+    finally:
+        TRACER._startup_open = None
+    assert seen["thread"] is not threading.main_thread() and seen["thread"].name == "finchat-warmup"
+    assert not seen["thread"].is_alive()
+    assert seen["depth"] < 9  # the thread's bootstrap, the helper's closure, the roomy frame, the work
+    assert seen["stage"] == "engine_init"

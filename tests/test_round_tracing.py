@@ -24,6 +24,7 @@ import dataclasses
 import logging
 import re
 import textwrap
+import threading
 import time
 
 import jax
@@ -416,21 +417,101 @@ def test_admission_backoff_is_yield_not_scheduler_work():
 
 
 def test_nested_phase_takes_its_time_out_of_the_outer_one():
-    acc = RoundPhases()
-    t0 = time.perf_counter()
-    with TRACER.phase("stage", acc):
-        time.sleep(0.02)
+    """On an injected clock: no sleep, and so nothing a loaded worker can
+    stretch (this case asserted ``time.sleep`` to 8 ms before ISSUE 38)."""
+    now = [100.0]
+    acc = RoundPhases(clock=lambda: now[0])
+    with TRACER.phase("stage", acc) as _:
+        now[0] += 0.02
         with TRACER.phase("dispatch", acc):
-            time.sleep(0.03)
+            now[0] += 0.03
             acc.note(kind="decode", kv_tokens=7)  # no capture: a no-op
-        time.sleep(0.01)
-    total = time.perf_counter() - t0
+        now[0] += 0.01
     assert acc.open is None
-    assert acc.seconds["dispatch"] == pytest.approx(0.03, abs=0.008)
-    assert acc.seconds["stage"] == pytest.approx(0.03, abs=0.008)
-    assert sum(acc.seconds.values()) == pytest.approx(total, abs=0.002)
+    assert acc.seconds["dispatch"] == pytest.approx(0.03)
+    assert acc.seconds["stage"] == pytest.approx(0.03)
+    assert sum(acc.seconds.values()) == pytest.approx(now[0] - 100.0)
     acc.reset()
     assert set(acc.seconds) == set(ROUND_PHASES) and not any(acc.seconds.values())
+    assert RoundPhases().clock is time.perf_counter  # the ring's clock otherwise
+
+
+# --- what a round lost outside a device step (ISSUE 38) ------------------------
+
+@pytest.mark.parametrize("lost", ["compile", "freeze", "both", "neither"])
+def test_a_round_carries_the_compile_and_the_freeze_it_spanned(lost, caplog, monkeypatch):
+    """A program compiled while serving and a late heartbeat tick, fed to
+    the tracer in mid-stream: the round that closes next carries their
+    seconds, no other round does, and the WARNING names them."""
+    from finchat_tpu.engine import scheduler as scheduler_module
+
+    monkeypatch.setattr(scheduler_module, "SLOW_ROUND_FLOOR_S", 0.0)
+    monkeypatch.setattr(scheduler_module, "SLOW_ROUND_MEDIANS", 0.0)
+    fed = {}
+
+    async def go():
+        sched = _scheduler(mixed_step=False)
+        await sched.start()
+        try:
+            h = await sched.submit("a", PROMPT_A, SamplingParams(
+                temperature=0.0, max_new_tokens=40), trace_id="a")
+            while True:
+                event = await h.events.get()
+                if event["type"] != "token":
+                    return
+                if h.generated == 20 and not fed:  # compiled and warm by then
+                    fed["n"] = sched._dispatch_tally
+                    monkeypatch.setattr(TRACER, "_serving", 1)
+                    if lost in ("compile", "both"):
+                        jax.monitoring.record_event_duration_secs(
+                            "/jax/core/compile/jaxpr_trace_duration", 0.5, fun_name="unwarmed")
+                        jax.monitoring.record_event_duration_secs(
+                            "/jax/core/compile/backend_compile_duration", 0.25,
+                            fun_name="jit(unwarmed)")
+                    if lost in ("freeze", "both"):
+                        TRACER.freeze(time.perf_counter() - 1.5, 1.5, 0.0)
+                    monkeypatch.setattr(TRACER, "_serving", 0)
+                    sched._slow_round_logged = float("-inf")  # the next round warns
+        finally:
+            await sched.stop()
+
+    with caplog.at_level(logging.WARNING, logger="finchat_tpu.engine.scheduler"):
+        asyncio.run(go())
+    compile_s = 0.75 if lost in ("compile", "both") else 0.0
+    frozen_s = 1.5 if lost in ("freeze", "both") else 0.0
+    carrying = [ev[5] for ev in _ring("round") if "compile_s" in ev[5] or "frozen_s" in ev[5]]
+    if lost == "neither":
+        assert not carrying
+    else:
+        assert len(carrying) == 1 and carrying[0]["n"] >= fed["n"]
+        assert carrying[0].get("compile_s", 0.0) == pytest.approx(compile_s)
+        assert carrying[0].get("frozen_s", 0.0) == pytest.approx(frozen_s)
+        assert ("compile_s" in carrying[0]) == bool(compile_s)  # only when non-zero
+        assert ("frozen_s" in carrying[0]) == bool(frozen_s)
+    said = [r.getMessage() for r in caplog.records if "slow scheduler round" in r.getMessage()]
+    program = re.escape("jit(unwarmed)") if compile_s else "none"
+    assert any(re.search(rf"compiling or loading {compile_s:.3f} s \(last program {program}\), "
+                         rf"process frozen {frozen_s:.3f} s", message)
+               for message in said[-1:]), said
+
+
+def test_streams_are_the_same_tokens_with_the_heartbeat_running():
+    """Tracing on with the tracer's thread alive and the stage ``serving``
+    against tracing off and no thread: the same tokens on the ragged path."""
+    options, _kind, arrival = BRANCHES["ragged"]
+    TRACER.serving_started()
+    try:
+        assert [t.name for t in threading.enumerate()].count("finchat-heartbeat") == 1
+        tokens_on, _riders, _tally = _run_branch(options, arrival, traced=True)
+        assert _ring("round")
+        # the first-time compiles of this run happened while "serving"
+        assert any(ev[5]["stage"] == "serving" for ev in _ring("compile")) or not _ring("compile")
+    finally:
+        TRACER.serving_stopped()
+    assert "finchat-heartbeat" not in [t.name for t in threading.enumerate()]
+    tokens_off, _riders, _tally = _run_branch(options, arrival, traced=False)
+    assert TRACER.snapshot() == []
+    assert tokens_on == tokens_off and all(tokens_on)
 
 
 # --- device scopes -----------------------------------------------------------
@@ -563,7 +644,9 @@ def test_startup_phases_set_their_gauge_and_leave_an_event(startup_gauges_restor
     before = METRICS.get("finchat_startup_seconds", labels=labels)
     TRACER.startup("warmup", 1.5)
     with TRACER.startup_phase("heads"):
+        assert TRACER.stage == "heads"  # what a program compiled in here is booked under
         time.sleep(0.01)
+    assert TRACER.stage == "idle"
     assert METRICS.get("finchat_startup_seconds", labels=labels) - before == pytest.approx(1.5)
     assert METRICS.get("finchat_startup_seconds", labels={"phase": "heads"}) >= 0.01
     events = _ring("startup")

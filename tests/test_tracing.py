@@ -23,8 +23,10 @@ Pins the tracing contract:
 """
 
 import asyncio
+import contextlib
 import dataclasses
 import json
+import threading
 import time
 
 import jax
@@ -42,11 +44,15 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.metrics import METRICS, MetricsRegistry
 from finchat_tpu.utils.tracing import (
     ANOMALY_KINDS,
+    COMPILE_CACHE,
+    HEARTBEAT_INTERVAL_S,
     SPAN_MARKS,
     TRACE_EVENT_NAMES,
     TRACER,
+    Heartbeat,
     RequestSpan,
     Tracer,
+    listen_for_compiles,
     load_flight_dump,
 )
 
@@ -470,3 +476,241 @@ async def test_debug_trace_endpoint_prefix_route():
         assert status == 404
     finally:
         await server.stop()
+
+
+# --- compile events (ISSUE 38) ----------------------------------------------
+
+BACKEND = "/jax/core/compile/backend_compile_duration"
+
+
+def _ring(name, tracer=TRACER):
+    return [ev for ev in tracer.snapshot() if ev[2] == name]
+
+
+def _compile_counters(stage):
+    series = METRICS.snapshot()
+    return {family: sum(v for k, v in series.items()
+                        if k.startswith(family + "{") and f'stage="{stage}"' in k)
+            for family in ("finchat_compiles_total", "finchat_compile_seconds_total",
+                           "finchat_compile_trace_seconds_total")}
+
+
+@contextlib.contextmanager
+def _at_stage(stage):
+    """Hold the process tracer at a stage without an App, a thread or a
+    start-up gauge moved (``startup_phase`` itself: tests/test_round_tracing.py)."""
+    attr, held = ("_serving", 1) if stage == "serving" else ("_startup_open", stage)
+    if stage != "idle":
+        setattr(TRACER, attr, held)
+    try:
+        yield
+    finally:
+        TRACER._serving, TRACER._startup_open = 0, None
+
+
+@pytest.mark.parametrize("stage", ["warmup", "heads", "serving", "idle"])
+def test_a_fresh_jitted_function_is_one_compile_event_at_its_stage(stage):
+    def fresh_program(x):
+        return x * 3 + 1
+
+    fun = jax.jit(fresh_program)
+    x = jnp.ones((5,), jnp.float32)
+    jax.block_until_ready(x)
+    before = _compile_counters(stage)
+    serving_s0 = TRACER.serving_compile_s
+    with _at_stage(stage):
+        assert TRACER.stage == stage
+        fun(x)
+        fun(x)  # the second call finds the program: no event, no count
+    mine = [ev for ev in _ring("compile") if ev[5]["fun_name"] == "jit(fresh_program)"]
+    assert len(mine) == 1, _ring("compile")
+    ts, _tid, _name, dur, track, args = mine[0]
+    assert track == "compile" and dur > 0 and ts + dur <= time.perf_counter()
+    assert args["stage"] == stage and args["cache"] in COMPILE_CACHE
+    assert args["trace_s"] > 0 and args["lower_s"] > 0
+    after = _compile_counters(stage)
+    assert after["finchat_compiles_total"] - before["finchat_compiles_total"] == 1
+    assert (after["finchat_compile_seconds_total"]
+            - before["finchat_compile_seconds_total"]) == pytest.approx(dur)
+    assert (after["finchat_compile_trace_seconds_total"]
+            - before["finchat_compile_trace_seconds_total"]
+            ) == pytest.approx(args["trace_s"] + args["lower_s"])
+    # only a program compiled while serving is time a round may have lost
+    carried = TRACER.serving_compile_s - serving_s0
+    if stage == "serving":
+        assert carried == pytest.approx(dur + args["trace_s"] + args["lower_s"])
+        assert TRACER.last_compiled == "jit(fresh_program)"
+    else:
+        assert carried == 0.0
+    assert TRACER.stage == "idle"
+
+
+@pytest.mark.parametrize("heard, cache", [
+    (["/jax/compilation_cache/cache_hits"], "hit"),
+    (["/jax/compilation_cache/compile_requests_use_cache",
+      "/jax/compilation_cache/cache_misses"], "miss"),
+    (["/jax/compilation_cache/compile_requests_use_cache"], "off"),
+    ([], "off"),
+])
+def test_cache_is_what_the_thread_heard_since_its_last_compile(heard, cache):
+    """``jax.monitoring``'s own events, fed directly: the cache's hit or miss
+    belongs to the backend span that closes after it ON THE SAME THREAD, and
+    tracing and lowering heard before the span ride with it once."""
+    labels = {"stage": "idle", "cache": cache}
+    n0 = METRICS.get("finchat_compiles_total", labels=labels)
+    s0 = METRICS.get("finchat_compile_seconds_total", labels=labels)
+
+    def elsewhere():  # another thread's hit is not this thread's
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+
+    other = threading.Thread(target=elsewhere)
+    other.start()
+    other.join()
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 0.25, fun_name="inner")
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_trace_duration", 2.0, fun_name="fed_program")
+    jax.monitoring.record_event_duration_secs(  # a lowering rule traces a helper of its own
+        "/jax/core/compile/jaxpr_trace_duration", 0.001, fun_name="in_a_lowering_rule")
+    jax.monitoring.record_event_duration_secs(
+        "/jax/core/compile/jaxpr_to_mlir_module_duration", 3.0, fun_name="jit_fed_program")
+    for event in heard:
+        jax.monitoring.record_event(event)
+    jax.monitoring.record_event_duration_secs(BACKEND, 0.5, fun_name="jit(fed_program)")
+    jax.monitoring.record_event_duration_secs(BACKEND, 0.125, fun_name="jit(next_program)")
+    first, second = [ev for ev in _ring("compile") if "_program" in ev[5]["fun_name"]]
+    assert first[3] == 0.5 and first[5] == {
+        "fun_name": "jit(fed_program)", "cache": cache, "stage": "idle",
+        "trace_s": 2.0, "lower_s": 3.0}  # the longest heard: the callee's is inside the caller's
+    assert second[5] == {"fun_name": "jit(next_program)", "cache": "off", "stage": "idle",
+                         "trace_s": 0.0, "lower_s": 0.0}
+    assert METRICS.get("finchat_compiles_total", labels=labels) - n0 == 1 + (cache == "off")
+    assert METRICS.get("finchat_compile_seconds_total", labels=labels) - s0 == (
+        0.5 + 0.125 * (cache == "off"))
+
+
+def test_compile_counters_are_booked_with_the_ring_off():
+    TRACER.configure(enabled=False)
+    n0 = METRICS.get("finchat_compiles_total", labels={"stage": "idle", "cache": "off"})
+    jax.monitoring.record_event_duration_secs(BACKEND, 0.5, fun_name="jit(quiet)")
+    assert METRICS.get("finchat_compiles_total",
+                       labels={"stage": "idle", "cache": "off"}) - n0 == 1
+    assert not _ring("compile")
+
+
+def test_the_listeners_register_once_however_often_they_are_asked():
+    assert [listen_for_compiles() for _ in range(3)] == [False] * 3  # the import did
+    from jax._src import monitoring
+
+    assert monitoring.get_event_duration_listeners().count(TRACER.on_jax_duration) == 1
+    assert monitoring.get_event_listeners().count(TRACER.on_jax_event) == 1
+
+
+# --- the heartbeat (ISSUE 38) -----------------------------------------------
+
+class _ScriptedTime:
+    """A clock, a CPU clock and a sleeper that no wall clock drives: every
+    sleep advances the two clocks by the next scripted pair and the last one
+    stops the heartbeat."""
+
+    def __init__(self, ticks):
+        self.now, self.cpu, self.ticks = 1000.0, 50.0, list(ticks)
+        self.heartbeat = None
+
+    def sleep(self, seconds):
+        assert seconds == HEARTBEAT_INTERVAL_S
+        slept, cpu = self.ticks.pop(0)
+        self.now += slept
+        self.cpu += cpu
+        if not self.ticks:
+            self.heartbeat._stopped.set()
+
+    def run(self, tracer):
+        self.heartbeat = Heartbeat(tracer, clock=lambda: self.now,
+                                   cpu_clock=lambda: self.cpu, sleep=self.sleep)
+        self.heartbeat.run()  # on this thread: the script ends it
+
+
+END = (0.05, 0.0)  # the tick that stops the script is never booked
+
+
+@pytest.mark.parametrize("ticks, expected", [
+    # (slept, CPU seconds of the process meanwhile) a tick -> (due, dur, cpu, owner) a freeze
+    ([(0.05, 0.0), (0.051, 0.05), (0.149, 0.1), END], []),            # on time: nothing
+    ([(0.05, 0.0), (1.05, 0.0), END], [(1000.1, 1.0, 0.0, "machine")]),
+    ([(1.05, 0.19), END], [(1000.05, 1.0, 0.19, "machine")]),         # under 0.2 x dur
+    ([(1.05, 0.2), END], [(1000.05, 1.0, 0.2, "process")]),           # the rule's edge
+    ([(1.05, 1.0), (0.05, 0.0), (0.35, 0.3), END],
+     [(1000.05, 1.0, 1.0, "process"), (1001.15, 0.3, 0.3, "process")]),
+])
+def test_late_ticks_become_freeze_events_owned_by_the_cpu_rule(ticks, expected):
+    tracer = Tracer()
+    frozen0 = {owner: METRICS.get("finchat_process_frozen_seconds_total",
+                                  labels={"owner": owner}) for owner in ("machine", "process")}
+    _ScriptedTime(ticks).run(tracer)
+    got = [(ev[0], ev[3], ev[5]["process_cpu_s"], ev[5]["owner"]) for ev in _ring("freeze", tracer)]
+    assert [tuple(pytest.approx(x) if isinstance(x, float) else x for x in row)
+            for row in expected] == got
+    assert all(ev[4] == "host" for ev in _ring("freeze", tracer))
+    assert tracer.frozen_s == pytest.approx(sum(row[1] for row in expected))
+    for owner in frozen0:
+        assert METRICS.get("finchat_process_frozen_seconds_total", labels={"owner": owner}
+                           ) - frozen0[owner] == pytest.approx(
+            sum(row[1] for row in expected if row[3] == owner))
+
+
+def _heartbeat_threads():
+    return [t for t in threading.enumerate() if t.name == "finchat-heartbeat"]
+
+
+def test_one_heartbeat_a_process_from_the_first_start_to_the_last_stop():
+    tracer = Tracer()
+    assert tracer._heartbeat is None and tracer.stage == "idle"
+    tracer.serving_started()
+    thread = tracer._heartbeat._thread
+    assert thread.is_alive() and thread.daemon and tracer.stage == "serving"
+    tracer.serving_started()  # a second App of the process
+    assert tracer._heartbeat._thread is thread and _heartbeat_threads() == [thread]
+    tracer.serving_stopped()
+    assert thread.is_alive() and tracer.stage == "serving"
+    tracer.serving_stopped()  # joins: the sleeper waits on the stop flag
+    assert not thread.is_alive() and tracer._heartbeat is None and tracer.stage == "idle"
+    tracer.serving_stopped()  # a stop without a start changes nothing
+    assert tracer._serving == 0 and not _heartbeat_threads()
+
+
+async def test_no_heartbeat_thread_before_app_start_nor_after_stop():
+    from finchat_tpu.io.kafka import InMemoryBroker, KafkaClient
+    from finchat_tpu.io.store import InMemoryStore
+    from finchat_tpu.serve.app import build_app
+    from finchat_tpu.utils.config import load_config
+
+    cfg = load_config(overrides={"model.preset": "stub"})
+    app = build_app(cfg, store=InMemoryStore(),
+                    kafka=KafkaClient(cfg.kafka, broker=InMemoryBroker()),
+                    tool_generator=StubGenerator(default="No tool call"),
+                    response_generator=StubGenerator(default="fine"))
+    assert not _heartbeat_threads() and TRACER.stage == "idle"
+    await app.start(serve_http=False)
+    try:
+        assert len(_heartbeat_threads()) == 1 and TRACER.stage == "serving"
+    finally:
+        await app.stop()
+    assert not _heartbeat_threads() and TRACER.stage == "idle"
+    await app.stop()  # stopping twice is not a second App's stop
+    assert TRACER._serving == 0
+
+
+def test_export_adds_the_compiles_and_freezes_a_request_overlapped():
+    t = Tracer()
+    t.event("ingress", "req", ts=10.0)
+    t.event("request", "req", ts=10.0, dur=5.0, track="request")
+    t.event("compile", ts=11.0, dur=1.0, track="compile", args={"fun_name": "jit(a)"})
+    t.event("freeze", ts=14.5, dur=2.0, track="host", args={"owner": "machine"})
+    t.event("compile", ts=3.0, dur=1.0, track="compile", args={"fun_name": "jit(before)"})
+    t.event("freeze", ts=15.5, dur=1.0, track="host", args={"owner": "process"})
+    t.event("ingress", "other", ts=12.0)
+    got = [(e["name"], e["ts"]) for e in t.export("req")["traceEvents"]]
+    assert got == [("ingress", 10.0e6), ("request", 10.0e6), ("compile", 11.0e6),
+                   ("freeze", 14.5e6)]
+    assert t.export("nobody")["traceEvents"] == []
