@@ -578,79 +578,106 @@ def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
                                 key_dim: int = 96, value_dim: int = 192,
                                 layers: int = 6, layer: int = 4,
                                 timed_calls: int = 20) -> dict[str, float]:
-    """The one-token gated-delta-rule update (``models/gdn.py`` ``_step`` over
-    the whole slot batch in place, as ``decode_step`` runs it) vs the
-    recurrence as it is written, in float64, at the shape of the benchmark's
-    cell (``olmo-hybrid-report-saturated``): 16 rows of 30 heads of 96 x 192
-    float32 in layer 4 of 6, one row inert. ``o`` and the layer's new state to
-    float32 round-off; the inert row and every other layer bit for bit. On the
-    chip (``pallas``: this update is XLA's, no kernel yet) also its device time
-    from a profiler capture against its stream bound (every row's state read
-    and written once at 819 GB/s: what ``gdn_state_roofline.sat`` divides by)."""
+    """The one-token gated-delta-rule update (``ops/gdn_step.py``, in place
+    over the whole slot batch as ``decode_step`` runs it) vs the recurrence as
+    it is written, in float64, at the shape of the benchmark's cell
+    (``olmo-hybrid-report-saturated``): 16 rows of 30 heads of 96 x 192
+    float32 — 15 tiles of 96 x 384 as ``LlamaConfig.state_shape`` lays them —
+    in layer 4 of 6, one row inert. ``o`` and the layer's new state to float32
+    round-off; the inert row and every other layer bit for bit. On the chip
+    (``pallas``) also the kernel's device time from a profiler capture against
+    its stream bound (every row's state read and written once at 819 GB/s:
+    what ``gdn_state_roofline.sat`` divides by) — the number to tune the
+    kernel by — and beside it XLA's ``models/gdn.py`` ``_step`` over the same
+    leaf, the two passes it replaces (the ``ref`` backend's body and the
+    fallback for gathered slots), which is also the form whose values a
+    ``ref`` backend checks."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from finchat_tpu.models import gdn
+    from finchat_tpu.models.llama import FULL, LINEAR, LlamaConfig
     from finchat_tpu.models.ssm import SsmRows, _read, _write
+    from finchat_tpu.ops.gdn_step import gdn_state_step
 
     f32 = jnp.float32
+    tiles = LlamaConfig(n_layers=4, layer_pattern=(LINEAR, LINEAR, LINEAR, FULL), gdn_heads=heads,
+                        gdn_key_dim=key_dim, gdn_value_dim=value_dim).state_shape[0]
     ks = jax.random.split(jax.random.key(32), 6)
-    state = 0.3 * jax.random.normal(ks[0], (layers, rows, heads, key_dim, value_dim), f32)
+    by_head = 0.3 * jax.random.normal(ks[0], (layers * rows, heads, key_dim, value_dim), f32)
+    state = gdn._tiles(by_head, tiles).reshape(layers, rows, tiles, key_dim, -1)
     q = gdn._l2norm(jax.random.normal(ks[1], (rows, heads, key_dim), f32)) * key_dim ** -0.5
     k = gdn._l2norm(jax.random.normal(ks[2], (rows, heads, key_dim), f32))
     v = jax.random.normal(ks[3], (rows, heads, value_dim), f32)
     g = -jax.random.uniform(ks[4], (rows, heads), f32, 0.01, 1.5).at[1].set(0.0)
     beta = jax.random.uniform(ks[5], (rows, heads), f32, 0.0, 2.0).at[1].set(0.0)
     at = jnp.asarray([layer], jnp.int32)
-    whole = SsmRows(None, jnp.ones((rows,), jnp.int32))
 
-    @partial(jax.jit, donate_argnums=0)
-    def update(state, q, k, v, g, beta, at):
-        with jax.named_scope("gdn_scan"):
-            o, new = gdn._step(_read(state, at, whole), q, k, v, g, beta)
-            return o, _write(state, new, at, whole)
-
-    S = np.asarray(state[layer], np.float64)
+    S = np.asarray(by_head, np.float64).reshape(layers, rows, heads, key_dim, value_dim)[layer]
     q64, k64, v64, g64, b64 = (np.asarray(t, np.float64) for t in (q, k, v, g, beta))
     S = np.exp(g64)[..., None, None] * S
     u = b64[..., None] * (v64 - np.einsum("nhkv,nhk->nhv", S, k64))
     want_new = S + k64[..., :, None] * u[..., None, :]
     want_o = np.einsum("nhkv,nhk->nhv", want_new, q64)
+    whole = SsmRows(None, jnp.ones((rows,), jnp.int32))
+
+    @partial(jax.jit, donate_argnums=0)
+    def xla_update(state, q, k, v, g, beta, at):  # what `mixer` runs on `ref`
+        with jax.named_scope("gdn_scan"):
+            o, new = gdn._step(gdn._heads(_read(state, at, whole), heads), q, k, v, g, beta)
+            return o, _write(state, gdn._tiles(new, tiles), at, whole)
+
+    label = "gdn step (XLA's _step)" if backend == "ref" else "kernel gdn_state_step"
     before = np.asarray(state)
-    o, state = update(state, q, k, v, g, beta, at)
+    if backend == "ref":
+        o, state = xla_update(state, q, k, v, g, beta, at)
+    else:
+        o, state = gdn_state_step(state, q, k, v, g, beta, at,
+                                  interpret=backend == "pallas-interpret")
     after = np.asarray(state)
     errors = {}
-    for name, got, want in (("o", o, want_o), ("state", after[layer], want_new)):
+    for name, got, want in (("o", o, want_o),
+                            ("state", gdn._heads(jnp.asarray(after[layer]), heads), want_new)):
         got = np.asarray(got)
-        require(np.isfinite(got).all(), f"gdn step: non-finite {name}")
+        require(np.isfinite(got).all(), f"{label}: non-finite {name}")
         errors[name] = float(np.abs(got - want).max())
         require(np.allclose(got, want, rtol=1e-5, atol=1e-4),
-                f"gdn step: {name} off the recurrence (max abs err {errors[name]:.3g})")
+                f"{label}: {name} off the recurrence (max abs err {errors[name]:.3g})")
     untouched = [i for i in range(layers) if i != layer]
     require(np.array_equal(after[untouched], before[untouched]),
-            "gdn step: a layer the update does not name changed")
+            f"{label}: a layer the update does not name changed")
     require(np.array_equal(after[layer, 1], before[layer, 1]),
-            "gdn step: the inert row's state changed")
-    say(f"gdn step: ok (layer {layer} of {layers}, {rows} rows; max abs err "
-        f"o {errors['o']:.3g}, state {errors['state']:.3g})")
+            f"{label}: the inert row's state changed")
+    say(f"{label}: ok (layer {layer} of {layers}, {rows} rows, state "
+        f"{list(state.shape)}; max abs err o {errors['o']:.3g}, state {errors['state']:.3g})")
     if backend != "pallas":
         return errors
 
-    def once():
-        nonlocal state
-        o, state = update(state, q, k, v, g, beta, at)
-        return o
+    def once(update):
+        def run():
+            nonlocal state
+            o, state = update(state, q, k, v, g, beta, at)
+            return o
+        return run
 
     small = (2 * heads * key_dim + 2 * heads * value_dim + 2 * heads) * 4
     bound_us = 1e6 * rows * (2 * heads * key_dim * value_dim * 4 + small) / 819e9
-    by_op: dict[str, float] = {}
-    for name, us in device_ops_us(once, timed_calls):
-        by_op[name] = by_op.get(name, 0.0) + us / timed_calls
-    errors.update(call_us=sum(by_op.values()), bound_us=bound_us)
-    say(f"gdn step: {errors['call_us']:.1f} us a call in {len(by_op)} operations ("
-        + ", ".join(f"{name} {us:.1f}" for name, us in sorted(by_op.items(), key=lambda x: -x[1])[:6])
-        + f"); stream bound {bound_us:.1f} us: {100 * bound_us / errors['call_us']:.1f} % of it")
+    errors["bound_us"] = bound_us
+    for label, update in (("kernel gdn_state_step", gdn_state_step), ("XLA's _step", xla_update)):
+        by_op: dict[str, float] = {}
+        for name, us in device_ops_us(once(update), timed_calls):
+            by_op[name] = by_op.get(name, 0.0) + us / timed_calls
+        call_us = sum(by_op.values())
+        if update is gdn_state_step:
+            kernel = [us for name, us in by_op.items() if "gdn_state_step" in name]
+            require(len(kernel) == 1, f"kernel gdn_state_step: {len(kernel)} custom calls a call")
+            errors.update(kernel_us=kernel[0], call_us=call_us)
+        else:
+            errors["xla_us"] = call_us
+        say(f"{label}: {call_us:.1f} us a call in {len(by_op)} operations ("
+            + ", ".join(f"{name} {us:.1f}" for name, us in sorted(by_op.items(), key=lambda x: -x[1])[:6])
+            + f"); stream bound {bound_us:.1f} us: {100 * bound_us / call_us:.1f} % of it")
     return errors
 
 

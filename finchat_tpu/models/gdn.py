@@ -29,9 +29,16 @@ rows by rank of length (``_packed_scan``): only the rows that CAN hold more
 than a block get the row width, every other row one block, so that a round
 of one prompt's chunk beside decode rows does not cost a round of prompts.
 
-The state is laid out ``[layers, slots, H, dk, dv]`` float32, one head's
-matrix the two minor dimensions — the recurrence as it is written, which the
-snapshot, reset and admission programs of the engine take as it comes.
+The state is laid out ``[layers, slots, H / n, dk, n dv]`` float32
+(``LlamaConfig.state_shape``): ``n`` heads' matrices side by side along the
+lanes of one tile, so that the minor dimension is whole 128-lane tiles
+(Olmo-Hybrid: 15 tiles of 96 x 384; ``[.., 96, 192]`` pads 192 to 256 in
+HBM). The snapshot, reset and admission programs of the engine take the leaf
+as it comes. The decode step's whole slot batch on a kernel backend advances
+it where it lies (``ops/gdn_step.py``: this rule in one in-place pass, on the
+row-block pipeline it shares with ``ops/ssm_step.py``); every other path —
+the chunked form, the packed scan, ``_step`` on ``ref`` or over gathered
+slots — reads and writes ``[N, H, dk, dv]`` through ``_heads`` / ``_tiles``.
 """
 
 from __future__ import annotations
@@ -44,6 +51,8 @@ from jax import Array, lax
 
 from finchat_tpu.models.quant import dense
 from finchat_tpu.models.ssm import SsmRows, _read, _to_packed, _to_rows, _write, causal_conv
+from finchat_tpu.ops.gdn_step import gdn_state_step
+from finchat_tpu.utils.metrics import METRICS
 
 _HIGHEST = lax.Precision.HIGHEST
 _L2_EPS = 1e-6
@@ -76,6 +85,20 @@ def init_params(c, key: Array, n: int, rand_init: Callable) -> dict[str, Array]:
 
 def _l2norm(x: Array) -> Array:
     return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + _L2_EPS)
+
+
+def _heads(tiles: Array, H: int) -> Array:
+    """The leaf's tiles ``[N, H/n, dk, n dv]`` as heads ``[N, H, dk, dv]``."""
+    N, T, dk, W = tiles.shape
+    n = H // T
+    return tiles.reshape(N, T, dk, n, W // n).swapaxes(2, 3).reshape(N, H, dk, W // n)
+
+
+def _tiles(state: Array, T: int) -> Array:
+    """Heads ``[N, H, dk, dv]`` as the leaf's ``T`` tiles ``[N, T, dk, n dv]``."""
+    N, H, dk, dv = state.shape
+    n = H // T
+    return state.reshape(N, T, n, dk, dv).swapaxes(2, 3).reshape(N, T, dk, n * dv)
 
 
 def _step(state, q, k, v, g, beta):
@@ -204,7 +227,7 @@ def _packed_scan(leaf: Array, layer_idx: Array, rows: SsmRows, q, k, v, g, beta
                  ) -> tuple[Array, Array]:
     """The ragged step's rows through the chunked form, from the packed
     buffer and back: q, k [T,H,dk]; v [T,H,dv]; g, beta [T,H]; ``leaf`` the
-    state ``[L,slots,H,dk,dv]``. Returns (o [T,H,dv], the leaf updated).
+    state ``[L,slots,H/n,dk,n dv]``. Returns (o [T,H,dv], the leaf updated).
 
     Rows are regrouped to ``[N, width]`` for the scan, which costs ``N x
     width`` whatever they hold; but of a buffer of T tokens at most ``T //
@@ -224,9 +247,9 @@ def _packed_scan(leaf: Array, layer_idx: Array, rows: SsmRows, q, k, v, g, beta
         grp = SsmRows(rows.slots[of], rows.n_valid[of], (q_start[of], None, None), width)
         live = (jnp.arange(width, dtype=jnp.int32)[None, :] < grp.n_valid[:, None])[..., None]
         qr, kr, vr, gr, br = (_to_rows(t, grp) for t in (q, k, v, g, beta))
-        og, state = _chunked(_read(leaf, layer_idx, grp), qr, kr, vr,
+        og, state = _chunked(_heads(_read(leaf, layer_idx, grp), q.shape[1]), qr, kr, vr,
                              jnp.where(live, gr, 0.0), jnp.where(live, br, 0.0), CHUNK)
-        leaf = _write(leaf, state, layer_idx, grp)
+        leaf = _write(leaf, _tiles(state, leaf.shape[2]), layer_idx, grp)
         og = og[jnp.clip(rank - lo, 0, hi - lo - 1), jnp.clip(tok_off, 0, width - 1)]
         o = og if o is None else jnp.where((rank < lo)[:, None, None], o, og)
     return o, leaf
@@ -251,7 +274,7 @@ def gated_head_norm(o: Array, gate: Array, weight: Array, eps: float) -> Array:
 def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
           rows: SsmRows | None, qm_backend: str | None = None) -> tuple[Array, Any]:
     """The layer's output for its input ``h`` [B,S,D] and the updated
-    ``cache`` (``(state [L,slots,H,dk,dv], conv tail [L,slots,K-1,C])``
+    ``cache`` (``(state [L,slots,H/n,dk,n dv], conv tail [L,slots,K-1,C])``
     float32, indexed by the layer's place among the LINEAR layers; or None:
     every row from zero, nothing kept)."""
     f32 = jnp.float32
@@ -290,15 +313,27 @@ def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
         else:
             live = (jnp.arange(S, dtype=jnp.int32)[None, :] < rows.n_valid[:, None])[..., None]
             g, beta = _gates(ba, lp, live, c.gdn_neg_eigval)
-            if cache is not None:
-                state = _read(cache[0], layer_idx, rows)
-            if S == 1:
-                o, state = _step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+            one_token = S == 1 and cache is not None and rows.backend != "ref"
+            if one_token and rows.slots is None:
+                # the decode step: every slot's state advances where it lies
+                o, state_leaf = gdn_state_step(
+                    cache[0], q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                    layer_idx.reshape(1), interpret=rows.backend == "pallas-interpret")
+                cache = (state_leaf, conv_state)
                 o = o[:, None]
             else:
-                o, state = _chunked(state, q, k, v, g, beta, CHUNK)
-            if cache is not None:
-                cache = (_write(cache[0], state, layer_idx, rows), conv_state)
+                if one_token:  # gathered slots: XLA's two passes over the state
+                    METRICS.inc("finchat_ssm_step_fallbacks_total")
+                if cache is not None:
+                    state = _heads(_read(cache[0], layer_idx, rows), H)
+                if S == 1:
+                    o, state = _step(state, q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0])
+                    o = o[:, None]
+                else:
+                    o, state = _chunked(state, q, k, v, g, beta, CHUNK)
+                if cache is not None:
+                    cache = (_write(cache[0], _tiles(state, cache[0].shape[2]), layer_idx, rows),
+                             conv_state)
     with jax.named_scope("gdn_norm"):
         y = gated_head_norm(o, gate.reshape(*o.shape), lp["gdn_norm"], c.norm_eps)
         y = y.reshape(*y.shape[:2], H * dv).astype(h.dtype)

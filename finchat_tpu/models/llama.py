@@ -202,10 +202,24 @@ class LlamaConfig:
 
     @property
     def state_shape(self) -> tuple[int, ...]:
-        """One slot's recurrent state in one layer (float32)."""
+        """One slot's recurrent state in one layer (float32). A LINEAR layer's
+        ``dk x dv`` matrices stand ``gdn_tile_heads`` side by side: tiles
+        ``[dk, n dv]`` (models/gdn.py has the views to and from heads)."""
         if self.gdn_heads:
-            return (self.gdn_heads, self.gdn_key_dim, self.gdn_value_dim)
+            n = self.gdn_tile_heads
+            return (self.gdn_heads // n, self.gdn_key_dim, n * self.gdn_value_dim)
         return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
+    def gdn_tile_heads(self) -> int:
+        """Heads whose matrices share a tile of the state: the fewest that
+        fill whole 128-lane tiles (Olmo-Hybrid: 2 x 192 = 3 x 128; a minor
+        dimension of 192 alone is padded to 256 in HBM, a third more bytes in
+        every pass over the state) and leave two tiles or more (the one-token
+        kernel works a row's tiles in two halves); one where none does."""
+        H, dv = self.gdn_heads, self.gdn_value_dim
+        fits = [n for n in range(1, H // 2 + 1) if H % n == 0 and n * dv % 128 == 0]
+        return fits[0] if fits else 1
 
     @property
     def conv_shape(self) -> tuple[int, ...]:

@@ -29,11 +29,19 @@ to arrive with P on sublanes: the wrapper hands it over as ``[P, H]`` columns
 (a few KiB a row, transposed by XLA), and ``y`` comes back the same way.
 ``exp(dt A)`` is one scalar a head and rides in SMEM with the layer. ``D xs``
 is added outside (no pass over the state needs it).
+
+Shared. The stream above — ``rows_per_block``, the DMAs, the two buffers, the
+halves, the aliasing, the layer as a scalar-prefetch operand — is
+``in_place_pass`` and ``in_place_call``, and ``ops/gdn_step.py`` (the gated
+delta rule's one-token update over ``[L, slots, tiles, dk, n dv]``) runs on
+the same two: one pipeline, two updates, each kernel bringing its
+``advance`` and the operands beside the state (ROADMAP D14).
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -54,31 +62,24 @@ def rows_per_block(rows: int, row_bytes: int) -> int:
                 if rows % d == 0 and d * row_bytes <= _BLOCK_BYTES] or [1])
 
 
-def _step_kernel(
-    # scalar prefetch
-    layer_ref,  # [1] int32
-    decay_ref,  # [N, H] float32 — exp(dt A); 1 for an inert row
-    # blocks (r rows a grid step)
-    dtx_ref,  # [r, P, H] — dt * xs, P on sublanes; 0 for an inert row
-    bc_ref,  # [r, G, 2, Ns] — each group's B row, then its C row
-    s_any,  # [L, N, H, P, Ns] ANY (aliased to o_any)
-    y_ref,  # [r, P, H]
-    o_any,  # the same buffer as s_any
-    # scratch
-    buf,  # [2, r, H, P, Ns] VMEM
-    sems,  # DMA semaphores [2 (in, out), 2 (buffer), 2 (half of the heads)]
-):
+def in_place_pass(layer_ref, s_any, o_any, buf, sems, advance):
+    """One grid step of the row-block pipeline that the one-token state
+    kernels share (this file's and ``ops/gdn_step.py``'s; they differ in
+    ``advance`` and in the operands beside the state). ``s_any`` and
+    ``o_any`` are the ONE carried buffer ``[L, N, tiles, ...]`` in HBM, ``buf``
+    two blocks of ``r`` rows in VMEM, ``sems`` DMA semaphores [2 (in, out), 2
+    (buffer), 2 (half of the tiles)]; ``advance(i, tiles)`` updates those
+    tiles of every row of block ``i`` where they lie in ``buf[i % 2]``."""
     i, steps = pl.program_id(0), pl.num_programs(0)
     layer = layer_ref[0]
-    r, G = bc_ref.shape[:2]
-    H = buf.shape[2]
+    r, tiles = buf.shape[1:3]
 
-    halves = (range(H // 2), range(H // 2, H))
+    halves = (range(tiles // 2), range(tiles // 2, tiles))
 
     def copy(out: bool, block, half: int):
-        slot, heads = block % 2, pl.ds(halves[half].start, len(halves[half]))
-        hbm = (o_any if out else s_any).at[layer, pl.ds(block * r, r), heads]
-        vmem = buf.at[slot, :, heads]
+        slot, part = block % 2, pl.ds(halves[half].start, len(halves[half]))
+        hbm = (o_any if out else s_any).at[layer, pl.ds(block * r, r), part]
+        vmem = buf.at[slot, :, part]
         src, dst = (vmem, hbm) if out else (hbm, vmem)
         return pltpu.make_async_copy(src, dst, sems.at[int(out), slot, half])
 
@@ -90,17 +91,6 @@ def _step_kernel(
         copy(out, block, 0).wait()
         copy(out, block, 1).wait()
 
-    def advance(heads):
-        slot = i % 2
-        for row in range(r):
-            for h in heads:
-                g = h // (H // G)
-                new = (decay_ref[i * r + row, h] * buf[slot, row, h]
-                       + dtx_ref[row, :, h:h + 1] * bc_ref[row, g, 0:1, :])
-                buf[slot, row, h] = new
-                y_ref[row, :, h:h + 1] = jnp.sum(
-                    new * bc_ref[row, g, 1:2, :], axis=-1, keepdims=True)
-
     first, last = i == 0, i + 1 == steps
 
     @pl.when(first)
@@ -110,7 +100,7 @@ def _step_kernel(
 
     # block i's first half is in VMEM; behind it block i-1 goes out or, in
     # the first step, block i's second half comes in
-    advance(halves[0])
+    advance(i, halves[0])
 
     @pl.when(first)
     def _():
@@ -128,7 +118,7 @@ def _step_kernel(
     def _():
         copy(True, i, 0).start()
 
-    advance(halves[1])
+    advance(i, halves[1])
 
     @pl.when(jnp.logical_not(last))
     def _():
@@ -139,6 +129,77 @@ def _step_kernel(
     def _():
         copy(True, i, 1).start()
         wait(True, i)
+
+
+def in_place_call(kernel, state: Array, layer: Array, scalars: list[Array],
+                  blocks: list[Array], out: jax.ShapeDtypeStruct, *, interpret: bool):
+    """The ``pallas_call`` around ``in_place_pass``: ``state`` ``[L, N, tiles,
+    ...]`` stays in HBM and comes back in its own buffer
+    (``input_output_aliases``), ``scalars`` ride in SMEM behind the layer,
+    ``blocks`` ``[N, ...]`` come in and ``out`` ``[N, ...]`` goes back
+    ``rows_per_block`` rows a grid step. ``kernel`` takes ``(layer, *scalars,
+    *blocks, state, out, state, buf, sems)``. Returns ``(out, state)``."""
+    N = state.shape[1]
+    row_bytes = math.prod(state.shape[2:]) * state.dtype.itemsize
+    r = rows_per_block(N, row_bytes)
+
+    def rows(x):
+        return pl.BlockSpec((r, *x.shape[1:]), lambda i, *_: (i,) + (0,) * (len(x.shape) - 1))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1 + len(scalars),
+        grid=(N // r,),
+        in_specs=[*map(rows, blocks), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[rows(out), pl.BlockSpec(memory_space=pl.ANY)],
+        scratch_shapes=[
+            pltpu.VMEM((2, r, *state.shape[2:]), state.dtype),
+            pltpu.SemaphoreType.DMA((2, 2, 2)),
+        ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[out, jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        # flattened operands: the scalar prefetch, the blocks, then the state
+        input_output_aliases={1 + len(scalars) + len(blocks): 1},
+        compiler_params=pltpu.CompilerParams(
+            # a step's DMAs are started in the step before it
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * r * row_bytes + 8 * 1024 * 1024,
+        ),
+        interpret=interpret,
+    )(jnp.asarray(layer, jnp.int32), *scalars, *blocks, state)
+
+
+def _step_kernel(
+    # scalar prefetch
+    layer_ref,  # [1] int32
+    decay_ref,  # [N, H] float32 — exp(dt A); 1 for an inert row
+    # blocks (r rows a grid step)
+    dtx_ref,  # [r, P, H] — dt * xs, P on sublanes; 0 for an inert row
+    bc_ref,  # [r, G, 2, Ns] — each group's B row, then its C row
+    s_any,  # [L, N, H, P, Ns] ANY (aliased to o_any)
+    y_ref,  # [r, P, H]
+    o_any,  # the same buffer as s_any
+    # scratch
+    buf,  # [2, r, H, P, Ns] VMEM
+    sems,
+):
+    r, G = bc_ref.shape[:2]
+    H = buf.shape[2]
+
+    def advance(i, heads):
+        slot = i % 2
+        for row in range(r):
+            for h in heads:
+                g = h // (H // G)
+                new = (decay_ref[i * r + row, h] * buf[slot, row, h]
+                       + dtx_ref[row, :, h:h + 1] * bc_ref[row, g, 0:1, :])
+                buf[slot, row, h] = new
+                y_ref[row, :, h:h + 1] = jnp.sum(
+                    new * bc_ref[row, g, 1:2, :], axis=-1, keepdims=True)
+
+    in_place_pass(layer_ref, s_any, o_any, buf, sems, advance)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",), donate_argnums=(0,))
@@ -156,47 +217,12 @@ def ssm_state_step(
 ) -> tuple[Array, Array]:
     """Advance layer ``layer``'s state of every slot by one token, in place.
     Returns ``(y [N, H, P], ssm_state)`` (the state aliased to its input)."""
-    L, N, H, P, Ns = ssm_state.shape
-    G = Bm.shape[1]
+    _L, N, H, P, _Ns = ssm_state.shape
     f32 = jnp.float32
-    row_bytes = H * P * Ns * 4
-    r = rows_per_block(N, row_bytes)
-
     decay = jnp.exp(dt * A[None, :]).astype(f32)
     dtx = (dt[..., None] * xs).astype(f32).transpose(0, 2, 1)  # [N, P, H]
     bc = jnp.stack([Bm, Cm], axis=2).astype(f32)  # [N, G, 2, Ns]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(N // r,),
-        in_specs=[
-            pl.BlockSpec((r, P, H), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec((r, G, 2, Ns), lambda i, *_: (i, 0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=[
-            pl.BlockSpec((r, P, H), lambda i, *_: (i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((2, r, H, P, Ns), f32),
-            pltpu.SemaphoreType.DMA((2, 2, 2)),
-        ],
-    )
-    y, ssm_state = pl.pallas_call(
-        _step_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((N, P, H), f32),
-            jax.ShapeDtypeStruct(ssm_state.shape, ssm_state.dtype),
-        ],
-        # flattened operands: 2 scalar-prefetch, dtx, bc, then the state
-        input_output_aliases={4: 1},
-        compiler_params=pltpu.CompilerParams(
-            # a step's DMAs are started in the step before it
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=2 * r * row_bytes + 8 * 1024 * 1024,
-        ),
-        interpret=interpret,
-    )(jnp.asarray(layer, jnp.int32), decay, dtx, bc, ssm_state)
+    y, ssm_state = in_place_call(
+        _step_kernel, ssm_state, layer, [decay], [dtx, bc],
+        jax.ShapeDtypeStruct((N, P, H), f32), interpret=interpret)
     return y.transpose(0, 2, 1) + D[None, :, None] * xs, ssm_state

@@ -196,9 +196,13 @@ def test_olmo_hybrid_decode_step_compiles_for_v5e_with_pool_and_state_in_place(o
     kernels): the pool has the FULL layers' depth and the recurrent state the
     LINEAR layers', both are donated and updated in place, and a period's
     layers stand one after another in the ONE scan's body (no loop inside
-    it: PERF.md section 6, PR 32) — three in-place updates of the carried
-    state under ``gdn_scan``, a period's, which is what
-    ``gdn_state_roofline.sat`` times and the adapter counts."""
+    it: PERF.md section 6, PR 32). The state is 15 tiles of 96 x 384 a row —
+    two heads side by side, no padded lane — and its one-token update is
+    ``ops/gdn_step.py``'s kernel (Mosaic's layout rules for its [rows, 15, 96,
+    384] float32 blocks are checked by this compile): three custom calls
+    under ``gdn_scan``, a period's, which is what ``gdn_state_roofline.sat``
+    times and the adapter counts, and no fusion under that scope touches the
+    carried state."""
     from perfbench.models import olmo_hybrid
 
     file = _config_file("olmo-hybrid-7b")
@@ -206,7 +210,7 @@ def test_olmo_hybrid_decode_step_compiles_for_v5e_with_pool_and_state_in_place(o
         one_chip, olmo_hybrid,
         dict(file, num_hidden_layers=8, layer_types=file["layer_types"][:4] * 2))
     assert state.k_pages.shape == (2, POOL, PAGE, 30 * HEAD_DIM)
-    assert state.ssm_state.shape == (6, ROWS, 30, 96, 192)
+    assert state.ssm_state.shape == (6, ROWS, 15, 96, 384)
     assert state.conv_state.shape == (6, ROWS, 3, 11520)
     memory = compiled.memory_analysis()
     state_bytes = 6 * ROWS * 30 * 96 * 192 * 4
@@ -221,12 +225,16 @@ def test_olmo_hybrid_decode_step_compiles_for_v5e_with_pool_and_state_in_place(o
                  if f"/{scope}/" in line and " = " in line
                  and 'custom_call_target="tpu_custom_call"' in line]
         assert len(calls) == 1 and name in calls[0], (scope, calls)
-    # the carried state is written three times in the program: a period's
-    # linear layers, each in place
-    updates = [line for line in text.splitlines()
-               if "/gdn_scan/" in line and re.match(r"\s*(?:ROOT )?%[\w.-]+ = f32\[6,16,30,96,192\]", line)
-               and " fusion(" in line]
-    assert len(updates) == 3, updates
+    # the carried state is advanced three times in the program: a period's
+    # linear layers, each by the kernel, in place
+    scan = [line for line in text.splitlines()
+            if "/gdn_scan/" in line and " = " in line and "op_name=" in line]
+    kernels = [line.split(" = ")[0] for line in scan
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3 and all("gdn_state_step" in name for name in kernels), kernels
+    assert not any("attention" in name for name in kernels)  # attn_share.sat takes calls by name
+    fusions = [line for line in scan if " fusion(" in line and "f32[6,16,15,96,384]" in line]
+    assert fusions == [], fusions
     assert text.count(" while(") == 1  # the scan over periods and no other loop
 
 
