@@ -57,7 +57,9 @@ from finchat_tpu.engine.kv_cache import (
 )
 from finchat_tpu.engine.sampler import sample
 from finchat_tpu.models.llama import LlamaConfig, forward, lm_head
+from finchat_tpu.models.mla import LatentInputs
 from finchat_tpu.models.ssm import SsmRows
+from finchat_tpu.ops.latent_attention import LatentShape
 from finchat_tpu.ops.dispatch import paged_attention
 from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.logging import get_logger
@@ -94,7 +96,10 @@ class DecodeState:
     stops being referenced. All zeros reduces every compacted expression
     to the legacy absolute one bit-for-bit."""
 
-    k_pages: Array  # [L, P, page_size, Hkv*hd] (model dtype, or int8)
+    # [L, P, page_size, Hkv*hd] (model dtype, or int8); for a model with
+    # latent attention (config.kv_row_widths) the first array holds the latent
+    # rows [.., latent_row] and the second the indexer's key rows [.., Di]
+    k_pages: Array
     v_pages: Array
     k_scales: Array  # [L, P, scale_rows, page_size] fp32 (or (1,1,1,1))
     v_scales: Array
@@ -191,12 +196,50 @@ def _ssm_read_slot(ssm_state: Array, conv_state: Array, slot: Array):
             jax.lax.dynamic_index_in_dim(conv_state, slot, 1, keepdims=False))
 
 
+def _latent_shape(config: LlamaConfig) -> LatentShape | None:
+    """What the attention callbacks of a model with latent attention need of
+    its config (None for every other model)."""
+    if not config.kv_lora_rank:
+        return None
+    return LatentShape(config.kv_lora_rank, config.index_topk,
+                       config.attention_scale or config.head_dim ** -0.5)
+
+
+def _latent_attention(write, page_rows: Array, start: Array, n_valid: Array,
+                      page_size: int, latent: LatentShape, rows: SsmRows | None = None):
+    """The ``LatentAttentionFn`` of a step: ``write(row, idx_k, cache,
+    layer_idx)`` puts the chunk's rows into the pool; then row ``n``'s
+    ``n_valid[n]`` queries, at the compacted positions ``start[n] ..``, attend
+    over its pages ``page_rows[n]``. With ``rows`` the tokens arrive PACKED
+    ``[1, T]`` (the ragged step): row ``n``'s lie from ``rows.pack[0][n]`` on."""
+    from finchat_tpu.ops.latent_attention import packed_attention, rows_attention
+
+    def attention(x: LatentInputs, cache: Any, layer_idx: Array):
+        idx_k = x.idx_k if x.idx_k is not None else jnp.zeros((*x.row.shape[:2], 1), x.row.dtype)
+        cache = write(x.row, idx_k, cache, layer_idx)
+        kw = dict(page_size=page_size, shape=latent)
+        layer = layer_idx.reshape(())
+        if rows is None:
+            out, selected = rows_attention(x.q, x.idx_q, x.idx_w, cache[0], cache[1], layer,
+                                           page_rows, start, n_valid, **kw)
+        else:
+            out, selected = packed_attention(
+                *(None if a is None else a[0] for a in (x.q, x.idx_q, x.idx_w)),
+                cache[0], cache[1], layer, page_rows, rows.pack[0], start, n_valid,
+                width=rows.width, **kw)
+            out = out[None]
+        return out, cache, selected
+
+    return attention
+
+
 def _paged_attention_fn(
     page_table: Array, start_pos: Array, n_valid: Array,
     page_size: int, n_kv: int, attn_backend: str,
     inplace_append: bool = False,
     decode: bool = False,
     scale: float | None = None,
+    latent: LatentShape | None = None,
 ):
     """Build the model's attention callback for paged prefill/decode.
 
@@ -219,8 +262,32 @@ def _paged_attention_fn(
 
     ``scale``: the model's softmax scale (``LlamaConfig.attention_scale``;
     None = head_dim ** -0.5), handed to the kernel as it is.
+
+    ``latent`` (a model with latent attention, ``_latent_shape``): the
+    callback is a ``LatentAttentionFn`` (models/mla.py) — it writes the
+    token's latent row into the first paged array and its index key into the
+    second, the same append or scatter, and attends by
+    ops/latent_attention.py over the indexer's selection.
     """
     interpret = attn_backend == "pallas-interpret"
+    if latent is not None:
+        def write(row: Array, idx_k: Array, cache: Any, layer_idx: Array):
+            k_pages, v_pages, k_scales, v_scales = cache
+            B, C = row.shape[:2]
+            if C == 1 and attn_backend != "ref":
+                from finchat_tpu.ops.kv_append import paged_kv_append
+
+                with jax.named_scope("kv_append"):
+                    k_pages, v_pages = paged_kv_append(
+                        jnp.concatenate([row, idx_k], axis=-1), k_pages, v_pages, page_table,
+                        start_pos, n_valid, layer_idx.reshape(1), page_size=page_size,
+                        interpret=interpret)
+                return k_pages, v_pages, k_scales, v_scales
+            with jax.named_scope("kv_scatter"):
+                return _scatter_kv(cache, row[:, :, None], idx_k[:, :, None], page_table,
+                                   start_pos, n_valid, page_size, layer_idx, 1)
+
+        return _latent_attention(write, page_table, start_pos, n_valid, page_size, latent)
     shared = None
     if decode and attn_backend != "ref":
         from finchat_tpu.ops.paged_attention import shared_head
@@ -307,6 +374,7 @@ def prefill_step(
     attention = _paged_attention_fn(
         page_rows, start_pos - state.kv_gaps[slots], n_valid,
         page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
+        latent=_latent_shape(config),
     )
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
@@ -615,6 +683,7 @@ def decode_step(
     attention = _paged_attention_fn(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
         page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
+        latent=_latent_shape(config),
     )
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
@@ -624,7 +693,7 @@ def decode_step(
         config=config, attention=attention,
         ssm_rows=SsmRows(None, n_valid, backend=attn_backend),
         qm_backend=qm_backend, moe_backend=attn_backend,
-        **({"moe_live": active[:, None]} if config.moe_sparse else {}),
+        **({"moe_live": active[:, None]} if config.moe_sparse or config.kv_lora_rank else {}),
     )
     step_logits = logits[:, 0, :]  # [B, vocab]
 
@@ -652,6 +721,8 @@ def _ragged_attention_fn(
     attn_backend: str,
     row_gap: Array | None = None,  # [R] int32 — bounded-KV eviction gap
     scale: float | None = None,  # the model's softmax scale (None = D ** -0.5)
+    latent: LatentShape | None = None,  # a model with latent attention ...
+    rows: SsmRows | None = None,  # ... and how its packed tokens lie in rows
 ):
     """Attention callback for the packed ragged step (``ragged_mixed_step``):
     per-token KV writes through the chunk scatter (one full-cache copy per
@@ -680,6 +751,16 @@ def _ragged_attention_fn(
         # (the scheduler's eviction/restore invariant), so the uniform
         # subtraction is exact; the clamp only guards padding tokens
         tok_wpos = jnp.maximum(tok_pos - row_gap[safe_row], 0)
+    if latent is not None:
+        def write(row: Array, idx_k: Array, cache: Any, layer_idx: Array):
+            with jax.named_scope("kv_scatter_ragged"):
+                return _scatter_kv(cache, row[0][:, None, None], idx_k[0][:, None, None],
+                                   pt_tok, tok_wpos, n_valid_tok, page_size, layer_idx, 1)
+
+        # a row's compacted start: its first packed token's write position
+        start = tok_wpos[jnp.minimum(rows.pack[0], tok_wpos.shape[0] - 1)]
+        return _latent_attention(write, page_rows, start, rows.n_valid, page_size, latent,
+                                 rows=rows)
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
         k_pages, v_pages, k_scales, v_scales = cache
@@ -782,19 +863,26 @@ def _ragged_round_math(
     row_kv_len = jnp.where(row_len > 0, eff_start + row_len, 0)  # [R]
     row_gap = state.kv_gaps[row_slot]  # [R] — bounded-KV compaction offset
 
+    # a mixer's conv and scan must not run across a row boundary: the packed
+    # tokens are regrouped to [R, row_width] rows, each from its slot's state
+    # (a dead or padding row rides inert), and its last state goes back there.
+    # Latent attention regroups its queries the same way (a row's chunk shares
+    # the row's pages and walks them once)
+    def packed_rows() -> SsmRows:
+        return SsmRows(
+            row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
+            width=min(T, max_row_tokens or T), backend=attn_backend,
+        )
+
+    latent = _latent_shape(config)
     attention = _ragged_attention_fn(
         page_rows, tok_row, tok_pos, row_kv_len, tok_valid,
         page_size, config.n_kv_heads, attn_backend, row_gap=row_gap, scale=config.attention_scale,
+        latent=latent, rows=packed_rows() if latent is not None else None,
     )
+    ssm_rows = packed_rows() if config.has_state else None
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes
-    # a mixer's conv and scan must not run across a row boundary: the packed
-    # tokens are regrouped to [R, row_width] rows, each from its slot's state
-    # (a dead or padding row rides inert), and its last state goes back there
-    ssm_rows = SsmRows(
-        row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
-        width=min(T, max_row_tokens or T), backend=attn_backend,
-    ) if config.has_state else None
     hidden, state = _forward_cached(
         params, state, tok_in[None], tok_pos[None],
         config=config, attention=attention, ssm_rows=ssm_rows,
@@ -1376,6 +1464,8 @@ class InferenceEngine:
             self._refuse_without_state_carry(mesh)
         if config.moe_fused_glu:
             self._refuse_with_held_experts(mesh, quant)
+        if config.kv_lora_rank:
+            self._refuse_with_latent_cache(mesh, quant)
         state = create_state(config, engine_cfg, self.max_pages_per_seq, kv_quant=kv_quant)
         if mesh is not None:
             # TP placement: params sharded Megatron-style, KV pages sharded
@@ -1443,6 +1533,29 @@ class InferenceEngine:
             raise ValueError(
                 f"model.quant={quant!r} is not supported for a model with fused-GLU expert "
                 "stacks: the grouped matmul (lax.ragged_dot) takes no quantized operand")
+
+    def _refuse_with_latent_cache(self, mesh, quant: str) -> None:
+        """A model with latent attention (``config.kv_lora_rank``) keeps a
+        latent row and an index key a token, written and read by
+        ``prefill_step``, ``decode_step`` and ``ragged_mixed_step`` alone;
+        every option that reaches another step or another page layout is
+        refused here by name — none may run and be silently wrong."""
+        cfg = self.engine_cfg
+        refused = {
+            "engine.kv_quant": bool(cfg.kv_quant),  # int8 pages: a scale a KV head
+            "model.quant": bool(quant),  # no quantized form of the absorbed projections
+            "engine.spec_tokens": cfg.spec_tokens > 0,  # verify_step attends K/V heads
+            "engine.decode_loop_depth": self.decode_loop_depth > 1,
+            "engine.freerun_rounds": self.freerun_rounds > 1,
+            "engine.kv_sink_pages / engine.kv_window_pages": self.bounded_kv is not None,
+            "mesh.* > 1": mesh is not None and mesh.devices.size > 1,
+        }
+        named = [option for option, on in refused.items() if on]
+        if named:
+            raise ValueError(
+                "a model with latent attention (a latent row and an index key a token in "
+                "the page pool) is served by prefill_step, decode_step and ragged_mixed_step "
+                f"only; not supported with it: {', '.join(named)}")
 
     @property
     def ssm_state_bytes(self) -> int:
@@ -2013,6 +2126,16 @@ class InferenceEngine:
                     if pb >= top_pb:
                         break
                     pb = min(pb * 2, top_pb)
+        if (self.engine_cfg.session_cache and self.engine_cfg.session_cache_bytes > 0
+                and not self.config.has_state):
+            # the session tier's offload at a row's end is an eager take shaped
+            # by its page count (kv_cache.gather_pages_host): every bucket once
+            from finchat_tpu.engine.kv_cache import TRASH_PAGE, gather_bucket
+
+            n = 1
+            while n <= gather_bucket(self.max_pages_per_seq):
+                self.offload_pages([TRASH_PAGE] * n)
+                n *= 2
         np.asarray(self.state.context_lens)  # barrier: compilation done
         elapsed = time.perf_counter() - t0
         # recorded for the warmup-matrix-collapse observability (ISSUE 10):
@@ -2098,10 +2221,11 @@ class InferenceEngine:
         return emitted, n_emitted, row_logits, loop_block
 
     def _ragged_kw(self) -> dict:
-        """What only a model with a mixer passes to ``ragged_mixed_step``
+        """What only a model with a mixer or with latent attention (both
+        regroup a round's packed tokens to rows) passes to ``ragged_mixed_step``
         (jit keys on the keywords a call passes: the others' calls stay as
         warm-up compiled them): no row of a round is longer than a chunk."""
-        if not self.config.has_state:
+        if not (self.config.has_state or self.config.kv_lora_rank):
             return {}
         return {"max_row_tokens": self.engine_cfg.prefill_chunk}
 

@@ -65,7 +65,9 @@ class PagedKVCache:
     When off, the scale leaves are kept as (1,1,1,1) placeholders so the
     engine state pytree structure is identical in both modes."""
 
-    k_pages: Any  # [L, P, page_size, Hkv * head_dim] (dtype or int8)
+    # [L, P, page_size, Hkv * head_dim] (dtype or int8); for latent attention
+    # the latent rows [.., latent_row] and the indexer's key rows [.., Di]
+    k_pages: Any
     v_pages: Any
     k_scales: Any  # [L, P, scale_rows(Hkv), page_size] fp32 (or (1,1,1,1))
     v_scales: Any
@@ -77,14 +79,17 @@ class PagedKVCache:
     def create(cls, config: LlamaConfig, num_pages: int, page_size: int,
                kv_quant: str = "") -> "PagedKVCache":
         # the pool has the depth of the layers that own pages: every layer,
-        # or a layer pattern's full-attention layers alone
-        shape = (
-            config.n_attn_layers, num_pages, page_size,
-            config.n_kv_heads * config.head_dim,
-        )
+        # or a layer pattern's full-attention layers alone; a row's width in
+        # each of the two arrays is the model's (``kv_row_widths``: K and V
+        # heads, or a latent row and the indexer's key row)
+        k_row, v_row = config.kv_row_widths
+        shape = (config.n_attn_layers, num_pages, page_size, k_row)
         if kv_quant:
             if kv_quant != "int8":
                 raise ValueError(f"unknown kv_quant mode {kv_quant!r} (supported: 'int8')")
+            if config.kv_lora_rank:
+                raise ValueError("kv_quant is not supported with latent attention: the int8 "
+                                 "pages keep a scale a KV head, and a latent row has none")
             sshape = (shape[0], num_pages, scale_rows(config.n_kv_heads), page_size)
             return cls(
                 k_pages=jnp.zeros(shape, jnp.int8),
@@ -95,7 +100,7 @@ class PagedKVCache:
             )
         return cls(
             k_pages=jnp.zeros(shape, config.dtype),
-            v_pages=jnp.zeros(shape, config.dtype),
+            v_pages=jnp.zeros((*shape[:3], v_row), config.dtype),
             k_scales=jnp.zeros((1, 1, 1, 1), jnp.float32),
             v_scales=jnp.zeros((1, 1, 1, 1), jnp.float32),
             page_size=page_size, num_pages=num_pages,
@@ -120,9 +125,8 @@ def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "") -> i
     tests/test_kv_cache.py)."""
     import numpy as np
 
-    row = config.n_kv_heads * config.head_dim
     itemsize = 1 if kv_quant else np.dtype(config.dtype).itemsize
-    per = 2 * config.n_attn_layers * page_size * row * itemsize
+    per = config.n_attn_layers * page_size * sum(config.kv_row_widths) * itemsize
     if kv_quant:
         per += 2 * config.n_attn_layers * scale_rows(config.n_kv_heads) * page_size * 4
     return per
@@ -354,7 +358,7 @@ def scatter_kv_chunk(
     flat_phys = phys.reshape(-1)  # [B*C]
     flat_off = offset.reshape(-1)
     k_flat = k_new.reshape(B * C, hd_fused)  # token rows, heads fused
-    v_flat = v_new.reshape(B * C, hd_fused)
+    v_flat = v_new.reshape(B * C, v_pages.shape[-1])
     k_pages = k_pages.at[lay, flat_phys, flat_off].set(k_flat, mode="drop")
     v_pages = v_pages.at[lay, flat_phys, flat_off].set(v_flat, mode="drop")
     return k_pages, v_pages
@@ -379,15 +383,27 @@ def gather_pages_host(
     writes. Per-turn cost, never on the per-token hot path."""
     import numpy as np
 
-    ids = jnp.asarray(page_ids, jnp.int32)
+    # the eager take is compiled by its shape: the page count is padded to a
+    # power of two (the trash page repeated, dropped again on the host), so a
+    # process meets log2(max_pages) + 1 shapes — which ``InferenceEngine.warmup``
+    # runs once — and not one for every context length that ends: at a
+    # vocabulary of 16k a window samples six EOS, each a page count no earlier
+    # one had, and each cost 2.5 s of compiling on the loop (PERF.md section 6,
+    # PR 40)
+    n = len(page_ids)
+    ids = jnp.asarray([*page_ids, *[TRASH_PAGE] * (gather_bucket(n) - n)], jnp.int32)
+
+    def take(pages):  # a copy of the rows asked for: the padding goes with the buffer
+        return np.ascontiguousarray(np.asarray(jax.device_get(jnp.take(pages, ids, axis=1)))[:, :n])
+
     quantized = k_pages.dtype == jnp.int8
-    k = np.asarray(jax.device_get(jnp.take(k_pages, ids, axis=1)))
-    v = np.asarray(jax.device_get(jnp.take(v_pages, ids, axis=1)))
-    ks = vs = None
-    if quantized:
-        ks = np.asarray(jax.device_get(jnp.take(k_scales, ids, axis=1)))
-        vs = np.asarray(jax.device_get(jnp.take(v_scales, ids, axis=1)))
-    return k, v, ks, vs
+    return (take(k_pages), take(v_pages),
+            take(k_scales) if quantized else None, take(v_scales) if quantized else None)
+
+
+def gather_bucket(n_pages: int) -> int:
+    """The page count ``gather_pages_host`` pads ``n_pages`` to."""
+    return 1 << max(n_pages - 1, 0).bit_length()
 
 
 def scatter_pages_device(

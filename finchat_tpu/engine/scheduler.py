@@ -626,6 +626,26 @@ class ContinuousBatchingScheduler:
             self.metrics.inc("finchat_moe_experts_touched_total", 0.0)
             self.metrics.inc("finchat_moe_experts_read_total", 0.0)
             self.metrics.inc("finchat_moe_layer_steps_total", 0.0)
+        # a model with latent attention (models/mla.py): the step also counts
+        # the context tokens its live rows attended to, over the layers (the
+        # indexer's selection); the pool's two arrays hold other things than
+        # K and V, so their bytes are said by array
+        self._round_selected: int | None = None
+        latent = bool(engine.config.kv_lora_rank)
+        if latent:
+            self.metrics.inc("finchat_dsa_selected_tokens_total", 0.0)
+            self.metrics.inc("finchat_dsa_row_layer_steps_total", 0.0)
+            if fabric is not None or getattr(cfg, "session_cache_disk_path", ""):
+                raise ValueError(
+                    "fabric.path / engine.session_cache_disk_path: the warm fabric's and the "
+                    "session tier's disk records have not carried a latent model's pages "
+                    "(a latent row and an index key a token); only the RAM session tier has")
+        state = getattr(engine, "state", None)  # a test's stand-in engine has none
+        for array, leaf in zip(("latent", "index_keys") if latent else ("k", "v"),
+                               ("k_pages", "v_pages")):
+            self.metrics.set_gauge("finchat_kv_pool_bytes",
+                                   getattr(getattr(state, leaf, None), "nbytes", 0),
+                                   labels={"array": array})
         # disaggregated serving (serve/disagg.py — ISSUE 17): the fleet
         # attaches its DisaggCoordinator to SERVING-pool schedulers only;
         # submit routes cold prompt prefills through it when set
@@ -3874,12 +3894,18 @@ class ContinuousBatchingScheduler:
         )
         with TRACER.phase("deliver", self._phases):
             if experts is not None:
-                touched, read = (int(count) for count in experts)
-                self.metrics.inc("finchat_moe_experts_touched_total", touched)
-                self.metrics.inc("finchat_moe_experts_read_total", read)
-                self.metrics.inc("finchat_moe_layer_steps_total",
-                                 self.engine.config.n_layers)
-                self._round_moe_experts = touched, read
+                touched, read, *selected = (int(count) for count in experts)
+                if self.engine.config.moe_sparse:
+                    self.metrics.inc("finchat_moe_experts_touched_total", touched)
+                    self.metrics.inc("finchat_moe_experts_read_total", read)
+                    self.metrics.inc("finchat_moe_layer_steps_total",  # the layers that route
+                                     self.engine.config.n_scan_layers)
+                    self._round_moe_experts = touched, read
+                if selected:  # a model with latent attention
+                    self.metrics.inc("finchat_dsa_selected_tokens_total", selected[0])
+                    self.metrics.inc("finchat_dsa_row_layer_steps_total",
+                                     len(step.members) * self.engine.config.n_layers)
+                    self._round_selected = selected[0]
             for slot, handle, epoch in step.members:
                 if handle.finished or handle.slot != slot or handle.epoch != epoch:
                     continue  # evicted/cancelled/preempted since dispatch
@@ -3970,6 +3996,7 @@ class ContinuousBatchingScheduler:
         started, now = base.started, base.ended
         total = now - started
         experts, self._round_moe_experts = self._round_moe_experts, None
+        selected, self._round_selected = self._round_selected, None
         compiled, frozen = TRACER.serving_compile_s, TRACER.frozen_s
         compile_s, frozen_s = compiled - self._seen_compile_s, frozen - self._seen_frozen_s
         self._seen_compile_s, self._seen_frozen_s = compiled, frozen
@@ -3977,6 +4004,8 @@ class ContinuousBatchingScheduler:
             args = {**phases, "kind": kind, "n": self._dispatch_tally}
             if experts is not None:  # of the decode step this round delivered
                 args["experts_touched"], args["experts_read"] = experts
+            if selected is not None:
+                args["selected_tokens"] = selected
             if compile_s:
                 args["compile_s"] = compile_s
             if frozen_s:

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array, lax
 
-from finchat_tpu.models import gdn
+from finchat_tpu.models import gdn, mla
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
 from finchat_tpu.models.ssm import mixer, scaled
 from finchat_tpu.ops import moe_step
@@ -135,10 +135,80 @@ class LlamaConfig:
     gdn_value_dim: int = 0  # a head's values
     gdn_conv: int = 4  # width of the causal depthwise conv over q, k, v
     gdn_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues allowed
+    # latent attention (models/mla.py): q and kv go through low-rank latents
+    # with an RMSNorm on each; ONE row a token, [c_kv | k_rope], is key and
+    # value at once for every head, and it is what the pool's pages hold.
+    # kv_lora_rank 0 = none: today's block. ``head_dim`` is then a head's
+    # q/k width, qk_nope_dim + qk_rope_dim, and ``n_kv_heads`` 1
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0  # the rotated part of a head's q and of the shared key
+    v_head_dim: int = 0
+    # YaRN's per-frequency correction of the rotation (models/mla.py
+    # ``RopeScaling``); None = plain frequencies
+    rope_scaling: Any = None
+    # the indexer beside latent attention: ``index_heads`` query heads of
+    # ``index_head_dim`` score every context token against ONE key row a token
+    # (a second paged array on the same page table), and a query attends to
+    # its ``index_topk`` best-scored tokens only — the exact k largest.
+    # index_topk 0 = none: every token of the context is attended
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    # the router's rule (moe_mlp), as data: "softmax" = the k largest logits,
+    # gates the softmax over them (Mixtral, Granite); "sigmoid" = scores
+    # sigmoid(logit), picks by score + a selection bias that chooses and does
+    # not weigh (leaf ``router_bias`` [L, R] float32, with ``moe_select_bias``),
+    # limited to the ``moe_topk_groups`` best of ``moe_groups`` groups (a
+    # group's score the sum of its 2 largest; 0 = no groups), gates the picked
+    # SCORES, over their sum with ``moe_norm_picks``, times ``moe_gate_scale``
+    moe_score: str = "softmax"
+    moe_select_bias: bool = False
+    moe_groups: int = 0
+    moe_topk_groups: int = 0
+    moe_gate_scale: float = 1.0
+    moe_norm_picks: bool = True
+    # dense layers in front of the routed ones (an MLP of ``dense_hidden_dim``
+    # in the experts' place): stacks of their own, ``params["dense_layers"]``,
+    # run before the scan; ``n_layers`` counts them
+    leading_dense_layers: int = 0
+    dense_hidden_dim: int = 0
 
     def __post_init__(self) -> None:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        if self.kv_lora_rank:
+            if self.head_dim != self.qk_nope_dim + self.qk_rope_dim or self.n_kv_heads != 1:
+                raise ValueError(
+                    "latent attention: head_dim is qk_nope_dim + qk_rope_dim and n_kv_heads 1 "
+                    "(one latent row a token serves every head)")
+            if not (self.q_lora_rank and self.v_head_dim and self.rope_theta is not None):
+                raise ValueError("latent attention comes with q_lora_rank, v_head_dim and a "
+                                 "rotation (rope_theta)")
+            if self.layer_pattern or self.ssm_heads or self.qk_norm or self.norm_after:
+                raise ValueError("latent attention is not combined with a layer_pattern, a "
+                                 "mixer, qk_norm or norm_after")
+        if bool(self.index_topk) != bool(self.index_heads and self.index_head_dim):
+            raise ValueError("index_topk, index_heads and index_head_dim go together")
+        if self.index_topk and not self.kv_lora_rank:
+            raise ValueError("the indexer's selection is latent attention's (kv_lora_rank)")
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score {self.moe_score!r}: 'softmax' or 'sigmoid'")
+        if self.moe_score == "softmax" and (
+                self.moe_select_bias or self.moe_groups or self.moe_gate_scale != 1.0):
+            raise ValueError("a selection bias, groups and a gate scale are the 'sigmoid' "
+                             "router's")
+        if self.moe_groups and (
+                (self.moe_router_width or self.n_experts) % self.moe_groups
+                or not 0 < self.moe_topk_groups <= self.moe_groups):
+            raise ValueError("moe_groups divides the router's width, and moe_topk_groups of "
+                             "them are kept")
+        if self.leading_dense_layers and (
+                self.layer_pattern or not self.dense_hidden_dim or not self.n_experts
+                or self.leading_dense_layers >= self.n_layers):
+            raise ValueError("leading_dense_layers: dense layers of dense_hidden_dim in front of "
+                             "routed ones, all of one kind (no layer_pattern)")
         pattern = self.layer_pattern
         if pattern:
             if set(pattern) - {FULL, LINEAR, MAMBA} or self.n_layers % len(pattern):
@@ -175,6 +245,30 @@ class LlamaConfig:
     def n_attn_layers(self) -> int:
         """Layers that own K/V pages: the depth of the page pool."""
         return self.n_of(FULL)
+
+    @property
+    def n_scan_layers(self) -> int:
+        """Layers in the scanned stacks (``params["layers"]``): all but the
+        leading dense ones."""
+        return self.n_layers - self.leading_dense_layers
+
+    @property
+    def latent_row(self) -> int:
+        """Columns of a token's latent row in its page, ``[c_kv | k_rope]``
+        padded to whole 128-lane tiles (576 -> 640: a minor dimension of 576
+        is padded so in HBM whatever the shape says)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
+
+    @property
+    def kv_row_widths(self) -> tuple[int, int]:
+        """Columns of a token's row in each of the pool's two paged arrays —
+        THE place a page's width is decided (``PagedKVCache.create`` and
+        ``page_hbm_bytes`` read it): K and V heads side by side, or for latent
+        attention the latent row in the first array and the indexer's key row
+        in the second (one column, never read, without an indexer)."""
+        if self.kv_lora_rank:
+            return self.latent_row, self.index_head_dim or 1
+        return (self.n_kv_heads * self.head_dim,) * 2
 
     @property
     def n_state_layers(self) -> int:
@@ -284,10 +378,14 @@ def n_params(config: LlamaConfig) -> int:
     c = config
     d, hd = c.dim, c.head_dim
     attn = d * (c.n_heads * hd) + 2 * d * (c.n_kv_heads * hd) + (c.n_heads * hd) * d
+    if c.kv_lora_rank:
+        attn = mla.n_attention_params(c)
     mlp = 3 * d * c.hidden_dim
     if c.n_experts:
-        # the held experts, the router at its whole width, the shared expert
-        mlp = mlp * c.n_experts + d * c.moe_router_width + 3 * d * c.moe_shared_dim
+        # the held experts, the router at its whole width (and its selection
+        # bias), the shared expert
+        mlp = (mlp * c.n_experts + (d + c.moe_select_bias) * c.moe_router_width
+               + 3 * d * c.moe_shared_dim)
     if c.qk_norm:
         attn += (c.n_heads + c.n_kv_heads) * hd
     per_layer = mlp + 2 * d
@@ -301,7 +399,8 @@ def n_params(config: LlamaConfig) -> int:
     d_v = c.gdn_heads * c.gdn_value_dim
     linear = (d * (c.gdn_conv_dim + d_v + 2 * c.gdn_heads) + d_v * d
               + c.gdn_conv * c.gdn_conv_dim + 2 * c.gdn_heads + c.gdn_value_dim)
-    total = (c.vocab_size * d + c.n_layers * per_layer + c.n_attn_layers * attn
+    total = (c.vocab_size * d + c.n_scan_layers * per_layer + c.n_attn_layers * attn
+             + c.leading_dense_layers * (3 * d * c.dense_hidden_dim + 2 * d)
              + c.n_of(LINEAR) * linear + c.n_of(MAMBA) * ssm + d)
     if not c.tie_embeddings:
         total += d * c.vocab_size
@@ -370,20 +469,40 @@ def init_params(
         return tf(name, (jax.random.normal(k, shape, gen_dtype) * fan_in ** -0.5).astype(c.dtype))
 
     keys = jax.random.split(k_layers, 8)
-    L, D, H, Hkv, hd, F = c.n_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
-    La = c.n_attn_layers
+    L, D, H, Hkv, hd, F = c.n_scan_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
+    La = c.n_attn_layers - c.leading_dense_layers
+
+    def attention_leaves(depth: int, ks: Array) -> dict[str, Array]:
+        if c.kv_lora_rank:
+            return mla.init_attention(c, ks[0], depth, rand_init)
+        return {
+            "attn_q": rand_init("attn_q", ks[0], (depth, D, H * hd), D),
+            "attn_k": rand_init("attn_k", ks[1], (depth, D, Hkv * hd), D),
+            "attn_v": rand_init("attn_v", ks[2], (depth, D, Hkv * hd), D),
+            "attn_o": rand_init("attn_o", ks[3], (depth, H * hd, D), H * hd),
+        }
+
     params: dict[str, Any] = {
         "embed": rand_init("embed", k_embed, (c.vocab_size, D), D),
         "layers": {
-            "attn_q": rand_init("attn_q", keys[0], (La, D, H * hd), D),
-            "attn_k": rand_init("attn_k", keys[1], (La, D, Hkv * hd), D),
-            "attn_v": rand_init("attn_v", keys[2], (La, D, Hkv * hd), D),
-            "attn_o": rand_init("attn_o", keys[3], (La, H * hd, D), H * hd),
+            **attention_leaves(La, keys),
             "ln_attn": jnp.ones((L, D), c.dtype),
             "ln_mlp": jnp.ones((L, D), c.dtype),
         },
         "norm": jnp.ones((D,), c.dtype),
     }
+    if c.leading_dense_layers:
+        # the dense layers in front: stacks of their own, keys of their own
+        Ld, Fd = c.leading_dense_layers, c.dense_hidden_dim
+        kd = jax.random.split(jax.random.fold_in(k_layers, 3), 7)
+        params["dense_layers"] = {
+            **attention_leaves(Ld, kd),
+            "ln_attn": jnp.ones((Ld, D), c.dtype),
+            "ln_mlp": jnp.ones((Ld, D), c.dtype),
+            "mlp_gate": rand_init("mlp_gate", kd[4], (Ld, D, Fd), D),
+            "mlp_up": rand_init("mlp_up", kd[5], (Ld, D, Fd), D),
+            "mlp_down": rand_init("mlp_down", kd[6], (Ld, Fd, D), Fd),
+        }
     if c.n_experts and c.moe_fused_glu:
         E, Fs = c.n_experts, c.moe_shared_dim
         params["layers"].update(
@@ -394,6 +513,10 @@ def init_params(
                 "moe_out": rand_init("moe_out", keys[6], (L, E, F, D), F),
             }
         )
+        if c.moe_select_bias:
+            # trained from zero; drawn so that it moves picks
+            params["layers"]["router_bias"] = 0.1 * jax.random.normal(
+                jax.random.fold_in(keys[7], 1), (L, c.moe_router_width), jnp.float32)
         if Fs:
             ks = jax.random.split(keys[5])
             params["layers"].update(
@@ -446,8 +569,10 @@ def init_params(
             }
         )
     if c.qk_norm:
-        params["layers"].update({"attn_q_norm": jnp.ones((La, H * hd), c.dtype),
-                                 "attn_k_norm": jnp.ones((La, Hkv * hd), c.dtype)})
+        for stack, depth in (("layers", La), ("dense_layers", c.leading_dense_layers)):
+            if depth:
+                params[stack].update({"attn_q_norm": jnp.ones((depth, H * hd), c.dtype),
+                                      "attn_k_norm": jnp.ones((depth, Hkv * hd), c.dtype)})
     if c.gdn_heads:
         params["layers"].update(gdn.init_params(
             c, jax.random.fold_in(k_layers, 2), c.n_of(LINEAR), rand_init))
@@ -506,7 +631,11 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
             backend: str = "ref") -> Array | tuple[Array, Array]:
     """Top-k routed SwiGLU experts: the router scores ``moe_router_width``
     experts in float32, the ``top_k_experts`` largest are a token's picks, and
-    their gates are the softmax over the picked logits alone.
+    their gates are the softmax over the picked logits alone
+    (``moe_score`` "softmax"); or (``"sigmoid"``, ``_sigmoid_picks``) the
+    scores are sigmoids, a selection bias and a limit to the best groups
+    choose the picks, and the gates are the picked scores, normalised and
+    scaled — the router's rule is data of ``LlamaConfig``.
 
     This process holds the first ``n_experts`` of them (Mixtral: all of
     them). A pick on an absent expert adds nothing here — the
@@ -560,8 +689,11 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
         # exactly-k selection from top_k INDICES (threshold comparison would
         # over-select on tied logits); softmax over the selected logits only
         # (Mixtral renormalization), scattered back to expert positions
-        top_vals, top_idx = jax.lax.top_k(r, k)  # [B,S,k]
-        w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
+        if c.moe_score == "sigmoid":
+            top_idx, w = _sigmoid_picks(r, layer_params.get("router_bias"), c)
+        else:
+            top_vals, top_idx = jax.lax.top_k(r, k)  # [B,S,k]
+            w = jax.nn.softmax(top_vals, axis=-1)  # [B,S,k]
         if form != "grouped" or live is not None:
             onehot = jax.nn.one_hot(top_idx, E, dtype=w.dtype)  # [B,S,k,E]; absent (>= E): zeros
         if form != "grouped":
@@ -622,6 +754,30 @@ def moe_mlp(h: Array, layer_params: dict[str, Array], config: LlamaConfig,
             out = out + dense(glu(dense(h, layer_params["shared_in"], qm_backend=qm_backend)),
                               layer_params["shared_out"], qm_backend=qm_backend)
     return out if live is None else (out, jnp.stack([touched, read]))
+
+
+def _sigmoid_picks(r: Array, bias: Array | None, config: LlamaConfig) -> tuple[Array, Array]:
+    """The "sigmoid" router's picks and gates from the logits ``r`` [B,S,R]
+    (float32): scores ``sigmoid(r)``; the picks are the ``top_k_experts``
+    largest of score + ``bias`` (the selection bias chooses and does not
+    weigh) inside the ``moe_topk_groups`` groups whose two largest sum
+    highest; the gates are the picked SCORES, over their sum
+    (``moe_norm_picks``), times ``moe_gate_scale``."""
+    c = config
+    score = jax.nn.sigmoid(r)
+    choice = score if bias is None else score + bias
+    if c.moe_groups:
+        G = c.moe_groups
+        grouped = choice.reshape(*choice.shape[:-1], G, -1)  # [B,S,G,R/G]
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)  # [B,S,G]
+        kept = jax.lax.top_k(group_score, c.moe_topk_groups)[1]  # [B,S,g]
+        keep = jnp.any(jax.nn.one_hot(kept, G, dtype=jnp.bool_), axis=-2)  # [B,S,G]
+        choice = jnp.where(keep[..., None], grouped, -jnp.inf).reshape(choice.shape)
+    top_idx = jax.lax.top_k(choice, c.top_k_experts)[1]  # [B,S,k]
+    w = jnp.take_along_axis(score, top_idx, axis=-1)
+    if c.moe_norm_picks:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return top_idx, w * c.moe_gate_scale
 
 
 def _moe_form(config: LlamaConfig, tokens: int, layer_params: dict[str, Any],
@@ -698,6 +854,7 @@ def _layer(
     kind: str = FULL,
     moe_live: Array | None = None,
     moe_backend: str = "ref",
+    dense_mlp: bool = False,
 ) -> tuple[Array, ...]:
     """One decoder layer. Under GSPMD (the usual path) ``tp_axis`` is
     None — the compiler partitions from the param shardings. Under an
@@ -721,7 +878,13 @@ def _layer(
     mixer alone there. With ``config.norm_after`` the two norms stand on the
     sub-blocks' outputs instead of their inputs. With ``moe_live`` (see
     ``moe_mlp``) the counts of held experts touched and read are the last
-    element; ``moe_backend`` is ``moe_mlp``'s ``backend``."""
+    element; ``moe_backend`` is ``moe_mlp``'s ``backend``.
+
+    With ``config.kv_lora_rank`` attention is latent (models/mla.py) and
+    ``attention`` a ``LatentAttentionFn``; the context tokens the live queries
+    attended to are then a third count beside the experts'. ``dense_mlp``: a
+    leading dense layer of a model that routes (an MLP of
+    ``dense_hidden_dim`` in the experts' place; it counts no experts)."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
@@ -748,6 +911,16 @@ def _layer(
         with jax.named_scope("ssm_out"):
             x = x + scaled(norm_out(mixed, layer_params["ln_attn"]), c.residual_multiplier)
         new_layer_cache = layer_cache
+    elif c.kv_lora_rank:
+        assert tp_axis is None, "manual-TP stage blocks have no latent attention"
+        with jax.named_scope("mla_project"):
+            inputs = mla.project(h, layer_params, c, positions, qm_backend=qm_backend)
+        # the callback opens its own scopes (engine/engine.py)
+        o_latent, new_layer_cache, selected = attention(inputs, layer_cache, layer_idx)
+        with jax.named_scope("mla_project"):
+            attn_out = mla.up_values(o_latent, layer_params, c)
+        with jax.named_scope("attn_o"):
+            x = x + dense(attn_out, layer_params["attn_o"], qm_backend=qm_backend)
     else:
         if c.ssm_heads and not c.layer_pattern:
             assert tp_axis is None, "manual-TP stage blocks have no mixer"
@@ -788,7 +961,7 @@ def _layer(
                 x = x + mixed
 
     h = norm_in(x, layer_params["ln_mlp"])
-    if c.n_experts:
+    if c.n_experts and not dense_mlp:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
         moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend, live=moe_live,
                           backend=moe_backend)
@@ -815,7 +988,11 @@ def _layer(
             x = x + scaled(norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"]),
                            c.residual_multiplier)
     out = (x, new_layer_cache, ssm_cache) if c.has_state else (x, new_layer_cache)
-    return out if moe_live is None else (*out, experts)
+    if moe_live is None:
+        return out
+    if dense_mlp or not c.n_experts:
+        experts = jnp.zeros((2,), jnp.int32)
+    return (*out, jnp.append(experts, selected) if c.kv_lora_rank else experts)
 
 
 def forward(
@@ -879,26 +1056,35 @@ def forward(
     # ragged round with that nested loop hung one time in ten (PERF.md §6,
     # PR 32)
     pattern = c.layer_pattern or (FULL,)
-    n_periods = c.n_layers // len(pattern)
+    n_periods = c.n_scan_layers // len(pattern)
     stacks = params["layers"]
 
-    def one_layer(carry, layer_params, layer_idx, kind):
+    def one_layer(carry, layer_params, layer_idx, kind, dense_mlp=False):
         x, cache, ssm, experts = carry
         out = _layer(
             x, layer_params, cache, layer_idx,
             positions=positions, config=c, attention=attention,
             qm_backend=qm_backend, ssm_cache=ssm, ssm_rows=ssm_rows, kind=kind,
-            moe_live=moe_live, moe_backend=moe_backend,
+            moe_live=moe_live, moe_backend=moe_backend, dense_mlp=dense_mlp,
         )
         if moe_live is not None:
             experts = experts + out[-1]
         # the layer returns its ssm cache only where the model has state
         return (*out[:2], out[2] if c.has_state else ssm, experts)
 
+    # a layer's leaves come to the body as the scan's slices, or, where a
+    # period is longer than one layer or the experts' stacks must stay whole
+    # (below), are indexed out of the stacks inside it
+    by_index = len(pattern) > 1 or c.moe_sparse
+    n_lead = c.leading_dense_layers
+
+    def pool_index(i):  # the leading layers' pages come first in the pool
+        return i + n_lead if n_lead else i
+
     def scan_body(carry, scanned):
         layer_params, period_idx = scanned
-        if len(pattern) == 1:
-            return one_layer(carry, layer_params, period_idx, pattern[0]), None
+        if not by_index:
+            return one_layer(carry, layer_params, pool_index(period_idx), pattern[0]), None
         for j, kind in enumerate(pattern):
             # the layer's index among its own kind (what the caches, stacked
             # by kind, are indexed by) and down the whole depth
@@ -912,7 +1098,7 @@ def forward(
                     lambda a, i=at[_stack_kind(name)]: lax.dynamic_index_in_dim(
                         a, i, 0, keepdims=False), leaf)
                 for name, leaf in stacks.items() if _stack_kind(name) in at}
-            carry = one_layer(carry, layer_params, at[kind], kind)
+            carry = one_layer(carry, layer_params, pool_index(at[kind]), kind)
         return carry, None
 
     if remat:
@@ -920,9 +1106,13 @@ def forward(
         # residuals stay O(one layer) instead of O(n_layers)
         scan_body = jax.checkpoint(scan_body)
 
+    carry = (x, cache, ssm_cache, None if moe_live is None else jnp.zeros(
+        (3 if c.kv_lora_rank else 2,), jnp.int32))
+    for i in range(n_lead):
+        carry = one_layer(carry, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]),
+                          jnp.int32(i), FULL, dense_mlp=True)
     (x, new_cache, ssm_cache, experts), _ = lax.scan(
-        scan_body, (x, cache, ssm_cache, None if moe_live is None else jnp.zeros((2,), jnp.int32)),
-        (stacks if len(pattern) == 1 else None, jnp.arange(n_periods)))
+        scan_body, carry, (None if by_index else stacks, jnp.arange(n_periods)))
     if ssm_cache is not None:
         new_cache = (new_cache, ssm_cache)
 
@@ -950,14 +1140,30 @@ def lm_head(params: dict[str, Any], x: Array, *, config: LlamaConfig,
                   config.lm_head_multiplier)
 
 
-def make_causal_attention(backend: str, scale: float | None = None) -> AttentionFn:
+def make_causal_attention(backend: str, scale: float | None = None,
+                          config: LlamaConfig | None = None) -> AttentionFn:
     """Cache-less causal attention over the whole sequence (training, tests,
     one-shot prefill) on an explicitly-resolved backend. Callers that jit
     must resolve the backend OUTSIDE the traced function and key their jit
     cache on it — resolving env state at trace time bakes the first answer
     into the cache (see ops/dispatch.py). ``scale``: the model's softmax
-    scale (``LlamaConfig.attention_scale``; None = head_dim ** -0.5)."""
+    scale (``LlamaConfig.attention_scale``; None = head_dim ** -0.5). With
+    the ``config`` of a model with latent attention: its callback
+    (``mla.LatentAttentionFn``), dense over the sequence, the same selection."""
     from finchat_tpu.ops.dispatch import causal_attention
+
+    if config is not None and config.kv_lora_rank:
+        from finchat_tpu.ops import latent_attention
+
+        shape = latent_attention.LatentShape(
+            config.kv_lora_rank, config.index_topk, scale or config.head_dim ** -0.5)
+
+        def latent(x: mla.LatentInputs, layer_cache: Any, layer_idx: Array):
+            out, selected = latent_attention.causal_attention(
+                x.q, x.row, x.idx_q, x.idx_w, x.idx_k, shape)
+            return out, layer_cache, selected
+
+        return latent
 
     def attention(q: Array, k: Array, v: Array, layer_cache: Any, layer_idx: Array) -> tuple[Array, Any]:
         return causal_attention(q, k, v, backend=backend, scale=scale), layer_cache
@@ -980,8 +1186,8 @@ def _forward_full_jit(
 ) -> Array:
     logits, _ = forward(
         params, tokens, positions, config=config,
-        attention=make_causal_attention(attn_backend, config.attention_scale), cache=None,
-        qm_backend=qm_backend,
+        attention=make_causal_attention(attn_backend, config.attention_scale, config),
+        cache=None, qm_backend=qm_backend,
     )
     return logits
 

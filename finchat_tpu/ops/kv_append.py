@@ -70,7 +70,7 @@ def _append_kernel(
     # limit) must not index past the table row — read column 0 instead
     logical = jnp.where(valid, pos // page_size, 0)
     phys = jnp.where(valid, page_table_ref[b, logical], TRASH_PAGE)
-    hd = k_scr.shape[-1]
+    hd, hv = k_scr.shape[-1], v_scr.shape[-1]
 
     kin = pltpu.make_async_copy(k_any.at[layer, phys], k_scr, sems.at[0])
     vin = pltpu.make_async_copy(v_any.at[layer, phys], v_scr, sems.at[1])
@@ -82,7 +82,7 @@ def _append_kernel(
     row = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
     hit = row == off
     k_scr[:] = jnp.where(hit, kv_new_ref[0, :, 0:hd], k_scr[:])
-    v_scr[:] = jnp.where(hit, kv_new_ref[0, :, hd:2 * hd], v_scr[:])
+    v_scr[:] = jnp.where(hit, kv_new_ref[0, :, hd:hd + hv], v_scr[:])
 
     kout = pltpu.make_async_copy(k_scr, o_k.at[layer, phys], sems.at[2])
     vout = pltpu.make_async_copy(v_scr, o_v.at[layer, phys], sems.at[3])
@@ -250,15 +250,17 @@ def paged_kv_append(
     interpret: bool = False,
 ) -> tuple[Array, Array]:
     """Append one token's K/V per sequence into layer ``layer``'s pages,
-    in place. Returns the (aliased) cache pair."""
+    in place. Returns the (aliased) cache pair. The two arrays' rows may
+    differ in width (a latent row and an index key: ``kv_new`` is the one
+    beside the other)."""
     B = kv_new.shape[0]
-    HD = k_pages.shape[-1]
+    HD, HV = k_pages.shape[-1], v_pages.shape[-1]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, 1, 2 * HD), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, 1, HD + HV), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -268,7 +270,7 @@ def paged_kv_append(
         ],
         scratch_shapes=[
             pltpu.VMEM((page_size, HD), k_pages.dtype),
-            pltpu.VMEM((page_size, HD), k_pages.dtype),
+            pltpu.VMEM((page_size, HV), v_pages.dtype),
             pltpu.SemaphoreType.DMA((4,)),
         ],
     )

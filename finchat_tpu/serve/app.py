@@ -361,11 +361,16 @@ def make_engine_replica(
     replica's session tier the fleet-shared one and lets its shared
     prompt heads restore from / publish to the cluster-wide store."""
     config, params, tokenizer, mesh = artifacts
-    if config.has_state:
-        # what moves a row between engines moves its pages, never a mixer's
-        # recurrent state (session, handoff and pod wire formats hold none):
-        # refused by name rather than served from a state of zero (the
-        # scheduler refuses the warm fabric itself)
+    # what moves a row between engines moves its pages by id: never a mixer's
+    # recurrent state (session, handoff and pod wire formats hold none), and a
+    # latent model's pages (a latent row and an index key a token) have been
+    # carried by the RAM session tier alone. Refused by name rather than
+    # served from a state of zero or through an unproven record (the
+    # scheduler refuses the warm fabric, and a latent model's disk records)
+    one_engine = (
+        f"a model with recurrent state ({config.n_state_layers} layers)" if config.has_state
+        else "a model with latent attention (latent pages)" if config.kv_lora_rank else None)
+    if one_engine:
         from finchat_tpu.serve.disagg import parse_roles
 
         refused = {
@@ -377,8 +382,8 @@ def make_engine_replica(
         named = [option for option, on in refused.items() if on]
         if named:
             raise ValueError(
-                f"a model with recurrent state ({config.n_state_layers} layers) is served "
-                f"by one engine; not supported with it: {', '.join(named)}")
+                f"{one_engine} is served by one engine; not supported with it: "
+                f"{', '.join(named)}")
     metrics = METRICS.labeled(replica=replica_id) if replica_id is not None else None
     with TRACER.startup_phase("engine_init"):
         engine = InferenceEngine(config, params, cfg.engine, mesh=mesh,

@@ -159,6 +159,11 @@ DEVICE_SCOPES = frozenset({
     # the attention callbacks (engine/engine.py)
     "kv_append", "kv_scatter", "paged_attention",
     "kv_scatter_ragged", "ragged_paged_attention",
+    # latent attention (models/mla.py, ops/latent_attention.py): the low-rank
+    # projections and the absorbed up-projections; the indexer's scores over
+    # the context; the exact top-k of them (and the gather of the selected
+    # rows); the softmax over the selected latent rows
+    "mla_project", "dsa_indexer", "dsa_select", "mla_attention",
 })
 
 #: Why a request's span ended (``RequestSpan.finish(reason=...)``).

@@ -120,3 +120,25 @@ def test_page_hbm_bytes_matches_real_allocation():
             # the no-quant layout carries (1,1,1,1) scale placeholders
             expected += cache.k_scales.nbytes + cache.v_scales.nbytes
         assert cache.hbm_bytes() == expected
+
+
+@pytest.mark.parametrize("n_pages", [1, 3, 4, 5])
+def test_the_offloads_take_is_shaped_by_a_power_of_two_and_returns_the_pages_asked_for(n_pages):
+    """``gather_pages_host`` pads its page count to a power of two (the trash
+    page repeated) so that the eager take meets a handful of shapes, and hands
+    back exactly the pages asked for, in their order, as arrays of their own."""
+    import numpy as np
+
+    from finchat_tpu.engine.kv_cache import gather_bucket, gather_pages_host
+
+    assert [gather_bucket(n) for n in (1, 2, 3, 4, 5, 64, 65, 128)] == [1, 2, 4, 4, 8, 64, 128, 128]
+    k = jnp.arange(2 * 9 * 4 * 6, dtype=jnp.float32).reshape(2, 9, 4, 6)
+    v = -jnp.arange(2 * 9 * 4 * 3, dtype=jnp.float32).reshape(2, 9, 4, 3)  # another width
+    scales = jnp.zeros((1, 1, 1, 1), jnp.float32)
+    ids = [7, 2, 5, 1, 8][:n_pages]
+    got_k, got_v, ks, vs = gather_pages_host(k, v, scales, scales, ids)
+    assert ks is None and vs is None
+    assert np.array_equal(got_k, np.asarray(k)[:, ids]) and got_k.shape == (2, n_pages, 4, 6)
+    assert np.array_equal(got_v, np.asarray(v)[:, ids]) and got_v.shape == (2, n_pages, 4, 3)
+    held = got_k if got_k.base is None else got_k.base  # what the snapshot keeps alive
+    assert got_k.flags["C_CONTIGUOUS"] and held.nbytes == got_k.nbytes  # not the padded buffer

@@ -442,3 +442,71 @@ def test_granite_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_c
     assert "bf16[16,36,1536]" not in text and "bf16[36,4096,1536]" not in text
     out_shapes = [x.shape for x in jax.tree.leaves(compiled.out_info)]
     assert out_shapes.count((2,)) == 1  # the counts (touched, read) beside the tokens
+
+
+# --- deepseek-v3.2-exp (PR 40): latent pages, the indexer's selection -----------
+
+def test_the_touched_expert_pass_compiles_for_v5e_at_deepseeks_stacks(one_chip):
+    """``ops/moe_step.py`` over ``deepseek-v3.2-exp``'s stacks [4, 16, 7168,
+    4096] and [4, 16, 2048, 7168]: a tile of 256 columns brings three blocks
+    of 3.7 MB a grid step (7168 x 256 x 2 B), double-buffered under a VMEM
+    limit of about 32 MB by its own formula, against Granite's 22: one custom
+    call, nothing copied and no temporary beside it."""
+    from finchat_tpu.ops.moe_step import moe_experts_step, width_tile
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, E, D, F = 4, 16, 7168, 2048
+    assert width_tile(F) == 256
+    compiled = moe_experts_step.lower(
+        shape((ROWS, D)), shape((E, ROWS, 1), jnp.float32), shape((E,), jnp.int32),
+        shape((1,), jnp.int32), shape((L, E, D, 2 * F)), shape((L, E, F, D)),
+        shape((1,), jnp.int32)).compile()
+    calls = [line for line in compiled.as_text().splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "%moe_experts_step" in calls[0].split(" = ")[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
+def test_kv_append_compiles_for_v5e_with_a_latent_row_and_an_index_key(one_chip):
+    """The decode step's in-place append where the pool's two arrays hold rows
+    of different widths: a latent row of 640 columns and an index key of 128,
+    one beside the other in ``kv_new``; each page read, patched and written
+    back whole by ONE custom call."""
+    from finchat_tpu.ops.kv_append import paged_kv_append
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *args: paged_kv_append(*args, page_size=PAGE)).lower(
+        shape((ROWS, 1, 640 + 128)), shape((5, POOL, PAGE, 640)), shape((5, POOL, PAGE, 128)),
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32), shape((ROWS,), jnp.int32),
+        shape((1,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
+def test_the_one_token_latent_attention_compiles_for_v5e_at_the_cell_shape(one_chip):
+    """``ops/latent_attention.py``'s gather form at ``deepseek-v3.2-exp``'s
+    widths: 16 rows, 128 heads of 576 against pages of 640-column latent rows,
+    an indexer of 64 heads of 128 over a table of 128 pages (16,384 tokens),
+    the 2,048 best gathered: plain XLA — no custom call — under the three
+    scopes the benchmark's readers time, with temporaries of about a quarter
+    of a GB (the index keys of 16 x 16,384 tokens and their scores)."""
+    from finchat_tpu.ops.latent_attention import LatentShape, decode_attention
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *args: decode_attention(
+        *args, page_size=PAGE, shape=LatentShape(512, 2048, 0.135234))).lower(
+        shape((ROWS, 128, 576)), shape((ROWS, 64, 128)), shape((ROWS, 64), jnp.float32),
+        shape((5, POOL, PAGE, 640)), shape((5, POOL, PAGE, 128)), shape((), jnp.int32),
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32), shape((ROWS,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    for scope in ("dsa_indexer", "dsa_select", "mla_attention"):
+        assert f"/{scope}/" in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
