@@ -59,7 +59,7 @@ from finchat_tpu.engine.sampler import sample
 from finchat_tpu.models.llama import LlamaConfig, forward, lm_head
 from finchat_tpu.models.mla import LatentInputs
 from finchat_tpu.models.ssm import SsmRows
-from finchat_tpu.ops.latent_attention import LatentShape
+from finchat_tpu.ops.latent_attention import LatentShape, decode_form
 from finchat_tpu.ops.dispatch import paged_attention
 from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.logging import get_logger
@@ -206,22 +206,27 @@ def _latent_shape(config: LlamaConfig) -> LatentShape | None:
 
 
 def _latent_attention(write, page_rows: Array, start: Array, n_valid: Array,
-                      page_size: int, latent: LatentShape, rows: SsmRows | None = None):
+                      page_size: int, latent: LatentShape, backend: str,
+                      rows: SsmRows | None = None,
+                      shared: tuple[Array, Array] | None = None):
     """The ``LatentAttentionFn`` of a step: ``write(row, idx_k, cache,
     layer_idx)`` puts the chunk's rows into the pool; then row ``n``'s
     ``n_valid[n]`` queries, at the compacted positions ``start[n] ..``, attend
     over its pages ``page_rows[n]``. With ``rows`` the tokens arrive PACKED
-    ``[1, T]`` (the ragged step): row ``n``'s lie from ``rows.pack[0][n]`` on."""
+    ``[1, T]`` (the ragged step): row ``n``'s lie from ``rows.pack[0][n]`` on.
+    One-token rows take the form ``latent_attention.decode_form`` reads off
+    ``backend`` and the table's width (``shared``: the decode step's
+    ``shared_head``, for the walk)."""
     from finchat_tpu.ops.latent_attention import packed_attention, rows_attention
 
     def attention(x: LatentInputs, cache: Any, layer_idx: Array):
         idx_k = x.idx_k if x.idx_k is not None else jnp.zeros((*x.row.shape[:2], 1), x.row.dtype)
         cache = write(x.row, idx_k, cache, layer_idx)
-        kw = dict(page_size=page_size, shape=latent)
+        kw = dict(page_size=page_size, shape=latent, backend=backend)
         layer = layer_idx.reshape(())
         if rows is None:
             out, selected = rows_attention(x.q, x.idx_q, x.idx_w, cache[0], cache[1], layer,
-                                           page_rows, start, n_valid, **kw)
+                                           page_rows, start, n_valid, shared=shared, **kw)
         else:
             out, selected = packed_attention(
                 *(None if a is None else a[0] for a in (x.q, x.idx_q, x.idx_w)),
@@ -287,7 +292,15 @@ def _paged_attention_fn(
                 return _scatter_kv(cache, row[:, :, None], idx_k[:, :, None], page_table,
                                    start_pos, n_valid, page_size, layer_idx, 1)
 
-        return _latent_attention(write, page_table, start_pos, n_valid, page_size, latent)
+        shared = None
+        if decode and decode_form(attn_backend, page_table.shape[1] * page_size,
+                                  latent.topk) == "walk":
+            from finchat_tpu.ops.paged_attention import shared_head
+
+            with jax.named_scope("mla_attention"):  # once a step, outside the layer scan
+                shared = shared_head(page_table, start_pos + n_valid, page_size, n_valid > 0)
+        return _latent_attention(write, page_table, start_pos, n_valid, page_size, latent,
+                                 attn_backend, shared=shared)
     shared = None
     if decode and attn_backend != "ref":
         from finchat_tpu.ops.paged_attention import shared_head
@@ -760,7 +773,7 @@ def _ragged_attention_fn(
         # a row's compacted start: its first packed token's write position
         start = tok_wpos[jnp.minimum(rows.pack[0], tok_wpos.shape[0] - 1)]
         return _latent_attention(write, page_rows, start, rows.n_valid, page_size, latent,
-                                 rows=rows)
+                                 attn_backend, rows=rows)
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
         k_pages, v_pages, k_scales, v_scales = cache
@@ -1435,6 +1448,11 @@ class InferenceEngine:
             engine_cfg.num_pages - 1,
             -(-engine_cfg.max_seq_len // engine_cfg.page_size),
         )
+        # a model with latent attention: the form its one-token rows take, as
+        # the steps' traces read it off the same table (latent_attention.decode_form)
+        self.latent_form = decode_form(
+            attn_backend, self.max_pages_per_seq * engine_cfg.page_size,
+            config.index_topk) if config.kv_lora_rank else None
         self.mesh = mesh
         # bounded-KV long-context serving (ISSUE 15): attention-sink +
         # sliding-window page eviction. The policy is pure host math; the

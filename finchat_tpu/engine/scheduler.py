@@ -497,6 +497,8 @@ class ContinuousBatchingScheduler:
         # fallback/envelope counters so a mode flip or a refused
         # cross-mode restore is visible from zero
         self._quant_label = getattr(engine, "quant_label", "bf16")
+        # a model with latent attention: the form its one-token rows take
+        self._latent_form = getattr(engine, "latent_form", None)
         _wbits = {"": None, "int8": 8, "int4": 4}.get(
             getattr(engine, "quant", ""))
         _elem_bits = 8 * np.dtype(engine.config.dtype).itemsize
@@ -635,6 +637,8 @@ class ContinuousBatchingScheduler:
         if latent:
             self.metrics.inc("finchat_dsa_selected_tokens_total", 0.0)
             self.metrics.inc("finchat_dsa_row_layer_steps_total", 0.0)
+            self.metrics.inc("finchat_latent_attention_calls_total", 0.0,
+                             labels={"form": self._latent_form})
             if fabric is not None or getattr(cfg, "session_cache_disk_path", ""):
                 raise ValueError(
                     "fabric.path / engine.session_cache_disk_path: the warm fabric's and the "
@@ -959,7 +963,8 @@ class ContinuousBatchingScheduler:
         self._phases.note(
             kind=kind, rows=len(riders), kv_tokens=kv_tokens,
             kv_tokens_distinct=kv_tokens - sum(sum(t) - max(t) for t in heads.values()),
-            prefix_rows=len(most) if len(most) > 1 else 0)
+            prefix_rows=len(most) if len(most) > 1 else 0,
+            **({"form": self._latent_form} if self._latent_form else {}))
 
     @staticmethod
     def _rider(handle: SequenceHandle, mode: str, drafts: int = 0) -> tuple:
@@ -3905,6 +3910,9 @@ class ContinuousBatchingScheduler:
                     self.metrics.inc("finchat_dsa_selected_tokens_total", selected[0])
                     self.metrics.inc("finchat_dsa_row_layer_steps_total",
                                      len(step.members) * self.engine.config.n_layers)
+                    self.metrics.inc("finchat_latent_attention_calls_total",
+                                     self.engine.config.n_layers,
+                                     labels={"form": self._latent_form})
                     self._round_selected = selected[0]
             for slot, handle, epoch in step.members:
                 if handle.finished or handle.slot != slot or handle.epoch != epoch:

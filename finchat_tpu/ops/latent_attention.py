@@ -5,22 +5,29 @@ A page of the first array holds one latent row a token, ``[c_kv | k_rope]``
 key row. A query scores every context token with the indexer, keeps the exact
 ``topk`` largest (``select``: no approximation, no selection by page or block),
 and runs the ABSORBED softmax over the kept rows alone (models/mla.py has the
-algebra). Plain ``jax.lax`` on every backend — no kernel of the repo computes
-this yet — in two forms that realise the same selection:
+algebra). Two forms realise the same selection:
 
-- one token a row (the decode step): a stable sort of the scores with the
-  tokens' pool addresses beside them, a GATHER of the first ``topk`` rows
-  ``[B, topk, row]``, attention over them;
-- a chunk of tokens a row (prefill, a ragged round's prompt rows): the k-th
-  largest score by an exact bit search, a MASK over a dense walk of the row's
-  pages in blocks with a running softmax. Rows are walked one after another,
-  and a row without tokens costs nothing. A ragged round's one-token rows
-  take the first form together (``packed_attention``).
+- the GATHER form (one token a row): a stable sort of the scores with the
+  tokens' pool addresses beside them, a gather of the first ``topk`` rows
+  ``[B, topk, row]``, attention over them; plain ``jax.lax``;
+- the MASK form: the k-th largest score by an exact bit search, a mask over a
+  dense walk of the row's pages in blocks with a running softmax. A chunk of
+  tokens a row (prefill, a ragged round's prompt rows) walks in plain
+  ``jax.lax``, rows one after another, and a row without tokens costs
+  nothing. One token a row on a kernel backend walks in ONE Pallas pass
+  (``ops/paged_attention.py`` ``paged_latent_attention``: the paged kernel's
+  walk — whole pages, the batch's shared head once for all rows' queries
+  stacked), where the page table spans at most ``WALK_MAX_CONTEXTS``
+  selections (``decode_form``: the walk's work grows with the context, the
+  gather's with ``rows x topk``; PERF.md section 6, PR 41). The ``ref``
+  backend, and a wider table, take the gather form. A ragged round's
+  one-token rows take their form together (``packed_attention``).
 
 Of tokens tied AT the k-th score both keep those at the lowest positions, so
-the two select the same ``topk`` tokens always (tests/test_deepseek_v32.py;
-exact ties are everyday at a test's width — four index heads are all negative
-under the ReLU one time in sixteen — and unheard of at 64 heads).
+the two select the same ``topk`` tokens always (tests/test_deepseek_v32.py,
+tests/test_latent_walk.py; exact ties are everyday at a test's width — four
+index heads are all negative under the ReLU one time in sixteen — and unheard
+of at 64 heads).
 """
 
 from __future__ import annotations
@@ -35,6 +42,12 @@ from finchat_tpu.ops.refs import NEG_INF
 
 #: context tokens a step of the chunk form's walk brings in (whole pages)
 WALK_BLOCK = 1024
+#: the widest page table, in selections, whose one-token rows walk their pages
+#: (``decode_form``). Alone on a v5e at 16 rows x 128 heads (PERF.md section 6, PR
+#: 41): the gather form 0.743 ms a layer whatever the context; the walk 0.28-0.31
+#: at contexts of 2.5-6 selections, 0.59 with EVERY row at 8; the lines cross
+#: near 10. 8 is as far as it was measured (the served table's width)
+WALK_MAX_CONTEXTS = 8
 
 
 class LatentShape(NamedTuple):
@@ -111,21 +124,49 @@ def _take_pages(pages: Array, layer: Array, ids: Array) -> Array:
     return got.reshape(*ids.shape[:-1], -1, got.shape[-1])
 
 
+def decode_form(backend: str, context: int, topk: int) -> str:
+    """Which realisation a one-token call takes, read off the call: ``walk``
+    (the mask form: ``paged_latent_attention`` walks the row's pages under the
+    selection's mask) on a kernel backend where the page table spans at most
+    ``WALK_MAX_CONTEXTS`` selections, else ``gather``. The walk's work grows
+    with the context, the gather's with ``rows x topk``."""
+    kept = min(topk or context, context)
+    return "walk" if backend != "ref" and context <= WALK_MAX_CONTEXTS * kept else "gather"
+
+
 def decode_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
                      latent_pages: Array, index_pages: Array, layer: Array,
                      page_table: Array, kv_len: Array, live: Array, *,
-                     page_size: int, shape: LatentShape) -> tuple[Array, Array]:
+                     page_size: int, shape: LatentShape, backend: str = "ref",
+                     shared: tuple[Array, Array] | None = None) -> tuple[Array, Array]:
     """One query a row (``q`` [B,H,R+r]) over the row's first ``kv_len``
-    tokens (its own row already written): the gather form. Returns
+    tokens (its own row already written), in the form ``decode_form`` reads
+    off the call (``shared``: ``shared_head``'s, for the walk). Returns
     ``(o_latent [B,H,R], selected)``; ``live`` [B] rows count."""
     B = q.shape[0]
     J = page_table.shape[1] * page_size
     allowed = (jnp.arange(J)[None, :] < kv_len[:, None]) & live[:, None]
     k = min(shape.topk or J, J)
-    if shape.topk and shape.topk < J:
+    selects = k < J
+    if selects:
         with jax.named_scope("dsa_indexer"):
             keys = _take_pages(index_pages, layer, page_table)  # [B,J,Di]
             scores = index_scores(idx_q[:, None], idx_w[:, None], keys)[:, 0]
+    if decode_form(backend, J, shape.topk) == "walk":
+        from finchat_tpu.ops.paged_attention import paged_latent_attention
+
+        kept = allowed
+        if selects:
+            with jax.named_scope("dsa_select"):
+                kept = select(scores, allowed, k)
+        with jax.named_scope("mla_attention"):
+            out = paged_latent_attention(
+                _pad_q(q, latent_pages.shape[-1]), latent_pages, kept, page_table,
+                jnp.where(live, kv_len, 0), layer.reshape(1), shared, page_size=page_size,
+                value_width=shape.kv_lora, scale=shape.scale,
+                interpret=backend == "pallas-interpret")
+        return out, jnp.sum(kept.astype(jnp.int32))
+    if selects:
         with jax.named_scope("dsa_select"):
             # token j of a row lies in its page j // page_size at j % page_size:
             # its address in the layer's pool rides the sort as a second
@@ -213,19 +254,20 @@ def chunk_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
 def rows_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
                    latent_pages: Array, index_pages: Array, layer: Array,
                    page_rows: Array, start: Array, n_valid: Array, *,
-                   page_size: int, shape: LatentShape) -> tuple[Array, Array]:
+                   page_size: int, shape: LatentShape, backend: str = "ref",
+                   shared: tuple[Array, Array] | None = None) -> tuple[Array, Array]:
     """``q`` [N,C,H,R+r]: row ``n``'s ``n_valid[n]`` queries stand at the
     compacted positions ``start[n] ..``; their rows are written. One token a
-    row takes the gather form over all rows at once; a chunk the mask form a
-    row at a time (a row without tokens is skipped). Returns ``(o_latent
-    [N,C,H,R], selected)``."""
+    row takes ``decode_attention``'s form over all rows at once; a chunk the
+    mask form a row at a time (a row without tokens is skipped). Returns
+    ``(o_latent [N,C,H,R], selected)``."""
     N, C = q.shape[:2]
     kw = dict(page_size=page_size, shape=shape)
     if C == 1:
         out, selected = decode_attention(
             q[:, 0], None if idx_q is None else idx_q[:, 0],
             None if idx_w is None else idx_w[:, 0], latent_pages, index_pages, layer,
-            page_rows, start + n_valid, n_valid > 0, **kw)
+            page_rows, start + n_valid, n_valid > 0, backend=backend, shared=shared, **kw)
         return out[:, None], selected
     col = jnp.arange(C, dtype=jnp.int32)
     no_index = idx_q is None
@@ -248,12 +290,13 @@ def rows_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
 def packed_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
                      latent_pages: Array, index_pages: Array, layer: Array,
                      page_rows: Array, q_start: Array, start: Array, n_valid: Array, *,
-                     width: int, page_size: int, shape: LatentShape) -> tuple[Array, Array]:
+                     width: int, page_size: int, shape: LatentShape,
+                     backend: str = "ref") -> tuple[Array, Array]:
     """A ragged round's PACKED queries ``q`` [T,H,R+r]: row ``n``'s
     ``n_valid[n]`` (at most ``width``) tokens lie from ``q_start[n]`` on and
     stand at the compacted positions ``start[n] ..``. The rows of ONE token
-    (the round's decode rows) take the gather form together, off the packed
-    buffer; only a row of more — a prompt's chunk — walks, a row at a time, on
+    (the round's decode rows) take ``decode_attention``'s form together, off
+    the packed buffer; only a row of more — a prompt's chunk — walks, a row at a time, on
     its ``width`` tokens sliced out of the buffer where they lie and written
     back there (regrouping every row to ``[rows, width]`` first costs a
     buffer of 0.6 GB a layer at 16 rows of 256 x 128 heads, most of it for
@@ -286,7 +329,7 @@ def packed_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
     first = jnp.minimum(q_start, T - 1)
     out_one, selected_one = decode_attention(
         q[first], None if no_index else idx_q[first], None if no_index else idx_w[first],
-        latent_pages, index_pages, layer, page_rows, start + 1, one, **kw)
+        latent_pages, index_pages, layer, page_rows, start + 1, one, backend=backend, **kw)
     # a row that is not of one token writes into the padding behind the buffer
     out = out.at[jnp.where(one, first, T + width - 1)].set(out_one)
     return out[:T], selected + selected_one

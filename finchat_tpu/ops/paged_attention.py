@@ -93,6 +93,20 @@ Design — a walk as long as the row, several pages a block:
   uncovered first copy had become a fifth of the call. At C > 1 none of
   this is traced.
 
+- a LATENT cache (``paged_latent_attention``; PERF.md section 6, PR 41). Where
+  a token's ONE row is key and value at once for every head (models/mla.py)
+  and an indexer keeps a subset of the context, the decode call is the same
+  walk with one source (one DMA stream feeds both matmuls: the value block
+  is the key block's first lanes), ONE KV "head" whose rows are all the
+  query heads, and the selection as a mask block beside the positions'
+  mask. The shared head's stacked rows (16 x 128) take their block update a
+  tile of whole sequences at a time (``LATENT_TILE_BYTES`` of logits), each
+  sequence under its own mask. Blocks are ``LATENT_BLOCK_TOKENS`` (1,024):
+  the call is bound by the MXU (295 kFLOP a context token a row), not by the
+  copies, and at 128 query rows a block's fixed work showed (0.334 ms a
+  layer at the cell's shapes at 512, 0.289 at 1,024, 0.323 at 2,048, where a
+  partial last block's waste overtakes it).
+
 Serves both decode (C = 1) and paged chunked prefill (C = chunk) — the same
 causal/ragged masking as ``ops.refs.mha_reference`` with ``q_offset``/
 ``kv_len`` semantics.
@@ -120,6 +134,13 @@ SCORE_TILE_BYTES = 1 << 19  # one kv head's fp32 [rows, block] logit tile
 KV_BUFFER_BYTES = 8 << 20  # both slots of the K and the V block
 VMEM_BYTES = 31 << 19  # 15.5 MiB of the v5e's 16 MiB of scoped VMEM: blocks, state, buffers
 SUBLANES = 8  # rows of a float32 tile: the least a block update works on
+# the latent form asks for more than the scoped default: 16 rows' stacked queries
+# of 128 heads and their softmax state are 12 MiB before a block is copied
+LATENT_VMEM_BYTES = 48 << 20
+# ... and walks in longer blocks: at 128 query rows a block's fixed work (the state's
+# read-modify-write, the matmuls' fill and drain) is a fifth of a 512-token block's time
+LATENT_BLOCK_TOKENS = 1024
+LATENT_TILE_BYTES = 1 << 20  # the float32 logit tile of the stacked rows that take one update
 
 
 def _pad_chunk(q: Array) -> tuple[Array, int]:
@@ -222,6 +243,7 @@ def _paged_kernel(
     scale: float,
     quantized: bool,
     shared_rows: int,
+    latent_rows: int = 0,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -250,13 +272,27 @@ def _paged_kernel(
     the V block leaves each row's result at its head's lanes of a ``pack * D``
     wide acc; the other lanes hold other heads' values under this row's
     weights and are dropped at the end. The stacked queries are ``[tiles,
-    shared_rows, pack * D]`` likewise, a sequence's 8 rows one tile."""
-    n_src = 4 if quantized else 2
+    shared_rows, pack * D]`` likewise, a sequence's 8 rows one tile.
+
+    With ``latent_rows`` (``paged_latent_attention``: decode over a LATENT
+    cache) there is ONE source, whose token row is key and value at once for
+    every query head: the query block is ``[1, H, Dk]`` (``Dk`` the row's
+    width), the value block the first ``D`` lanes of the key block that the
+    one copy brought, and behind the queries comes a block ``keep [B,
+    (max_pages + pages_per_block - 1) * page_size]`` int32 — the selection: a
+    token whose entry is 0 is masked like one beyond ``kv_len``. The shared
+    head's stacked queries are ``[1, shared_rows, Dk]`` and take the block
+    update ``latent_rows`` rows at a time (whole sequences: a tile of rows
+    where the other forms have a tile of heads), each sequence's rows under
+    its own mask. The other forms' bodies are traced as they were."""
+    n_src = 1 if latent_rows else 4 if quantized else 2
     layer_ref, page_table_ref, q_offset_ref, kv_len_ref, *refs = refs
     if shared_rows:
         member_ref, head_ref, q_ref, qs_ref, *refs = refs
     else:
         q_ref, *refs = refs
+    if latent_rows:
+        keep_ref, *refs = refs
     sources = refs[:n_src]
     o_ref, *own_state = refs[n_src:n_src + 4]
     refs = refs[n_src + 4:]
@@ -268,6 +304,7 @@ def _paged_kernel(
     qi = pl.program_id(1)
     Bq, ppb = block_q, pages_per_block
     D = o_ref.shape[-1]
+    Dk = q_ref.shape[-1]  # a key row's lanes: a head's D, or the latent row
     Rt = pack * group * Bq  # scratch rows per tile
     n_tiles = pl.cdiv(n_kv, pack)
     T = ppb * page_size  # tokens per block
@@ -276,11 +313,13 @@ def _paged_kernel(
     kv_len = kv_len_ref[b]
 
     def walk(row, first, n_pages, limit, causal, q_of, state, R,
-             slot0=0, primed=False, then=None):
+             slot0=0, primed=False, then=None, tiles=n_tiles, keep_of=None):
         """Online softmax of ``R`` query rows a tile (``q_of(t)``, state in
         ``state``'s rows ``t*R .. (t+1)*R``) over table columns ``first ..
         first + n_pages`` of ``row``; positions at or beyond ``limit`` are
-        masked, and with ``causal`` those after a query row's own. Block j
+        masked, and with ``causal`` those after a query row's own, and with
+        ``keep_of`` (the latent form) those where ``keep_of(t, the block's
+        first column)`` [R or 1, T] is 0. Block j
         lands in buffer slot ``(slot0 + j) % 2``. ``primed``: the walk before
         this one already started block 0's copies; ``then = (row, first,
         n_pages)``: the walk after this one, whose block 0 this one starts
@@ -330,20 +369,27 @@ def _paged_kernel(
             for c in copies(slot):
                 c.wait()
 
+            # (the latent form's rows of a walk share their positions' mask: one row of it)
             kv_pos = (first + j * ppb) * page_size + jax.lax.broadcasted_iota(
-                jnp.int32, (R, T), 1)
+                jnp.int32, (1 if latent_rows else R, T), 1)
             invalid = kv_pos >= limit
             if causal:
                 rows = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
                 q_pos = q_off + qi * Bq + rows % Bq
                 invalid = jnp.logical_or(invalid, kv_pos > q_pos)
 
-            for t in range(n_tiles):  # static unroll over tiles of kv heads
+            for t in range(tiles):  # static unroll over tiles of kv heads
                 h0 = t * pack
-                W = (min(h0 + pack, n_kv) - h0) * D  # the tile's lanes
+                W = D if latent_rows else (min(h0 + pack, n_kv) - h0) * D  # the tile's lanes
                 q_blk = q_of(t, W)
-                k_blk = buffers[0][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
-                v_blk = buffers[1][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
+                masked = invalid
+                if latent_rows:  # one row a token: the key, its first lanes the value
+                    k_blk = buffers[0][slot].reshape(T, Dk)
+                    v_blk = k_blk[:, :D]
+                    masked = jnp.logical_or(invalid, keep_of(t, first + j * ppb) == 0)
+                else:
+                    k_blk = buffers[0][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
+                    v_blk = buffers[1][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
                 k_scale = v_scale = None
                 if quantized:  # int8 is exact in the query dtype
                     k_blk = k_blk.astype(jnp.float32).astype(q_blk.dtype)
@@ -359,7 +405,7 @@ def _paged_kernel(
                 r0 = t * R
 
                 m_new, l_new, acc_new = _online_softmax_update(
-                    q_blk, k_blk, v_blk, invalid,
+                    q_blk, k_blk, v_blk, masked,
                     m_ref[r0:r0 + R, :1], l_ref[r0:r0 + R, :1],
                     acc_ref[r0:r0 + R, :W], scale, k_scale, v_scale,
                 )
@@ -380,6 +426,11 @@ def _paged_kernel(
         for i in range(pack):
             out = jnp.where(row_head == i, piece(i), out)
         return out
+
+    def keep_block(col, seq):
+        """The selection's entries of sequence ``seq`` for the block whose
+        first table column is ``col``: [1, T] int32."""
+        return keep_ref[pl.ds(seq, 1), pl.ds(pl.multiple_of(col * page_size, page_size), T)]
 
     def reset(state):
         m_ref, l_ref, acc_ref = state
@@ -411,13 +462,28 @@ def _paged_kernel(
         def _call():
             slot_ref[0] = 0
 
+        stacked = {}
+        if latent_rows:  # a tile is `latent_rows` stacked rows: whole sequences
+            def stacked_keep(t, col):
+                return jnp.concatenate(
+                    [jnp.broadcast_to(keep_block(col, t * (latent_rows // gp) + i), (gp, T))
+                     for i in range(latent_rows // gp)], axis=0)
+
+            stacked = dict(tiles=shared_rows // latent_rows, keep_of=stacked_keep)
+
         @pl.when(jnp.logical_and(b == 0, n_shared > 0))
         def _shared():
             reset(shared_state)
+            if latent_rows:
+                def stacked_q(t, W):
+                    return qs_ref[0, t * latent_rows:(t + 1) * latent_rows, :]
+            else:
+                def stacked_q(t, W):
+                    return qs_ref[t, :, :W]
             slot_ref[0] = walk(
                 head_ref[1], 0, n_shared, n_shared * page_size, False,
-                lambda t, W: qs_ref[t, :, :W], shared_state, shared_rows,
-                then=own_walk(0))
+                stacked_q, shared_state, latent_rows or shared_rows,
+                then=own_walk(0), **stacked)
 
         @pl.when(member_ref[b] != 0)
         def _resume():
@@ -431,13 +497,19 @@ def _paged_kernel(
                      then=(row, first, jnp.where(b + 1 < B, n_pages, 0)))
 
     _row, first, n_pages = own_walk(b)
-    if pack == 1:
+    if latent_rows:
+        def own_q(t, W):
+            return q_ref[0]
+
+        chain.update(keep_of=lambda t, col: keep_block(col, b))
+    elif pack == 1:
         def own_q(t, W):
             return q_ref[0, t * group:(t + 1) * group].reshape(Rt, D)
     else:
         def own_q(t, W):
             return q_ref[0, t, :, :W]
-    slot = walk(b, first, n_pages, kv_len, True, own_q, own_state, Rt, **chain)
+    # (a latent row's one query stands on its last token: ``kv_len`` is its causal bound)
+    slot = walk(b, first, n_pages, kv_len, not latent_rows, own_q, own_state, Rt, **chain)
     if shared_rows:
         slot_ref[0] = slot
 
@@ -448,7 +520,10 @@ def _paged_kernel(
     else:  # a row's own head is at lanes i * D .. (i + 1) * D of its acc
         full = acc_scr[:] / jnp.maximum(l_scr[:, :1], 1e-30)
         out = own_head(full.shape[0], D, lambda i: full[:, i * D:(i + 1) * D])[:R]
-    o_ref[0] = out.reshape(n_kv * group, Bq, D).astype(o_ref.dtype)
+    if latent_rows:
+        o_ref[0] = out.astype(o_ref.dtype)
+    else:
+        o_ref[0] = out.reshape(n_kv * group, Bq, D).astype(o_ref.dtype)
 
 
 def _block_diagonal(q: Array, n_kv: int, pack: int) -> Array:
@@ -619,3 +694,77 @@ def paged_flash_attention(
         q, (k_pages, v_pages), page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, scale=scale, block_q=block_q,
         interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "value_width", "scale", "interpret"))
+def paged_latent_attention(
+    q: Array,  # [B, H, Dk] — one query a row, a head's lanes the latent row's
+    pages: Array,  # [L, P, page_size, Dk] — a token's ONE row: key, and value in its head
+    keep: Array,  # [B, max_pages * page_size] bool — the selection
+    page_table: Array,  # [B, max_pages] int32
+    kv_len: Array,  # [B] int32 — the row's tokens, its own included; 0: a dead row
+    layer: Array,  # [1] int32
+    shared: tuple[Array, Array] | None = None,  # ``shared_head``'s
+    *,
+    page_size: int,
+    value_width: int,
+    scale: float,
+    interpret: bool = False,
+) -> Array:
+    """Decode attention over a LATENT paged cache (ops/latent_attention.py):
+    every head of row ``b`` attends the tokens ``j < kv_len[b]`` with
+    ``keep[b, j]``, scores against the whole token row, values its first
+    ``value_width`` lanes; returns [B, H, value_width]. ``_paged_kernel``'s
+    walk — whole pages double-buffered as far as the row goes, the batch's
+    shared head once for all rows' queries stacked — with one source, one KV
+    "head" of ``H`` query rows, and the selection as a mask block. A row
+    without a kept token gives zeros."""
+    B, H, Dk = q.shape
+    max_pages = page_table.shape[1]
+    assert pages.shape[2:] == (page_size, Dk), (pages.shape, page_size, Dk)
+    page_table = jnp.asarray(page_table, jnp.int32)
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    q_offset = jnp.maximum(kv_len - 1, 0)  # a decode query stands on its row's last token
+    gp = _round_up(H, SUBLANES)
+    shared_rows = B * gp if B > 1 else 0
+    ppb = max(1, min(LATENT_BLOCK_TOKENS // page_size, max_pages))
+    # sequences whose stacked rows take one block update: as many as keep the
+    # float32 logit tile in its budget, and divide the batch
+    per = max((d for d in range(1, B + 1)
+               if B % d == 0 and d * gp * ppb * page_size * 4 <= LATENT_TILE_BYTES), default=1)
+    # (a partial last block reads on behind the table's last column)
+    keep = jnp.pad(keep.astype(jnp.int32), ((0, 0), (0, (ppb - 1) * page_size)))
+    prefetch = [jnp.asarray(layer, jnp.int32), page_table, q_offset, kv_len]
+    blocks, in_specs = [q], [pl.BlockSpec((1, H, Dk), lambda b, qi, *_: (b, 0, 0))]
+    state = [pltpu.VMEM((gp, 128), jnp.float32), pltpu.VMEM((gp, 128), jnp.float32),
+             pltpu.VMEM((gp, value_width), jnp.float32)]
+    if shared_rows:
+        if shared is None:
+            shared = shared_head(page_table, kv_len, page_size, kv_len > 0)
+        prefetch += [jnp.asarray(x, jnp.int32) for x in shared]
+        blocks.append(jnp.pad(q, ((0, 0), (0, gp - H), (0, 0))).reshape(1, shared_rows, Dk))
+        in_specs.append(pl.BlockSpec((1, shared_rows, Dk), lambda b, qi, *_: (0, 0, 0)))
+        state += [pltpu.VMEM((shared_rows, 128), jnp.float32),
+                  pltpu.VMEM((shared_rows, 128), jnp.float32),
+                  pltpu.VMEM((shared_rows, value_width), jnp.float32),
+                  pltpu.SMEM((1,), jnp.int32)]
+    blocks.append(keep)
+    in_specs.append(pl.BlockSpec(keep.shape, lambda b, qi, *_: (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, 1),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, value_width), lambda b, qi, *_: (b, 0, 0)),
+        scratch_shapes=[*state, pltpu.VMEM((2, ppb) + pages.shape[2:], pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 1))],
+    )
+    kernel = functools.partial(
+        _paged_kernel, block_q=1, page_size=page_size, pages_per_block=ppb, n_kv=1,
+        group=H, pack=1, scale=scale, quantized=False, shared_rows=shared_rows,
+        latent_rows=per * gp)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, value_width), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=LATENT_VMEM_BYTES),
+        interpret=interpret,
+    )(*prefetch, *blocks, pages)

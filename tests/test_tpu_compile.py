@@ -510,3 +510,56 @@ def test_the_one_token_latent_attention_compiles_for_v5e_at_the_cell_shape(one_c
     for scope in ("dsa_indexer", "dsa_select", "mla_attention"):
         assert f"/{scope}/" in text, scope
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_the_walked_latent_attention_compiles_for_v5e_at_the_cell_shape(one_chip):
+    """The same call on a kernel backend (PR 41): a table of 128 pages is 8
+    selections of 2,048, so the rows WALK their pages — ONE custom call under
+    ``mla_attention`` (``paged_latent_attention``: the paged kernel's walk
+    with one source, 128 query rows a KV "head", 16 x 128 stacked rows for
+    the shared head, under a 48 MiB VMEM limit), the selection a mask made
+    under ``dsa_select`` without a sort, no gathered ``[32768, 640]`` copy."""
+    from finchat_tpu.ops.latent_attention import LatentShape, decode_attention
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *args: decode_attention(
+        *args, page_size=PAGE, shape=LatentShape(512, 2048, 0.135234), backend="pallas")).lower(
+        shape((ROWS, 128, 576)), shape((ROWS, 64, 128)), shape((ROWS, 64), jnp.float32),
+        shape((5, POOL, PAGE, 640)), shape((5, POOL, PAGE, 128)), shape((), jnp.int32),
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32), shape((ROWS,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "/mla_attention/" in calls[0]
+    assert "paged_latent_attention" in calls[0].split(" = ")[0]
+    assert "bf16[32768,640]" not in text and "bf16[16,2048,640]" not in text
+    assert " sort(" not in text
+    for scope in ("dsa_indexer", "dsa_select", "mla_attention"):
+        assert f"/{scope}/" in text, scope
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+def test_deepseek_decode_step_compiles_for_v5e_walking_its_latent_pages(one_chip):
+    """The whole decode step at the cell's size (1 dense + 4 routed layers, 16
+    slots, a table of 128 pages = 8 selections): the one-token latent
+    attention is ONE custom call a layer under ``mla_attention`` (one in the
+    scan's body, one in the leading dense layer), no gathered ``[32768, 640]``
+    copy and no sort under ``dsa_select``; both paged arrays are updated in
+    place, and the step's temporaries are a ninth of the gather form's."""
+    from perfbench.models import deepseek_v32
+
+    compiled, state = _compiled_decode_step(
+        one_chip, deepseek_v32, _config_file("deepseek-v3.2-exp"))
+    text = compiled.as_text()
+    walks = [line for line in text.splitlines()
+             if "/mla_attention/" in line and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(walks) == 2 and all(
+        "paged_latent_attention" in line.split(" = ")[0] for line in walks)
+    assert "bf16[32768,640]" not in text
+    assert not [line for line in text.splitlines() if "/dsa_select/" in line and " sort(" in line]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= state.k_pages.size * 2 + state.v_pages.size * 2
+    assert memory.temp_size_in_bytes < 64 * 1024 * 1024
