@@ -259,7 +259,7 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
                                page_size: int = 128, width: int = 128,
                                contexts: tuple[int, int] = (5000, 12000),
                                pool_pages: int = 1600, shared_pages: int = 0,
-                               quantized: bool = False) -> float:
+                               quantized: bool = False, window: int = 0) -> float:
     """Paged decode attention vs its oracle at the decode shape of the
     benchmark's cells (``mixtral-report-saturated``; 20 / 4 heads for
     ``falcon-h1-report-saturated``): ragged contexts under a page table far
@@ -269,7 +269,11 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
     gets the same cache with that page zeroed. With ``shared_pages`` every
     row holds the same physical pages at the head of its table, as the cells'
     16 rows hold the system prompt's 31: the kernel's shared-head pass reads
-    them once for all rows. Returns the max abs error."""
+    them once for all rows. With ``window`` (a sliding-window layer of
+    ``phi4-flash-report-saturated``: 40 / 10 heads, a table of 6 pages, the
+    contexts COMPACTED to what a row's bounded page list holds) a query masks
+    what lies ``window`` or more before it and no shared head is read.
+    Returns the max abs error."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -301,6 +305,8 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
     q = jax.random.normal(keys[4], (rows, 1, n_heads, head_dim), dtype)
     rest = (jnp.asarray(table), kv_len - 1, kv_len, jnp.asarray([1], jnp.int32))
     kw = dict(page_size=page_size, n_kv=n_kv)
+    if window:  # (the engine hands a window layer's call a shared head of no pages)
+        kw.update(window=window, shared=(jnp.zeros((rows,), jnp.int32), jnp.zeros((2,), jnp.int32)))
 
     def oracle(pages, **scales):
         # a row at a time: the oracle gathers a row's whole table, 0.12 GB of
@@ -330,6 +336,7 @@ def check_decode_at_cell_shape(backend: str, *, rows: int = 16, n_heads: int = 3
         want = oracle(pages)
     # a non-finite output here means a dead table entry was read
     name = "paged_attention[decode, cell shape" + (", int8" if quantized else "") + (
+        f", window {window}" if window else "") + (
         f", {shared_pages} shared pages]" if shared_pages else "]")
     return kernel_error(
         name, got, want,
@@ -1073,6 +1080,14 @@ def _run(mesh_model: int) -> int:
     check_ssm_step_at_cell_shape("pallas", heads=128, head_dim=64, state=128, groups=1,
                                  layers=9, layer=5)
     check_moe_at_cell_shape("pallas")
+    # one cache read by eight layers and eight window layers at their cell's
+    # shape (phi4-flash-report-saturated): 32 rows, 40 query heads of a PAIR's
+    # width over 10 K/V heads, on the system prompt's 31 pages; and a window
+    # layer's call over its rows' bounded page lists
+    check_decode_at_cell_shape("pallas", rows=32, n_heads=40, n_kv=10, shared_pages=31,
+                               contexts=(4000, 9000), pool_pages=3072)
+    check_decode_at_cell_shape("pallas", rows=32, n_heads=40, n_kv=10, width=6,
+                               contexts=(513, 768), pool_pages=217, window=512)
     # the parity engines share the app's weights; their own KV pools are
     # small — two slots, one prompt of a chunk and a half
     parity_cfg = dataclasses.replace(

@@ -117,6 +117,15 @@ class DecodeState:
     # these the layers' that carry state (config.n_state_layers)
     ssm_state: Array  # [Ls, max_seqs, *config.state_shape] float32 — the recurrence's state
     conv_state: Array  # [Ls, max_seqs, K-1, C] float32 — the conv's last inputs
+    # the second POOL (a model with sliding-window layers, config.window; None
+    # for every other model: no leaf, no operand): the window layers' pages,
+    # each slot's bounded page list of them from column 0 and the tokens
+    # before it — kv_gaps' compacted coordinates for ONE kind of layer, kept
+    # by the host (kv_cache.WindowPager) and uploaded when a list changes
+    win_k_pages: Array | None = None  # [Lw, Pw, page_size, Hkv*hd]
+    win_v_pages: Array | None = None
+    win_table: Array | None = None  # [max_seqs, window / page_size + 2] int32
+    win_gaps: Array | None = None  # [max_seqs] int32
 
 
 def create_state(
@@ -137,7 +146,31 @@ def create_state(
         kv_gaps=jnp.zeros((engine_cfg.max_seqs,), jnp.int32),
         rng=jax.random.key(engine_cfg.max_seqs),
         **_ssm_leaves(config, engine_cfg.max_seqs),
+        **_window_leaves(config, engine_cfg),
     )
+
+
+def window_pool_pages(config: LlamaConfig, engine_cfg: EngineConfig) -> int:
+    """Pages of the window layers' pool: every slot's bound, four shared
+    heads' beside (the system and tool prompts', and their successors while a
+    refresh retires them), and the trash page — what no traffic can exhaust."""
+    from finchat_tpu.engine.kv_cache import window_pages_per_row
+
+    per_row = window_pages_per_row(config.window, engine_cfg.page_size)
+    return (engine_cfg.max_seqs + 4) * per_row + 1
+
+
+def _window_leaves(config: LlamaConfig, engine_cfg: EngineConfig) -> dict[str, Array]:
+    if not config.window:
+        return {}
+    from finchat_tpu.engine.kv_cache import window_pages_per_row
+
+    pool = PagedKVCache.create_window(
+        config, window_pool_pages(config, engine_cfg), engine_cfg.page_size)
+    per_row = window_pages_per_row(config.window, engine_cfg.page_size)
+    return {"win_k_pages": pool.k_pages, "win_v_pages": pool.v_pages,
+            "win_table": jnp.zeros((engine_cfg.max_seqs, per_row), jnp.int32),
+            "win_gaps": jnp.zeros((engine_cfg.max_seqs,), jnp.int32)}
 
 
 def _ssm_leaves(config: LlamaConfig, max_seqs: int) -> dict[str, Array]:
@@ -159,6 +192,8 @@ def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array,
     the recurrent state (``ssm_rows`` says whose state each batch row is).
     With ``moe_live`` among ``kw``, ``forward``'s count comes back third."""
     cache = (state.k_pages, state.v_pages, state.k_scales, state.v_scales)
+    if config.window:  # (the FULL layer's pool, the WINDOW layers')
+        cache = (cache, (state.win_k_pages, state.win_v_pages, state.k_scales, state.v_scales))
     if not config.has_state:
         out, cache, *count = forward(params, tokens, positions, config=config,
                                      attention=attention, cache=cache, **kw)
@@ -168,10 +203,36 @@ def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array,
             params, tokens, positions, config=config, attention=attention,
             cache=cache, ssm_cache=(state.ssm_state, state.conv_state),
             ssm_rows=ssm_rows, **kw)
+    window = {}
+    if config.window:
+        cache, (win_k, win_v, _ks, _vs) = cache
+        window = {"win_k_pages": win_k, "win_v_pages": win_v}
     k_pages, v_pages, k_scales, v_scales = cache
     return out, dataclasses.replace(
         state, k_pages=k_pages, v_pages=v_pages, k_scales=k_scales,
-        v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1]), *count
+        v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1], **window), *count
+
+
+def _attention_by_kind(full, window):
+    """The attention callback of a model with a ``layer_plan``
+    (``sambay.attention``): the FULL layer writes and reads its pool through
+    ``full``, a CROSS layer reads it there and writes nothing (``k`` None), a
+    WINDOW layer goes through ``window`` over the second pool and the slots'
+    bounded page lists. The scopes are what the capture's readers look for."""
+    from finchat_tpu.models.sambay import WINDOW
+
+    def attention(q: Array, k: Array | None, v: Array | None, cache: Any, layer_idx: Array,
+                  kind: str):
+        pool, win_pool = cache
+        if kind == WINDOW:
+            with jax.named_scope("swa_attention"):
+                out, win_pool = window(q, k, v, win_pool, layer_idx)
+        else:
+            with jax.named_scope("yoco_attention"):
+                out, pool = full(q, k, v, pool, layer_idx)
+        return out, (pool, win_pool)
+
+    return attention
 
 
 @partial(jax.jit, donate_argnums=(0, 1))
@@ -245,6 +306,7 @@ def _paged_attention_fn(
     decode: bool = False,
     scale: float | None = None,
     latent: LatentShape | None = None,
+    window: int = 0,
 ):
     """Build the model's attention callback for paged prefill/decode.
 
@@ -302,19 +364,24 @@ def _paged_attention_fn(
         return _latent_attention(write, page_table, start_pos, n_valid, page_size, latent,
                                  attn_backend, shared=shared)
     shared = None
-    if decode and attn_backend != "ref":
+    if decode and attn_backend != "ref" and window:
+        shared = (jnp.zeros((page_table.shape[0],), jnp.int32), jnp.zeros((2,), jnp.int32))
+    elif decode and attn_backend != "ref":
         from finchat_tpu.ops.paged_attention import shared_head
 
         with jax.named_scope("paged_attention"):
             shared = shared_head(page_table, start_pos + n_valid, page_size,
                                  n_valid > 0)
+    window_kw = {"window": window} if window else {}
 
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
-        B, C = k.shape[:2]
+        B, C = q.shape[:2]
         layer = layer_idx.reshape(1)
-        if (C == 1 or inplace_append) and attn_backend != "ref":
+        if k is None:
+            pass  # a CROSS layer: the pages are another layer's, written already
+        elif (C == 1 or inplace_append) and attn_backend != "ref":
             # decode / spec verify: in-place single-page RMW appends (no
             # cache copy); token i of the chunk is valid iff i < n_valid
             with jax.named_scope("kv_append"):
@@ -353,7 +420,7 @@ def _paged_attention_fn(
                 layer, page_size=page_size, n_kv=n_kv, backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
-                shared=shared, scale=scale,
+                shared=shared, scale=scale, **window_kw,
             )
         return out, (k_pages, v_pages, k_scales, v_scales)
 
@@ -389,6 +456,11 @@ def prefill_step(
         page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
         latent=_latent_shape(config),
     )
+    if config.window:
+        attention = _attention_by_kind(attention, _paged_attention_fn(
+            state.win_table[slots], start_pos - state.win_gaps[slots], n_valid,
+            page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
+            window=config.window))
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
     # 64 x 128 x 128256 (an 8B model) — vs 33 MB for [N, vocab]
@@ -698,6 +770,11 @@ def decode_step(
         page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
         latent=_latent_shape(config),
     )
+    if config.window:
+        attention = _attention_by_kind(attention, _paged_attention_fn(
+            state.win_table, state.context_lens - state.win_gaps, n_valid,
+            page_size, config.n_kv_heads, attn_backend, decode=True,
+            scale=config.attention_scale, window=config.window))
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
     # pass over the layer's state, on `ref` a slice, _step and an update)
@@ -736,6 +813,8 @@ def _ragged_attention_fn(
     scale: float | None = None,  # the model's softmax scale (None = D ** -0.5)
     latent: LatentShape | None = None,  # a model with latent attention ...
     rows: SsmRows | None = None,  # ... and how its packed tokens lie in rows
+    window: int = 0,  # a sliding-window layer's callback (see _paged_attention_fn)
+    block_q: int = 0,  # the ragged kernel's query block (0 = its default)
 ):
     """Attention callback for the packed ragged step (``ragged_mixed_step``):
     per-token KV writes through the chunk scatter (one full-cache copy per
@@ -775,19 +854,23 @@ def _ragged_attention_fn(
         return _latent_attention(write, page_rows, start, rows.n_valid, page_size, latent,
                                  attn_backend, rows=rows)
 
+    # (jit keys on the keywords a call passes: the other models' calls stay as they were)
+    kernel_kw = {k: v for k, v in (("window", window), ("block_q", block_q)) if v}
+
     def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
-        T = k.shape[1]
+        T = q.shape[1]
         layer = layer_idx.reshape(1)
-        with jax.named_scope("kv_scatter_ragged"):
-            # each packed token is one (B=T, C=1) scatter row at its own
-            # COMPACTED position through its own page list
-            k_pages, v_pages, k_scales, v_scales = _scatter_kv(
-                (k_pages, v_pages, k_scales, v_scales),
-                k.reshape(T, 1, n_kv, -1), v.reshape(T, 1, n_kv, -1),
-                pt_tok, tok_wpos, n_valid_tok, page_size, layer_idx, n_kv,
-            )
+        if k is not None:  # (None: a CROSS layer reads pages another layer wrote)
+            with jax.named_scope("kv_scatter_ragged"):
+                # each packed token is one (B=T, C=1) scatter row at its own
+                # COMPACTED position through its own page list
+                k_pages, v_pages, k_scales, v_scales = _scatter_kv(
+                    (k_pages, v_pages, k_scales, v_scales),
+                    k.reshape(T, 1, n_kv, -1), v.reshape(T, 1, n_kv, -1),
+                    pt_tok, tok_wpos, n_valid_tok, page_size, layer_idx, n_kv,
+                )
         with jax.named_scope("ragged_paged_attention"):
             out = ragged_paged_attention(
                 q[0], k_pages, v_pages, page_rows, tok_row, tok_pos,
@@ -795,7 +878,7 @@ def _ragged_attention_fn(
                 backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
-                kv_gap=row_gap, scale=scale,
+                kv_gap=row_gap, scale=scale, **kernel_kw,
             )
         return out[None], (k_pages, v_pages, k_scales, v_scales)
 
@@ -888,11 +971,25 @@ def _ragged_round_math(
         )
 
     latent = _latent_shape(config)
+    # the ragged kernel's query block, read off the round and the model: a
+    # round of 2,048 tokens or more is prompts (at most max_seqs of its tokens
+    # are decode rows'), and where SEVERAL layers walk one layer's pages
+    # (config.cache_readers: a full-attention layer and the cross layers
+    # behind it) that walk at blocks of 8 is the round — 98 ms a reading layer
+    # at 8,192 tokens, 27 at blocks of 64 (PERF.md section 6, PR 42). A model
+    # whose layers each walk their own pages keeps the kernel's default at
+    # every size: not measured there (PERF.md section 7, Left by PR 42 (1))
+    block_q = 64 if T >= 2048 and config.cache_readers > 1 else 0
     attention = _ragged_attention_fn(
         page_rows, tok_row, tok_pos, row_kv_len, tok_valid,
         page_size, config.n_kv_heads, attn_backend, row_gap=row_gap, scale=config.attention_scale,
-        latent=latent, rows=packed_rows() if latent is not None else None,
+        latent=latent, rows=packed_rows() if latent is not None else None, block_q=block_q,
     )
+    if config.window:
+        attention = _attention_by_kind(attention, _ragged_attention_fn(
+            state.win_table[row_slot], tok_row, tok_pos, row_kv_len, tok_valid,
+            page_size, config.n_kv_heads, attn_backend, row_gap=state.win_gaps[row_slot],
+            scale=config.attention_scale, window=config.window, block_q=block_q))
     ssm_rows = packed_rows() if config.has_state else None
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes
@@ -1473,6 +1570,8 @@ class InferenceEngine:
                 spec_tokens=engine_cfg.spec_tokens,
             )
         self.bounded_kv = _bp if _bp.enabled else None
+        if config.window:
+            self._refuse_with_window_layers()
         # int8 KV composes with a mesh: pages shard over the fused KV-head
         # minor dim, scales over their head row dim (decode_state_shardings;
         # aligned blocks when Hkv % 8 == 0, replicated — they're ~6% of the
@@ -1510,6 +1609,97 @@ class InferenceEngine:
         self.params = params
         self.state = state
         self.sp_mode = self._resolve_sp_mode(engine_cfg.sp_mode)
+        # a model with sliding-window layers: the host's half of the second
+        # pool — each slot's bounded page list — and the context length of
+        # every slot as the host knows it (the steps that carry recurrent
+        # state advance a row by what the host packed, nothing is rewound)
+        self.window_pager = None
+        self._host_ctx = np.zeros((engine_cfg.max_seqs,), np.int64)
+        if config.window:
+            from finchat_tpu.engine.kv_cache import WindowPager
+
+            self.window_pager = WindowPager(
+                window_pool_pages(config, engine_cfg), engine_cfg.max_seqs, config.window,
+                engine_cfg.page_size)
+            self._window_gauges()
+
+    def _refuse_with_window_layers(self) -> None:
+        """Sliding-window layers keep a bounded page list of their OWN kind
+        (``kv_cache.WindowPager``); ``BoundedKVPolicy`` bounds a ROW for every
+        layer alike and is an approximation of full attention: the two do not
+        combine. The window's pages must also be whole, and a chunk must fit
+        beside the window in a row's bound."""
+        cfg, W = self.engine_cfg, self.config.window
+        if self.bounded_kv is not None:
+            raise ValueError(
+                f"engine.kv_sink_pages / engine.kv_window_pages bound a row's pages in EVERY "
+                f"layer (an approximate serving option); this model's {self.config.n_window_layers} "
+                f"sliding-window layers keep an exact window of {W} tokens on a bounded page "
+                "list of their own and its full-attention layer keeps the whole row: set both "
+                "to 0")
+        if W % cfg.page_size or cfg.prefill_chunk > 2 * cfg.page_size:
+            raise ValueError(
+                f"a model with sliding-window layers (window {W}) needs whole pages in the "
+                f"window (engine.page_size {cfg.page_size}) and a chunk of at most two pages "
+                f"(engine.prefill_chunk {cfg.prefill_chunk}): a row holds window / page_size + 2 "
+                "pages a window layer")
+        if cfg.kv_quant:
+            raise ValueError("engine.kv_quant has no sliding-window form: the int8 pages' "
+                             "kernels take no window")
+
+    def _window_gauges(self) -> None:
+        from finchat_tpu.engine.kv_cache import page_hbm_bytes
+        from finchat_tpu.utils.metrics import METRICS
+
+        per_page = page_hbm_bytes(self.config, self.page_size, kind="window")
+        METRICS.set_gauge("finchat_window_kv_bytes", self.window_pager.pages_in_use * per_page)
+        METRICS.set_gauge("finchat_window_kv_pool_bytes",
+                          self.window_pager.allocator.num_pages * per_page)
+
+    def prefill_room(self, start: int) -> int:
+        """Tokens ONE dispatch may carry for a row whose next token stands at
+        ``start``: what fits a row's bound of window pages (a chunk that
+        starts inside a page ends at a page's end); no limit for a model
+        without window layers."""
+        if self.window_pager is None:
+            return self.engine_cfg.max_seq_len
+        return self.window_pager.room(start)
+
+    def head_room(self) -> bool:
+        """Whether one more shared head may be registered: its trailing window
+        pages must fit beside every slot's bound (always, without window
+        layers)."""
+        return self.window_pager is None or self.window_pager.room_for_head()
+
+    def _window_advance(self, spans: list[tuple[int, int | None, int]]) -> None:
+        """Before a dispatch that carries ``(slot, start, tokens)`` rows
+        (``start`` None: the slot's context as the host knows it): slide every
+        row's window pages, upload the lists that changed, keep the host's
+        context lengths. (Callers ask ``window_pager`` first: the host copies
+        of a dispatch's descriptors are made for this alone.)"""
+        pager = self.window_pager
+        from finchat_tpu.utils.metrics import METRICS
+
+        freed = 0
+        for slot, start, n in spans:
+            start = int(self._host_ctx[slot]) if start is None else int(start)
+            freed += pager.advance(slot, start, int(n))
+            self._host_ctx[slot] = start + int(n)
+        if freed:
+            METRICS.inc("finchat_window_pages_freed_total", freed)
+        self._window_upload()
+
+    def _window_upload(self) -> None:
+        pager = self.window_pager
+        if pager is not None and pager.dirty:
+            pager.dirty = False
+            # COPIES: the pager goes on changing its arrays in place, and a
+            # device array made from a host buffer may alias it (the CPU
+            # backend) or read it after this returns (a transfer in flight)
+            self.state = dataclasses.replace(
+                self.state, win_table=jnp.asarray(pager.table.copy()),
+                win_gaps=jnp.asarray(pager.gaps.copy()))
+            self._window_gauges()
 
     def _refuse_without_state_carry(self, mesh) -> None:
         """A model with a mixer or with linear-attention layers
@@ -1531,7 +1721,7 @@ class InferenceEngine:
         if named:
             raise ValueError(
                 f"a model with recurrent state ({self.config.n_state_layers} layers: a "
-                f"Mamba-2 mixer or linear-attention layers) "
+                f"Mamba-2 mixer, Mamba-1 or linear-attention layers) "
                 f"carries it through prefill_step, decode_step and "
                 f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
 
@@ -1591,12 +1781,40 @@ class InferenceEngine:
             return None
         return _ssm_read_slot(self.state.ssm_state, self.state.conv_state, jnp.int32(slot))
 
+    def detach_head(self, slot: int) -> tuple | None:
+        """What a shared head keeps of the slot that just prefilled it:
+        ``ssm_snapshot``'s copy of the state and — a model with sliding-window
+        layers — the slot's trailing window pages, by OWNERSHIP and not by
+        copy: the slot holds none of them afterwards, a row admitted from the
+        snapshot references them, ``release_snapshot`` ends it. Only the
+        registration of a head calls this (``scheduler._head_snapshot``);
+        ``ssm_snapshot`` alone changes nothing."""
+        snap = self.ssm_snapshot(slot)
+        if snap is None or self.window_pager is None:
+            return snap
+        head = self.window_pager.detach_head(slot, int(self._host_ctx[slot]))
+        self._window_upload()
+        return (*snap, head)
+
+    def release_snapshot(self, snap: tuple | None) -> None:
+        """A head's snapshot (``detach_head``) is dropped: the window pages it
+        holds go back once no row reads them. No-op for every other snapshot."""
+        if self.window_pager is not None and snap is not None and len(snap) > 2:
+            self.window_pager.release_head(snap[2])
+            self._window_gauges()
+
     def ssm_restore(self, slot: int, snap: tuple) -> None:
-        """Start ``slot`` from a snapshot (admission from a shared head)."""
+        """Start ``slot`` from a snapshot (admission from a shared head: its
+        state copied in and, where the snapshot holds a head's window pages,
+        those referenced in place of the slot's own)."""
         ssm_state, conv_state = _ssm_load_slot(
-            self.state.ssm_state, self.state.conv_state, jnp.int32(slot), snap)
+            self.state.ssm_state, self.state.conv_state, jnp.int32(slot), tuple(snap[:2]))
         self.state = dataclasses.replace(
             self.state, ssm_state=ssm_state, conv_state=conv_state)
+        if self.window_pager is not None and len(snap) > 2:
+            self.window_pager.release(slot)
+            self.window_pager.share(slot, snap[2])
+            self._window_upload()
 
     def ssm_admit(self, rows: dict[int, tuple | None]) -> None:
         """Admission owns the state: every admitted slot starts from its
@@ -1607,6 +1825,10 @@ class InferenceEngine:
         cold = [slot for slot, snap in rows.items() if snap is None]
         if cold:
             self._ssm_clear(cold)
+            if self.window_pager is not None:  # (a head's window pages, like its state)
+                for slot in cold:
+                    self.window_pager.release(slot)
+                self._window_upload()
         for slot, snap in rows.items():
             if snap is not None:
                 self.ssm_restore(slot, snap)
@@ -1695,6 +1917,8 @@ class InferenceEngine:
             return
         idx = self._slot_rows(rows)
         vals = jnp.asarray(self._slot_values(rows.values(), len(idx)))
+        for slot, n in rows.items():
+            self._host_ctx[slot] = n
         self.state = dataclasses.replace(
             self.state, context_lens=self.state.context_lens.at[jnp.asarray(idx)].set(vals)
         )
@@ -1737,6 +1961,11 @@ class InferenceEngine:
             last_tokens=self.state.last_tokens.at[idx].set(0),
             kv_gaps=self.state.kv_gaps.at[idx].set(0),
         )
+        self._host_ctx[list(slots)] = 0
+        if self.window_pager is not None:
+            for slot in slots:
+                self.window_pager.release(slot)
+            self._window_upload()
         if self.config.has_state:
             self._ssm_clear(slots)
 
@@ -1793,6 +2022,10 @@ class InferenceEngine:
 
             state = shard_decode_state(state, self.mesh, self.config.n_kv_heads)
         self.state = state
+        self._host_ctx[:] = 0
+        if self.window_pager is not None:
+            self.window_pager.reset()
+            self._window_upload()
 
     def _use_ring_prefill(self, prompt_len: int) -> bool:
         return (
@@ -1878,6 +2111,10 @@ class InferenceEngine:
         jit keys on which keywords a call passes, so a second site that
         spelled them differently would serve variants warm-up never
         compiled."""
+        if self.window_pager is not None:
+            self._window_advance([
+                (int(s), int(p), int(n)) for s, p, n in zip(
+                    np.asarray(slots), np.asarray(start_pos), np.asarray(n_valid)) if n > 0])
         self.state, logits = prefill_step(
             self.params, self.state, tokens, slots, start_pos, n_valid,
             config=self.config, page_size=self.page_size,
@@ -2179,6 +2416,8 @@ class InferenceEngine:
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_decode_dispatches_total")
+        if self.window_pager is not None:
+            self._window_advance([(int(s), None, 1) for s in np.flatnonzero(np.asarray(active))])
         self.state, next_tokens, logits, self.moe_experts = decode_step(
             self.params, self.state, active, temperature, top_p, top_k,
             config=self.config, page_size=self.page_size,
@@ -2223,6 +2462,11 @@ class InferenceEngine:
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_mixed_dispatches_total")
+        if self.window_pager is not None:
+            self._window_advance([
+                (int(s), None if dev else int(p), int(n)) for s, p, n, dev in zip(
+                    np.asarray(row_slot), np.asarray(row_start), np.asarray(row_len),
+                    np.asarray(row_from_device)) if n > 0])
         self.state, emitted, n_emitted, row_logits, loop_block = (
             ragged_mixed_step(
                 self.params, self.state, tokens, tok_row, row_slot,

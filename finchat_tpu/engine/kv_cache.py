@@ -106,6 +106,23 @@ class PagedKVCache:
             page_size=page_size, num_pages=num_pages,
         )
 
+    @classmethod
+    def create_window(cls, config: LlamaConfig, num_pages: int,
+                      page_size: int) -> "PagedKVCache":
+        """The second pool of a model with sliding-window layers
+        (``config.n_window_layers`` deep): the same rows as the first pool's,
+        never quantized; a row's pages of it are the window's alone
+        (``WindowPager``)."""
+        k_row, v_row = config.kv_row_widths
+        shape = (config.n_window_layers, num_pages, page_size)
+        return cls(
+            k_pages=jnp.zeros((*shape, k_row), config.dtype),
+            v_pages=jnp.zeros((*shape, v_row), config.dtype),
+            k_scales=jnp.zeros((1, 1, 1, 1), jnp.float32),
+            v_scales=jnp.zeros((1, 1, 1, 1), jnp.float32),
+            page_size=page_size, num_pages=num_pages,
+        )
+
     def layers_pytree(self) -> tuple[Any, Any, Any, Any]:
         """The (k, v, k_scales, v_scales) tuple carried through the model
         forward as the cache (scales are placeholders when kv_quant is
@@ -117,14 +134,19 @@ class PagedKVCache:
                 + self.k_scales.nbytes + self.v_scales.nbytes)
 
 
-def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "") -> int:
+def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "",
+                   kind: str = "full") -> int:
     """HBM bytes ONE page costs across all layers that own pages (K+V, plus
     the int8 scale rows) — computed WITHOUT allocating, so harnesses can fit a KV
     pool to an HBM budget before engine construction. Mirrors
     ``PagedKVCache.create``'s shapes exactly (asserted in
-    tests/test_kv_cache.py)."""
+    tests/test_kv_cache.py); with ``kind`` "window" a page of the second pool
+    (``create_window``: the sliding-window layers', never quantized)."""
     import numpy as np
 
+    if kind == "window":
+        return (config.n_window_layers * page_size * sum(config.kv_row_widths)
+                * np.dtype(config.dtype).itemsize)
     itemsize = 1 if kv_quant else np.dtype(config.dtype).itemsize
     per = config.n_attn_layers * page_size * sum(config.kv_row_widths) * itemsize
     if kv_quant:
@@ -188,6 +210,14 @@ class PageAllocator:
     def owned_by(self, seq_id: str) -> list[int]:
         return [p for p, s in self._owner.items() if s == seq_id]
 
+    def transfer(self, pages: list[int], seq_id: str, to: str) -> None:
+        """Hand ``pages`` from one owner to another (a slot's window pages
+        become a shared head's): nothing is freed in between."""
+        for p in pages:
+            if self._owner.get(p) != seq_id:
+                raise PageAllocationError(f"page {p} is not {seq_id}'s to hand to {to}")
+            self._owner[p] = to
+
     def reset(self) -> None:
         """Return EVERY page to the free list, dropping all ownership —
         the engine-rebuild path (scheduler breaker trip): the device KV
@@ -210,6 +240,188 @@ class PageAllocator:
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
     return max(1, -(-n_tokens // page_size))
+
+
+def window_pages_per_row(window: int, page_size: int) -> int:
+    """The most pages of ONE sliding-window layer a row holds, whatever its
+    length: the window's own and two more — the page the window's oldest token
+    shares with tokens that slid out, and the page a dispatch's new tokens
+    reach into."""
+    return window // page_size + 2
+
+
+@dataclass
+class WindowHead:
+    """A shared head's trailing window pages: what a row admitted from the
+    head reads, by reference, until its own window slides past them."""
+
+    owner: str
+    first: int  # the logical page of ``pages[0]``
+    pages: list[int]
+    released: bool = False
+
+
+class WindowPager:
+    """Host-side page lists of the sliding-window layers' pool: a row (an
+    engine slot) holds the pages that cover its window and nothing older.
+
+    A row's list covers the CONTIGUOUS logical pages ``first .. first +
+    len(pages) - 1``; the device sees it compacted — ``table[slot]`` the
+    physical pages from column 0, ``gaps[slot] = first * page_size`` the tokens
+    before them — which is the coordinate shift bounded KV already runs under
+    (``DecodeState.kv_gaps``), applied to ONE kind of layer. ``advance`` runs
+    before every dispatch: pages wholly behind the window of the dispatch's
+    first query go back to the allocator (or, a shared head's, lose this row's
+    reference), pages up to the dispatch's last token are allocated. A row
+    never holds more than ``window_pages_per_row``; ``room`` is how many
+    tokens a dispatch may carry for that to hold.
+
+    A head's pages are read-only and counted by reference: the head's own and
+    one a row. They return to the allocator when the head is released AND the
+    last row slid past them."""
+
+    def __init__(self, num_pages: int, max_seqs: int, window: int, page_size: int):
+        import numpy as np
+
+        if window % page_size:
+            raise ValueError(f"window {window} is not a whole number of {page_size}-token pages")
+        self.window, self.page_size = window, page_size
+        self.per_row = window_pages_per_row(window, page_size)
+        if num_pages < max_seqs * self.per_row + 1:
+            raise ValueError(
+                f"{num_pages} window pages cannot hold {max_seqs} rows of {self.per_row} "
+                f"(and the trash page)")
+        self.allocator = PageAllocator(num_pages)
+        self.table = np.zeros((max_seqs, self.per_row), np.int32)
+        self.gaps = np.zeros((max_seqs,), np.int32)
+        self._first = [0] * max_seqs
+        self._pages: list[list[int]] = [[] for _ in range(max_seqs)]
+        self._refs: dict[int, int] = {}  # a head's page -> references (the head's own is one)
+        self._heads: dict[int, WindowHead] = {}  # a head's page -> its head
+        self._n_heads = 0
+        self.dirty = False
+
+    def _lowest(self, start: int) -> int:
+        """The logical page of the oldest token a query at ``start`` sees."""
+        return max(start - self.window + 1, 0) // self.page_size
+
+    def room(self, start: int) -> int:
+        """Tokens a dispatch starting at position ``start`` may carry."""
+        return (self._lowest(start) + self.per_row) * self.page_size - start
+
+    def pages_of(self, slot: int) -> list[int]:
+        return list(self._pages[slot])
+
+    @property
+    def pages_in_use(self) -> int:
+        return self.allocator.used_count
+
+    def _drop(self, slot: int, page: int) -> int:
+        """The row lets go of ``page``; returns 1 if it went back to the pool."""
+        if page not in self._refs:
+            self.allocator.free(f"w{slot}", [page])
+            return 1
+        return self._unref(page)
+
+    def _unref(self, page: int) -> int:
+        if page not in self._refs:  # a head that outlived a ``reset``
+            return 0
+        self._refs[page] -= 1
+        if self._refs[page]:
+            return 0
+        del self._refs[page]
+        self.allocator.free(self._heads.pop(page).owner, [page])
+        return 1
+
+    def advance(self, slot: int, start: int, incoming: int) -> int:
+        """Before a dispatch of ``incoming`` tokens from position ``start``:
+        returns the pages that went back to the allocator."""
+        pages, ps = self._pages[slot], self.page_size
+        lo, hi = self._lowest(start), (start + incoming - 1) // ps
+        if hi - lo >= self.per_row:
+            raise PageAllocationError(
+                f"a dispatch of {incoming} tokens from {start} spans {hi - lo + 1} window "
+                f"pages; a row holds {self.per_row} (see WindowPager.room)")
+        freed, held = 0, len(pages)
+        while pages and self._first[slot] < lo:
+            freed += self._drop(slot, pages.pop(0))
+            self._first[slot] += 1
+        if not pages:
+            self._first[slot] = lo
+        need = hi + 1 - (self._first[slot] + len(pages))
+        if need > 0:
+            pages += self.allocator.allocate(f"w{slot}", need)
+        if need > 0 or len(pages) != held:
+            self.dirty = True
+            self.table[slot] = 0
+            self.table[slot, :len(pages)] = pages
+            self.gaps[slot] = self._first[slot] * ps
+        return freed
+
+    def release(self, slot: int) -> None:
+        """The row is over: its own pages go back, a head's lose its reference."""
+        for page in self._pages[slot]:
+            self._drop(slot, page)
+        self._pages[slot] = []
+        self._first[slot] = 0
+        self.table[slot] = 0
+        self.gaps[slot] = 0
+        self.dirty = True
+
+    def detach_head(self, slot: int, n_tokens: int) -> WindowHead:
+        """The row just prefilled a shared head of ``n_tokens``: its pages
+        that a row CONTINUING from there will read become the head's (the
+        older ones go back), and the slot holds nothing."""
+        lo = self._lowest(n_tokens)
+        keep = self._pages[slot][max(lo - self._first[slot], 0):]
+        for page in self._pages[slot][:len(self._pages[slot]) - len(keep)]:
+            self._drop(slot, page)
+        head = WindowHead(f"wh{self._n_heads}", max(lo, self._first[slot]), keep)
+        self._n_heads += 1
+        self.allocator.transfer(keep, f"w{slot}", head.owner)
+        for page in keep:
+            self._refs[page] = 1
+            self._heads[page] = head
+        self._pages[slot] = []
+        self.release(slot)
+        return head
+
+    def room_for_head(self) -> bool:
+        """One more head's pages fit beside every slot's bound: what heads
+        hold now (retired ones that rows still read too) and one more."""
+        reserve = self.allocator.num_pages - 1 - len(self._pages) * self.per_row
+        return len(self._refs) + self.per_row <= reserve
+
+    def share(self, slot: int, head: WindowHead) -> None:
+        """Start the row from ``head``'s pages, by reference."""
+        assert not self._pages[slot] and not head.released
+        for page in head.pages:
+            self._refs[page] += 1
+        self._pages[slot] = list(head.pages)
+        self._first[slot] = head.first
+        self.table[slot] = 0
+        self.table[slot, :len(head.pages)] = head.pages
+        self.gaps[slot] = head.first * self.page_size
+        self.dirty = True
+
+    def release_head(self, head: WindowHead) -> None:
+        """The head is retired: its own reference goes; a page returns to the
+        allocator now, or when the last row slides past it."""
+        if not head.released:
+            head.released = True
+            for page in head.pages:
+                self._unref(page)
+
+    def reset(self) -> None:
+        """Every page back (the engine's state was rebuilt)."""
+        self.allocator.reset()
+        self.table[:] = 0
+        self.gaps[:] = 0
+        self._first = [0] * len(self._first)
+        self._pages = [[] for _ in self._pages]
+        self._refs.clear()
+        self._heads.clear()
+        self.dirty = True
 
 
 @dataclass(frozen=True)

@@ -605,6 +605,11 @@ class ContinuousBatchingScheduler:
         # from, so those rows recompute from their tokens (counted) or the
         # option is refused here
         self.has_ssm = engine.config.has_state
+        # (a stand-in engine has no window layers and no limit on a chunk)
+        self._prefill_room = getattr(engine, "prefill_room", lambda pos: 1 << 30)
+        self._window = getattr(engine.config, "window", 0)
+        if self._window:
+            self.metrics.inc("finchat_window_pages_freed_total", 0.0)
         self.metrics.set_gauge("finchat_ssm_state_bytes",
                                getattr(engine, "ssm_state_bytes", 0))
         if self.has_ssm:
@@ -964,7 +969,11 @@ class ContinuousBatchingScheduler:
             kind=kind, rows=len(riders), kv_tokens=kv_tokens,
             kv_tokens_distinct=kv_tokens - sum(sum(t) - max(t) for t in heads.values()),
             prefix_rows=len(most) if len(most) > 1 else 0,
-            **({"form": self._latent_form} if self._latent_form else {}))
+            **({"form": self._latent_form} if self._latent_form else {}),
+            # a model with sliding-window layers: the tokens ONE window layer
+            # reads (a row's context up to the window)
+            **({"window_tokens": sum(min(kv, self._window) for *_row, kv in riders)}
+               if self._window else {}))
 
     @staticmethod
     def _rider(handle: SequenceHandle, mode: str, drafts: int = 0) -> tuple:
@@ -1104,6 +1113,21 @@ class ContinuousBatchingScheduler:
                     shared_len, len(pages))
         return shared_len
 
+    def _release_snapshot(self, entry: "_PrefixEntry") -> None:
+        """A head is dropped: what its snapshot holds beside the state (a
+        model with sliding-window layers: the head's trailing window pages)
+        goes back. A test's stand-in engine has nothing to release."""
+        release = getattr(self.engine, "release_snapshot", None)
+        if release is not None:
+            release(entry.ssm_snap)
+
+    def _chunk(self, ids: list[int], pos: int, C: int) -> list[int]:
+        """The next chunk of ``ids`` from ``pos``: ``C`` tokens, or what a
+        row's bound of window pages leaves room for (``engine.prefill_room``:
+        a model with sliding-window layers, a chunk that starts inside a
+        page)."""
+        return ids[pos : pos + min(C, self._prefill_room(pos))]
+
     def _head_snapshot(self, slot: int) -> tuple | None:
         """The recurrent state ``slot`` holds after a head's last token, to
         keep with the head's pages (None for a model without a mixer). Taken
@@ -1111,7 +1135,9 @@ class ContinuousBatchingScheduler:
         if not self.has_ssm:
             return None
         self.metrics.inc("finchat_ssm_snapshots_total", labels={"kind": "head"})
-        return self.engine.ssm_snapshot(slot)
+        # (detach_head: the state's copy and, by ownership, the slot's window
+        # pages; a stand-in engine has the copy alone)
+        return getattr(self.engine, "detach_head", self.engine.ssm_snapshot)(slot)
 
     def _fabric_restore_head(self, ids: list[int], shared_len: int,
                              pages: list[int]) -> bool:
@@ -1187,7 +1213,8 @@ class ContinuousBatchingScheduler:
         for job in self._prefix_jobs:
             if job.shared_len == shared_len and job.ids == ids:
                 return 0  # registration already in flight; caller may retry
-        if not self.allocator.can_allocate(n_pages) or not self.free_slots:
+        if (not self.allocator.can_allocate(n_pages) or not self.free_slots
+                or not getattr(self.engine, "head_room", lambda: True)()):
             logger.warning("prefix cache: no pages/slot free; not registering")
             return 0
         owner = f"__prefix_{self._n_prefixes_ever}__"
@@ -1280,6 +1307,7 @@ class ContinuousBatchingScheduler:
         for entry in list(self._prefixes):
             if entry.retired and entry.refs == 0:
                 self.allocator.free(entry.owner, entry.pages)
+                self._release_snapshot(entry)
                 self._prefixes.remove(entry)
 
     def _match_prefix(self, prompt_ids: list[int]) -> tuple["_PrefixEntry | None", int]:
@@ -2768,7 +2796,8 @@ class ContinuousBatchingScheduler:
             rows = [(h.slot, h.prompt_ids, h.prefill_pos) for h in batch]
             rows += [(j.slot, j.ids, j.pos) for j in jobs]
             N = round_up_pow2(len(rows))
-            tokens, slots, starts, n_valids = self._pack_prefill_rows(rows, N, C)
+            tokens, slots, starts, n_valids = self._pack_prefill_rows(
+                rows, N, C, self._prefill_room)
             with (TRACER.phase("dispatch", self._phases),
                   Timer(self.metrics, "finchat_prefill_seconds") as _pt):
                 # host-side dispatch time for the round (device work is
@@ -2837,7 +2866,7 @@ class ContinuousBatchingScheduler:
                     self._evict(handle, "error", error=str(e))
 
     @staticmethod
-    def _pack_prefill_rows(rows, N: int, C: int):
+    def _pack_prefill_rows(rows, N: int, C: int, room=lambda pos: 1 << 30):
         """Ragged row arrays for a chunked split-path round
         (_prefill_round; the packed ragged round builds its own buffer):
         one chunk per ``(slot, ids, pos)`` row; padding rows carry the
@@ -2848,7 +2877,7 @@ class ContinuousBatchingScheduler:
         n_valids = np.zeros((N,), np.int32)
         slots[:] = rows[0][0]
         for i, (slot, ids, pos) in enumerate(rows):
-            chunk = ids[pos : pos + C]
+            chunk = ids[pos : pos + min(C, room(pos))]
             tokens[i, : len(chunk)] = chunk
             slots[i] = slot
             starts[i] = pos
@@ -3264,7 +3293,7 @@ class ContinuousBatchingScheduler:
 
         i = 0
         for h in batch:
-            chunk = h.prompt_ids[h.prefill_pos : h.prefill_pos + C]
+            chunk = self._chunk(h.prompt_ids, h.prefill_pos, C)
             row_slot[i] = h.slot
             row_start[i] = h.prefill_pos
             row_len[i] = len(chunk)
@@ -3287,7 +3316,7 @@ class ContinuousBatchingScheduler:
             prefill_rows.append((i, h))
             i += 1
         for job in jobs:
-            chunk = job.ids[job.pos : job.pos + C]
+            chunk = self._chunk(job.ids, job.pos, C)
             row_slot[i] = job.slot
             row_start[i] = job.pos
             row_len[i] = len(chunk)

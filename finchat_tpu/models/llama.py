@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array, lax
 
-from finchat_tpu.models import gdn, mla
+from finchat_tpu.models import gdn, mla, sambay
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
 from finchat_tpu.models.ssm import mixer, scaled
 from finchat_tpu.ops import moe_step
@@ -40,6 +40,10 @@ AttentionFn = Callable[[Array, Array, Array, Any, Array], tuple[Array, Any]]
 # a recurrent state by slot (models/gdn.py), or the Mamba-2 mixer alone
 # (models/ssm.py) over its own state by slot. Each is followed by the MLP
 FULL, LINEAR, MAMBA = "full_attention", "linear_attention", "mamba"
+# the kinds only a ``layer_plan`` may name (models/sambay.py): attention over a
+# window with a bounded page list of its own, the Mamba-1 mixer, a gated memory
+# unit (no state, no cache) and attention that reads another layer's pages
+WINDOW, MAMBA1, GMU, CROSS = sambay.WINDOW, sambay.MAMBA1, sambay.GMU, sambay.CROSS
 
 # moe_mlp's one rule among its THREE forms (see there; ``_moe_form``). Dense
 # dispatch computes every held expert over every token: router width / picks
@@ -174,10 +178,41 @@ class LlamaConfig:
     # run before the scan; ``n_layers`` counts them
     leading_dense_layers: int = 0
     dense_hidden_dim: int = 0
+    # layers of more than one kind in more than one RUN of periods: segments
+    # of (the kinds of one period, its repeats), one after another down the
+    # depth — 8 x (mamba1, sliding_attention), 1 x (mamba1, full_attention),
+    # 7 x (gmu, cross_attention) is Phi-4-mini-flash (models/sambay.py has the
+    # kinds and their block: LayerNorm or RMSNorm, a fused [gate | up] MLP).
+    # Empty = a ``layer_pattern`` or none: the blocks above. Parameters and
+    # caches are stacked by kind as under a pattern; WINDOW layers own a second
+    # pool and a bounded page list a row, CROSS layers own nothing
+    layer_plan: tuple[tuple[tuple[str, ...], int], ...] = ()
+    # a WINDOW layer attends the token itself and the ``window - 1`` before it
+    window: int = 0
+    # the MAMBA1 layers' mixer: channels E, state channels a channel N, the
+    # rank dt passes through, the causal depthwise conv's width
+    m1_inner: int = 0
+    m1_state: int = 16
+    m1_dt_rank: int = 0
+    m1_conv: int = 4
+    # (a plan's block is models/sambay.py's and has ONE form: differential
+    # attention — n_heads / n_kv_heads / head_dim are then the kernel's, a
+    # pair's width —, LayerNorm with bias, biases on the attention projections)
 
     def __post_init__(self) -> None:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.dim // self.n_heads)
+        if self.layer_plan:
+            if (self.layer_pattern or self.ssm_heads or self.gdn_heads or self.kv_lora_rank
+                    or self.n_experts or self.leading_dense_layers or self.qk_norm
+                    or self.norm_after):
+                raise ValueError("a layer_plan is not combined with a layer_pattern, a Mamba-2 "
+                                 "mixer, linear or latent attention, experts, qk_norm or "
+                                 "norm_after")
+            sambay.validate(self)
+        elif self.window or self.m1_inner:
+            raise ValueError(f"window and m1_inner are a layer_plan's ({WINDOW!r}, {MAMBA1!r}, "
+                             f"{GMU!r} and {CROSS!r} layers)")
         if self.kv_lora_rank:
             if self.head_dim != self.qk_nope_dim + self.qk_rope_dim or self.n_kv_heads != 1:
                 raise ValueError(
@@ -213,7 +248,8 @@ class LlamaConfig:
         if pattern:
             if set(pattern) - {FULL, LINEAR, MAMBA} or self.n_layers % len(pattern):
                 raise ValueError(
-                    f"layer_pattern {pattern}: kinds are {FULL!r}, {LINEAR!r} and {MAMBA!r}, "
+                    f"layer_pattern {pattern}: kinds are {FULL!r}, {LINEAR!r} and {MAMBA!r} "
+                    f"({WINDOW!r}, {MAMBA1!r}, {GMU!r} and {CROSS!r} are a layer_plan's), "
                     f"and n_layers ({self.n_layers}) is a whole number of periods")
             if (MAMBA in pattern) != bool(self.ssm_heads):
                 raise ValueError(f"under a layer_pattern, ssm_heads and {MAMBA!r} layers go "
@@ -237,6 +273,8 @@ class LlamaConfig:
 
     def n_of(self, kind: str) -> int:
         """Layers of ``kind``: the depth of that kind's stacks."""
+        if self.layer_plan:
+            return sambay.kinds_of(self.layer_plan).count(kind)
         if not self.layer_pattern:
             return self.n_layers if kind == FULL else 0
         return self.layer_pattern.count(kind) * (self.n_layers // len(self.layer_pattern))
@@ -245,6 +283,17 @@ class LlamaConfig:
     def n_attn_layers(self) -> int:
         """Layers that own K/V pages: the depth of the page pool."""
         return self.n_of(FULL)
+
+    @property
+    def cache_readers(self) -> int:
+        """Layers that walk ONE layer's pages of the full pool in a step: the
+        layer that wrote them and, under a plan, the CROSS layers behind it."""
+        return 1 + self.n_of(CROSS) if self.layer_plan else 1
+
+    @property
+    def n_window_layers(self) -> int:
+        """Layers that own WINDOW pages: the depth of the second pool."""
+        return self.n_of(WINDOW)
 
     @property
     def n_scan_layers(self) -> int:
@@ -276,7 +325,7 @@ class LlamaConfig:
         ``DecodeState.ssm_state`` / ``conv_state``."""
         if self.ssm_heads and not self.layer_pattern:
             return self.n_layers
-        return self.n_of(LINEAR) + self.n_of(MAMBA)
+        return self.n_of(LINEAR) + self.n_of(MAMBA) + self.n_of(MAMBA1)
 
     @property
     def moe_sparse(self) -> bool:
@@ -302,6 +351,8 @@ class LlamaConfig:
         if self.gdn_heads:
             n = self.gdn_tile_heads
             return (self.gdn_heads // n, self.gdn_key_dim, n * self.gdn_value_dim)
+        if self.m1_inner:  # a MAMBA1 layer's [N, E]: the channels along the lanes
+            return (1, self.m1_state, self.m1_inner)
         return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
 
     @property
@@ -320,6 +371,8 @@ class LlamaConfig:
         """One slot's conv tail in one layer (float32): the last K-1 inputs."""
         if self.gdn_heads:
             return (self.gdn_conv - 1, self.gdn_conv_dim)
+        if self.m1_inner:
+            return (self.m1_conv - 1, self.m1_inner)
         return (self.ssm_conv - 1, self.ssm_conv_dim)
 
     @property
@@ -376,6 +429,8 @@ def n_params(config: LlamaConfig) -> int:
     """Analytic parameter count (no materialization); tests hold
     ``init_params`` and the benchmark's adapters to it."""
     c = config
+    if c.layer_plan:
+        return sambay.n_params(c)
     d, hd = c.dim, c.head_dim
     attn = d * (c.n_heads * hd) + 2 * d * (c.n_kv_heads * hd) + (c.n_heads * hd) * d
     if c.kv_lora_rank:
@@ -468,6 +523,14 @@ def init_params(
             return tf(name, leaf)
         return tf(name, (jax.random.normal(k, shape, gen_dtype) * fan_in ** -0.5).astype(c.dtype))
 
+    if c.layer_plan:
+        params = {"embed": rand_init("embed", k_embed, (c.vocab_size, c.dim), c.dim),
+                  "layers": sambay.init_layers(c, k_layers, rand_init),
+                  "norm": jnp.ones((c.dim,), c.dtype),
+                  "norm_b": jnp.zeros((c.dim,), c.dtype)}
+        if not c.tie_embeddings:
+            params["lm_head"] = rand_init("lm_head", k_head, (c.dim, c.vocab_size), c.dim)
+        return params
     keys = jax.random.split(k_layers, 8)
     L, D, H, Hkv, hd, F = c.n_scan_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
     La = c.n_attn_layers - c.leading_dense_layers
@@ -1046,6 +1109,13 @@ def forward(
             "does not carry it")
     with jax.named_scope("embed"):
         x = scaled(params["embed"][tokens], c.embedding_multiplier)  # [B,S,D]
+    if c.layer_plan:
+        assert moe_live is None, "a layer_plan routes nothing"
+        x, new_cache = _forward_plan(params, x, c, attention, cache, ssm_cache, ssm_rows,
+                                     remat, qm_backend)
+        if not return_hidden:
+            x = lm_head(params, x, config=c, qm_backend=qm_backend)
+        return x, new_cache
 
     # the scan runs over PERIODS of the layer pattern; inside one the kinds
     # are static and its layers stand one after another in the body. A period
@@ -1122,6 +1192,67 @@ def forward(
     return (x, new_cache) if moe_live is None else (x, new_cache, experts)
 
 
+def _forward_plan(params: dict[str, Any], x: Array, config: LlamaConfig, attention: Any,
+                  cache: Any, ssm_cache: Any, ssm_rows: Any, remat: bool,
+                  qm_backend: str | None) -> tuple[Array, Any]:
+    """``forward``'s layers under a ``layer_plan``: one scan a segment over
+    its periods (a segment of one period stands unrolled), a layer's leaves
+    indexed out of the whole stacks by the count of earlier layers that have
+    the leaf (``sambay.stack_kinds``). The carry holds, beside the caches, the
+    memory the last MAMBA1 layer left for the GMU layers: one token's ``y``,
+    never a state. ``cache`` is ``(the FULL layer's pool, the WINDOW layers'
+    pool)`` (each the four arrays of ``PagedKVCache.layers_pytree``), or None:
+    then the FULL layer's K and V ride in its place for the CROSS layers."""
+    c = config
+    stacks = params["layers"]
+    kinds = sambay.kinds_of(c.layer_plan)
+    B, S, _ = x.shape
+    memory = jnp.zeros((B, S, c.m1_inner), x.dtype) if GMU in kinds else None
+    if cache is None and CROSS in kinds:
+        kv = jnp.zeros((B, S, c.n_kv_heads, c.head_dim), x.dtype)
+        cache = (kv, kv)
+    # the segment whose MAMBA1 layers' y the GMU layers read: the last with one
+    memory_segment = max((i for i, (p, _r) in enumerate(c.layer_plan) if MAMBA1 in p),
+                         default=-1) if GMU in kinds else -1
+    carry = (x, cache, ssm_cache, memory)
+    before: dict[str, int] = {k: 0 for k in sambay.PLAN_KINDS}  # layers of a kind so far
+    depth0 = 0
+    for seg, (period, repeats) in enumerate(c.layer_plan):
+        def body(carry, p_idx, period=period, before=dict(before), depth0=depth0, seg=seg):
+            x, cache, ssm, memory = carry
+            for j, kind in enumerate(period):
+                def at(of):  # this layer's index among the layers of kinds ``of``
+                    return sum(before[k] + p_idx * period.count(k) + period[:j].count(k)
+                               for k in of)
+
+                lp = {name: jax.tree.map(
+                    lambda a, i=at(sambay.stack_kinds(name)): lax.dynamic_index_in_dim(
+                        a, i, 0, keepdims=False), leaf)
+                    for name, leaf in stacks.items() if kind in sambay.stack_kinds(name)}
+                # a CROSS layer reads the pool of the FULL layer before it
+                cache_idx = at((FULL,)) - 1 if kind == CROSS else at((kind,))
+                x, cache, ssm, memory = sambay.layer(
+                    x, lp, c, kind=kind, depth=depth0 + p_idx * len(period) + j,
+                    attention_fn=attention, cache=cache,
+                    cache_idx=jnp.asarray(cache_idx, jnp.int32), ssm_cache=ssm,
+                    state_idx=jnp.asarray(at((MAMBA1,)), jnp.int32), ssm_rows=ssm_rows,
+                    memory=memory, keep_memory=seg == memory_segment, qm_backend=qm_backend)
+            return (x, cache, ssm, memory), None
+
+        if remat:
+            body = jax.checkpoint(body)
+        if repeats == 1:
+            carry, _ = body(carry, 0)
+        else:
+            carry, _ = lax.scan(body, carry, jnp.arange(repeats))
+        for k in sambay.PLAN_KINDS:
+            before[k] += repeats * period.count(k)
+        depth0 += repeats * len(period)
+    x, cache, ssm_cache, _memory = carry
+    x = sambay.layer_norm(x, params["norm"], params["norm_b"], c.norm_eps)
+    return x, (cache if ssm_cache is None else (cache, ssm_cache))
+
+
 @jax.named_scope("head")
 def lm_head(params: dict[str, Any], x: Array, *, config: LlamaConfig,
             qm_backend: str | None = None) -> Array:
@@ -1164,6 +1295,24 @@ def make_causal_attention(backend: str, scale: float | None = None,
             return out, layer_cache, selected
 
         return latent
+
+    if config is not None and config.layer_plan:
+        from finchat_tpu.ops.refs import mha_reference
+
+        def by_kind(q: Array, k: Array | None, v: Array | None, layer_cache: Any,
+                    layer_idx: Array, kind: str = FULL):
+            # dense over the sequence: the FULL layer leaves its K and V in the
+            # cache's place, a CROSS layer reads them, a WINDOW layer masks
+            if kind == CROSS:
+                k, v = layer_cache
+            elif kind == FULL:
+                layer_cache = (k, v)
+            if kind == WINDOW:
+                return mha_reference(q, k, v, causal=True, scale=scale,
+                                     window=config.window), layer_cache
+            return causal_attention(q, k, v, backend=backend, scale=scale), layer_cache
+
+        return by_kind
 
     def attention(q: Array, k: Array, v: Array, layer_cache: Any, layer_idx: Array) -> tuple[Array, Any]:
         return causal_attention(q, k, v, backend=backend, scale=scale), layer_cache

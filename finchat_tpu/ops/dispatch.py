@@ -61,6 +61,7 @@ def paged_attention(
     v_scales: Array | None = None,
     shared: tuple[Array, Array] | None = None,  # decode: ``shared_head``'s
     scale: float | None = None,  # the softmax scale; None = D ** -0.5
+    window: int = 0,  # > 0: a sliding-window layer (a query and the window - 1 before it)
 ) -> Array:
     """Paged-KV attention via the requested (or default) backend. An int8
     cache (engine kv_quant) is detected from the page dtype; the scale
@@ -81,11 +82,14 @@ def paged_attention(
             lay, n_kv, dtype=q.dtype,
         )
         return mha_reference(
-            q, k_all, v_all, causal=True, q_offset=q_offset, kv_len=kv_len, scale=scale
+            q, k_all, v_all, causal=True, q_offset=q_offset, kv_len=kv_len, scale=scale,
+            window=window,
         )
     interpret = backend == "pallas-interpret"
     if quantized:
         from finchat_tpu.ops.paged_attention import paged_flash_attention_q8
+
+        assert not window, "no sliding-window form over the int8 cache"
 
         return paged_flash_attention_q8(
             q, k_pages, v_pages, k_scales, v_scales, page_table,
@@ -97,6 +101,7 @@ def paged_attention(
     return paged_flash_attention(
         q, k_pages, v_pages, page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
+        **({"window": window} if window else {}),
     )
 
 
@@ -117,6 +122,8 @@ def ragged_paged_attention(
     v_scales: Array | None = None,
     kv_gap: Array | None = None,  # [R] — bounded-KV window offset per row
     scale: float | None = None,  # the softmax scale; None = D ** -0.5
+    window: int = 0,  # > 0: a sliding-window layer (a query and the window - 1 before it)
+    block_q: int = 0,  # > 0: the kernel's query block (0 = its own default)
 ) -> Array:
     """Ragged paged-KV attention (ops/ragged_paged_attention.py): prefill
     chunks, decode tokens, and spec verify blocks as rows of ONE packed
@@ -139,13 +146,15 @@ def ragged_paged_attention(
             page_size=page_size, n_kv=n_kv,
             k_scales=k_scales if quantized else None,
             v_scales=v_scales if quantized else None,
-            kv_gap=kv_gap, scale=scale,
+            kv_gap=kv_gap, scale=scale, window=window,
         )
     interpret = backend == "pallas-interpret"
     if quantized:
         from finchat_tpu.ops.ragged_paged_attention import (
             ragged_flash_attention_q8,
         )
+
+        assert not window, "no sliding-window form over the int8 cache"
 
         return ragged_flash_attention_q8(
             q, k_pages, v_pages, k_scales, v_scales, page_table,
@@ -158,7 +167,8 @@ def ragged_paged_attention(
     return ragged_flash_attention(
         q, k_pages, v_pages, page_table, tok_row, tok_pos, kv_len, layer,
         page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
-        kv_gap=kv_gap,
+        kv_gap=kv_gap, **({"window": window} if window else {}),
+        **({"block_q": block_q} if block_q else {}),
     )
 
 

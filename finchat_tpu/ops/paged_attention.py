@@ -134,6 +134,7 @@ SCORE_TILE_BYTES = 1 << 19  # one kv head's fp32 [rows, block] logit tile
 KV_BUFFER_BYTES = 8 << 20  # both slots of the K and the V block
 VMEM_BYTES = 31 << 19  # 15.5 MiB of the v5e's 16 MiB of scoped VMEM: blocks, state, buffers
 SUBLANES = 8  # rows of a float32 tile: the least a block update works on
+BLOCK_STATE_BYTES = 12 << 20  # the most a query block's blocks and softmax state may take
 # the latent form asks for more than the scoped default: 16 rows' stacked queries
 # of 128 heads and their softmax state are 12 MiB before a block is copied
 LATENT_VMEM_BYTES = 48 << 20
@@ -244,6 +245,7 @@ def _paged_kernel(
     quantized: bool,
     shared_rows: int,
     latent_rows: int = 0,
+    window: int = 0,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -377,6 +379,8 @@ def _paged_kernel(
                 rows = jax.lax.broadcasted_iota(jnp.int32, (R, T), 0)
                 q_pos = q_off + qi * Bq + rows % Bq
                 invalid = jnp.logical_or(invalid, kv_pos > q_pos)
+                if window:  # a query sees itself and the window - 1 before it
+                    invalid = jnp.logical_or(invalid, kv_pos <= q_pos - window)
 
             for t in range(tiles):  # static unroll over tiles of kv heads
                 h0 = t * pack
@@ -540,8 +544,23 @@ def _block_diagonal(q: Array, n_kv: int, pack: int) -> Array:
         B, n_tiles, pack * group, pack * D)
 
 
+def _fit_block(bq: int, heads: int, head_dim: int, itemsize: int) -> int:
+    """The query block a chunk call may take: a FIT rule, read off the call's
+    own shapes (no speed-up, no model's name). A query block's VMEM — the
+    query and output blocks, twice each, and the softmax state — grows with
+    heads x block, and past ``BLOCK_STATE_BYTES`` no page of K and V fits
+    beside it: 40 heads of 128 at a block of 128 are 13.1 MiB and the chip's
+    compiler refuses the call by 1 MiB (PR 42). Such a call takes half the
+    block; 32 heads of 128 (10.5 MiB) and fewer keep theirs, so every call
+    that compiled before this rule is traced as it was."""
+    while bq > 8 and heads * bq * (4 * itemsize * head_dim + 4 * (256 + head_dim)) \
+            > BLOCK_STATE_BYTES:
+        bq //= 2
+    return bq
+
+
 def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
-                page_size, n_kv, scale, block_q, interpret):
+                page_size, n_kv, scale, block_q, interpret, window=0):
     """The walk over ``sources`` = ``(k_pages, v_pages)`` or, for the int8
     cache, ``(k_pages, v_pages, k_scales, v_scales)``."""
     B, n_queries, H, D = q.shape
@@ -559,6 +578,7 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
 
     q, C = _pad_chunk(q)
     bq = _pick_block(C, block_q)
+    bq = _fit_block(bq, H, D, q.dtype.itemsize)
     nq = C // bq
     # a tile: the KV heads of one block update, their lanes of K, V and acc
     pack = _heads_per_tile(group, bq)
@@ -619,7 +639,7 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
         _paged_kernel,
         block_q=bq, page_size=page_size, pages_per_block=ppb, n_kv=n_kv,
         group=group, pack=pack, scale=scale, quantized=len(sources) == 4,
-        shared_rows=shared_rows,
+        shared_rows=shared_rows, window=window,
     )
     out_t = pl.pallas_call(
         kernel,
@@ -664,7 +684,7 @@ def paged_flash_attention_q8(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "n_kv", "scale", "block_q", "interpret"),
+    static_argnames=("page_size", "n_kv", "scale", "block_q", "interpret", "window"),
 )
 def paged_flash_attention(
     q: Array,  # [B, C, H, D] — C = 1 for decode, chunk size for prefill
@@ -681,6 +701,7 @@ def paged_flash_attention(
     scale: float | None = None,
     block_q: int = 128,
     interpret: bool = False,
+    window: int = 0,
 ) -> Array:
     """Attention over the paged KV cache; returns [B, C, H, D].
 
@@ -689,11 +710,17 @@ def paged_flash_attention(
     The current chunk's K/V must already be in the pages (the decode append
     kernel or the prefill scatter runs first). Table entries at or beyond a
     row's live page count are never read.
+
+    ``window`` > 0 (a sliding-window layer): a query also masks the positions
+    ``window`` or more before its own. The caller's table holds the window's
+    pages alone, in compacted coordinates (the engine's ``win_table`` and
+    ``win_gaps``), and hands in a ``shared`` of no pages: the shared head's
+    pass reads whole pages for every row, which a window does not allow.
     """
     return _paged_call(
         q, (k_pages, v_pages), page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, scale=scale, block_q=block_q,
-        interpret=interpret)
+        interpret=interpret, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "value_width", "scale", "interpret"))

@@ -117,6 +117,7 @@ def ragged_paged_attention_ref(
     k_scales: Array | None = None,  # int8 cache: [L, P, SPAD, page_size] fp32
     v_scales: Array | None = None,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
+    window: int = 0,  # > 0: a sliding-window layer (``mha_reference``)
 ) -> Array:
     """``jax.lax`` reference for the ragged kernel — the correctness oracle
     AND the CPU/tier-1 serving path (ops/dispatch.py backend "ref").
@@ -158,7 +159,7 @@ def ragged_paged_attention_ref(
     )  # [T, MP*page_size, Hkv, hd]
     out = mha_reference(
         q[:, None], k_all, v_all, causal=True,
-        q_offset=jnp.asarray(tok_pos, jnp.int32), kv_len=kv_tok, scale=scale,
+        q_offset=jnp.asarray(tok_pos, jnp.int32), kv_len=kv_tok, scale=scale, window=window,
     )  # [T, 1, H, D]
     return out[:, 0]
 
@@ -187,6 +188,7 @@ def _ragged_kernel(
     n_kv: int,
     group: int,
     scale: float,
+    window: int = 0,
 ):
     j = pl.program_id(0)
     p = pl.program_id(1)
@@ -222,6 +224,8 @@ def _ragged_kernel(
         q_pos = pos0 + qi
         kv_pos = page_start + cols
         invalid = (kv_pos >= kv_len) | (kv_pos > q_pos) | (qi >= q_len)
+        if window:  # a sliding-window layer: itself and the window - 1 before it
+            invalid = invalid | (kv_pos <= q_pos - window)
 
         for h in range(n_kv):  # static unroll over kv heads
             q_blk = q_ref[h * group:(h + 1) * group].reshape(Rh, D)
@@ -381,7 +385,7 @@ def _aligned_layout(tok_row, tok_pos, T: int, R: int, block_q: int):
 
 @functools.partial(
     jax.jit,
-    static_argnames=("page_size", "n_kv", "scale", "block_q", "interpret"),
+    static_argnames=("page_size", "n_kv", "scale", "block_q", "interpret", "window"),
 )
 def ragged_flash_attention(  # finchat-lint: hot
     q: Array,  # [T, H, D] packed
@@ -399,6 +403,7 @@ def ragged_flash_attention(  # finchat-lint: hot
     block_q: int = 8,
     interpret: bool = False,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
+    window: int = 0,  # > 0: a sliding-window layer's mask beside the causal one
 ) -> Array:
     """Ragged paged attention over the native-dtype cache; returns
     [T, H, D]. Same descriptor contract as ``ragged_paged_attention_ref``
@@ -459,7 +464,7 @@ def ragged_flash_attention(  # finchat-lint: hot
     kernel = functools.partial(
         _ragged_kernel,
         block_q=block_q, page_size=page_size, n_kv=n_kv, group=group,
-        scale=scale,
+        scale=scale, window=window,
     )
     o_t = pl.pallas_call(
         kernel,

@@ -37,6 +37,7 @@ def mha_reference(
     q_offset: Array | int = 0,  # absolute position of q[0] within the kv axis
     kv_len: Array | None = None,  # [B] valid kv length (rest is padding)
     scale: float | None = None,
+    window: int = 0,  # > 0: a query sees itself and the window - 1 positions before it
 ) -> Array:
     """Masked multi-head attention with GQA, fp32 softmax.
 
@@ -62,6 +63,8 @@ def mha_reference(
         else:
             q_pos = q_offset[:, None] + jnp.arange(Sq)[None, :]
         mask = mask | (kv_pos > q_pos[:, None, :, None])
+        if window:
+            mask = mask | (kv_pos <= q_pos[:, None, :, None] - window)
     if kv_len is not None:
         mask = mask | (kv_pos >= kv_len[:, None, None, None])
 

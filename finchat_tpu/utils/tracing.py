@@ -164,6 +164,13 @@ DEVICE_SCOPES = frozenset({
     # the context; the exact top-k of them (and the gather of the selected
     # rows); the softmax over the selected latent rows
     "mla_project", "dsa_indexer", "dsa_select", "mla_attention",
+    # a layer_plan's kinds (models/sambay.py): the Mamba-1 mixer, the gated
+    # memory unit, differential attention's subtraction and sub-norm; and the
+    # attention callbacks by the pool they read (engine/engine.py
+    # _attention_by_kind): the ONE full-attention cache, walked by the full
+    # layer and every cross layer, and the sliding-window layers' own pool
+    "m1_in", "m1_conv", "m1_scan", "m1_out", "gmu", "attn_diff",
+    "yoco_attention", "swa_attention",
 })
 
 #: Why a request's span ended (``RequestSpan.finish(reason=...)``).

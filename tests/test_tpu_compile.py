@@ -563,3 +563,100 @@ def test_deepseek_decode_step_compiles_for_v5e_walking_its_latent_pages(one_chip
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= state.k_pages.size * 2 + state.v_pages.size * 2
     assert memory.temp_size_in_bytes < 64 * 1024 * 1024
+
+
+# --- phi-4-mini-flash-reasoning (PR 42): one cache read by eight layers, window layers ---
+
+def _phi4_step(one_chip, step, *extra, **static):
+    """``step`` of ``phi-4-mini-flash-reasoning`` compiled for the described
+    chip at the file's engine options (32 slots), from shapes alone."""
+    from finchat_tpu.engine import engine as E
+    from finchat_tpu.models.llama import init_params
+    from finchat_tpu.utils.config import EngineConfig
+    from perfbench.models import phi4flash
+
+    file = _config_file("phi-4-mini-flash-reasoning")
+    c = phi4flash.program_config(file)
+    cfg = EngineConfig(**file["engine"])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(c, jax.random.key(0))))
+    state = described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH)))
+    rows = lambda dtype: shape((cfg.max_seqs,), dtype)  # noqa: E731
+    args = extra[0](shape, rows) if extra else (
+        rows(bool), rows(jnp.float32), rows(jnp.float32), rows(jnp.int32))
+    compiled = getattr(E, step).lower(
+        params, state, *args, config=c, page_size=PAGE, attn_backend="pallas", qm_backend="ref",
+        **static).compile()
+    return compiled, state, cfg
+
+
+def test_phi4_flash_decode_step_compiles_for_v5e_walking_one_cache_from_eight_layers(one_chip):
+    """The whole decode step at the cell's size (32 layers in three segments,
+    32 slots): the paged kernel once under ``yoco_attention`` in the full
+    layer's unrolled segment and once in the cross layers' scan (seven passes
+    over the SAME pool at run time), once under ``swa_attention`` in the window
+    layers' scan; the appends in place in BOTH pools (the cross layers write
+    nothing: two appends in all), the Mamba-1 state in place; the step's
+    temporaries a quarter of a GB."""
+    compiled, state, _cfg = _phi4_step(one_chip, "decode_step")
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and " custom-call(" in line]
+    by_scope = {scope: [c for c in calls if f"/{scope}/" in c]
+                for scope in ("yoco_attention", "swa_attention")}
+    walks = {s: sum("paged_flash_attention" in c.split(" = ")[0] for c in cs)
+             for s, cs in by_scope.items()}
+    appends = {s: sum("kv_append" in c for c in cs) for s, cs in by_scope.items()}
+    assert walks == {"yoco_attention": 2, "swa_attention": 1}, walks
+    assert appends == {"yoco_attention": 1, "swa_attention": 1}, appends
+    for scope in ("m1_scan", "m1_conv", "gmu", "attn_diff"):
+        assert f"/{scope}/" in text, scope
+    memory = compiled.memory_analysis()
+    pools = sum(x.size * 2 for x in (state.k_pages, state.v_pages, state.win_k_pages,
+                                     state.win_v_pages))
+    assert memory.alias_size_in_bytes >= pools + state.ssm_state.size * 4
+    assert memory.temp_size_in_bytes < 0.4e9
+
+
+@pytest.mark.parametrize("T", [512, 8192], ids=["a chunk beside decode rows", "the top bucket"])
+def test_phi4_flash_ragged_round_compiles_for_v5e_and_fits_beside_the_model(one_chip, T):
+    """``ragged_mixed_step`` at the window round's bucket and at the top one
+    (32 prompts' chunks at once): the ragged kernel under both scopes, the
+    Mamba-1 scan a loop over TOKENS that carries ``[rows, 16, 5120]`` — nothing
+    of ``[tokens, 5120, 16]`` is materialised (2.7 GB at 8,192 tokens) — and
+    arguments and temporaries together under the chip's 15.75 GB."""
+    def args(shape, rows):
+        return (shape((T,), jnp.int32), shape((T,), jnp.int32), rows(jnp.int32), rows(jnp.int32),
+                rows(jnp.int32), rows(bool), rows(bool), rows(jnp.int32), rows(jnp.float32),
+                rows(jnp.float32), rows(jnp.int32), rows(bool), rows(jnp.float32),
+                rows(jnp.float32), rows(jnp.int32), shape((), jnp.int32))
+
+    compiled, _state, cfg = _phi4_step(one_chip, "ragged_mixed_step", args,
+                                       max_row_tokens=256)
+    text = compiled.as_text()
+    for scope in ("yoco_attention", "swa_attention"):
+        assert [line for line in text.splitlines() if f"/{scope}/" in line
+                and "ragged_flash_attention" in line.split(" = ")[0]], scope
+    assert "/m1_scan/while" in text
+    assert f"f32[{T},5120,16]" not in text and f"f32[{T},16,5120]" not in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 * 0.85
+
+
+def test_phi4_flash_prefill_chunk_compiles_for_v5e_at_forty_heads(one_chip):
+    """``prefill_step`` of 32 rows: the chunk form of the paged kernel at 40
+    query heads of 128 takes a query block of 64 (at 128 its blocks and
+    softmax state are 13.1 MiB and the chip's compiler refused the call by
+    1 MiB of VMEM, PR 42's first chip call)."""
+    def args(shape, rows):
+        return (shape((32, 256), jnp.int32), rows(jnp.int32), rows(jnp.int32), rows(jnp.int32))
+
+    compiled, _state, _cfg = _phi4_step(one_chip, "prefill_step", args)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
