@@ -17,7 +17,13 @@ reads (``dsa_indexer``, ``dsa_select``, ``mla_attention``), ms a layer. The
 ``tokens a block : bytes of the logit tile``), and the two ways to the k-th
 score (32 counting passes, a values-only sort) alone. The crossover printed
 is the context at which the walk's ``mla_attention + dsa_select`` passes the
-gather's, by a line through the two batches.
+gather's, by a line through the two batches. The INDEXER alone comes first,
+its two forms side by side (``indexer``: ``staged`` — the whole table's keys
+copied out, ``index_scores`` — and ``walk@<tokens a block>`` —
+``paged_index_scores`` at the blocks of ``--index-blocks``): ``dsa_indexer``
+ms a layer, the share of its bound (the keys on distinct pages once at 819
+GB/s: what ``dsa_index_roofline.sat`` reads), and the walk's distance from
+``index_scores`` on the columns below each row's length (PR 43).
 
     chiprun -- python3 benchmarks/latent_attention_bench.py [--seed N]
 
@@ -57,6 +63,14 @@ def scope_ms(trace_dir: str, calls: int) -> dict[str, float]:
     return out
 
 
+def report(result: dict, seed: int) -> int:
+    print(json.dumps(result))
+    out = Path("chiprun_out")
+    out.mkdir(exist_ok=True)
+    (out / f"latent_attention_bench_{seed}.json").write_text(json.dumps(result, indent=1))
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
@@ -64,13 +78,19 @@ def main() -> int:
     ap.add_argument("--layers", type=int, default=2)
     ap.add_argument("--blocks", default="1024:1048576,512:1048576,1024:524288,2048:1048576",
                     help="the walk at LATENT_BLOCK_TOKENS:LATENT_TILE_BYTES, the first the tree's own")
+    ap.add_argument("--index-blocks", default="2048,1024,512",
+                    help="the indexer's walk at INDEX_BLOCK_TOKENS, the first the tree's own")
+    ap.add_argument("--parts", default="indexer,forms,kth",
+                    help="which of the script's three parts run")
     args = ap.parse_args()
+    parts = args.parts.split(",")
 
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from benchmarks.paged_attention_alone import POOL_PAGES, contexts, page_table
+    from benchmarks.paged_attention_alone import (
+        HBM_BYTES_PER_S, POOL_PAGES, contexts, page_table)
     from finchat_tpu.ops import latent_attention as la
     from finchat_tpu.ops import paged_attention as pa
 
@@ -110,6 +130,19 @@ def main() -> int:
             return jax.lax.fori_loop(0, layers, layer, jnp.zeros((ROWS, HEADS, LATENT)))
         return all_layers
 
+    def indexer(backend):
+        @jax.jit
+        def all_layers(index, table, kv_len):
+            def layer(i, acc):
+                with jax.named_scope("dsa_indexer"):
+                    if backend == "ref":
+                        return acc + la.index_scores(
+                            idx_q[:, None], idx_w[:, None], la._take_pages(index, i, table))[:, 0]
+                    return acc + pa.paged_index_scores(
+                        idx_q, idx_w, index, table, kv_len, i.reshape(1), page_size=PAGE)
+            return jax.lax.fori_loop(0, layers, layer, jnp.zeros((ROWS, width * PAGE)))
+        return all_layers
+
     result = {"seed": args.seed, "device": jax.devices()[0].device_kind, "layers": layers,
               "steps": args.steps, "batches": {}}
     calls = args.steps * layers
@@ -118,6 +151,29 @@ def main() -> int:
         kv_len = jnp.asarray(ctx, jnp.int32)
         entry = {"context_tokens": int(ctx.sum()),
                  "distinct_tokens": int(ctx.sum()) - (ROWS - 1) * 31 * PAGE}
+        result["batches"][name] = entry
+        if "indexer" in parts:
+            bound_ms = entry["distinct_tokens"] * INDEX_DIM * 2 / HBM_BYTES_PER_S * 1e3
+            below = jnp.arange(width * PAGE)[None] < kv_len[:, None]
+
+            def priced(ms):
+                return {"dsa_indexer_ms": ms["dsa_indexer"],
+                        "share_of_bound": bound_ms / ms["dsa_indexer"]}
+
+            ms, want = timed(indexer("ref"), index, table, kv_len, calls=calls)
+            entry["index_bound_ms"], entry["indexer"] = bound_ms, {"staged": priced(ms)}
+            own = pa.INDEX_BLOCK_TOKENS
+            for block in args.index_blocks.split(","):
+                pa.INDEX_BLOCK_TOKENS = int(block)
+                jax.clear_caches()
+                ms, got = timed(indexer("pallas"), index, table, kv_len, calls=calls)
+                entry["indexer"][f"walk@{block}"] = {
+                    **priced(ms),
+                    "max_abs_diff_below_kv_len": float(jnp.abs(jnp.where(below, got - want, 0)).max()),
+                    "largest_score": float(jnp.abs(jnp.where(below, want, 0)).max())}
+            pa.INDEX_BLOCK_TOKENS = own
+        if "forms" not in parts:
+            continue
         entry["gather"], want = timed(form("ref"), latent, index, table, kv_len, calls=calls)
         for block in args.blocks.split(","):
             pa.LATENT_BLOCK_TOKENS, pa.LATENT_TILE_BYTES = (int(n) for n in block.split(":"))
@@ -131,7 +187,6 @@ def main() -> int:
             entry[f"walk@{block}"]["max_abs_diff_from_gather"] = float(jnp.abs(got - want).max())
         pa.LATENT_BLOCK_TOKENS, pa.LATENT_TILE_BYTES = (int(n) for n in
                                                 args.blocks.split(",")[0].split(":"))
-        result["batches"][name] = entry
 
     # the k-th largest of [16, 16384] float32 alone: 32 counting passes, or a sort
     scores = jax.random.normal(keys[0], (ROWS, width * PAGE), jnp.float32)
@@ -141,13 +196,16 @@ def main() -> int:
                 return kth(x)
         return jax.jit(fn)
 
-    for name, kth in {
+    kth_ways = {
             "kth_by_bit_search": lambda x: la.kth_largest(x, TOPK),
             "kth_by_sort": lambda x: jax.lax.sort(x, dimension=1)[:, -TOPK],
             "kth_by_top_k": lambda x: jax.lax.top_k(x, TOPK)[0][:, -1],
-            "select_whole": lambda x: la.select(x, x > -3.0, TOPK).astype(jnp.int32)}.items():
+            "select_whole": lambda x: la.select(x, x > -3.0, TOPK).astype(jnp.int32)}
+    for name, kth in kth_ways.items() if "kth" in parts else ():
         result[name + "_ms"] = timed(scoped(kth), scores, calls=args.steps)[0]["dsa_select"]
 
+    if "forms" not in parts:
+        return report(result, args.seed)
     # the context at which the walk's attention + selection passes the gather's
     own = args.blocks.split(",")[0]
     cost = {f: [sum(result["batches"][b][f][s] for s in ("dsa_select", "mla_attention"))
@@ -158,11 +216,7 @@ def main() -> int:
     result["crossover_context_tokens"] = (
         tokens[0] + gap / (slope[1] - slope[0]) if slope[1] > slope[0] else None)
     result["cost_ms_attention_plus_select"] = cost
-    print(json.dumps(result))
-    out = Path("chiprun_out")
-    out.mkdir(exist_ok=True)
-    (out / f"latent_attention_bench_{args.seed}.json").write_text(json.dumps(result, indent=1))
-    return 0
+    return report(result, args.seed)
 
 
 if __name__ == "__main__":

@@ -59,7 +59,7 @@ from finchat_tpu.engine.sampler import sample
 from finchat_tpu.models.llama import LlamaConfig, forward, lm_head
 from finchat_tpu.models.mla import LatentInputs
 from finchat_tpu.models.ssm import SsmRows
-from finchat_tpu.ops.latent_attention import LatentShape, decode_form
+from finchat_tpu.ops.latent_attention import LatentShape, decode_form, index_form
 from finchat_tpu.ops.dispatch import paged_attention
 from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.logging import get_logger
@@ -1550,6 +1550,10 @@ class InferenceEngine:
         self.latent_form = decode_form(
             attn_backend, self.max_pages_per_seq * engine_cfg.page_size,
             config.index_topk) if config.kv_lora_rank else None
+        # ... and, where an indexer selects among the table's tokens, how the
+        # decode step's indexer comes by its scores (latent_attention.index_form)
+        self.index_form = index_form(attn_backend) if config.kv_lora_rank and (
+            0 < config.index_topk < self.max_pages_per_seq * engine_cfg.page_size) else None
         self.mesh = mesh
         # bounded-KV long-context serving (ISSUE 15): attention-sink +
         # sliding-window page eviction. The policy is pure host math; the

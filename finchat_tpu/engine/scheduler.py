@@ -499,6 +499,8 @@ class ContinuousBatchingScheduler:
         self._quant_label = getattr(engine, "quant_label", "bf16")
         # a model with latent attention: the form its one-token rows take
         self._latent_form = getattr(engine, "latent_form", None)
+        # ... and the form its decode step's indexer takes (None: no indexer selects)
+        self._index_form = getattr(engine, "index_form", None)
         _wbits = {"": None, "int8": 8, "int4": 4}.get(
             getattr(engine, "quant", ""))
         _elem_bits = 8 * np.dtype(engine.config.dtype).itemsize
@@ -644,6 +646,9 @@ class ContinuousBatchingScheduler:
             self.metrics.inc("finchat_dsa_row_layer_steps_total", 0.0)
             self.metrics.inc("finchat_latent_attention_calls_total", 0.0,
                              labels={"form": self._latent_form})
+            if self._index_form:
+                self.metrics.inc("finchat_dsa_index_calls_total", 0.0,
+                                 labels={"form": self._index_form})
             if fabric is not None or getattr(cfg, "session_cache_disk_path", ""):
                 raise ValueError(
                     "fabric.path / engine.session_cache_disk_path: the warm fabric's and the "
@@ -970,6 +975,7 @@ class ContinuousBatchingScheduler:
             kv_tokens_distinct=kv_tokens - sum(sum(t) - max(t) for t in heads.values()),
             prefix_rows=len(most) if len(most) > 1 else 0,
             **({"form": self._latent_form} if self._latent_form else {}),
+            **({"index_form": self._index_form} if self._index_form else {}),
             # a model with sliding-window layers: the tokens ONE window layer
             # reads (a row's context up to the window)
             **({"window_tokens": sum(min(kv, self._window) for *_row, kv in riders)}
@@ -3942,6 +3948,10 @@ class ContinuousBatchingScheduler:
                     self.metrics.inc("finchat_latent_attention_calls_total",
                                      self.engine.config.n_layers,
                                      labels={"form": self._latent_form})
+                    if self._index_form:
+                        self.metrics.inc("finchat_dsa_index_calls_total",
+                                         self.engine.config.n_layers,
+                                         labels={"form": self._index_form})
                     self._round_selected = selected[0]
             for slot, handle, epoch in step.members:
                 if handle.finished or handle.slot != slot or handle.epoch != epoch:

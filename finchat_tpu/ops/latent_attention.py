@@ -23,6 +23,19 @@ algebra). Two forms realise the same selection:
   backend, and a wider table, take the gather form. A ragged round's
   one-token rows take their form together (``packed_attention``).
 
+The indexer's scores come in two forms too (``index_form``). STAGED: the index
+keys of the whole table's pages copied out a row (``_take_pages``), then
+``index_scores`` over them — the ``ref`` backend, the chunk forms, a ragged
+round's rows. WALK (PR 43): one token a row of the decode step on a kernel
+backend scores its row's LIVE index pages in ONE Pallas pass a layer
+(``ops/paged_attention.py`` ``paged_index_scores``: the same walk with the
+index keys as its source and the scores as its output, the shared head's keys
+once); it fills nothing beyond a row's last live page, and ``select`` reads
+scores only ``where(allowed, ., -inf)``. The selection is EXACT either way:
+every token below the row's length scored by every head from the stored
+values, float32 sums; the two differ by the order of a float32 head sum alone
+(tests/test_latent_walk.py says how much that is).
+
 Of tokens tied AT the k-th score both keep those at the lowest positions, so
 the two select the same ``topk`` tokens always (tests/test_deepseek_v32.py,
 tests/test_latent_walk.py; exact ties are everyday at a test's width — four
@@ -134,15 +147,29 @@ def decode_form(backend: str, context: int, topk: int) -> str:
     return "walk" if backend != "ref" and context <= WALK_MAX_CONTEXTS * kept else "gather"
 
 
+def index_form(backend: str, packed: bool = False) -> str:
+    """How a one-token call's indexer comes by its scores, read off the call:
+    ``walk`` (``paged_index_scores``: the paged kernel's walk over the row's
+    index pages, the shared head's keys once) on a kernel backend, else
+    ``staged`` (``_take_pages`` + ``index_scores``: the keys of the whole
+    table's pages copied out for every row, whatever the row's length). The
+    walk is never the slower, so the table's width has no say. A ragged
+    round's ``packed`` one-token rows stay staged with their chunk rows."""
+    return "walk" if backend != "ref" and not packed else "staged"
+
+
 def decode_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
                      latent_pages: Array, index_pages: Array, layer: Array,
                      page_table: Array, kv_len: Array, live: Array, *,
                      page_size: int, shape: LatentShape, backend: str = "ref",
-                     shared: tuple[Array, Array] | None = None) -> tuple[Array, Array]:
+                     shared: tuple[Array, Array] | None = None,
+                     packed: bool = False) -> tuple[Array, Array]:
     """One query a row (``q`` [B,H,R+r]) over the row's first ``kv_len``
     tokens (its own row already written), in the form ``decode_form`` reads
-    off the call (``shared``: ``shared_head``'s, for the walk). Returns
-    ``(o_latent [B,H,R], selected)``; ``live`` [B] rows count."""
+    off the call, the indexer's scores in the form ``index_form`` does
+    (``shared``: ``shared_head``'s, for the walks; ``packed``: the rows are a
+    ragged round's). Returns ``(o_latent [B,H,R], selected)``; ``live`` [B]
+    rows count."""
     B = q.shape[0]
     J = page_table.shape[1] * page_size
     allowed = (jnp.arange(J)[None, :] < kv_len[:, None]) & live[:, None]
@@ -150,8 +177,17 @@ def decode_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
     selects = k < J
     if selects:
         with jax.named_scope("dsa_indexer"):
-            keys = _take_pages(index_pages, layer, page_table)  # [B,J,Di]
-            scores = index_scores(idx_q[:, None], idx_w[:, None], keys)[:, 0]
+            if index_form(backend, packed) == "walk":
+                from finchat_tpu.ops.paged_attention import paged_index_scores
+
+                # beyond a row's tokens the walk leaves what it finds: only ``allowed`` scores count
+                scores = paged_index_scores(
+                    idx_q, idx_w, index_pages, page_table, jnp.where(live, kv_len, 0),
+                    layer.reshape(1), shared, page_size=page_size,
+                    interpret=backend == "pallas-interpret")
+            else:
+                keys = _take_pages(index_pages, layer, page_table)  # [B,J,Di]
+                scores = index_scores(idx_q[:, None], idx_w[:, None], keys)[:, 0]
     if decode_form(backend, J, shape.topk) == "walk":
         from finchat_tpu.ops.paged_attention import paged_latent_attention
 
@@ -329,7 +365,8 @@ def packed_attention(q: Array, idx_q: Array | None, idx_w: Array | None,
     first = jnp.minimum(q_start, T - 1)
     out_one, selected_one = decode_attention(
         q[first], None if no_index else idx_q[first], None if no_index else idx_w[first],
-        latent_pages, index_pages, layer, page_rows, start + 1, one, backend=backend, **kw)
+        latent_pages, index_pages, layer, page_rows, start + 1, one, backend=backend,
+        packed=True, **kw)
     # a row that is not of one token writes into the padding behind the buffer
     out = out.at[jnp.where(one, first, T + width - 1)].set(out_one)
     return out[:T], selected + selected_one

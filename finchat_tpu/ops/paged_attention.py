@@ -107,6 +107,18 @@ Design — a walk as long as the row, several pages a block:
   layer at the cell's shapes at 512, 0.289 at 1,024, 0.323 at 2,048, where a
   partial last block's waste overtakes it).
 
+- the INDEXER's scores over its key pages (``paged_index_scores``; PERF.md
+  section 6, PR 43): the walk again — the copies, the chain across rows, the
+  shared head once for all rows' queries stacked — with the index keys as its
+  one source and, in place of a block's softmax update, ``sum_h w[h] ReLU(q[h]
+  . key)`` stored at the block's columns of a ``[rows, tokens]`` float32
+  output that stays in VMEM over the grid. No state to reset or resume, no
+  mask, nothing written when a walk ends; blocks of ``INDEX_BLOCK_TOKENS``
+  (2,048: a key page is 32 KB and a block's fixed work shows sooner than its
+  partial last block's repeats). Staging the table's keys for XLA's einsum
+  cost 0.15 ms a layer at the cell's shapes whatever the contexts; the walk
+  0.05.
+
 Serves both decode (C = 1) and paged chunked prefill (C = chunk) — the same
 causal/ragged masking as ``ops.refs.mha_reference`` with ``q_offset``/
 ``kv_len`` semantics.
@@ -142,6 +154,12 @@ LATENT_VMEM_BYTES = 48 << 20
 # read-modify-write, the matmuls' fill and drain) is a fifth of a 512-token block's time
 LATENT_BLOCK_TOKENS = 1024
 LATENT_TILE_BYTES = 1 << 20  # the float32 logit tile of the stacked rows that take one update
+# the index form's block: a key page is a fifth of a latent page and a block's work three
+# operations an element, so a block's fixed work (the loop, the waits, the matmul's fill)
+# shows before a partial last block's repeats do: alone on a v5e at the cell's shapes 71.7
+# us a layer at 512 tokens, 51.8 at 1,024, 45.9 at 2,048, 46.5 at 4,096; with every row at
+# 16,384 tokens 186 / 126 / 103 / 106 (PERF.md section 6, PR 43)
+INDEX_BLOCK_TOKENS = 2048
 
 
 def _pad_chunk(q: Array) -> tuple[Array, int]:
@@ -246,6 +264,7 @@ def _paged_kernel(
     shared_rows: int,
     latent_rows: int = 0,
     window: int = 0,
+    index_heads: int = 0,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -286,8 +305,24 @@ def _paged_kernel(
     head's stacked queries are ``[1, shared_rows, Dk]`` and take the block
     update ``latent_rows`` rows at a time (whole sequences: a tile of rows
     where the other forms have a tile of heads), each sequence's rows under
-    its own mask. The other forms' bodies are traced as they were."""
-    n_src = 1 if latent_rows else 4 if quantized else 2
+    its own mask. The other forms' bodies are traced as they were.
+
+    With ``index_heads`` (``paged_index_scores``: the indexer's scores over
+    its key pages) the walk is the same — the copies, the chain, the shared
+    head once for the stacked rows — and a block's work is not a softmax: the
+    one source holds an index KEY a token, the query block is ``[1, Hi, Dk]``
+    (``Hi = index_heads``, a multiple of 8; the stacked queries ``[1, B * Hi,
+    Dk]``), behind the queries come the heads' weights ``[B * Hi, 128]``
+    float32 (a row's weight on every lane), and the output block is the WHOLE
+    ``[B, (max_pages + pages_per_block - 1) * page_size]`` float32 score
+    array, resident over the grid: a block stores ``sum_h w[h] ReLU(q[h] .
+    key)`` at its columns of its sequence's row (the stacked pass
+    ``LATENT_TILE_BYTES`` of products = whole sequences at a time, at every
+    sequence's row; a row that is no member walks from column 0 and
+    overwrites them). There is no softmax state, no mask and nothing to write
+    when a walk ends: columns no walk reaches — at or beyond ``kv_len``
+    rounded up to a block, a dead row's — hold whatever the buffer held."""
+    n_src = 1 if latent_rows or index_heads else 4 if quantized else 2
     layer_ref, page_table_ref, q_offset_ref, kv_len_ref, *refs = refs
     if shared_rows:
         member_ref, head_ref, q_ref, qs_ref, *refs = refs
@@ -295,11 +330,15 @@ def _paged_kernel(
         q_ref, *refs = refs
     if latent_rows:
         keep_ref, *refs = refs
-    sources = refs[:n_src]
-    o_ref, *own_state = refs[n_src:n_src + 4]
-    refs = refs[n_src + 4:]
+    if index_heads:
+        w_ref, *refs = refs
+    sources, o_ref, refs = refs[:n_src], refs[n_src], refs[n_src + 1:]
+    if not index_heads:
+        own_state, refs = refs[:3], refs[3:]
+    if shared_rows and not index_heads:
+        shared_state, refs = refs[:3], refs[3:]
     if shared_rows:
-        shared_state, slot_ref, refs = refs[:3], refs[3], refs[4:]
+        slot_ref, *refs = refs
     buffers, sems = refs[:n_src], refs[n_src]
 
     b = pl.program_id(0)
@@ -314,19 +353,13 @@ def _paged_kernel(
     q_off = q_offset_ref[b]
     kv_len = kv_len_ref[b]
 
-    def walk(row, first, n_pages, limit, causal, q_of, state, R,
-             slot0=0, primed=False, then=None, tiles=n_tiles, keep_of=None):
-        """Online softmax of ``R`` query rows a tile (``q_of(t)``, state in
-        ``state``'s rows ``t*R .. (t+1)*R``) over table columns ``first ..
-        first + n_pages`` of ``row``; positions at or beyond ``limit`` are
-        masked, and with ``causal`` those after a query row's own, and with
-        ``keep_of`` (the latent form) those where ``keep_of(t, the block's
-        first column)`` [R or 1, T] is 0. Block j
-        lands in buffer slot ``(slot0 + j) % 2``. ``primed``: the walk before
+    def walk(row, first, n_pages, update, slot0=0, primed=False, then=None):
+        """Table columns ``first .. first + n_pages`` of ``row``, a block at
+        a time: ``update(slot, first, j)`` works on block j once it stands in
+        buffer slot ``slot = (slot0 + j) % 2``. ``primed``: the walk before
         this one already started block 0's copies; ``then = (row, first,
         n_pages)``: the walk after this one, whose block 0 this one starts
         beside its own last block. Returns the slot that block lands in."""
-        m_ref, l_ref, acc_ref = state
         n_blocks = pl.cdiv(n_pages, ppb)
 
         def copies(slot, block=None):
@@ -370,7 +403,22 @@ def _paged_kernel(
 
             for c in copies(slot):
                 c.wait()
+            update(slot, first, j)
+            return carry
 
+        jax.lax.fori_loop(0, n_blocks, block, None)
+        return (slot0 + n_blocks) % 2
+
+    def softmax(limit, causal, q_of, state, R, tiles=n_tiles, keep_of=None):
+        """A walk's ``update``: the online softmax of ``R`` query rows a tile
+        (``q_of(t)``, state in ``state``'s rows ``t*R .. (t+1)*R``) over a
+        block; positions at or beyond ``limit`` are masked, and with
+        ``causal`` those after a query row's own, and with ``keep_of`` (the
+        latent form) those where ``keep_of(t, the block's first column)`` [R
+        or 1, T] is 0."""
+        m_ref, l_ref, acc_ref = state
+
+        def update(slot, first, j):
             # (the latent form's rows of a walk share their positions' mask: one row of it)
             kv_pos = (first + j * ppb) * page_size + jax.lax.broadcasted_iota(
                 jnp.int32, (1 if latent_rows else R, T), 1)
@@ -416,10 +464,27 @@ def _paged_kernel(
                 m_ref[r0:r0 + R, :1] = m_new
                 l_ref[r0:r0 + R, :1] = l_new
                 acc_ref[r0:r0 + R, :W] = acc_new
-            return carry
 
-        jax.lax.fori_loop(0, n_blocks, block, None)
-        return (slot0 + n_blocks) % 2
+        return update
+
+    def scores(tile_of, R, tiles=1):
+        """A walk's ``update`` in the index form: ``sum_h w[h] ReLU(q[h] .
+        key)`` of a block's keys for ``R // index_heads`` sequences a tile —
+        ``tile_of(t)``: their queries [R, Dk], the heads' weights [R, 1]
+        float32, their rows of the output — stored at the block's columns of
+        those rows. Float32 products' sums, float32 weights, a float32 sum
+        over the heads."""
+        def update(slot, first, j):
+            keys = buffers[0][slot].reshape(T, Dk)
+            at = pl.ds(pl.multiple_of((first + j * ppb) * page_size, page_size), T)
+            for t in range(tiles):
+                q, w, rows = tile_of(t)
+                s = jax.lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)  # [R, T]
+                s = jnp.maximum(s, 0.0) * w
+                o_ref[rows, at] = jnp.sum(s.reshape(R // index_heads, index_heads, T), axis=1)
+
+        return update
 
     def own_head(rows, cols, piece):
         """``[rows, cols]`` float32 in which each query row of a tile (a
@@ -452,7 +517,8 @@ def _paged_kernel(
         first = jnp.where(member_ref[row] != 0, head_ref[0], 0) if shared_rows else 0
         return row, first, jnp.maximum(n_live - first, 0)
 
-    reset(own_state)
+    if not index_heads:
+        reset(own_state)
     chain = {}
     if shared_rows:
         # the batch's shared head (``shared_head``): program 0 reads those
@@ -477,6 +543,21 @@ def _paged_kernel(
 
         @pl.when(jnp.logical_and(b == 0, n_shared > 0))
         def _shared():
+            if index_heads:
+                # every sequence's row of the head's columns, as many sequences a
+                # tile as keep its float32 products in their budget and divide the batch
+                seqs = shared_rows // index_heads
+                per = max(d for d in range(1, seqs + 1) if seqs % d == 0 and (
+                    d == 1 or d * index_heads * T * 4 <= LATENT_TILE_BYTES))
+
+                def tile(t):
+                    rows = slice(t * per * index_heads, (t + 1) * per * index_heads)
+                    return qs_ref[0, rows, :], w_ref[rows, :1], slice(t * per, (t + 1) * per)
+
+                slot_ref[0] = walk(head_ref[1], 0, n_shared,
+                                   scores(tile, per * index_heads, seqs // per),
+                                   then=own_walk(0))
+                return
             reset(shared_state)
             if latent_rows:
                 def stacked_q(t, W):
@@ -485,27 +566,36 @@ def _paged_kernel(
                 def stacked_q(t, W):
                     return qs_ref[t, :, :W]
             slot_ref[0] = walk(
-                head_ref[1], 0, n_shared, n_shared * page_size, False,
-                stacked_q, shared_state, latent_rows or shared_rows,
-                then=own_walk(0), **stacked)
+                head_ref[1], 0, n_shared, softmax(
+                    n_shared * page_size, False, stacked_q, shared_state,
+                    latent_rows or shared_rows, **stacked), then=own_walk(0))
 
-        @pl.when(member_ref[b] != 0)
-        def _resume():
-            for t in range(n_tiles):
-                at = pl.ds(pl.multiple_of(t * shared_rows + b * gp, 8), gp)
-                for own, shared in zip(own_state, shared_state):
-                    own[t * Rt:(t + 1) * Rt] = shared[at, :][:Rt]
+        if not index_heads:
+            @pl.when(member_ref[b] != 0)
+            def _resume():
+                for t in range(n_tiles):
+                    at = pl.ds(pl.multiple_of(t * shared_rows + b * gp, 8), gp)
+                    for own, shared in zip(own_state, shared_state):
+                        own[t * Rt:(t + 1) * Rt] = shared[at, :][:Rt]
 
         row, first, n_pages = own_walk(jnp.minimum(b + 1, B - 1))
         chain = dict(slot0=slot_ref[0], primed=jnp.logical_or(b > 0, n_shared > 0),
                      then=(row, first, jnp.where(b + 1 < B, n_pages, 0)))
 
     _row, first, n_pages = own_walk(b)
+    if index_heads:  # nothing outlives a block but its scores: no state, no output to finish
+        own = pl.ds(pl.multiple_of(b * index_heads, 8), index_heads)
+        slot = walk(b, first, n_pages, scores(
+            lambda t: (q_ref[0], w_ref[own, :1], pl.ds(b, 1)), index_heads), **chain)
+        if shared_rows:
+            slot_ref[0] = slot
+        return
+    own_keep = {}
     if latent_rows:
         def own_q(t, W):
             return q_ref[0]
 
-        chain.update(keep_of=lambda t, col: keep_block(col, b))
+        own_keep = dict(keep_of=lambda t, col: keep_block(col, b))
     elif pack == 1:
         def own_q(t, W):
             return q_ref[0, t * group:(t + 1) * group].reshape(Rt, D)
@@ -513,7 +603,8 @@ def _paged_kernel(
         def own_q(t, W):
             return q_ref[0, t, :, :W]
     # (a latent row's one query stands on its last token: ``kv_len`` is its causal bound)
-    slot = walk(b, first, n_pages, kv_len, not latent_rows, own_q, own_state, Rt, **chain)
+    slot = walk(b, first, n_pages,
+                softmax(kv_len, not latent_rows, own_q, own_state, Rt, **own_keep), **chain)
     if shared_rows:
         slot_ref[0] = slot
 
@@ -795,3 +886,74 @@ def paged_latent_attention(
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=LATENT_VMEM_BYTES),
         interpret=interpret,
     )(*prefetch, *blocks, pages)
+
+
+@functools.partial(jax.jit, static_argnames=("page_size", "interpret"))
+def paged_index_scores(
+    idx_q: Array,  # [B, Hi, Dk] — one index query a head a row
+    idx_w: Array,  # [B, Hi] float32 — the heads' weights (both scales inside)
+    pages: Array,  # [L, P, page_size, Dk] — a token's index key
+    page_table: Array,  # [B, max_pages] int32
+    kv_len: Array,  # [B] int32 — the row's tokens, its own included; 0: a dead row
+    layer: Array,  # [1] int32
+    shared: tuple[Array, Array] | None = None,  # ``shared_head``'s
+    *,
+    page_size: int,
+    interpret: bool = False,
+) -> Array:
+    """The indexer's scores of one query a row over the row's own pages
+    (ops/latent_attention.py ``index_scores`` without the staged copy of the
+    table's pages): ``[B, max_pages * page_size]`` float32, ``I[b, j] = sum_h
+    w[b, h] ReLU(q[b, h] . key[b, j])`` for ``j < kv_len[b]`` — every such
+    token by every head, products of the stored values summed in float32,
+    then a float32 sum over the heads. COLUMNS AT OR BEYOND ``kv_len[b]``
+    (all of a dead row's) HOLD ANYTHING, NaN included: the walk stops at the
+    row's last live page and nothing fills what it does not reach. ``select``
+    reads scores only ``where(allowed, ., -inf)``; any other reader must mask
+    likewise. ``_paged_kernel``'s walk in its index form: whole pages
+    double-buffered as far as the row goes, the batch's shared head scored
+    once for all rows' queries stacked, the walks of the call one chain."""
+    B, Hi, Dk = idx_q.shape
+    max_pages = page_table.shape[1]
+    assert pages.shape[2:] == (page_size, Dk), (pages.shape, page_size, Dk)
+    page_table = jnp.asarray(page_table, jnp.int32)
+    kv_len = jnp.asarray(kv_len, jnp.int32)
+    q_offset = jnp.maximum(kv_len - 1, 0)  # the query stands on its row's last token
+    gp = _round_up(Hi, SUBLANES)  # (a head of zeros under a weight of zero adds nothing)
+    shared_rows = B * gp if B > 1 else 0
+    ppb = max(1, min(INDEX_BLOCK_TOKENS // page_size, max_pages))
+    q = jnp.pad(idx_q, ((0, 0), (0, gp - Hi), (0, 0)))
+    weights = jnp.broadcast_to(
+        jnp.pad(idx_w.astype(jnp.float32), ((0, 0), (0, gp - Hi))).reshape(B * gp, 1),
+        (B * gp, 128))
+    prefetch = [jnp.asarray(layer, jnp.int32), page_table, q_offset, kv_len]
+    blocks, in_specs = [q], [pl.BlockSpec((1, gp, Dk), lambda b, qi, *_: (b, 0, 0))]
+    scratch = []
+    if shared_rows:
+        if shared is None:
+            shared = shared_head(page_table, kv_len, page_size, kv_len > 0)
+        prefetch += [jnp.asarray(x, jnp.int32) for x in shared]
+        blocks.append(q.reshape(1, shared_rows, Dk))
+        in_specs.append(pl.BlockSpec((1, shared_rows, Dk), lambda b, qi, *_: (0, 0, 0)))
+        scratch.append(pltpu.SMEM((1,), jnp.int32))
+    blocks.append(weights)
+    in_specs.append(pl.BlockSpec(weights.shape, lambda b, qi, *_: (0, 0)))
+    # (a partial last block writes on behind the table's last column)
+    out_shape = (B, (max_pages + ppb - 1) * page_size)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, 1),
+        in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(out_shape, lambda b, qi, *_: (0, 0)),
+        scratch_shapes=[*scratch, pltpu.VMEM((2, ppb) + pages.shape[2:], pages.dtype),
+                        pltpu.SemaphoreType.DMA((2, 1))],
+    )
+    kernel = functools.partial(
+        _paged_kernel, block_q=1, page_size=page_size, pages_per_block=ppb, n_kv=1,
+        group=gp, pack=1, scale=1.0, quantized=False, shared_rows=shared_rows,
+        index_heads=gp)
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(out_shape, jnp.float32),
+        interpret=interpret,
+    )(*prefetch, *blocks, pages)[:, :max_pages * page_size]

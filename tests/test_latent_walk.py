@@ -11,6 +11,9 @@ SHARED    a shared head with a row that is no member; a dead row
 BLOCKS    several blocks a walk, several tiles of stacked rows
 PACKED    ``packed_attention``'s one-token rows through the same form
 ENGINE    the decode step on the kernel backend; the counter's ``form``
+INDEX     the indexer's scores by the same walk (PR 43): ``paged_index_scores``
+          against ``index_scores`` below each row's length, the selection it
+          leads to against the ``ref`` form's
 """
 
 import jax
@@ -191,19 +194,21 @@ def test_blocks_of_any_size_and_tiles_of_stacked_rows_give_the_same_values(
 
 # --- PACKED ----------------------------------------------------------------------
 
-def test_a_ragged_rounds_one_token_rows_take_the_walk_and_its_chunk_rows_the_mask_form():
+def _ragged_round():
+    """row 0: one token at position 99; row 1: a chunk of 6 from 40; row 2: one at 60; row 3: none"""
     rows, keys = _pool(seed=8)
-    T, width = 16, 8
     ks = jax.random.split(jax.random.key(9), 3)
-    q = jax.random.normal(ks[0], (T, HEADS, LATENT + ROPE))
-    iq, iw = jax.random.normal(ks[1], (T, HEADS, DI)), jax.random.normal(ks[2], (T, HEADS))
-    # row 0: one token at position 99; row 1: a chunk of 6 from 40; row 2: one at 60; row 3: none
+    q = jax.random.normal(ks[0], (16, HEADS, LATENT + ROPE))
+    iq, iw = jax.random.normal(ks[1], (16, HEADS, DI)), jax.random.normal(ks[2], (16, HEADS))
     table = jnp.concatenate([TABLE, jnp.zeros((1, 8), jnp.int32)])
-    q_start, start = jnp.asarray([0, 1, 7, 8]), jnp.asarray([99, 40, 60, 0])
-    n_valid = jnp.asarray([1, 6, 1, 0])
-    args = (q, iq, iw, rows, keys, jnp.int32(1), table, q_start, start, n_valid)
-    got, selected = la.packed_attention(*args, width=width, backend="pallas-interpret", **KW)
-    want, n = la.packed_attention(*args, width=width, **KW)
+    return (q, iq, iw, rows, keys, jnp.int32(1), table, jnp.asarray([0, 1, 7, 8]),
+            jnp.asarray([99, 40, 60, 0]), jnp.asarray([1, 6, 1, 0]))
+
+
+def test_a_ragged_rounds_one_token_rows_take_the_walk_and_its_chunk_rows_the_mask_form():
+    args = _ragged_round()
+    got, selected = la.packed_attention(*args, width=8, backend="pallas-interpret", **KW)
+    want, n = la.packed_attention(*args, width=8, **KW)
     assert int(selected) == int(n) == 24 * 8
     assert jnp.abs(got - want)[:8].max() < 1e-5
 
@@ -248,9 +253,199 @@ def test_the_scheduler_counts_a_layer_a_step_under_the_form_the_steps_took(max_s
     name = "finchat_latent_attention_calls_total"
     sched = ContinuousBatchingScheduler(_engine("pallas-interpret", max_seq_len), eos_id=-1)
     before = {f: METRICS.get(name, labels={"form": f}) for f in (form, other)}
+    index = "finchat_dsa_index_calls_total"
+    walked, staged = (METRICS.get(index, labels={"form": f}) for f in ("walk", "staged"))
     steps = METRICS.get("finchat_dsa_row_layer_steps_total")
     [(_handle, tokens)] = _run(sched, _tokens(40, seed=4), n_new=4)
     steps = METRICS.get("finchat_dsa_row_layer_steps_total") - steps  # one live row
     assert len(tokens) == 4 and steps >= 3 * 3
     assert METRICS.get(name, labels={"form": form}) - before[form] == steps
     assert METRICS.get(name, labels={"form": other}) == before[other]
+    # the indexer walks on a kernel backend whatever the table's width; ``staged`` does not move
+    assert sched.engine.index_form == "walk"
+    assert METRICS.get(index, labels={"form": "walk"}) - walked == steps
+    assert METRICS.get(index, labels={"form": "staged"}) == staged
+
+
+# --- INDEX -----------------------------------------------------------------------
+
+EPS = 2.0 ** -23  # a float32 sum's relative rounding a term
+
+
+def _index_inputs(seed, rows=3, heads=HEADS, width=DI, dtype=jnp.float32):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(ks[0], (rows, heads, width)).astype(dtype),
+            jax.random.normal(ks[1], (rows, heads)),
+            jax.random.normal(ks[2], (2, 20, PAGE, width)).astype(dtype))
+
+
+def _staged_scores(iq, iw, keys, table, layer=1):
+    staged = keys[layer][table].reshape(len(table), -1, keys.shape[-1])
+    return la.index_scores(iq[:, None], iw[:, None], staged)[:, 0], staged
+
+
+def _rounding(iq, iw, staged):
+    """How far two float32 evaluations of ``sum_h w ReLU(q . k)`` may lie apart
+    for a summation order's sake: each of the ``heads + width`` additions a
+    score goes through rounds by at most ``EPS`` of what it has summed, and
+    that is at most the sum of the terms' magnitudes (the stored values'
+    products are exact in float32 where they are bf16, and rounded alike in
+    both where they are float32) — the largest over the tokens."""
+    heads, width = iq.shape[1:]
+    size = jnp.einsum("bhd,bjd,bh->bj", *(jnp.abs(a.astype(jnp.float32))
+                                            for a in (iq, staged, iw)))
+    return float((heads + width) * EPS * size.max())
+
+
+def _walk_scores(iq, iw, keys, table, kv_len, layer=1, **kw):
+    return pa.paged_index_scores(iq, iw, keys, table, jnp.asarray(kv_len, jnp.int32),
+                                 jnp.asarray([layer]), page_size=PAGE, interpret=True, **kw)
+
+
+def _below(kv_len, J=128):
+    return jnp.arange(J)[None] < jnp.asarray(kv_len)[:, None]
+
+
+@pytest.mark.parametrize("heads, width, dtype", [(HEADS, DI, jnp.float32), (64, 128, jnp.bfloat16)],
+                         ids=["4x16-float32", "64x128-bf16"])
+@pytest.mark.parametrize("kv_len", [
+    [100, 112, 70],  # rows that end in mid-page, and (blocks of two pages) in mid-block
+    [97, 33, 17],  # a last page of ONE token; rows shorter than the members' shared head + 1
+    [128, 96, 64],  # whole pages, whole blocks, a full table
+    [5, 31, 1],  # no page whole: no head is shared
+], ids=["ragged", "one-token-page", "whole", "short"])
+def test_the_index_walk_scores_every_token_below_a_rows_length_as_index_scores_does(
+        kv_len, heads, width, dtype, monkeypatch):
+    """At the tests' width and at the cell's (64 index heads over keys of 128,
+    bf16): the walk's float32 head sum may associate otherwise than XLA's, no
+    more (``_rounding``; the values themselves differ by a few 1e-6 of
+    scores up to about 30)."""
+    monkeypatch.setattr(pa, "INDEX_BLOCK_TOKENS", 2 * PAGE)
+    jax.clear_caches()
+    iq, iw, keys = _index_inputs(11, heads=heads, width=width, dtype=dtype)
+    want, staged = _staged_scores(iq, iw, keys, TABLE)
+    got = _walk_scores(iq, iw, keys, TABLE, kv_len)
+    jax.clear_caches()
+    assert got.shape == want.shape == (3, 128) and got.dtype == jnp.float32
+    far = jnp.abs(jnp.where(_below(kv_len), got - want, 0)).max()
+    assert far <= _rounding(iq, iw, staged) < 1e-3 * jnp.abs(want).max()
+
+
+def test_the_index_walk_scores_a_shared_head_once_and_a_row_outside_it_from_column_0():
+    """Rows 0 and 1 share two pages (the stacked pass writes EVERY row's
+    scores of those columns, row 2's too: its own walk from column 0 puts its
+    own keys' there); handed the head or finding it, the same scores; with a
+    member as far as the head and no further, and a row outside that is
+    shorter than the head."""
+    iq, iw, keys = _index_inputs(12)
+    want, staged = _staged_scores(iq, iw, keys, TABLE)
+    tol = _rounding(iq, iw, staged)
+    for kv_len in ([100, 112, 70], [32, 112, 20], [100, 112, 0]):
+        kv = jnp.asarray(kv_len)
+        member, head = pa.shared_head(TABLE, kv, PAGE, kv > 0)
+        assert [int(m) for m in member] == [1, 1, 0] and [int(h) for h in head] == [2, 0]
+        got = _walk_scores(iq, iw, keys, TABLE, kv_len)
+        assert jnp.abs(jnp.where(_below(kv_len), got - want, 0)).max() <= tol
+        handed = _walk_scores(iq, iw, keys, TABLE, kv_len, shared=(member, head))
+        assert (jnp.where(_below(kv_len), handed - got, 0) == 0).all()
+
+
+def test_the_index_walk_of_one_row_alone_has_no_stacked_pass():
+    iq, iw, keys = _index_inputs(13, rows=1)
+    want, staged = _staged_scores(iq, iw, keys, TABLE[:1])
+    got = _walk_scores(iq, iw, keys, TABLE[:1], [90])
+    assert jnp.abs(jnp.where(_below([90]), got - want, 0)).max() <= _rounding(iq, iw, staged)
+
+
+@pytest.mark.parametrize("kv_len", [[100, 0, 70], [0, 112, 70], [0, 0, 0]])
+def test_a_dead_row_among_live_ones_is_not_walked_and_the_others_score_as_they_did(kv_len):
+    iq, iw, keys = _index_inputs(14)
+    want, staged = _staged_scores(iq, iw, keys, TABLE)
+    got = _walk_scores(iq, iw, keys, TABLE, kv_len)
+    assert jnp.abs(jnp.where(_below(kv_len), got - want, 0)).max() <= _rounding(iq, iw, staged)
+
+
+@pytest.mark.parametrize("tile_bytes", [1 << 20, HEADS * 2 * 4], ids=["one-tile", "a-tile-a-row"])
+def test_index_blocks_of_several_sizes_give_the_same_scores(tile_bytes, monkeypatch):
+    """One page a block, two, three (which does not divide the table) and the
+    whole table in one; the stacked pass in one tile of all rows, and in a
+    tile a sequence. A token's score does not depend on its block."""
+    monkeypatch.setattr(pa, "LATENT_TILE_BYTES", tile_bytes)
+    iq, iw, keys = _index_inputs(15)
+    table = TABLE.at[2, :3].set(jnp.asarray([3, 5, 7]))  # row 2 a member too, as far as row 0
+    kv_len = [100, 112, 77]
+    want, staged = _staged_scores(iq, iw, keys, table)
+    seen = []
+    for block_tokens in (PAGE, 2 * PAGE, 3 * PAGE, 8 * PAGE):
+        monkeypatch.setattr(pa, "INDEX_BLOCK_TOKENS", block_tokens)
+        jax.clear_caches()
+        seen.append(jnp.where(_below(kv_len), _walk_scores(iq, iw, keys, table, kv_len), 0))
+    jax.clear_caches()
+    tol = _rounding(iq, iw, staged)
+    assert all(jnp.abs(got - jnp.where(_below(kv_len), want, 0)).max() <= tol for got in seen)
+    assert all(jnp.abs(got - seen[0]).max() <= tol for got in seen[1:])
+
+
+@pytest.mark.parametrize("poison", [jnp.nan, jnp.inf, -jnp.inf, 1e30])
+def test_what_the_walk_leaves_beyond_a_rows_length_never_reaches_the_selection(
+        poison, monkeypatch):
+    """The walk fills nothing beyond a row's last live page (and nothing of a
+    dead row): whatever stands there — here NaN, infinities, a huge score
+    written over the tail of the walk's output — the selection and the
+    attention over it are those of the ``ref`` form."""
+    walk = pa.paged_index_scores
+
+    def poisoned(idx_q, idx_w, pages, table, kv_len, *args, **kw):
+        scores = walk(idx_q, idx_w, pages, table, kv_len, *args, **kw)
+        return jnp.where(_below(kv_len, scores.shape[1]), scores, poison)
+
+    monkeypatch.setattr(pa, "paged_index_scores", poisoned)
+    rows, keys = _pool(seed=9)
+    q, iq, iw = _queries(seed=10)
+    (got, selected), (want, n) = _both_forms(q, iq, iw, rows, keys, TABLE, [100, 112, 33],
+                                             [True, False, True])
+    assert int(selected) == int(n) == 2 * 24 and jnp.abs(got - want).max() < 1e-5
+    assert not got[1].any()
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+def test_the_walks_scores_select_the_tokens_the_ref_forms_scores_select(seed):
+    """The SAME 24 tokens a row, where no score lies within the rounding of
+    the k-th (none does at these seeds: the gap at the cut is a thousand
+    roundings and more); then the whole call, on both backends."""
+    rows, keys = _pool(seed=seed)
+    q, iq, iw = _queries(seed=20 + seed)
+    kv_len = [100, 112, 80]
+    want, staged = _staged_scores(iq, iw, keys, TABLE)
+    got = _walk_scores(iq, iw, keys, TABLE, kv_len)
+    allowed = _below(kv_len)
+    ranked = jnp.sort(jnp.where(allowed, want, -jnp.inf), axis=-1)
+    assert (ranked[:, -24] - ranked[:, -25]).min() > 2 * _rounding(iq, iw, staged)
+    kept = la.select(got, allowed, 24)
+    assert (kept == la.select(want, allowed, 24)).all() and int(kept.sum()) == 3 * 24
+    (out, selected), (ref, n) = _both_forms(q, iq, iw, rows, keys, TABLE, kv_len)
+    assert int(selected) == int(n) == 3 * 24 and jnp.abs(out - ref).max() < 1e-5
+
+
+@pytest.mark.parametrize("backend, packed, form", [
+    ("ref", False, "staged"), ("pallas", False, "walk"), ("pallas-interpret", False, "walk"),
+    ("pallas", True, "staged"), ("ref", True, "staged")])
+def test_the_indexers_form_is_read_off_the_backend_and_the_rows_packing(backend, packed, form):
+    assert la.index_form(backend, packed) == form
+
+
+def test_a_ragged_rounds_one_token_rows_keep_the_staged_indexer(monkeypatch):
+    """``packed_attention`` (and the chunk form beside it) computes what it
+    computed: on a kernel backend its one-token rows walk their LATENT pages
+    and stage their index keys."""
+    def refuse(*args, **kw):
+        raise AssertionError("a ragged round's rows took the indexer's walk")
+
+    monkeypatch.setattr(pa, "paged_index_scores", refuse)
+    args = _ragged_round()
+    got, selected = la.packed_attention(*args, width=8, backend="pallas-interpret", **KW)
+    want, n = la.packed_attention(*args, width=8, **KW)
+    assert int(selected) == int(n) and jnp.abs(got - want)[:8].max() < 1e-5
+    q, iq, iw, rows, keys = args[:5]
+    with pytest.raises(AssertionError, match="took the indexer's walk"):
+        _both_forms(q[:3], iq[:3], iw[:3], rows, keys, TABLE, [100, 112, 80])

@@ -518,7 +518,10 @@ def test_the_walked_latent_attention_compiles_for_v5e_at_the_cell_shape(one_chip
     ``mla_attention`` (``paged_latent_attention``: the paged kernel's walk
     with one source, 128 query rows a KV "head", 16 x 128 stacked rows for
     the shared head, under a 48 MiB VMEM limit), the selection a mask made
-    under ``dsa_select`` without a sort, no gathered ``[32768, 640]`` copy."""
+    under ``dsa_select`` without a sort, no gathered ``[32768, 640]`` copy.
+    Since PR 43 the indexer walks too: ONE custom call under ``dsa_indexer``
+    (``paged_index_scores``), no staged ``[2048 pages, 128, 128]`` copy of the
+    table's index keys and no ``[16, 64, 16384]`` products."""
     from finchat_tpu.ops.latent_attention import LatentShape, decode_attention
 
     def shape(dims, dtype=jnp.bfloat16):
@@ -533,13 +536,43 @@ def test_the_walked_latent_attention_compiles_for_v5e_at_the_cell_shape(one_chip
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
              if "tpu_custom_call" in line and " custom-call(" in line]
-    assert len(calls) == 1 and "/mla_attention/" in calls[0]
-    assert "paged_latent_attention" in calls[0].split(" = ")[0]
+    by_scope = {scope: [c.split(" = ")[0] for c in calls if f"/{scope}/" in c]
+                for scope in ("dsa_indexer", "mla_attention")}
+    assert len(calls) == 2 and [len(v) for v in by_scope.values()] == [1, 1], by_scope
+    assert "paged_latent_attention" in by_scope["mla_attention"][0]
+    assert "paged_index_scores" in by_scope["dsa_indexer"][0]
     assert "bf16[32768,640]" not in text and "bf16[16,2048,640]" not in text
+    assert "bf16[2048,128,128]" not in text and "bf16[16,16384,128]" not in text
+    assert "f32[16,64,16384]" not in text and "f32[16,1,64,16384]" not in text
     assert " sort(" not in text
     for scope in ("dsa_indexer", "dsa_select", "mla_attention"):
         assert f"/{scope}/" in text, scope
-    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_the_indexers_walk_compiles_for_v5e_at_the_cell_shape(one_chip):
+    """``paged_index_scores`` alone at the cell's shapes (16 rows, 64 index
+    heads over keys of 128, a table of 128 columns over a pool five layers
+    deep): ONE custom call — the paged kernel's walk in its index form,
+    blocks of 2,048 tokens, the ``[16, 18304]`` float32 scores resident over
+    the grid, inside the 16 MiB of scoped VMEM a kernel has without asking —
+    and temporaries of the padded scores and the lane-wide weights alone."""
+    from finchat_tpu.ops.paged_attention import INDEX_BLOCK_TOKENS, paged_index_scores
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda *args: paged_index_scores(*args, page_size=PAGE)).lower(
+        shape((ROWS, 64, 128)), shape((ROWS, 64), jnp.float32), shape((5, POOL, PAGE, 128)),
+        shape((ROWS, WIDTH), jnp.int32), shape((ROWS,), jnp.int32), shape((1,), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "paged_index_scores" in calls[0]
+    padded = (WIDTH + INDEX_BLOCK_TOKENS // PAGE - 1) * PAGE
+    assert f"f32[{ROWS},{padded}]" in text and "vmem_limit_bytes" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * 1024 * 1024
 
 
 def test_deepseek_decode_step_compiles_for_v5e_walking_its_latent_pages(one_chip):
@@ -560,6 +593,12 @@ def test_deepseek_decode_step_compiles_for_v5e_walking_its_latent_pages(one_chip
         "paged_latent_attention" in line.split(" = ")[0] for line in walks)
     assert "bf16[32768,640]" not in text
     assert not [line for line in text.splitlines() if "/dsa_select/" in line and " sort(" in line]
+    # ... and the indexer its index pages (PR 43): one custom call a layer, nothing staged
+    scored = [line for line in text.splitlines()
+              if "/dsa_indexer/" in line and 'custom_call_target="tpu_custom_call"' in line]
+    assert len(scored) == 2 and all(
+        "paged_index_scores" in line.split(" = ")[0] for line in scored)
+    assert "bf16[2048,128,128]" not in text and "f32[16,64,16384]" not in text
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= state.k_pages.size * 2 + state.v_pages.size * 2
     assert memory.temp_size_in_bytes < 64 * 1024 * 1024
