@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from jax import Array, lax
 
 from finchat_tpu.models import gdn, mla, sambay
-from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize
+from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize, flat_fence
 from finchat_tpu.models.ssm import mixer, scaled
 from finchat_tpu.ops import moe_step
 
@@ -997,8 +997,10 @@ def _layer(
                     t = rms_norm(t, layer_params[norm], c.norm_eps)
                 return t.reshape(B, S, n, c.head_dim)
 
-            q = heads(dense(h, layer_params["attn_q"], qm_backend=qm_backend), hq, "attn_q_norm")
-            k = heads(scaled(dense(h, layer_params["attn_k"], qm_backend=qm_backend),
+            # q and k fenced flat: the heads' layout must not reach the weights
+            q = heads(flat_fence(dense(h, layer_params["attn_q"], qm_backend=qm_backend)),
+                      hq, "attn_q_norm")
+            k = heads(scaled(flat_fence(dense(h, layer_params["attn_k"], qm_backend=qm_backend)),
                              c.key_multiplier), hkv, "attn_k_norm")
             v = heads(dense(h, layer_params["attn_v"], qm_backend=qm_backend), hkv)
             if c.rope_theta is not None:

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import Array
 
-from finchat_tpu.models.quant import dense
+from finchat_tpu.models.quant import dense, flat_fence
 
 
 class RopeScaling(NamedTuple):
@@ -119,8 +119,10 @@ def _from_latent(c_q: Array, w: Array) -> Array:
     [N, Q]: the layout the TPU compiler gives a weight whose contraction is
     the q latent's 1,536 columns — kept input-major, all three (q_nope, q_rope,
     the indexer's queries) were transposed in HBM on every step, 12 % of it
-    (PERF.md section 5)."""
-    return jnp.einsum("bsq,nq->bsn", c_q, w)
+    (PERF.md section 5). The product is split into heads and rotated, so it
+    is fenced flat (``flat_fence``: else each weight is staged out of its stack
+    before its matmul)."""
+    return flat_fence(jnp.einsum("bsq,nq->bsn", c_q, w))
 
 
 def project(h: Array, lp: dict[str, Array], c, positions: Array,

@@ -233,6 +233,25 @@ def dense(x: Array, w: Array | QTensor | Q4Tensor, *,
     return x @ w
 
 
+def flat_fence(product: Array) -> Array:
+    """The identity, as a fence: a projection's product ``[B, S, N]`` held FLAT
+    before its columns are split into heads. What it stops is the TPU
+    compiler's layout assignment carrying the head-major layout of the split
+    (and of the rotation behind it) back through the dot into the WEIGHT:
+    unfenced, a layer's ``attn_q`` and ``attn_k`` were sliced out of their
+    stack into on-chip memory and transposed there before the matmul could
+    start (Mistral: ``bf16[1,4096,4096]`` ``{2,1,0}`` -> ``{1,2,0}``, 59 us of
+    a 1.2 ms layer; Phi-4-flash: its whole stack of 16 ``attn_q``, 210 MB,
+    transposed in HBM once a step), where ``attn_v`` and the MLP's weights,
+    whose product is not rotated, are read straight from the stack inside the
+    matmul's fusion. Fenced, q and k are read that way too. The product is
+    rounded where it was, so no value changes. Found in the programs compiled
+    for a described v5e (``benchmarks/relayout_probe.py`` lists them,
+    ``tests/test_tpu_compile.py`` holds the count at 0) and measured on the
+    chip (PERF.md sections 5 and 6, PR 45)."""
+    return jax.lax.optimization_barrier(product)
+
+
 def should_quantize(name: str) -> bool:
     """The ONE definition of which param leaves quantize: the layer-stack
     matmul weights plus the (untied) ``lm_head``. Shared by engine-side

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax import Array, lax
 
-from finchat_tpu.models.quant import dense
+from finchat_tpu.models.quant import dense, flat_fence
 from finchat_tpu.models.ssm import SsmRows, _read, _to_packed, _to_rows, _write, causal_conv
 
 FULL = "full_attention"
@@ -295,14 +295,14 @@ def attention(h: Array, lp: dict[str, Any], c, attention_fn: Callable, cache: An
 
     with jax.named_scope("attn_qkv"):
         # query head 2p is q1 of pair p, 2p + 1 its q2: [q1 | 0] and [0 | q2]
-        q = project("attn_q").reshape(B, S, H // 2, 2, hd // 2)
+        q = flat_fence(project("attn_q")).reshape(B, S, H // 2, 2, hd // 2)
         zero = jnp.zeros_like(q[..., :1, :])
         q = jnp.concatenate(
             [jnp.concatenate([q[..., :1, :], zero], axis=-1),
              jnp.concatenate([zero, q[..., 1:, :]], axis=-1)], axis=-2).reshape(B, S, H, hd)
         k = v = None
         if kind != CROSS:
-            k = project("attn_k").reshape(B, S, Hkv, hd)
+            k = flat_fence(project("attn_k")).reshape(B, S, Hkv, hd)
             v = project("attn_v").reshape(B, S, Hkv, hd)
     # the callback opens its own scopes (engine/engine.py)
     out, cache = attention_fn(q, k, v, cache, cache_idx, kind)

@@ -8,6 +8,7 @@ All such compiles live in this one file: only one process may hold the TPU
 library, and the worker that is given this file is that process.
 """
 
+import json
 import re
 
 import jax
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from benchmarks import relayout_probe
 from finchat_tpu.engine.kv_cache import scale_rows
 from finchat_tpu.ops.paged_attention import (
     paged_flash_attention,
@@ -87,36 +89,20 @@ def test_paged_attention_compiles_for_v5e_at_falcon_h1s_head_counts(one_chip, C,
     assert len(calls) == 1 and calls[0].startswith("%paged_flash_attention")
 
 
-def _compiled_decode_step(one_chip, model, file):
-    """``decode_step`` of ``file``'s configuration (``model``: its adapter)
-    compiled for the described chip at the file's engine options, from shapes
-    alone; returns ``(compiled, state shapes)``."""
-    from finchat_tpu.engine import engine as E
-    from finchat_tpu.models.llama import init_params
-    from finchat_tpu.utils.config import EngineConfig
-
-    c = model.program_config(file)
-    cfg = EngineConfig(**file["engine"])
-
-    def described(tree):
-        return jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), tree)
-
-    params = described(jax.eval_shape(lambda: init_params(c, jax.random.key(0))))
-    state = described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH)))
-    row = lambda dtype: jax.ShapeDtypeStruct((ROWS,), dtype, sharding=one_chip)  # noqa: E731
-    compiled = E.decode_step.lower(
-        params, state, row(bool), row(jnp.float32), row(jnp.float32), row(jnp.int32),
-        config=c, page_size=PAGE, attn_backend="pallas", qm_backend="ref").compile()
-    return compiled, state
+_DECODE_STEPS = {}  # a configuration's compiled decode step, shared by the tests that read it
 
 
-def _config_file(name):
-    import json
-    from pathlib import Path
+def _compiled_decode_step(one_chip, file):
+    """``decode_step`` of ``file``'s configuration compiled for the described
+    chip at the file's engine options, from shapes alone; returns ``(compiled,
+    state shapes)``."""
+    key = json.dumps(file, sort_keys=True)
+    if key not in _DECODE_STEPS:
+        _DECODE_STEPS[key] = relayout_probe.compiled_decode_step(file, one_chip)
+    return _DECODE_STEPS[key]
 
-    return json.loads((Path(__file__).resolve().parents[1]
-                       / f"perfbench/configs/{name}.json").read_text())
+
+_config_file = relayout_probe.config_file
 
 
 def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip):
@@ -128,10 +114,8 @@ def test_falcon_h1_decode_step_compiles_for_v5e_with_its_state_in_place(one_chip
     rules for its [rows, 32, 128, 256] float32 blocks are checked by this
     compile), and it is the ONLY operation under ``ssm_scan`` that touches
     the carried state: XLA's own two fusions both read it."""
-    from perfbench.models import falcon_h1
-
     compiled, state = _compiled_decode_step(
-        one_chip, falcon_h1, dict(_config_file("falcon-h1-34b-instruct"), num_hidden_layers=5))
+        one_chip, dict(_config_file("falcon-h1-34b-instruct"), num_hidden_layers=5))
     memory = compiled.memory_analysis()
     state_bytes = 5 * ROWS * 32 * 128 * 256 * 4
     assert state.ssm_state.shape == (5, ROWS, 32, 128, 256)
@@ -203,12 +187,9 @@ def test_olmo_hybrid_decode_step_compiles_for_v5e_with_pool_and_state_in_place(o
     under ``gdn_scan``, a period's, which is what ``gdn_state_roofline.sat``
     times and the adapter counts, and no fusion under that scope touches the
     carried state."""
-    from perfbench.models import olmo_hybrid
-
     file = _config_file("olmo-hybrid-7b")
     compiled, state = _compiled_decode_step(
-        one_chip, olmo_hybrid,
-        dict(file, num_hidden_layers=8, layer_types=file["layer_types"][:4] * 2))
+        one_chip, dict(file, num_hidden_layers=8, layer_types=file["layer_types"][:4] * 2))
     assert state.k_pages.shape == (2, POOL, PAGE, 30 * HEAD_DIM)
     assert state.ssm_state.shape == (6, ROWS, 15, 96, 384)
     assert state.conv_state.shape == (6, ROWS, 3, 11520)
@@ -413,10 +394,8 @@ def test_granite_decode_step_compiles_for_v5e_with_pool_and_state_in_place(one_c
     WHOLE stacks: no layer's slice is copied, no ``[16, 36, 1536]``
     intermediate; no grouped matmul at 16 tokens), and the counts of experts
     touched and read two int32 out."""
-    from perfbench.models import granitemoehybrid
-
     file = _config_file("granite-4.0-h-small")
-    compiled, state = _compiled_decode_step(one_chip, granitemoehybrid, file)
+    compiled, state = _compiled_decode_step(one_chip, file)
     memory = compiled.memory_analysis()
     state_bytes = 9 * ROWS * 128 * 64 * 128 * 4
     assert state.ssm_state.shape == (9, ROWS, 128, 64, 128)
@@ -582,10 +561,7 @@ def test_deepseek_decode_step_compiles_for_v5e_walking_its_latent_pages(one_chip
     scan's body, one in the leading dense layer), no gathered ``[32768, 640]``
     copy and no sort under ``dsa_select``; both paged arrays are updated in
     place, and the step's temporaries are a ninth of the gather form's."""
-    from perfbench.models import deepseek_v32
-
-    compiled, state = _compiled_decode_step(
-        one_chip, deepseek_v32, _config_file("deepseek-v3.2-exp"))
+    compiled, state = _compiled_decode_step(one_chip, _config_file("deepseek-v3.2-exp"))
     text = compiled.as_text()
     walks = [line for line in text.splitlines()
              if "/mla_attention/" in line and 'custom_call_target="tpu_custom_call"' in line]
@@ -699,3 +675,46 @@ def test_phi4_flash_prefill_chunk_compiles_for_v5e_at_forty_heads(one_chip):
 
     compiled, _state, _cfg = _phi4_step(one_chip, "prefill_step", args)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+# --- no weight is copied or staged before its matmul (PR 45) ---
+
+LLAMA_BLOCK = ("mistral-7b-v0.3", "mixtral-8x7b-v0.1", "falcon-h1-34b-instruct",
+               "olmo-hybrid-7b", "granite-4.0-h-small")
+
+
+@pytest.mark.parametrize("name", [*LLAMA_BLOCK, "deepseek-v3.2-exp",
+                                  "phi-4-mini-flash-reasoning"])
+def test_decode_step_reads_every_projections_weight_where_it_lies(one_chip, name):
+    """``decode_step`` of each of the seven configuration files at its cell's
+    shapes, compiled for the described v5e: (a) no ``copy``, fused or not,
+    whose result is a bfloat16 array of 2,000,000 elements or more (the
+    smallest weight at stake, Falcon-H1's ``attn_k``, has 2.6 M; the largest
+    re-tiling of an activation that remains, DeepSeek's ``bf16[16,128,512]``,
+    1.05 M); (b) no ``dynamic-slice`` fusion of that size that leaves its
+    result in on-chip memory (``S(1)``): a layer's weight staged out of its
+    stack before the matmul can start; (c) in a llama-block file, a fusion
+    under ``attn_qkv`` that takes the WHOLE ``attn_q`` stack ``bf16[L, D, N]``
+    as an operand: the matmul reads its layer where it lies, as ``attn_v``'s
+    and the MLP's always did. What keeps it so is ``models/quant.py``
+    ``flat_fence`` round the product of a projection that is split into heads.
+    On the parent of PR 45 this FAILS for Mistral, Mixtral, Falcon-H1 (2 copies
+    + 2 staged slices each: ``attn_q`` and ``attn_k``, sliced and transposed a
+    layer), Phi-4-flash (the whole ``bf16[16,2560,2560]`` stack copied in HBM
+    once a step, ``bf16[2560,2560]`` and two staged slices) and DeepSeek (three
+    staged up-projections of the q latent and a nested re-layout of
+    ``attn_q_rope``), and passes for Olmo-Hybrid and Granite, which do not
+    rotate; with the fence all seven pass.
+    ``python3 benchmarks/relayout_probe.py <name>`` prints the listing."""
+    from finchat_tpu.models.llama import init_params
+    from perfbench.models import adapter
+
+    probe, file = relayout_probe, _config_file(name)
+    ops = probe.operations(_compiled_decode_step(one_chip, file)[0].as_text())
+    copies, staged = probe.weight_relayouts(ops)
+    assert not copies and not staged, [o.line() for o in copies + staged]
+    if name in LLAMA_BLOCK:
+        c = adapter(file).program_config(file)
+        stack = jax.eval_shape(lambda: init_params(c, jax.random.key(0)))["layers"]["attn_q"]
+        assert stack.dtype == jnp.bfloat16
+        assert probe.reads_whole_stack(ops, "attn_qkv", tuple(stack.shape)), stack.shape
