@@ -69,8 +69,8 @@ def one_round(start, n):
     tok_row = [0] * n + [B] * (T - n); packed += [0] * (T - n)
     zeros, ones, zi = jnp.zeros((B,), jnp.float32), jnp.ones((B,), jnp.float32), jnp.zeros((B,), jnp.int32)
     no = jnp.zeros((B,), bool)
-    out = engine.ragged_mixed(jnp.asarray(np.asarray(packed, np.int32)), jnp.asarray(np.asarray(tok_row, np.int32)),
-        jnp.asarray(row_slot), jnp.asarray(row_start), jnp.asarray(row_len), no, no, zi, zeros, ones, zi, no, zeros, ones, zi, -1)
+    out = engine.ragged_round(jnp.asarray(np.asarray(packed, np.int32)), jnp.asarray(np.asarray(tok_row, np.int32)),
+        jnp.asarray(row_slot), jnp.asarray(row_start), jnp.asarray(row_len), no, no, zi, zeros, ones, zi)
     return np.asarray(out[2], np.float32)
 
 for i in range(iters):

@@ -36,10 +36,6 @@ def main() -> None:
     p.add_argument("--preset", default=None, help="model preset override")
     p.add_argument("--port", type=int, default=None)
     p.add_argument("--no-http", action="store_true", help="Kafka worker loop only")
-    p.add_argument("--decode-loop-depth", type=int, default=None,
-                   help="tokens per fused decode dispatch (engine "
-                        "decode_loop_step); 1 = per-token decode "
-                        "— also FINCHAT_DECODE_LOOP_DEPTH")
     p.add_argument("--session-cache-bytes", type=int, default=None,
                    help="host-RAM byte budget for the session KV cache "
                         "(engine/session_cache.py); 0 disables cross-turn "
@@ -101,8 +97,6 @@ def main() -> None:
         overrides["model.preset"] = args.preset
     if args.port:
         overrides["serve.port"] = args.port
-    if args.decode_loop_depth is not None:
-        overrides["engine.decode_loop_depth"] = args.decode_loop_depth
     if args.session_cache_bytes is not None:
         overrides["engine.session_cache_bytes"] = args.session_cache_bytes
     if args.request_deadline_seconds is not None:

@@ -1,9 +1,8 @@
 """R2 ``hot-path-host-sync``: device→host synchronization inside the hot
 dispatch/consume paths.
 
-PR 4's contract — ONE ragged dispatch per scheduler iteration — and the
-free-running-loop direction (ROADMAP item 5) both die by a thousand
-``.item()`` calls: any host materialization of a device value inside the
+PR 4's contract — ONE ragged dispatch per scheduler iteration — dies by a
+thousand ``.item()`` calls: any host materialization of a device value inside the
 dispatch path serializes the pipeline (the host blocks until the device
 catches up) and reintroduces the per-round sync PR 1/PR 4 removed. The
 blessed pattern is batching every host fetch into the single
@@ -57,28 +56,14 @@ _OFF_LOOP_TAILS = ("to_thread", "run_in_executor", "submit")
 
 SCHEDULER_HOT = {
     "_dispatch_decode",
-    "_dispatch_decode_loop",
     "_ragged_round",
     "_prefill_round",
     "_run_spec_step",
     "_consume_step",
-    "_consume_block",
-    "_consume_inflight",
     "_drain_inflight",
     "_deliver",
     "_pack_prefill_rows",
 }
-
-# the freerun-consume check (ISSUE 13): the free-running loop's ring-drain
-# seam joins the hot set by name — a block_until_ready / .item() /
-# implicit __bool__ on the drain path would re-serialize the host against
-# the very capture the loop exists to overlap (the token ring must be
-# fetched through the off-loop to_thread seam, never synced inline)
-FREERUN_HOT = {
-    "_dispatch_freerun",
-    "_consume_ring",
-}
-SCHEDULER_HOT |= FREERUN_HOT
 
 ENGINE_COLD = {"__init__", "create_state", "warmup", "rebuild_device_state"}
 

@@ -12,27 +12,16 @@ point has ONE static shape per (batch-bucket) —
   exhausted prompts ride later rounds with ``n_valid = 0``.
 - ``decode_step``: the full ``max_seqs`` slot batch, every step. Inactive
   slots ride along writing their KV to the trash page.
-- ``decode_loop_step``: the same slot batch, ``decode_loop_depth`` fused
-  decode iterations per dispatch (on-device sampling + per-slot EOS mask
-  inside a ``fori_loop``) — the host pays one dispatch and one
-  ``[K, max_seqs]`` token fetch per K tokens instead of per token.
 - ``ragged_mixed_step``: ONE packed ragged dispatch advancing every
-  prefilling sequence a chunk, every decoding slot a token, every
-  spec-decode slot a (1+Kd)-token verify block, and every loop-eligible
-  slot a fused K-token tail — rows of a PACKED token buffer
-  (ops/ragged_paged_attention.py), each carrying its own length, page
-  list, and sampling params, with on-device sampling preserved
+  prefilling sequence a chunk, every decoding slot a token and every
+  spec-decode slot a (1+Kd)-token verify block — rows of a PACKED token
+  buffer (ops/ragged_paged_attention.py), each carrying its own length,
+  page list, and sampling params, with on-device sampling preserved
   throughout. The scheduler's mixed path (engine.mixed_step config,
   default on) cuts a coexisting iteration from two-or-more serialized
   model dispatches to one, with no per-mode demotions (ISSUE 10; PR 4's
-  padded ``[rows, chunk]`` buffer demoted on spec/loop/constrained work
-  and paid dense decode-row compute per padded column).
-- ``ragged_multi_round``: the free-running loop (ISSUE 13) — up to
-  ``freerun_rounds`` consecutive ragged rounds captured as ONE device
-  program (``lax.scan`` over the same round body), with a staged
-  descriptor queue the rounds drain in order, on-device EOS stop masks
-  generalized to every row, and a per-round output token ring the host
-  drains asynchronously; host control returns only at membership epochs.
+  padded ``[rows, chunk]`` buffer demoted on spec/constrained work and
+  paid dense decode-row compute per padded column).
 
 State is donated on every call and the KV cache is updated IN PLACE by the
 Pallas append kernel (ops/kv_append.py) on the decode path — XLA's scatter
@@ -65,6 +54,27 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.logging import get_logger
 
 logger = get_logger(__name__)
+
+
+# What a kind of per-row memory beyond paged K/V cannot be served with: kind ->
+# option -> why, in a phrase (``InferenceEngine._refuse_what_a_kind_cannot_carry``).
+# A new kind adds a row here, not a method.
+NOT_CARRIED: dict[str, dict[str, str]] = {
+    # config.has_state: a Mamba-2 mixer, Mamba-1 or linear-attention layers
+    "recurrent state": {
+        "engine.spec_tokens": "rejected drafts rewind a row, and no page holds the state",
+        "engine.kv_sink_pages / engine.kv_window_pages": "they re-lay a row's pages beside a state that keeps every token",
+        "mesh.* > 1": "the state has no sharding rule",
+    },
+    # config.kv_lora_rank: a latent row and an index key a token in the page pool
+    "latent pages": {
+        "engine.kv_quant": "int8 pages keep a scale a KV head",
+        "model.quant": "the absorbed projections have no quantized form",
+        "engine.spec_tokens": "verify_step attends K/V heads",
+        "engine.kv_sink_pages / engine.kv_window_pages": "they re-lay a row's pages under the indexer's selection",
+        "mesh.* > 1": "the latent pool has no sharding rule",
+    },
+}
 
 
 def round_up_pow2(n: int) -> int:
@@ -885,7 +895,13 @@ def _ragged_attention_fn(
     return attention
 
 
-def _ragged_round_math(
+@partial(
+    jax.jit,
+    static_argnames=("config", "page_size", "attn_backend", "qm_backend",
+                     "spec_width", "max_row_tokens"),
+    donate_argnums=(1,),
+)
+def ragged_mixed_step(
     params: dict[str, Any],
     state: DecodeState,
     tokens: Array,  # [T] int32 PACKED token buffer (0 at device-read positions)
@@ -900,47 +916,51 @@ def _ragged_round_math(
     temperature: Array,  # [R] — PER-ROW sampling params
     top_p: Array,  # [R]
     top_k: Array,  # [R] int32
-    loop_active: Array,  # [max_seqs] bool — slots riding the fused K-token tail
-    loop_temperature: Array,  # [max_seqs] — per-SLOT params for the tail
-    loop_top_p: Array,  # [max_seqs]
-    loop_top_k: Array,  # [max_seqs] int32
-    eos_id: Array,  # scalar int32 (< 0 disables the tail's stop mask)
-    row_live: Array,  # [R] bool — free-run stop mask (see docstring)
     *,
     config: LlamaConfig,
     page_size: int,
     attn_backend: str = "ref",
     qm_backend: str = "ref",
     spec_width: int = 0,
-    loop_depth: int = 1,
-    max_row_tokens: int = 0,
-) -> tuple[DecodeState, Array, Array, Array, Array]:
-    """The packed ragged round body, shared VERBATIM by the single-round
-    ``ragged_mixed_step`` and the multi-round free-run capture
-    (``ragged_multi_round``) so a captured round is bit-identical math to
-    a host-stepped one by construction.
+    max_row_tokens: int = 0,  # a mixer's row width (0 = the buffer's length)
+) -> tuple[DecodeState, Array, Array, Array]:
+    """ONE packed ragged dispatch advancing every serving population at once
+    (the scheduler's mixed path, ISSUE 10 — built on
+    ops/ragged_paged_attention.py): prefill chunks of any length, 1-token
+    decode rows, grammar-constrained rows (host overrides via the returned
+    logits), and (1+Kd)-token spec verify rows are rows of ONE packed
+    buffer. Returns ``(state, emitted [R, W], n_emitted [R], row_logits
+    [R, vocab])`` with ``W = spec_width + 1``.
 
-    ``row_live`` is the free-run generalization of ``decode_loop_step``'s
-    per-slot stop mask to the full ragged row set: a dead row rides the
-    round fully inert — its KV writes trash-redirect (the scatter sees
-    ``n_valid 0``), nothing arms, ``context_lens``/``last_tokens`` stay
-    frozen, and its emitted count is 0 (the host drain sentinel). The
-    single-round path passes all-True, which reduces every gate below to
-    the identity — the mixed-vs-split byte-identity tests pin that the
-    extraction changed nothing. (See ``ragged_mixed_step`` for the full
-    row/descriptor contract.)"""
+    - Device-read rows (``row_from_device``) take their first token from
+      ``state.last_tokens[slot]`` and start at ``context_lens[slot]`` ON
+      DEVICE; spec rows' drafts ride the packed buffer at offsets 1..Kd.
+    - Spec acceptance is the ``verify_step`` math verbatim: draft i commits
+      iff it equals THIS forward's argmax at its position;
+      ``emitted[r, :n_emitted[r]]`` are the row's tokens (1..Kd+1 for spec
+      rows, 1 for armed plain rows, 0 for mid-prompt prefill rows), and
+      rejected drafts' KV lands beyond the new ``context_lens``.
+    - ``row_logits`` is each row's sampling-position logits (position 0
+      for device rows, the last valid chunk token for prefill rows) — the
+      host-side grammar-pick path, exactly ``decode_step return_logits``.
+    - One rng split for the packed round — the same discipline as
+      ``decode_step``; greedy streams are rng-independent.
+    - ``last_tokens`` commits as a DELTA scatter-add so duplicate-slot
+      padding rows (delta 0) cannot race the real row's write.
+
+    Numerics contract (tests/test_mixed_step.py): same MATH as the split
+    path per token; greedy streams byte-identical at fp32. The documented
+    bf16 near-tie caveat of ``verify_step``/PR 4 applies unchanged: a
+    token computed at the packed shape can differ in the last ulp from the
+    ``[max_seqs, 1]`` shape and flip a later near-tie argmax — either
+    stream is a valid greedy decode.
+    """
     T = tokens.shape[0]
     R = row_slot.shape[0]
-    B = state.context_lens.shape[0]
     W = spec_width + 1
     tok_row = jnp.asarray(tok_row, jnp.int32)
     safe_row = jnp.minimum(tok_row, R - 1)
-    # dead rows' tokens are demoted to padding: KV writes trash-redirect
-    # and attention treats them as buffer padding (all-True live mask →
-    # exactly the original tok_row < R predicate)
-    tok_valid = (tok_row < R) & row_live[safe_row]
-    # nothing arms on a dead row: n_emitted 0, last_tokens delta 0
-    row_arm = row_arm & row_live
+    tok_valid = tok_row < R
     q_start = jnp.concatenate(
         [jnp.zeros((1,), jnp.int32),
          jnp.cumsum(row_len, dtype=jnp.int32)[:-1]]
@@ -961,12 +981,12 @@ def _ragged_round_math(
 
     # a mixer's conv and scan must not run across a row boundary: the packed
     # tokens are regrouped to [R, row_width] rows, each from its slot's state
-    # (a dead or padding row rides inert), and its last state goes back there.
+    # (a padding row rides inert), and its last state goes back there.
     # Latent attention regroups its queries the same way (a row's chunk shares
     # the row's pages and walks them once)
     def packed_rows() -> SsmRows:
         return SsmRows(
-            row_slot, jnp.where(row_live, row_len, 0), pack=(q_start, tok_row, tok_off),
+            row_slot, row_len, pack=(q_start, tok_row, tok_off),
             width=min(T, max_row_tokens or T), backend=attn_backend,
         )
 
@@ -1033,11 +1053,8 @@ def _ragged_round_math(
 
     # context advance: spec rows move by what they EMITTED (rejected
     # drafts' KV stays beyond the new length); every other row by its
-    # packed length (chunk for prefill, 1 for decode, 0 for padding);
-    # dead free-run rows stay frozen
-    advance = jnp.where(
-        row_live, jnp.where(row_n_drafts > 0, n_emitted, row_len), 0
-    )
+    # packed length (chunk for prefill, 1 for decode, 0 for padding)
+    advance = jnp.where(row_n_drafts > 0, n_emitted, row_len)
     delta = jnp.where(row_arm, last_tok - row_last, 0)
     state = dataclasses.replace(
         state,
@@ -1046,341 +1063,7 @@ def _ragged_round_math(
         rng=rng,
     )
 
-    # fused K-token tail: loop-eligible decode slots free-run loop_depth-1
-    # further iterations in the SAME dispatch — the decode_loop_step body
-    # verbatim (same forward, appends, sampling, EOS mask, rng discipline),
-    # so the tail is byte-identical to a split-path block
-    token_block = jnp.full((max(loop_depth - 1, 0), B), -1, jnp.int32)
-    if loop_depth > 1:
-        live0 = loop_active & (state.last_tokens != eos_id)
-
-        def body(i, carry):
-            state, live, token_block = carry
-            toks = state.last_tokens[:, None]  # [B, 1]
-            positions = state.context_lens[:, None]
-            n_valid = live.astype(jnp.int32)
-
-            attn = _paged_attention_fn(
-                state.page_table, state.context_lens - state.kv_gaps, n_valid,
-                page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
-            )
-            step_logits, (kp, vp, ks, vs) = forward(
-                params, toks, positions,
-                config=config, attention=attn,
-                cache=(state.k_pages, state.v_pages,
-                       state.k_scales, state.v_scales),
-                qm_backend=qm_backend,
-            )
-            step_logits = step_logits[:, 0, :]
-            rng, sub = jax.random.split(state.rng)
-            next_tokens = sample(
-                step_logits, sub, loop_temperature, loop_top_p, loop_top_k
-            )
-            state = dataclasses.replace(
-                state,
-                k_pages=kp, v_pages=vp, k_scales=ks, v_scales=vs,
-                context_lens=state.context_lens + n_valid,
-                last_tokens=jnp.where(live, next_tokens, state.last_tokens),
-                rng=rng,
-            )
-            token_block = token_block.at[i].set(
-                jnp.where(live, next_tokens, -1)
-            )
-            live = live & (next_tokens != eos_id)
-            return state, live, token_block
-
-        state, _, token_block = jax.lax.fori_loop(
-            0, loop_depth - 1, body, (state, live0, token_block)
-        )
-    return state, emitted, n_emitted, row_logits, token_block
-
-
-@partial(
-    jax.jit,
-    static_argnames=("config", "page_size", "attn_backend", "qm_backend",
-                     "spec_width", "loop_depth", "max_row_tokens"),
-    donate_argnums=(1,),
-)
-def ragged_mixed_step(
-    params: dict[str, Any],
-    state: DecodeState,
-    tokens: Array,  # [T] int32 PACKED token buffer (0 at device-read positions)
-    tok_row: Array,  # [T] int32 — owning row, ascending contiguous (R = padding)
-    row_slot: Array,  # [R] int32 — engine slot per row
-    row_start: Array,  # [R] int32 — abs pos of the row's first token (prefill)
-    row_len: Array,  # [R] int32 — tokens in the row (0 = padding row)
-    row_from_device: Array,  # [R] bool — token 0 reads last_tokens[slot] and the
-    #   row starts at context_lens[slot] (decode rows, spec verify rows)
-    row_arm: Array,  # [R] bool — commit this row's sampled token to last_tokens
-    row_n_drafts: Array,  # [R] int32 — spec rows: row_len == 1 + n_drafts
-    temperature: Array,  # [R] — PER-ROW sampling params
-    top_p: Array,  # [R]
-    top_k: Array,  # [R] int32
-    loop_active: Array,  # [max_seqs] bool — slots riding the fused K-token tail
-    loop_temperature: Array,  # [max_seqs] — per-SLOT params for the tail
-    loop_top_p: Array,  # [max_seqs]
-    loop_top_k: Array,  # [max_seqs] int32
-    eos_id: Array,  # scalar int32 (< 0 disables the tail's stop mask)
-    *,
-    config: LlamaConfig,
-    page_size: int,
-    attn_backend: str = "ref",
-    qm_backend: str = "ref",
-    spec_width: int = 0,
-    loop_depth: int = 1,
-    max_row_tokens: int = 0,  # a mixer's row width (0 = the buffer's length)
-) -> tuple[DecodeState, Array, Array, Array, Array]:
-    """ONE packed ragged dispatch advancing every serving population at once
-    (the scheduler's mixed path, ISSUE 10 — built on
-    ops/ragged_paged_attention.py): prefill chunks of any length, 1-token
-    decode rows, grammar-constrained rows (host overrides via the returned
-    logits), and (1+Kd)-token spec verify rows are rows of ONE packed
-    buffer; loop-eligible decode slots then free-run ``loop_depth - 1``
-    additional fused iterations INSIDE the same dispatch (the
-    ``decode_loop_step`` body verbatim). Returns
-    ``(state, emitted [R, W], n_emitted [R], row_logits [R, vocab],
-    loop_block [loop_depth-1, max_seqs])`` with ``W = spec_width + 1``.
-
-    - Device-read rows (``row_from_device``) take their first token from
-      ``state.last_tokens[slot]`` and start at ``context_lens[slot]`` ON
-      DEVICE; spec rows' drafts ride the packed buffer at offsets 1..Kd.
-    - Spec acceptance is the ``verify_step`` math verbatim: draft i commits
-      iff it equals THIS forward's argmax at its position;
-      ``emitted[r, :n_emitted[r]]`` are the row's tokens (1..Kd+1 for spec
-      rows, 1 for armed plain rows, 0 for mid-prompt prefill rows), and
-      rejected drafts' KV lands beyond the new ``context_lens``.
-    - ``row_logits`` is each row's sampling-position logits (position 0
-      for device rows, the last valid chunk token for prefill rows) — the
-      host-side grammar-pick path, exactly ``decode_step return_logits``.
-    - One rng split for the packed round plus one per tail iteration —
-      the same per-iteration discipline as ``decode_step`` /
-      ``decode_loop_step``; greedy streams are rng-independent.
-    - ``last_tokens`` commits as a DELTA scatter-add so duplicate-slot
-      padding rows (delta 0) cannot race the real row's write; the tail
-      reads the committed tokens, so a loop slot's phase-1 token chains
-      into its fused tail exactly like K single steps.
-
-    Numerics contract (tests/test_mixed_step.py): same MATH as the split
-    path per token; greedy streams byte-identical at fp32. The documented
-    bf16 near-tie caveat of ``verify_step``/PR 4 applies unchanged: a
-    token computed at the packed shape can differ in the last ulp from the
-    ``[max_seqs, 1]`` shape and flip a later near-tie argmax — either
-    stream is a valid greedy decode.
-    """
-    R = row_slot.shape[0]
-    return _ragged_round_math(
-        params, state, tokens, tok_row, row_slot, row_start, row_len,
-        row_from_device, row_arm, row_n_drafts, temperature, top_p, top_k,
-        loop_active, loop_temperature, loop_top_p, loop_top_k, eos_id,
-        jnp.ones((R,), bool),  # every row live: the host stepped this round
-        config=config, page_size=page_size, attn_backend=attn_backend,
-        qm_backend=qm_backend, spec_width=spec_width, loop_depth=loop_depth,
-        max_row_tokens=max_row_tokens,
-    )
-
-
-@partial(
-    jax.jit,
-    static_argnames=("config", "page_size", "attn_backend", "qm_backend",
-                     "loop_depth"),
-    donate_argnums=(1,),
-)
-def ragged_multi_round(
-    params: dict[str, Any],
-    state: DecodeState,
-    tokens: Array,  # [F, T] int32 — staged packed token buffer PER ROUND
-    tok_row: Array,  # [F, T] int32
-    row_slot: Array,  # [R] int32 — row↔slot binding is FIXED across the run
-    row_start: Array,  # [F, R] int32
-    row_len: Array,  # [F, R] int32
-    row_from_device: Array,  # [F, R] bool
-    row_arm: Array,  # [F, R] bool
-    temperature: Array,  # [R] — per-row sampling params (fixed across rounds)
-    top_p: Array,  # [R]
-    top_k: Array,  # [R] int32
-    loop_active: Array,  # [F, max_seqs] bool — staged fused-tail schedule
-    loop_temperature: Array,  # [max_seqs]
-    loop_top_p: Array,  # [max_seqs]
-    loop_top_k: Array,  # [max_seqs] int32
-    eos_id: Array,  # scalar int32
-    *,
-    config: LlamaConfig,
-    page_size: int,
-    attn_backend: str = "ref",
-    qm_backend: str = "ref",
-    loop_depth: int = 1,
-) -> tuple[DecodeState, Array, Array, Array]:
-    """The free-running serving loop (ISSUE 13): ``F = freerun_rounds``
-    consecutive ragged rounds captured as ONE replayable device program —
-    a ``lax.scan`` over the exact ``_ragged_round_math`` body the
-    host-stepped path runs, erasing F-1 of every F host round-trips.
-
-    - **Staged-descriptor queue**: the leading ``[F, ...]`` axis of the
-      descriptor arrays is a queue in device memory that rounds drain in
-      order. The host pre-stages each round at dispatch time from data it
-      already owns — prompt chunks advance deterministically, so a
-      prefill row's completion round is known ahead and later rounds
-      stage it as an on-device-sampled decode row (on-device admission of
-      the pre-staged prompt: the completing round arms the row and its
-      first token commits to ``last_tokens`` with no host involvement,
-      exactly ``commit_first_token``'s math).
-    - **On-device stop masks**: budget exhaustion is staged away by the
-      host (a row past its remaining ``max_new_tokens`` simply stops
-      appearing in later rounds' descriptors); EOS — the one
-      data-dependent stop — is the device's: a round recomputes
-      ``row_live`` from ``last_tokens[row_slot] == eos_id`` for
-      device-read rows, so a row that commits EOS (in its own round OR
-      its fused tail) rides every later round inert, emitting 0. This is
-      ``decode_loop_step``'s per-slot mask generalized to the ragged row
-      set, and it is also what makes a stale capture safe: rows whose
-      stream the host has since retired stay dead because their EOS is
-      still in ``last_tokens`` until the post-run slot reset applies.
-    - **Output ring**: per-round emissions land in the scan's stacked
-      output buffers — ``ring_tokens [F, R]`` (each armed row's token),
-      ``ring_n [F, R]`` (0 = mid-prompt chunk / dead row — the drain
-      sentinel), ``ring_blocks [F, loop_depth-1, max_seqs]`` (the fused
-      tails). The scheduler drains the ring off-loop while the device is
-      mid-flight on the NEXT capture (depth-2, engine/scheduler.py
-      ``_consume_ring``).
-
-    No spec verify rows inside a capture (drafts are host data proposed
-    from DELIVERED tokens; live proposal windows cap the capture to one
-    round — scheduler ``_freerun_rounds_cap``), so ``spec_width`` is
-    pinned to 0 and each ring round emits at most one token per row plus
-    its tail. Returns ``(state, ring_tokens, ring_n, ring_blocks)``.
-
-    Byte-identity contract: round r of a capture is bit-identical math to
-    the r'th host-stepped ``ragged_mixed_step`` over the same descriptors
-    (same body, same rng split discipline — tests/test_freerun.py pins
-    the stream-level identity at fp32)."""
-    R = row_slot.shape[0]
-    no_drafts = jnp.zeros((R,), jnp.int32)
-
-    def one_round(state, staged):
-        toks, trow, rstart, rlen, rdev, rarm, lact = staged
-        # the EOS stop mask: device-read rows whose slot already committed
-        # EOS ride this round dead (eos_id < 0 disables, as in the tail)
-        row_live = jnp.logical_not(
-            rdev & (state.last_tokens[row_slot] == eos_id)
-        )
-        state, emitted, n_emitted, _row_logits, blk = _ragged_round_math(
-            params, state, toks, trow, row_slot, rstart, rlen, rdev, rarm,
-            no_drafts, temperature, top_p, top_k, lact,
-            loop_temperature, loop_top_p, loop_top_k, eos_id, row_live,
-            config=config, page_size=page_size, attn_backend=attn_backend,
-            qm_backend=qm_backend, spec_width=0, loop_depth=loop_depth,
-        )
-        # W = 1 (no spec rows): column 0 is every armed row's token
-        return state, (emitted[:, 0], n_emitted, blk)
-
-    state, (ring_tokens, ring_n, ring_blocks) = jax.lax.scan(
-        one_round, state,
-        (tokens, tok_row, row_start, row_len, row_from_device, row_arm,
-         loop_active),
-    )
-    return state, ring_tokens, ring_n, ring_blocks
-
-
-@partial(
-    jax.jit,
-    static_argnames=("config", "page_size", "attn_backend", "qm_backend",
-                     "loop_depth"),
-    donate_argnums=(1,),
-)
-def decode_loop_step(
-    params: dict[str, Any],
-    state: DecodeState,
-    active: Array,  # [max_seqs] bool
-    temperature: Array,  # [max_seqs]
-    top_p: Array,  # [max_seqs]
-    top_k: Array,  # [max_seqs] int32
-    eos_id: Array,  # scalar int32 (< 0 disables the on-device stop mask)
-    *,
-    config: LlamaConfig,
-    page_size: int,
-    attn_backend: str = "ref",
-    qm_backend: str = "ref",
-    loop_depth: int = 4,
-) -> tuple[DecodeState, Array]:
-    """K fused decode iterations in ONE dispatch (``jax.lax.fori_loop``):
-    the multi-step path that amortizes the per-token synchronization
-    boundary (one ``decode_step`` dispatch + one device→host token fetch +
-    one Python dispatch per generated token) across ``loop_depth`` tokens —
-    the dominant remaining tax once the kernels themselves are tuned
-    (arxiv 2410.23668 "kernel looping").
-
-    Each iteration is EXACTLY the ``decode_step`` body — same forward, same
-    in-place Pallas KV appends, same on-device ``sample`` call with the same
-    per-iteration ``jax.random.split`` rng discipline — so a K-block greedy
-    stream is token-for-token identical to K single steps
-    (tests/test_decode_loop.py pins this).
-
-    On-device stop mask: a slot that samples ``eos_id`` has the EOS token
-    recorded, then free-runs the remaining iterations INACTIVE — KV writes
-    trash-redirected, ``context_lens`` frozen, output rows -1 — instead of
-    forcing an early host exit (a data-dependent loop bound would defeat
-    the single fixed-shape dispatch). Slots inactive at entry stay -1
-    throughout. The host fetches the whole ``[loop_depth, max_seqs]`` block
-    once per dispatch and delivers per-slot rows until EOS/-1.
-
-    Host contract (scheduler ``decode_loop`` mode): slots needing per-token
-    host control — grammar-constrained picks, spec-decode drafts, slots
-    within ``loop_depth`` tokens of their ``max_new_tokens``/page budget —
-    must NOT ride a block; the scheduler demotes them to single-step.
-
-    PRNG: the carried ``state.rng`` splits ONCE per iteration for the whole
-    batch — deliberately the same per-iteration discipline as
-    ``decode_step`` (not a per-slot key tree), so an iteration of the block
-    is bit-identical math to a single step given the same carried state.
-    Non-greedy streams still depend on batch-global rng consumption order
-    (as they always have); greedy streams are rng-independent, which is
-    the block/single-step parity contract the tests pin.
-    """
-    B = active.shape[0]
-
-    def body(i, carry):
-        state, live, token_block = carry
-        tokens = state.last_tokens[:, None]  # [B, 1]
-        positions = state.context_lens[:, None]  # [B, 1] — absolute (rotary)
-        n_valid = live.astype(jnp.int32)  # [B]
-
-        # compacted write/mask coordinates (bounded KV; see decode_step)
-        attention = _paged_attention_fn(
-            state.page_table, state.context_lens - state.kv_gaps, n_valid,
-            page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
-        )
-        logits, (k_pages, v_pages, k_scales, v_scales) = forward(
-            params, tokens, positions,
-            config=config, attention=attention,
-            cache=(state.k_pages, state.v_pages, state.k_scales, state.v_scales),
-            qm_backend=qm_backend,
-        )
-        step_logits = logits[:, 0, :]  # [B, vocab]
-
-        rng, sub = jax.random.split(state.rng)
-        next_tokens = sample(step_logits, sub, temperature, top_p, top_k)
-
-        state = dataclasses.replace(
-            state,
-            k_pages=k_pages,
-            v_pages=v_pages,
-            k_scales=k_scales,
-            v_scales=v_scales,
-            context_lens=state.context_lens + n_valid,
-            last_tokens=jnp.where(live, next_tokens, state.last_tokens),
-            rng=rng,
-        )
-        token_block = token_block.at[i].set(jnp.where(live, next_tokens, -1))
-        # EOS is recorded above, THEN the slot goes inactive: later
-        # iterations trash-write and emit -1 (the host's drain sentinel)
-        live = live & (next_tokens != eos_id)
-        return state, live, token_block
-
-    token_block = jnp.full((loop_depth, B), -1, jnp.int32)
-    state, _, token_block = jax.lax.fori_loop(
-        0, loop_depth, body, (state, active, token_block)
-    )
-    return state, token_block
+    return state, emitted, n_emitted, row_logits
 
 
 @partial(
@@ -1527,12 +1210,6 @@ class InferenceEngine:
         self.tp_overlap = engine_cfg.tp_overlap
         self.engine_cfg = engine_cfg
         self.page_size = engine_cfg.page_size
-        # fused multi-step decode (decode_loop_step): tokens per dispatch;
-        # 1 = per-token decode_step only (today's behavior)
-        self.decode_loop_depth = max(1, engine_cfg.decode_loop_depth)
-        # free-running loop (ragged_multi_round): consecutive ragged
-        # rounds captured per dispatch; 1 = host-stepped rounds only
-        self.freerun_rounds = max(1, engine_cfg.freerun_rounds)
         # serving-variant count of the last warmup() (0 = not warmed yet);
         # the scheduler emits it as the finchat_warmup_compiled_variants
         # gauge — the ISSUE 10 warmup-matrix-collapse instrument
@@ -1570,7 +1247,6 @@ class InferenceEngine:
             _bp.validate(
                 prefill_chunk=engine_cfg.prefill_chunk,
                 max_pages_per_seq=self.max_pages_per_seq,
-                decode_loop_depth=self.decode_loop_depth,
                 spec_tokens=engine_cfg.spec_tokens,
             )
         self.bounded_kv = _bp if _bp.enabled else None
@@ -1581,12 +1257,9 @@ class InferenceEngine:
         # aligned blocks when Hkv % 8 == 0, replicated — they're ~6% of the
         # pages — otherwise), and the SP-prefill write path quantizes too
         self.kv_quant = kv_quant = engine_cfg.kv_quant
-        if config.has_state:
-            self._refuse_without_state_carry(mesh)
+        self._refuse_what_a_kind_cannot_carry(mesh, quant)
         if config.moe_fused_glu:
             self._refuse_with_held_experts(mesh, quant)
-        if config.kv_lora_rank:
-            self._refuse_with_latent_cache(mesh, quant)
         state = create_state(config, engine_cfg, self.max_pages_per_seq, kv_quant=kv_quant)
         if mesh is not None:
             # TP placement: params sharded Megatron-style, KV pages sharded
@@ -1705,29 +1378,27 @@ class InferenceEngine:
                 win_gaps=jnp.asarray(pager.gaps.copy()))
             self._window_gauges()
 
-    def _refuse_without_state_carry(self, mesh) -> None:
-        """A model with a mixer or with linear-attention layers
-        (``config.has_state``) keeps a recurrent
-        state a row cannot be rewound over and that no page holds. The steps
-        that carry it are ``prefill_step``, ``decode_step`` and
-        ``ragged_mixed_step``; every option that reaches another step, or
-        that rewinds or re-lays a row's K/V, is refused here by name — none
+    def _refuse_what_a_kind_cannot_carry(self, mesh, quant: str) -> None:
+        """Each kind of per-row memory beyond paged K/V is written and read by
+        ``prefill_step``, ``decode_step`` and ``ragged_mixed_step`` alone:
+        every option that reaches another step, rewinds a row or re-lays its
+        pages is refused here by name, from ONE table (``NOT_CARRIED``) — none
         may run and be silently wrong."""
-        cfg = self.engine_cfg
-        refused = {
-            "engine.spec_tokens": cfg.spec_tokens > 0,  # rejected drafts rewind a row
-            "engine.decode_loop_depth": self.decode_loop_depth > 1,
-            "engine.freerun_rounds": self.freerun_rounds > 1,
+        cfg, c = self.engine_cfg, self.config
+        on = {
+            "engine.kv_quant": bool(cfg.kv_quant),
+            "model.quant": bool(quant),
+            "engine.spec_tokens": cfg.spec_tokens > 0,
             "engine.kv_sink_pages / engine.kv_window_pages": self.bounded_kv is not None,
             "mesh.* > 1": mesh is not None and mesh.devices.size > 1,
         }
-        named = [option for option, on in refused.items() if on]
-        if named:
-            raise ValueError(
-                f"a model with recurrent state ({self.config.n_state_layers} layers: a "
-                f"Mamba-2 mixer, Mamba-1 or linear-attention layers) "
-                f"carries it through prefill_step, decode_step and "
-                f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
+        held = {"recurrent state": c.has_state, "latent pages": bool(c.kv_lora_rank)}
+        for kind, options in NOT_CARRIED.items():
+            named = [f"{option} ({why})" for option, why in options.items() if on[option]]
+            if held[kind] and named:
+                raise ValueError(
+                    f"a model with {kind} is served by prefill_step, decode_step and "
+                    f"ragged_mixed_step only; not supported with it: {', '.join(named)}")
 
     def _refuse_with_held_experts(self, mesh, quant: str) -> None:
         """A model whose expert stacks are a held range of a wider router
@@ -1745,29 +1416,6 @@ class InferenceEngine:
             raise ValueError(
                 f"model.quant={quant!r} is not supported for a model with fused-GLU expert "
                 "stacks: the grouped matmul (lax.ragged_dot) takes no quantized operand")
-
-    def _refuse_with_latent_cache(self, mesh, quant: str) -> None:
-        """A model with latent attention (``config.kv_lora_rank``) keeps a
-        latent row and an index key a token, written and read by
-        ``prefill_step``, ``decode_step`` and ``ragged_mixed_step`` alone;
-        every option that reaches another step or another page layout is
-        refused here by name — none may run and be silently wrong."""
-        cfg = self.engine_cfg
-        refused = {
-            "engine.kv_quant": bool(cfg.kv_quant),  # int8 pages: a scale a KV head
-            "model.quant": bool(quant),  # no quantized form of the absorbed projections
-            "engine.spec_tokens": cfg.spec_tokens > 0,  # verify_step attends K/V heads
-            "engine.decode_loop_depth": self.decode_loop_depth > 1,
-            "engine.freerun_rounds": self.freerun_rounds > 1,
-            "engine.kv_sink_pages / engine.kv_window_pages": self.bounded_kv is not None,
-            "mesh.* > 1": mesh is not None and mesh.devices.size > 1,
-        }
-        named = [option for option, on in refused.items() if on]
-        if named:
-            raise ValueError(
-                "a model with latent attention (a latent row and an index key a token in "
-                "the page pool) is served by prefill_step, decode_step and ragged_mixed_step "
-                f"only; not supported with it: {', '.join(named)}")
 
     @property
     def ssm_state_bytes(self) -> int:
@@ -2229,54 +1877,25 @@ class InferenceEngine:
             # the packed ragged variants the scheduler's mixed path
             # dispatches (ragged_mixed_step) — ONE pow-2 packed-token
             # bucket axis, descriptors fixed at [max_seqs]; all-padding
-            # rows (row_len 0, nothing armed, no loop slots) keep it
-            # state-neutral. Replaces PR 4's row-bucket × chunk-bucket
-            # matrix AND its per-mode demotions — the collapsed warmup
-            # matrix is the point (ISSUE 10; the gauge below records it).
+            # rows (row_len 0, nothing armed) keep it state-neutral.
+            # Replaces PR 4's row-bucket × chunk-bucket matrix AND its
+            # per-mode demotions — the collapsed warmup matrix is the point
+            # (ISSUE 10; the gauge below records it).
             R = B
             rz = jnp.zeros((R,), jnp.int32)
             rflags = jnp.zeros((R,), bool)
-            bflags = jnp.zeros((B,), bool)
-            bz = jnp.zeros((B,), jnp.float32)
-            bo = jnp.ones((B,), jnp.float32)
-            bk = jnp.zeros((B,), jnp.int32)
             for t in self.ragged_token_buckets():
-                self.state, _, _, _, _ = ragged_mixed_step(
+                self.state, _, _, _ = ragged_mixed_step(
                     self.params, self.state,
                     jnp.zeros((t,), jnp.int32), jnp.full((t,), R, jnp.int32),
                     rz, rz, rz, rflags, rflags, rz,
                     jnp.zeros((R,), jnp.float32), jnp.ones((R,), jnp.float32),
                     jnp.zeros((R,), jnp.int32),
-                    bflags, bz, bo, bk, jnp.int32(-1),
                     config=self.config, page_size=self.page_size,
                     attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-                    spec_width=cfg.spec_tokens,
-                    loop_depth=self.decode_loop_depth, **self._ragged_kw(),
+                    spec_width=cfg.spec_tokens, **self._ragged_kw(),
                 )
                 n_variants += 1
-            if self.freerun_rounds > 1:
-                # the captured multi-round program (ragged_multi_round) —
-                # one extra variant per packed-token bucket at the fixed
-                # freerun_rounds depth, all-padding rounds keeping it
-                # state-neutral exactly like the single-round warmup
-                F = self.freerun_rounds
-                for t in self.ragged_token_buckets():
-                    self.state, _, _, _ = ragged_multi_round(
-                        self.params, self.state,
-                        jnp.zeros((F, t), jnp.int32),
-                        jnp.full((F, t), R, jnp.int32),
-                        rz, jnp.zeros((F, R), jnp.int32),
-                        jnp.zeros((F, R), jnp.int32),
-                        jnp.zeros((F, R), bool), jnp.zeros((F, R), bool),
-                        jnp.zeros((R,), jnp.float32),
-                        jnp.ones((R,), jnp.float32),
-                        jnp.zeros((R,), jnp.int32),
-                        jnp.zeros((F, B), bool), bz, bo, bk, jnp.int32(-1),
-                        config=self.config, page_size=self.page_size,
-                        attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-                        loop_depth=self.decode_loop_depth,
-                    )
-                    n_variants += 1
         inactive = jnp.zeros((B,), bool)
         temp = jnp.full((B,), 1.0, jnp.float32)
         top_p = jnp.ones((B,), jnp.float32)
@@ -2286,19 +1905,6 @@ class InferenceEngine:
                 self.params, self.state, inactive, temp, top_p, top_k,
                 config=self.config, page_size=self.page_size,
                 attn_backend=self.attn_backend, qm_backend=self.qm_backend, return_logits=return_logits,
-            )
-            n_variants += 1
-        if self.decode_loop_depth > 1:
-            # the fused multi-step block the scheduler's decode_loop mode
-            # dispatches — all slots inactive, so writes trash-redirect and
-            # context_lens gains zero (eos_id is a runtime scalar, not part
-            # of the jit cache key)
-            self.state, _ = decode_loop_step(
-                self.params, self.state, inactive, temp, top_p, top_k,
-                jnp.int32(-1),
-                config=self.config, page_size=self.page_size,
-                attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-                loop_depth=self.decode_loop_depth,
             )
             n_variants += 1
         if cfg.spec_tokens > 0:
@@ -2435,7 +2041,7 @@ class InferenceEngine:
         matrix: the dispatch shape varies only in the packed buffer length
         (descriptors are fixed at ``[max_seqs]``), so the compiled-variant
         count is log2 in max_seqs × chunk instead of their product — and
-        spec/loop/constrained rows reuse the SAME variants instead of
+        spec/constrained rows reuse the SAME variants instead of
         demoting to per-mode dispatch schedules. Floored at 64 tokens:
         small rounds pad into the smallest warmed bucket (padding rows are
         fully masked), trading a little dead compute at light load for
@@ -2453,16 +2059,12 @@ class InferenceEngine:
         """Smallest warmed packed-token bucket holding ``n_tokens``."""
         return next(b for b in self.ragged_token_buckets() if b >= n_tokens)
 
-    def ragged_mixed(self, tokens, tok_row, row_slot, row_start, row_len,  # finchat-lint: hot
-                     row_from_device, row_arm, row_n_drafts,
-                     temperature, top_p, top_k,
-                     loop_active, loop_temperature, loop_top_p, loop_top_k,
-                     eos_id: int):
+    def ragged_round(self, tokens, tok_row, row_slot, row_start, row_len,  # finchat-lint: hot
+                     row_from_device, row_arm, row_n_drafts, temperature, top_p, top_k):
         """One packed ragged dispatch (see ragged_mixed_step); returns
-        ``(emitted, n_emitted, row_logits, loop_block)`` device arrays —
-        the scheduler fetches once per round. Counted at the dispatch seam
-        like decode()/decode_loop(): one enqueued device program, one
-        count."""
+        ``(emitted, n_emitted, row_logits)`` device arrays — the scheduler
+        fetches once per round. Counted at the dispatch seam like decode():
+        one enqueued device program, one count."""
         from finchat_tpu.utils.metrics import METRICS
 
         METRICS.inc("finchat_mixed_dispatches_total")
@@ -2471,20 +2073,24 @@ class InferenceEngine:
                 (int(s), None if dev else int(p), int(n)) for s, p, n, dev in zip(
                     np.asarray(row_slot), np.asarray(row_start), np.asarray(row_len),
                     np.asarray(row_from_device)) if n > 0])
-        self.state, emitted, n_emitted, row_logits, loop_block = (
-            ragged_mixed_step(
-                self.params, self.state, tokens, tok_row, row_slot,
-                row_start, row_len, row_from_device, row_arm, row_n_drafts,
-                temperature, top_p, top_k,
-                loop_active, loop_temperature, loop_top_p, loop_top_k,
-                jnp.int32(eos_id),
-                config=self.config, page_size=self.page_size,
-                attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-                spec_width=self.engine_cfg.spec_tokens,
-                loop_depth=self.decode_loop_depth, **self._ragged_kw(),
-            )
+        self.state, emitted, n_emitted, row_logits = ragged_mixed_step(
+            self.params, self.state, tokens, tok_row, row_slot,
+            row_start, row_len, row_from_device, row_arm, row_n_drafts,
+            temperature, top_p, top_k,
+            config=self.config, page_size=self.page_size,
+            attn_backend=self.attn_backend, qm_backend=self.qm_backend,
+            spec_width=self.engine_cfg.spec_tokens, **self._ragged_kw(),
         )
-        return emitted, n_emitted, row_logits, loop_block
+        return emitted, n_emitted, row_logits
+
+    def ragged_mixed(self, tokens, tok_row, row_slot, row_start, row_len,
+                     row_from_device, row_arm, row_n_drafts, temperature, top_p, top_k,
+                     loop_active, loop_temperature, loop_top_p, loop_top_k, eos_id: int):
+        """``ragged_round`` as the benchmark calls it (perfbench/correct.py,
+        sparse_control.py): five arguments no step reads, a fourth value that
+        is None; it goes when they call the short form (ROADMAP D9 (l))."""
+        return *self.ragged_round(tokens, tok_row, row_slot, row_start, row_len, row_from_device,
+                                  row_arm, row_n_drafts, temperature, top_p, top_k), None
 
     def _ragged_kw(self) -> dict:
         """What only a model with a mixer or with latent attention (both
@@ -2495,60 +2101,13 @@ class InferenceEngine:
             return {}
         return {"max_row_tokens": self.engine_cfg.prefill_chunk}
 
-    def ragged_multi(self, tokens, tok_row, row_slot, row_start, row_len,  # finchat-lint: hot
-                     row_from_device, row_arm, temperature, top_p, top_k,
-                     loop_active, loop_temperature, loop_top_p, loop_top_k,
-                     eos_id: int):
-        """One captured multi-round dispatch (see ragged_multi_round):
-        ``tokens.shape[0]`` consecutive ragged rounds in ONE enqueued
-        device program, returning the per-round token ring
-        ``(ring_tokens, ring_n, ring_blocks)`` as device arrays — the
-        scheduler drains them off-loop while the device free-runs the
-        next capture. Counted ONCE at the dispatch seam (one program),
-        which is why dispatches per round drop below 1
-        (tests/test_freerun.py)."""
-        from finchat_tpu.utils.metrics import METRICS
-
-        METRICS.inc("finchat_mixed_dispatches_total")
-        self.state, ring_tokens, ring_n, ring_blocks = ragged_multi_round(
-            self.params, self.state, tokens, tok_row, row_slot, row_start,
-            row_len, row_from_device, row_arm, temperature, top_p, top_k,
-            loop_active, loop_temperature, loop_top_p, loop_top_k,
-            jnp.int32(eos_id),
-            config=self.config, page_size=self.page_size,
-            attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-            loop_depth=self.decode_loop_depth,
-        )
-        return ring_tokens, ring_n, ring_blocks
-
-    def decode_loop(self, active, temperature, top_p, top_k, eos_id: int):
-        """Fused multi-step decode (see decode_loop_step):
-        ``decode_loop_depth`` iterations in one dispatch, on-device
-        sampling + EOS mask. Returns the ``[K, max_seqs]`` token block
-        (device array — callers fetch once)."""
-        from finchat_tpu.utils.metrics import METRICS
-
-        # counted at the DISPATCH seam (one jitted program enqueued), the
-        # same counter decode() bumps once per step, so a host-side
-        # fallback that looped K single steps here would be visible, not
-        # assumed away
-        METRICS.inc("finchat_decode_dispatches_total")
-        self.state, token_block = decode_loop_step(
-            self.params, self.state, active, temperature, top_p, top_k,
-            jnp.int32(eos_id),
-            config=self.config, page_size=self.page_size,
-            attn_backend=self.attn_backend, qm_backend=self.qm_backend,
-            loop_depth=self.decode_loop_depth,
-        )
-        return token_block
-
     def decode_spec(self, active, drafts, n_drafts, temperature, top_p, top_k,
                     return_logits: bool = False):
         """Speculative verify step (see verify_step). ``drafts`` [B, Kd]
         keys the compiled shape — callers pad to a fixed Kd."""
         from finchat_tpu.utils.metrics import METRICS
 
-        # counted at the DISPATCH seam like decode()/decode_loop()/mixed:
+        # counted at the DISPATCH seam like decode() and the ragged round:
         # a verify step is one enqueued device program, and dispatches per
         # coexist iteration on the split path must see the spec plane too
         METRICS.inc("finchat_decode_dispatches_total")

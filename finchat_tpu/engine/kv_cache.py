@@ -447,9 +447,7 @@ class BoundedKVPolicy:
     unbounded oracle while the context still fits).
 
     All methods are pure host-side integer math (no device work, no syncs)
-    — the scheduler's eviction wave calls them between dispatches, and the
-    free-run staging uses them to cap captures at eviction boundaries so a
-    captured round's gap schedule is identical to the host-stepped one.
+    — the scheduler's eviction wave calls them between dispatches.
     """
 
     sink_pages: int
@@ -470,10 +468,10 @@ class BoundedKVPolicy:
         return self.sink_pages * self.page_size
 
     def validate(self, *, prefill_chunk: int, max_pages_per_seq: int,
-                 decode_loop_depth: int = 1, spec_tokens: int = 0) -> None:
+                 spec_tokens: int = 0) -> None:
         """Feasibility at engine construction: the window must always be
         able to make room for the next dispatch's writes by evicting full
-        post-sink pages — a chunk (prefill) or a fused/spec burst (decode)
+        post-sink pages — a chunk (prefill) or a spec burst (decode)
         plus one partial page of already-written tail must fit."""
         if not self.enabled:
             return
@@ -483,8 +481,7 @@ class BoundedKVPolicy:
                 f"(got sink={self.sink_pages}, window={self.window_pages}); "
                 "set both to 0 for unbounded serving"
             )
-        burst = max(prefill_chunk,
-                    1 + max(decode_loop_depth - 1, spec_tokens))
+        burst = max(prefill_chunk, 1 + spec_tokens)
         need = -(-burst // self.page_size) + 2  # burst + partial tail + slack
         if self.window_pages < need:
             raise ValueError(
@@ -515,7 +512,7 @@ class BoundedKVPolicy:
         a shared-prefix head larger than the sink is pinned whole, an
         effectively larger sink for that row). Returns 0 when everything
         already fits. Deterministic in the written-token count alone — the
-        freerun capture-vs-host-stepped identity leans on this."""
+        preempt-replay identity leans on this."""
         need = -(-(compacted_ctx + incoming) // self.page_size)
         e = max(0, need - capacity_pages)
         if e == 0:

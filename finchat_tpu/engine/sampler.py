@@ -5,14 +5,6 @@ heterogeneous batch (different temperatures/top-p per conversation). Greedy
 is temperature == 0. Default temperature 0.5 for parity with the reference's
 both LLM roles (llm_agent.py:37,44).
 
-Because ``sample`` is already device-resident, the fused multi-step decode
-loop (engine/engine.py ``decode_loop_step``) calls it once per
-``fori_loop`` iteration with a fresh ``jax.random.split`` of the carried
-state rng — K tokens sample on-device per dispatch with the SAME
-per-iteration math and rng discipline as K single ``decode_step`` calls,
-which is what makes the greedy block bit-reproducible against single-step
-decode (tests/test_decode_loop.py).
-
 TPU note: a full-vocab ``argsort`` cost ~26 ms/step for [64, 32000] on
 v5e in the builders' July 2026 profile (not measured since) — nearly half
 that decode step.

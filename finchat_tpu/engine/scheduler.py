@@ -23,46 +23,18 @@ model replica:
   written back before its next step, so it sits OUT the speculative step
   (inactive, trash-redirected) and rejoins the following one — advancing
   every other step while unconstrained streams keep full depth-2 cadence.
-- Fused multi-step decode (``decode_loop_depth`` K > 1): slots needing no
-  per-token host control ride ``decode_loop_step`` blocks — K decode
-  iterations, on-device sampling, and the EOS stop mask inside ONE device
-  dispatch, with the host fetching a ``[K, max_seqs]`` token block per
-  round-trip instead of ``[max_seqs]`` per token. Composes with the
-  depth-2 pipeline (block N+1 dispatched before block N is consumed).
-  Grammar-constrained slots, spec-decode iterations, and slots within K
-  tokens of their ``max_new_tokens``/page budget are demoted to
-  single-step (mirroring the SPEC_MISS_DEMOTE machinery) and rejoin
-  blocks when eligibility returns; slots that finish mid-block free-run
-  into the trash page and their tail iterations are counted as waste.
 - Unified packed ragged step (``engine.mixed_step``, default on; ISSUE
   10): when prefill work and in-flight decodes coexist, the iteration
   runs ONE ``ragged_mixed_step`` dispatch over a PACKED token buffer
   (ops/ragged_paged_attention.py) — every prefilling row advances a
   chunk, every decoding row a token, grammar-constrained rows return
-  their logits for the host pick, spec-eligible rows verify a
-  (1+Kd)-token draft block, and loop-eligible rows free-run a fused
-  ``loop_depth-1`` tail, all with on-device sampling — instead of two or
-  more serialized dispatches. Only ring/seq-sharded prefill rows demote
-  the iteration to the split path below, which remains the
-  golden-identical fallback (greedy streams are byte-identical either
-  way; tests/test_mixed_step.py pins it); demotions are counted per
-  reason in ``finchat_mixed_demotions_total``.
-- Free-running device loop (``engine.freerun_rounds`` > 1; ISSUE 13):
-  when the mixed path is live and no row needs a per-round host decision
-  (no grammar-constrained rows, no live spec-proposal window — the
-  ``_use_mixed``-style cap), up to ``freerun_rounds`` consecutive ragged
-  rounds are CAPTURED into one device program
-  (engine.ragged_multi_round): prefill descriptors for every round are
-  pre-staged into a device-memory queue the rounds drain (completed
-  prompts flip to on-device-sampled decode rows mid-run), EOS stops via
-  the on-device ``row_live`` mask (budget stops are staged away), and
-  per-round tokens land in an output ring the host drains OFF-LOOP while
-  the device free-runs the next capture (depth-2). Host control returns
-  only at membership epochs: any admit/evict/preempt/breaker event ends
-  re-entry at a round boundary, residual ring tokens replay exactly once
-  under the PR 5 epoch discipline, and the host-stepped round (and split
-  path below it) remain the golden-identical fallbacks. Dispatches per
-  ROUND drop to 1/freerun_rounds on the coexist counters.
+  their logits for the host pick and spec-eligible rows verify a
+  (1+Kd)-token draft block, all with on-device sampling — instead of two
+  or more serialized dispatches. The split path below serves an iteration
+  with no decode beside its prompts, and stays the golden-identical
+  fallback (greedy streams are byte-identical either way;
+  tests/test_mixed_step.py pins it); demotions are counted per reason in
+  ``finchat_mixed_demotions_total``, every reason at zero.
 - Session KV cache (engine/session_cache.py): sequences submitted with a
   ``conversation_id`` snapshot their KV pages device→host when they retire
   normally (eos/length, before the pages are freed) and the conversation's
@@ -220,8 +192,7 @@ class SequenceHandle:
     # eviction schedule's sole input: the wave runs between dispatches, so
     # kv_ctx at a wave is exactly the next dispatch's write position, and
     # the gap a token's dispatch sees becomes a PURE function of that
-    # position — independent of pipeline depth, free-run capture depth,
-    # or a preempt/replay boundary (the byte-identity contracts lean on
+    # position — independent of pipeline depth or a preempt/replay boundary (the byte-identity contracts lean on
     # this; delivered-count-plus-inflight inference is phase-dependent).
     kv_ctx: int = 0
     # preempt-replay restore plane for bounded rows (ISSUE 15 satellite):
@@ -284,44 +255,6 @@ class _InFlightStep:
     # those the step's form read, each summed over the layers (a model that
     # routes sparsely; else None)
     moe_experts: object | None = None
-
-
-@dataclass
-class _InFlightBlock:
-    """A dispatched-but-unconsumed fused decode block (decode_loop mode):
-    one ``[K, max_seqs]`` device token block for the loop-eligible slots,
-    plus the single ``decode_step`` covering the DEMOTED slots (grammar-
-    constrained / within K of budget) dispatched in the same scheduler
-    iteration, if any."""
-
-    block_tokens: object  # [K, max_seqs] int32, device (-1 = no token)
-    block_members: list[tuple[int, SequenceHandle, int]]
-    step: _InFlightStep | None
-
-
-@dataclass
-class _InFlightRing:
-    """A dispatched-but-unconsumed captured multi-round run (the
-    free-running loop, ISSUE 13): the per-round token ring device arrays
-    from ``engine.ragged_multi`` plus the staged plan's host bookkeeping.
-    Members carry the admission epoch exactly like ``_InFlightStep`` —
-    the PR 5 discipline is what makes an epoch boundary (admit / evict /
-    preempt / breaker while the capture is mid-flight) safe: stale rows'
-    ring tokens are discarded at drain time and the preempt-replay
-    recomputes them, so delivery stays exactly-once."""
-
-    tokens: object  # [F, R] int32, device — each armed row's round token
-    n_emitted: object  # [F, R] int32, device (0 = mid-prompt chunk / dead)
-    blocks: object  # [F, K-1, max_seqs] int32, device — fused tails
-    rounds: int
-    # (row, slot, owner, epoch, kind) — owner is a SequenceHandle for
-    # "prefill"/"decode" rows, a _PrefixJob for "job" rows (no tokens)
-    members: list
-    armed: object  # np [F, R] staged arm mask — exactly-once replay ref
-    loop_rounds: object  # np [F, max_seqs] staged fused-tail schedule
-    completes_at: dict  # row -> round its prompt completes (first token)
-    ahead: dict  # slot -> staged max emissions (budget accounting for
-    #   the NEXT dispatch staged before this ring is consumed)
 
 
 @dataclass
@@ -408,23 +341,14 @@ class ContinuousBatchingScheduler:
         # retrieved rows, so a one-way demotion would miss the recovery).
         self._spec_miss_streak = 0
         self._spec_cooldown = 0
-        # fused multi-step decode (engine decode_loop_step): K > 1 switches
-        # the pipelined path to K-token blocks per dispatch for slots that
-        # need no per-token host control; constrained / near-budget slots
-        # are demoted to a single decode_step riding the same iteration,
-        # and spec-decode iterations keep their own depth-1 verify cadence
-        self.loop_depth = engine.decode_loop_depth
-        self.metrics.set_gauge("finchat_decode_loop_depth", self.loop_depth)
         # unified packed ragged step (engine.mixed_step config): one
         # dispatch advances every prefilling row a chunk, every decoding
-        # row a token, spec rows a verify block, and loop-eligible rows a
-        # fused tail whenever both populations exist — see _use_mixed /
-        # _ragged_round (ISSUE 10). Only ring-routed prefill demotes.
+        # row a token and spec rows a verify block whenever both
+        # populations exist — see _use_mixed / _ragged_round (ISSUE 10).
         self.mixed_enabled = bool(cfg.mixed_step)
         # demotion observability (ISSUE 10 satellite): every reason the
         # old padded mixed step demoted on is pre-seeded at zero, so the
-        # erasure (spec/decode_loop/constrained stuck at 0, only ring — a
-        # collective schedule — still firing) is visible per replica
+        # erasure (every reason stuck at 0) is visible per replica
         for reason in self.MIXED_DEMOTION_REASONS:
             self.metrics.inc("finchat_mixed_demotions_total", 0.0,
                              labels={"reason": reason})
@@ -440,16 +364,6 @@ class ContinuousBatchingScheduler:
         # holds it at 1) is exact, not a racy window over global counters
         self._dispatch_tally = 0
         self._coexist_mark: int | None = None
-        # free-running loop (ISSUE 13): consecutive ragged rounds captured
-        # per dispatch (engine.freerun_rounds; 1 = host-stepped rounds).
-        # _round_tally counts logical serving ROUNDS the same way
-        # _dispatch_tally counts enqueued programs — a captured run books
-        # F rounds for its one dispatch — and the same mark/attribute pair
-        # lands both in the coexist counters, so the headline ratio
-        # becomes dispatches per ROUND (< 1 once captures engage) measured
-        # by the exact PR 10 attribution, not a new ad-hoc window.
-        self.freerun_rounds = max(1, getattr(engine, "freerun_rounds", 1))
-        self._round_tally = 0
         # the current loop iteration on the clock (ISSUE 24): seconds per
         # phase, the base phase it runs in, the dispatch tally when it
         # began, the kind of its last dispatch; the last 256 rounds'
@@ -463,13 +377,6 @@ class ContinuousBatchingScheduler:
         # the tracer's running totals as the last round saw them
         self._seen_compile_s = TRACER.serving_compile_s
         self._seen_frozen_s = TRACER.frozen_s
-        self._coexist_round_mark = 0
-        if self.freerun_rounds > 1:
-            # pre-seed the cap reasons (the _use_mixed demotion-counter
-            # discipline): a capture that never caps is visible as zeros
-            for reason in self.FREERUN_CAP_REASONS:
-                self.metrics.inc("finchat_freerun_capped_total", 0.0,
-                                 labels={"reason": reason})
         # trace-event track label (utils/tracing.py — ISSUE 12): one
         # Perfetto track per engine so a fleet's dispatch timelines stay
         # separable in one export
@@ -1838,13 +1745,11 @@ class ContinuousBatchingScheduler:
 
         The wave is host-deterministic: its sole inputs are each row's
         ``kv_ctx`` (the dispatch-time context mirror — exactly the next
-        dispatch's write position, whatever the pipeline depth or capture
-        state) and fixed per-config reserve constants, so the gap a token
-        is computed under is a pure function of its position. That is
-        what makes the free-run capture's gap schedule identical to the
-        host-stepped one (captures are capped at the next eviction
-        boundary — ``_bounded_freerun_cap`` — exactly like budget stops)
-        and a preempt-replay's identical to the uninterrupted run's."""
+        dispatch's write position, whatever the pipeline depth) and fixed
+        per-config reserve constants, so the gap a token is computed under
+        is a pure function of its position. That is
+        what makes a preempt-replay's gap schedule identical to the
+        uninterrupted run's."""
         bp = self.bounded_kv
         if bp is None:
             return
@@ -1857,12 +1762,11 @@ class ContinuousBatchingScheduler:
             if handle.slot < 0 or handle.finished or self._parked(handle):
                 continue
             # the reserve is exactly what the next dispatch WRITES for
-            # this row: a prefill chunk, or ONE decode token — fused
-            # multi-token spans (decode_loop tails, spec verify blocks)
-            # are gated to never cross the eviction boundary
+            # this row: a prefill chunk, or ONE decode token — spec
+            # verify spans are gated to never cross the eviction boundary
             # (_bounded_span_room), so the only dispatch that ever
             # reaches the boundary writes a single token. Reserving the
-            # full fused burst here would evict one dispatch EARLY
+            # full verify span here would evict one dispatch EARLY
             # whenever the gate demotes at the boundary — and a replay,
             # whose residual chunk regroups those positions, would then
             # see a different gap schedule than the uninterrupted run
@@ -1919,11 +1823,10 @@ class ContinuousBatchingScheduler:
 
     def _bounded_span_room(self, handle: SequenceHandle) -> int:
         """Tokens this row may still write before its next eviction
-        boundary (``page-list capacity + kv_gap``). Fused multi-token
-        dispatches — decode_loop blocks/tails, spec verify spans — must
-        FIT this room: a span crossing the boundary would give its tail
-        tokens the pre-eviction gap, and since a preempt-replay (or a
-        capture) regroups spans on a shifted grid, the gap a given token
+        boundary (``page-list capacity + kv_gap``). A spec verify span
+        must FIT this room: a span crossing the boundary would give its tail
+        tokens the pre-eviction gap, and since a preempt-replay
+        regroups spans on a shifted grid, the gap a given token
         sees would stop being a pure function of its position — breaking
         the byte-identity contracts. Unbounded rows have unlimited room
         by construction (capacity covers prompt + max_new)."""
@@ -1932,30 +1835,6 @@ class ContinuousBatchingScheduler:
         boundary = (len(handle.page_list) * self.engine.page_size
                     + handle.kv_gap)
         return max(0, boundary - handle.kv_ctx)
-
-    def _bounded_freerun_cap(self) -> int:
-        """Rounds the next capture may free-run before some bounded row
-        needs an eviction wave — the capture-boundary staging of eviction
-        (like budget stops): within the cap the staged writes fit every
-        row's current page list, so the captured rounds see exactly the
-        gap schedule the host-stepped loop would."""
-        bp = self.bounded_kv
-        cap = self.freerun_rounds
-        if bp is None:
-            return cap
-        chunk = self.engine.engine_cfg.prefill_chunk
-        decode_burst = 1 + max(self.loop_depth - 1, self.spec_k)
-        for handle in list(self.prefilling) + list(self.decoding.values()):
-            if handle.slot < 0 or handle.finished or self._parked(handle):
-                continue
-            room = self._bounded_span_room(handle)
-            # a prefill row may flip to decode mid-capture; the larger of
-            # a chunk and a decode burst bounds both roles' per-round
-            # writes, so it is the conservative deterministic divisor
-            prefilling = handle.prefill_pos < len(handle.prompt_ids)
-            per_round = max(chunk, decode_burst) if prefilling else decode_burst
-            cap = min(cap, max(1, room // max(1, per_round)))
-        return cap
 
     # --- resilience plane (ISSUE 5; ROBUSTNESS.md) ----------------------
     def _preempt(self, handle: SequenceHandle, *, for_rebuild: bool = False) -> None:
@@ -2726,9 +2605,6 @@ class ContinuousBatchingScheduler:
         same round."""
         eng = self.engine
         C = eng.engine_cfg.prefill_chunk
-        # one logical serving round (the dispatches-per-ROUND denominator;
-        # the decode dispatch riding the same iteration is the same round)
-        self._round_tally += 1
         batch: list[SequenceHandle] = []
         # (handle, device logits row, epoch) triples whose prompt completed
         # this round — the epoch tells a preempted-and-replayed incarnation
@@ -2925,286 +2801,15 @@ class ContinuousBatchingScheduler:
 
     # every label the demotion counter can emit — pre-seeded to 0 at
     # construction so the whole family renders even when (by design, the
-    # ISSUE 10 point) spec / decode_loop / constrained never fire again
-    MIXED_DEMOTION_REASONS = ("spec", "decode_loop", "constrained", "ring", "other")
-
-    # every reason a free-run capture caps to one host-stepped round —
-    # pre-seeded at 0 when the free-running loop is enabled (the same
-    # discipline as MIXED_DEMOTION_REASONS)
-    FREERUN_CAP_REASONS = ("constrained", "spec", "underfill", "boundedkv")
-
-    def _freerun_rounds_cap(self) -> int:
-        """How many consecutive rounds the next capture may free-run — the
-        ``_use_mixed``-style predicate of ISSUE 13. Rows that need a HOST
-        decision every round cap the capture to 1 (exactly today's
-        host-stepped behavior): grammar-constrained rows (the host pick
-        feeds the next round's input) and live spec-proposal windows
-        (drafts are proposed from DELIVERED tokens the device is still
-        holding). Bounded-KV rows cap the capture at their next eviction
-        boundary (ISSUE 15 — eviction is staged at capture boundaries
-        like budget stops, so a capture's gap schedule matches the
-        host-stepped loop's exactly)."""
-        F = self.freerun_rounds
-        if F <= 1:
-            return 1
-        if (any(h.constraint is not None for h in self.decoding.values())
-                or any(h.constraint is not None for h in self.prefilling
-                       if not self._parked(h))):
-            # parked holds are skipped by the staging anyway (and today's
-            # overlap API never parks a constrained prompt) — only rows
-            # that would actually ride the capture may cap it
-            self.metrics.inc("finchat_freerun_capped_total",
-                             labels={"reason": "constrained"})
-            return 1
-        if (self.spec_k > 0 and self._spec_cooldown == 0
-                and self._spec_proposal_live()):
-            # a proposal must ACTUALLY fire to cap the capture: eligible
-            # slots whose n-gram lookups all miss would run a plain decode
-            # round anyway (see _spec_proposal_live), so they free-run
-            self.metrics.inc("finchat_freerun_capped_total",
-                             labels={"reason": "spec"})
-            return 1
-        if self.bounded_kv is not None:
-            cap = self._bounded_freerun_cap()
-            if cap < F:
-                self.metrics.inc("finchat_freerun_capped_total",
-                                 labels={"reason": "boundedkv"})
-                return max(1, cap)
-        return F
-
-    def _dispatch_freerun(self, rounds: int,  # finchat-lint: hot
-                          ahead: dict[int, int]) -> "_InFlightRing | None":
-        """Stage and enqueue ONE captured multi-round program (ISSUE 13;
-        engine.ragged_multi over ops/freerun.stage_freerun): every
-        prefilling row's next ``rounds`` chunks, every decode slot's next
-        ``rounds`` tokens (with fused tails where eligible), and the
-        completion→decode flips in between are pre-staged into the
-        descriptor queue; the device then free-runs ``rounds`` ragged
-        rounds with no host round-trip, emitting into the token ring this
-        returns. Returns None — the caller runs the host-stepped single
-        round instead — when the staged plan cannot fill every round
-        (work runs out mid-capture; empty device rounds would be pure
-        waste). ``ahead`` is ``_undelivered()`` for the still-unconsumed
-        in-flight dispatch: budgets are staged NET of it, so a capture
-        staged before the previous ring drains can never run a stream
-        past ``max_new_tokens`` or its page allocation."""
-        from finchat_tpu.ops.freerun import RowSpec, stage_freerun
-
-        eng = self.engine
-        C = eng.engine_cfg.prefill_chunk
-        B = eng.engine_cfg.max_seqs
-        specs: list[RowSpec] = []
-        members: list[tuple] = []
-
-        def _budget(h: SequenceHandle) -> int:
-            return max(
-                0, h.sampling.max_new_tokens - h.generated - ahead.get(h.slot, 0)
-            )
-
-        for handle in list(self.prefilling):
-            if self._parked(handle):
-                continue  # awaiting extend_prompt
-            try:
-                inject("scheduler.prefill", seq_id=handle.seq_id,
-                       replica=self.replica_id)
-            except Exception as e:  # per-sequence isolation, as in _ragged_round
-                logger.error("prefill error for %s: %s", handle.seq_id, e)
-                self._evict(handle, "error", error=str(e))
-                continue
-            s = handle.sampling
-            if handle.prefill_pos >= len(handle.prompt_ids):
-                # completed inside a still-unconsumed ring: its first
-                # token is in flight (counted in ``ahead``) and this
-                # capture stages it as a plain decode row
-                specs.append(RowSpec(
-                    slot=handle.slot, kind="decode", budget=_budget(handle),
-                    loop_ok=self.loop_depth > 1,
-                    temperature=s.temperature, top_p=s.top_p, top_k=s.top_k,
-                ))
-                members.append((len(specs) - 1, handle.slot, handle,
-                                handle.epoch, "decode"))
-                continue
-            specs.append(RowSpec(
-                slot=handle.slot, kind="prefill", ids=handle.prompt_ids,
-                pos=handle.prefill_pos, arm=not handle.held,
-                budget=_budget(handle), loop_ok=self.loop_depth > 1,
-                temperature=s.temperature, top_p=s.top_p, top_k=s.top_k,
-            ))
-            members.append((len(specs) - 1, handle.slot, handle,
-                            handle.epoch, "prefill"))
-        jobs = list(self._prefix_jobs)
-        for job in jobs:
-            specs.append(RowSpec(slot=job.slot, kind="job",
-                                 ids=job.ids[: job.shared_len], pos=job.pos,
-                                 arm=False))
-            members.append((len(specs) - 1, job.slot, job, 0, "job"))
-        for slot, handle in self.decoding.items():
-            s = handle.sampling
-            specs.append(RowSpec(
-                slot=slot, kind="decode", budget=_budget(handle),
-                loop_ok=self.loop_depth > 1,
-                temperature=s.temperature, top_p=s.top_p, top_k=s.top_k,
-            ))
-            members.append((len(specs) - 1, slot, handle, handle.epoch,
-                            "decode"))
-        if not specs:
-            return None  # a fault drained everything; split paths resume
-
-        plan = stage_freerun(specs, rounds=rounds, chunk=C,
-                             loop_depth=self.loop_depth, max_seqs=B,
-                             bucket=eng.ragged_bucket)
-        if plan.active_rounds < rounds:
-            # the work runs out before the capture would: fall back to the
-            # host-stepped round rather than free-running empty rounds
-            self.metrics.inc("finchat_freerun_capped_total",
-                             labels={"reason": "underfill"})
-            return None
-        inject("scheduler.decode", replica=self.replica_id)
-        inject("scheduler.mixed", replica=self.replica_id)
-        with (TRACER.phase("dispatch", self._phases),
-              Timer(self.metrics, "finchat_mixed_step_seconds") as _mt):
-            ring_tok, ring_n, ring_blk = eng.ragged_multi(
-                jnp.asarray(plan.tokens), jnp.asarray(plan.tok_row),
-                jnp.asarray(plan.row_slot), jnp.asarray(plan.row_start),
-                jnp.asarray(plan.row_len), jnp.asarray(plan.row_from_device),
-                jnp.asarray(plan.row_arm),
-                jnp.asarray(plan.temperature), jnp.asarray(plan.top_p),
-                jnp.asarray(plan.top_k), jnp.asarray(plan.loop_active),
-                jnp.asarray(self._temperature), jnp.asarray(self._top_p),
-                jnp.asarray(self._top_k), self.eos_id,
-            )
-        self._tally_dispatch("freerun")
-        self._round_tally += rounds
-        self.metrics.inc("finchat_freerun_dispatches_total")
-        # unit is ROUNDS, not seconds: the N-rounds-per-1-dispatch
-        # attribution instrument ISSUE 13 names
-        self.metrics.observe("finchat_freerun_rounds_per_dispatch", rounds)  # finchat-lint: disable=metrics-discipline -- rounds-per-dispatch histogram: the unit is rounds (ISSUE 13 names this metric); _seconds would be a lie
-        # prompt-cursor bookkeeping at dispatch, exactly _ragged_round's
-        # discipline: the staged chunks ARE dispatched
-        traced = TRACER.enabled
-        riders = []  # a row's captured rounds count against its final context
-        for row, slot, owner, _epoch, kind in members:
-            adv = plan.advanced.get(row, 0)
-            if kind == "job":
-                if traced:
-                    riders.append((slot, f"prefix:{owner.owner}", "freerun",
-                                   None, owner.pos + adv))
-                if adv:
-                    owner.pos += adv
-                    if owner.pos >= owner.shared_len:
-                        self._complete_prefix_job(owner, "freerun")
-                continue
-            if adv:
-                owner.prefill_pos += adv
-                owner.kv_ctx = owner.prefill_pos
-            # staged decode rounds advance device context by 1 per armed
-            # round (+ the fused tails) — plan.ahead counts exactly those
-            # emissions, except a completion flip's first token (sampled,
-            # its KV not yet written)
-            extra = plan.ahead.get(slot, 0)
-            if row in plan.completes_at:
-                extra -= 1
-            owner.kv_ctx += max(0, extra)
-            if traced:
-                riders.append(self._rider(owner, "freerun"))
-        if traced:
-            self._trace_dispatch("freerun", riders,
-                                 ts=_mt.started, dur=_mt.elapsed)
-        return _InFlightRing(
-            tokens=ring_tok, n_emitted=ring_n, blocks=ring_blk,
-            rounds=rounds, members=members, armed=plan.row_arm,
-            loop_rounds=plan.loop_active, completes_at=plan.completes_at,
-            ahead=plan.ahead,
-        )
-
-    async def _consume_ring(self, ring: _InFlightRing) -> None:  # finchat-lint: hot
-        """Drain a captured run's token ring: ONE device→host fetch (in a
-        worker thread — never ``block_until_ready`` on the consume path,
-        the finchat-lint R2 seam) for up to ``rounds`` tokens per row plus
-        the fused tails, delivered round-by-round in device order. Runs
-        while the device is already mid-flight on the NEXT capture
-        (depth-2). Stale rows — evicted / preempted / replayed since
-        dispatch, detected by the (slot, handle, epoch) snapshot — have
-        their residual ring tokens discarded exactly once and recomputed
-        by the replay (the PR 5 discipline); such a drain is the epoch
-        boundary and is recorded as a ``freerun_epoch_break`` trace
-        event. A round emitting where the staged plan never armed is a
-        free-run divergence: flight-recorder dump, tokens not
-        delivered."""
-        tok_host, n_host, blk_host = await self._fetch(
-            lambda: (np.asarray(ring.tokens), np.asarray(ring.n_emitted),
-                     np.asarray(ring.blocks)),
-        )
-        with TRACER.phase("deliver", self._phases):
-            armed = ring.armed
-            if bool(((n_host > 0) & ~armed).any()):
-                # ring replay mismatch: the device emitted outside the staged
-                # schedule — dump the black box and deliver nothing from the
-                # unarmed cells (they were never part of any stream)
-                self.metrics.inc("finchat_freerun_divergences_total")
-                TRACER.anomaly("freerun_divergence", args={
-                    "replica": self.replica_id, "rounds": ring.rounds,
-                    "cells": int(((n_host > 0) & ~armed).sum()),
-                })
-            K1 = int(blk_host.shape[1])
-            wasted = 0
-            epoch_break = False
-            for r in range(ring.rounds):
-                for row, slot, owner, epoch, kind in ring.members:
-                    if kind == "job":
-                        continue
-                    handle: SequenceHandle = owner
-                    stale = (handle.finished or handle.slot != slot
-                             or handle.epoch != epoch)
-                    n = int(n_host[r, row])
-                    if n > 0 and armed[r, row]:
-                        if stale:
-                            # evicted/cancelled/preempted since dispatch: the
-                            # replay recomputes this token — discarding it
-                            # here is what keeps delivery exactly-once
-                            epoch_break = True
-                            wasted += n
-                        else:
-                            if ring.completes_at.get(row) == r:
-                                handle.span.mark("prefill_done")
-                                self.prefilling.remove(handle)
-                                self.decoding[handle.slot] = handle
-                            self._deliver(handle, int(tok_host[r, row]))
-                            stale = (handle.finished or handle.slot != slot
-                                     or handle.epoch != epoch)
-                    if K1 and ring.loop_rounds[r, slot]:
-                        # fused tail rows: -1 marks where the device stop
-                        # mask kicked in (exactly _consume_block's drain)
-                        if stale:
-                            wasted += K1
-                            continue
-                        for j in range(K1):
-                            token = int(blk_host[r, j, slot])
-                            if token < 0:
-                                wasted += K1 - j
-                                break
-                            self._deliver(handle, token)
-                            if handle.finished:
-                                wasted += K1 - j - 1
-                                break
-            if wasted:
-                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total",
-                                 wasted)
-            if epoch_break:
-                # the membership epoch invalidated this capture mid-flight:
-                # visible on the Perfetto timeline as the capture/replay
-                # boundary (ISSUE 13)
-                self.metrics.inc("finchat_freerun_epoch_breaks_total")
-                TRACER.event("freerun_epoch_break", track=self._trace_track,
-                             args={"replica": self.replica_id,
-                                   "rounds": ring.rounds})
+    # ISSUE 10 point) spec / constrained never fire again
+    MIXED_DEMOTION_REASONS = ("spec", "constrained", "ring", "other")
 
     def _use_mixed(self) -> bool:
         """Can this iteration run ONE packed ragged dispatch instead of a
         prefill round plus a decode-side dispatch? Both populations must
         exist — and that is now the ONLY condition. The ragged rebuild
-        (ISSUE 10) folded spec verify blocks, decode_loop fused tails, and
-        grammar-constrained picks into rows of the packed buffer; ring/
+        (ISSUE 10) folded spec verify blocks and grammar-constrained
+        picks into rows of the packed buffer; ring/
         seq-sharded prefill — the last demotion reason — is promoted too
         (ISSUE 15): a ring-routed prompt rides the packed round as
         ordinary bounded-size chunk rows, where the ragged kernel's
@@ -3230,9 +2835,8 @@ class ContinuousBatchingScheduler:
         dispatch (ISSUE 10; engine.ragged_mixed_step over
         ops/ragged_paged_attention.py): prefilling sequences a chunk each,
         plain decode slots a token, grammar-constrained slots a token with
-        their logits row returned for the host pick, spec-eligible slots a
-        (1+Kd)-token verify block, and loop-eligible slots a further fused
-        ``loop_depth - 1``-token tail — one model dispatch, one host
+        their logits row returned for the host pick and spec-eligible
+        slots a (1+Kd)-token verify block — one model dispatch, one host
         fetch. PR 4's padded mixed step demoted the whole iteration to the
         serialized split path whenever any of those features was live —
         exactly the mix a loaded engine runs; now only ring/seq-sharded
@@ -3242,7 +2846,6 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         C = eng.engine_cfg.prefill_chunk
         B = eng.engine_cfg.max_seqs
-        self._round_tally += 1  # one host-stepped serving round
         Kd = self.spec_k
         spec_on = Kd > 0 and self._spec_cooldown == 0
         batch: list[SequenceHandle] = []
@@ -3283,7 +2886,6 @@ class ContinuousBatchingScheduler:
         temp = np.zeros((R,), np.float32)
         top_p = np.ones((R,), np.float32)
         top_k = np.zeros((R,), np.int32)
-        loop_active = np.zeros((B,), bool)
         packed: list[int] = []
         tok_row: list[int] = []
 
@@ -3294,7 +2896,6 @@ class ContinuousBatchingScheduler:
         spec_rows: list[tuple[int, int, SequenceHandle, int]] = []
         constrained_decode: list[tuple[int, int, SequenceHandle, int]] = []
         constrained_rows: list[int] = []  # row indices whose logits the host needs
-        loop_members: list[tuple[int, SequenceHandle, int]] = []
         spec_consulted = False
 
         i = 0
@@ -3377,17 +2978,7 @@ class ContinuousBatchingScheduler:
                 packed.append(0)
                 tok_row.append(i)
                 plain_rows.append((i, slot, h, epoch))
-                if self.loop_depth > 1 and self._loop_eligible(h, 0):
-                    # fused K-token tail inside the SAME dispatch: the
-                    # row's phase-1 token plus loop_depth-1 tail tokens
-                    # stay within the budget (and eviction-boundary room)
-                    # _loop_eligible checks — the span starts at the
-                    # phase-1 write, so eligibility runs pre-bump
-                    loop_active[slot] = True
-                    loop_members.append((slot, h, epoch))
-                    h.kv_ctx += self.loop_depth
-                else:
-                    h.kv_ctx += 1
+                h.kv_ctx += 1
             i += 1
 
         T = eng.ragged_bucket(len(packed))
@@ -3395,16 +2986,13 @@ class ContinuousBatchingScheduler:
         tok_row += [R] * (T - len(tok_row))
         with (TRACER.phase("dispatch", self._phases),
               Timer(self.metrics, "finchat_mixed_step_seconds") as _mt):
-            emitted_dev, n_em_dev, row_logits_dev, block_dev = eng.ragged_mixed(
+            emitted_dev, n_em_dev, row_logits_dev = eng.ragged_round(
                 jnp.asarray(np.asarray(packed, np.int32)),
                 jnp.asarray(np.asarray(tok_row, np.int32)),
                 jnp.asarray(row_slot), jnp.asarray(row_start),
                 jnp.asarray(row_len), jnp.asarray(row_from_device),
                 jnp.asarray(row_arm), jnp.asarray(row_n_drafts),
                 jnp.asarray(temp), jnp.asarray(top_p), jnp.asarray(top_k),
-                jnp.asarray(loop_active), jnp.asarray(self._temperature),
-                jnp.asarray(self._top_p), jnp.asarray(self._top_k),
-                self.eos_id,
             )
         self._tally_dispatch("ragged")
         # prefill bookkeeping happens at dispatch: row_len is host data
@@ -3422,9 +3010,7 @@ class ContinuousBatchingScheduler:
                        for _i, j in job_rows]
             riders += [self._rider(h, "constrained")
                        for _i, _slot, h, _e in constrained_decode]
-            riders += [
-                self._rider(h, "decode_loop" if loop_active[slot] else "decode")
-                for _i, slot, h, _e in plain_rows]
+            riders += [self._rider(h, "decode") for _i, _slot, h, _e in plain_rows]
             riders += [self._rider(h, "spec", int(row_n_drafts[i]))
                        for i, _slot, h, _e in spec_rows]
             self._trace_dispatch("ragged", riders,
@@ -3438,12 +3024,11 @@ class ContinuousBatchingScheduler:
             # slice [n, vocab], exactly the _dispatch_decode discipline
             logits_sel = eng.logits_rows(row_logits_dev, constrained_rows)
         # ONE host fetch serves decode tokens, spec acceptances, first
-        # tokens, the fused tail block, and the constrained rows' logits
-        # (worker thread keeps the event loop live)
-        emitted, n_emitted, block, logits_host = await self._fetch(
+        # tokens and the constrained rows' logits (worker thread keeps the
+        # event loop live)
+        emitted, n_emitted, logits_host = await self._fetch(
             lambda: (
                 np.asarray(emitted_dev), np.asarray(n_em_dev),
-                np.asarray(block_dev),
                 np.asarray(logits_sel) if logits_sel is not None else None,
             )
         )
@@ -3494,25 +3079,6 @@ class ContinuousBatchingScheduler:
                 # cadence: a ragged round where every proposal missed (or
                 # nothing was accepted) advances the streak
                 self._spec_note_step(accepted=accepted_total)
-            # fused tail: drain each loop slot's [loop_depth-1] row — -1 marks
-            # where the device stop mask kicked in after a phase-1/tail EOS
-            wasted = 0
-            K1 = int(block.shape[0])
-            for slot, handle, epoch in loop_members:
-                if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                    wasted += K1  # phase-1 EOS/length/cancel: device free-ran
-                    continue
-                for j in range(K1):
-                    token = int(block[j, slot])
-                    if token < 0:  # device stop mask
-                        wasted += K1 - j
-                        break
-                    self._deliver(handle, token)
-                    if handle.finished:  # EOS (host view) / length / cancel
-                        wasted += K1 - j - 1
-                        break
-            if wasted:
-                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
 
     def _deliver(self, handle: SequenceHandle, token_id: int) -> None:
         now = time.perf_counter()
@@ -3546,37 +3112,24 @@ class ContinuousBatchingScheduler:
         else:
             handle.events.put_nowait({"type": "token", "token_id": token_id})
 
-    def _dispatch_decode(
-        self, exclude: set[int] = frozenset(),
-        membership: list[tuple[int, SequenceHandle, int]] | None = None,
-    ) -> _InFlightStep:
+    def _dispatch_decode(self, exclude: set[int] = frozenset()) -> _InFlightStep:
         """Enqueue one decode step on the device; returns without syncing.
 
         ``exclude`` slots ride the step INACTIVE (KV writes trash-redirected,
         ``context_lens`` frozen, no token delivered) — used for
         grammar-constrained slots whose host-side pick from the previous
         step has not landed yet, so unconstrained streams keep the depth-2
-        pipeline cadence while a tool decision is in flight.
-
-        ``membership`` pins the step to an EXPLICIT (slot, handle, epoch)
-        snapshot instead of re-reading ``self.decoding`` — the PR 5 epoch
-        discipline applied to dispatch BUILDING: _dispatch_decode_loop
-        passes its demoted subset so both of the iteration's dispatches
-        derive from the same snapshot (see the regression note there)."""
+        pipeline cadence while a tool decision is in flight."""
         inject("scheduler.decode", replica=self.replica_id)
         eng = self.engine
         B = eng.engine_cfg.max_seqs
-        if membership is None:
-            membership = [
-                (slot, h, h.epoch) for slot, h in self.decoding.items()
-            ]
         active = np.zeros((B,), bool)
         members = []
-        for slot, handle, epoch in membership:
+        for slot, handle in self.decoding.items():
             if slot in exclude:
                 continue
             active[slot] = True
-            members.append((slot, handle, epoch))
+            members.append((slot, handle, handle.epoch))
             handle.kv_ctx += 1
         # step logits come back to host only while a grammar-constrained
         # sequence is IN this step (a second compiled decode variant), and
@@ -3610,146 +3163,6 @@ class ContinuousBatchingScheduler:
             moe_experts=eng.moe_experts,
         )
 
-    def _undelivered(self, inflight) -> dict[int, int]:
-        """Per-slot token count already dispatched in the still-unconsumed
-        in-flight step/block. ``handle.generated`` lags by exactly this
-        amount at the next dispatch (depth-2 dispatches N+1 BEFORE
-        consuming N), so budget eligibility must subtract it — otherwise a
-        slot with K tokens left would ride TWO consecutive blocks and the
-        second one's K in-place appends would run past its page
-        allocation."""
-        if inflight is None:
-            return {}
-        if isinstance(inflight, _InFlightRing):
-            # the staged plan's max emissions per slot (budget already
-            # consumed deterministically at staging time)
-            return dict(inflight.ahead)
-        if isinstance(inflight, _InFlightBlock):
-            ahead = {slot: self.loop_depth for slot, _h, _e in inflight.block_members}
-            if inflight.step is not None:
-                for slot, _h, _e in inflight.step.members:
-                    ahead[slot] = 1
-            return ahead
-        return {slot: 1 for slot, _h, _e in inflight.members}
-
-    def _loop_eligible(self, handle: SequenceHandle, ahead: int = 0) -> bool:
-        """Can this slot ride a fused K-token block? It must need NO
-        per-token host control for the next ``loop_depth`` tokens: no
-        grammar constraint (host-side picks land between steps) and at
-        least K tokens of ``max_new_tokens`` budget left beyond the
-        ``ahead`` tokens still undelivered in the in-flight dispatch (its
-        page allocation covers prompt + max_new, so the budget check also
-        bounds the block's in-place KV appends). Slots that fail are
-        DEMOTED to the single-step decode riding the same iteration and
-        rejoin blocks when eligibility returns — the same
-        demote-and-reprobe shape as SPEC_MISS_DEMOTE."""
-        return (
-            handle.constraint is None
-            and handle.sampling.max_new_tokens - handle.generated - ahead
-            >= self.loop_depth
-            # bounded rows: the fused span must not cross the next
-            # eviction boundary (see _bounded_span_room) — the row rides
-            # single-step for that iteration and rejoins after the wave
-            and self._bounded_span_room(handle) >= self.loop_depth
-        )
-
-    def _dispatch_decode_loop(
-        self, exclude: set[int] = frozenset(),
-        ahead: dict[int, int] | None = None,
-    ) -> _InFlightBlock:
-        """Enqueue one fused K-token decode block (plus a single decode
-        step for any demoted slots) on the device; returns without
-        syncing. The caller guarantees at least one non-excluded
-        loop-eligible slot. ``exclude`` slots (constrained picks still in
-        flight) ride fully inactive, exactly as in _dispatch_decode;
-        ``ahead`` is _undelivered() for the in-flight dispatch.
-
-        ONE membership snapshot drives BOTH dispatches (regression,
-        ISSUE 10 satellite): the demoted-slot step used to be rebuilt
-        from ``self.decoding`` AFTER the block dispatch
-        (``exclude=set(self.decoding) - demoted``), so a slot vacated by
-        a mid-iteration fault handler and re-populated before the second
-        dispatch would be swept into the demoted step under a handle that
-        was never in this iteration's membership — stepped once by the
-        stale exclusion math and again by its own next iteration
-        (double-step). The snapshot pins both dispatches to the same
-        (slot, handle, epoch) view, the PR 5 discipline membership
-        CONSUMPTION already used."""
-        inject("scheduler.decode", replica=self.replica_id)
-        eng = self.engine
-        ahead = ahead or {}
-        B = eng.engine_cfg.max_seqs
-        membership = [
-            (slot, h, h.epoch) for slot, h in self.decoding.items()
-        ]
-        active = np.zeros((B,), bool)
-        block_members = []
-        demoted: list[tuple[int, SequenceHandle, int]] = []
-        for slot, handle, epoch in membership:
-            if slot in exclude:
-                continue
-            if self._loop_eligible(handle, ahead.get(slot, 0)):
-                active[slot] = True
-                block_members.append((slot, handle, epoch))
-                handle.kv_ctx += self.loop_depth
-            else:
-                demoted.append((slot, handle, epoch))
-        with TRACER.phase("dispatch", self._phases):
-            token_block = eng.decode_loop(
-                jnp.asarray(active),
-                jnp.asarray(self._temperature),
-                jnp.asarray(self._top_p),
-                jnp.asarray(self._top_k),
-                eos_id=self.eos_id,
-            )
-        self._tally_dispatch("decode_loop")
-        if TRACER.enabled:
-            # a fused row's K steps count against its final context
-            self._trace_dispatch(
-                "decode_loop",
-                [self._rider(h, "decode_loop") for _slot, h, _e in block_members],
-            )
-        self.metrics.inc("finchat_decode_loop_blocks_total")
-        self.metrics.set_gauge("finchat_decode_loop_demoted_slots", len(demoted))
-        step = None
-        if demoted:
-            # demoted slots advance one token via the plain step, built
-            # from the SAME snapshot as the block (never re-read from
-            # self.decoding — see the docstring's double-step regression)
-            step = self._dispatch_decode(membership=demoted)
-        return _InFlightBlock(
-            block_tokens=token_block, block_members=block_members, step=step
-        )
-
-    async def _consume_block(self, blk: _InFlightBlock) -> None:
-        """Fetch a dispatched block's ``[K, max_seqs]`` tokens (one
-        device→host round-trip for K steps' worth of output) and drain each
-        member slot's row: deliver until EOS/length finishes the sequence
-        or a -1 sentinel marks where the device's stop mask kicked in.
-        Device iterations spent free-running past a finished slot are the
-        price of the fixed-shape block — counted as wasted tail tokens."""
-        tokens_host = await self._fetch(lambda: np.asarray(blk.block_tokens))
-        with TRACER.phase("deliver", self._phases):
-            K = tokens_host.shape[0]
-            wasted = 0
-            for slot, handle, epoch in blk.block_members:
-                if handle.finished or handle.slot != slot or handle.epoch != epoch:
-                    wasted += K  # evicted/cancelled/preempted since dispatch
-                    continue
-                for i in range(K):
-                    token = int(tokens_host[i, slot])
-                    if token < 0:  # device stop mask: EOS'd at i-1, free-ran
-                        wasted += K - i
-                        break
-                    self._deliver(handle, token)
-                    if handle.finished:  # EOS (host view) / length / cancel
-                        wasted += K - i - 1
-                        break
-            if wasted:
-                self.metrics.inc("finchat_decode_loop_wasted_tail_tokens_total", wasted)
-        if blk.step is not None:
-            await self._consume_step(blk.step)
-
     @staticmethod
     def _spec_eligible(handle: SequenceHandle) -> bool:
         """Can this slot benefit from drafts? Greedy, unconstrained, and at
@@ -3765,32 +3178,6 @@ class ContinuousBatchingScheduler:
         step — otherwise the pipelined depth-2 decode path is strictly
         better."""
         return any(self._spec_eligible(h) for h in self.decoding.values())
-
-    def _spec_proposal_live(self) -> bool:
-        """Would the spec path actually PROPOSE drafts this round? The
-        probe mirrors ``_run_spec_step``'s proposal loop exactly — lazy
-        one-time ``NgramIndex`` build included (``_deliver`` keeps the
-        index in sync afterwards, so building here is the same build the
-        spec step would do), same span cap, same ``propose`` lookup
-        (read-only). Eligibility alone (``_spec_candidates``) is NOT a
-        live proposal window: an eligible slot whose n-gram lookup misses
-        would make ``_run_spec_step`` fall back to the plain decode round
-        anyway, so capping a free-run capture for it threw away F-1
-        captured rounds for nothing — the streams are byte-identical
-        either way (spec verify is greedy-exact)."""
-        from finchat_tpu.engine.spec import NgramIndex
-
-        Kd = self.spec_k
-        for handle in self.decoding.values():
-            if not self._spec_eligible(handle):
-                continue
-            if handle.ngram_index is None:
-                handle.ngram_index = NgramIndex(handle.history)
-            remaining = handle.sampling.max_new_tokens - handle.generated
-            cap = min(Kd, remaining - 1, self._bounded_span_room(handle) - 1)
-            if cap > 0 and handle.ngram_index.propose(cap):
-                return True
-        return False
 
     def _constrained_pick(self, handle: SequenceHandle, row_logits) -> int:
         """Host-side grammar pick for one constrained slot: choose the
@@ -3964,25 +3351,6 @@ class ContinuousBatchingScheduler:
                 else:
                     self._deliver(handle, int(tokens_host[slot]))
 
-    def _pending_constrained(self, inflight) -> set[int]:
-        """Constrained slots whose host-side pick lands only when
-        ``inflight`` is consumed — they must sit out the next dispatch.
-        In a block, constrained slots only ever ride the demoted step; a
-        free-run capture never carries constrained rows (the cap)."""
-        if isinstance(inflight, _InFlightRing):
-            return set()
-        if isinstance(inflight, _InFlightBlock):
-            return set(inflight.step.constrained_slots) if inflight.step else set()
-        return set(inflight.constrained_slots)
-
-    async def _consume_inflight(self, inflight) -> None:
-        if isinstance(inflight, _InFlightRing):
-            await self._consume_ring(inflight)
-        elif isinstance(inflight, _InFlightBlock):
-            await self._consume_block(inflight)
-        else:
-            await self._consume_step(inflight)
-
     async def _drain_inflight(self, inflight) -> None:
         """Consume an in-flight dispatch OUTSIDE the decode try-block
         (idle drain, pre-mixed drain, pre-preemption drain), converting a
@@ -3992,16 +3360,11 @@ class ContinuousBatchingScheduler:
         one, and preempt/replay recomputes the undelivered tokens.
         Always returns None (the caller's new ``inflight``)."""
         try:
-            await self._consume_inflight(inflight)
+            await self._consume_step(inflight)
             self._note_round_ok("decode")
-            if isinstance(inflight, _InFlightRing):
-                # a captured run carried the prefill rows too: its drain
-                # is a successful round of BOTH planes
-                self._note_round_ok("prefill")
         except Exception as e:
             logger.error("in-flight step consume error: %s", e)
-            scope = "mixed" if isinstance(inflight, _InFlightRing) else "decode"
-            await self._round_failed(scope, str(e))
+            await self._round_failed("decode", str(e))
         return None
 
     def _close_round(self, *, reopen: bool = True) -> None:  # finchat-lint: hot
@@ -4090,7 +3453,7 @@ class ContinuousBatchingScheduler:
 
     async def _loop(self) -> None:
         logger.info("scheduler loop started (max_seqs=%d)", self.engine.engine_cfg.max_seqs)
-        inflight: _InFlightStep | _InFlightBlock | None = None
+        inflight: _InFlightStep | None = None
         phases = self._phases
         phases.reset()  # a loop that died mid-round left its phases open
         self._base_phase = None
@@ -4103,11 +3466,6 @@ class ContinuousBatchingScheduler:
             if self._coexist_mark is not None:
                 self.metrics.inc("finchat_coexist_dispatches_total",
                                  self._dispatch_tally - self._coexist_mark)
-                # ...and the logical ROUNDS those dispatches advanced (a
-                # captured free-run books F rounds for its 1 dispatch) —
-                # together the exact dispatches-per-round ratio (ISSUE 13)
-                self.metrics.inc("finchat_coexist_rounds_total",
-                                 self._round_tally - self._coexist_round_mark)
                 self._coexist_mark = None
             # parked holds (prefix prefilled, waiting for extend_prompt)
             # are not work: without the _prefill_work() refinement the
@@ -4177,7 +3535,6 @@ class ContinuousBatchingScheduler:
             if prefill_active and self.decoding:
                 self.metrics.inc("finchat_coexist_iterations_total")
                 self._coexist_mark = self._dispatch_tally
-                self._coexist_round_mark = self._round_tally
 
             if self._spec_cooldown > 0:
                 # demoted after sustained all-miss steps: count pipelined
@@ -4185,37 +3542,7 @@ class ContinuousBatchingScheduler:
                 self._spec_cooldown -= 1
 
             if self._use_mixed():
-                rounds = self._freerun_rounds_cap()
-                if rounds > 1:
-                    # free-running loop (ISSUE 13), depth-2: stage and
-                    # dispatch the next captured multi-round program FIRST,
-                    # then drain the previous in-flight dispatch's tokens —
-                    # the host delivers to streams while the device is
-                    # mid-flight on the later rounds. Membership events in
-                    # between (admit/evict/preempt/breaker) end re-entry at
-                    # this round boundary: the next iteration re-stages
-                    # from the new snapshot, and stale residual ring
-                    # tokens replay exactly once via the epoch discipline.
-                    ring = None
-                    try:
-                        ring = self._dispatch_freerun(
-                            rounds, self._undelivered(inflight))
-                    except Exception as e:
-                        logger.error("freerun dispatch error: %s", e)
-                        if inflight is not None:
-                            inflight = await self._drain_inflight(inflight)
-                        await self._round_failed("mixed", str(e))
-                        await self._yield()
-                        continue
-                    if ring is not None:
-                        prev, inflight = inflight, ring
-                        if prev is not None:
-                            await self._drain_inflight(prev)
-                        await self._yield()
-                        continue
-                    # staging underfilled: fall through to the host-stepped
-                    # single round below
-                # the host-stepped mixed path is depth-1 (dispatch + consume
+                # the mixed path is depth-1 (dispatch + consume
                 # within the iteration — the prefill side was synchronous in
                 # the split path too): drain any pipelined leftover first
                 if inflight is not None:
@@ -4234,20 +3561,6 @@ class ContinuousBatchingScheduler:
                         await self._round_failed("mixed", str(e))
                     await self._yield()
                     continue
-
-            if isinstance(inflight, _InFlightRing):
-                # leaving the mixed path with a captured run still in
-                # flight (the decode side was cancelled/evicted, or a
-                # ring-routed admission demoted the iteration): the ring
-                # must drain BEFORE any split-path round. A prompt that
-                # completed INSIDE the capture is still in `prefilling`
-                # until the drain flips it to decoding — a split prefill
-                # round running first would re-complete it on an empty
-                # chunk (a garbage duplicate first token off an all-padding
-                # logits row, then the drain's flip raises). Regression:
-                # tests/test_freerun.py
-                # test_freerun_cancel_mid_capture_spares_completions.
-                inflight = await self._drain_inflight(inflight)
 
             # one batched prefill round (all prefilling sequences advance a
             # chunk together), interleaved with decode so TTFT work cannot
@@ -4281,7 +3594,7 @@ class ContinuousBatchingScheduler:
                     # out. Drain any pipelined step left over from the
                     # depth-2 path before switching modes.
                     if inflight is not None:
-                        await self._consume_inflight(inflight)
+                        await self._consume_step(inflight)
                         inflight = None
                     await self._run_spec_step()
                     self._note_round_ok("decode")
@@ -4303,39 +3616,21 @@ class ContinuousBatchingScheduler:
                     # device whose errors surface at the host FETCH would
                     # oscillate the streak 0↔1 and never trip the breaker
                     consumed = False
-                    pending = self._pending_constrained(inflight) if inflight is not None else set()
-                    ahead = self._undelivered(inflight)
-                    use_loop = self.loop_depth > 1 and any(
-                        slot not in pending
-                        and self._loop_eligible(h, ahead.get(slot, 0))
-                        for slot, h in self.decoding.items()
-                    )
-                    if use_loop:
-                        # decode_loop mode, same depth-2 shape: dispatch
-                        # block N+1 (loop-eligible slots fused K steps,
-                        # demoted slots one plain step, pending constrained
-                        # slots out entirely), then consume block N — the
-                        # device runs K decode iterations while the host
-                        # delivers the previous K tokens per slot
-                        blk = self._dispatch_decode_loop(exclude=pending, ahead=ahead)
-                        if inflight is not None:
-                            await self._consume_inflight(inflight)
-                            consumed = True
-                        inflight = blk
-                    elif any(slot not in pending for slot in self.decoding):
+                    pending = set(inflight.constrained_slots) if inflight is not None else set()
+                    if any(slot not in pending for slot in self.decoding):
                         # depth-2 pipeline: dispatch N+1 (sans pending
                         # constrained slots), then consume N — the device
                         # computes while the host delivers tokens
                         step = self._dispatch_decode(exclude=pending)
                         if inflight is not None:
-                            await self._consume_inflight(inflight)
+                            await self._consume_step(inflight)
                             consumed = True
                         inflight = step
                     else:
                         # every decoding slot is waiting on a host pick:
                         # drain, then run depth-1
                         if inflight is not None:
-                            await self._consume_inflight(inflight)
+                            await self._consume_step(inflight)
                             inflight = None
                             consumed = True
                         if self.decoding:

@@ -75,117 +75,6 @@ logger = get_logger(__name__)
 _PROMPTS_DIR = Path(__file__).resolve().parent.parent.parent / "prompts"
 
 
-_REPACE_DONE = object()
-
-
-async def _repace_bursts(updates, loop_depth: int, burst_cap: int | None = None):
-    """Smooth the fused decode loop's K-token bursts for SSE clients.
-
-    With ``decode_loop_depth`` K > 1 the scheduler delivers K token events
-    per device dispatch, so the raw stream is K chunks back-to-back then a
-    block-length gap — a visible stutter at the terminal. (The free-run
-    capture multiplies the burst: its ring drains up to
-    ``freerun_rounds`` x ``loop_depth`` tokens at once — callers pass
-    that product as ``burst_cap`` while ``loop_depth`` stays the
-    steady-state seed, and the observed-width EMA below adapts between
-    them.) This pacer keeps
-    the per-chunk emit (every token is still its own SSE frame, flushed
-    individually by HTTPServer) but spreads each burst over the observed
-    block cadence.
-
-    A reader task timestamps arrivals BEFORE any pacing sleep — measuring
-    gaps on the paced consumer side would fold our own sleeps into the
-    estimate (the boundary gap shrinks by (K-1)·pace and the EMA converges
-    to ~half the true block time, leaving a residual stall). Burst starts
-    are detected on the true timeline (members of one block land within
-    ~µs of each other), the EMA runs over burst-START-to-burst-start
-    deltas (= the true block period), and members are emitted ~block/K
-    apart. Added latency is bounded: a chunk is never held past one
-    EMA-block after its arrival (drain guard) nor paced more than
-    50 ms/token. K <= 1 with no wider cap is a passthrough."""
-    burst_cap = max(loop_depth, burst_cap or loop_depth)
-    if burst_cap <= 1:
-        async for update in updates:
-            yield update
-        return
-    import time as _time
-
-    queue: asyncio.Queue = asyncio.Queue()
-
-    async def _reader():
-        try:
-            async for update in updates:
-                queue.put_nowait((_time.monotonic(), update))
-        except BaseException as e:  # propagate into the consumer
-            queue.put_nowait((0.0, e))
-            return
-        queue.put_nowait((0.0, _REPACE_DONE))
-
-    reader = asyncio.create_task(_reader())
-    ema: float | None = None
-    burst_start: float | None = None
-    last_arrival: float | None = None
-    next_emit = 0.0
-    # observed burst WIDTH (chunks per burst), EMA'd alongside the period:
-    # free-run captures engage solely in coexist windows, so steady-state
-    # bursts are loop_depth-sized while ring drains reach
-    # loop_depth x freerun_rounds — pacing by the static product would
-    # spread a steady-state block over 1/freerun_rounds of its period and
-    # bring the stutter back. Seed at the steady-state loop_depth (the
-    # common case is right from burst one; a wide ring drain is bounded
-    # by the never-hold-past-one-block cap while the EMA widens), clamp
-    # to [1, burst_cap].
-    eff_width = float(max(1, loop_depth))
-    burst_n = 0
-    try:
-        while True:
-            t_arr, update = await queue.get()
-            if update is _REPACE_DONE:
-                return
-            if isinstance(update, BaseException):
-                raise update
-            if update.get("type") != "response_chunk":
-                yield update
-                continue
-            # burst-boundary threshold: EMA-relative with a 10 ms floor —
-            # a µs-scale cutoff would let ordinary event-loop jitter
-            # between same-block dequeues fragment one burst into several,
-            # polluting the EMA with near-zero deltas until the pacer
-            # silently degrades to passthrough under load. The floor is
-            # safe: a stream whose REAL block boundaries are under 10 ms
-            # is already >100 tokens/s/slot and needs no smoothing
-            threshold = max(1e-2, ema / (2 * eff_width)) if ema else 1e-2
-            if last_arrival is None or t_arr - last_arrival > threshold:
-                if burst_start is not None:
-                    delta = t_arr - burst_start
-                    ema = delta if ema is None else 0.7 * ema + 0.3 * delta
-                    eff_width = min(
-                        max(0.7 * eff_width + 0.3 * max(burst_n, 1), 1.0),
-                        float(burst_cap),
-                    )
-                burst_start = t_arr
-                burst_n = 0
-            burst_n += 1
-            last_arrival = t_arr
-            if ema:
-                pace = min(ema / eff_width, 0.05)
-                now = _time.monotonic()
-                # pace from the previous emit, but never hold a chunk past
-                # one block after its true arrival (bounds added latency
-                # and lets a backed-up queue drain)
-                target = min(max(now, next_emit), t_arr + ema)
-                if target > now:
-                    await asyncio.sleep(target - now)
-                next_emit = target + pace
-            yield update
-    finally:
-        reader.cancel()
-        try:
-            await reader
-        except (asyncio.CancelledError, Exception):
-            pass
-
-
 def load_prompts() -> tuple[str, str]:
     system_prompt = (_PROMPTS_DIR / "system_prompt.txt").read_text()
     tool_prompt = (_PROMPTS_DIR / "tool_prompt.txt").read_text()
@@ -1030,17 +919,7 @@ class App:
                 conversation_id=conversation_id, deadline=deadline,
                 trace_id=trace_id,
             )
-            # decode_loop AND free-run bursts re-pace through the SAME
-            # per-chunk emit — clients see a smooth token cadence, not
-            # K-frame stutters. A captured multi-round dispatch can drain
-            # up to freerun_rounds x loop_depth tokens at once, but only
-            # during coexist windows — loop_depth seeds the pacer's
-            # steady-state width and the product bounds the observed-width
-            # EMA (see _repace_bursts).
-            cap = (max(1, self.cfg.engine.decode_loop_depth)
-                   * max(1, self.cfg.engine.freerun_rounds))
-            async for update in _repace_bursts(
-                    updates, self.cfg.engine.decode_loop_depth, burst_cap=cap):
+            async for update in updates:
                 yield sse_event(update)
 
         return StreamingResponse(chunks=events())

@@ -257,15 +257,6 @@ class EngineConfig:
     # collectives when heads divide the seq axis — falls back to ring
     # when they don't)
     sp_mode: str = "ring"
-    # fused multi-step decode (engine decode_loop_step): tokens generated
-    # per device dispatch. 1 = today's per-token decode_step. K > 1 runs K
-    # decode iterations inside one jitted fori_loop — on-device sampling,
-    # in-place KV appends, per-slot EOS mask — cutting host↔device
-    # round-trips and Python dispatch overhead ~K× at the cost of up to K
-    # steps of inter-token burstiness (the SSE path re-paces emits).
-    # Grammar-constrained, spec-decode, and within-K-of-budget slots are
-    # demoted to single-step by the scheduler.
-    decode_loop_depth: int = 1
     # retrieval/prefill overlap (agent/graph.py + scheduler submit_partial):
     # prefill the response prompt's static prefix (system + context +
     # history) WHILE the retrieval tool's embed+search run, grafting the
@@ -292,24 +283,9 @@ class EngineConfig:
     # prefill round plus a decode step — the admission-stall a long prompt
     # adds to every in-flight stream's inter-token latency shrinks to the
     # fused step's own time. Default on for the chunked path; the split
-    # path remains the golden-identical fallback and takes over whenever
-    # spec decode, decode_loop blocks, grammar-constrained picks, or
-    # ring/seq-sharded prefill are active.
+    # path remains the golden-identical fallback when no decode coexists
+    # with the prefill work.
     mixed_step: bool = True
-    # free-running device loop (ISSUE 13; engine ragged_multi_round): up
-    # to this many CONSECUTIVE ragged rounds are captured into ONE device
-    # dispatch — the staged-descriptor queue pre-admits each round's
-    # prefill chunks, completed prompts flip to on-device-sampled decode
-    # rows mid-run, the decode_loop EOS/budget stop mask generalizes to
-    # every row, and per-round tokens land in an output ring the host
-    # drains asynchronously while the device is mid-flight on the NEXT
-    # capture. Host control returns only at membership epochs (admission,
-    # eviction, preemption, breaker — the PR 5 epoch discipline), and
-    # grammar-constrained or live spec-proposal rows cap the capture to 1
-    # round (today's behavior). 1 = off (one host round-trip per round).
-    # Streams stay byte-identical to the round-stepped path (fp32
-    # contract; tests/test_freerun.py). Requires mixed_step.
-    freerun_rounds: int = 1
     # TP collective-compute overlap (ops/tp_overlap.py): the manual-TP
     # stage path chunks each row-parallel output projection so every
     # chunk's partial-sum all-reduce overlaps the next chunk's matmul —
@@ -704,9 +680,6 @@ def load_config(
         "FINCHAT_RING_PREFILL_MIN", cfg.engine.ring_prefill_min_tokens
     )
     cfg.engine.spec_tokens = _env_int("FINCHAT_SPEC_TOKENS", cfg.engine.spec_tokens)
-    cfg.engine.decode_loop_depth = _env_int(
-        "FINCHAT_DECODE_LOOP_DEPTH", cfg.engine.decode_loop_depth
-    )
     cfg.engine.ring_prefill_chunk = _env_int(
         "FINCHAT_RING_PREFILL_CHUNK", cfg.engine.ring_prefill_chunk
     )
@@ -780,9 +753,6 @@ def load_config(
         "FINCHAT_TOOL_STREAMING", cfg.engine.tool_streaming
     )
     cfg.engine.mixed_step = _env_bool("FINCHAT_MIXED_STEP", cfg.engine.mixed_step)
-    cfg.engine.freerun_rounds = _env_int(
-        "FINCHAT_FREERUN_ROUNDS", cfg.engine.freerun_rounds
-    )
     cfg.engine.tp_overlap = _env_bool("FINCHAT_TP_OVERLAP", cfg.engine.tp_overlap)
     cfg.engine.tp_overlap_chunks = _env_int(
         "FINCHAT_TP_OVERLAP_CHUNKS", cfg.engine.tp_overlap_chunks

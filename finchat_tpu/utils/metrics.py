@@ -4,14 +4,6 @@ The reference has no metrics (SURVEY §5.5); these counters ARE the product's
 north-star surface (tok/s/chip, TTFT, queue depth, batch occupancy, KV-page
 utilization), exported in Prometheus text format at ``/metrics``.
 
-Decode-loop family (scheduler decode_loop mode, engine decode_loop_step):
-``finchat_decode_loop_depth`` (gauge — configured K),
-``finchat_decode_loop_blocks_total`` (fused K-token blocks dispatched),
-``finchat_decode_loop_wasted_tail_tokens_total`` (device iterations spent
-free-running past finished slots — the fixed-shape block's overhead), and
-``finchat_decode_loop_demoted_slots`` (gauge — slots currently advancing
-via single-step because they need per-token host control).
-
 Session-KV-cache family (engine/session_cache.py, scheduler offload/resume):
 ``finchat_session_cache_hits_total`` / ``_misses_total`` (admission matches
 for conversation-keyed submissions), ``finchat_session_cache_resident_bytes``
@@ -33,10 +25,9 @@ prefill work and in-flight decodes coexist) and
 iterations by the scheduler's own attribution — together the exact
 dispatches-per-coexist-iteration figure tests/test_mixed_step.py holds;
 the split path pays >= 2 per such iteration, the ragged path 1),
-``finchat_mixed_demotions_total{reason=spec|decode_loop|constrained|ring|
-other}`` (coexist iterations demoted to the split path, per reason —
-spec/decode_loop/constrained are pre-seeded at zero and stay there since
-the ragged rebuild; only ring still fires),
+``finchat_mixed_demotions_total{reason=spec|constrained|ring|other}``
+(coexist iterations demoted to the split path, per reason — every one is
+pre-seeded at zero and stays there since the ragged rebuild),
 ``finchat_warmup_compiled_variants`` (serving-variant count of the last
 engine warmup — the collapsed row×chunk×mode matrix), and
 ``finchat_inter_token_seconds`` — a histogram of per-sequence inter-token

@@ -92,10 +92,6 @@ TRACE_EVENTS = frozenset({
     "drain_handoff",    # breaker drain handed a stream to a sibling
     "session_migrate",  # session-cache bytes moved between replicas
     "request",          # whole-request complete span (emitted at finish)
-    # a membership epoch invalidated an in-flight free-run capture: the
-    # drain discarded stale residual ring tokens (replayed exactly once
-    # via preempt/replay) — the capture/replay boundary on the timeline
-    "freerun_epoch_break",
     # bounded-KV eviction wave (ISSUE 15): args carry the evicted page
     # count and the affected slots — page occupancy drops are attributable
     # on the timeline without any per-token cost
@@ -205,10 +201,6 @@ FREEZE_OWNERS = ("machine", "process")
 ANOMALY_KINDS = frozenset({
     "breaker_trip", "watchdog_timeout", "shed", "replica_give_up",
     "record_quarantine", "sigterm_drain",
-    # free-run ring replay mismatch: a captured round emitted where the
-    # staged descriptor plan never armed a row (ISSUE 13) — the drain
-    # refuses the unarmed cells and dumps the black box
-    "freerun_divergence",
     # pod plane (ISSUE 20): a liaison peer missed enough heartbeats to be
     # declared dead — the host failure domain tripped; partition adoption
     # follows
@@ -221,8 +213,7 @@ TRACE_EVENT_NAMES = SPAN_MARKS | TRACE_EVENTS | ANOMALY_KINDS
 #: element of each ``[slot, trace_id, mode]`` row) — declared so timeline
 #: consumers and tests have one source of truth.
 DISPATCH_ROW_MODES = frozenset({
-    "prefill", "prefix", "decode", "decode_loop", "spec", "constrained",
-    "ring", "freerun",
+    "prefill", "prefix", "decode", "spec", "constrained", "ring",
 })
 
 #: Serving quant-mode labels a ``dispatch`` event's ``args.quant`` may

@@ -1,6 +1,9 @@
 """Inputs for the tests of the paged attention walk (ops/paged_attention.py),
-shared by tests/test_pallas_attention.py (float cache) and
-tests/test_kv_quant.py (int8 cache).
+shared by the float cache's files (tests/test_pallas_attention.py: the plain
+walk; test_paged_walk_shared_head.py; test_paged_walk_packed_tile.py;
+test_paged_walk_engine.py) and the int8 cache's (tests/test_kv_quant.py,
+test_kv_quant_shared_head.py, test_kv_quant_packed_tile.py,
+test_kv_quant_engine.py).
 
 One call holds every edge of the walk as a row: no token, one token, one
 short of / exactly on / one past a page and a block boundary, live pages that
@@ -18,10 +21,21 @@ entry do: the kernel's first pass reads such a head once for all of its rows
 ``PACKED_SHAPES`` are head counts at which several KV heads' query rows share
 one 8-row softmax tile of the decode kernel (``_heads_per_tile``: one or two
 query heads a KV head), ``PACKED_CASES`` the shared-head cases they run on.
+
+Nearly all of a case's time here is the compile of its program (the kernel in
+interpret mode: 8-16 s against a run of milliseconds), and the program is its
+shapes: cases that differ only in the pages their rows hold take a pool of
+one size (``walk_case``'s ``pool``: ``SHARED_POOL`` for the shared-head
+cases), so those with as many rows run one compiled program.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+# the kernels compile on the chip (FINCHAT_TESTS_TPU=1) and are interpreted here
+INTERPRET = jax.default_backend() != "tpu"
+ATOL = RTOL = 2e-5 if INTERPRET else 2e-2
 
 PAGE_SIZE = 64
 WIDTH = 16  # table entries a row: 1,024 tokens
@@ -65,8 +79,12 @@ SHARED_CASES = {
 }
 
 
+# physical pages of the largest shared-head case, and the two every pool leads with
+SHARED_POOL = 2 + max(sum(-(-n // PAGE_SIZE) for n in case[0]) for case in SHARED_CASES.values())
+
+
 def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
-              page_size=PAGE_SIZE, n_kv=2, head_dim=32, seed=0, heads=()):
+              page_size=PAGE_SIZE, n_kv=2, head_dim=32, seed=0, heads=(), pool=0):
     """Returns ``(q, sources, page_table, q_offset, kv_len, layer, k_dense,
     v_dense)``: ``sources`` is ``(k_pages, v_pages)`` or, quantized, ``(k_pages,
     v_pages, k_scales, v_scales)`` with layer 1 of 2 filled; the dense pair
@@ -74,11 +92,12 @@ def walk_case(group, C, *, quantized=False, contexts=EDGE_CONTEXTS, width=WIDTH,
     (for the int8 cache: the dequantized values). ``heads`` lists ``(rows,
     pages)``: those rows hold the first row's first ``pages`` physical pages
     (as many as each has live) and so its tokens there; a row without a token
-    then has a table row of zeros, as a slot that was never admitted."""
+    then has a table row of zeros, as a slot that was never admitted. The
+    pool holds ``pool`` physical pages where the rows need fewer."""
     rng = np.random.RandomState(seed)
     B, hd_fused = len(contexts), n_kv * head_dim
     live = [-(-n // page_size) for n in contexts]
-    num_phys = 2 + sum(live)
+    num_phys = max(2 + sum(live), pool)
     phys = rng.permutation(np.arange(2, num_phys))  # shuffled, as under churn
     table = np.full((B, width), DEAD_PAGE, np.int32)
     dense = np.zeros((2, B, width * page_size, hd_fused), np.float32)  # k, v
@@ -138,3 +157,15 @@ def assert_matches_reference(out, ref, contexts=EDGE_CONTEXTS, atol=2e-5, rtol=2
             np.testing.assert_array_equal(out[b], 0.0)
         else:
             np.testing.assert_allclose(out[b], ref[b], atol=atol, rtol=rtol)
+
+
+def pallas_eqn(jaxpr):
+    """The first ``pallas_call`` equation in a (nested) jaxpr."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns") and (found := pallas_eqn(inner)) is not None:
+                return found
+    return None

@@ -293,23 +293,22 @@ def test_r2_host_helpers_do_not_taint(tmp_path):
     assert res.findings == []
 
 
-def test_r2_freerun_consume_check(tmp_path):
-    """The freerun-consume extension (ISSUE 13): the free-running loop's
-    ring-drain functions join the hot set BY NAME in engine/scheduler.py —
-    a ``block_until_ready``, ``.item()``, D2H, or implicit ``__bool__`` on
-    the ring re-serializes the host against the very capture the loop
-    exists to overlap. The blessed off-loop ``to_thread`` fetch stays
-    clean."""
+def test_r2_scheduler_consume_seam_is_hot_by_name(tmp_path):
+    """The scheduler's dispatch and consume functions are in the hot set BY
+    NAME in engine/scheduler.py: a ``block_until_ready``, ``.item()``, D2H,
+    or implicit ``__bool__`` on a step's tokens there serializes the host
+    against the step the pipeline dispatched ahead. The blessed off-loop
+    ``to_thread`` fetch stays clean."""
     bad = """
         import jax.numpy as jnp
         import numpy as np
 
         class Sched:
-            async def _consume_ring(self, ring):
-                ring_tok = jnp.ones((4, 4))
-                ring_tok.block_until_ready()
-                n = np.asarray(ring_tok)
-                if ring_tok:
+            async def _consume_step(self, step):
+                tokens = jnp.ones((4, 4))
+                tokens.block_until_ready()
+                n = np.asarray(tokens)
+                if tokens:
                     pass
                 return n
     """
@@ -325,14 +324,14 @@ def test_r2_freerun_consume_check(tmp_path):
         import numpy as np
 
         class Sched:
-            async def _consume_ring(self, ring):
-                ring_tok = jnp.ones((4, 4))
-                host = await asyncio.to_thread(lambda: np.asarray(ring_tok))
+            async def _consume_step(self, step):
+                tokens = jnp.ones((4, 4))
+                host = await asyncio.to_thread(lambda: np.asarray(tokens))
                 return host
 
-            async def _dispatch_freerun(self, rounds):
-                ring = jnp.ones((4, 4))
-                return ring
+            def _dispatch_decode(self, exclude):
+                tokens = jnp.ones((4, 4))
+                return tokens
     """
     res = _lint(tmp_path, {"engine/scheduler.py": good}, {"hot-path-host-sync"})
     assert res.findings == []

@@ -64,16 +64,15 @@ def params():
 
 
 def _sched(params, *, sink=SINK, window=WINDOW, mixed=True, max_seqs=4,
-           num_pages=128, eos_id=-1, spec_tokens=0, decode_loop_depth=1,
-           freerun_rounds=1, session=False, disk="", max_seq_len=512):
+           num_pages=128, eos_id=-1, spec_tokens=0,
+           session=False, disk="", max_seq_len=512):
     cfg = EngineConfig(
         max_seqs=max_seqs, page_size=PAGE, num_pages=num_pages,
         max_seq_len=max_seq_len, prefill_chunk=CHUNK, mixed_step=mixed,
         session_cache=session,
         session_cache_bytes=(32 << 20) if session else 0,
         session_cache_disk_path=disk,
-        spec_tokens=spec_tokens, decode_loop_depth=decode_loop_depth,
-        freerun_rounds=freerun_rounds,
+        spec_tokens=spec_tokens,
         kv_sink_pages=sink, kv_window_pages=window,
     )
     engine = InferenceEngine(CONFIG, params, cfg)
@@ -212,14 +211,13 @@ def test_long_session_bounded_occupancy_and_envelope(params):
         "finchat_boundedkv_evicted_pages_total", 0) == after
 
 
-def test_bounded_composes_with_loop_tails_and_spec(params):
-    """decode_loop fused tails and spec verify rows ride bounded rows:
-    the stream completes with occupancy bounded (write bursts covered by
+def test_bounded_composes_with_spec(params):
+    """Spec verify rows ride bounded rows: the stream completes with occupancy bounded (write bursts covered by
     the eviction reserve) and zero leaks."""
     prompt = (_prompt(4, seed=3) * 5)[:18]  # repetitive: proposals fire
     out, peak, _ = _run_single(
         params, sink=SINK, window=WINDOW, prompt=prompt, max_new=36,
-        spec_tokens=2, decode_loop_depth=3,
+        spec_tokens=2,
     )
     assert len(out) == 36
     assert peak <= SINK + WINDOW
@@ -457,60 +455,6 @@ def test_gapped_entry_whole_resume_or_sink_salvage():
     assert cache.get("c") is None
 
 
-# --- free-run composition ---------------------------------------------------
-
-
-def _freerun_workload(params, freerun):
-    """Decode streams + a long bounded stream admitted mid-decode, long
-    enough that eviction waves fire while captures are (or would be) in
-    flight."""
-    sched = _sched(params, freerun_rounds=freerun, decode_loop_depth=2,
-                   max_seqs=4, num_pages=64)
-    rng = np.random.default_rng(11)
-    a = rng.integers(1, CONFIG.vocab_size, size=10).tolist()
-    b = rng.integers(1, CONFIG.vocab_size, size=30).tolist()
-
-    async def go():
-        snap0 = METRICS.snapshot()
-        await sched.start()
-        try:
-            ha = await sched.submit("a", a, _greedy(40))
-            outs = {"a": [], "b": []}
-            tasks = [asyncio.create_task(_drain(ha, outs["a"]))]
-            while len(outs["a"]) < 2:
-                await asyncio.sleep(0.002)
-            hb = await sched.submit("b", b, _greedy(30))
-            tasks.append(asyncio.create_task(_drain(hb, outs["b"])))
-            await asyncio.gather(*tasks)
-            await asyncio.sleep(0.05)
-            sched.allocator.check_invariants()
-            assert sched.allocator.used_count == 0
-            snap1 = METRICS.snapshot()
-            win = {k: snap1.get(k, 0) - snap0.get(k, 0) for k in (
-                "finchat_freerun_dispatches_total",
-                "finchat_boundedkv_evicted_pages_total",
-            )}
-            return outs, win
-        finally:
-            await sched.stop()
-
-    return asyncio.run(go())
-
-
-def test_freerun_capture_equality_with_eviction(params):
-    """Captured vs host-stepped WITH eviction active: byte-identical
-    streams. Eviction is staged at capture boundaries (the boundedkv cap
-    reason), so a capture's gap schedule equals the host-stepped one."""
-    base, win1 = _freerun_workload(params, 1)
-    fr, win4 = _freerun_workload(params, 4)
-    assert win1["finchat_boundedkv_evicted_pages_total"] > 0
-    assert win4["finchat_boundedkv_evicted_pages_total"] == \
-        win1["finchat_boundedkv_evicted_pages_total"]
-    assert win4["finchat_freerun_dispatches_total"] >= 1, (
-        "captures never engaged")
-    assert fr == base
-
-
 # --- ring promotion ---------------------------------------------------------
 
 
@@ -560,8 +504,7 @@ def test_ring_promotion_no_demotion_and_identity(params, monkeypatch):
                 coexist = {
                     k: snap1.get(k, 0) - snap0.get(k, 0)
                     for k in ("finchat_coexist_dispatches_total",
-                              "finchat_coexist_iterations_total",
-                              "finchat_coexist_rounds_total")
+                              "finchat_coexist_iterations_total")
                 }
                 return outs, ring_demotions, coexist
             finally:
@@ -575,10 +518,9 @@ def test_ring_promotion_no_demotion_and_identity(params, monkeypatch):
     assert promoted == plain, "promoted ring rows changed the streams"
     iters = coexist["finchat_coexist_iterations_total"]
     assert iters > 0, "long prompt never coexisted with the decode stream"
-    # the acceptance headline: one fused dispatch per coexist round even
-    # with the ring-routed row in the mix
-    assert coexist["finchat_coexist_dispatches_total"] == \
-        coexist["finchat_coexist_rounds_total"]
+    # the acceptance headline: one fused dispatch per coexist iteration
+    # even with the ring-routed row in the mix
+    assert coexist["finchat_coexist_dispatches_total"] == iters
 
 
 @pytest.mark.slow

@@ -56,14 +56,14 @@ def test_engine_env_readers(monkeypatch):
 
     monkeypatch.setenv("FINCHAT_WARMUP", "0")
     monkeypatch.setenv("FINCHAT_RING_PREFILL_MIN", "2048")
-    monkeypatch.setenv("FINCHAT_DECODE_LOOP_DEPTH", "4")
+    monkeypatch.setenv("FINCHAT_SPEC_TOKENS", "4")
     cfg = load_config()
     assert cfg.engine.warmup_on_start is False
     assert cfg.engine.ring_prefill_min_tokens == 2048
-    assert cfg.engine.decode_loop_depth == 4
+    assert cfg.engine.spec_tokens == 4
 
-    monkeypatch.delenv("FINCHAT_DECODE_LOOP_DEPTH")
-    assert load_config().engine.decode_loop_depth == 1  # per-token default
+    monkeypatch.delenv("FINCHAT_SPEC_TOKENS")
+    assert load_config().engine.spec_tokens == 0  # per-token default
 
     monkeypatch.setenv("FINCHAT_WARMUP", "1")
     cfg = load_config()

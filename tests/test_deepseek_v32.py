@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny_models
 
 from finchat_tpu.engine.engine import InferenceEngine
 from finchat_tpu.engine.kv_cache import PagedKVCache, page_hbm_bytes
@@ -37,29 +38,8 @@ from finchat_tpu.utils.metrics import METRICS
 from finchat_tpu.utils.tracing import TRACER
 from perfbench.models import deepseek_v32 as ds
 
-# DeepSeek-V3.2's block at a size a test holds: one dense layer and two
-# routed ones; 4 heads of [16 | 8] over latents of 32; an indexer of 4 heads
-# of 16 that keeps 24 tokens; 16 routed experts of 32 in 4 groups, 2 groups
-# kept, 2 a token, of which 4 are held (half of group 0 ... the whole of it
-# here), a shared expert of 32; YaRN over an original window of 64
-FILE = {
-    "model_type": "deepseek_v32", "hidden_size": 64, "intermediate_size": 96,
-    "moe_intermediate_size": 32, "n_routed_experts": 4, "num_experts_per_tok": 2,
-    "reduced": {"n_routed_experts": {"from": 16, "to": 4, "why": "a chip's share"}},
-    "n_group": 4, "topk_group": 2, "n_shared_experts": 1, "norm_topk_prob": True,
-    "routed_scaling_factor": 2.5, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
-    "first_k_dense_replace": 1, "num_hidden_layers": 3, "num_nextn_predict_layers": 0,
-    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 32, "kv_lora_rank": 32,
-    "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
-    "index_n_heads": 4, "index_head_dim": 16, "index_topk": 24,
-    "rope_theta": 10000, "rope_scaling": {
-        "type": "yarn", "factor": 40, "original_max_position_embeddings": 64,
-        "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1},
-    "rms_norm_eps": 1e-6, "vocab_size": 300, "tie_word_embeddings": False,
-    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
-}
-CONFIG = dataclasses.replace(ds.program_config(FILE), dtype=jnp.float32)
-PARAMS = init_params(CONFIG, jax.random.key(0))
+FILE = tiny_models.FILES["deepseek_v32"]
+CONFIG, PARAMS = tiny_models.build("deepseek_v32")
 PAGE, CHUNK, SLOTS = 16, 12, 4
 TOL = 2e-4  # float32 against float32; the logits' spread is about 1, a control reads 4 or more
 
@@ -514,8 +494,6 @@ def test_the_scheduler_books_the_selection_on_deliver_and_on_the_rounds_event():
 @pytest.mark.parametrize("options, named", [
     ({"kv_quant": "int8"}, "engine.kv_quant"),
     ({"spec_tokens": 2}, "engine.spec_tokens"),
-    ({"decode_loop_depth": 4}, "engine.decode_loop_depth"),
-    ({"freerun_rounds": 4}, "engine.freerun_rounds"),
     ({"kv_sink_pages": 1, "kv_window_pages": 4}, "engine.kv_sink_pages"),
 ])
 def test_engine_options_that_would_not_carry_latent_pages_are_refused_by_name(options, named):

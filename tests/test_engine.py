@@ -158,3 +158,29 @@ def test_logits_rows_pads_the_count_to_a_power_of_two(params, rows, width):
     got = eng.logits_rows(logits, rows)
     assert got.shape == (width, 6)
     assert jnp.array_equal(got[: len(rows)], logits[jnp.asarray(rows)])
+
+
+@pytest.mark.parametrize("kind,model", [("recurrent state", "falcon_h1"),
+                                         ("latent pages", "deepseek_v32")])
+def test_one_table_says_what_a_kind_of_per_row_memory_is_not_served_with(kind, model):
+    """``NOT_CARRIED`` is the one place that says it: with every engine option
+    of a kind's row switched on at once, ONE error names the kind and each
+    option beside its reason (a mesh and ``model.quant`` are not engine
+    options: tests/test_falcon_h1.py, test_deepseek_v32.py hold those)."""
+    import tiny_models
+    from finchat_tpu.engine.engine import NOT_CARRIED
+
+    options = {"engine.spec_tokens": {"spec_tokens": 2}, "engine.kv_quant": {"kv_quant": "int8"},
+               "engine.kv_sink_pages / engine.kv_window_pages":
+                   {"kv_sink_pages": 1, "kv_window_pages": 4}}
+    row = {option: why for option, why in NOT_CARRIED[kind].items() if option in options}
+    assert len(row) >= 2
+    config, params = tiny_models.build(model)
+    cfg = EngineConfig(max_seqs=2, page_size=16, num_pages=64, max_seq_len=256, prefill_chunk=12,
+                       **{k: v for option in row for k, v in options[option].items()})
+    with pytest.raises(ValueError) as refused:
+        InferenceEngine(config, params, cfg, attn_backend="ref")
+    said = str(refused.value)
+    assert f"a model with {kind}" in said
+    for option, why in row.items():
+        assert f"{option} ({why})" in said

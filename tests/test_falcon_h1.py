@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny_models
 
 from finchat_tpu.engine import engine as engine_module
 from finchat_tpu.engine.engine import InferenceEngine, ragged_mixed_step
@@ -37,22 +38,8 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.metrics import METRICS
 from perfbench.models import falcon_h1
 
-# Falcon-H1's block at a size a test holds: head_dim 32 is not 64 / 4, two
-# groups of B/C, every multiplier away from 1, the scan in blocks of 8
-FILE = {
-    "model_type": "falcon_h1", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 2, "num_attention_heads": 4, "num_key_value_heads": 2,
-    "head_dim": 32, "vocab_size": 300, "rope_theta": 1e11, "rms_norm_eps": 1e-5,
-    "mamba_n_heads": 4, "mamba_d_head": 16, "mamba_d_ssm": 64, "mamba_d_state": 8,
-    "mamba_n_groups": 2, "mamba_d_conv": 4, "mamba_chunk_size": 8,
-    "embedding_multiplier": 5.65, "lm_head_multiplier": 0.0625,
-    "attention_in_multiplier": 0.9, "attention_out_multiplier": 0.3,
-    "key_multiplier": 0.11, "mlp_multipliers": [0.17, 0.5], "ssm_in_multiplier": 1.3,
-    "ssm_out_multiplier": 1.5, "ssm_multipliers": [1.2, 1.5, 1.4, 1.6, 2.0],
-    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
-}
-CONFIG = dataclasses.replace(falcon_h1.program_config(FILE), dtype=jnp.float32)
-PARAMS = init_params(CONFIG, jax.random.key(0))
+FILE = tiny_models.FILES["falcon_h1"]
+CONFIG, PARAMS = tiny_models.build("falcon_h1")
 PAGE, CHUNK, SLOTS = 16, 12, 4  # a prefill chunk of 12 against scan blocks of 8
 TOL = 2e-5  # float32 against float32, logits of spread 0.06
 
@@ -166,14 +153,13 @@ def test_ragged_round_with_rows_at_both_ends_of_the_buffer():
     tok_row = [0] + [1] * CHUNK + [2] * CHUNK + [3]
     dev = np.asarray([True, False, False, True])
     zeros_i = jnp.zeros((SLOTS,), jnp.int32)
-    engine.state, _e, _n, row_logits, _b = ragged_mixed_step(
+    engine.state, _e, _n, row_logits = ragged_mixed_step(
         engine.params, engine.state, jnp.asarray(packed, jnp.int32),
         jnp.asarray(tok_row, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32),
         jnp.asarray([0, 0, CHUNK, 0], jnp.int32), jnp.asarray([1, CHUNK, CHUNK, 1], jnp.int32),
         jnp.asarray(dev), jnp.asarray(dev), zeros_i,
         jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.zeros((SLOTS,), bool), jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.int32(-1), config=CONFIG, page_size=PAGE, attn_backend="ref",
+        config=CONFIG, page_size=PAGE, attn_backend="ref",
         max_row_tokens=CHUNK)
     row_logits = np.asarray(row_logits)
     after = _decode(engine, {slot: seqs[slot][-1] for slot in range(SLOTS)})
@@ -423,8 +409,6 @@ def test_rebuild_device_state_starts_every_slot_from_zero():
 
 @pytest.mark.parametrize("options,named", [
     ({"spec_tokens": 2}, "engine.spec_tokens"),
-    ({"decode_loop_depth": 4}, "engine.decode_loop_depth"),
-    ({"freerun_rounds": 4, "mixed_step": True}, "engine.freerun_rounds"),
     ({"kv_sink_pages": 1, "kv_window_pages": 4}, "engine.kv_sink_pages"),
 ])
 def test_engine_options_that_would_not_carry_the_state_are_refused_by_name(options, named):
@@ -469,10 +453,10 @@ def test_a_step_that_does_not_carry_the_state_raises_instead_of_running():
     engine = _engine()
     B = SLOTS
     with pytest.raises(NotImplementedError, match="ssm_cache"):
-        engine_module.decode_loop_step(
-            engine.params, engine.state, jnp.zeros((B,), bool), jnp.ones((B,)), jnp.ones((B,)),
-            jnp.zeros((B,), jnp.int32), jnp.int32(-1), config=CONFIG, page_size=PAGE,
-            attn_backend="ref", loop_depth=2)
+        engine_module.verify_step(
+            engine.params, engine.state, jnp.zeros((B,), bool), jnp.zeros((B, 2), jnp.int32),
+            jnp.zeros((B,), jnp.int32), jnp.ones((B,)), jnp.ones((B,)),
+            jnp.zeros((B,), jnp.int32), config=CONFIG, page_size=PAGE, attn_backend="ref")
 
 
 # --- the block without the mixer is the block it was ----------------------------

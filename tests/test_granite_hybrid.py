@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny_models
 
 from finchat_tpu.engine.engine import InferenceEngine, ragged_mixed_step
 from finchat_tpu.engine.kv_cache import page_hbm_bytes
@@ -44,28 +45,8 @@ from finchat_tpu.utils.metrics import METRICS
 from finchat_tpu.utils.tracing import TRACER
 from perfbench.models import granitemoehybrid as granite
 
-# Granite-4.0-H's block at a size a test holds: one whole period (five mamba,
-# one attention, four mamba), 12 routed experts of 32 of which 6 are held, 2 a
-# token, a shared expert of 48, 8 mixer heads of 16 with 16 state channels,
-# 4 / 2 attention heads of 16, the published scalars
-KINDS = ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
-FILE = {
-    "model_type": "granitemoehybrid", "hidden_size": 64, "intermediate_size": 32,
-    "shared_intermediate_size": 48, "num_local_experts": 6, "num_experts_per_tok": 2,
-    "reduced": {"num_local_experts": {"from": 12, "to": 6, "why": "a chip's share"}},
-    "num_hidden_layers": 10, "layer_types": KINDS,
-    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
-    "vocab_size": 300, "rms_norm_eps": 1e-5, "tie_word_embeddings": True,
-    "position_embedding_type": "nope", "rope_theta": 10000,
-    "attention_multiplier": 0.0078125, "embedding_multiplier": 12, "residual_multiplier": 0.22,
-    "logits_scaling": 16,
-    "mamba_n_heads": 8, "mamba_d_head": 16, "mamba_d_state": 16, "mamba_n_groups": 1,
-    "mamba_d_conv": 4, "mamba_expand": 2, "mamba_conv_bias": True, "mamba_proj_bias": False,
-    "program_ssm_chunk": 8,
-    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
-}
-CONFIG = dataclasses.replace(granite.program_config(FILE), dtype=jnp.float32)
-PARAMS = init_params(CONFIG, jax.random.key(0))
+FILE = tiny_models.FILES["granite_hybrid"]
+CONFIG, PARAMS = tiny_models.build("granite_hybrid")
 PAGE, CHUNK, SLOTS = 16, 12, 4
 TOL = 2e-4  # float32 against float32; the logits' spread is about 0.07, a dropped term reads 3e-3 or more
 
@@ -248,8 +229,10 @@ def test_a_large_stack_is_drawn_layer_by_layer_in_place(monkeypatch):
     assert sliced["layers"]["moe_in"].shape == (10, 6, 64, 64)
     assert float(jnp.std(sliced["layers"]["moe_in"])) == pytest.approx(64 ** -0.5, rel=0.02)
     assert float(jnp.abs(sliced["layers"]["moe_in"][3] - sliced["layers"]["moe_in"][4]).max()) > 0
+    monkeypatch.undo()  # the same draw with no leaf sliced (PARAMS come from ONE compiled
+    whole = init_params(CONFIG, jax.random.key(0))  # initialiser: an ulp from a draw a leaf)
     np.testing.assert_array_equal(np.asarray(sliced["layers"]["moe_out"]),
-                                  np.asarray(PARAMS["layers"]["moe_out"]))
+                                  np.asarray(whole["layers"]["moe_out"]))
 
 
 @pytest.mark.parametrize("chunk", [5, 64])
@@ -364,14 +347,13 @@ def test_ragged_round_with_rows_at_both_ends_of_the_buffer():
     tok_row = [0] + [1] * CHUNK + [2] * CHUNK + [3]
     dev = np.asarray([True, False, False, True])
     zeros_i = jnp.zeros((SLOTS,), jnp.int32)
-    engine.state, _e, _n, row_logits, _b = ragged_mixed_step(
+    engine.state, _e, _n, row_logits = ragged_mixed_step(
         engine.params, engine.state, jnp.asarray(packed, jnp.int32),
         jnp.asarray(tok_row, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32),
         jnp.asarray([0, 0, CHUNK, 0], jnp.int32), jnp.asarray([1, CHUNK, CHUNK, 1], jnp.int32),
         jnp.asarray(dev), jnp.asarray(dev), zeros_i,
         jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.zeros((SLOTS,), bool), jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.int32(-1), config=CONFIG, page_size=PAGE, attn_backend="ref",
+        config=CONFIG, page_size=PAGE, attn_backend="ref",
         **engine._ragged_kw())
     row_logits = np.asarray(row_logits)
     after = _decode(engine, {slot: seqs[slot][-1] for slot in range(SLOTS)})
@@ -610,8 +592,6 @@ def test_the_read_counter_is_absent_for_a_model_that_does_not_route_sparsely():
 
 @pytest.mark.parametrize("options,named", [
     ({"spec_tokens": 2}, "engine.spec_tokens"),
-    ({"decode_loop_depth": 4}, "engine.decode_loop_depth"),
-    ({"freerun_rounds": 4, "mixed_step": True}, "engine.freerun_rounds"),
     ({"kv_sink_pages": 1, "kv_window_pages": 4}, "engine.kv_sink_pages"),
 ])
 def test_engine_options_that_would_not_carry_the_state_are_refused_by_name(options, named):

@@ -4,8 +4,8 @@ path, ISSUE 10 — rebuilt from PR 4's padded mixed step).
 The contract under test: the ragged path is pure dispatch fusion — greedy
 streams are byte-identical to the split path (prefill round + decode-side
 dispatches), including the combinations the PADDED mixed step used to demote
-(a grammar-constrained slot, spec-decode verify rows, decode_loop fused
-tails, and a short-tail prefill chunk, all coexisting in one iteration);
+(a grammar-constrained slot, spec-decode verify rows and a short-tail
+prefill chunk, all coexisting in one iteration);
 decode slots advance in EVERY ragged round while a long prompt prefills
 (admission fairness); allocator/page-table invariants hold after ragged
 rounds; the demotion counter stays at zero for the erased reasons; and a
@@ -23,7 +23,6 @@ import pytest
 from finchat_tpu.engine.engine import (
     InferenceEngine,
     commit_first_token,
-    decode_loop_step,
     decode_step,
     prefill_step,
     ragged_mixed_step,
@@ -52,11 +51,11 @@ def params():
 
 
 def _stack(params, mixed=True, max_seqs=4, num_pages=128, eos_id=-1,
-           spec_tokens=0, decode_loop_depth=1):
+           spec_tokens=0):
     cfg = EngineConfig(
         max_seqs=max_seqs, page_size=8, num_pages=num_pages, max_seq_len=128,
         prefill_chunk=CHUNK, mixed_step=mixed, session_cache=False,
-        spec_tokens=spec_tokens, decode_loop_depth=decode_loop_depth,
+        spec_tokens=spec_tokens,
     )
     engine = InferenceEngine(CONFIG, params, cfg)
     return ContinuousBatchingScheduler(engine, eos_id=eos_id)
@@ -82,17 +81,17 @@ def test_engine_ragged_step_matches_split_math(params):
     completing prefill row's greedy first token (vs prefill + commit), a
     decode row's token (vs a verify row with no drafts — the split spec
     path's plain-slot math), a spec row's accepted prefix (vs verify_step),
-    the fused tail block (vs decode_loop_step), and the resulting
-    context_lens / last_tokens all match an identically prepared engine."""
+    and the resulting context_lens / last_tokens all match an identically
+    prepared engine."""
 
     def prepare():
         cfg = EngineConfig(
             max_seqs=4, page_size=8, num_pages=64, max_seq_len=128,
-            prefill_chunk=CHUNK, spec_tokens=2, decode_loop_depth=3,
+            prefill_chunk=CHUNK, spec_tokens=2,
         )
         eng = InferenceEngine(CONFIG, params, cfg)
         alloc = PageAllocator(cfg.num_pages)
-        # slot 0: decoding (will ride the fused tail)
+        # slot 0: decoding
         p0 = [3, 7, 11, 200, 42]
         eng.set_page_table_row(0, alloc.allocate("s0", pages_needed(len(p0) + 16, 8)))
         logits = eng.prefill(0, p0)
@@ -124,7 +123,7 @@ def test_engine_ragged_step_matches_split_math(params):
     oB = jnp.ones((B,), jnp.float32)
     kB = jnp.zeros((B,), jnp.int32)
 
-    # --- split: prefill tail + commit, verify step, loop tail -----------
+    # --- split: prefill tail + commit, verify step ------------------------
     eng_s, p1 = prepare()
     tail = p1[CHUNK:]
     drafts = np.zeros((B, 2), np.int32)
@@ -148,16 +147,9 @@ def test_engine_ragged_step_matches_split_math(params):
         jnp.asarray(nd), zB, oB, kB,
         config=eng_s.config, page_size=8, attn_backend=eng_s.attn_backend,
     )
-    act0 = jnp.zeros((B,), bool).at[0].set(True)
-    eng_s.state, blk_s = decode_loop_step(
-        eng_s.params, eng_s.state, act0, zB, oB, kB, jnp.int32(-1),
-        config=eng_s.config, page_size=8, attn_backend=eng_s.attn_backend,
-        loop_depth=2,
-    )
     split = dict(
         first1=int(first1), tok0=int(emitted_s[0, 0]),
         em2=np.asarray(emitted_s[2, : int(n_em_s[2])]).tolist(),
-        blk0=np.asarray(blk_s[:, 0]).tolist(),
         ctx=np.asarray(eng_s.state.context_lens).tolist(),
         last=np.asarray(eng_s.state.last_tokens).tolist(),
     )
@@ -176,7 +168,7 @@ def test_engine_ragged_step_matches_split_math(params):
     row_slot[0], row_start[0], row_len[0], arm[0] = 1, CHUNK, len(tail), True
     toks += tail
     tok_row += [0] * len(tail)
-    # row 1: slot 0 plain decode (loop tail slot)
+    # row 1: slot 0 plain decode
     row_slot[1], row_len[1], from_dev[1], arm[1] = 0, 1, True, True
     toks += [0]
     tok_row += [1]
@@ -186,23 +178,19 @@ def test_engine_ragged_step_matches_split_math(params):
     tok_row += [2] * 3
     toks += [0] * (T - len(toks))
     tok_row += [R] * (T - len(tok_row))
-    loop_active = np.zeros((B,), bool)
-    loop_active[0] = True
-    eng_r.state, emitted, n_em, _logits, blk = ragged_mixed_step(
+    eng_r.state, emitted, n_em, _logits = ragged_mixed_step(
         eng_r.params, eng_r.state,
         jnp.asarray(toks, jnp.int32), jnp.asarray(tok_row, jnp.int32),
         jnp.asarray(row_slot), jnp.asarray(row_start), jnp.asarray(row_len),
         jnp.asarray(from_dev), jnp.asarray(arm), jnp.asarray(ndr),
         jnp.zeros((R,), jnp.float32), jnp.ones((R,), jnp.float32),
         jnp.zeros((R,), jnp.int32),
-        jnp.asarray(loop_active), zB, oB, kB, jnp.int32(-1),
         config=eng_r.config, page_size=8, attn_backend=eng_r.attn_backend,
-        spec_width=2, loop_depth=3,
+        spec_width=2,
     )
     got = dict(
         first1=int(emitted[0, 0]), tok0=int(emitted[1, 0]),
         em2=np.asarray(emitted[2, : int(n_em[2])]).tolist(),
-        blk0=np.asarray(blk[:, 0]).tolist(),
         ctx=np.asarray(eng_r.state.context_lens).tolist(),
         last=np.asarray(eng_r.state.last_tokens).tolist(),
     )
@@ -249,7 +237,7 @@ def test_engine_ragged_step_accepts_matching_drafts(params):
     from_dev = np.asarray([True, False])
     arm = np.asarray([True, False])
     ndr = np.asarray([2, 0], np.int32)
-    eng.state, emitted, n_em, _lg, _blk = ragged_mixed_step(
+    eng.state, emitted, n_em, _lg = ragged_mixed_step(
         eng.params, eng.state,
         jnp.asarray(toks, jnp.int32), jnp.asarray(tok_row, jnp.int32),
         jnp.asarray(row_slot), jnp.zeros((R,), jnp.int32),
@@ -257,9 +245,8 @@ def test_engine_ragged_step_accepts_matching_drafts(params):
         jnp.asarray(ndr),
         jnp.zeros((R,), jnp.float32), jnp.ones((R,), jnp.float32),
         jnp.zeros((R,), jnp.int32),
-        jnp.zeros((B,), bool), zB, oB, kB, jnp.int32(-1),
         config=eng.config, page_size=8, attn_backend=eng.attn_backend,
-        spec_width=2, loop_depth=1,
+        spec_width=2,
     )
     assert int(n_em[0]) == 3  # both drafts + bonus token committed
     assert np.asarray(emitted[0, :3]).tolist() == ref_tokens
@@ -361,7 +348,7 @@ def _spec_prompt(seed, rng=None):
 def _demoted_combo_workload(params, mixed, recorded=None, seed=7,
                             spec_oracle=None):
     """The previously-demoted feature mix in ONE scheduler (satellite
-    fuzz): spec decode on, decode_loop on, a grammar-constrained stream, a
+    fuzz): spec decode on, a grammar-constrained stream, a
     greedy bystander, and a long prompt with a short tail admitted
     mid-decode — under PR 4 any ONE of these demoted every coexist
     iteration to the split path. ``recorded`` (ragged runs) collects, per
@@ -386,20 +373,18 @@ def _demoted_combo_workload(params, mixed, recorded=None, seed=7,
         with mock.patch.object(NgramIndex, "propose", oracle_propose):
             return _demoted_combo_workload(params, mixed, recorded, seed)
     sched = _stack(params, mixed=mixed, max_seqs=5, num_pages=256,
-                   spec_tokens=2, decode_loop_depth=3)
+                   spec_tokens=2)
     if recorded is not None:
-        real = sched.engine.ragged_mixed
+        real = sched.engine.ragged_round
 
         def spy(tokens, tok_row, row_slot, row_start, row_len,
                 row_from_device, row_arm, row_n_drafts, *rest):
-            loop_active = rest[3]
             nd = np.asarray(row_n_drafts)
             fd = np.asarray(row_from_device)
             rl = np.asarray(row_len)
             recorded.append({
                 "prefill": bool(((rl > 0) & ~fd).any()),
                 "spec": bool((nd > 0).any()),
-                "loop": bool(np.asarray(loop_active).any()),
                 "constrained": any(
                     h.constraint is not None for h in sched.decoding.values()
                 ),
@@ -408,7 +393,7 @@ def _demoted_combo_workload(params, mixed, recorded=None, seed=7,
             return real(tokens, tok_row, row_slot, row_start, row_len,
                         row_from_device, row_arm, row_n_drafts, *rest)
 
-        sched.engine.ragged_mixed = spy
+        sched.engine.ragged_round = spy
     tok = ByteTokenizer()
     rng = np.random.default_rng(seed)
     # repetitive prompts: greedy decode on random tiny weights settles into
@@ -467,7 +452,7 @@ def _demoted_combo_workload(params, mixed, recorded=None, seed=7,
 @pytest.mark.parametrize("seed", [7, 23, 41])
 def test_previously_demoted_combo_byte_identity(params, seed):
     """The erased-demotion fuzz (ISSUE 10 satellite): spec verify rows,
-    decode_loop fused tails, a grammar-constrained stream, and a
+    a grammar-constrained stream, and a
     short-tail prefill coexisting in one iteration — greedy/constrained
     streams byte-identical ragged vs split, with the ragged run actually
     carrying the feature mix in fused dispatches."""
@@ -481,14 +466,12 @@ def test_previously_demoted_combo_byte_identity(params, seed):
     assert ragged == split
     assert dpi_ragged <= 1.2 and dpi_split >= 1.8, (dpi_ragged, dpi_split)
     assert recorded, "no ragged dispatch ran"
-    assert any(r["prefill"] and r["spec"] and r["loop"] and r["constrained"]
+    assert any(r["prefill"] and r["spec"] and r["constrained"]
                for r in recorded), (
         "no single dispatch carried every previously demoting feature",
         recorded)
     assert any(r["prefill"] and r["constrained"] for r in recorded), (
         "constrained slot never rode a fused dispatch", recorded)
-    assert any(r["prefill"] and r["loop"] for r in recorded), (
-        "no fused loop tail in any coexist dispatch", recorded)
     assert any(r["prefill"] and r["spec"] for r in recorded), (
         "no spec verify row in any coexist dispatch", recorded)
     assert any(r["short_tail"] for r in recorded), recorded
@@ -497,15 +480,15 @@ def test_previously_demoted_combo_byte_identity(params, seed):
 def test_demotion_counter_erased_reasons_stay_zero(params):
     """finchat_mixed_demotions_total (ISSUE 10 satellite): the reason
     family is pre-seeded, and running the previously-demoting feature mix
-    increments NONE of the erased reasons (spec / decode_loop /
-    constrained) — the erasure is observable, not assumed."""
+    increments NONE of the erased reasons (spec / constrained) — the
+    erasure is observable, not assumed."""
     before = {
         r: METRICS.get("finchat_mixed_demotions_total", labels={"reason": r})
         for r in ContinuousBatchingScheduler.MIXED_DEMOTION_REASONS
     }
     _demoted_combo_workload(params, mixed=True)
     snap = METRICS.snapshot()
-    for reason in ("spec", "decode_loop", "constrained"):
+    for reason in ("spec", "constrained"):
         key = f'finchat_mixed_demotions_total{{reason="{reason}"}}'
         assert snap.get(key, 0) == before[reason], (reason, snap.get(key))
 
@@ -520,7 +503,7 @@ def test_admission_fairness_decode_advances_every_ragged_round(params):
     serialized prefill round."""
     sched = _stack(params, mixed=True)
     calls: list[tuple[int, int, int]] = []  # (#prefill rows, #decode rows, #decoding)
-    real = sched.engine.ragged_mixed
+    real = sched.engine.ragged_round
 
     def spy(tokens, tok_row, row_slot, row_start, row_len,
             row_from_device, row_arm, row_n_drafts, *rest):
@@ -532,7 +515,7 @@ def test_admission_fairness_decode_advances_every_ragged_round(params):
         return real(tokens, tok_row, row_slot, row_start, row_len,
                     row_from_device, row_arm, row_n_drafts, *rest)
 
-    sched.engine.ragged_mixed = spy
+    sched.engine.ragged_round = spy
     rng = np.random.default_rng(3)
     short = rng.integers(1, CONFIG.vocab_size, size=9).tolist()
     long_p = rng.integers(1, CONFIG.vocab_size, size=6 * CHUNK).tolist()

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny_models
 
 from finchat_tpu.engine import engine as engine_module
 from finchat_tpu.engine.engine import InferenceEngine, ragged_mixed_step
@@ -47,21 +48,8 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.metrics import METRICS
 from perfbench.models import olmo_hybrid
 
-# Olmo-Hybrid's block at a size a test holds: two whole periods of three
-# linear layers and a full one, keys half as wide as values, 4 heads of 16
-# with as many KV heads, no rotation, the WY form in blocks of 8
-FILE = {
-    "model_type": "olmo_hybrid", "hidden_size": 64, "intermediate_size": 128,
-    "num_hidden_layers": 8, "num_attention_heads": 4, "num_key_value_heads": 4,
-    "vocab_size": 300, "rms_norm_eps": 1e-6,
-    "layer_types": ([LINEAR] * 3 + [FULL]) * 2,
-    "linear_num_key_heads": 4, "linear_num_value_heads": 4, "linear_key_head_dim": 8,
-    "linear_value_head_dim": 16, "linear_conv_kernel_dim": 4, "linear_allow_neg_eigval": True,
-    "rope_parameters": {"rope_theta": None},
-    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
-}
-CONFIG = dataclasses.replace(olmo_hybrid.program_config(FILE), dtype=jnp.float32)
-PARAMS = init_params(CONFIG, jax.random.key(0))
+FILE = tiny_models.FILES["olmo_hybrid"]
+CONFIG, PARAMS = tiny_models.build("olmo_hybrid")
 PAGE, CHUNK, SLOTS = 16, 12, 4  # a prefill chunk of 12 against WY blocks of 8
 
 
@@ -392,14 +380,13 @@ def test_ragged_round_with_rows_at_both_ends_of_the_buffer():
     tok_row = [0] + [1] * CHUNK + [2] * CHUNK + [3]
     dev = np.asarray([True, False, False, True])
     zeros_i = jnp.zeros((SLOTS,), jnp.int32)
-    engine.state, _e, _n, row_logits, _b = ragged_mixed_step(
+    engine.state, _e, _n, row_logits = ragged_mixed_step(
         engine.params, engine.state, jnp.asarray(packed, jnp.int32),
         jnp.asarray(tok_row, jnp.int32), jnp.arange(SLOTS, dtype=jnp.int32),
         jnp.asarray([0, 0, CHUNK, 0], jnp.int32), jnp.asarray([1, CHUNK, CHUNK, 1], jnp.int32),
         jnp.asarray(dev), jnp.asarray(dev), zeros_i,
         jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.zeros((SLOTS,), bool), jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.int32(-1), config=CONFIG, page_size=PAGE, attn_backend="ref",
+        config=CONFIG, page_size=PAGE, attn_backend="ref",
         **engine._ragged_kw())
     row_logits = np.asarray(row_logits)
     after = _decode(engine, {slot: seqs[slot][-1] for slot in range(SLOTS)})
@@ -608,8 +595,6 @@ def test_what_cannot_start_from_a_snapshot_recomputes_and_counts():
 
 @pytest.mark.parametrize("options,named", [
     ({"spec_tokens": 2}, "engine.spec_tokens"),
-    ({"decode_loop_depth": 4}, "engine.decode_loop_depth"),
-    ({"freerun_rounds": 4, "mixed_step": True}, "engine.freerun_rounds"),
     ({"kv_sink_pages": 1, "kv_window_pages": 4}, "engine.kv_sink_pages"),
 ])
 def test_engine_options_that_would_not_carry_the_state_are_refused_by_name(options, named):
@@ -654,10 +639,10 @@ def test_a_step_that_does_not_carry_the_state_raises_instead_of_running():
     engine = _engine()
     B = SLOTS
     with pytest.raises(NotImplementedError, match="ssm_cache"):
-        engine_module.decode_loop_step(
-            engine.params, engine.state, jnp.zeros((B,), bool), jnp.ones((B,)), jnp.ones((B,)),
-            jnp.zeros((B,), jnp.int32), jnp.int32(-1), config=CONFIG, page_size=PAGE,
-            attn_backend="ref", loop_depth=2)
+        engine_module.verify_step(
+            engine.params, engine.state, jnp.zeros((B,), bool), jnp.zeros((B, 2), jnp.int32),
+            jnp.zeros((B,), jnp.int32), jnp.ones((B,)), jnp.ones((B,)),
+            jnp.zeros((B,), jnp.int32), config=CONFIG, page_size=PAGE, attn_backend="ref")
 
 
 # --- PERIOD ONE ------------------------------------------------------------------

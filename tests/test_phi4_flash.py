@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+import tiny_models
 
 from finchat_tpu.engine.engine import InferenceEngine, window_pool_pages
 from finchat_tpu.engine.kv_cache import (
@@ -48,21 +49,8 @@ from finchat_tpu.utils.config import EngineConfig
 from finchat_tpu.utils.metrics import METRICS
 from perfbench.models import phi4flash
 
-# the published plan at a size a test holds: 2 x (mamba1, sliding), (mamba1,
-# full), 2 x (gmu, cross); 8 / 4 heads of 8 in 4 / 2 pairs, a window of 8
-# tokens = two pages of 4, Mamba-1 of 128 channels x 4 state channels
-KINDS = ([MAMBA1, WINDOW] * 2 + [MAMBA1, FULL] + [GMU, CROSS] * 2)
-FILE = {
-    "model_type": "phi4flash", "hidden_size": 64, "intermediate_size": 96,
-    "num_attention_heads": 8, "num_key_value_heads": 4, "num_hidden_layers": 10,
-    "layer_types": KINDS, "sliding_window": 8, "vocab_size": 211, "layer_norm_eps": 1e-5,
-    "tie_word_embeddings": True, "mlp_bias": False, "lm_head_bias": False,
-    "mamba_d_state": 4, "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 4,
-    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
-    "ssm_state_dtype": "float32",
-}
-CONFIG = dataclasses.replace(phi4flash.program_config(FILE), dtype=jnp.float32)
-PARAMS = init_params(CONFIG, jax.random.key(0))
+FILE = tiny_models.FILES["phi4_flash"]
+CONFIG, PARAMS = tiny_models.build("phi4_flash")
 PAGE, CHUNK, SLOTS, W = 4, 8, 4, 8
 TOL = 2e-4  # float32 against float32; the logits' spread is about 0.5
 BOUND = window_pages_per_row(W, PAGE)
@@ -244,12 +232,11 @@ def test_ragged_round_with_rows_at_both_ends_of_the_buffer():
     tok_row = [0] + [1] * CHUNK + [2] * CHUNK + [3]
     dev = np.asarray([True, False, False, True])
     zeros_i = jnp.zeros((SLOTS,), jnp.int32)
-    _e, _n, row_logits, _b = engine.ragged_mixed(
+    _e, _n, row_logits = engine.ragged_round(
         jnp.asarray(packed, jnp.int32), jnp.asarray(tok_row, jnp.int32),
         jnp.arange(SLOTS, dtype=jnp.int32), jnp.asarray([0, 0, 2 * CHUNK, 0], jnp.int32),
         jnp.asarray([1, CHUNK, CHUNK, 1], jnp.int32), jnp.asarray(dev), jnp.asarray(dev), zeros_i,
-        jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i,
-        jnp.zeros((SLOTS,), bool), jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i, -1)
+        jnp.zeros((SLOTS,)), jnp.ones((SLOTS,)), zeros_i)
     row_logits = np.asarray(row_logits)
     after = _decode(engine, {slot: seqs[slot][-1] for slot in range(SLOTS)})
     for slot, seq in seqs.items():
@@ -509,8 +496,6 @@ def test_the_pools_have_their_kinds_depths_and_the_cross_layers_own_nothing():
 @pytest.mark.parametrize("options,named", [
     (dict(kv_sink_pages=1, kv_window_pages=8), "kv_sink_pages"),
     (dict(spec_tokens=2), "engine.spec_tokens"),
-    (dict(decode_loop_depth=2), "engine.decode_loop_depth"),
-    (dict(freerun_rounds=2), "engine.freerun_rounds"),
 ])
 def test_engine_options_that_would_not_carry_the_state_are_refused_by_name(options, named):
     with pytest.raises(ValueError, match=named):

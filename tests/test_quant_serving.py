@@ -7,7 +7,7 @@ The contracts under test:
   bitwise-identical to whole-leaf, bounded roundtrip error, forward
   logits inside the quality envelope vs full precision.
 - int8 KV as a first-class page dtype on every serving path: the
-  free-run capture equals host-stepped rounds, spec-verify acceptance
+  packed ragged round equals the split steps, spec-verify acceptance
   stays greedy-exact, and the session tier round-trips the scale planes
   byte-identically (RAM and disk).
 - Record-format versioning (SessionDiskTier v2): dtypes stored by NAME
@@ -33,6 +33,7 @@ import pytest
 from finchat_tpu.analysis.sanitizers import scheduler_leak_report
 from finchat_tpu.engine.engine import InferenceEngine, commit_first_token, prefill_step
 from finchat_tpu.engine.kv_cache import (
+    TRASH_PAGE,
     PageAllocator,
     gather_pages_host,
     pages_needed,
@@ -236,20 +237,20 @@ def test_import_session_entry_cross_mode_refused_and_counted(params):
     assert sched.session_cache.get("x") is None
 
 
-def test_freerun_capture_matches_stepped_rounds_int8kv(params):
-    """ISSUE 14 acceptance: the free-running capture composes with
-    quantized pages — a 3-round ragged_multi_round over an int8-KV pool
-    equals 3 host-stepped ragged_mixed_step rounds exactly (ring tokens,
-    emission counts, fused tails, final device state)."""
-    from finchat_tpu.engine.engine import ragged_mixed_step, ragged_multi_round
+def test_ragged_round_matches_split_steps_int8kv(params):
+    """The packed ragged round composes with quantized pages: a prompt's
+    completing tail and a decode row in ONE ``ragged_mixed_step`` over an
+    int8-KV pool give the tokens, the context and the pool (the int8
+    pages bit for bit, the scale planes to the last ulp) that ``prefill_step`` +
+    ``commit_first_token`` and a ``decode_step`` give an identically
+    prepared engine."""
+    from finchat_tpu.engine.engine import decode_step, ragged_mixed_step
 
     CHUNK = 16
 
     def prepare():
         cfg = EngineConfig(max_seqs=4, page_size=8, num_pages=64,
-                           max_seq_len=128, prefill_chunk=CHUNK,
-                           decode_loop_depth=2, freerun_rounds=3,
-                           kv_quant="int8")
+                           max_seq_len=128, prefill_chunk=CHUNK, kv_quant="int8")
         eng = InferenceEngine(CONFIG, params, cfg)
         alloc = PageAllocator(cfg.num_pages)
         p0 = [3, 7, 11, 200, 42]
@@ -265,77 +266,49 @@ def test_freerun_capture_matches_stepped_rounds_int8kv(params):
             jnp.asarray([p1[:CHUNK]], jnp.int32), jnp.asarray([1], jnp.int32),
             jnp.asarray([0], jnp.int32), jnp.asarray([CHUNK], jnp.int32),
             config=eng.config, page_size=8, attn_backend=eng.attn_backend)
-        return eng, p1
+        return eng, p1[CHUNK:]
+
+    def end_state(eng):  # the pool less the trash page, where padding writes land
+        st = eng.state
+        pool = (st.k_pages, st.v_pages, st.k_scales, st.v_scales)
+        return [np.asarray(x) for x in (st.context_lens, st.last_tokens)] + [
+            np.asarray(x)[:, TRASH_PAGE + 1:] for x in pool]
 
     B = R = 4
-    F, T = 3, 8
-    zR, oR = jnp.zeros((R,)), jnp.ones((R,))
-    kR = jnp.zeros((R,), jnp.int32)
-    zB, oB = jnp.zeros((B,)), jnp.ones((B,))
-    kB = jnp.zeros((B,), jnp.int32)
+    zB, oB, kB = jnp.zeros((B,)), jnp.ones((B,)), jnp.zeros((B,), jnp.int32)
+    static = dict(config=CONFIG, page_size=8)
 
-    def stage():
-        eng, p1 = prepare()
-        tail = p1[CHUNK:]
-        tokens = np.zeros((F, T), np.int32)
-        tok_row = np.full((F, T), R, np.int32)
-        row_slot = np.zeros((R,), np.int32)
-        row_slot[0], row_slot[1] = 1, 0
-        row_start = np.zeros((F, R), np.int32)
-        row_len = np.zeros((F, R), np.int32)
-        from_dev = np.zeros((F, R), bool)
-        arm = np.zeros((F, R), bool)
-        loop_active = np.zeros((F, B), bool)
-        tokens[0, : len(tail)] = tail
-        tok_row[0, : len(tail)] = 0
-        tok_row[0, len(tail)] = 1
-        row_start[0, 0], row_len[0, 0], arm[0, 0] = CHUNK, len(tail), True
-        row_len[0, 1], from_dev[0, 1], arm[0, 1] = 1, True, True
-        loop_active[0, 0] = True
-        for r in (1, 2):
-            tok_row[r, 0], tok_row[r, 1] = 0, 1
-            row_len[r, 0], from_dev[r, 0], arm[r, 0] = 1, True, True
-            row_len[r, 1], from_dev[r, 1], arm[r, 1] = 1, True, True
-            loop_active[r, 0] = True
-        return eng, (tokens, tok_row, row_slot, row_start, row_len,
-                     from_dev, arm, loop_active)
+    eng_s, tail = prepare()
+    eng_s.state, lg = prefill_step(
+        eng_s.params, eng_s.state,
+        jnp.asarray([tail + [0] * (CHUNK - len(tail))], jnp.int32),
+        jnp.asarray([1], jnp.int32), jnp.asarray([CHUNK], jnp.int32),
+        jnp.asarray([len(tail)], jnp.int32), attn_backend=eng_s.attn_backend, **static)
+    eng_s.state, first1 = commit_first_token(
+        eng_s.state, jnp.int32(1), lg[0], jnp.float32(0.0), jnp.float32(1.0), jnp.int32(0))
+    eng_s.state, toks, *_ = decode_step(
+        eng_s.params, eng_s.state, jnp.zeros((B,), bool).at[0].set(True), zB, oB, kB,
+        attn_backend=eng_s.attn_backend, **static)
 
-    eng_s, (tokens, tok_row, row_slot, row_start, row_len, from_dev, arm,
-            loop_active) = stage()
-    stepped = []
-    for r in range(F):
-        eng_s.state, emitted, n_em, _lg, blk = ragged_mixed_step(
-            eng_s.params, eng_s.state,
-            jnp.asarray(tokens[r]), jnp.asarray(tok_row[r]),
-            jnp.asarray(row_slot), jnp.asarray(row_start[r]),
-            jnp.asarray(row_len[r]), jnp.asarray(from_dev[r]),
-            jnp.asarray(arm[r]), jnp.zeros((R,), jnp.int32),
-            zR, oR, kR, jnp.asarray(loop_active[r]), zB, oB, kB,
-            jnp.int32(-1),
-            config=eng_s.config, page_size=8, attn_backend=eng_s.attn_backend,
-            spec_width=0, loop_depth=2)
-        stepped.append((np.asarray(emitted[:, 0]).tolist(),
-                        np.asarray(n_em).tolist(), np.asarray(blk).tolist()))
-    final_s = (np.asarray(eng_s.state.context_lens).tolist(),
-               np.asarray(eng_s.state.last_tokens).tolist())
+    eng_r, tail = prepare()
+    T = 8
+    tokens = tail + [0] * (T - len(tail))
+    tok_row = [0] * len(tail) + [1] + [R] * (T - len(tail) - 1)
+    eng_r.state, emitted, n_em, _lg = ragged_mixed_step(
+        eng_r.params, eng_r.state, jnp.asarray(tokens, jnp.int32),
+        jnp.asarray(tok_row, jnp.int32), jnp.asarray([1, 0, 0, 0], jnp.int32),
+        jnp.asarray([CHUNK, 0, 0, 0], jnp.int32), jnp.asarray([len(tail), 1, 0, 0], jnp.int32),
+        jnp.asarray([False, True, False, False]), jnp.asarray([True, True, False, False]),
+        kB, zB, oB, kB, attn_backend=eng_r.attn_backend, spec_width=0, **static)
 
-    eng_c, (tokens, tok_row, row_slot, row_start, row_len, from_dev, arm,
-            loop_active) = stage()
-    eng_c.state, ring_tok, ring_n, ring_blk = ragged_multi_round(
-        eng_c.params, eng_c.state,
-        jnp.asarray(tokens), jnp.asarray(tok_row), jnp.asarray(row_slot),
-        jnp.asarray(row_start), jnp.asarray(row_len), jnp.asarray(from_dev),
-        jnp.asarray(arm), zR, oR, kR, jnp.asarray(loop_active),
-        zB, oB, kB, jnp.int32(-1),
-        config=eng_c.config, page_size=8, attn_backend=eng_c.attn_backend,
-        loop_depth=2)
-    captured = [(np.asarray(ring_tok[r]).tolist(),
-                 np.asarray(ring_n[r]).tolist(),
-                 np.asarray(ring_blk[r]).tolist()) for r in range(F)]
-    final_c = (np.asarray(eng_c.state.context_lens).tolist(),
-               np.asarray(eng_c.state.last_tokens).tolist())
-    assert captured == stepped
-    assert final_c == final_s
+    assert eng_r.state.k_pages.dtype == jnp.int8 and eng_r.state.k_scales is not None
+    assert np.asarray(n_em).tolist() == [1, 1, 0, 0]
+    assert [int(emitted[0, 0]), int(emitted[1, 0])] == [int(first1), int(toks[0])]
+    for got, want in zip(end_state(eng_r), end_state(eng_s)):
+        if got.dtype == np.float32:  # a scale computed at the packed shape: the last ulp
+            np.testing.assert_allclose(got, want, rtol=1e-5)
+        else:
+            np.testing.assert_array_equal(got, want)
 
 
 def test_spec_verify_acceptance_parity_int8kv(params):

@@ -97,10 +97,8 @@ PROMPT_B = PROMPT_A[::-1] * 2
 # "none" for one request alone)
 BRANCHES = {
     "split_decode": (dict(mixed_step=False), "decode", "staggered"),
-    "decode_loop": (dict(mixed_step=False, decode_loop_depth=4), "decode_loop", "staggered"),
     "spec": (dict(mixed_step=False, spec_tokens=2), "spec", "staggered"),
     "ragged": (dict(), "ragged", "staggered"),
-    "freerun": (dict(freerun_rounds=4), "freerun", "staggered"),
     "prefill_only": (dict(mixed_step=False), "prefill", "none"),
 }
 
@@ -544,8 +542,7 @@ def test_compiled_steps_carry_every_scope(preset, expected):
     ragged = ragged_mixed_step.lower(
         eng.params, eng.state, jnp.zeros((T,), jnp.int32), jnp.zeros((T,), jnp.int32),
         i32, i32, i32, jnp.zeros((B,), bool), jnp.zeros((B,), bool), i32,
-        f32, f32 + 1, i32, jnp.zeros((B,), bool), f32, f32 + 1, i32, jnp.int32(-1),
-        spec_width=0, loop_depth=1, **static).compile().as_text()
+        f32, f32 + 1, i32, spec_width=0, **static).compile().as_text()
     assert (expected | {"ragged_paged_attention", "kv_scatter_ragged"}
             <= _op_name_parts(ragged))
     assert expected <= DEVICE_SCOPES

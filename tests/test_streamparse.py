@@ -159,7 +159,7 @@ def test_split_point_invariance_against_serial_parser():
     """For every corpus text and every chunking (whole, per-char, random
     bursts), finish() must equal parse_tool_decision(text) and the event
     stream must be identical — the incremental plane may never let the
-    chunk boundaries of decode_loop K-token bursts change the outcome."""
+    chunk boundaries of a burst of tokens change the outcome."""
     rng = random.Random(9)
     for text in CORPUS:
         serial = parse_tool_decision(text)

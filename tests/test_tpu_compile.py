@@ -282,8 +282,7 @@ def test_olmo_hybrid_ragged_round_has_no_loop_over_layers_inside_the_period_scan
         described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH))),
         shape((T,), jnp.int32), shape((T,), jnp.int32), rows(jnp.int32), rows(jnp.int32),
         rows(jnp.int32), rows(bool), rows(bool), rows(jnp.int32), rows(jnp.float32),
-        rows(jnp.float32), rows(jnp.int32), rows(bool), rows(jnp.float32), rows(jnp.float32),
-        rows(jnp.int32), shape((), jnp.int32), config=c, page_size=PAGE, attn_backend="pallas",
+        rows(jnp.float32), rows(jnp.int32), config=c, page_size=PAGE, attn_backend="pallas",
         qm_backend="ref", max_row_tokens=cfg.prefill_chunk).compile()
     nest = _loop_nest(compiled.as_text())
     assert [op for depth, op in nest if depth == 0] == ["jit(ragged_mixed_step)/while"], nest
@@ -649,8 +648,7 @@ def test_phi4_flash_ragged_round_compiles_for_v5e_and_fits_beside_the_model(one_
     def args(shape, rows):
         return (shape((T,), jnp.int32), shape((T,), jnp.int32), rows(jnp.int32), rows(jnp.int32),
                 rows(jnp.int32), rows(bool), rows(bool), rows(jnp.int32), rows(jnp.float32),
-                rows(jnp.float32), rows(jnp.int32), rows(bool), rows(jnp.float32),
-                rows(jnp.float32), rows(jnp.int32), shape((), jnp.int32))
+                rows(jnp.float32), rows(jnp.int32))
 
     compiled, _state, cfg = _phi4_step(one_chip, "ragged_mixed_step", args,
                                        max_row_tokens=256)

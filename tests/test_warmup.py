@@ -13,7 +13,6 @@ import numpy as np
 from finchat_tpu.engine.engine import (
     InferenceEngine,
     commit_first_token,
-    decode_loop_step,
     decode_step,
     prefill_step,
     ragged_mixed_step,
@@ -24,11 +23,11 @@ from finchat_tpu.models.llama import PRESETS, init_params
 from finchat_tpu.utils.config import EngineConfig
 
 
-def _tiny_engine(max_seqs=2, spec_tokens=0, decode_loop_depth=1):
+def _tiny_engine(max_seqs=2, spec_tokens=0):
     config = PRESETS["tiny"]
     engine_cfg = EngineConfig(
         max_seqs=max_seqs, page_size=8, num_pages=32, max_seq_len=64, prefill_chunk=8,
-        spec_tokens=spec_tokens, decode_loop_depth=decode_loop_depth,
+        spec_tokens=spec_tokens,
     )
     params = init_params(config, jax.random.key(0))
     return InferenceEngine(config, params, engine_cfg, attn_backend="ref")
@@ -95,42 +94,15 @@ def test_warmup_covers_spec_verify_variants():
     assert verify_step._cache_size() == before, "first verify step recompiled"
 
 
-def test_warmup_covers_decode_loop_variant():
-    """With decode_loop_depth > 1 the scheduler's fused K-token block
-    (decode_loop_step) must be compiled at startup — and the eos_id being a
-    runtime scalar (not a jit cache key) means one warmed variant covers
-    every eos value the scheduler can pass."""
-    eng = _tiny_engine(decode_loop_depth=4)
-    eng.warmup()
-    before = decode_loop_step._cache_size()
-
-    B = eng.engine_cfg.max_seqs
-    active = jnp.zeros((B,), bool).at[0].set(True)
-    zeros, ones, zk = jnp.zeros((B,)), jnp.ones((B,)), jnp.zeros((B,), jnp.int32)
-    alloc = PageAllocator(eng.engine_cfg.num_pages)
-    # 3 prompt tokens + one block of 4 appends
-    pages = alloc.allocate("s", pages_needed(3 + 4, eng.page_size))
-    eng.set_page_table_row(0, pages)
-    eng.prefill(0, [3, 7, 11])
-    eng.decode_loop(active, zeros, ones, zk, eos_id=-1)
-    eng.decode_loop(active, zeros, ones, zk, eos_id=7)  # different eos id
-
-    assert decode_loop_step._cache_size() == before, "first block recompiled"
-    # state-neutrality of the warmup block itself is covered by
-    # test_warmup_is_state_neutral running depth 1; check the depth>1 path
-    eng2 = _tiny_engine(decode_loop_depth=4)
-    eng2.warmup()
-    assert np.asarray(eng2.state.context_lens).tolist() == [0, 0]
-    assert np.asarray(eng2.state.page_table).sum() == 0
-
-
 def test_warmup_covers_ragged_step_variants():
     """With mixed_step on (the default) every packed-token bucket of the
     scheduler's unified ragged dispatch must be compiled at startup — the
     first admission-during-decode must not compile. One bucket axis
-    replaces PR 4's row-bucket x chunk-bucket matrix, and spec/loop/
-    constrained rows reuse the same variants (ISSUE 10)."""
-    eng = _tiny_engine(spec_tokens=2, decode_loop_depth=3)
+    replaces PR 4's row-bucket x chunk-bucket matrix, and spec/
+    constrained rows reuse the same variants (ISSUE 10). The round is
+    called as the benchmark calls it (``ragged_mixed``: sixteen arguments,
+    four values — perfbench/correct.py; ROADMAP D9 (l))."""
+    eng = _tiny_engine(spec_tokens=2)
     eng.warmup()
     before = ragged_mixed_step._cache_size()
     assert before > 0, "warmup compiled no ragged variants"
@@ -139,14 +111,14 @@ def test_warmup_covers_ragged_step_variants():
     B = eng.engine_cfg.max_seqs  # == 2: row 0 prefill, row 1 spec decode
     R = B
     zB = jnp.zeros((B,), jnp.float32)
-    loop_active = jnp.zeros((B,), bool).at[1].set(True)
+    no_tail = jnp.zeros((B,), bool)
     for t in eng.ragged_token_buckets():
         # a serving-shaped round: a 3-token prefill row plus a spec verify
-        # row with one draft riding a loop tail slot — every feature mix
-        # reuses the SAME compiled variant as the all-padding warmup shape
+        # row with one draft — every feature mix reuses the SAME compiled
+        # variant as the all-padding warmup shape
         toks = [5, 6, 7, 0, 9] + [0] * (t - 5)
         tok_row = [0, 0, 0, 1, 1] + [R] * (t - 5)
-        eng.ragged_mixed(
+        _emitted, _n, row_logits, fourth = eng.ragged_mixed(
             jnp.asarray(toks, jnp.int32), jnp.asarray(tok_row, jnp.int32),
             jnp.asarray([0, 1], jnp.int32),  # row slots
             jnp.zeros((R,), jnp.int32),  # row_start
@@ -156,9 +128,10 @@ def test_warmup_covers_ragged_step_variants():
             jnp.asarray([0, 1], jnp.int32),  # n_drafts
             jnp.zeros((R,), jnp.float32), jnp.ones((R,), jnp.float32),
             jnp.zeros((R,), jnp.int32),
-            loop_active, zB, jnp.ones((B,), jnp.float32),
+            no_tail, zB, jnp.ones((B,), jnp.float32),
             jnp.zeros((B,), jnp.int32), -1,
         )
+        assert fourth is None and row_logits.shape == (R, eng.config.vocab_size)
     assert ragged_mixed_step._cache_size() == before, (
         "first ragged dispatch recompiled")
     # state-neutrality with the ragged variants included
@@ -166,51 +139,6 @@ def test_warmup_covers_ragged_step_variants():
     eng2.warmup()
     assert np.asarray(eng2.state.context_lens).tolist() == [0, 0]
     assert np.asarray(eng2.state.page_table).sum() == 0
-
-
-def test_warmup_covers_freerun_capture_variants():
-    """With freerun_rounds > 1 the captured multi-round program
-    (ragged_multi_round) is warmed for every packed-token bucket — the
-    first free-run capture on the serving path must not compile (one
-    extra bucket axis at the fixed rounds depth, ISSUE 13)."""
-    from finchat_tpu.engine.engine import ragged_multi_round
-
-    config = PRESETS["tiny"]
-    engine_cfg = EngineConfig(
-        max_seqs=2, page_size=8, num_pages=32, max_seq_len=64,
-        prefill_chunk=8, decode_loop_depth=2, freerun_rounds=3,
-    )
-    params = init_params(config, jax.random.key(0))
-    eng = InferenceEngine(config, params, engine_cfg, attn_backend="ref")
-    eng.warmup()
-    before = ragged_multi_round._cache_size()
-    assert before > 0, "warmup compiled no freerun variants"
-
-    B = R = 2
-    F = 3
-    zB = jnp.zeros((B,), jnp.float32)
-    for t in eng.ragged_token_buckets():
-        # a serving-shaped capture: one decode row riding a fused tail
-        # every round — reuses the all-padding warmup variant
-        tok_row = np.full((F, t), R, np.int32)
-        tok_row[:, 0] = 0
-        ones = np.ones((F, R), np.int32)
-        ones[:, 1] = 0
-        live = np.zeros((F, R), bool)
-        live[:, 0] = True
-        loop = np.zeros((F, B), bool)
-        loop[:, 0] = True
-        eng.ragged_multi(
-            jnp.zeros((F, t), jnp.int32), jnp.asarray(tok_row),
-            jnp.asarray([0, 1], jnp.int32), jnp.zeros((F, R), jnp.int32),
-            jnp.asarray(ones), jnp.asarray(live), jnp.asarray(live),
-            jnp.zeros((R,), jnp.float32), jnp.ones((R,), jnp.float32),
-            jnp.zeros((R,), jnp.int32),
-            jnp.asarray(loop), zB, jnp.ones((B,), jnp.float32),
-            jnp.zeros((B,), jnp.int32), -1,
-        )
-    assert ragged_multi_round._cache_size() == before, (
-        "first freerun capture recompiled")
 
 
 def test_ragged_bucket_matrix_collapsed():
