@@ -74,6 +74,12 @@ NOT_CARRIED: dict[str, dict[str, str]] = {
         "engine.kv_sink_pages / engine.kv_window_pages": "they re-lay a row's pages under the indexer's selection",
         "mesh.* > 1": "the latent pool has no sharding rule",
     },
+    # config.window: sliding-window layers' pages in a second pool, a bounded
+    # page list a row kept by the host (kv_cache.WindowPager)
+    "window pages": {
+        "engine.spec_tokens": "verify_step walks one pool, and a rejected draft rewinds a row",
+        "mesh.* > 1": "the window pool has no sharding rule",
+    },
 }
 
 
@@ -223,12 +229,17 @@ def _forward_cached(params, state: DecodeState, tokens: Array, positions: Array,
         v_scales=v_scales, ssm_state=ssm[0], conv_state=ssm[1], **window), *count
 
 
-def _attention_by_kind(full, window):
-    """The attention callback of a model with a ``layer_plan``
-    (``sambay.attention``): the FULL layer writes and reads its pool through
-    ``full``, a CROSS layer reads it there and writes nothing (``k`` None), a
-    WINDOW layer goes through ``window`` over the second pool and the slots'
-    bounded page lists. The scopes are what the capture's readers look for."""
+def _attention_by_kind(full, window, config: LlamaConfig):
+    """The attention callback of a model with sliding-window layers — a
+    ``layer_plan``'s (``sambay.attention``) or a ``layer_pattern``'s
+    (``llama._layer``): a FULL layer writes and reads its pool through
+    ``full``, a plan's CROSS layer reads it there and writes nothing (``k``
+    None), a WINDOW layer goes through ``window`` over the second pool and the
+    slots' bounded page lists. The scopes are what the capture's readers look
+    for: ``swa_attention`` around a window layer; ``yoco_attention`` around the
+    ONE cache a plan's full and cross layers walk, while a pattern's full
+    layers, each walking pages of its own, stay under ``full``'s own scopes
+    (``paged_attention``), as every model's without window layers."""
     from finchat_tpu.models.sambay import WINDOW
 
     def attention(q: Array, k: Array | None, v: Array | None, cache: Any, layer_idx: Array,
@@ -237,9 +248,11 @@ def _attention_by_kind(full, window):
         if kind == WINDOW:
             with jax.named_scope("swa_attention"):
                 out, win_pool = window(q, k, v, win_pool, layer_idx)
-        else:
+        elif config.layer_plan:
             with jax.named_scope("yoco_attention"):
                 out, pool = full(q, k, v, pool, layer_idx)
+        else:
+            out, pool = full(q, k, v, pool, layer_idx)
         return out, (pool, win_pool)
 
     return attention
@@ -470,7 +483,7 @@ def prefill_step(
         attention = _attention_by_kind(attention, _paged_attention_fn(
             state.win_table[slots], start_pos - state.win_gaps[slots], n_valid,
             page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
-            window=config.window))
+            window=config.window), config)
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
     # 64 x 128 x 128256 (an 8B model) — vs 33 MB for [N, vocab]
@@ -784,7 +797,7 @@ def decode_step(
         attention = _attention_by_kind(attention, _paged_attention_fn(
             state.win_table, state.context_lens - state.win_gaps, n_valid,
             page_size, config.n_kv_heads, attn_backend, decode=True,
-            scale=config.attention_scale, window=config.window))
+            scale=config.attention_scale, window=config.window), config)
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
     # pass over the layer's state, on `ref` a slice, _step and an update)
@@ -1009,7 +1022,7 @@ def ragged_mixed_step(
         attention = _attention_by_kind(attention, _ragged_attention_fn(
             state.win_table[row_slot], tok_row, tok_pos, row_kv_len, tok_valid,
             page_size, config.n_kv_heads, attn_backend, row_gap=state.win_gaps[row_slot],
-            scale=config.attention_scale, window=config.window, block_q=block_q))
+            scale=config.attention_scale, window=config.window, block_q=block_q), config)
     ssm_rows = packed_rows() if config.has_state else None
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes
@@ -1307,6 +1320,8 @@ class InferenceEngine:
         combine. The window's pages must also be whole, and a chunk must fit
         beside the window in a row's bound."""
         cfg, W = self.engine_cfg, self.config.window
+        from finchat_tpu.engine.kv_cache import window_pages_per_row
+
         if self.bounded_kv is not None:
             raise ValueError(
                 f"engine.kv_sink_pages / engine.kv_window_pages bound a row's pages in EVERY "
@@ -1319,7 +1334,7 @@ class InferenceEngine:
                 f"a model with sliding-window layers (window {W}) needs whole pages in the "
                 f"window (engine.page_size {cfg.page_size}) and a chunk of at most two pages "
                 f"(engine.prefill_chunk {cfg.prefill_chunk}): a row holds window / page_size + 2 "
-                "pages a window layer")
+                f"= {window_pages_per_row(W, cfg.page_size)} pages a window layer")
         if cfg.kv_quant:
             raise ValueError("engine.kv_quant has no sliding-window form: the int8 pages' "
                              "kernels take no window")
@@ -1392,7 +1407,8 @@ class InferenceEngine:
             "engine.kv_sink_pages / engine.kv_window_pages": self.bounded_kv is not None,
             "mesh.* > 1": mesh is not None and mesh.devices.size > 1,
         }
-        held = {"recurrent state": c.has_state, "latent pages": bool(c.kv_lora_rank)}
+        held = {"recurrent state": c.has_state, "latent pages": bool(c.kv_lora_rank),
+                "window pages": bool(c.window)}
         for kind, options in NOT_CARRIED.items():
             named = [f"{option} ({why})" for option, why in options.items() if on[option]]
             if held[kind] and named:
@@ -1442,11 +1458,12 @@ class InferenceEngine:
         registration of a head calls this (``scheduler._head_snapshot``);
         ``ssm_snapshot`` alone changes nothing."""
         snap = self.ssm_snapshot(slot)
-        if snap is None or self.window_pager is None:
+        if self.window_pager is None:
             return snap
         head = self.window_pager.detach_head(slot, int(self._host_ctx[slot]))
         self._window_upload()
-        return (*snap, head)
+        # (a model with window layers and no recurrent state: no copy, the pages alone)
+        return (*(snap or (None, None)), head)
 
     def release_snapshot(self, snap: tuple | None) -> None:
         """A head's snapshot (``detach_head``) is dropped: the window pages it
@@ -1459,10 +1476,11 @@ class InferenceEngine:
         """Start ``slot`` from a snapshot (admission from a shared head: its
         state copied in and, where the snapshot holds a head's window pages,
         those referenced in place of the slot's own)."""
-        ssm_state, conv_state = _ssm_load_slot(
-            self.state.ssm_state, self.state.conv_state, jnp.int32(slot), tuple(snap[:2]))
-        self.state = dataclasses.replace(
-            self.state, ssm_state=ssm_state, conv_state=conv_state)
+        if self.config.has_state:
+            ssm_state, conv_state = _ssm_load_slot(
+                self.state.ssm_state, self.state.conv_state, jnp.int32(slot), tuple(snap[:2]))
+            self.state = dataclasses.replace(
+                self.state, ssm_state=ssm_state, conv_state=conv_state)
         if self.window_pager is not None and len(snap) > 2:
             self.window_pager.release(slot)
             self.window_pager.share(slot, snap[2])
@@ -1476,7 +1494,8 @@ class InferenceEngine:
         state)."""
         cold = [slot for slot, snap in rows.items() if snap is None]
         if cold:
-            self._ssm_clear(cold)
+            if self.config.has_state:
+                self._ssm_clear(cold)
             if self.window_pager is not None:  # (a head's window pages, like its state)
                 for slot in cold:
                     self.window_pager.release(slot)
@@ -1992,7 +2011,7 @@ class InferenceEngine:
                         break
                     pb = min(pb * 2, top_pb)
         if (self.engine_cfg.session_cache and self.engine_cfg.session_cache_bytes > 0
-                and not self.config.has_state):
+                and not (self.config.has_state or self.config.window)):
             # the session tier's offload at a row's end is an eager take shaped
             # by its page count (kv_cache.gather_pages_host): every bucket once
             from finchat_tpu.engine.kv_cache import TRASH_PAGE, gather_bucket

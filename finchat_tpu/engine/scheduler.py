@@ -512,11 +512,13 @@ class ContinuousBatchingScheduler:
         # row admitted from a head starts from a copy; a partial match of a
         # head, a session resume and the warm fabric have no state to start
         # from, so those rows recompute from their tokens (counted) or the
-        # option is refused here
-        self.has_ssm = engine.config.has_state
+        # option is refused here. Sliding-window layers' pages are such memory
+        # too (a head keeps its trailing ones, kv_cache.WindowPager), with or
+        # without a state beside them
         # (a stand-in engine has no window layers and no limit on a chunk)
         self._prefill_room = getattr(engine, "prefill_room", lambda pos: 1 << 30)
         self._window = getattr(engine.config, "window", 0)
+        self.has_ssm = engine.config.has_state or bool(self._window)
         if self._window:
             self.metrics.inc("finchat_window_pages_freed_total", 0.0)
         self.metrics.set_gauge("finchat_ssm_state_bytes",
@@ -525,8 +527,9 @@ class ContinuousBatchingScheduler:
             if fabric is not None:
                 raise ValueError(
                     "fabric.path: the warm-state fabric's head and session records "
-                    "hold pages only; a model with recurrent state cannot resume "
-                    "from them (no recurrent state in the record)")
+                    "hold pages only; a model with recurrent state or sliding-window "
+                    "layers cannot resume from them (no recurrent state and no window "
+                    "pages in the record)")
             for kind in ("head", "session"):
                 self.metrics.inc("finchat_ssm_snapshots_total", 0.0,
                                  labels={"kind": kind})
@@ -594,9 +597,10 @@ class ContinuousBatchingScheduler:
         self._ssm_session_fallback = (
             self.has_ssm and cfg.session_cache and cfg.session_cache_bytes > 0)
         if self._ssm_session_fallback:
-            logger.info("session cache off: its entries hold no recurrent state "
-                        "(%d layers carry it); resumed turns recompute from their tokens",
-                        engine.config.n_state_layers)
+            logger.info("session cache off: its entries hold no recurrent state and no "
+                        "window pages (%d and %d layers carry them); resumed turns recompute "
+                        "from their tokens", engine.config.n_state_layers,
+                        getattr(engine.config, "n_window_layers", 0))
         elif cfg.session_cache and cfg.session_cache_bytes > 0:
             from finchat_tpu.engine.session_cache import (
                 SessionDiskTier,
@@ -885,7 +889,7 @@ class ContinuousBatchingScheduler:
             **({"index_form": self._index_form} if self._index_form else {}),
             # a model with sliding-window layers: the tokens ONE window layer
             # reads (a row's context up to the window)
-            **({"window_tokens": sum(min(kv, self._window) for *_row, kv in riders)}
+            **({"window_kv_tokens": sum(min(kv, self._window) for *_row, kv in riders)}
                if self._window else {}))
 
     @staticmethod
@@ -1042,9 +1046,9 @@ class ContinuousBatchingScheduler:
         return ids[pos : pos + min(C, self._prefill_room(pos))]
 
     def _head_snapshot(self, slot: int) -> tuple | None:
-        """The recurrent state ``slot`` holds after a head's last token, to
-        keep with the head's pages (None for a model without a mixer). Taken
-        before the slot is reset."""
+        """The recurrent state ``slot`` holds after a head's last token and
+        its trailing window pages, to keep with the head's pages (None for a
+        model with neither). Taken before the slot is reset."""
         if not self.has_ssm:
             return None
         self.metrics.inc("finchat_ssm_snapshots_total", labels={"kind": "head"})

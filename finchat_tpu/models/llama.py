@@ -38,12 +38,15 @@ AttentionFn = Callable[[Array, Array, Array, Any, Array], tuple[Array, Any]]
 # the kinds of layer a ``layer_pattern`` may name (the published configs' own
 # words): softmax attention over the paged cache, or the gated delta rule over
 # a recurrent state by slot (models/gdn.py), or the Mamba-2 mixer alone
-# (models/ssm.py) over its own state by slot. Each is followed by the MLP
+# (models/ssm.py) over its own state by slot, or softmax attention over a
+# window (``sliding_attention``: the same projections as a FULL layer's, pages
+# in a second pool on a bounded page list a row). Each is followed by the MLP
 FULL, LINEAR, MAMBA = "full_attention", "linear_attention", "mamba"
-# the kinds only a ``layer_plan`` may name (models/sambay.py): attention over a
-# window with a bounded page list of its own, the Mamba-1 mixer, a gated memory
+WINDOW = sambay.WINDOW
+# the kinds only a ``layer_plan`` may name (models/sambay.py, which has a
+# block of its own for WINDOW layers too): the Mamba-1 mixer, a gated memory
 # unit (no state, no cache) and attention that reads another layer's pages
-WINDOW, MAMBA1, GMU, CROSS = sambay.WINDOW, sambay.MAMBA1, sambay.GMU, sambay.CROSS
+MAMBA1, GMU, CROSS = sambay.MAMBA1, sambay.GMU, sambay.CROSS
 
 # moe_mlp's one rule among its THREE forms (see there; ``_moe_form``). Dense
 # dispatch computes every held expert over every token: router width / picks
@@ -72,6 +75,10 @@ class LlamaConfig:
     n_kv_heads: int = 2
     hidden_dim: int = 256
     rope_theta: float | None = 10_000.0  # None = q and k are not rotated
+    # the kinds of layer whose q and k are rotated (a ``layer_pattern``'s
+    # words); empty = every attention layer. (WINDOW,): window layers rotated,
+    # full layers not
+    rope_kinds: tuple[str, ...] = ()
     norm_eps: float = 1e-5
     max_seq_len: int = 2048
     dtype: Any = jnp.bfloat16
@@ -125,14 +132,28 @@ class LlamaConfig:
     # layers of more than one kind: the kinds of ONE period, repeated down the
     # depth (Olmo-Hybrid: three LINEAR, then one FULL; Granite-4.0-H: five
     # MAMBA, one FULL, four MAMBA). Empty = every layer alike, today's block.
-    # Parameters and caches are stacked by KIND: only FULL layers own pages,
-    # only LINEAR or MAMBA ones (or the mixer in every layer, above) state
+    # Parameters and caches are stacked by KIND: FULL layers own pages of the
+    # first pool and WINDOW layers (``window``, below) of the second, both kinds
+    # share the ``attn_*`` stacks; only LINEAR or MAMBA layers (or the mixer in
+    # every layer, above) own state
     layer_pattern: tuple[str, ...] = ()
     # RMSNorm over the whole width of q and of k, before the heads are split
     qk_norm: bool = False
+    # RMSNorm over each HEAD of q and of k, after the split: one weight vector
+    # of ``head_dim`` for q and one for k (``attn_q_norm`` / ``attn_k_norm``
+    # [L, head_dim]); not both this and ``qk_norm``
+    qk_head_norm: bool = False
+    # a sigmoid gate on attention's output before its projection, from a
+    # projection of the layer's normed input (``attn_gate`` [L, D, H * hd]):
+    # a = W_o (o * sigmoid(W_g h))
+    attn_gate: bool = False
     # the Olmo family's placement: a norm on each sub-block's OUTPUT and none
     # on its input, x = x + Norm(f(x))
     norm_after: bool = False
+    # a norm on each sub-block's input AND its output, x = x + Norm_out(f(
+    # Norm_in(x))): ``ln_attn_out`` / ``ln_mlp_out`` beside ``ln_attn`` /
+    # ``ln_mlp``; not both this and ``norm_after``
+    norm_both: bool = False
     # the LINEAR layers' gated delta rule (models/gdn.py); gdn_heads 0 = none
     gdn_heads: int = 0
     gdn_key_dim: int = 0  # a head's keys and queries
@@ -173,11 +194,21 @@ class LlamaConfig:
     moe_topk_groups: int = 0
     moe_gate_scale: float = 1.0
     moe_norm_picks: bool = True
+    # the seeded selection bias's standard deviation (``init_params``; a
+    # checkpoint brings its own): it trains from zero to BALANCE the experts'
+    # load, so it is drawn small against the scores' spread (a sigmoid of a
+    # unit-normal logit spreads by 0.21) — large enough to move picks, small
+    # enough not to herd every row onto the same experts
+    moe_bias_init_std: float = 0.1
     # dense layers in front of the routed ones (an MLP of ``dense_hidden_dim``
     # in the experts' place): stacks of their own, ``params["dense_layers"]``,
-    # run before the scan; ``n_layers`` counts them
+    # run before the scan; ``n_layers`` counts them and a ``layer_pattern``
+    # covers the layers behind them. ``leading_kinds`` names each one's kind of
+    # attention (FULL or WINDOW; empty = every one FULL): its pages come first
+    # in its kind's pool
     leading_dense_layers: int = 0
     dense_hidden_dim: int = 0
+    leading_kinds: tuple[str, ...] = ()
     # layers of more than one kind in more than one RUN of periods: segments
     # of (the kinds of one period, its repeats), one after another down the
     # depth — 8 x (mamba1, sliding_attention), 1 x (mamba1, full_attention),
@@ -187,7 +218,8 @@ class LlamaConfig:
     # caches are stacked by kind as under a pattern; WINDOW layers own a second
     # pool and a bounded page list a row, CROSS layers own nothing
     layer_plan: tuple[tuple[tuple[str, ...], int], ...] = ()
-    # a WINDOW layer attends the token itself and the ``window - 1`` before it
+    # a WINDOW layer (a plan's or a pattern's) attends the token itself and the
+    # ``window - 1`` before it; set where the model has such layers, and only there
     window: int = 0
     # the MAMBA1 layers' mixer: channels E, state channels a channel N, the
     # rank dt passes through, the causal depthwise conv's width
@@ -205,14 +237,32 @@ class LlamaConfig:
         if self.layer_plan:
             if (self.layer_pattern or self.ssm_heads or self.gdn_heads or self.kv_lora_rank
                     or self.n_experts or self.leading_dense_layers or self.qk_norm
-                    or self.norm_after):
+                    or self.norm_after or self.qk_head_norm or self.attn_gate
+                    or self.norm_both or self.rope_kinds):
                 raise ValueError("a layer_plan is not combined with a layer_pattern, a Mamba-2 "
-                                 "mixer, linear or latent attention, experts, qk_norm or "
-                                 "norm_after")
+                                 "mixer, linear or latent attention, experts, a q/k norm, an "
+                                 "output gate, rope_kinds, norm_after or norm_both")
             sambay.validate(self)
-        elif self.window or self.m1_inner:
-            raise ValueError(f"window and m1_inner are a layer_plan's ({WINDOW!r}, {MAMBA1!r}, "
-                             f"{GMU!r} and {CROSS!r} layers)")
+        elif self.m1_inner:
+            raise ValueError(f"m1_inner and {MAMBA1!r}, {GMU!r} and {CROSS!r} layers are a "
+                             "layer_plan's")
+        elif bool(self.window) != (WINDOW in self.layer_pattern + self.leading_kinds):
+            raise ValueError(f"window and {WINDOW!r} layers (in layer_pattern or leading_kinds; "
+                             f"{MAMBA1!r}, {GMU!r} and {CROSS!r} are a layer_plan's) go together")
+        if self.window and not self.layer_plan and (
+                self.ssm_heads or self.gdn_heads or self.kv_lora_rank):
+            raise ValueError(f"{WINDOW!r} layers of a layer_pattern stand beside {FULL!r} layers "
+                             "alone: no mixer, linear or latent attention (a row's snapshot "
+                             "would hold state and window pages of two blocks)")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm (over the whole width) and qk_head_norm (over each head): "
+                             "one or the other")
+        if self.norm_after and self.norm_both:
+            raise ValueError("norm_after (output alone) and norm_both (input and output): one "
+                             "or the other")
+        if set(self.rope_kinds) - {FULL, WINDOW} or (self.rope_kinds and self.rope_theta is None):
+            raise ValueError(f"rope_kinds names the rotated kinds among {FULL!r} and {WINDOW!r}, "
+                             "and comes with a rope_theta")
         if self.kv_lora_rank:
             if self.head_dim != self.qk_nope_dim + self.qk_rope_dim or self.n_kv_heads != 1:
                 raise ValueError(
@@ -221,9 +271,10 @@ class LlamaConfig:
             if not (self.q_lora_rank and self.v_head_dim and self.rope_theta is not None):
                 raise ValueError("latent attention comes with q_lora_rank, v_head_dim and a "
                                  "rotation (rope_theta)")
-            if self.layer_pattern or self.ssm_heads or self.qk_norm or self.norm_after:
+            if (self.layer_pattern or self.ssm_heads or self.qk_norm or self.norm_after
+                    or self.qk_head_norm or self.attn_gate or self.norm_both):
                 raise ValueError("latent attention is not combined with a layer_pattern, a "
-                                 "mixer, qk_norm or norm_after")
+                                 "mixer, a q/k norm, an output gate, norm_after or norm_both")
         if bool(self.index_topk) != bool(self.index_heads and self.index_head_dim):
             raise ValueError("index_topk, index_heads and index_head_dim go together")
         if self.index_topk and not self.kv_lora_rank:
@@ -240,17 +291,23 @@ class LlamaConfig:
             raise ValueError("moe_groups divides the router's width, and moe_topk_groups of "
                              "them are kept")
         if self.leading_dense_layers and (
-                self.layer_pattern or not self.dense_hidden_dim or not self.n_experts
+                not self.dense_hidden_dim or not self.n_experts
                 or self.leading_dense_layers >= self.n_layers):
             raise ValueError("leading_dense_layers: dense layers of dense_hidden_dim in front of "
-                             "routed ones, all of one kind (no layer_pattern)")
+                             "routed ones")
+        if self.leading_kinds and (
+                len(self.leading_kinds) != self.leading_dense_layers
+                or set(self.leading_kinds) - {FULL, WINDOW}):
+            raise ValueError(f"leading_kinds names each leading dense layer {FULL!r} or "
+                             f"{WINDOW!r}")
         pattern = self.layer_pattern
         if pattern:
-            if set(pattern) - {FULL, LINEAR, MAMBA} or self.n_layers % len(pattern):
+            if set(pattern) - {FULL, LINEAR, MAMBA, WINDOW} or self.n_scan_layers % len(pattern):
                 raise ValueError(
-                    f"layer_pattern {pattern}: kinds are {FULL!r}, {LINEAR!r} and {MAMBA!r} "
-                    f"({WINDOW!r}, {MAMBA1!r}, {GMU!r} and {CROSS!r} are a layer_plan's), "
-                    f"and n_layers ({self.n_layers}) is a whole number of periods")
+                    f"layer_pattern {pattern}: kinds are {FULL!r}, {LINEAR!r}, {MAMBA!r} and "
+                    f"{WINDOW!r} ({MAMBA1!r}, {GMU!r} and {CROSS!r} are a layer_plan's), and the "
+                    f"{self.n_scan_layers} layers behind the leading dense ones are a whole "
+                    "number of periods")
             if (MAMBA in pattern) != bool(self.ssm_heads):
                 raise ValueError(f"under a layer_pattern, ssm_heads and {MAMBA!r} layers go "
                                  "together (the mixer beside attention in EVERY layer is "
@@ -275,9 +332,20 @@ class LlamaConfig:
         """Layers of ``kind``: the depth of that kind's stacks."""
         if self.layer_plan:
             return sambay.kinds_of(self.layer_plan).count(kind)
-        if not self.layer_pattern:
-            return self.n_layers if kind == FULL else 0
-        return self.layer_pattern.count(kind) * (self.n_layers // len(self.layer_pattern))
+        pattern = self.layer_pattern or (FULL,)
+        return self.n_leading_of(kind) + pattern.count(kind) * (
+            self.n_scan_layers // len(pattern))
+
+    @property
+    def kinds_of_leading(self) -> tuple[str, ...]:
+        """The leading dense layers' kinds, in order (``leading_kinds``, or
+        every one FULL)."""
+        return self.leading_kinds or (FULL,) * self.leading_dense_layers
+
+    def n_leading_of(self, kind: str) -> int:
+        """Leading dense layers of ``kind``: their pages come first in that
+        kind's pool."""
+        return self.kinds_of_leading.count(kind)
 
     @property
     def n_attn_layers(self) -> int:
@@ -294,6 +362,12 @@ class LlamaConfig:
     def n_window_layers(self) -> int:
         """Layers that own WINDOW pages: the depth of the second pool."""
         return self.n_of(WINDOW)
+
+    @property
+    def n_kv_layers(self) -> int:
+        """Layers with attention projections of their own (the ``attn_*``
+        stacks of a model without a plan): the FULL and the WINDOW ones."""
+        return self.n_of(FULL) + self.n_of(WINDOW)
 
     @property
     def n_scan_layers(self) -> int:
@@ -422,6 +496,36 @@ PRESETS: dict[str, LlamaConfig] = {
         hidden_dim=14_336, rope_theta=1_000_000.0, max_seq_len=8192,
         n_experts=8, top_k_experts=2,
     ),
+    # Trinity-Mini (arcee-ai, ``afmoe``): window layers (rotated) three to one
+    # full layer (not rotated) as kinds of the pattern, a q/k norm a head, a
+    # gated attention output, norms on a sub-block's input and output; two
+    # leading dense window layers, then 128 routed experts of 1,024 at 8 a
+    # token (sigmoid scores, a selection bias, one group) beside a shared one.
+    # The 30 layers behind the leading two start inside a period, so the
+    # pattern is their kinds spelled out, one period
+    "trinity-mini": LlamaConfig(
+        vocab_size=200_192, dim=2048, n_layers=32, n_heads=32, n_kv_heads=4, head_dim=128,
+        hidden_dim=1024, rope_theta=10_000.0, rope_kinds=(WINDOW,), max_seq_len=16_384,
+        embedding_multiplier=2048 ** 0.5, qk_head_norm=True, attn_gate=True, norm_both=True,
+        n_experts=128, top_k_experts=8, moe_shared_dim=1024, moe_fused_glu=True,
+        moe_score="sigmoid", moe_select_bias=True, moe_gate_scale=2.826,
+        moe_bias_init_std=0.02, leading_dense_layers=2, dense_hidden_dim=6144,
+        leading_kinds=(WINDOW, WINDOW),
+        layer_pattern=(((WINDOW,) * 3 + (FULL,)) * 8)[2:], window=2048,
+    ),
+    # the same block at a size a test holds (tests/tiny_models.py builds it from
+    # the published keys): ONE leading dense window layer, two periods, a window
+    # of 8 tokens, 32 experts at 4 a token
+    "trinity-tiny": LlamaConfig(
+        vocab_size=211, dim=64, n_layers=9, n_heads=8, n_kv_heads=2, head_dim=16,
+        hidden_dim=32, rope_theta=10_000.0, rope_kinds=(WINDOW,), max_seq_len=256,
+        embedding_multiplier=8.0, qk_head_norm=True, attn_gate=True, norm_both=True,
+        n_experts=32, top_k_experts=4, moe_shared_dim=32, moe_fused_glu=True,
+        moe_score="sigmoid", moe_select_bias=True, moe_gate_scale=2.826,
+        moe_bias_init_std=0.02, leading_dense_layers=1, dense_hidden_dim=96,
+        leading_kinds=(WINDOW,),
+        layer_pattern=(WINDOW, WINDOW, WINDOW, FULL), window=8,
+    ),
 }
 
 
@@ -443,7 +547,12 @@ def n_params(config: LlamaConfig) -> int:
                + 3 * d * c.moe_shared_dim)
     if c.qk_norm:
         attn += (c.n_heads + c.n_kv_heads) * hd
-    per_layer = mlp + 2 * d
+    if c.qk_head_norm:
+        attn += 2 * hd
+    if c.attn_gate:
+        attn += d * c.n_heads * hd
+    norms = 4 * d if c.norm_both else 2 * d
+    per_layer = mlp + norms
     # in/out projections, conv weight and bias, A_log, dt_bias, D, the gated
     # norm's weight: in every layer, or in the MAMBA layers of a pattern
     ssm = (d * c.ssm_in_dim + c.d_ssm * d
@@ -454,8 +563,8 @@ def n_params(config: LlamaConfig) -> int:
     d_v = c.gdn_heads * c.gdn_value_dim
     linear = (d * (c.gdn_conv_dim + d_v + 2 * c.gdn_heads) + d_v * d
               + c.gdn_conv * c.gdn_conv_dim + 2 * c.gdn_heads + c.gdn_value_dim)
-    total = (c.vocab_size * d + c.n_scan_layers * per_layer + c.n_attn_layers * attn
-             + c.leading_dense_layers * (3 * d * c.dense_hidden_dim + 2 * d)
+    total = (c.vocab_size * d + c.n_scan_layers * per_layer + c.n_kv_layers * attn
+             + c.leading_dense_layers * (3 * d * c.dense_hidden_dim + norms)
              + c.n_of(LINEAR) * linear + c.n_of(MAMBA) * ssm + d)
     if not c.tie_embeddings:
         total += d * c.vocab_size
@@ -496,10 +605,12 @@ def init_params(
       layers/ssm_{in,out,conv_w,conv_b,A_log,dt_bias,D,norm}[L, ...] (with
       ``ssm_heads``; the recurrence's own A_log, dt_bias, D stay float32)
       norm[dim], lm_head[dim, vocab] (absent when tie_embeddings)
-    With a ``layer_pattern`` the stacks are by KIND (``_stack_kind``): the
-    ``attn_*`` leaves (``attn_{q,k}_norm`` with ``qk_norm``) have the FULL
-    layers' depth, the ``gdn_*`` leaves the LINEAR layers', the MLP and the
-    two norms every layer's; without one this is the tree it always was.
+    With a ``layer_pattern`` the stacks are by KIND (``_stack_kinds``): the
+    ``attn_*`` leaves (``attn_{q,k}_norm`` with ``qk_norm`` or
+    ``qk_head_norm``, ``attn_gate`` with ``attn_gate``) have the FULL and
+    WINDOW layers' depth, the ``gdn_*`` leaves the LINEAR layers', the MLP and
+    the norms (``ln_{attn,mlp}_out`` too with ``norm_both``) every layer's;
+    without one this is the tree it always was.
 
     ``leaf_transform(name, array)`` is applied to each MATMUL weight at
     creation, before the next leaf materializes — so e.g. int8 quantization
@@ -533,25 +644,28 @@ def init_params(
         return params
     keys = jax.random.split(k_layers, 8)
     L, D, H, Hkv, hd, F = c.n_scan_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
-    La = c.n_attn_layers - c.leading_dense_layers
+    La = c.n_kv_layers - c.leading_dense_layers
 
     def attention_leaves(depth: int, ks: Array) -> dict[str, Array]:
         if c.kv_lora_rank:
             return mla.init_attention(c, ks[0], depth, rand_init)
+        gate = {"attn_gate": rand_init("attn_gate", jax.random.fold_in(ks[3], 1),
+                                       (depth, D, H * hd), D)} if c.attn_gate else {}
         return {
             "attn_q": rand_init("attn_q", ks[0], (depth, D, H * hd), D),
             "attn_k": rand_init("attn_k", ks[1], (depth, D, Hkv * hd), D),
             "attn_v": rand_init("attn_v", ks[2], (depth, D, Hkv * hd), D),
             "attn_o": rand_init("attn_o", ks[3], (depth, H * hd, D), H * hd),
+            **gate,
         }
+
+    def norm_leaves(depth: int) -> dict[str, Array]:
+        names = ("ln_attn", "ln_mlp") + (("ln_attn_out", "ln_mlp_out") if c.norm_both else ())
+        return {name: jnp.ones((depth, D), c.dtype) for name in names}
 
     params: dict[str, Any] = {
         "embed": rand_init("embed", k_embed, (c.vocab_size, D), D),
-        "layers": {
-            **attention_leaves(La, keys),
-            "ln_attn": jnp.ones((L, D), c.dtype),
-            "ln_mlp": jnp.ones((L, D), c.dtype),
-        },
+        "layers": {**attention_leaves(La, keys), **norm_leaves(L)},
         "norm": jnp.ones((D,), c.dtype),
     }
     if c.leading_dense_layers:
@@ -560,8 +674,7 @@ def init_params(
         kd = jax.random.split(jax.random.fold_in(k_layers, 3), 7)
         params["dense_layers"] = {
             **attention_leaves(Ld, kd),
-            "ln_attn": jnp.ones((Ld, D), c.dtype),
-            "ln_mlp": jnp.ones((Ld, D), c.dtype),
+            **norm_leaves(Ld),
             "mlp_gate": rand_init("mlp_gate", kd[4], (Ld, D, Fd), D),
             "mlp_up": rand_init("mlp_up", kd[5], (Ld, D, Fd), D),
             "mlp_down": rand_init("mlp_down", kd[6], (Ld, Fd, D), Fd),
@@ -578,7 +691,7 @@ def init_params(
         )
         if c.moe_select_bias:
             # trained from zero; drawn so that it moves picks
-            params["layers"]["router_bias"] = 0.1 * jax.random.normal(
+            params["layers"]["router_bias"] = c.moe_bias_init_std * jax.random.normal(
                 jax.random.fold_in(keys[7], 1), (L, c.moe_router_width), jnp.float32)
         if Fs:
             ks = jax.random.split(keys[5])
@@ -631,11 +744,12 @@ def init_params(
                 "ssm_norm": jnp.ones((Lm, c.d_ssm), c.dtype),
             }
         )
-    if c.qk_norm:
+    if c.qk_norm or c.qk_head_norm:
+        q_width, k_width = (hd, hd) if c.qk_head_norm else (H * hd, Hkv * hd)
         for stack, depth in (("layers", La), ("dense_layers", c.leading_dense_layers)):
             if depth:
-                params[stack].update({"attn_q_norm": jnp.ones((depth, H * hd), c.dtype),
-                                      "attn_k_norm": jnp.ones((depth, Hkv * hd), c.dtype)})
+                params[stack].update({"attn_q_norm": jnp.ones((depth, q_width), c.dtype),
+                                      "attn_k_norm": jnp.ones((depth, k_width), c.dtype)})
     if c.gdn_heads:
         params["layers"].update(gdn.init_params(
             c, jax.random.fold_in(k_layers, 2), c.n_of(LINEAR), rand_init))
@@ -644,14 +758,14 @@ def init_params(
     return params
 
 
-def _stack_kind(name: str) -> str | None:
+def _stack_kinds(name: str) -> tuple[str, ...] | None:
     """Whose depth the stacked leaf ``name`` has under a ``layer_pattern``:
-    one kind's layers, or (None) every layer."""
+    the layers of these kinds, or (None) every layer."""
     if name.startswith("gdn_"):
-        return LINEAR
+        return (LINEAR,)
     if name.startswith("ssm_"):
-        return MAMBA
-    return FULL if name.startswith("attn_") else None
+        return (MAMBA,)
+    return (FULL, WINDOW) if name.startswith("attn_") else None
 
 
 @jax.named_scope("norm")
@@ -947,32 +1061,42 @@ def _layer(
     ``attention`` a ``LatentAttentionFn``; the context tokens the live queries
     attended to are then a third count beside the experts'. ``dense_mlp``: a
     leading dense layer of a model that routes (an MLP of
-    ``dense_hidden_dim`` in the experts' place; it counts no experts)."""
+    ``dense_hidden_dim`` in the experts' place; it counts no experts).
+
+    A WINDOW layer is a FULL layer whose callback masks the window and keeps
+    its pages in the second pool: a model with such layers hands ``attention``
+    the kind as a sixth argument. The attention sub-block's further pieces are
+    data of the config, each absent unless set: a q/k norm a head
+    (``qk_head_norm``), the rotation of some kinds only (``rope_kinds``), a
+    sigmoid gate on the output (``attn_gate``), a norm on a sub-block's input
+    AND output (``norm_both``)."""
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
     hkv = c.n_kv_heads // tp_size
 
-    def norm_in(x: Array, weight: Array) -> Array:
-        return x if c.norm_after else rms_norm(x, weight, c.norm_eps)
+    def norm_in(x: Array, name: str) -> Array:
+        return x if c.norm_after else rms_norm(x, layer_params[name], c.norm_eps)
 
-    def norm_out(y: Array, weight: Array) -> Array:
-        return rms_norm(y, weight, c.norm_eps) if c.norm_after else y
+    def norm_out(y: Array, name: str) -> Array:
+        if c.norm_both:
+            return rms_norm(y, layer_params[name + "_out"], c.norm_eps)
+        return rms_norm(y, layer_params[name], c.norm_eps) if c.norm_after else y
 
-    h = norm_in(x, layer_params["ln_attn"])
+    h = norm_in(x, "ln_attn")
     if kind == LINEAR:
         assert tp_axis is None, "manual-TP stage blocks have no linear-attention layers"
         mixed, ssm_cache = gdn.mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
                                      qm_backend=qm_backend)
         with jax.named_scope("gdn_out"):
-            x = x + norm_out(mixed, layer_params["ln_attn"])
+            x = x + norm_out(mixed, "ln_attn")
         new_layer_cache = layer_cache
     elif kind == MAMBA:
         assert tp_axis is None, "manual-TP stage blocks have no mixer"
         mixed, ssm_cache = mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
                                  qm_backend=qm_backend)
         with jax.named_scope("ssm_out"):
-            x = x + scaled(norm_out(mixed, layer_params["ln_attn"]), c.residual_multiplier)
+            x = x + scaled(norm_out(mixed, "ln_attn"), c.residual_multiplier)
         new_layer_cache = layer_cache
     elif c.kv_lora_rank:
         assert tp_axis is None, "manual-TP stage blocks have no latent attention"
@@ -995,7 +1119,10 @@ def _layer(
             def heads(t: Array, n: int, norm: str = "") -> Array:
                 if norm and c.qk_norm:  # over the whole width, before the split
                     t = rms_norm(t, layer_params[norm], c.norm_eps)
-                return t.reshape(B, S, n, c.head_dim)
+                t = t.reshape(B, S, n, c.head_dim)
+                if norm and c.qk_head_norm:  # over each head, one weight vector for all
+                    t = rms_norm(t, layer_params[norm], c.norm_eps)
+                return t
 
             # q and k fenced flat: the heads' layout must not reach the weights
             q = heads(flat_fence(dense(h, layer_params["attn_q"], qm_backend=qm_backend)),
@@ -1003,13 +1130,20 @@ def _layer(
             k = heads(scaled(flat_fence(dense(h, layer_params["attn_k"], qm_backend=qm_backend)),
                              c.key_multiplier), hkv, "attn_k_norm")
             v = heads(dense(h, layer_params["attn_v"], qm_backend=qm_backend), hkv)
-            if c.rope_theta is not None:
+            if c.rope_theta is not None and (not c.rope_kinds or kind in c.rope_kinds):
                 q = rope(q, positions, c.rope_theta)
                 k = rope(k, positions, c.rope_theta)
 
         # the attention callback opens its own scopes (engine/engine.py)
-        attn_out, new_layer_cache = attention(q, k, v, layer_cache, layer_idx)
+        attn_out, new_layer_cache = attention(
+            q, k, v, layer_cache, layer_idx, *((kind,) if c.window else ()))
         with jax.named_scope("attn_o"):
+            if c.attn_gate:
+                assert tp_axis is None, "manual-TP stage blocks have no output gate"
+                gate = jax.nn.sigmoid(dense(h, layer_params["attn_gate"],
+                                            qm_backend=qm_backend).astype(jnp.float32))
+                attn_out = (attn_out.reshape(B, S, -1).astype(jnp.float32)
+                            * gate).astype(attn_out.dtype)
             if tp_axis is not None:
                 from finchat_tpu.ops.tp_overlap import row_parallel_dense
 
@@ -1020,12 +1154,12 @@ def _layer(
             else:
                 attn_proj = dense(attn_out.reshape(B, S, -1), layer_params["attn_o"],
                                   qm_backend=qm_backend)
-            x = x + scaled(norm_out(scaled(attn_proj, c.attention_out_multiplier),
-                                    layer_params["ln_attn"]), c.residual_multiplier)
+            x = x + scaled(norm_out(scaled(attn_proj, c.attention_out_multiplier), "ln_attn"),
+                           c.residual_multiplier)
             if c.ssm_heads and not c.layer_pattern:
                 x = x + mixed
 
-    h = norm_in(x, layer_params["ln_mlp"])
+    h = norm_in(x, "ln_mlp")
     if c.n_experts and not dense_mlp:
         assert tp_axis is None, "manual-TP stage blocks are dense-only (PPxEP future work)"
         moe_out = moe_mlp(h, layer_params, c, qm_backend=qm_backend, live=moe_live,
@@ -1034,7 +1168,7 @@ def _layer(
             moe_out, experts = moe_out
         with jax.named_scope("moe_experts"):
             # the residual add fuses into the down matmul
-            x = x + scaled(norm_out(moe_out, layer_params["ln_mlp"]), c.residual_multiplier)
+            x = x + scaled(norm_out(moe_out, "ln_mlp"), c.residual_multiplier)
     else:
         with jax.named_scope("mlp"):
             gate = scaled(dense(h, layer_params["mlp_gate"], qm_backend=qm_backend),
@@ -1050,7 +1184,7 @@ def _layer(
                 )
             else:
                 down = dense(act, layer_params["mlp_down"], qm_backend=qm_backend)
-            x = x + scaled(norm_out(scaled(down, c.mlp_multipliers[1]), layer_params["ln_mlp"]),
+            x = x + scaled(norm_out(scaled(down, c.mlp_multipliers[1]), "ln_mlp"),
                            c.residual_multiplier)
     out = (x, new_layer_cache, ssm_cache) if c.has_state else (x, new_layer_cache)
     if moe_live is None:
@@ -1148,29 +1282,38 @@ def forward(
     # period is longer than one layer or the experts' stacks must stay whole
     # (below), are indexed out of the stacks inside it
     by_index = len(pattern) > 1 or c.moe_sparse
-    n_lead = c.leading_dense_layers
 
-    def pool_index(i):  # the leading layers' pages come first in the pool
+    def pool_index(i, kind):  # the leading layers' pages come first in their kind's pool
+        n_lead = c.n_leading_of(kind)
         return i + n_lead if n_lead else i
 
     def scan_body(carry, scanned):
         layer_params, period_idx = scanned
         if not by_index:
-            return one_layer(carry, layer_params, pool_index(period_idx), pattern[0]), None
+            return one_layer(carry, layer_params, pool_index(period_idx, pattern[0]),
+                             pattern[0]), None
         for j, kind in enumerate(pattern):
-            # the layer's index among its own kind (what the caches, stacked
-            # by kind, are indexed by) and down the whole depth
-            at = {kind: period_idx * pattern.count(kind) + pattern[:j].count(kind),
-                  None: period_idx * len(pattern) + j}
+            def among(kinds):
+                """The layer's index among the scan's layers of ``kinds`` (what
+                the stacks and the caches, both by kind, are indexed by; None:
+                down the whole depth), or None where it is of none of them."""
+                if kinds is None:
+                    return period_idx * len(pattern) + j
+                if kind not in kinds:
+                    return None
+                first, *rest = [period_idx * pattern.count(k) + pattern[:j].count(k)
+                                for k in kinds if k in pattern]
+                return sum(rest, first)
+
             # the grouped matmul and the touched pass take the experts' whole
             # stacks (StackedLeaf)
             whole = ("moe_in", "moe_out") if c.moe_sparse else ()
             layer_params = {
-                name: StackedLeaf(leaf, at[None]) if name in whole else jax.tree.map(
-                    lambda a, i=at[_stack_kind(name)]: lax.dynamic_index_in_dim(
+                name: StackedLeaf(leaf, among(None)) if name in whole else jax.tree.map(
+                    lambda a, i=among(_stack_kinds(name)): lax.dynamic_index_in_dim(
                         a, i, 0, keepdims=False), leaf)
-                for name, leaf in stacks.items() if _stack_kind(name) in at}
-            carry = one_layer(carry, layer_params, pool_index(at[kind]), kind)
+                for name, leaf in stacks.items() if among(_stack_kinds(name)) is not None}
+            carry = one_layer(carry, layer_params, pool_index(among((kind,)), kind), kind)
         return carry, None
 
     if remat:
@@ -1180,9 +1323,10 @@ def forward(
 
     carry = (x, cache, ssm_cache, None if moe_live is None else jnp.zeros(
         (3 if c.kv_lora_rank else 2,), jnp.int32))
-    for i in range(n_lead):
+    leading = c.kinds_of_leading
+    for i, kind in enumerate(leading):
         carry = one_layer(carry, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]),
-                          jnp.int32(i), FULL, dense_mlp=True)
+                          jnp.int32(leading[:i].count(kind)), kind, dense_mlp=True)
     (x, new_cache, ssm_cache, experts), _ = lax.scan(
         scan_body, carry, (None if by_index else stacks, jnp.arange(n_periods)))
     if ssm_cache is not None:
@@ -1298,16 +1442,16 @@ def make_causal_attention(backend: str, scale: float | None = None,
 
         return latent
 
-    if config is not None and config.layer_plan:
+    if config is not None and (config.layer_plan or config.window):
         from finchat_tpu.ops.refs import mha_reference
 
         def by_kind(q: Array, k: Array | None, v: Array | None, layer_cache: Any,
                     layer_idx: Array, kind: str = FULL):
-            # dense over the sequence: the FULL layer leaves its K and V in the
-            # cache's place, a CROSS layer reads them, a WINDOW layer masks
+            # dense over the sequence: a plan's FULL layer leaves its K and V in
+            # the cache's place, a CROSS layer reads them, a WINDOW layer masks
             if kind == CROSS:
                 k, v = layer_cache
-            elif kind == FULL:
+            elif kind == FULL and config.layer_plan:
                 layer_cache = (k, v)
             if kind == WINDOW:
                 return mha_reference(q, k, v, causal=True, scale=scale,

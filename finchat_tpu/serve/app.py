@@ -251,14 +251,17 @@ def make_engine_replica(
     prompt heads restore from / publish to the cluster-wide store."""
     config, params, tokenizer, mesh = artifacts
     # what moves a row between engines moves its pages by id: never a mixer's
-    # recurrent state (session, handoff and pod wire formats hold none), and a
+    # recurrent state or a window layer's pages (session, handoff and pod wire
+    # formats hold neither), and a
     # latent model's pages (a latent row and an index key a token) have been
     # carried by the RAM session tier alone. Refused by name rather than
     # served from a state of zero or through an unproven record (the
     # scheduler refuses the warm fabric, and a latent model's disk records)
     one_engine = (
         f"a model with recurrent state ({config.n_state_layers} layers)" if config.has_state
-        else "a model with latent attention (latent pages)" if config.kv_lora_rank else None)
+        else "a model with latent attention (latent pages)" if config.kv_lora_rank
+        else f"a model with sliding-window layers ({config.n_window_layers} layers' window pages)"
+        if config.window else None)
     if one_engine:
         from finchat_tpu.serve.disagg import parse_roles
 

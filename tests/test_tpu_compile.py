@@ -716,3 +716,79 @@ def test_decode_step_reads_every_projections_weight_where_it_lies(one_chip, name
         stack = jax.eval_shape(lambda: init_params(c, jax.random.key(0)))["layers"]["attn_q"]
         assert stack.dtype == jnp.bfloat16
         assert probe.reads_whole_stack(ops, "attn_qkv", tuple(stack.shape)), stack.shape
+
+
+# --- trinity-mini: window layers in the llama block's pattern, 128 held experts (PR 47) ---
+
+def test_trinity_mini_decode_step_compiles_for_v5e_with_both_pools_in_place(one_chip):
+    """The whole decode step at the cell's size (1 dense + 4 routed layers, 32
+    slots, 128 experts held): the paged kernel at 8 query heads a KV head under
+    ``swa_attention`` over a table of 18 columns — once for the leading dense
+    layer, outside the scan, and three times in its body — and once under the
+    full layer's own ``paged_attention``; the touched-expert pass at ``[2048, 2
+    x 1024] x 128`` four times; the appends in place in BOTH pools; no weight
+    copied or staged before its matmul; temporaries under a tenth of a GB."""
+    file = _config_file("trinity-mini")
+    compiled, state = _compiled_decode_step(one_chip, file)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and " custom-call(" in line]
+    window = [c for c in calls if "/swa_attention/" in c]
+    full = [c for c in calls if "/swa_attention/" not in c and "/yoco_attention/" not in c]
+
+    def count(cs, kernel, inside_scan=None):
+        return sum(kernel in c.split(" = ")[0] and (inside_scan is None
+                                                    or ("/while/body/" in c) == inside_scan)
+                   for c in cs)
+
+    for kernel in ("paged_flash_attention", "paged_kv_append"):  # (outside the scan, inside it)
+        assert (count(window, kernel, False), count(window, kernel, True)) == (1, 3), kernel
+    assert count(full, "paged_flash_attention") == 1 and count(full, "paged_kv_append") == 1
+    assert count(calls, "moe_experts_step") == 4 and "/yoco_attention/" not in text
+    assert state.win_table.shape == (32, 18) and state.win_k_pages.shape == (4, 649, 128, 512)
+    memory = compiled.memory_analysis()
+    pools = sum(x.size * 2 for x in (state.k_pages, state.v_pages, state.win_k_pages,
+                                     state.win_v_pages))
+    assert memory.alias_size_in_bytes >= pools and memory.temp_size_in_bytes < 0.1e9
+    copies, staged = relayout_probe.weight_relayouts(relayout_probe.operations(text))
+    assert not copies and not staged, [o.line() for o in copies + staged]
+
+
+def test_trinity_mini_ragged_round_compiles_for_v5e_and_fits_beside_the_model(one_chip):
+    """``ragged_mixed_step`` at the top bucket (32 prompts' chunks at once:
+    65,536 (token, pick) pairs through the grouped matmul over 128 held
+    experts): the ragged kernel under ``swa_attention`` and under the full
+    layer's ``ragged_paged_attention``; arguments and temporaries together
+    under the chip's 15.75 GB with room for the encoder."""
+    from finchat_tpu.engine import engine as E
+    from finchat_tpu.models.llama import init_params
+    from finchat_tpu.utils.config import EngineConfig
+    from perfbench.models import adapter
+
+    file = _config_file("trinity-mini")
+    c, cfg = adapter(file).program_config(file), EngineConfig(**file["engine"])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(c, jax.random.key(0))))
+    state = described(jax.eval_shape(lambda: E.create_state(c, cfg, WIDTH)))
+    T, B = 8192, cfg.max_seqs
+    compiled = E.ragged_mixed_step.lower(
+        params, state, shape((T,), jnp.int32), shape((T,), jnp.int32), shape((B,), jnp.int32),
+        shape((B,), jnp.int32), shape((B,), jnp.int32), shape((B,), bool), shape((B,), bool),
+        shape((B,), jnp.int32), shape((B,), jnp.float32), shape((B,), jnp.float32),
+        shape((B,), jnp.int32), config=c, page_size=PAGE, attn_backend="pallas",
+        qm_backend="ref", spec_width=0).compile()
+    text = compiled.as_text()
+    walks = [line for line in text.splitlines()
+             if "ragged_flash_attention" in line.split(" = ")[0] and " custom-call(" in line]
+    assert [w for w in walks if "/swa_attention/" in w] and [
+        w for w in walks if "/swa_attention/" not in w and "/ragged_paged_attention/" in w]
+    assert "ragged-dot" in text or "ragged_dot" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.2e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 * 0.75

@@ -1,6 +1,6 @@
 """The configurations' blocks at a size a test holds, built once: what
 tests/test_falcon_h1.py, test_olmo_hybrid.py, test_granite_hybrid.py,
-test_deepseek_v32.py and test_phi4_flash.py check against the plain references
+test_deepseek_v32.py, test_phi4_flash.py and test_trinity_mini.py check against the plain references
 of ``perfbench/models``, and what tests/test_decode_pipeline.py serves, one
 for each kind of per-row memory the engine has.
 
@@ -32,9 +32,10 @@ from finchat_tpu.models.llama import (
 # name -> its adapter under perfbench/models
 ADAPTERS = {"falcon_h1": "falcon_h1", "olmo_hybrid": "olmo_hybrid",
             "granite_hybrid": "granitemoehybrid", "deepseek_v32": "deepseek_v32",
-            "phi4_flash": "phi4flash"}
+            "phi4_flash": "phi4flash", "trinity_mini": "afmoe"}
 SHAPES = {"tiny": (8, 16, 4), "falcon_h1": (16, 12, 4), "olmo_hybrid": (16, 12, 4),
-          "granite_hybrid": (16, 12, 4), "deepseek_v32": (16, 12, 4), "phi4_flash": (4, 8, 4)}
+          "granite_hybrid": (16, 12, 4), "deepseek_v32": (16, 12, 4), "phi4_flash": (4, 8, 4),
+          "trinity_mini": (4, 8, 4)}
 FILES: dict[str, dict] = {}
 
 # Falcon-H1's block at a size a test holds: head_dim 32 is not 64 / 4, two
@@ -121,6 +122,26 @@ FILES["phi4_flash"] = {
     "mamba_d_state": 4, "mamba_d_conv": 4, "mamba_expand": 2, "mamba_dt_rank": 4,
     "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
     "ssm_state_dtype": "float32",
+}
+
+# Trinity-Mini's block at a size a test holds: ONE leading dense window layer,
+# then two whole periods of three window layers and a full one; 8 / 2 heads of
+# 16 (hidden 64: a head is not hidden / heads), a window of 8 tokens = two
+# pages of 4; 32 routed experts of 32 at 4 a token (over MOE_DENSE_WASTE_MAX
+# x 4, so that the model routes sparsely as the published 128 at 8 do) beside
+# a shared one, every expert held
+TRINITY_KINDS = [WINDOW] + [WINDOW, WINDOW, WINDOW, FULL] * 2
+FILES["trinity_mini"] = {
+    "model_type": "afmoe", "hidden_size": 64, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "num_experts": 32, "num_experts_per_tok": 4,
+    "num_shared_experts": 1, "num_dense_layers": 1, "num_hidden_layers": 9,
+    "layer_types": TRINITY_KINDS, "sliding_window": 8, "global_attn_every_n_layers": 4,
+    "num_attention_heads": 8, "num_key_value_heads": 2, "head_dim": 16,
+    "score_func": "sigmoid", "route_norm": True, "route_scale": 2.826, "n_group": 1,
+    "topk_group": 1, "num_expert_groups": 1, "num_limited_groups": 1, "mup_enabled": True,
+    "hidden_act": "silu", "rope_theta": 10000, "rope_scaling": None, "rms_norm_eps": 1e-5,
+    "vocab_size": 211, "tie_word_embeddings": False,
+    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
 }
 
 
