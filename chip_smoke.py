@@ -458,29 +458,41 @@ def _router_picking(h, picks, width: int):
     return (h.T @ np.linalg.solve(h @ h.T, Z)).astype(np.float32)
 
 
-def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
-                            touched: tuple[int, ...] = (30, 36), timed_calls: int = 10,
-                            shrink: dict | None = None) -> dict[str, float]:
-    """``moe_mlp`` at the shape of ``granite-h-small-report-saturated`` (36 held
+def check_moe_at_cell_shape(backend: str, *, configuration: str = "granite-4.0-h-small",
+                            rows: int = 16, tokens: int = 4096,
+                            touched: tuple[int, ...] = (30, 36), layers: int = 0, layer: int = 0,
+                            timed_calls: int = 10, shrink: dict | None = None) -> dict[str, float]:
+    """``moe_mlp`` at the shape of ``perfbench/configs/<configuration>.json``'s
+    routed layer — by default ``granite-h-small-report-saturated``'s: 36 held
     experts of 768, fused [gate | up], of a router of 72, 10 a token, beside a
-    shared expert of 1,536; hidden 4,096; ``shrink`` replaces keys of the
-    file, for a CPU run) against the plain reference's loop over the held
-    experts (``perfbench/models/granitemoehybrid.py``):
+    shared expert of 1,536; hidden 4,096; ``trinity-mini`` is 128 of 128 of
+    1,024, 8 a token, hidden 2,048 (``rows`` 32, ``touched`` about 93 there);
+    ``shrink`` replaces keys of the file, for a CPU run — against the plain
+    reference's loop over the held experts (the file's adapter under
+    ``perfbench/models/``):
 
     - the one-token step's ``rows`` tokens under a router built so that the
-      rows pick exactly ``touched`` of the held experts (5 held and 5 absent
-      picks a row), in BOTH of its forms: ``backend``'s (``ops/moe_step.py``'s
-      pass over the touched experts on a kernel backend) and dense dispatch
-      over every held stack (``ref``), with the counts each returns;
-    - the top ragged bucket's ``tokens`` (the grouped form: 10 pairs a token
-      sorted by expert through ``lax.ragged_dot``; 0 = not this leg).
+      rows pick exactly ``touched`` of the held experts (where the router is
+      wider than what is held, half of a row's picks are absent ones), in
+      BOTH of its forms: ``backend``'s (``ops/moe_step.py``'s pass over the
+      touched experts on a kernel backend) and dense dispatch over every held
+      stack (``ref``), with the counts each returns; ``layers`` > 0 hands both
+      the stacks ``[layers, E, ...]`` with the index ``layer``, as the
+      engine's scan does (0: a layer's own leaf);
+    - the top ragged bucket's ``tokens`` (the grouped form: the (token, pick)
+      pairs sorted by expert through ``lax.ragged_dot``; 0 = not this leg).
 
     On the chip (``pallas``) also each form's device time from a profiler
     capture against its bound: the step's, without the shared expert, against
-    the bytes of the experts it TOUCHED (``moe_step_stream_bytes``, what
-    ``moe_expert_roofline.sat`` divides by), the bucket's against its pairs'
-    FLOPs at 197 TF/s and its bytes."""
+    the bytes of the experts it TOUCHED (a layer of the adapter's
+    ``moe_step_stream_bytes``, what ``moe_expert_roofline.sat`` divides by),
+    the bucket's against its pairs' FLOPs at 197 TF/s and its bytes. To read
+    the pass alone at another tile, set ``ops.moe_step._WHOLE_BYTES`` /
+    ``_BLOCK_BYTES`` and ``jax.clear_caches()`` between calls — but a tile is
+    judged inside the compiled step (``benchmarks/moe_step_in_step.py``): PR 48
+    read 512 columns faster than 256 alone and slower there."""
     import dataclasses
+    import inspect
     import json
     from pathlib import Path
 
@@ -488,16 +500,18 @@ def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
     import jax.numpy as jnp
     import numpy as np
 
-    from finchat_tpu.models.llama import moe_mlp
-    from perfbench.models import granitemoehybrid as granite
+    from finchat_tpu.models.llama import StackedLeaf, moe_mlp
+    from perfbench.models import adapter
 
     file = json.loads((Path(__file__).resolve().parent
-                       / "perfbench/configs/granite-4.0-h-small.json").read_text())
+                       / f"perfbench/configs/{configuration}.json").read_text())
     file.update(shrink or {})
-    c, s = granite.program_config(file), granite._sizes(file)
+    model = adapter(file)
+    c, s = model.program_config(file), model._sizes(file)
     D, E, F, Fs, R = c.dim, c.n_experts, c.hidden_dim, c.moe_shared_dim, c.moe_router_width
     k = c.top_k_experts
-    ks = jax.random.split(jax.random.key(34), 7)
+    held_picks = k if R == E else k // 2  # of a row; the others fall on absent experts
+    ks = jax.random.split(jax.random.key(34), 8)
     bf16 = jnp.bfloat16
 
     def normal(key, shape, fan_in, dtype=bf16):
@@ -506,13 +520,24 @@ def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
     lp = {"router": normal(ks[0], (D, R), D, jnp.float32),
           "moe_in": normal(ks[1], (E, D, 2 * F), D), "moe_out": normal(ks[2], (E, F, D), F),
           "shared_in": normal(ks[3], (D, 2 * Fs), D), "shared_out": normal(ks[4], (Fs, D), Fs)}
+    if c.moe_select_bias:
+        lp["router_bias"] = c.moe_bias_init_std * jax.random.normal(ks[7], (R,), jnp.float32)
     results = {}
+    # one adapter's loop takes the pick to swap, another's also returns the margin
+    swap = {"swap": False} if "swap" in inspect.signature(model._experts).parameters else {}
+
+    # the expert stacks as the engine's scan hands them: ``[layers, E, ...]`` and
+    # the index (the other layers zeros)
+    stacks = {name: StackedLeaf(jnp.pad(lp[name][None], [(layer, layers - layer - 1)] + [(0, 0)] * 3),
+                                jnp.asarray(layer, jnp.int32))
+              for name in ("moe_in", "moe_out")} if layers else {}
 
     def against_reference(label, got, h, lp):
         with jax.default_matmul_precision("highest"):
-            want = granite._experts(h[0].astype(jnp.float32),
-                                    {name: leaf[None] for name, leaf in lp.items()}, 0, s,
-                                    lambda w: w, swap=False)
+            want = model._experts(h[0].astype(jnp.float32),
+                                  {name: leaf[None] for name, leaf in lp.items()}, 0, s,
+                                  lambda w: w, **swap)
+        want = want[0] if isinstance(want, tuple) else want
         got, want = np.asarray(got[0].astype(jnp.float32)), np.asarray(want)
         require(np.isfinite(got).all(), f"moe_mlp {label}: non-finite output")
         rel = float(np.sqrt(np.mean((got - want) ** 2)) / np.std(want))
@@ -539,14 +564,15 @@ def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
     routed = dataclasses.replace(c, moe_shared_dim=0)  # what is timed: the routed experts alone
     for t in touched:
         held = np.sort(np.random.RandomState(t).permutation(E)[:t])
-        picks = [[*(held[(k // 2 * i + j) % t] for j in range(k // 2)),
-                  *(E + (k // 2 * i + j) % (R - E) for j in range(k - k // 2))]
+        picks = [[*(held[(held_picks * i + j) % t] for j in range(held_picks)),
+                  *(E + (held_picks * i + j) % (R - E) for j in range(k - held_picks))]
                  for i in range(rows)]
         lp_t = {**lp, "router": jnp.asarray(_router_picking(h[0].astype(jnp.float32), picks, R))}
+        lp_run = {**lp_t, **stacks}
         for form, form_backend in (("touched", backend), ("dense", "ref")):
             label = f"{form} at {t} of {E}"
             run = jax.jit(lambda h, lp, b=form_backend: moe_mlp(h, lp, c, live=live, backend=b))
-            got, counts = run(h, lp_t)
+            got, counts = run(h, lp_run)
             counts = tuple(int(n) for n in counts)
             want_read = t if form == "touched" and form_backend != "ref" else E
             require(counts == (t, want_read),
@@ -555,11 +581,11 @@ def check_moe_at_cell_shape(backend: str, *, rows: int = 16, tokens: int = 4096,
             say(f"moe_mlp {label}: ok ({rows} tokens, touched / read {counts}; rms error "
                 f"{rel:.4f} of the reference's spread)")
             if backend == "pallas":
-                period = granite.moe_step_stream_bytes(file, rows=rows, experts_touched=float(t))
-                bound_us = results[f"bound_{t}_us"] = 1e6 * period / len(s["kinds"]) / 819e9
+                one_layer = (t * 3 * D * F + 2 * rows * D) * 2  # the touched experts, the rows
+                bound_us = results[f"bound_{t}_us"] = 1e6 * one_layer / 819e9
                 alone = jax.jit(lambda h, lp, b=form_backend: moe_mlp(h, lp, routed, backend=b))
                 results[f"{form}_{t}_us"] = timed(
-                    label + ", without the shared expert", lambda: alone(h, lp_t), bound_us,
+                    label + ", without the shared expert", lambda: alone(h, lp_run), bound_us,
                     f"stream bound of the {t} touched experts")
 
     if not tokens:

@@ -17,7 +17,19 @@ projection, float32 accumulation over the expert width and over the experts,
 one cast at the end. An expert no live token picked has a zero gate in every
 live row, so leaving it out changes no live row's value.
 
-The grid runs over ``E`` slots x tiles of the expert width. ``plan`` sorts the
+The grid runs over ``E`` slots x tiles of the expert width, and how an expert
+is cut follows from the call's static shapes (``width_tile``): an expert whose
+three blocks are at most ``_WHOLE_BYTES`` is ONE grid step — ``W_in[e]`` and
+``W_out[e]`` read where they lie, contiguously (Trinity-Mini's ``[2048, 2 x
+1024]``: 12 MiB a step) — and a larger one is cut into the narrowest tiles whose
+``D x tile`` block reaches ``_BLOCK_BYTES`` (256 columns at Granite's ``[4096,
+768]`` and DeepSeek's ``[7168, 2048]``). The tile is judged INSIDE the
+compiled decode step (``benchmarks/moe_step_in_step.py``), not alone: at
+Trinity-Mini's shape 512 columns read faster than 256 alone and slower in the
+step, and only the whole width reads in the step what it reads alone (PERF.md
+section 6, PR 48; the suspect is the strided read of a column tile of
+``W_in[e]`` at a power-of-two stride, 64 KiB a group of 16 rows there, 48 KiB
+at Granite's, whose pass reads the same at every tile). ``plan`` sorts the
 touched ids first (ascending); slot ``s < n`` maps to expert ``ids[s]``, and a
 slot ``s >= n`` maps to the very block the last touched slot ended on (the
 same expert AND the same tile), so the pipeline issues no copy for it, and
@@ -40,15 +52,32 @@ from jax import Array
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# columns of the expert width a grid step brings in (a multiple of 128 that
-# divides it, else the whole width): at Granite's 768 x 4096 in bf16 three
-# tiles of 256, each of the step's three blocks 2 MiB
-_TILE = 256
+# where an expert is cut into tiles, the least bytes of ONE of a grid step's
+# three blocks (``D x tile`` elements of the weights): PR 35's cut, 256 columns
+# at Granite's and DeepSeek's shapes, kept so that their programs stay as they
+# were (Granite's pass reads within 0.4 % at 128 to 768 columns inside its
+# decode step, PR 48: the block's size is not what its speed hangs on)
+_BLOCK_BYTES = 2 * 1024 * 1024
+# the most bytes of an expert (its three blocks) that is ONE grid step: two
+# buffers of it are then at most a quarter of a v5e's 128 MiB of VMEM. Measured
+# inside Trinity-Mini's decode step (12 MiB an expert, PR 48): the pass 1,304 us
+# whole against 1,353 / 1,429 / 1,484 at tiles of 128 / 256 / 512 columns;
+# Granite's 18 MiB expert reads the same whole or cut and stays cut
+_WHOLE_BYTES = 16 * 1024 * 1024
 
 
-def width_tile(width: int) -> int:
-    tiles = [t for t in range(128, min(width, _TILE) + 1, 128) if width % t == 0]
-    return max(tiles) if tiles else width
+def width_tile(width: int, dim: int, itemsize: int) -> int:
+    """Columns of the expert ``width`` that a grid step brings in, for a model
+    width ``dim`` and weights of ``itemsize`` bytes: the whole width where the
+    expert's three blocks are at most ``_WHOLE_BYTES``; else the smallest
+    multiple of 128 that divides it and makes a block ``dim x tile`` at least
+    ``_BLOCK_BYTES``, else the whole width."""
+    if 3 * dim * width * itemsize <= _WHOLE_BYTES:
+        return width
+    for tile in range(128, width, 128):
+        if width % tile == 0 and dim * tile * itemsize >= _BLOCK_BYTES:
+            return tile
+    return width
 
 
 def plan(picked: Array, gates: Array) -> tuple[Array, Array, Array]:
@@ -117,7 +146,7 @@ def moe_experts_step(
     reading the ``n`` experts ``ids[:n]`` of the stacks and no other."""
     T, D = h.shape
     E, F = w_out.shape[1:3]
-    tile = width_tile(F)
+    tile = width_tile(F, D, w_in.dtype.itemsize)
     nj = F // tile
     # whole sublane tiles of the model's dtype (a row of zeros adds nothing)
     pad = -T % (32 // h.dtype.itemsize)

@@ -78,6 +78,22 @@ def test_moe_at_cell_shape_interpret():
     assert max(results.values()) < 0.01
 
 
+def test_moe_at_cell_shape_interpret_for_a_configuration_that_holds_every_expert():
+    """The same check told another configuration (``trinity-mini``'s file cut
+    to a test's size: 12 of 12 experts held, 2 a token, a sigmoid router with
+    a selection bias, so every pick of a row is a held one), the stacks handed
+    as the engine's scan hands them, ``[3, E, ...]`` at index 1."""
+    results = chip_smoke.check_moe_at_cell_shape(
+        "pallas-interpret", configuration="trinity-mini", rows=8, tokens=160, touched=(5, 12),
+        layers=3, layer=1,
+        shrink={"hidden_size": 128, "moe_intermediate_size": 256, "intermediate_size": 256,
+                "num_experts": 12, "num_experts_per_tok": 2, "num_attention_heads": 4,
+                "num_key_value_heads": 2, "head_dim": 32, "vocab_size": 512})
+    assert set(results) == {"touched_5_rel", "dense_5_rel", "touched_12_rel", "dense_12_rel",
+                            "grouped_rel"}
+    assert max(results.values()) < 0.01
+
+
 def test_gdn_step_at_cell_shape_values():
     """The delta rule's one-token update at a small size against the
     recurrence as written (values only: a time comes from the chip): layer 1

@@ -118,18 +118,44 @@ def test_the_pass_in_bfloat16_rounds_where_dense_dispatch_rounds():
                                rtol=2e-2, atol=2e-2)
 
 
-@pytest.mark.parametrize("tile,width,want", [(256, 768, 256), (384, 768, 384), (256, 32, 32),
-                                             (256, 640, 128), (128, 200, 200)])
-def test_a_tile_is_a_multiple_of_128_that_divides_the_width_or_the_width(tile, width, want,
+MIB = 1 << 20
+
+
+@pytest.mark.parametrize("block_bytes,width,want", [(2 * MIB, 768, 256), (3 * MIB, 768, 384),
+                                                    (2 * MIB, 32, 32), (MIB, 640, 128),
+                                                    (MIB, 200, 200), (8 * MIB, 768, 768)])
+def test_a_tile_is_a_multiple_of_128_that_divides_the_width_or_the_width(block_bytes, width, want,
                                                                         monkeypatch):
-    monkeypatch.setattr(moe_step, "_TILE", tile)
-    assert moe_step.width_tile(width) == want
+    """Where an expert is cut, at a model width of 4,096 in bfloat16 (128
+    columns are 1 MiB): the narrowest dividing tile whose block reaches the
+    floor, else the width."""
+    monkeypatch.setattr(moe_step, "_WHOLE_BYTES", 0)
+    monkeypatch.setattr(moe_step, "_BLOCK_BYTES", block_bytes)
+    assert moe_step.width_tile(width, 4096, 2) == want
+
+
+@pytest.mark.parametrize("model,width,dim,itemsize,want", [
+    ("granite-4.0-h-small", 768, 4096, 2, 256), ("deepseek-v3.2-exp", 2048, 7168, 2, 256),
+    ("trinity-mini", 1024, 2048, 2, 1024), ("trinity-mini in float32", 1024, 2048, 4, 256),
+    ("15 MiB an expert at granite's model width", 640, 4096, 2, 640)])
+def test_the_served_shapes_tiles(model, width, dim, itemsize, want):
+    """The rule as it stands, on the three shapes that cells serve: Granite's
+    (18 MiB an expert) and DeepSeek's (84 MiB) are cut into 256 columns as
+    they always were (blocks of 2 and 3.5 MiB), Trinity-Mini's 12 MiB expert
+    is one grid step, as is one of 640 columns at Granite's model width."""
+    tile = moe_step.width_tile(width, dim, itemsize)
+    assert tile == want and width % tile == 0
+    if tile != width:
+        assert tile % 128 == 0 and 3 * dim * width * itemsize > 16 * MIB
+        assert dim * tile * itemsize >= 2 * MIB > dim * (tile - 128) * itemsize
 
 
 def test_the_kernel_alone_over_several_tiles_and_rows_that_are_no_whole_sublane_tile(monkeypatch):
     """Three tiles of 128 columns a expert and 5 tokens (padded to 8): slots
     past the touched ones rest on the last touched block."""
-    monkeypatch.setattr(moe_step, "_TILE", 128)
+    monkeypatch.setattr(moe_step, "_WHOLE_BYTES", 0)
+    monkeypatch.setattr(moe_step, "_BLOCK_BYTES", 64 * 128 * 4)
+    assert moe_step.width_tile(384, 64, 4) == 128
     L, D, F, T = 2, 64, 384, 5
     ks = jax.random.split(jax.random.key(5), 4)
     w_in = jax.random.normal(ks[0], (L, E, D, 2 * F)) * D ** -0.5
@@ -145,6 +171,33 @@ def test_the_kernel_alone_over_several_tiles_and_rows_that_are_no_whole_sublane_
         * jnp.einsum("td,edf->tef", h, w_in[1][..., F:])
     want = jnp.einsum("tef,efd->td", act * gates[..., None], w_out[1])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("tile", [512, 1024], ids=["two_tiles_of_512", "the_whole_expert"])
+def test_an_expert_of_1024_through_the_pass_equals_dense_dispatch_in_bfloat16(tile, monkeypatch):
+    """Trinity-Mini's expert width at a small model width, as one grid step
+    (what the rule gives) and cut into two tiles of 512 columns: the same
+    products rounded at the same places, the float32 partial sums over the
+    expert width grouped one way or the other."""
+    wide = dataclasses.replace(CONFIG, hidden_dim=1024)
+    if tile != 1024:
+        monkeypatch.setattr(moe_step, "_WHOLE_BYTES", 0)
+        monkeypatch.setattr(moe_step, "_BLOCK_BYTES", wide.dim * tile * 2)
+    assert moe_step.width_tile(1024, wide.dim, 2) == tile
+    lp, h = _one_layer(wide)
+    picks = CASES["every_expert"]["picks"]
+    lp = {**lp, "router": jnp.asarray(_router_picking(h.reshape(10, -1), picks, R))}
+    lp = {name: leaf if name == "router" else leaf.astype(jnp.bfloat16) for name, leaf in lp.items()}
+    h, live = h.astype(jnp.bfloat16), jnp.ones((2, 5), bool)
+    want, _ = moe_mlp(h, lp, wide, live=live)
+    # jitted anew: the tile is read while the call is traced
+    step = jax.jit(moe_step.moe_experts_step.__wrapped__, static_argnames=("interpret",))
+    monkeypatch.setattr(moe_step, "moe_experts_step", step)
+    got, (n_touched, n_read) = jax.jit(
+        lambda h, lp: moe_mlp(h, lp, wide, live=live, backend="pallas-interpret"))(h, lp)
+    assert got.dtype == jnp.bfloat16 and int(n_touched) == int(n_read) == E
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+                               rtol=2e-2, atol=2e-2)
 
 
 # --- RULE ----------------------------------------------------------------------

@@ -26,6 +26,7 @@ from finchat_tpu.ops.paged_attention import (
 # mixtral-8x7b-v0.1 as perfbench/configs has it: 32 / 8 heads of 128, pages
 # of 128 tokens, a table of max_seq_len / page = 128 entries, 1,600 pages
 ROWS, HEADS, KV_HEADS, HEAD_DIM, PAGE, WIDTH, LAYERS, POOL = 16, 32, 8, 128, 128, 128, 3, 1600
+MIB = 1 << 20
 
 
 @pytest.fixture(scope="module")
@@ -372,7 +373,7 @@ def test_the_touched_expert_pass_compiles_for_v5e_at_granites_stacks(one_chip, t
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     L, E, D, F = 10, 36, 4096, 768
-    assert width_tile(F) == 256
+    assert width_tile(F, D, 2) == 256
     compiled = moe_experts_step.lower(
         shape((tokens, D)), shape((E, tokens, 1), jnp.float32), shape((E,), jnp.int32),
         shape((1,), jnp.int32), shape((L, E, D, 2 * F)), shape((L, E, F, D)),
@@ -436,7 +437,7 @@ def test_the_touched_expert_pass_compiles_for_v5e_at_deepseeks_stacks(one_chip):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     L, E, D, F = 4, 16, 7168, 2048
-    assert width_tile(F) == 256
+    assert width_tile(F, D, 2) == 256
     compiled = moe_experts_step.lower(
         shape((ROWS, D)), shape((E, ROWS, 1), jnp.float32), shape((E,), jnp.int32),
         shape((1,), jnp.int32), shape((L, E, D, 2 * F)), shape((L, E, F, D)),
@@ -445,6 +446,38 @@ def test_the_touched_expert_pass_compiles_for_v5e_at_deepseeks_stacks(one_chip):
              if "tpu_custom_call" in line and " custom-call(" in line]
     assert len(calls) == 1 and "%moe_experts_step" in calls[0].split(" = ")[0]
     assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
+# --- trinity-mini (PR 47): 128 small experts; the pass's tile by its bytes (PR 48) ---
+
+@pytest.mark.parametrize("tokens", [32, 128])
+def test_the_touched_expert_pass_compiles_for_v5e_at_trinity_minis_stacks(one_chip, tokens):
+    """``ops/moe_step.py`` over ``trinity-mini``'s stacks [4, 128, 2048, 2048]
+    and [4, 128, 1024, 2048]: an expert's three blocks are 12 MiB, so
+    ``width_tile`` gives the whole width — ONE grid step an expert, ``W_in[e]``
+    and ``W_out[e]`` read contiguously — at the decode step's 32 rows and a
+    ragged round's 128 tokens: one custom call, nothing copied, no temporary
+    beside it, and the VMEM it asks for (two buffers of an expert, the rows,
+    8 MiB of room: 33 and 36 MiB) far under the chip's 128."""
+    from finchat_tpu.ops.moe_step import moe_experts_step, width_tile
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    L, E, D, F = 4, 128, 2048, 1024
+    assert width_tile(F, D, 2) == F
+    compiled = moe_experts_step.lower(
+        shape((tokens, D)), shape((E, tokens, 1), jnp.float32), shape((E,), jnp.int32),
+        shape((1,), jnp.int32), shape((L, E, D, 2 * F)), shape((L, E, F, D)),
+        shape((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "%moe_experts_step" in calls[0].split(" = ")[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+    asked = int(re.search(r'"scoped_memory_configs":\[\{"memory_space":"1","offset":"0","size":"(\d+)"',
+                          calls[0]).group(1))
+    assert asked == 2 * (3 * D * F * 2) + 16 * tokens * D + 8 * MIB < 128 * MIB
 
 
 def test_kv_append_compiles_for_v5e_with_a_latent_row_and_an_index_key(one_chip):
