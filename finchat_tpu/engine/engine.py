@@ -338,7 +338,8 @@ def _paged_attention_fn(
     for inactive decode slots). The callback receives the FULL-depth cache
     (carried through the layer scan) plus the layer index.
 
-    ``inplace_append`` forces the in-place page-RMW write path for C > 1
+    ``inplace_append`` forces the in-place append (a token's slab read,
+    patched and written a row: ``ops/kv_append.py``) for C > 1
     (one single-token append per chunk position) — used by the speculative
     verify step, whose few-token chunks would otherwise pay the scatter's
     full-cache copy every step, exactly what the append kernel exists to
@@ -405,7 +406,7 @@ def _paged_attention_fn(
         if k is None:
             pass  # a CROSS layer: the pages are another layer's, written already
         elif (C == 1 or inplace_append) and attn_backend != "ref":
-            # decode / spec verify: in-place single-page RMW appends (no
+            # decode / spec verify: in-place appends of a token's slab (no
             # cache copy); token i of the chunk is valid iff i < n_valid
             with jax.named_scope("kv_append"):
                 for i in range(C):

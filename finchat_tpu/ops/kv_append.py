@@ -4,28 +4,41 @@ The XLA alternative (``engine/kv_cache.py scatter_kv_chunk``) lowers to a
 scatter that rebuilds the destination buffer: ~22 ms/step for a 1.5 GB
 TinyLlama cache on v5e (builders' July 2026 measurement, not reproduced
 since), both as scan xs→ys and as an in-carry scatter — XLA never does it
-in place. This
-kernel does: ``input_output_aliases`` pins the output to the input buffer
-and each program read-modify-writes exactly ONE page, so per-step traffic is
-B pages instead of the whole cache.
+in place. This kernel does: ``input_output_aliases`` pins the output to the
+input buffer and a row's append read-modify-writes the SLAB that holds its
+token — the dtype's packed tile, 16 token rows of bfloat16 (``slab_rows``) —
+so a step's traffic is B slabs each way, not B pages and not the cache.
 
-Mosaic constraints that shaped the design (discovered on v5e hardware,
-round 4 — see git history for the failed variants):
+Mosaic constraints that shaped the design (v5e; round 4 and PR 49 — see git
+history for the failed variants):
 - DMA slices must be tile-aligned in the trailing two dims: a single-token
-  ``(1, hd)`` copy is rejected, a full page ``(page_size, Hkv*hd)`` is
-  legal. Hence RMW of the whole page with the token row inserted by a
-  masked select, not a token-granular write.
+  ``(1, hd)`` copy is rejected. The pool lies ``T(8,128)(2,1)``: a bfloat16
+  tile is 16 token rows, and a dynamic slice of 16 rows of the page at an
+  offset that is a multiple of 16 (``pl.multiple_of``) is whole tiles — Mosaic
+  takes it where it lies, at every accepted row width (512 to 3,840 columns,
+  640 beside 128). Hence RMW of the slab with the token row inserted by a
+  masked select: an eighth of the page's bytes each way (PR 49; until then
+  the whole page moved, 2 x 640 KiB a row for 5 KiB of payload at a 1,280-wide
+  row, and a program a row waited on its own page before the next row's
+  started: 24-121 us a layer).
 - Dynamic (scalar-prefetch-dependent) OUTPUT BlockSpec index maps compile
   but fail at runtime; manual ``make_async_copy`` into an ``ANY``-space
   aliased output works.
 
-Grid is ``(B,)`` — one program per sequence per layer; the layer is a
-scalar-prefetch operand so the kernel indexes the full-depth cache that the
-model's layer scan carries (no per-layer dynamic-slice copies).
+ONE program walks the rows (grid ``(1,)``): every row's two slab reads are
+started before the first is waited, a row is patched as soon as ITS reads
+stand (a DMA semaphore a row and direction) and its writes are started at
+once, and all writes are waited last — a layer's append is two DMA latencies
+and the slabs' bytes. Rows never share a slab but on the trash page, where
+inactive rows' writes may land in any order. The layer is a scalar-prefetch
+operand so the kernel indexes the full-depth cache that the model's layer scan
+carries (no per-layer dynamic-slice copies).
 
-Serves decode only (C = 1). Prefill chunks keep the XLA scatter: one
-full-cache copy amortized over a whole batched chunk is noise next to the
-prefill matmuls.
+Serves decode (C = 1) and, a call a chunk position, the speculative verify
+step (``engine.py`` ``inplace_append``). Prefill chunks keep the XLA scatter:
+one full-cache copy amortized over a whole batched chunk is noise next to the
+prefill matmuls. The int8 form (``paged_kv_append_q8``) still moves whole
+pages, a program a row: it runs in no cell.
 """
 
 from __future__ import annotations
@@ -41,6 +54,14 @@ from jax.experimental.pallas import tpu as pltpu
 TRASH_PAGE = 0
 
 
+def slab_rows(page_size: int, itemsize: int) -> int:
+    """Token rows of the SLAB a one-token append moves: the dtype's packed
+    tile (32 bytes of sublanes: 16 rows of bfloat16, 8 of float32), so that a
+    slab at an offset that is a multiple of its rows is whole tiles of the
+    pool's layout — or the page, where a page is smaller."""
+    return min(page_size, 32 // itemsize)
+
+
 def _append_kernel(
     # scalar prefetch
     layer_ref,  # [1] int32
@@ -48,48 +69,74 @@ def _append_kernel(
     pos_ref,  # [B] int32 — absolute write position (the token's position)
     n_valid_ref,  # [B] int32 — 1 = live slot, 0 = inactive (trash redirect)
     # blocks
-    kv_new_ref,  # [1, 1, 2*HD] VMEM — k row ++ v row
+    kv_new_ref,  # [B, 1, HD + HV] VMEM — every row's k row ++ v row
     k_any,  # [L, P, PS, HD] ANY (aliased to output 0)
     v_any,
     o_k,  # aliased outputs (same buffers as k_any / v_any)
     o_v,
     # scratch
-    k_scr,  # [PS, HD] VMEM
-    v_scr,
-    sems,  # DMA semaphores (4,)
+    k_scr,  # [B, slab, HD] VMEM
+    v_scr,  # [B, slab, HV]
+    sems,  # DMA semaphores (2, B): a row's two reads, its two writes
     *,
     page_size: int,
 ):
-    b = pl.program_id(0)
-    pos = pos_ref[b]
-    off = pos % page_size
-    layer = layer_ref[0]
-    valid = n_valid_ref[b] > 0
-    # the table read happens BEFORE the select, so an invalid lane's pos
-    # (e.g. a trash-redirected verify-step position at the slot's length
-    # limit) must not index past the table row — read column 0 instead
-    logical = jnp.where(valid, pos // page_size, 0)
-    phys = jnp.where(valid, page_table_ref[b, logical], TRASH_PAGE)
+    """ONE program walks the rows: every row's slab reads are in flight before
+    the first is waited, each row is patched as its slab stands and its
+    writes started at once, and the writes are waited last."""
+    B, slab = k_scr.shape[:2]
     hd, hv = k_scr.shape[-1], v_scr.shape[-1]
+    layer = layer_ref[0]
 
-    kin = pltpu.make_async_copy(k_any.at[layer, phys], k_scr, sems.at[0])
-    vin = pltpu.make_async_copy(v_any.at[layer, phys], v_scr, sems.at[1])
-    kin.start()
-    vin.start()
-    kin.wait()
-    vin.wait()
+    def where(b):
+        """``(physical page, the slab's first token row, the token's row of
+        the slab)`` of row ``b``'s append."""
+        pos = pos_ref[b]
+        valid = n_valid_ref[b] > 0
+        # the table read happens BEFORE the select, so an invalid lane's pos
+        # (e.g. a trash-redirected verify-step position at the slot's length
+        # limit) must not index past the table row — read column 0 instead
+        logical = jnp.where(valid, pos // page_size, 0)
+        phys = jnp.where(valid, page_table_ref[b, logical], TRASH_PAGE)
+        off = pos % page_size
+        t0 = pl.multiple_of(off // slab * slab, slab)
+        return phys, t0, off - t0
 
-    row = jax.lax.broadcasted_iota(jnp.int32, (page_size, 1), 0)
-    hit = row == off
-    k_scr[:] = jnp.where(hit, kv_new_ref[0, :, 0:hd], k_scr[:])
-    v_scr[:] = jnp.where(hit, kv_new_ref[0, :, hd:hd + hv], v_scr[:])
+    def slabs(b, back, phys=0, t0=0):
+        """Row ``b``'s two slab copies, pool -> scratch or (``back``) scratch
+        -> pool; a wait takes them unaddressed (it counts bytes)."""
+        out = []
+        for pool, aliased, scr in ((k_any, o_k, k_scr), (v_any, o_v, v_scr)):
+            at = (aliased if back else pool).at[layer, phys, pl.ds(t0, slab)]
+            src, dst = (scr.at[b], at) if back else (at, scr.at[b])
+            out.append(pltpu.make_async_copy(src, dst, sems.at[int(back), b]))
+        return out
 
-    kout = pltpu.make_async_copy(k_scr, o_k.at[layer, phys], sems.at[2])
-    vout = pltpu.make_async_copy(v_scr, o_v.at[layer, phys], sems.at[3])
-    kout.start()
-    vout.start()
-    kout.wait()
-    vout.wait()
+    def read(b, carry):
+        phys, t0, _at = where(b)
+        for c in slabs(b, False, phys, t0):
+            c.start()
+        return carry
+
+    def patch(b, carry):
+        for c in slabs(b, False):
+            c.wait()
+        phys, t0, at = where(b)
+        hit = jax.lax.broadcasted_iota(jnp.int32, (slab, 1), 0) == at
+        k_scr[b] = jnp.where(hit, kv_new_ref[b, :, 0:hd], k_scr[b])
+        v_scr[b] = jnp.where(hit, kv_new_ref[b, :, hd:hd + hv], v_scr[b])
+        for c in slabs(b, True, phys, t0):
+            c.start()
+        return carry
+
+    def written(b, carry):
+        for c in slabs(b, True):
+            c.wait()
+        return carry
+
+    jax.lax.fori_loop(0, B, read, None)
+    jax.lax.fori_loop(0, B, patch, None)
+    jax.lax.fori_loop(0, B, written, None)
 
 
 def _append_kernel_q8(
@@ -255,12 +302,13 @@ def paged_kv_append(
     beside the other)."""
     B = kv_new.shape[0]
     HD, HV = k_pages.shape[-1], v_pages.shape[-1]
+    slab = slab_rows(page_size, k_pages.dtype.itemsize)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B,),
+        grid=(1,),
         in_specs=[
-            pl.BlockSpec((1, 1, HD + HV), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((B, 1, HD + HV), lambda i, *_: (0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
@@ -269,9 +317,9 @@ def paged_kv_append(
             pl.BlockSpec(memory_space=pl.ANY),
         ],
         scratch_shapes=[
-            pltpu.VMEM((page_size, HD), k_pages.dtype),
-            pltpu.VMEM((page_size, HV), v_pages.dtype),
-            pltpu.SemaphoreType.DMA((4,)),
+            pltpu.VMEM((B, slab, HD), k_pages.dtype),
+            pltpu.VMEM((B, slab, HV), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, B)),
         ],
     )
     kernel = functools.partial(_append_kernel, page_size=page_size)

@@ -92,6 +92,22 @@ Design — a walk as long as the row, several pages a block:
   uncovered — a row's own walk is 7-8 blocks where it was 15, and its
   uncovered first copy had become a fifth of the call. At C > 1 none of
   this is traced.
+- a WINDOW layer's decode call (``window`` > 0, C = 1; PERF.md section 6, PR
+  49) is handed its row's bounded page list — ``window / page_size + 2``
+  columns in compacted coordinates, of which all but one hold live pages
+  whatever the context — and no shared page, by contract. So the stacked pass
+  is NOT traced (no stacked queries, no second m / l / acc: at Phi-4-flash's 32
+  rows 3.8 MiB of state and a ``[256, T]`` logit tile that bounded the block);
+  the chain across rows stays; and a block is the TABLE cut in the fewest
+  equal parts whose double-buffered K and V fit (``_pages_per_block``'s
+  ``whole_table``): the 6 columns at a 10-head cache ONE block of 7.5 MiB,
+  18 columns at 4 heads two blocks of 9 pages — where blocks of 512 tokens
+  cost 8 page slots for 5 live pages and 20 for 17, every row, every layer.
+  A block starts and waits the copies of its LIVE pages alone (the first
+  always; the others each under its own ``pl.when``): what the dead slots of
+  the V buffer hold meets a probability of exactly 0 and only has to be
+  finite, so program 0 zeroes that buffer once a call. Every other call is
+  traced as it was.
 
 - a LATENT cache (``paged_latent_attention``; PERF.md section 6, PR 41). Where
   a token's ONE row is key and value at once for every head (models/mla.py)
@@ -191,7 +207,7 @@ def _heads_per_tile(group: int, block_q: int) -> int:
 
 
 def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
-                     max_pages: int, reserved: int = 0) -> int:
+                     max_pages: int, reserved: int = 0, whole_table: bool = False) -> int:
     """Pages copied and computed together: ``BLOCK_TOKENS`` tokens, halved
     while one tile's logits (``head_rows`` query rows: a KV head's, or those
     of the heads that share a tile) or the double-buffered K and V blocks
@@ -204,15 +220,28 @@ def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
     the better block, not only the one that fits: with more VMEM asked for
     (``vmem_limit_bytes``) 512 tokens at 30 heads ran 3.5 % slower, copies
     alone, than 256 — a walk's partial last block copies its last page again
-    for every page it lacks (PERF.md section 6, PR 33)."""
-    tokens = BLOCK_TOKENS
+    for every page it lacks (PERF.md section 6, PR 33).
+
+    ``whole_table`` (a window layer's one-token call: the table is the row's
+    bounded page list, live but for its last column): the table cut in the
+    fewest equal parts that fit the same budgets, ``ceil(max_pages / n)`` pages
+    for the smallest such ``n`` — no partial last block but the rounding's."""
     # an int8 block also stands dequantized beside its buffers, head by head
     # (float32, then the query dtype: 6 bytes an element; Mosaic keeps every
     # head's copy of the static unroll)
     token_bytes = width * (4 * itemsize + (6 if itemsize == 1 else 0))
-    while tokens > page_size and (
-            head_rows * tokens * 4 > SCORE_TILE_BYTES
-            or tokens * token_bytes > min(KV_BUFFER_BYTES, VMEM_BYTES - reserved)):
+
+    def over(tokens):
+        return (head_rows * tokens * 4 > SCORE_TILE_BYTES
+                or tokens * token_bytes > min(KV_BUFFER_BYTES, VMEM_BYTES - reserved))
+
+    if whole_table:
+        for parts in range(1, max_pages + 1):
+            pages = -(-max_pages // parts)
+            if pages == 1 or not over(pages * page_size):
+                return pages
+    tokens = BLOCK_TOKENS
+    while tokens > page_size and over(tokens):
         tokens //= 2
     return max(1, min(tokens // page_size, max_pages))
 
@@ -265,6 +294,7 @@ def _paged_kernel(
     latent_rows: int = 0,
     window: int = 0,
     index_heads: int = 0,
+    chained: bool = False,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -337,7 +367,8 @@ def _paged_kernel(
         own_state, refs = refs[:3], refs[3:]
     if shared_rows and not index_heads:
         shared_state, refs = refs[:3], refs[3:]
-    if shared_rows:
+    chained = chained or bool(shared_rows)
+    if chained:
         slot_ref, *refs = refs
     buffers, sems = refs[:n_src], refs[n_src]
 
@@ -349,6 +380,7 @@ def _paged_kernel(
     Rt = pack * group * Bq  # scratch rows per tile
     n_tiles = pl.cdiv(n_kv, pack)
     T = ppb * page_size  # tokens per block
+    live_only = bool(window) and chained  # a window's one-token walk copies LIVE pages alone
     layer = layer_ref[0]
     q_off = q_offset_ref[b]
     kv_len = kv_len_ref[b]
@@ -363,9 +395,9 @@ def _paged_kernel(
         n_blocks = pl.cdiv(n_pages, ppb)
 
         def copies(slot, block=None):
-            """One block's page copies into ``slot``; ``block = (row, the
-            column of its first page, the walk's last column)``, without
-            which only their shapes matter (a wait counts bytes, not
+            """One block's page copies into ``slot``, a list a page; ``block
+            = (row, the column of its first page, the walk's last column)``,
+            without which only their shapes matter (a wait counts bytes, not
             addresses)."""
             out = []
             for i in range(ppb):
@@ -373,16 +405,29 @@ def _paged_kernel(
                     phys = 0
                 else:  # a partial last block re-reads the last live page
                     phys = page_table_ref[block[0], jnp.minimum(block[1] + i, block[2])]
-                out += [pltpu.make_async_copy(src.at[layer, phys], buf.at[slot, i],
-                                              sems.at[slot, s])
-                        for s, (src, buf) in enumerate(zip(sources, buffers))]
+                out.append([pltpu.make_async_copy(src.at[layer, phys], buf.at[slot, i],
+                                                  sems.at[slot, s])
+                            for s, (src, buf) in enumerate(zip(sources, buffers))])
             return out
+
+        def each(slot, act, block=None, addressed=True):
+            """``act`` on every copy of a block into ``slot``; ``live_only``:
+            on those of its live pages alone (``block`` says which, also for
+            a wait, whose copies are not ``addressed``)."""
+            for i, page in enumerate(copies(slot, block if addressed else None)):
+                if live_only and i:
+                    @pl.when(block[1] + i <= block[2])
+                    def _(page=page):
+                        for c in page:
+                            act(c)
+                else:
+                    for c in page:
+                        act(c)
 
         def start(slot, row, first, n_pages, j=0):
             @pl.when(j * ppb < n_pages)
             def _():
-                for c in copies(slot, (row, first + j * ppb, first + n_pages - 1)):
-                    c.start()
+                each(slot, lambda c: c.start(), (row, first + j * ppb, first + n_pages - 1))
 
         @pl.when(jnp.logical_not(primed))
         def _first():
@@ -401,8 +446,8 @@ def _paged_kernel(
                 def _then():
                     start(1 - slot, *then)
 
-            for c in copies(slot):
-                c.wait()
+            live = (row, first + j * ppb, first + n_pages - 1) if live_only else None
+            each(slot, lambda c: c.wait(), live, addressed=False)
             update(slot, first, j)
             return carry
 
@@ -519,19 +564,27 @@ def _paged_kernel(
 
     if not index_heads:
         reset(own_state)
+    if live_only:  # what a dead slot of the V buffer holds meets a probability of 0: finite
+        @pl.when(b == 0)
+        def _finite():
+            buffers[1][...] = jnp.zeros(buffers[1].shape, buffers[1].dtype)
+
     chain = {}
-    if shared_rows:
-        # the batch's shared head (``shared_head``): program 0 reads those
-        # pages ONCE for every row's queries stacked, and a member's own walk
-        # starts behind them from its rows of that partial result. The walks
-        # of one call are a chain: each starts the first block of the next
-        # beside its own last one, so only the call's first copy is uncovered
-        n_shared, gp, B = head_ref[0], _round_up(pack * group, 8), pl.num_programs(0)
+    if chained:
+        # The walks of one call are a chain: each starts the first block of
+        # the next beside its own last one, so only the call's first copy is
+        # uncovered. With ``shared_rows`` also the batch's shared head
+        # (``shared_head``): program 0 reads those pages ONCE for every row's
+        # queries stacked, and a member's own walk starts behind them from its
+        # rows of that partial result
+        n_shared = head_ref[0] if shared_rows else 0
+        gp, B = _round_up(pack * group, 8), pl.num_programs(0)
 
         @pl.when(b == 0)
         def _call():
             slot_ref[0] = 0
 
+    if shared_rows:
         stacked = {}
         if latent_rows:  # a tile is `latent_rows` stacked rows: whole sequences
             def stacked_keep(t, col):
@@ -578,6 +631,7 @@ def _paged_kernel(
                     for own, shared in zip(own_state, shared_state):
                         own[t * Rt:(t + 1) * Rt] = shared[at, :][:Rt]
 
+    if chained:
         row, first, n_pages = own_walk(jnp.minimum(b + 1, B - 1))
         chain = dict(slot0=slot_ref[0], primed=jnp.logical_or(b > 0, n_shared > 0),
                      then=(row, first, jnp.where(b + 1 < B, n_pages, 0)))
@@ -587,7 +641,7 @@ def _paged_kernel(
         own = pl.ds(pl.multiple_of(b * index_heads, 8), index_heads)
         slot = walk(b, first, n_pages, scores(
             lambda t: (q_ref[0], w_ref[own, :1], pl.ds(b, 1)), index_heads), **chain)
-        if shared_rows:
+        if chained:
             slot_ref[0] = slot
         return
     own_keep = {}
@@ -605,7 +659,7 @@ def _paged_kernel(
     # (a latent row's one query stands on its last token: ``kv_len`` is its causal bound)
     slot = walk(b, first, n_pages,
                 softmax(kv_len, not latent_rows, own_q, own_state, Rt, **own_keep), **chain)
-    if shared_rows:
+    if chained:
         slot_ref[0] = slot
 
     m_scr, l_scr, acc_scr = own_state
@@ -678,7 +732,9 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     # decode over more than one row: the shared-head pass, each row's query
     # heads of a tile padded to whole 8-row tiles of the stacked block
     gp = _round_up(pack * group, 8)
-    shared_rows = B * gp if C == 1 and B > 1 else 0
+    # (a window's table holds no shared page: its walks are chained and start at column 0)
+    chained = n_queries == 1 and B > 1
+    shared_rows = B * gp if chained and not window else 0
 
     if pack == 1:
         q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, D]
@@ -705,15 +761,17 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
         in_specs.append(pl.BlockSpec((n_tiles, shared_rows, Wt), lambda b, qi, *_: (0, 0, 0)))
         state += [pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
                   pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
-                  pltpu.VMEM((n_tiles * shared_rows, Wt), jnp.float32),
-                  pltpu.SMEM((1,), jnp.int32)]
+                  pltpu.VMEM((n_tiles * shared_rows, Wt), jnp.float32)]
+    if chained:
+        state.append(pltpu.SMEM((1,), jnp.int32))
     # what stands in VMEM beside the K and V buffers: the query and output
     # blocks (the pipeline keeps two of each) and the softmax state
     reserved = (2 * q.dtype.itemsize * (q_bytes + H * max(bq, 8) * D
                                         + n_tiles * shared_rows * Wt)
                 + 4 * (r_pad + n_tiles * shared_rows) * (2 * 128 + Wt))
     ppb = _pages_per_block(page_size, max(pack * group * bq, shared_rows), n_kv * D,
-                           k_pages.dtype.itemsize, page_table.shape[1], reserved)
+                           k_pages.dtype.itemsize, page_table.shape[1], reserved,
+                           whole_table=chained and window > 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -730,7 +788,7 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
         _paged_kernel,
         block_q=bq, page_size=page_size, pages_per_block=ppb, n_kv=n_kv,
         group=group, pack=pack, scale=scale, quantized=len(sources) == 4,
-        shared_rows=shared_rows, window=window,
+        shared_rows=shared_rows, window=window, chained=chained,
     )
     out_t = pl.pallas_call(
         kernel,
@@ -806,7 +864,9 @@ def paged_flash_attention(
     ``window`` or more before its own. The caller's table holds the window's
     pages alone, in compacted coordinates (the engine's ``win_table`` and
     ``win_gaps``), and hands in a ``shared`` of no pages: the shared head's
-    pass reads whole pages for every row, which a window does not allow.
+    pass reads whole pages for every row, which a window does not allow — so
+    a window's one-token call does not trace that pass, nor read ``shared``,
+    and walks its table in the fewest blocks that fit (the module's list).
     """
     return _paged_call(
         q, (k_pages, v_pages), page_table, q_offset, kv_len, layer, shared,

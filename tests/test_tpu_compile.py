@@ -160,8 +160,9 @@ def test_paged_attention_compiles_for_v5e_at_olmo_hybrids_head_counts(one_chip, 
 
 
 def test_kv_append_compiles_for_v5e_at_olmo_hybrids_row_width(one_chip):
-    """The decode step's in-place append at a 3,840-wide row: a page of 128
-    tokens is 0.94 MiB, read, patched and written back whole, K and V."""
+    """The decode step's in-place append at a 3,840-wide row (a page of 128
+    tokens is 0.94 MiB: since PR 49 a row's 16-token slab is read, patched and
+    written, 120 KiB each of K and V): ONE custom call."""
     from finchat_tpu.ops.kv_append import paged_kv_append
 
     def shape(dims, dtype):
@@ -483,8 +484,8 @@ def test_the_touched_expert_pass_compiles_for_v5e_at_trinity_minis_stacks(one_ch
 def test_kv_append_compiles_for_v5e_with_a_latent_row_and_an_index_key(one_chip):
     """The decode step's in-place append where the pool's two arrays hold rows
     of different widths: a latent row of 640 columns and an index key of 128,
-    one beside the other in ``kv_new``; each page read, patched and written
-    back whole by ONE custom call."""
+    one beside the other in ``kv_new``; each row's slab of both read, patched
+    and written back by ONE custom call."""
     from finchat_tpu.ops.kv_append import paged_kv_append
 
     def shape(dims, dtype=jnp.bfloat16):
@@ -825,3 +826,83 @@ def test_trinity_mini_ragged_round_compiles_for_v5e_and_fits_beside_the_model(on
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 1.2e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 15.75e9 * 0.75
+
+
+# --- the one-token step's page traffic: a token's slab, a window's pages (PR 49) ---
+
+@pytest.mark.parametrize("rows, layers, pool, widths", [
+    (32, 4, 649, (512, 512)),  # Trinity-Mini's window pool (4 KV heads)
+    (16, 9, POOL, (1024, 1024)),  # Mistral, Mixtral, Granite (8)
+    (32, 8, 217, (1280, 1280)),  # Phi-4-flash's window pool (10 of a pair's width)
+    (16, 2, POOL, (3840, 3840)),  # Olmo-Hybrid (30)
+    (16, 5, POOL, (640, 128)),  # DeepSeek: a latent row beside an index key
+], ids=["512", "1024", "1280", "3840", "640+128"])
+def test_the_append_moves_slabs_for_v5e_and_copies_no_pool(one_chip, rows, layers, pool, widths):
+    """``paged_kv_append`` at the accepted pools' row widths, the pools donated
+    as the decode step donates its state: ONE custom call whose VMEM scratch is
+    ``[rows, 16, width]`` a pool (a token's packed tile of bfloat16, not the
+    page), a dynamic 16-row slice of the pool's second-minor dimension that
+    Mosaic takes where it lies — no operation but the call yields an array of
+    a pool's shape, nothing is temporary, both pools are aliased."""
+    from finchat_tpu.ops.kv_append import paged_kv_append
+    from tests.paged_walk_cases import pallas_eqn
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    args = (shape((rows, 1, sum(widths))), shape((layers, pool, PAGE, widths[0])),
+            shape((layers, pool, PAGE, widths[1])), shape((rows, WIDTH), jnp.int32),
+            shape((rows,), jnp.int32), shape((rows,), jnp.int32), shape((1,), jnp.int32))
+    step = jax.jit(lambda *a: paged_kv_append(*a, page_size=PAGE), donate_argnums=(1, 2))
+    eqn = pallas_eqn(jax.make_jaxpr(step)(*args).jaxpr)
+    n_scratch = eqn.params["grid_mapping"].num_scratch_operands
+    scratch = [v.aval.shape for v in eqn.params["jaxpr"].invars[-n_scratch:]]
+    assert scratch == [(rows, 16, widths[0]), (rows, 16, widths[1]), (2, rows)]
+    compiled = step.lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    pools = [f"bf16[{layers},{pool},{PAGE},{w}]" for w in widths]
+    makers = [line.split(" = ")[0].strip() for line in text.splitlines()
+              if " = " in line and any(f" = {p}" in line or f" = ({p}" in line for p in pools)
+              and " parameter(" not in line and " get-tuple-element(" not in line]
+    assert len(makers) == 1 and "paged_kv_append" in makers[0], makers
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < MIB
+    assert memory.alias_size_in_bytes >= sum(layers * pool * PAGE * w * 2 for w in widths)
+
+
+@pytest.mark.parametrize("heads, kv_heads, layers, pool, columns, window, pages", [
+    (40, 10, 8, 217, 6, 512, 6),  # Phi-4-flash: ONE block a row, 7.5 MiB of buffers
+    (32, 4, 4, 649, 18, 2048, 9),  # Trinity-Mini: two blocks a row, 4.5 MiB
+], ids=["phi4-flash", "trinity-mini"])
+def test_a_window_walk_compiles_for_v5e_with_no_stacked_scratch(one_chip, heads, kv_heads, layers,
+                                                                pool, columns, window, pages):
+    """A window layer's one-token call at its cell's shape (32 rows): the
+    table in the fewest blocks whose double-buffered K and V fit, under the 16
+    MiB of scoped VMEM a kernel has without asking; no stacked queries among
+    its operands, no second m / l / acc among its scratch — a row's own state,
+    the chain's slot word, the two buffers, the semaphores."""
+    from tests.paged_walk_cases import pallas_eqn
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    pages_ = shape((layers, pool, PAGE, kv_heads * HEAD_DIM))
+    args = (shape((32, 1, heads, HEAD_DIM)), pages_, pages_, shape((32, columns), jnp.int32),
+            shape((32,), jnp.int32), shape((32,), jnp.int32), shape((1,), jnp.int32),
+            (shape((32,), jnp.int32), shape((2,), jnp.int32)))  # the engine's: no page
+    step = jax.jit(lambda *a: paged_flash_attention(*a, page_size=PAGE, n_kv=kv_heads,
+                                                    window=window))
+    eqn = pallas_eqn(jax.make_jaxpr(step)(*args).jaxpr)
+    mapping = eqn.params["grid_mapping"]
+    assert (mapping.num_index_operands, mapping.num_inputs) == (4, 3)
+    scratch = [v.aval.shape for v in eqn.params["jaxpr"].invars[-mapping.num_scratch_operands:]]
+    rows = heads  # a row's query heads: whole tiles of 8
+    buffer = (2, pages, PAGE, kv_heads * HEAD_DIM)
+    assert scratch == [(rows, 128), (rows, 128), (rows, HEAD_DIM), (1,), buffer, buffer, (2, 2)]
+    text = step.lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and calls[0].split(" = ")[0].strip().startswith("%paged_flash_attention")
+    assert "vmem_limit_bytes" not in calls[0]
+
