@@ -610,7 +610,8 @@ def check_moe_at_cell_shape(backend: str, *, configuration: str = "granite-4.0-h
 def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 30,
                                 key_dim: int = 96, value_dim: int = 192,
                                 layers: int = 6, layer: int = 4,
-                                timed_calls: int = 20) -> dict[str, float]:
+                                timed_calls: int = 20,
+                                channel_decay: bool = False) -> dict[str, float]:
     """The one-token gated-delta-rule update (``ops/gdn_step.py``, in place
     over the whole slot batch as ``decode_step`` runs it) vs the recurrence as
     it is written, in float64, at the shape of the benchmark's cell
@@ -624,7 +625,10 @@ def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     kernel by — and beside it XLA's ``models/gdn.py`` ``_step`` over the same
     leaf, the two passes it replaces (the ``ref`` backend's body and the
     fallback for gathered slots), which is also the form whose values a
-    ``ref`` backend checks."""
+    ``ref`` backend checks. ``channel_decay``: the decay a vector over a head's
+    key channels (the kernel's second form; ``kimi-linear-report-saturated``
+    runs it at 32 rows of 32 heads of 128 x 128, a head a tile, in 7 layers),
+    one channel in eight decaying to nothing in the token (exp(-80))."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -643,13 +647,18 @@ def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     q = gdn._l2norm(jax.random.normal(ks[1], (rows, heads, key_dim), f32)) * key_dim ** -0.5
     k = gdn._l2norm(jax.random.normal(ks[2], (rows, heads, key_dim), f32))
     v = jax.random.normal(ks[3], (rows, heads, value_dim), f32)
-    g = -jax.random.uniform(ks[4], (rows, heads), f32, 0.01, 1.5).at[1].set(0.0)
+    if channel_decay:
+        hard = jax.random.uniform(jax.random.fold_in(ks[4], 1), (rows, heads, key_dim)) < 0.125
+        g = -jnp.where(hard, 80.0, jax.random.uniform(ks[4], (rows, heads, key_dim), f32, 0.01, 1.5))
+        g = g.at[1].set(0.0)
+    else:
+        g = -jax.random.uniform(ks[4], (rows, heads), f32, 0.01, 1.5).at[1].set(0.0)
     beta = jax.random.uniform(ks[5], (rows, heads), f32, 0.0, 2.0).at[1].set(0.0)
     at = jnp.asarray([layer], jnp.int32)
 
     S = np.asarray(by_head, np.float64).reshape(layers, rows, heads, key_dim, value_dim)[layer]
     q64, k64, v64, g64, b64 = (np.asarray(t, np.float64) for t in (q, k, v, g, beta))
-    S = np.exp(g64)[..., None, None] * S
+    S = (np.exp(g64)[..., None] if channel_decay else np.exp(g64)[..., None, None]) * S
     u = b64[..., None] * (v64 - np.einsum("nhkv,nhk->nhv", S, k64))
     want_new = S + k64[..., :, None] * u[..., None, :]
     want_o = np.einsum("nhkv,nhk->nhv", want_new, q64)
@@ -694,7 +703,8 @@ def check_gdn_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
             return o
         return run
 
-    small = (2 * heads * key_dim + 2 * heads * value_dim + 2 * heads) * 4
+    small = ((3 if channel_decay else 2) * heads * key_dim + 2 * heads * value_dim
+             + (1 if channel_decay else 2) * heads) * 4
     bound_us = 1e6 * rows * (2 * heads * key_dim * value_dim * 4 + small) / 819e9
     errors["bound_us"] = bound_us
     for label, update in (("kernel gdn_state_step", gdn_state_step), ("XLA's _step", xla_update)):
@@ -1099,6 +1109,9 @@ def _run(mesh_model: int) -> int:
     # kernel at one query head a KV head, and the delta rule's one-token update
     check_decode_at_cell_shape("pallas", shared_pages=31, n_heads=30, n_kv=30)
     check_gdn_step_at_cell_shape("pallas")
+    # ... and in its second form, at kimi-linear-report-saturated's shape (PR 51)
+    check_gdn_step_at_cell_shape("pallas", rows=32, heads=32, key_dim=128, value_dim=128,
+                                 layers=7, layer=4, channel_decay=True)
     # many small experts and the mixer as a layer kind at their cell's shape:
     # the state kernel at 128 heads of 64 x 128, attention with a softmax scale
     # of its own is check_kernels' and the cell's logits check's, the expert

@@ -304,7 +304,9 @@ def _latent_attention(write, page_rows: Array, start: Array, n_valid: Array,
     from finchat_tpu.ops.latent_attention import packed_attention, rows_attention
 
     def attention(x: LatentInputs, cache: Any, layer_idx: Array):
-        idx_k = x.idx_k if x.idx_k is not None else jnp.zeros((*x.row.shape[:2], 1), x.row.dtype)
+        # (no indexer: the second array's one lane tile, LlamaConfig.kv_row_widths)
+        idx_k = x.idx_k if x.idx_k is not None else jnp.zeros(
+            (*x.row.shape[:2], cache[1].shape[-1]), x.row.dtype)
         cache = write(x.row, idx_k, cache, layer_idx)
         kw = dict(page_size=page_size, shape=latent, backend=backend)
         layer = layer_idx.reshape(())

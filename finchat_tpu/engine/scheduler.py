@@ -3332,16 +3332,15 @@ class ContinuousBatchingScheduler:
                     self.metrics.inc("finchat_moe_layer_steps_total",  # the layers that route
                                      self.engine.config.n_scan_layers)
                     self._round_moe_experts = touched, read
-                if selected:  # a model with latent attention
+                if selected:  # a model with latent attention: the layers that own pages
+                    latent_layers = self.engine.config.n_attn_layers
                     self.metrics.inc("finchat_dsa_selected_tokens_total", selected[0])
                     self.metrics.inc("finchat_dsa_row_layer_steps_total",
-                                     len(step.members) * self.engine.config.n_layers)
-                    self.metrics.inc("finchat_latent_attention_calls_total",
-                                     self.engine.config.n_layers,
+                                     len(step.members) * latent_layers)
+                    self.metrics.inc("finchat_latent_attention_calls_total", latent_layers,
                                      labels={"form": self._latent_form})
                     if self._index_form:
-                        self.metrics.inc("finchat_dsa_index_calls_total",
-                                         self.engine.config.n_layers,
+                        self.metrics.inc("finchat_dsa_index_calls_total", latent_layers,
                                          labels={"form": self._index_form})
                     self._round_selected = selected[0]
             for slot, handle, epoch in step.members:

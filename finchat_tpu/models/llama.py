@@ -160,11 +160,20 @@ class LlamaConfig:
     gdn_value_dim: int = 0  # a head's values
     gdn_conv: int = 4  # width of the causal depthwise conv over q, k, v
     gdn_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues allowed
+    # 0: the decay a scalar a head and a SiLU output gate as wide as the values
+    # (Olmo-Hybrid). r > 0 (Kimi Delta Attention): the decay a VECTOR over a
+    # head's key channels through a projection of rank r (``dt_bias`` one a
+    # channel), and a SIGMOID output gate through another of rank r with a bias
+    gdn_gate_rank: int = 0
     # latent attention (models/mla.py): q and kv go through low-rank latents
-    # with an RMSNorm on each; ONE row a token, [c_kv | k_rope], is key and
-    # value at once for every head, and it is what the pool's pages hold.
+    # with an RMSNorm on each (``q_lora_rank`` 0: q is ONE projection of the
+    # input, no latent and no norm); ONE row a token, [c_kv | k_rope], is key
+    # and value at once for every head, and it is what the pool's pages hold.
     # kv_lora_rank 0 = none: today's block. ``head_dim`` is then a head's
-    # q/k width, qk_nope_dim + qk_rope_dim, and ``n_kv_heads`` 1
+    # q/k width, qk_nope_dim + qk_rope_dim, and ``n_kv_heads`` 1. A KIND's, not
+    # a model's: under a ``layer_pattern`` the FULL layers are the latent ones
+    # (they own the pool's pages) beside LINEAR layers that own state by slot.
+    # ``rope_theta`` None: neither q_rope nor k_rope is rotated
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
@@ -203,9 +212,9 @@ class LlamaConfig:
     # dense layers in front of the routed ones (an MLP of ``dense_hidden_dim``
     # in the experts' place): stacks of their own, ``params["dense_layers"]``,
     # run before the scan; ``n_layers`` counts them and a ``layer_pattern``
-    # covers the layers behind them. ``leading_kinds`` names each one's kind of
-    # attention (FULL or WINDOW; empty = every one FULL): its pages come first
-    # in its kind's pool
+    # covers the layers behind them. ``leading_kinds`` names each one's kind
+    # (FULL or WINDOW; empty = every one FULL): its pages come first
+    # in its kind's pool; or LINEAR: its state comes first in the state stack
     leading_dense_layers: int = 0
     dense_hidden_dim: int = 0
     leading_kinds: tuple[str, ...] = ()
@@ -268,17 +277,20 @@ class LlamaConfig:
                 raise ValueError(
                     "latent attention: head_dim is qk_nope_dim + qk_rope_dim and n_kv_heads 1 "
                     "(one latent row a token serves every head)")
-            if not (self.q_lora_rank and self.v_head_dim and self.rope_theta is not None):
-                raise ValueError("latent attention comes with q_lora_rank, v_head_dim and a "
-                                 "rotation (rope_theta)")
-            if (self.layer_pattern or self.ssm_heads or self.qk_norm or self.norm_after
-                    or self.qk_head_norm or self.attn_gate or self.norm_both):
-                raise ValueError("latent attention is not combined with a layer_pattern, a "
-                                 "mixer, a q/k norm, an output gate, norm_after or norm_both")
+            if not self.v_head_dim:
+                raise ValueError("latent attention comes with v_head_dim")
+            if self.rope_theta is None and self.rope_scaling is not None:
+                raise ValueError("rope_scaling corrects a rotation: it comes with a rope_theta")
+            if (self.ssm_heads or self.qk_norm or self.norm_after or self.qk_head_norm
+                    or self.attn_gate or self.norm_both):
+                raise ValueError("latent attention is not combined with a Mamba-2 mixer, a q/k "
+                                 "norm, an output gate, norm_after or norm_both")
         if bool(self.index_topk) != bool(self.index_heads and self.index_head_dim):
             raise ValueError("index_topk, index_heads and index_head_dim go together")
         if self.index_topk and not self.kv_lora_rank:
             raise ValueError("the indexer's selection is latent attention's (kv_lora_rank)")
+        if self.index_topk and not self.q_lora_rank:
+            raise ValueError("the indexer's queries come off the q latent (q_lora_rank)")
         if self.moe_score not in ("softmax", "sigmoid"):
             raise ValueError(f"moe_score {self.moe_score!r}: 'softmax' or 'sigmoid'")
         if self.moe_score == "softmax" and (
@@ -297,9 +309,9 @@ class LlamaConfig:
                              "routed ones")
         if self.leading_kinds and (
                 len(self.leading_kinds) != self.leading_dense_layers
-                or set(self.leading_kinds) - {FULL, WINDOW}):
-            raise ValueError(f"leading_kinds names each leading dense layer {FULL!r} or "
-                             f"{WINDOW!r}")
+                or set(self.leading_kinds) - {FULL, WINDOW, LINEAR}):
+            raise ValueError(f"leading_kinds names each leading dense layer {FULL!r}, "
+                             f"{WINDOW!r} or {LINEAR!r}")
         pattern = self.layer_pattern
         if pattern:
             if set(pattern) - {FULL, LINEAR, MAMBA, WINDOW} or self.n_scan_layers % len(pattern):
@@ -317,6 +329,11 @@ class LlamaConfig:
                                  "recurrent state by slot has one shape")
         if (LINEAR in pattern) != bool(self.gdn_heads):
             raise ValueError(f"gdn_heads and {LINEAR!r} layers in layer_pattern go together")
+        if LINEAR in self.leading_kinds and LINEAR not in pattern:
+            raise ValueError(f"a leading {LINEAR!r} layer stands in front of a layer_pattern "
+                             "with such layers (the state stack is theirs)")
+        if self.gdn_gate_rank and not self.gdn_heads:
+            raise ValueError("gdn_gate_rank is the linear layers' (gdn_heads)")
         if not self.moe_router_width:
             object.__setattr__(self, "moe_router_width", self.n_experts)
         if self.moe_router_width < self.n_experts:
@@ -388,9 +405,11 @@ class LlamaConfig:
         THE place a page's width is decided (``PagedKVCache.create`` and
         ``page_hbm_bytes`` read it): K and V heads side by side, or for latent
         attention the latent row in the first array and the indexer's key row
-        in the second (one column, never read, without an indexer)."""
+        in the second (without an indexer ONE lane tile, never read: a minor
+        dimension of 1 is padded to 128 lanes in HBM whatever the shape says,
+        and the append's slab copy takes whole tiles only)."""
         if self.kv_lora_rank:
-            return self.latent_row, self.index_head_dim or 1
+            return self.latent_row, self.index_head_dim or 128
         return (self.n_kv_heads * self.head_dim,) * 2
 
     @property
@@ -563,6 +582,12 @@ def n_params(config: LlamaConfig) -> int:
     d_v = c.gdn_heads * c.gdn_value_dim
     linear = (d * (c.gdn_conv_dim + d_v + 2 * c.gdn_heads) + d_v * d
               + c.gdn_conv * c.gdn_conv_dim + 2 * c.gdn_heads + c.gdn_value_dim)
+    if c.gdn_gate_rank:
+        # [q | k | v] in, [W_f1 | W_g1 | w_b], W_f2, W_g2 and its bias, out, the
+        # conv, A_log a head, dt_bias a key channel, the norm
+        r, d_k = c.gdn_gate_rank, c.gdn_heads * c.gdn_key_dim
+        linear = (d * (c.gdn_conv_dim + 2 * r + c.gdn_heads) + r * (d_k + d_v) + d_v + d_v * d
+                  + c.gdn_conv * c.gdn_conv_dim + c.gdn_heads + d_k + c.gdn_value_dim)
     total = (c.vocab_size * d + c.n_scan_layers * per_layer + c.n_kv_layers * attn
              + c.leading_dense_layers * (3 * d * c.dense_hidden_dim + norms)
              + c.n_of(LINEAR) * linear + c.n_of(MAMBA) * ssm + d)
@@ -644,7 +669,11 @@ def init_params(
         return params
     keys = jax.random.split(k_layers, 8)
     L, D, H, Hkv, hd, F = c.n_scan_layers, c.dim, c.n_heads, c.n_kv_heads, c.head_dim, c.hidden_dim
-    La = c.n_kv_layers - c.leading_dense_layers
+    # the leading dense layers' stacks are by kind too: the attention leaves as
+    # deep as the FULL and WINDOW ones among them, the ``gdn_*`` as the LINEAR
+    Ld_linear = c.n_leading_of(LINEAR)
+    Ld_attn = c.leading_dense_layers - Ld_linear
+    La = c.n_kv_layers - Ld_attn
 
     def attention_leaves(depth: int, ks: Array) -> dict[str, Array]:
         if c.kv_lora_rank:
@@ -673,7 +702,9 @@ def init_params(
         Ld, Fd = c.leading_dense_layers, c.dense_hidden_dim
         kd = jax.random.split(jax.random.fold_in(k_layers, 3), 7)
         params["dense_layers"] = {
-            **attention_leaves(Ld, kd),
+            **(attention_leaves(Ld_attn, kd) if Ld_attn else {}),
+            **(gdn.init_params(c, jax.random.fold_in(kd[0], 2), Ld_linear, rand_init)
+               if Ld_linear else {}),
             **norm_leaves(Ld),
             "mlp_gate": rand_init("mlp_gate", kd[4], (Ld, D, Fd), D),
             "mlp_up": rand_init("mlp_up", kd[5], (Ld, D, Fd), D),
@@ -746,13 +777,13 @@ def init_params(
         )
     if c.qk_norm or c.qk_head_norm:
         q_width, k_width = (hd, hd) if c.qk_head_norm else (H * hd, Hkv * hd)
-        for stack, depth in (("layers", La), ("dense_layers", c.leading_dense_layers)):
+        for stack, depth in (("layers", La), ("dense_layers", Ld_attn)):
             if depth:
                 params[stack].update({"attn_q_norm": jnp.ones((depth, q_width), c.dtype),
                                       "attn_k_norm": jnp.ones((depth, k_width), c.dtype)})
     if c.gdn_heads:
         params["layers"].update(gdn.init_params(
-            c, jax.random.fold_in(k_layers, 2), c.n_of(LINEAR), rand_init))
+            c, jax.random.fold_in(k_layers, 2), c.n_of(LINEAR) - Ld_linear, rand_init))
     if not c.tie_embeddings:
         params["lm_head"] = rand_init("lm_head", k_head, (D, c.vocab_size), D)
     return params
@@ -1084,6 +1115,7 @@ def _layer(
         return rms_norm(y, layer_params[name], c.norm_eps) if c.norm_after else y
 
     h = norm_in(x, "ln_attn")
+    selected = jnp.int32(0)  # (a latent model's LINEAR layers attend to nothing)
     if kind == LINEAR:
         assert tp_axis is None, "manual-TP stage blocks have no linear-attention layers"
         mixed, ssm_cache = gdn.mixer(h, layer_params, c, ssm_cache, layer_idx, ssm_rows,
@@ -1325,8 +1357,16 @@ def forward(
         (3 if c.kv_lora_rank else 2,), jnp.int32))
     leading = c.kinds_of_leading
     for i, kind in enumerate(leading):
-        carry = one_layer(carry, jax.tree.map(lambda a, i=i: a[i], params["dense_layers"]),
-                          jnp.int32(leading[:i].count(kind)), kind, dense_mlp=True)
+        def at(name):  # the layer's place in the leading stack ``name``, by kind as the scan's
+            kinds = _stack_kinds(name)
+            if kinds is None:
+                return i
+            return sum(leading[:i].count(k) for k in kinds) if kind in kinds else None
+
+        carry = one_layer(
+            carry, {name: jax.tree.map(lambda a, j=at(name): a[j], leaf)
+                    for name, leaf in params["dense_layers"].items() if at(name) is not None},
+            jnp.int32(leading[:i].count(kind)), kind, dense_mlp=True)
     (x, new_cache, ssm_cache, experts), _ = lax.scan(
         scan_body, carry, (None if by_index else stacks, jnp.arange(n_periods)))
     if ssm_cache is not None:

@@ -24,6 +24,13 @@ and ``S_t`` the ``index_topk`` tokens ``j <= t`` with the largest ``I[t, j]``.
 
 The rotation pairs dimension ``i`` with ``i + half`` (the program's ``rope``
 convention; a weight-layout choice), at YaRN's corrected frequencies.
+
+Two pieces are data of the config and absent where it says so. ``q_lora_rank``
+0: ``q = W_q h`` in ONE projection, no latent and no norm (leaves
+``attn_q_nope`` / ``attn_q_rope`` input-major ``[D, H x]``; there is then no
+``c_q`` for an indexer to read, and none is built). ``rope_theta`` None:
+neither ``q_rope`` nor ``k_r`` is rotated (NoPE: the heads' last
+``qk_rope_dim`` dims are then plain dims that all heads share one key for).
 """
 
 from __future__ import annotations
@@ -133,16 +140,26 @@ def project(h: Array, lp: dict[str, Array], c, positions: Array,
 
     B, S, _ = h.shape
     H, R, rope_d = c.n_heads, c.kv_lora_rank, c.qk_rope_dim
-    inv_freq, mult = rope_tables(rope_d, c.rope_theta, c.rope_scaling)
-    c_q = rms(dense(h, lp["attn_q_a"], qm_backend=qm_backend), lp["attn_q_a_norm"], c.norm_eps)
-    q_nope = _from_latent(c_q, lp["attn_q_nope"]).reshape(B, S, H, -1)
-    q_rope = _from_latent(c_q, lp["attn_q_rope"]).reshape(B, S, H, -1)
+    rotated = c.rope_theta is not None  # (None: NoPE, the "rope" dims are plain dims)
+    if rotated:
+        inv_freq, mult = rope_tables(rope_d, c.rope_theta, c.rope_scaling)
+
+    def turn(x: Array) -> Array:
+        return rotate(x, positions, inv_freq, mult) if rotated else x
+    if c.q_lora_rank:
+        c_q = rms(dense(h, lp["attn_q_a"], qm_backend=qm_backend), lp["attn_q_a_norm"],
+                  c.norm_eps)
+        q_nope = _from_latent(c_q, lp["attn_q_nope"]).reshape(B, S, H, -1)
+        q_rope = _from_latent(c_q, lp["attn_q_rope"]).reshape(B, S, H, -1)
+    else:  # q is one projection of the input: no latent, no norm
+        q_nope = flat_fence(dense(h, lp["attn_q_nope"], qm_backend=qm_backend)).reshape(B, S, H, -1)
+        q_rope = flat_fence(dense(h, lp["attn_q_rope"], qm_backend=qm_backend)).reshape(B, S, H, -1)
     kv = dense(h, lp["attn_kv_a"], qm_backend=qm_backend)  # [B,S,R+rope]
     c_kv = rms(kv[..., :R], lp["attn_kv_a_norm"], c.norm_eps)
-    k_rope = rotate(kv[..., R:], positions, inv_freq, mult)
+    k_rope = turn(kv[..., R:])
     q_abs = jnp.einsum("bshn,hnr->bshr", q_nope, lp["attn_uk"],
                        preferred_element_type=jnp.float32).astype(h.dtype)
-    q_abs = jnp.concatenate([q_abs, rotate(q_rope, positions, inv_freq, mult)], axis=-1)
+    q_abs = jnp.concatenate([q_abs, turn(q_rope)], axis=-1)
     pad = jnp.zeros((B, S, c.latent_row - R - rope_d), h.dtype)
     row = jnp.concatenate([c_kv, k_rope, pad], axis=-1)
     if not c.index_topk:
@@ -156,8 +173,7 @@ def project(h: Array, lp: dict[str, Array], c, positions: Array,
         * lp["attn_idx_k_norm"] + lp["attn_idx_k_bias"]
     idx_w = dense(h, lp["attn_idx_w"], qm_backend=qm_backend).astype(jnp.float32) \
         * (Hi ** -0.5 * Di ** -0.5)  # the heads' and the dot's scales, once
-    return LatentInputs(q_abs, row, rotate(idx_q, positions, inv_freq, mult),
-                        idx_w, rotate(idx_k, positions, inv_freq, mult))
+    return LatentInputs(q_abs, row, turn(idx_q), idx_w, turn(idx_k))
 
 
 def up_values(o_latent: Array, lp: dict[str, Array], c) -> Array:
@@ -170,8 +186,10 @@ def up_values(o_latent: Array, lp: dict[str, Array], c) -> Array:
 def n_attention_params(c) -> int:
     """One layer's attention and indexer parameters."""
     d, H = c.dim, c.n_heads
-    attn = (d * c.q_lora_rank + c.q_lora_rank + c.q_lora_rank * H * c.head_dim
-            + d * (c.kv_lora_rank + c.qk_rope_dim) + c.kv_lora_rank
+    # q through its latent (down, norm, up), or one projection of the input
+    q = (d * c.q_lora_rank + c.q_lora_rank + c.q_lora_rank * H * c.head_dim
+         if c.q_lora_rank else d * H * c.head_dim)
+    attn = (q + d * (c.kv_lora_rank + c.qk_rope_dim) + c.kv_lora_rank
             + c.kv_lora_rank * H * (c.qk_nope_dim + c.v_head_dim) + H * c.v_head_dim * d)
     if c.index_topk:
         attn += (c.q_lora_rank * c.index_heads * c.index_head_dim + d * c.index_head_dim
@@ -184,15 +202,25 @@ def init_attention(c, key: Array, depth: int, rand_init: Callable) -> dict[str, 
     indexer's with ``index_topk``), stacked."""
     d, H, R, Q = c.dim, c.n_heads, c.kv_lora_rank, c.q_lora_rank
     ks = jax.random.split(key, 8)
+    if Q:
+        q_leaves = {
+            "attn_q_a": rand_init("attn_q_a", ks[0], (depth, d, Q), d),
+            "attn_q_a_norm": jnp.ones((depth, Q), c.dtype),
+            # W_uq's columns by what they make: every head's q_nope, every head's
+            # q_rope (one matrix whose heads are [128 | 64] wide was re-laid every step)
+            # (both output-major, [N, Q]: ``_from_latent``)
+            "attn_q_nope": rand_init("attn_q_nope", ks[1], (depth, H * c.qk_nope_dim, Q), Q),
+            "attn_q_rope": rand_init("attn_q_rope", jax.random.fold_in(ks[1], 1),
+                                     (depth, H * c.qk_rope_dim, Q), Q),
+        }
+    else:  # W_q's columns by what they make, input-major as every projection of h
+        q_leaves = {
+            "attn_q_nope": rand_init("attn_q_nope", ks[1], (depth, d, H * c.qk_nope_dim), d),
+            "attn_q_rope": rand_init("attn_q_rope", jax.random.fold_in(ks[1], 1),
+                                     (depth, d, H * c.qk_rope_dim), d),
+        }
     leaves = {
-        "attn_q_a": rand_init("attn_q_a", ks[0], (depth, d, Q), d),
-        "attn_q_a_norm": jnp.ones((depth, Q), c.dtype),
-        # W_uq's columns by what they make: every head's q_nope, every head's
-        # q_rope (one matrix whose heads are [128 | 64] wide was re-laid every step)
-        # (both output-major, [N, Q]: ``_from_latent``)
-        "attn_q_nope": rand_init("attn_q_nope", ks[1], (depth, H * c.qk_nope_dim, Q), Q),
-        "attn_q_rope": rand_init("attn_q_rope", jax.random.fold_in(ks[1], 1),
-                                 (depth, H * c.qk_rope_dim, Q), Q),
+        **q_leaves,
         "attn_kv_a": rand_init("attn_kv_a", ks[2], (depth, d, R + c.qk_rope_dim), d),
         "attn_kv_a_norm": jnp.ones((depth, R), c.dtype),
         # W_ukv's two halves, each as the absorbed form multiplies by it: a

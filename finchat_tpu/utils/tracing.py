@@ -151,7 +151,7 @@ DEVICE_SCOPES = frozenset({
     # the Mamba-2 mixer beside attention (models/ssm.py)
     "ssm_in", "ssm_conv", "ssm_scan", "ssm_norm", "ssm_out",
     # the gated delta rule of a linear-attention layer (models/gdn.py)
-    "gdn_in", "gdn_conv", "gdn_scan", "gdn_norm", "gdn_out",
+    "gdn_in", "gdn_conv", "gdn_gate", "gdn_scan", "gdn_norm", "gdn_out",
     # the attention callbacks (engine/engine.py)
     "kv_append", "kv_scatter", "paged_attention",
     "kv_scatter_ragged", "ragged_paged_attention",

@@ -129,6 +129,7 @@ LONGEST_FIRST = (
     "test_falcon_h1", "test_kv_quant_engine", "test_paged_walk_engine", "test_ssm_step_kernel",
     "test_quant_serving", "test_quant_matmul", "test_quant", "test_hf_loader",
     "test_ragged_attention", "test_checkpoint_io", "test_mixed_step",
+    "test_kimi_linear_engine", "test_kimi_linear_model", "test_kimi_linear",
 )
 
 

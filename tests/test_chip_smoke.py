@@ -103,6 +103,17 @@ def test_gdn_step_at_cell_shape_values():
     assert set(errors) == {"o", "state"} and errors["o"] < 1e-5
 
 
+@pytest.mark.parametrize("backend", ["ref", "pallas-interpret"])
+def test_gdn_step_with_a_decay_a_key_channel_at_cell_shape_values(backend):
+    """The same check in the rule's second form (Kimi Delta Attention, PR 51):
+    the decay a vector over a head's key channels, one channel in eight at
+    exp(-80), through XLA's ``_step`` and through the interpreted kernel."""
+    errors = chip_smoke.check_gdn_step_at_cell_shape(
+        backend, rows=4, heads=4, key_dim=8, value_dim=128, layers=3, layer=1,
+        channel_decay=True)
+    assert set(errors) == {"o", "state"} and errors["o"] < 1e-5
+
+
 @pytest.mark.no_stall_sanitizer
 def test_serving_function_at_tiny_interpret(monkeypatch):
     """The whole serving phase — start-up, logits parity, HTTP, Kafka, the

@@ -523,7 +523,8 @@ def test_quantized_weights_a_mesh_and_disk_records_are_refused():
     ({"moe_score": "softmax"}, "'sigmoid' router's"),
     ({"moe_groups": 3}, "moe_groups divides"),
     ({"dense_hidden_dim": 0}, "leading_dense_layers"),
-    ({"layer_pattern": ("full_attention",)}, "layer_pattern"),
+    # (latent attention under a layer_pattern is built since PR 51: tests/test_model.py)
+    ({"layer_pattern": ("full_attention", "sliding_attention"), "window": 8}, "stand beside"),
 ])
 def test_configs_that_do_not_hold_together_are_refused(fields, said):
     with pytest.raises(ValueError, match=said):

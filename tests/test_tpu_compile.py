@@ -906,3 +906,119 @@ def test_a_window_walk_compiles_for_v5e_with_no_stacked_scratch(one_chip, heads,
     assert len(calls) == 1 and calls[0].split(" = ")[0].strip().startswith("%paged_flash_attention")
     assert "vmem_limit_bytes" not in calls[0]
 
+
+
+# --- kimi-linear-48b-a3b (PR 51): the state step with a decay a key channel, latent pages walked
+# --- with no selection, both in one decode step ---
+
+def test_the_state_step_with_a_decay_a_key_channel_compiles_for_v5e_at_kimi_linears_tiles(one_chip):
+    """``ops/gdn_step.py``'s second form at ``kimi-linear-report-saturated``'s
+    shape: 32 rows of 32 heads of 128 x 128 float32 — a head a tile, 2 MiB a
+    row, four rows a block — in layer 4 of 7: ONE custom call, the state
+    aliased to its output (no copy of the 470 MB leaf), ``alpha`` arriving as a
+    ``[dk, H]`` column beside k and q."""
+    from finchat_tpu.ops.gdn_step import gdn_state_step
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, heads, dk, dv = 32, 32, 128, 128
+    compiled = gdn_state_step.lower(
+        shape((7, rows, heads, dk, dv)), shape((rows, heads, dk)), shape((rows, heads, dk)),
+        shape((rows, heads, dv)), shape((rows, heads, dk)), shape((rows, heads)),
+        shape((1,), jnp.int32)).compile()
+    text = compiled.as_text()
+    calls = [line.split(" = ")[0] for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "gdn_state_step" in calls[0]
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 7 * rows * heads * dk * dv * 4
+    assert memory.temp_size_in_bytes < 8 * MIB
+    # the scalar form at the same tiles is the program it was: alpha a lane vector
+    scalar = gdn_state_step.lower(
+        shape((7, rows, heads, dk, dv)), shape((rows, heads, dk)), shape((rows, heads, dk)),
+        shape((rows, heads, dv)), shape((rows, heads)), shape((rows, heads)),
+        shape((1,), jnp.int32)).compile()
+    assert f"f32[{rows},4,{dk},{heads}]" in text and f"f32[{rows},2,{dk},{heads}]" in scalar.as_text()
+
+
+def test_latent_pages_walk_with_no_selection_for_v5e_at_kimi_linears_shape(one_chip):
+    """``decode_attention`` with ``topk`` 0 on a kernel backend at the cell's
+    shape: 32 rows, 32 heads of 576 over pages of 640-column latent rows, a
+    table of 256 pages (32,768 tokens), a pool two layers deep: every token is
+    attended, so there is no indexer and no selection — ONE custom call under
+    ``mla_attention`` (``paged_latent_attention``), nothing under
+    ``dsa_indexer`` / ``dsa_select``, no gathered copy of the table's rows."""
+    from finchat_tpu.ops.latent_attention import LatentShape, decode_attention, decode_form
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    rows, table, pool = 32, 256, 5120
+    assert decode_form("pallas", table * PAGE, 0) == "walk"
+    compiled = jax.jit(lambda q, pages, keys, layer, page_table, kv_len, live: decode_attention(
+        q, None, None, pages, keys, layer, page_table, kv_len, live, page_size=PAGE,
+        shape=LatentShape(512, 0, 192 ** -0.5), backend="pallas")).lower(
+        shape((rows, 32, 576)), shape((2, pool, PAGE, 640)), shape((2, pool, PAGE, 128)),
+        shape((), jnp.int32), shape((rows, table), jnp.int32), shape((rows,), jnp.int32),
+        shape((rows,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if "tpu_custom_call" in line and " custom-call(" in line]
+    assert len(calls) == 1 and "/mla_attention/" in calls[0]
+    assert "paged_latent_attention" in calls[0].split(" = ")[0]
+    assert "/dsa_indexer/" not in text and "/dsa_select/" not in text and " sort(" not in text
+    assert "bf16[32768,640]" not in text and f"bf16[{rows},32768,640]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
+
+
+def test_kv_append_compiles_for_v5e_with_a_latent_row_and_a_lane_tile_nothing_reads(one_chip):
+    """A latent model without an indexer: the pool's second array is ONE lane
+    tile (``LlamaConfig.kv_row_widths``: Mosaic refuses a slab of 1 column —
+    "must be aligned to tiling (128)" — and a minor dimension of 1 is padded to
+    128 lanes in HBM anyway), written by the same ONE custom call."""
+    from finchat_tpu.ops.kv_append import paged_kv_append
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    file = _config_file("kimi-linear-48b-a3b")
+    from perfbench.models import adapter
+
+    c = adapter(file).program_config(file)
+    assert c.kv_row_widths == (640, 128) and not c.index_topk
+    compiled = jax.jit(lambda *args: paged_kv_append(*args, page_size=PAGE)).lower(
+        shape((32, 1, 640 + 128)), shape((2, 5120, PAGE, 640)), shape((2, 5120, PAGE, 128)),
+        shape((32, 256), jnp.int32), shape((32,), jnp.int32), shape((32,), jnp.int32),
+        shape((1,), jnp.int32)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 * 1024
+
+
+def test_kimi_linear_decode_step_compiles_for_v5e_with_pages_and_state_in_place(one_chip):
+    """The whole decode step at the cell's size (1 dense KDA layer + 8 routed
+    layers, 32 slots, 32 of 256 experts held, a table of 256 pages): the state
+    step in its second form once for the leading layer outside the scan and
+    three times in its body, ONE latent walk and ONE append in the body's
+    latent layer, the touched-expert pass four times; the latent pool AND the
+    recurrent state updated in place; temporaries under a tenth of a GB."""
+    file = _config_file("kimi-linear-48b-a3b")
+    compiled, state = _compiled_decode_step(one_chip, file)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and " custom-call(" in line]
+
+    def count(kernel, inside_scan=None):
+        return sum(kernel in c.split(" = ")[0] and (inside_scan is None
+                                                    or ("/while/body/" in c) == inside_scan)
+                   for c in calls)
+
+    assert (count("gdn_state_step", False), count("gdn_state_step", True)) == (1, 3)
+    assert (count("paged_latent_attention", False), count("paged_latent_attention", True)) == (0, 1)
+    assert count("paged_kv_append") == 1 and count("moe_experts_step") == 4
+    assert all("/gdn_scan/" in c for c in calls if "gdn_state_step" in c.split(" = ")[0])
+    assert "/gdn_gate/" in text and "/dsa_indexer/" not in text and "/swa_attention/" not in text
+    assert state.ssm_state.shape == (7, 32, 32, 128, 128) and state.k_pages.shape == (2, 5120, 128, 640)
+    memory = compiled.memory_analysis()
+    in_place = sum(x.size * x.dtype.itemsize for x in (state.k_pages, state.v_pages, state.ssm_state))
+    assert memory.alias_size_in_bytes >= in_place and memory.temp_size_in_bytes < 0.1e9

@@ -1,6 +1,6 @@
 """The configurations' blocks at a size a test holds, built once: what
 tests/test_falcon_h1.py, test_olmo_hybrid.py, test_granite_hybrid.py,
-test_deepseek_v32.py, test_phi4_flash.py and test_trinity_mini.py check against the plain references
+test_deepseek_v32.py, test_phi4_flash.py, test_trinity_mini.py and test_kimi_linear.py check against the plain references
 of ``perfbench/models``, and what tests/test_decode_pipeline.py serves, one
 for each kind of per-row memory the engine has.
 
@@ -32,10 +32,10 @@ from finchat_tpu.models.llama import (
 # name -> its adapter under perfbench/models
 ADAPTERS = {"falcon_h1": "falcon_h1", "olmo_hybrid": "olmo_hybrid",
             "granite_hybrid": "granitemoehybrid", "deepseek_v32": "deepseek_v32",
-            "phi4_flash": "phi4flash", "trinity_mini": "afmoe"}
+            "phi4_flash": "phi4flash", "trinity_mini": "afmoe", "kimi_linear": "kimi_linear"}
 SHAPES = {"tiny": (8, 16, 4), "falcon_h1": (16, 12, 4), "olmo_hybrid": (16, 12, 4),
           "granite_hybrid": (16, 12, 4), "deepseek_v32": (16, 12, 4), "phi4_flash": (4, 8, 4),
-          "trinity_mini": (4, 8, 4)}
+          "trinity_mini": (4, 8, 4), "kimi_linear": (16, 12, 4)}
 FILES: dict[str, dict] = {}
 
 # Falcon-H1's block at a size a test holds: head_dim 32 is not 64 / 4, two
@@ -142,6 +142,30 @@ FILES["trinity_mini"] = {
     "hidden_act": "silu", "rope_theta": 10000, "rope_scaling": None, "rms_norm_eps": 1e-5,
     "vocab_size": 211, "tie_word_embeddings": False,
     "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
+}
+
+# Kimi-Linear's block at a size a test holds: ONE leading dense KDA layer, then
+# two whole periods (KDA, KDA, latent, KDA); 4 KDA heads of 16 (keys and values
+# alike, the gate's rank 16), 4 latent heads of [16 | 8] over a latent of 32
+# with no q latent and no rotation; 16 routed experts of 32 at 2 a token (over
+# MOE_DENSE_WASTE_MAX x 2: it routes sparsely, as the published 256 at 8 do)
+# of which 8 are held, beside a shared one
+FILES["kimi_linear"] = {
+    "model_type": "kimi_linear", "hidden_size": 64, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "num_experts": 8, "num_experts_per_token": 2,
+    "reduced": {"num_experts": {"from": 16, "to": 8, "why": "a chip's share"}},
+    "num_shared_experts": 1, "first_k_dense_replace": 1, "num_hidden_layers": 9,
+    "linear_attn_config": {"kda_layers": [1, 2, 3, 5, 6, 7, 9], "full_attn_layers": [4, 8],
+                           "head_dim": 16, "num_heads": 4, "short_conv_kernel_size": 4},
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 16,
+    "q_lora_rank": None, "kv_lora_rank": 32, "qk_nope_head_dim": 16, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "mla_use_nope": True, "rope_theta": 10000, "rope_scaling": None,
+    "moe_router_activation_func": "sigmoid", "moe_renormalize": True, "moe_layer_freq": 1,
+    "num_expert_group": 1, "topk_group": 1, "use_grouped_topk": True,
+    "routed_scaling_factor": 2.446, "num_nextn_predict_layers": 0, "hidden_act": "silu",
+    "rms_norm_eps": 1e-5, "vocab_size": 300, "tie_word_embeddings": False,
+    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
+    "ssm_state_dtype": "float32",
 }
 
 
