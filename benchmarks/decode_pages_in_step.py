@@ -1,22 +1,25 @@
-"""A one-token step's page traffic INSIDE a configuration's compiled decode
-step: the appends and the walks, a custom call at a time (PERF.md section 6,
-PR 49).
+"""A configuration's compiled decode step, a custom call at a time: the ONE
+in-step probe (ROADMAP D16; PERF.md section 6, PRs 49 and 52).
 
-A kernel alone does not predict what it reads inside the step (PR 48), so the
-append's slab and a window walk's block are judged here: ``engine.decode_step``
-of ``perfbench/configs/<configuration>.json`` at the file's engine options on
-seeded weights, every slot active at ``--context`` tokens on pages of its own
-(no shared head), a window layer's table holding the row's last window as the
-pager leaves it (``window / page + 1`` live pages of ``+ 2`` columns, the
-coordinates compacted), ``--steps`` steps in one ``jax.profiler`` capture. One
-JSON line: the step's device time and, for every custom call whose name holds
-``paged``, calls a step and the mean time of one. ``--blocks`` gives a window
-walk's pages a block by hand (comma-separated, a line each; 0 = the rule's),
-``--tree`` times another checkout's package (the parent commit unpacked
-somewhere under the repo) on the same inputs. Runs on the chip only:
+A kernel alone does not predict what it reads inside the step (PR 48), so a
+change to a one-token kernel — the append's slab and a window walk's block
+(PR 49), the state update's tile (PR 52) — is judged here:
+``engine.decode_step`` of ``perfbench/configs/<configuration>.json`` at the
+file's engine options on seeded weights, every slot active at ``--context``
+tokens on pages of its own (no shared head), a window layer's table holding
+the row's last window as the pager leaves it (``window / page + 1`` live pages
+of ``+ 2`` columns, the coordinates compacted), ``--steps`` steps in one
+``jax.profiler`` capture. One JSON line: the step's device time and, for every
+custom call whose name holds ``--name`` (``paged``: the appends and the walks;
+``ssm_state_step``, ``gdn_state_step``, ``moe_experts_step``), calls a step
+and the mean time of one. ``--blocks`` gives a window walk's pages a block by
+hand (comma-separated, a line each; 0 = the rule's), ``--tree`` times another
+checkout's package (the parent commit unpacked somewhere under the repo) on
+the same inputs. Runs on the chip only:
 
     chiprun -- python3 benchmarks/decode_pages_in_step.py phi-4-mini-flash-reasoning
     chiprun -- python3 benchmarks/decode_pages_in_step.py trinity-mini --blocks 0,6,18
+    chiprun -- python3 benchmarks/decode_pages_in_step.py granite-4.0-h-small --name ssm_state_step
 
 The script runs in no cell. It stays because the next change to a one-token
 kernel is priced with it, parent against change, before a cell's runs are paid.
@@ -39,6 +42,8 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("configuration",
                     help="a file's name under perfbench/configs, without .json (or a path to such a file)")
+    ap.add_argument("--name", default="paged",
+                    help="the custom calls to time: those whose name holds this")
     ap.add_argument("--context", type=int, default=6000, help="every row's tokens")
     ap.add_argument("--blocks", default="0", help="a window walk's pages a block; 0 = the rule's")
     ap.add_argument("--steps", type=int, default=40)
@@ -109,13 +114,13 @@ def main(argv: list[str] | None = None) -> int:
             return tokens
 
         once().block_until_ready()
-        line = {"configuration": args.configuration, "tree": args.tree or ".",
+        line = {"configuration": args.configuration, "tree": args.tree or ".", "name": args.name,
                 "context": args.context, "window_block_pages": pages or "rule"}
         if on_chip:
             ops = chip_smoke.device_ops_us(once, args.steps)
             by_name = defaultdict(list)
             for name, us in ops:
-                if "paged" in name:
+                if args.name in name:
                     by_name[name.lstrip("%")].append(us)
             started = time.perf_counter()
             for _ in range(args.steps):
@@ -123,7 +128,7 @@ def main(argv: list[str] | None = None) -> int:
             tokens.block_until_ready()
             line.update(wall_us_a_step=round((time.perf_counter() - started) / args.steps * 1e6, 1),
                         step_us=round(sum(us for _n, us in ops) / args.steps, 1),
-                        paged_us_a_step=round(sum(sum(v) for v in by_name.values()) / args.steps, 1),
+                        calls_us_a_step=round(sum(sum(v) for v in by_name.values()) / args.steps, 1),
                         calls={name: [len(v) / args.steps, round(float(np.mean(v)), 2)]
                                for name, v in sorted(by_name.items())})
         print(json.dumps(line), flush=True)

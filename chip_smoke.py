@@ -373,8 +373,10 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     """The one-token Mamba-2 state update (``ops/ssm_step.py``) vs
     ``models/ssm.py``'s ``_step`` at the shape of the benchmark's cell
     (``falcon-h1-report-saturated``): 16 rows of 32 heads of 128 x 256 float32
-    in layer 3 of 5, one row inert. ``y`` and the layer's new state to float32
-    round-off; the inert row and every other layer bit for bit. On the chip
+    in layer 3 of 5, one row inert (``granite-h-small-report-saturated``'s by
+    its arguments: 128 heads of 64 x 128 in nine layers, stored as pairs).
+    ``y`` and the layer's new state to float32 round-off; the inert row and
+    every other layer bit for bit. On the chip
     (``pallas``) also the kernel's own device time from a profiler capture,
     against its stream bound (every row's state read and written once at
     819 GB/s: what ``ssm_state_roofline.sat`` divides by) — the number to
@@ -384,7 +386,7 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
     import numpy as np
 
     from finchat_tpu.models.ssm import _step
-    from finchat_tpu.ops.ssm_step import ssm_state_step
+    from finchat_tpu.ops.ssm_step import ssm_state_step, to_logical, to_stored
 
     f32, hg = jnp.float32, heads // groups
     ks = jax.random.split(jax.random.key(28), 7)
@@ -402,9 +404,10 @@ def check_ssm_step_at_cell_shape(backend: str, *, rows: int = 16, heads: int = 3
         xs.reshape(rows, groups, hg, head_dim), dt.reshape(rows, groups, hg),
         A.reshape(groups, hg), Bm, Cm, D.reshape(groups, hg))
     before = np.asarray(ssm_state)
-    y, ssm_state = ssm_state_step(ssm_state, xs, dt, A, Bm, Cm, D, at,
+    # (the kernel takes the state as the device holds it: ops/ssm_step.py stored_shape)
+    y, ssm_state = ssm_state_step(to_stored(ssm_state, groups), xs, dt, A, Bm, Cm, D, at,
                                   interpret=backend == "pallas-interpret")
-    after = np.asarray(ssm_state)
+    after = np.asarray(to_logical(ssm_state, (heads, head_dim, state), groups))
     errors = {}
     for name, got, want in (("y", y, want_y.reshape(y.shape)),
                             ("state", after[layer], np.asarray(want_new).reshape(after[layer].shape))):

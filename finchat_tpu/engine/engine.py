@@ -48,6 +48,7 @@ from finchat_tpu.engine.sampler import sample
 from finchat_tpu.models.llama import LlamaConfig, forward, lm_head
 from finchat_tpu.models.mla import LatentInputs
 from finchat_tpu.models.ssm import SsmRows
+from finchat_tpu.ops import ssm_step
 from finchat_tpu.ops.latent_attention import LatentShape, decode_form, index_form
 from finchat_tpu.ops.dispatch import paged_attention
 from finchat_tpu.utils.config import EngineConfig
@@ -131,7 +132,7 @@ class DecodeState:
     # without either, as k_scales has. The two kinds of cache have their own
     # depths: the pool the layers' that own pages (config.n_attn_layers),
     # these the layers' that carry state (config.n_state_layers)
-    ssm_state: Array  # [Ls, max_seqs, *config.state_shape] float32 — the recurrence's state
+    ssm_state: Array  # [Ls, max_seqs, *config.stored_state_shape] float32 — the recurrence's state
     conv_state: Array  # [Ls, max_seqs, K-1, C] float32 — the conv's last inputs
     # the second POOL (a model with sliding-window layers, config.window; None
     # for every other model: no leaf, no operand): the window layers' pages,
@@ -195,7 +196,7 @@ def _ssm_leaves(config: LlamaConfig, max_seqs: int) -> dict[str, Array]:
         return {"ssm_state": jnp.zeros((1, 1, 1, 1, 1), jnp.float32),
                 "conv_state": jnp.zeros((1, 1, 1, 1), jnp.float32)}
     return {
-        "ssm_state": jnp.zeros((c.n_state_layers, max_seqs, *c.state_shape), jnp.float32),
+        "ssm_state": jnp.zeros((c.n_state_layers, max_seqs, *c.stored_state_shape), jnp.float32),
         "conv_state": jnp.zeros((c.n_state_layers, max_seqs, *c.conv_shape), jnp.float32),
     }
 
@@ -267,16 +268,23 @@ def _ssm_clear_slots(ssm_state: Array, conv_state: Array, keep: Array):
             jnp.where(keep[None, :, None, None], conv_state, 0.0))
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _ssm_load_slot(ssm_state: Array, conv_state: Array, slot: Array, snap: tuple):
-    """Copy a snapshot (one slot's state, every layer) into ``slot``."""
-    return (jax.lax.dynamic_update_index_in_dim(ssm_state, snap[0], slot, 1),
+@partial(jax.jit, static_argnames=("config",), donate_argnums=(0, 1))
+def _ssm_load_slot(ssm_state: Array, conv_state: Array, slot: Array, snap: tuple, *,
+                   config: LlamaConfig):
+    """Copy a snapshot (one slot's state ``[Ls, *config.state_shape]``, every
+    layer) into ``slot``, as the device holds it."""
+    return (jax.lax.dynamic_update_index_in_dim(
+                ssm_state, config.state_to_stored(snap[0]), slot, 1),
             jax.lax.dynamic_update_index_in_dim(conv_state, snap[1], slot, 1))
 
 
-@jax.jit
-def _ssm_read_slot(ssm_state: Array, conv_state: Array, slot: Array):
-    return (jax.lax.dynamic_index_in_dim(ssm_state, slot, 1, keepdims=False),
+@partial(jax.jit, static_argnames=("config",))
+def _ssm_read_slot(ssm_state: Array, conv_state: Array, slot: Array, *, config: LlamaConfig):
+    """One slot's state, every layer, as the recurrence writes it
+    (``[Ls, *config.state_shape]``: a snapshot does not depend on how the
+    device lays the state out) and its conv tail."""
+    return (config.state_to_logical(
+                jax.lax.dynamic_index_in_dim(ssm_state, slot, 1, keepdims=False)),
             jax.lax.dynamic_index_in_dim(conv_state, slot, 1, keepdims=False))
 
 
@@ -1247,6 +1255,12 @@ class InferenceEngine:
         # decode step's indexer comes by its scores (latent_attention.index_form)
         self.index_form = index_form(attn_backend) if config.kv_lora_rank and (
             0 < config.index_topk < self.max_pages_per_seq * engine_cfg.page_size) else None
+        # a model with Mamba-2 layers on a kernel backend: the tile its decode
+        # step's state update works on, by the static shapes (ssm_step.tile_heads)
+        self.state_form = None
+        if config.ssm_heads and self.attn_backend != "ref":
+            pairs = ssm_step.tile_heads(*config.state_shape, config.ssm_groups) == 2
+            self.state_form = "pairs" if pairs else "heads"
         self.mesh = mesh
         # bounded-KV long-context serving (ISSUE 15): attention-sink +
         # sliding-window page eviction. The policy is pure host math; the
@@ -1450,7 +1464,8 @@ class InferenceEngine:
         for a model without a mixer."""
         if not self.config.has_state:
             return None
-        return _ssm_read_slot(self.state.ssm_state, self.state.conv_state, jnp.int32(slot))
+        return _ssm_read_slot(self.state.ssm_state, self.state.conv_state, jnp.int32(slot),
+                              config=self.config)
 
     def detach_head(self, slot: int) -> tuple | None:
         """What a shared head keeps of the slot that just prefilled it:
@@ -1481,7 +1496,8 @@ class InferenceEngine:
         those referenced in place of the slot's own)."""
         if self.config.has_state:
             ssm_state, conv_state = _ssm_load_slot(
-                self.state.ssm_state, self.state.conv_state, jnp.int32(slot), tuple(snap[:2]))
+                self.state.ssm_state, self.state.conv_state, jnp.int32(slot), tuple(snap[:2]),
+                config=self.config)
             self.state = dataclasses.replace(
                 self.state, ssm_state=ssm_state, conv_state=conv_state)
         if self.window_pager is not None and len(snap) > 2:

@@ -408,6 +408,8 @@ class ContinuousBatchingScheduler:
         self._latent_form = getattr(engine, "latent_form", None)
         # ... and the form its decode step's indexer takes (None: no indexer selects)
         self._index_form = getattr(engine, "index_form", None)
+        # a model with Mamba-2 layers: the tile its one-token state update works on
+        self._state_form = getattr(engine, "state_form", None)
         _wbits = {"": None, "int8": 8, "int4": 4}.get(
             getattr(engine, "quant", ""))
         _elem_bits = 8 * np.dtype(engine.config.dtype).itemsize
@@ -887,6 +889,7 @@ class ContinuousBatchingScheduler:
             prefix_rows=len(most) if len(most) > 1 else 0,
             **({"form": self._latent_form} if self._latent_form else {}),
             **({"index_form": self._index_form} if self._index_form else {}),
+            **({"state_form": self._state_form} if self._state_form else {}),
             # a model with sliding-window layers: the tokens ONE window layer
             # reads (a row's context up to the window)
             **({"window_kv_tokens": sum(min(kv, self._window) for *_row, kv in riders)}

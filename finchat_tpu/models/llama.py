@@ -28,7 +28,7 @@ from jax import Array, lax
 from finchat_tpu.models import gdn, mla, sambay
 from finchat_tpu.models.quant import Q4Tensor, QTensor, dense, dequantize, flat_fence
 from finchat_tpu.models.ssm import mixer, scaled
-from finchat_tpu.ops import moe_step
+from finchat_tpu.ops import moe_step, ssm_step
 
 # attention callback signature:
 #   fn(q[B,S,H,D], k[B,S,Hkv,D], v[B,S,Hkv,D], layer_cache, layer_idx) ->
@@ -447,6 +447,29 @@ class LlamaConfig:
         if self.m1_inner:  # a MAMBA1 layer's [N, E]: the channels along the lanes
             return (1, self.m1_state, self.m1_inner)
         return (self.ssm_heads, self.ssm_head_dim, self.ssm_state)
+
+    @property
+    def stored_state_shape(self) -> tuple[int, ...]:
+        """``state_shape`` as the device holds it (``create_state``'s leaf).
+        A Mamba-2 head narrower than a lane tile is stored in pairs with the
+        state axis on sublanes (``ops/ssm_step.py`` ``stored_shape``: the
+        one-token kernel's form, by the static shapes; ``state_to_logical`` is
+        the view back); every other state lies as ``state_shape`` says."""
+        if self.gdn_heads or self.m1_inner:
+            return self.state_shape
+        return ssm_step.stored_shape(*self.state_shape, self.ssm_groups)
+
+    def state_to_logical(self, stored: Array) -> Array:
+        """``[..., *stored_state_shape]`` as ``[..., *state_shape]``."""
+        if self.stored_state_shape == self.state_shape:
+            return stored
+        return ssm_step.to_logical(stored, self.state_shape, self.ssm_groups)
+
+    def state_to_stored(self, state: Array) -> Array:
+        """``[..., *state_shape]`` as ``[..., *stored_state_shape]``."""
+        if self.stored_state_shape == self.state_shape:
+            return state
+        return ssm_step.to_stored(state, self.ssm_groups)
 
     @property
     def gdn_tile_heads(self) -> int:

@@ -23,7 +23,14 @@ page. ``SsmRows`` tells the mixer which slot each batch row starts from and
 leaves its state in; with no cache (the cache-less forward) every row starts
 from zero and nothing is kept. All of it is float32 from the projection's
 output to the gated norm: the state is an accumulator over the whole
-context.
+context. The carried state lies as the one-token kernel wants it
+(``ops/ssm_step.py`` ``stored_shape``: heads narrower than a lane tile in
+pairs, the state axis on sublanes — Granite's 128 heads of 64 x 128 as 64
+tiles of 128 x 128); the decode step's whole slot batch on a kernel backend
+advances it where it lies, every other path here — the chunked form, ``_step``
+on ``ref`` or over gathered slots — reads and writes ``[N, H, P, Ns]`` through
+``to_logical`` / ``to_stored``, as ``models/gdn.py`` does through ``_heads`` /
+``_tiles``.
 """
 
 from __future__ import annotations
@@ -37,7 +44,7 @@ import numpy as np
 from jax import Array, lax
 
 from finchat_tpu.models.quant import dense
-from finchat_tpu.ops.ssm_step import ssm_state_step
+from finchat_tpu.ops.ssm_step import ssm_state_step, to_logical, to_stored
 from finchat_tpu.utils.metrics import METRICS
 
 _HIGHEST = lax.Precision.HIGHEST
@@ -194,8 +201,8 @@ def gated_norm(y: Array, z: Array, weight: Array, groups: int, eps: float) -> Ar
 def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
           rows: SsmRows | None, qm_backend: str | None = None) -> tuple[Array, Any]:
     """The mixer's output for the normed input ``h`` [B,S,D] and the updated
-    ``cache`` (``(ssm_state [L,slots,H,P,Ns], conv_state [L,slots,K-1,C])``
-    float32, or None: every row from zero, nothing kept)."""
+    ``cache`` (``(ssm_state [L,slots,*stored_shape(H,P,Ns,G)], conv_state
+    [L,slots,K-1,C])`` float32, or None: every row from zero, nothing kept)."""
     f32 = jnp.float32
     H, P, Ns, G = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
     Hg, gn = H // G, G * Ns
@@ -239,8 +246,8 @@ def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
         else:
             if one_token:  # gathered slots: XLA's two passes over the state
                 METRICS.inc("finchat_ssm_step_fallbacks_total")
-            if cache is not None:
-                state = _read(cache[0], layer_idx, rows)
+            if cache is not None:  # the carried state as the device holds it (ops/ssm_step.py)
+                state = to_logical(_read(cache[0], layer_idx, rows), (H, P, Ns), G)
             A, D = A.reshape(G, Hg), lp["ssm_D"].reshape(G, Hg)
             xs = xs.reshape(n, S, G, Hg, P)
             dt = dt.reshape(n, S, G, Hg)
@@ -250,7 +257,7 @@ def mixer(h: Array, lp: dict[str, Any], c, cache: Any, layer_idx: Array,
             else:
                 y, state = _chunked(state, xs, dt, A, Bm, Cm, D, c.ssm_chunk)
             if cache is not None:
-                cache = (_write(cache[0], state.reshape(n, H, P, Ns), layer_idx, rows),
+                cache = (_write(cache[0], to_stored(state.reshape(n, H, P, Ns), G), layer_idx, rows),
                          conv_state)
         y = y.reshape(n, S, c.d_ssm)
     if packed:

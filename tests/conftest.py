@@ -118,15 +118,17 @@ def _finchat_leak_sanitizer(request):
 # than the two eleven-minute files ROADMAP D10 split. The files that take over
 # three quarters of a minute go to the workers first, longest first (seconds a file
 # in the junit of the driver's run on PR 45's tree, the split files and
-# test_decode_pipeline.py by PR 46's own run); every other file keeps its place.
+# test_decode_pipeline.py by PR 46's own run, test_ssm_step_kernel.py by PR 52's:
+# 170 s with the pairs' cases); every other file keeps its place.
 # A file that grows past the last of these belongs in the list.
 LONGEST_FIRST = (
     "test_latent_walk", "test_tpu_compile", "test_kv_quant_packed_tile",
     "test_paged_walk_shared_head", "test_chip_smoke", "test_olmo_hybrid", "test_phi4_flash",
-    "test_granite_hybrid", "test_paged_walk_packed_tile", "test_kv_quant_shared_head",
+    "test_granite_hybrid", "test_ssm_step_kernel", "test_paged_walk_packed_tile",
+    "test_kv_quant_shared_head",
     "test_deepseek_v32", "test_parallel", "test_gdn_step_kernel", "test_decode_pipeline",
     "test_flat_fence", "test_pallas_attention", "test_moe_step_kernel", "test_kv_quant",
-    "test_falcon_h1", "test_kv_quant_engine", "test_paged_walk_engine", "test_ssm_step_kernel",
+    "test_falcon_h1", "test_kv_quant_engine", "test_paged_walk_engine",
     "test_quant_serving", "test_quant_matmul", "test_quant", "test_hf_loader",
     "test_ragged_attention", "test_checkpoint_io", "test_mixed_step",
     "test_kimi_linear_engine", "test_kimi_linear_model", "test_kimi_linear",
