@@ -90,6 +90,7 @@ from finchat_tpu.utils.faults import inject
 from finchat_tpu.utils.logging import get_logger
 from finchat_tpu.utils.metrics import METRICS, Timer
 from finchat_tpu.utils.tracing import (
+    RETIRE_PARTS,
     SLOW_ROUND_FLOOR_S,
     SLOW_ROUND_LOG_INTERVAL_S,
     SLOW_ROUND_MEDIANS,
@@ -99,6 +100,13 @@ from finchat_tpu.utils.tracing import (
 )
 
 logger = get_logger(__name__)
+
+
+def _retire_account() -> dict:
+    """What evicting one row took, as a ``retire`` event's args say it: the
+    seconds of each of RETIRE_PARTS and what the blocking copy moved."""
+    return {"offload_pages": 0, "offload_bytes": 0,
+            **{f"{part}_s": 0.0 for part in RETIRE_PARTS}}
 
 
 class OverloadedError(RuntimeError):
@@ -377,6 +385,10 @@ class ContinuousBatchingScheduler:
         # the tracer's running totals as the last round saw them
         self._seen_compile_s = TRACER.serving_compile_s
         self._seen_frozen_s = TRACER.frozen_s
+        # what evicting the last row took (ISSUE 53): seconds by part and
+        # the pages and bytes its blocking copy moved, written where the
+        # work happens (_maybe_offload, _evict) and read by _retire alone
+        self._retired = _retire_account()
         # trace-event track label (utils/tracing.py — ISSUE 12): one
         # Perfetto track per engine so a fleet's dispatch timelines stay
         # separable in one export
@@ -1677,14 +1689,18 @@ class ContinuousBatchingScheduler:
             ):
                 reuse_pages = 0  # entry replaced by a different stream since
         own_ids = handle.page_list[shared // page + reuse_pages : n_tok // page]
+        copy_started = time.perf_counter()
         try:
             inject("session.offload", seq_id=handle.seq_id)
-            with Timer(self.metrics, "finchat_session_offload_seconds"):
-                snap_new = self.engine.offload_pages(own_ids) if own_ids else None
+            snap_new = self.engine.offload_pages(own_ids) if own_ids else None
         except Exception as e:  # cache is an optimization; never fail eviction
             logger.error("session cache offload failed for %s: %s", handle.seq_id, e)
             return
-        from finchat_tpu.engine.session_cache import SessionEntry, concat_snaps
+        copied = time.perf_counter()
+        from finchat_tpu.engine.session_cache import SessionEntry, concat_snaps, snap_nbytes
+
+        self._retired.update(offload_s=copied - copy_started, offload_pages=len(own_ids),
+                             offload_bytes=snap_nbytes(snap_new))
 
         entry = SessionEntry(
             conversation_id=handle.conversation_id,
@@ -1713,19 +1729,50 @@ class ContinuousBatchingScheduler:
         elif entry.prefix_entry is not None:
             entry.prefix_entry.refs -= 1
             self._reap_prefixes()
+        self._retired["store_s"] = time.perf_counter() - copied
 
     def _evict(self, handle: SequenceHandle, reason: str, error: str | None = None) -> None:
         if error is None and reason in ("eos", "length"):
             # normal retirement: the sequence's KV is a coherent prefix of
             # this conversation's next turn — offload before pages free
             self._maybe_offload(handle)
+        release_started = time.perf_counter()
         self._release(handle)
+        released = time.perf_counter()
         if error is not None:
             handle.finished = True
             self._close_span(handle, "error")
             handle.events.put_nowait({"type": "error", "message": error})
         else:
             self._finish(handle, reason)
+        self._retired.update(release_s=released - release_started,
+                             finish_s=time.perf_counter() - released)
+
+    def _retire(self, handle: SequenceHandle, reason: str) -> None:
+        """A row's answer ended (``eos`` / ``length``) where its last token
+        was delivered, on the loop task: evict it inside the round's
+        ``retire`` phase — a ``finchat.retire`` annotation on the profiler's
+        clock, its time out of ``deliver`` — and say what finishing it took.
+        The parts (RETIRE_PARTS) are clocked where their work happens and go
+        to ``finchat_retire_seconds_total{part}`` whatever the tracer's state,
+        and with the phase's own ends to one ``retire`` event on the
+        request's timeline. Every other caller of ``_evict`` (cancel, error,
+        watchdog) runs outside the loop's phases and leaves neither."""
+        took = self._retired = _retire_account()
+        trace_id, context_tokens = handle.trace_id, len(handle.history)
+        phase = TRACER.phase("retire", self._phases)
+        with phase:
+            self._evict(handle, reason)
+        for part in RETIRE_PARTS:
+            self.metrics.inc("finchat_retire_seconds_total", took[f"{part}_s"],
+                             labels={"part": part})
+        if TRACER.enabled:
+            TRACER.event(
+                "retire", trace_id, ts=phase.started, dur=phase.ended - phase.started,
+                track=self._trace_track,
+                args={"reason": reason, "n": self._dispatch_tally,
+                      "decoding": len(self.decoding), "context_tokens": context_tokens,
+                      **took})
 
     # --- bounded-KV serving (ISSUE 15; kv_cache.BoundedKVPolicy) --------
     def _bounded_pinned_pages(self, handle: SequenceHandle) -> int:
@@ -3112,10 +3159,10 @@ class ContinuousBatchingScheduler:
             handle.ngram_index.push(token_id)
         self.metrics.inc("finchat_tokens_generated_total")
         if token_id == self.eos_id:
-            self._evict(handle, "eos")
+            self._retire(handle, "eos")
         elif handle.generated >= handle.sampling.max_new_tokens:
             handle.events.put_nowait({"type": "token", "token_id": token_id})
-            self._evict(handle, "length")
+            self._retire(handle, "length")
         else:
             handle.events.put_nowait({"type": "token", "token_id": token_id})
 

@@ -136,7 +136,7 @@ def resolve_dtype(name: str) -> np.dtype:
         return np.dtype(getattr(ml_dtypes, name))
 
 
-def _snap_nbytes(snap: tuple | None) -> int:
+def snap_nbytes(snap: tuple | None) -> int:
     if snap is None:
         return 0
     return sum(int(a.nbytes) for a in snap if a is not None)
@@ -675,7 +675,7 @@ class SessionEntry:
 
     @property
     def nbytes(self) -> int:
-        return _snap_nbytes(self.snap)
+        return snap_nbytes(self.snap)
 
     def own_pages_for(self, matched: int, page_size: int) -> int:
         """How many snapshot pages a ``matched``-token resume restores
@@ -863,7 +863,7 @@ class SessionKVCache:
         does (no shared head, not one page under budget) — the caller
         should drop the record rather than retry forever."""
         snap = payload["snap"]
-        nbytes = _snap_nbytes(snap)
+        nbytes = snap_nbytes(snap)
         if nbytes <= self.budget_bytes:
             return payload
         if payload.get("kv_gap"):

@@ -12,8 +12,8 @@ and ``finchat_session_cache_entries`` (gauges — host-RAM tier occupancy),
 resume), ``finchat_session_cache_offloaded_pages_total``,
 ``finchat_session_cache_evictions_total`` (LRU under the byte budget),
 ``finchat_session_cache_truncations_total`` (divergent-history cuts), and
-the ``finchat_session_offload_seconds`` / ``finchat_session_restore_seconds``
-histograms (D2H snapshot / H2D resume latency).
+the ``finchat_session_restore_seconds`` histogram (H2D resume latency; the
+D2H snapshot's seconds are ``finchat_retire_seconds_total{part="offload"}``).
 
 Ragged/mixed-step family (engine ragged_mixed_step, scheduler ragged
 path — ISSUE 10): ``finchat_mixed_dispatches_total`` (unified packed

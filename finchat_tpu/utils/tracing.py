@@ -41,6 +41,9 @@ clock (ISSUE 38): ``compile``, one per program XLA compiled or loaded
 (``jax.monitoring``'s own events, heard by :func:`listen_for_compiles`), and
 ``freeze``, one per late tick of a thread that only sleeps
 (:class:`Heartbeat`). A ``round`` event says how much of either it carried.
+The round in which an answer ENDS has a phase of its own, ``retire``, and
+each retired row leaves one ``retire`` event with the seconds of
+:data:`RETIRE_PARTS` (ISSUE 53): what the other rows waited for.
 
 Every ``mark()``/event/scope name MUST come from the registries below —
 finchat-lint R5's span-discipline check enforces it statically, because a
@@ -126,6 +129,13 @@ TRACE_EVENTS = frozenset({
     # the process could not run a thread that only sleeps (ISSUE 38): args
     # carry ``process_cpu_s`` over the gap and ``owner`` (FREEZE_OWNERS)
     "freeze",
+    # one row whose answer ended (sampled EOS / its budget) was retired on
+    # the scheduler's loop task (ISSUE 53): the round's ``retire`` phase as
+    # a span of the request's own timeline; args carry ``reason``, the
+    # dispatch tally ``n``, the rows left ``decoding``, ``context_tokens``
+    # and the seconds of each of RETIRE_PARTS (``<part>_s``) with the
+    # ``offload_pages`` / ``offload_bytes`` the blocking copy moved
+    "retire",
 })
 
 #: The parts of one scheduler iteration, in the order the loop runs them.
@@ -133,9 +143,18 @@ TRACE_EVENTS = frozenset({
 #: a dispatch's host arrays and row lists, and whatever else the loop does
 #: outside the other phases; ``dispatch`` the call into the engine's jitted
 #: step until it returns; ``fetch_wait`` awaiting the worker thread that
-#: fetches tokens; ``deliver`` handing tokens to the streams; ``yield`` the
-#: loop given to every other task of the process.
-ROUND_PHASES = ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
+#: fetches tokens; ``deliver`` handing tokens to the streams; ``retire``
+#: (inside ``deliver``, out of its time) finishing a row whose answer ended;
+#: ``yield`` the loop given to every other task of the process.
+ROUND_PHASES = ("admit", "stage", "dispatch", "fetch_wait", "deliver", "retire", "yield")
+
+#: What retiring a row is made of, in the order it runs (a ``retire``
+#: event's ``<part>_s``, ``finchat_retire_seconds_total{part}``): ``offload``
+#: the blocking device→host copy of the row's own pages for the session
+#: tier, ``store`` building the entry and putting it in the cache,
+#: ``release`` pages, slot and prefix reference given back (one device
+#: call), ``finish`` the request span's close and the ``done`` event.
+RETIRE_PARTS = ("offload", "store", "release", "finish")
 
 #: ``jax.named_scope`` names inside the jitted steps (finchat-lint R5
 #: rejects a literal that is not here). A device operation's scope path in

@@ -13,6 +13,10 @@ record:
   count of the same handles) and ``prefix_rows`` the rows sharing one.
 - REQUEST: the ``request`` span says how it ended and how large it was.
 - SLOW ROUND: a stalled event loop leaves one WARNING that names the phase.
+- RETIRE (ISSUE 53): the round in which an answer ends books the eviction
+  under ``retire``, out of ``deliver``; each row ended by ``eos`` / ``length``
+  leaves one ``retire`` event whose parts are clocked where the work
+  happens, and ``finchat_retire_seconds_total{part}`` moves without the ring.
 - SCOPES: every ``DEVICE_SCOPES`` name is in the compiled steps' ``op_name``
   metadata, and finchat-lint R5 rejects a scope, phase or reason literal the
   registries do not declare.
@@ -26,6 +30,7 @@ import re
 import textwrap
 import threading
 import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +48,7 @@ from finchat_tpu.utils.metrics import METRICS
 from finchat_tpu.utils.tracing import (
     DEVICE_SCOPES,
     FINISH_REASONS,
+    RETIRE_PARTS,
     ROUND_PHASES,
     STARTUP_PHASES,
     TRACE_EVENTS,
@@ -179,6 +185,14 @@ def test_every_loop_branch_leaves_rounds_that_add_up(branch):
                          "kv_tokens_distinct": sum(kv for *_row, kv in riders),
                          "prefix_rows": 0}
         assert all(kv >= 1 for *_row, kv in riders), riders
+    # every stream ran to its budget: one retirement each, inside a round
+    # whose ``retire`` phase holds exactly the retirements it spanned
+    retired = _ring("retire")
+    assert sorted(ev[1] for ev in retired) == ["a", "b"][:len(tokens_on)]
+    for ts, _tid, _name, dur, _track, args in rounds:
+        inside = [ev[3] for ev in retired if ts <= ev[0] and ev[0] + ev[3] <= ts + dur]
+        assert args["retire"] == pytest.approx(sum(inside), abs=1e-9)
+    assert sum(ev[5]["retire"] > 0.0 for ev in rounds) <= len(retired)
     # tracing on or off, the streams are the same tokens
     tokens_off, _riders, _tally = _run_branch(options, arrival, traced=False)
     assert TRACER.snapshot() == []
@@ -434,6 +448,151 @@ def test_nested_phase_takes_its_time_out_of_the_outer_one():
     assert RoundPhases().clock is time.perf_counter  # the ring's clock otherwise
 
 
+# --- the round in which an answer ends (ISSUE 53) -----------------------------
+
+RETIRE_ARGS = ({"reason", "n", "decoding", "context_tokens", "offload_pages", "offload_bytes"}
+               | {f"{part}_s" for part in RETIRE_PARTS})
+RELEASE_HELD_S = 0.05  # what the slowed ``_release`` of these cases sleeps
+
+
+def _retire_seconds():
+    return {part: METRICS.get("finchat_retire_seconds_total", labels={"part": part})
+            for part in RETIRE_PARTS}
+
+
+def _phase_seconds(phase):
+    return METRICS.get("finchat_round_phase_seconds_total", labels={"phase": phase})
+
+
+def _run_to_its_end(reason, *, tier, traced=True):
+    """One request alone on the split path, ended by ``reason``, with a
+    ``_release`` that holds the loop RELEASE_HELD_S; returns the handle's
+    generated count and the retire counters as they stood before it (the
+    probe that finds an EOS id retires a row of its own)."""
+    TRACER.configure(enabled=traced)
+    TRACER.clear()
+    options = dict(mixed_step=False, session_cache=tier)
+
+    async def go():
+        greedy = SamplingParams(temperature=0.0, max_new_tokens=6)
+        eos_id = -1
+        if reason == "eos":  # the third token of the greedy stream, if it is new there
+            sched = _scheduler(**options)
+            await sched.start()
+            try:
+                tokens, _end = await asyncio.wait_for(
+                    _drain(await sched.submit("probe", PROMPT_A, greedy)), timeout=120)
+            finally:
+                await sched.stop()
+            eos_id = next((t for i, t in enumerate(tokens) if i >= 2 and t not in tokens[:i]),
+                          tokens[0])
+            TRACER.clear()
+        sched = _scheduler(eos_id=eos_id, **options)
+        release = sched._release
+
+        def held_release(handle):
+            time.sleep(RELEASE_HELD_S)  # the injected hold
+            release(handle)
+
+        sched._release = held_release
+        before = _retire_seconds(), _phase_seconds("retire")
+        await sched.start()
+        try:
+            h = await sched.submit("a", PROMPT_A, greedy, trace_id="a",
+                                   conversation_id="conv" if tier else None)
+            _tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+            assert end == {"type": "done", "reason": reason}
+        finally:
+            await sched.stop()
+        return h.generated, *before
+
+    return asyncio.run(go())
+
+
+@pytest.mark.parametrize("tier", [False, True], ids=["no_session_tier", "session_tier"])
+@pytest.mark.parametrize("reason", ["eos", "length"])
+def test_a_row_that_ends_leaves_one_retire_event_with_its_parts(reason, tier):
+    generated, before, retire0 = _run_to_its_end(reason, tier=tier)
+    (ts, trace_id, _name, dur, track, args), = _ring("retire")
+    assert trace_id == "a" and track == "engine" and set(args) == RETIRE_ARGS
+    assert args["reason"] == reason and args["decoding"] == 0
+    assert args["context_tokens"] == len(PROMPT_A) + generated
+    parts = {part: args[f"{part}_s"] for part in RETIRE_PARTS}
+    assert all(seconds >= 0.0 for seconds in parts.values()), parts
+    # the parts are the span less what runs unclocked between them
+    assert 0.0 <= dur - sum(parts.values()) < 0.25, (parts, dur)
+    assert parts["release"] >= RELEASE_HELD_S and parts["finish"] > 0.0
+    if tier:  # the row's whole pages: the prompt's five and none of the answer's
+        pages = (len(PROMPT_A) + generated - 1) // 8
+        assert args["offload_pages"] == pages and args["offload_bytes"] > 0
+        assert args["offload_bytes"] % pages == 0
+        assert parts["offload"] > 0.0 and parts["store"] > 0.0
+    else:
+        assert (args["offload_pages"], args["offload_bytes"]) == (0, 0)
+        assert parts["offload"] == 0.0 and parts["store"] == 0.0
+    # the round that spans it: the same tally, the retirement under ``retire``
+    # and out of ``deliver``, the phases still summing to the round
+    (round_dur, round_args), = [(ev[3], ev[5]) for ev in _ring("round")
+                                if ev[0] <= ts and ts + dur <= ev[0] + ev[3]]
+    assert round_args["n"] == args["n"]
+    assert round_args["retire"] == pytest.approx(dur, abs=1e-9)
+    assert round_args["deliver"] < RELEASE_HELD_S
+    assert sum(round_args[p] for p in ROUND_PHASES) == pytest.approx(round_dur, rel=1e-3)
+    assert [ev[5]["retire"] > 0.0 for ev in _ring("round")].count(True) == 1
+    # on the request's own timeline, before the span it closes
+    names = [ev["name"] for ev in TRACER.export("a")["traceEvents"]]
+    assert names.count("retire") == 1 and "request" in names
+    # the same seconds on the counters
+    for part, seconds in _retire_seconds().items():
+        assert seconds - before[part] == pytest.approx(parts[part], abs=1e-9)
+    assert _phase_seconds("retire") - retire0 == pytest.approx(dur, abs=1e-9)
+
+
+def test_the_retire_counters_move_with_the_tracer_off():
+    _generated, before, retire0 = _run_to_its_end("length", tier=True, traced=False)
+    assert TRACER.snapshot() == []
+    moved = {part: seconds - before[part] for part, seconds in _retire_seconds().items()}
+    assert moved["release"] >= RELEASE_HELD_S
+    assert all(seconds > 0.0 for seconds in moved.values()), moved
+    assert _phase_seconds("retire") - retire0 >= sum(moved.values())
+
+
+@pytest.mark.parametrize("path", ["cancelled", "error"])
+def test_an_evict_off_the_answers_end_opens_no_phase_and_leaves_no_retire(path):
+    """A cancel (the submitting task's) and a failed prefill (the loop's own,
+    before its first dispatch) evict through ``_evict`` itself: no ``retire``
+    phase, event or counter."""
+    before, retire0 = _retire_seconds(), _phase_seconds("retire")
+
+    async def go():
+        sched = _scheduler(mixed_step=False)
+        await sched.start()
+        try:
+            if path == "cancelled":
+                h = await sched.submit("gone", PROMPT_A, SamplingParams(
+                    temperature=0.0, max_new_tokens=200), trace_id="gone")
+                while h.generated < 3:
+                    await asyncio.sleep(0.001)
+                sched.cancel(h)
+                await asyncio.sleep(0.05)  # the rounds that consume what was in flight
+            else:
+                faults.arm("scheduler.prefill", faults.for_seq("gone", RuntimeError("boom")))
+                h = await sched.submit("gone", PROMPT_A, SamplingParams(
+                    temperature=0.0, max_new_tokens=4), trace_id="gone")
+                _tokens, end = await asyncio.wait_for(_drain(h), timeout=120)
+                assert end["type"] == "error"
+            assert h.finished
+        finally:
+            faults.disarm_all()
+            await sched.stop()
+
+    asyncio.run(go())
+    assert not _ring("retire") and (_ring("round") or path == "error")
+    assert all(ev[5]["retire"] == 0.0 for ev in _ring("round"))
+    assert _retire_seconds() == before and _phase_seconds("retire") == retire0
+    assert [ev[5]["reason"] for ev in _ring("request")] == [path]
+
+
 # --- what a round lost outside a device step (ISSUE 38) ------------------------
 
 @pytest.mark.parametrize("lost", ["compile", "freeze", "both", "neither"])
@@ -549,8 +708,10 @@ def test_compiled_steps_carry_every_scope(preset, expected):
 
 
 def test_registries_hold_the_new_names():
-    assert {"round", "startup"} <= TRACE_EVENTS
-    assert ROUND_PHASES == ("admit", "stage", "dispatch", "fetch_wait", "deliver", "yield")
+    assert {"round", "startup", "retire"} <= TRACE_EVENTS
+    assert ROUND_PHASES == ("admit", "stage", "dispatch", "fetch_wait", "deliver", "retire",
+                            "yield")
+    assert RETIRE_PARTS == ("offload", "store", "release", "finish")
     assert {"moe_router", "moe_experts", "paged_attention"} <= DEVICE_SCOPES
     assert {"eos", "length", "cancelled", "shed", "error", "drained"} <= FINISH_REASONS
 
@@ -608,6 +769,28 @@ def test_r5_rejects_undeclared_scope_phase_reason_and_startup_literals(tmp_path)
                               ("gone", "FINISH_REASONS"), ("vanished", "FINISH_REASONS"),
                               ("warm_up", "STARTUP_PHASES")):
         assert any(f"`{literal}`" in m and registry in m for m in messages), (literal, messages)
+
+
+def test_r5_takes_retire_as_a_phase_and_an_event_from_the_real_registries(tmp_path):
+    from finchat_tpu.utils import tracing
+
+    src = """
+        from finchat_tpu.utils.tracing import TRACER
+
+        class Sched:
+            def go(self, handle, acc):
+                with TRACER.phase("retire", acc):     # declared: fine
+                    pass
+                TRACER.event("retire", handle.trace_id, dur=0.1)  # declared: fine
+                with TRACER.phase("retired", acc):    # flagged
+                    pass
+                TRACER.event("retires", handle.trace_id)  # flagged
+    """
+    messages = _lint(tmp_path, {"finchat_tpu/utils/tracing.py": Path(tracing.__file__).read_text(),
+                                "finchat_tpu/sched.py": src})
+    assert len(messages) == 2, messages
+    assert any("`retired`" in m and "ROUND_PHASES" in m for m in messages), messages
+    assert any("`retires`" in m for m in messages), messages
 
 
 def test_r5_leaves_a_registry_the_tracing_module_lacks_unchecked(tmp_path):
