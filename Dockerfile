@@ -13,11 +13,13 @@ RUN apt-get update && apt-get install -y --no-install-recommends curl \
     && rm -rf /var/lib/apt/lists/*
 
 # jax[tpu] resolves libtpu on TPU VMs.
-# matplotlib: the wired plot tool; orbax: native checkpoints; the serve
+# tokenizers reads a checkpoint's tokenizer.json (models/tokenizer.py; transformers
+# only for a directory without one); matplotlib: the wired plot tool; orbax:
+# native checkpoints; the serve
 # extras (confluent-kafka, pymongo, qdrant-client) are the reference-parity
 # external backends.
 RUN pip install --no-cache-dir "jax[tpu]" -f https://storage.googleapis.com/jax-releases/libtpu_releases.html \
-    && pip install --no-cache-dir safetensors transformers matplotlib orbax-checkpoint \
+    && pip install --no-cache-dir safetensors tokenizers transformers matplotlib orbax-checkpoint \
        confluent-kafka pymongo qdrant-client
 
 WORKDIR /app

@@ -17,7 +17,9 @@ playing the role of the reference's ChatPromptTemplate (llm_agent.py:47-51).
 
 from __future__ import annotations
 
+import json
 from dataclasses import dataclass
+from pathlib import Path
 from typing import Protocol, Sequence
 
 from finchat_tpu.io.schemas import ChatMessage
@@ -56,13 +58,142 @@ class ByteTokenizer:
         return bytes(i for i in ids if i < 256).decode("utf-8", errors="replace")
 
 
+# the keys of ``tokenizer_config.json`` / ``special_tokens_map.json`` that name
+# ONE special token (``additional_special_tokens`` names a list)
+_SPECIAL_TOKEN_KEYS = ("bos_token", "eos_token", "unk_token", "sep_token",
+                       "pad_token", "cls_token", "mask_token")
+
+
+def _read_json(path: Path) -> dict:
+    return json.loads(path.read_text(encoding="utf-8")) if path.exists() else {}
+
+
+def _clean_up_tokenization(text: str) -> str:
+    """transformers' ``clean_up_tokenization``: the spaces an English
+    word-level vocabulary leaves before punctuation and contractions."""
+    for spaced, joined in ((" .", "."), (" ?", "?"), (" !", "!"), (" ,", ","),
+                           (" ' ", "'"), (" n't", "n't"), (" 'm", "'m"),
+                           (" 's", "'s"), (" 've", "'ve"), (" 're", "'re")):
+        text = text.replace(spaced, joined)
+    return text
+
+
+class _TokenizersBackend:
+    """A directory's ``tokenizer.json`` read by ``tokenizers`` itself — the
+    library that does the work under ``transformers``' fast tokenizers — with
+    what ``AutoTokenizer`` adds from the files beside it: the special tokens'
+    names (``tokenizer_config.json``; where that file lists no
+    ``added_tokens_decoder``, ``special_tokens_map.json`` over it), a name a
+    string or a dict with ``content``, and a named special that the file does
+    not list among its added tokens is added as one; the file's truncation and
+    padding switched off (an ``encode`` here asks for neither);
+    ``clean_up_tokenization_spaces`` as the config states it, False where it
+    says nothing (transformers' default). Importing ``transformers`` brings
+    ``torch``, ``sklearn`` and ``pandas`` into the process — 20 s of every
+    start on a serving host (PERF.md section 6, PR 54) — to read this one
+    file. What a tokenizer CLASS writes over the file from the config is not
+    applied but DETECTED (``overlaid``, and ``_hf_backend`` then leaves the
+    directory to ``AutoTokenizer``). Not mirrored: a class's own defaults for
+    keys the config leaves out (``save_pretrained`` always writes them)."""
+
+    def __init__(self, path: Path):
+        from tokenizers import Tokenizer as TokenizersTokenizer
+
+        tok = TokenizersTokenizer.from_file(str(path / "tokenizer.json"))
+        tok.no_truncation()
+        tok.no_padding()
+        config = _read_json(path / "tokenizer_config.json")
+        names = config if "added_tokens_decoder" in config else {
+            **config, **_read_json(path / "special_tokens_map.json")}
+
+        def content(value) -> str | None:
+            return value.get("content") if isinstance(value, dict) else value
+
+        special = {key: content(names[key]) for key in _SPECIAL_TOKEN_KEYS
+                   if names.get(key) is not None}
+        named = list(dict.fromkeys(
+            [*special.values(),
+             *(content(t) for t in names.get("additional_special_tokens") or [])]))
+        have = {t.content for t in tok.get_added_tokens_decoder().values()}
+        missing = [t for t in named if t not in have]
+        if missing:
+            tok.add_special_tokens(missing)
+        self._tok = tok
+        # None where the files name no such token (every named one has an id by now)
+        self.bos_token_id, self.eos_token_id, self.pad_token_id = (
+            tok.token_to_id(special[key]) if key in special else None
+            for key in ("bos_token", "eos_token", "pad_token"))
+        # the NAMED specials' ids, as transformers' ``all_special_ids``
+        # (agent/constrained.py ``token_texts`` gives them no text)
+        self.all_special_ids = sorted({tok.token_to_id(t) for t in named})
+        self._clean_up = bool(config.get("clean_up_tokenization_spaces", False))
+        self.overlaid = self._overlaid(config)
+
+    def _overlaid(self, config: dict) -> list[str]:
+        """The keys of ``tokenizer_config.json`` whose value a tokenizer class
+        would write over a ``tokenizer.json`` that says otherwise: the framing
+        (``LlamaTokenizerFast`` and its kin rebuild the post-processor from
+        ``add_bos_token`` / ``add_eos_token``), the normalizer
+        (``BertTokenizerFast``, where the file's state has the key) and the
+        pre-tokenizer's ``add_prefix_space`` (every fast tokenizer; False where
+        the config leaves it out). Empty for a directory that agrees with itself."""
+        tok, keys = self._tok, []
+        framed = tok.encode("a", add_special_tokens=True)
+        text = [i for i, added in enumerate(framed.special_tokens_mask) if not added]
+        # (a vocabulary with no piece for the probe: all of it is framing, on both sides)
+        head, tail = (framed.ids[:text[0]], framed.ids[text[-1] + 1:]) if text else (framed.ids,) * 2
+        for key, got, token_id in (("add_bos_token", head, self.bos_token_id),
+                                   ("add_eos_token", tail, self.eos_token_id)):
+            if config.get(key) is not None and got != [token_id] * bool(config[key]):
+                keys.append(key)
+
+        def state(part) -> dict:
+            return json.loads(part.__getstate__()) if part is not None else {}
+
+        normalizer = state(tok.normalizer)
+        for key, theirs in (("do_lower_case", "lowercase"), ("strip_accents", "strip_accents"),
+                            ("tokenize_chinese_chars", "handle_chinese_chars")):
+            if key in config and normalizer.get(theirs, config[key]) != config[key]:
+                keys.append(key)
+        prefix = config.get("add_prefix_space", False)
+        if prefix is not None and state(tok.pre_tokenizer).get("add_prefix_space", prefix) != prefix:
+            keys.append("add_prefix_space")
+        return keys
+
+    def __len__(self) -> int:
+        return self._tok.get_vocab_size(with_added_tokens=True)
+
+    def encode(self, text: str, add_special_tokens: bool) -> list[int]:
+        return self._tok.encode(text, add_special_tokens=add_special_tokens).ids
+
+    def convert_ids_to_tokens(self, ids: Sequence[int]) -> list[str | None]:
+        """The vocabulary's own pieces ('▁foo', '<0x0A>'), undecoded."""
+        return [self._tok.id_to_token(i) for i in ids]
+
+    def decode(self, ids: Sequence[int], skip_special_tokens: bool) -> str:
+        text = self._tok.decode(list(ids), skip_special_tokens=skip_special_tokens)
+        return _clean_up_tokenization(text) if self._clean_up else text
+
+
+def _hf_backend(path: str):
+    """What reads the directory, chosen by what it holds: ``tokenizers`` where
+    there is a ``tokenizer.json`` that its config does not overrule, else
+    (SentencePiece only, or a config the class applies over the file)
+    ``AutoTokenizer``."""
+    if (Path(path) / "tokenizer.json").exists():
+        backend = _TokenizersBackend(Path(path))
+        if not backend.overlaid:
+            return backend
+    from transformers import AutoTokenizer  # deferred: heavy import
+
+    return AutoTokenizer.from_pretrained(path, local_files_only=True)
+
+
 class HFTokenizer:
     """Local HuggingFace tokenizer adapter (no network)."""
 
     def __init__(self, path: str):
-        from transformers import AutoTokenizer  # deferred: heavy import
-
-        self._tok = AutoTokenizer.from_pretrained(path, local_files_only=True)
+        self._tok = _hf_backend(path)
         self.vocab_size = len(self._tok)
         self.bos_id = self._tok.bos_token_id or 0
         self.eos_id = self._tok.eos_token_id or 0
