@@ -741,11 +741,16 @@ def check_pair_walk_at_cell_shape(backend: str, *, rows: int = 32, heads: int = 
     31 pages — against the chunk form on ``ref`` (a row at a time, the mask
     form). Rows of both widths ride: every third row has no draft (its second
     token's output is computed and unread), and the module's mask (slot 0
-    skipped) is one of the two cases. Returns the worst max abs error."""
+    skipped) is one of the two cases. The walk takes the shared head in BOTH
+    forms (``paged_attention.latent_head_form``: ``folded``, the rule's at this
+    shape, and ``stacked``, the rule's share of the MXU's peak set to 0 for the
+    call), each held to ``ref``, and the distance between the two is printed.
+    Returns the worst max abs error."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
+    from finchat_tpu.ops import paged_attention
     from finchat_tpu.ops.latent_attention import LatentShape, rows_attention
     from finchat_tpu.ops.paged_attention import shared_head
 
@@ -773,21 +778,37 @@ def check_pair_walk_at_cell_shape(backend: str, *, rows: int = 32, heads: int = 
     at = (table[jnp.arange(rows)[:, None], (start[:, None] + jnp.arange(2)) // page_size],
           (start[:, None] + jnp.arange(2)) % page_size)
     own = pages[1][at]
-    worst = 0.0
+    worst = apart = 0.0
+    the_rule = paged_attention.LATENT_MXU_SHARE
     for skip in (0, 1):
         shape = LatentShape(512, 0, 192 ** -0.5, skip)
         args = (q, None, None, pages, keys_pages, layer, table, start, n_valid)
         shared = shared_head(table, start + 1, page_size, n_valid > 0)
-        got, _ = jax.jit(lambda *a: rows_attention(
-            *a, page_size=page_size, shape=shape, backend=backend, shared=shared, own=own))(*args)
         want, _ = jax.jit(lambda *a: rows_attention(
             *a, page_size=page_size, shape=shape, backend="ref"))(*args)
         read = (jnp.arange(2)[None, :] < n_valid[:, None])[..., None, None]
-        error = kernel_error(f"pair walk (skip {skip})", jnp.where(read, got, 0),
-                             jnp.where(read, want, 0))
-        worst = max(worst, error)
+        got = {}
+        for form, share in (("folded", the_rule), ("stacked", 0.0)):
+            try:  # the form is read while the call is traced
+                paged_attention.LATENT_MXU_SHARE = share
+                paged_attention.paged_latent_attention.clear_cache()
+                require(paged_attention.latent_head_form(rows, 2 * heads, 640, 512, 2) == form,
+                        f"pair-walk case: the rule does not give {form} at this shape")
+                got[form], _ = jax.jit(lambda *a: rows_attention(
+                    *a, page_size=page_size, shape=shape, backend=backend, shared=shared,
+                    own=own))(*args)
+            finally:
+                paged_attention.LATENT_MXU_SHARE = the_rule
+                paged_attention.paged_latent_attention.clear_cache()
+            error = kernel_error(f"pair walk (skip {skip}, head {form})",
+                                 jnp.where(read, got[form], 0), jnp.where(read, want, 0))
+            worst = max(worst, error)
+        apart = max(apart, float(jnp.max(jnp.abs(
+            jnp.where(read, got["folded"].astype(jnp.float32)
+                      - got["stacked"].astype(jnp.float32), 0)))))
     say(f"pair walk {backend} vs ref at {rows} rows x 2 x {heads} heads, contexts "
-        f"{ctx.min()}-{ctx.max()}, {shared_pages} shared pages: ok (worst {worst:.4f})")
+        f"{ctx.min()}-{ctx.max()}, {shared_pages} shared pages, the head folded and stacked: "
+        f"ok (worst {worst:.4f}; the two forms {apart:.6f} apart)")
     return worst
 
 

@@ -1584,6 +1584,17 @@ class InferenceEngine:
         # decode step's indexer comes by its scores (latent_attention.index_form)
         self.index_form = index_form(attn_backend) if config.kv_lora_rank and (
             0 < config.index_topk < self.max_pages_per_seq * engine_cfg.page_size) else None
+        # ... and the form its walks take the batch's shared head in, by the static shapes
+        # the call itself reads (paged_attention.latent_head_form; a model that drafts walks
+        # a token and its draft as 2 x heads query rows); the gather form takes none
+        self.head_form = None
+        if config.kv_lora_rank:
+            from finchat_tpu.ops.paged_attention import latent_head_form
+
+            self.head_form = latent_head_form(
+                engine_cfg.max_seqs, config.n_heads * (2 if config.mtp_layers else 1),
+                config.latent_row, config.kv_lora_rank, jnp.dtype(config.dtype).itemsize,
+            ) if self.latent_form == "walk" else "none"
         # a model with Mamba-2 layers on a kernel backend: the tile its decode
         # step's state update works on, by the static shapes (ssm_step.tile_heads)
         self.state_form = None

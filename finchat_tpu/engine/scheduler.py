@@ -422,6 +422,8 @@ class ContinuousBatchingScheduler:
         self._index_form = getattr(engine, "index_form", None)
         # a model with Mamba-2 layers: the tile its one-token state update works on
         self._state_form = getattr(engine, "state_form", None)
+        # a model with latent attention: the form its walks take the batch's shared head in
+        self._head_form = getattr(engine, "head_form", None)
         _wbits = {"": None, "int8": 8, "int4": 4}.get(
             getattr(engine, "quant", ""))
         _elem_bits = 8 * np.dtype(engine.config.dtype).itemsize
@@ -583,6 +585,8 @@ class ContinuousBatchingScheduler:
             if self._index_form:
                 self.metrics.inc("finchat_dsa_index_calls_total", 0.0,
                                  labels={"form": self._index_form})
+            self.metrics.inc("finchat_latent_head_walks_total", 0.0,
+                             labels={"form": self._head_form})
             if fabric is not None or getattr(cfg, "session_cache_disk_path", ""):
                 raise ValueError(
                     "fabric.path / engine.session_cache_disk_path: the warm fabric's and the "
@@ -911,6 +915,7 @@ class ContinuousBatchingScheduler:
             prefix_rows=len(most) if len(most) > 1 else 0,
             **({"form": self._latent_form} if self._latent_form else {}),
             **({"index_form": self._index_form} if self._index_form else {}),
+            **({"head_form": self._head_form} if self._head_form else {}),
             **({"state_form": self._state_form} if self._state_form else {}),
             # a model with sliding-window layers: the tokens ONE window layer
             # reads (a row's context up to the window)
@@ -3429,6 +3434,8 @@ class ContinuousBatchingScheduler:
                     if self._index_form:
                         self.metrics.inc("finchat_dsa_index_calls_total", latent_layers,
                                          labels={"form": self._index_form})
+                    self.metrics.inc("finchat_latent_head_walks_total", latent_layers,
+                                     labels={"form": self._head_form})
                     self._round_selected = selected[0]
             for slot, handle, epoch in step.members:
                 if handle.finished or handle.slot != slot or handle.epoch != epoch:

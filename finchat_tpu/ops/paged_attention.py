@@ -122,6 +122,32 @@ Design — a walk as long as the row, several pages a block:
   copies, and at 128 query rows a block's fixed work showed (0.334 ms a
   layer at the cell's shapes at 512, 0.289 at 1,024, 0.323 at 2,048, where a
   partial last block's waste overtakes it).
+  The latent form takes the shared head in one of TWO forms, read off the
+  call's static shapes (``latent_head_form``; PERF.md sections 5 and 6, PR 56).
+  The stacked pass in program 0 is MXU work with the copy engine idle (a
+  256-row tile over a 1,024-token block: 3.56 us, 86 % of the MXU's peak,
+  for 1.3 MB copied); a row's own walk at 64 or 32 query rows is the
+  opposite, copies at the stream's pace with the MXU idle a third of the
+  time (a block update 1.29 / 1.14 us under a copy of 1.74). So where a
+  row's block update is shorter than its copy the head is FOLDED: program 0
+  only COPIES the head's pages, once, into a VMEM buffer that outlives the
+  grid (``LATENT_HEAD_TOKENS``: 32 pages, 5 MiB); the pass keeps its stacked
+  tiles (the MXU's better shape: the same update on the same shapes as the
+  prologue made) and its units — a tile over one block of the resident head
+  — are dealt out over the grid's programs, each taken behind one of its
+  row's own blocks; a row still resumes from its tile's partial, so tile t's
+  units are done by the rows of tile t - 1 (program 0 takes the first
+  tile's up front: all that is left of the prologue). And the blocks of the
+  whole call come through ONE ring of ``LATENT_RING_SLOTS`` slots — block g
+  of the call in slot g % ring, whoever works keeps the ring's copies
+  started across rows' ends — because a unit is two blocks' copies long and
+  two slots would stall the copy engine for it (32 rows x 64 query rows at
+  10.8k own tokens behind 31 shared pages, inside the compiled step: the
+  stacked pass 748 us a walk, folded through 3 slots 689, 4 slots 641, 6
+  slots 637; the rows' own pages alone 617, which is their copies). At 128
+  query rows (DeepSeek) a row's own walk is MXU-bound (2.21 us a block):
+  the pass has nothing to hide under and stays STACKED, the program the
+  parent's.
 
 - the INDEXER's scores over its key pages (``paged_index_scores``; PERF.md
   section 6, PR 43): the walk again — the copies, the chain across rows, the
@@ -170,6 +196,22 @@ LATENT_VMEM_BYTES = 48 << 20
 # read-modify-write, the matmuls' fill and drain) is a fifth of a 512-token block's time
 LATENT_BLOCK_TOKENS = 1024
 LATENT_TILE_BYTES = 1 << 20  # the float32 logit tile of the stacked rows that take one update
+# The latent form takes the shared head FOLDED into the rows' own walks where those walks
+# are copy-bound (``latent_head_form``): by a block update's FLOP a copied byte against the
+# chip's ridge (one v5e chip: perfbench/peaks.json's two numbers) times the share of the
+# MXU's peak that such an update reaches — measured inside the compiled step (PERF.md
+# section 5, PR 56): 2.21 us a 1,024-token block at 128 query rows (302 MFLOP: 69 %), 1.29
+# at 64 (59 %), 1.14 at 32, against 1.74 us for the block's copy
+RIDGE_FLOP_PER_BYTE = 197e12 / 819e9
+LATENT_MXU_SHARE = 0.65
+# ... then the head's pages stay in VMEM for the whole call, as far as this many tokens
+# (the served system prompt's 31 pages; a longer head's further pages are the rows' own)
+LATENT_HEAD_TOKENS = 4096
+# ... and the rows' blocks come through a ring of this many slots: a unit of the head's
+# pass is two blocks' copies long, and the ring is what lets those copies fly meanwhile
+# (inside the compiled step, 32 rows of 64 query rows at 10.8k own tokens behind 31 shared
+# pages: 688.7 us a walk at 3 slots, 640.7 at 4, 636.6 at 6; the stacked pass 748.4)
+LATENT_RING_SLOTS = 6
 # the index form's block: a key page is a fifth of a latent page and a block's work three
 # operations an element, so a block's fixed work (the loop, the waits, the matmul's fill)
 # shows before a partial last block's repeats do: alone on a v5e at the cell's shapes 71.7
@@ -280,6 +322,25 @@ def shared_head(page_table: Array, kv_len: Array, page_size: int,
     return member, jnp.stack([n_shared, lead])
 
 
+def latent_head_form(rows: int, heads: int, row_width: int, value_width: int,
+                     itemsize: int) -> str:
+    """How a walked latent call of ``rows`` sequences takes the batch's shared
+    head, read off the call's static shapes and nothing else: ``heads`` query
+    rows a latent row (two tokens' heads where a model drafts), the row's
+    ``row_width`` columns as a copy brings them, the ``value_width`` leading
+    ones its value. ``folded`` where a row's block update — ``2 heads
+    (row_width + value_width)`` FLOP a token at ``LATENT_MXU_SHARE`` of the
+    MXU's peak — is shorter than the copy of the token's ``row_width *
+    itemsize`` bytes: the row's own walk is copy-bound and the head's pass hides
+    under it. ``stacked`` where it is not (128 heads of 576: the walk is
+    MXU-bound and the pass has nothing to hide under; program 0's prologue
+    stays). ``none``: one row alone shares nothing."""
+    if rows < 2:
+        return "none"
+    flop_per_byte = 2 * heads * (row_width + value_width) / (row_width * itemsize)
+    return "folded" if flop_per_byte < LATENT_MXU_SHARE * RIDGE_FLOP_PER_BYTE else "stacked"
+
+
 def _paged_kernel(
     *refs,  # scalar prefetch, blocks, scratch: unpacked below
     block_q: int,
@@ -296,6 +357,7 @@ def _paged_kernel(
     index_heads: int = 0,
     chained: bool = False,
     with_lse: bool = False,
+    ring: int = 0,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -338,6 +400,19 @@ def _paged_kernel(
     where the other forms have a tile of heads), each sequence's rows under
     its own mask. The other forms' bodies are traced as they were.
 
+    With ``ring`` (the latent form with the head FOLDED, ``latent_head_form``;
+    the slots of the ring) the refs are the stacked form's, the chain's SMEM
+    word aside, and behind the stacked state come ``head_buf [head pages,
+    page_size, Dk]`` (the resident head), ``ring_ref`` (five SMEM words: blocks
+    started, the row and block the next start stands at, blocks consumed, units
+    done), a buffer of ``ring`` slots and ``ring + 1`` semaphores (a slot's,
+    and the head's). There is no prologue: program 0 starts the head's copies,
+    every program keeps the ring filled (``fill``), takes the units of the
+    stacked pass that its tile still lacks (``units``; program 0 the first
+    tile's), resumes from its tile's partial as the stacked form does, and
+    takes one more unit behind each of its first own blocks, so that a tile's
+    rows do the next tile's pass.
+
     With ``index_heads`` (``paged_index_scores``: the indexer's scores over
     its key pages) the walk is the same — the copies, the chain, the shared
     head once for the stacked rows — and a block's work is not a softmax: the
@@ -368,9 +443,11 @@ def _paged_kernel(
         own_state, refs = refs[:3], refs[3:]
     if shared_rows and not index_heads:
         shared_state, refs = refs[:3], refs[3:]
-    chained = chained or bool(shared_rows)
+    chained = (chained or bool(shared_rows)) and not ring
     if chained:
         slot_ref, *refs = refs
+    if ring:
+        head_buf, ring_ref, *refs = refs
     buffers, sems = refs[:n_src], refs[n_src]
 
     b = pl.program_id(0)
@@ -456,13 +533,15 @@ def _paged_kernel(
         jax.lax.fori_loop(0, n_blocks, block, None)
         return (slot0 + n_blocks) % 2
 
-    def softmax(limit, causal, q_of, state, R, tiles=n_tiles, keep_of=None):
+    def softmax(limit, causal, q_of, state, R, tiles=n_tiles, keep_of=None, block_of=None):
         """A walk's ``update``: the online softmax of ``R`` query rows a tile
         (``q_of(t)``, state in ``state``'s rows ``t*R .. (t+1)*R``) over a
         block; positions at or beyond ``limit`` are masked, and with
         ``causal`` those after a query row's own, and with ``keep_of`` (the
         latent form) those where ``keep_of(t, the block's first column)`` [R
-        or 1, T] is 0."""
+        or 1, T] is 0. ``block_of(slot)`` (the latent form's resident head):
+        the block's token rows [T, Dk] where they do not stand in the walk's
+        buffer."""
         m_ref, l_ref, acc_ref = state
 
         def update(slot, first, j):
@@ -483,7 +562,7 @@ def _paged_kernel(
                 q_blk = q_of(t, W)
                 masked = invalid
                 if latent_rows:  # one row a token: the key, its first lanes the value
-                    k_blk = buffers[0][slot].reshape(T, Dk)
+                    k_blk = block_of(slot) if block_of else buffers[0][slot].reshape(T, Dk)
                     v_blk = k_blk[:, :D]
                     masked = jnp.logical_or(invalid, keep_of(t, first + j * ppb) == 0)
                 else:
@@ -572,7 +651,7 @@ def _paged_kernel(
             buffers[1][...] = jnp.zeros(buffers[1].shape, buffers[1].dtype)
 
     chain = {}
-    if chained:
+    if chained or ring:
         # The walks of one call are a chain: each starts the first block of
         # the next beside its own last one, so only the call's first copy is
         # uncovered. With ``shared_rows`` also the batch's shared head
@@ -582,6 +661,7 @@ def _paged_kernel(
         n_shared = head_ref[0] if shared_rows else 0
         gp, B = _round_up(pack * group, 8), pl.num_programs(0)
 
+    if chained:
         @pl.when(b == 0)
         def _call():
             slot_ref[0] = 0
@@ -596,7 +676,10 @@ def _paged_kernel(
 
             stacked = dict(tiles=shared_rows // latent_rows, keep_of=stacked_keep)
 
-        @pl.when(jnp.logical_and(b == 0, n_shared > 0))
+        # (folded: no prologue — the pass's units ride the rows' own walks, below)
+        prologue = (lambda f: None) if ring else pl.when(jnp.logical_and(b == 0, n_shared > 0))
+
+        @prologue
         def _shared():
             if index_heads:
                 # every sequence's row of the head's columns, as many sequences a
@@ -624,6 +707,96 @@ def _paged_kernel(
                 head_ref[1], 0, n_shared, softmax(
                     n_shared * page_size, False, stacked_q, shared_state,
                     latent_rows or shared_rows, **stacked), then=own_walk(0))
+
+        if ring:
+            # The latent form FOLDED (PERF.md section 6, PR 56). The stacked pass is MXU
+            # work with the copy engine idle, a copy-bound row's own walk copies with MXU
+            # time to spare: so program 0 only COPIES the head's pages, once, into a
+            # buffer that outlives the grid, and the pass's units — a tile of stacked
+            # rows over one block of the resident head, the same update on the same
+            # shapes, tile after tile — are dealt out over the grid's programs, each
+            # taken behind one of its row's own blocks while the ring's copies fly. A
+            # row resumes from its tile's partial, so its tile is whole before its
+            # program's walk: program 0 takes the first tile's units up front, every
+            # later tile's are done a tile ahead. The ring: the call's blocks are ONE
+            # sequence over the rows, block g in slot g % ring; whoever works keeps
+            # ``ring`` blocks started beyond those consumed, across rows' ends
+            STARTED, ROW, COL, CONSUMED, DONE = range(5)  # ring_ref's words
+            n_head_blocks = pl.cdiv(n_shared, ppb)
+            seqs = latent_rows // gp  # sequences a stacked tile
+            n_units = (shared_rows // latent_rows) * n_head_blocks
+
+            def head_copies(act):
+                """The head's pages into ``head_buf``, whole blocks (a partial last
+                block's slots re-read the last page: finite under their mask)."""
+                def page(i, carry):
+                    phys = page_table_ref[head_ref[1], jnp.minimum(i, n_shared - 1)]
+                    act(pltpu.make_async_copy(sources[0].at[layer, phys], head_buf.at[i],
+                                              sems.at[ring, 0]))
+                    return carry
+
+                jax.lax.fori_loop(0, n_head_blocks * ppb, page, None)
+
+            def fill(upto):
+                """Start the call's next blocks, in order over the rows, until ``upto``
+                of them are started or the last row's last is."""
+                def more(c):
+                    return jnp.logical_and(c[0] < upto, c[1] < B)
+
+                def one(c):
+                    started, r, j = c
+                    _r, first_r, pages_r = own_walk(r)
+                    has = j * ppb < pages_r
+
+                    @pl.when(has)
+                    def _start():
+                        slot = started % ring
+                        for i in range(ppb):  # a partial last block re-reads the last live page
+                            col = first_r + jnp.minimum(j * ppb + i, pages_r - 1)
+                            phys = page_table_ref[r, col]
+                            pltpu.make_async_copy(sources[0].at[layer, phys],
+                                                  buffers[0].at[slot, i], sems.at[slot, 0]).start()
+
+                    return (jnp.where(has, started + 1, started), jnp.where(has, r, r + 1),
+                            jnp.where(has, j + 1, 0))
+
+                ring_ref[STARTED], ring_ref[ROW], ring_ref[COL] = jax.lax.while_loop(
+                    more, one, (ring_ref[STARTED], ring_ref[ROW], ring_ref[COL]))
+
+            def units(upto):
+                """The pass's units below ``upto`` that are not done yet: unit k is
+                stacked tile ``k // n_head_blocks`` over the head's block ``k %
+                n_head_blocks``."""
+                def unit(k, carry):
+                    t, c = k // n_head_blocks, k % n_head_blocks
+                    rows = pl.ds(pl.multiple_of(t * latent_rows, 8), latent_rows)
+                    softmax(n_shared * page_size, False, lambda _t, W: qs_ref[0, rows, :],
+                            tuple(ref.at[rows] for ref in shared_state), latent_rows, tiles=1,
+                            keep_of=lambda _t, col: stacked_keep(t, col),
+                            block_of=lambda c: head_buf[pl.ds(c * ppb, ppb)].reshape(T, Dk),
+                            )(c, 0, c)
+                    return carry
+
+                jax.lax.fori_loop(ring_ref[DONE], upto, unit, None)
+                ring_ref[DONE] = jnp.maximum(ring_ref[DONE], upto)
+
+            @pl.when(b == 0)
+            def _call():
+                for word in range(5):
+                    ring_ref[word] = 0
+
+                @pl.when(n_shared > 0)
+                def _head():
+                    reset(shared_state)
+                    head_copies(lambda c: c.start())
+
+            fill(ring_ref[CONSUMED] + ring)
+
+            @pl.when(jnp.logical_and(b == 0, n_shared > 0))
+            def _landed():
+                head_copies(lambda c: c.wait())
+
+            units(jnp.minimum(n_units, (b // seqs + 1) * n_head_blocks))
 
         if not index_heads:
             @pl.when(member_ref[b] != 0)
@@ -659,8 +832,30 @@ def _paged_kernel(
         def own_q(t, W):
             return q_ref[0, t, :, :W]
     # (a latent row's one query stands on its last token: ``kv_len`` is its causal bound)
-    slot = walk(b, first, n_pages,
-                softmax(kv_len, not latent_rows, own_q, own_state, Rt, **own_keep), **chain)
+    own_update = softmax(kv_len, not latent_rows, own_q, own_state, Rt, **own_keep)
+    if ring:
+        n_blocks, g0 = pl.cdiv(n_pages, ppb), ring_ref[CONSUMED]
+        # units a program takes behind its own blocks: a tile's rows do the next tile's pass
+        quota = pl.cdiv(n_head_blocks, seqs)
+
+        def block(j, carry):
+            fill(g0 + j + ring)
+            slot = (g0 + j) % ring
+            for i in range(ppb):  # (a wait counts bytes, not addresses)
+                pltpu.make_async_copy(sources[0].at[layer, 0], buffers[0].at[slot, i],
+                                      sems.at[slot, 0]).wait()
+            own_update(slot, first, j)
+
+            @pl.when(j < quota)
+            def _unit():
+                units(jnp.minimum(n_units, ring_ref[DONE] + 1))
+
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, None)
+        ring_ref[CONSUMED] = g0 + n_blocks
+    else:
+        slot = walk(b, first, n_pages, own_update, **chain)
     if chained:
         slot_ref[0] = slot
 
@@ -904,7 +1099,10 @@ def paged_latent_attention(
     ``keep[b, j]``, scores against the whole token row, values its first
     ``value_width`` lanes; returns [B, H, value_width]. ``_paged_kernel``'s
     walk — whole pages double-buffered as far as the row goes, the batch's
-    shared head once for all rows' queries stacked — with one source, one KV
+    shared head once for all rows' queries stacked (as program 0's prologue, or
+    where ``latent_head_form`` says the rows' own walks are copy-bound FOLDED
+    into them: the head resident in VMEM, the blocks through a ring) — with
+    one source, one KV
     "head" of ``H`` query rows, and the selection as a mask block. A row
     without a kept token gives zeros. ``with_lse``: returns ``(values [B, H,
     value_width] float32, each query row's log-sum-exp of its scaled scores
@@ -934,16 +1132,25 @@ def paged_latent_attention(
     blocks, in_specs = [q], [pl.BlockSpec((1, H, Dk), lambda b, qi, *_: (b, 0, 0))]
     state = [pltpu.VMEM((gp, 128), jnp.float32), pltpu.VMEM((gp, 128), jnp.float32),
              pltpu.VMEM((gp, value_width), jnp.float32)]
+    folded = latent_head_form(B, H, Dk, value_width, pages.dtype.itemsize) == "folded"
+    ring = LATENT_RING_SLOTS if folded else 0
     if shared_rows:
         if shared is None:
             shared = shared_head(page_table, kv_len, page_size, kv_len > 0)
-        prefetch += [jnp.asarray(x, jnp.int32) for x in shared]
+        member, head = (jnp.asarray(x, jnp.int32) for x in shared)
+        if folded:
+            # the resident head: whole blocks, as far as LATENT_HEAD_TOKENS and the table
+            # go; pages of a longer head behind those are the rows' own
+            head_pages = _round_up(min(LATENT_HEAD_TOKENS // page_size, max_pages), ppb)
+            head = head.at[0].min(head_pages)
+        prefetch += [member, head]
         blocks.append(jnp.pad(q, ((0, 0), (0, gp - H), (0, 0))).reshape(1, shared_rows, Dk))
         in_specs.append(pl.BlockSpec((1, shared_rows, Dk), lambda b, qi, *_: (0, 0, 0)))
         state += [pltpu.VMEM((shared_rows, 128), jnp.float32),
                   pltpu.VMEM((shared_rows, 128), jnp.float32),
-                  pltpu.VMEM((shared_rows, value_width), jnp.float32),
-                  pltpu.SMEM((1,), jnp.int32)]
+                  pltpu.VMEM((shared_rows, value_width), jnp.float32)]
+        state += ([pltpu.VMEM((head_pages,) + pages.shape[2:], pages.dtype),
+                   pltpu.SMEM((5,), jnp.int32)] if folded else [pltpu.SMEM((1,), jnp.int32)])
     blocks.append(keep)
     in_specs.append(pl.BlockSpec(keep.shape, lambda b, qi, *_: (0, 0)))
     out_width = value_width + (128 if with_lse else 0)
@@ -952,13 +1159,15 @@ def paged_latent_attention(
         grid=(B, 1),
         in_specs=in_specs + [pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, H, out_width), lambda b, qi, *_: (b, 0, 0)),
-        scratch_shapes=[*state, pltpu.VMEM((2, ppb) + pages.shape[2:], pages.dtype),
-                        pltpu.SemaphoreType.DMA((2, 1))],
+        # (folded: the ring's slots, and one semaphore more for the head's copies)
+        scratch_shapes=[*state, pltpu.VMEM((ring or 2, ppb) + pages.shape[2:], pages.dtype),
+                        pltpu.SemaphoreType.DMA((ring + 1 if ring else 2, 1))],
     )
     kernel = functools.partial(
         _paged_kernel, block_q=1, page_size=page_size, pages_per_block=ppb, n_kv=1,
         group=H, pack=1, scale=scale, quantized=False, shared_rows=shared_rows,
-        latent_rows=per * gp, **({"with_lse": True} if with_lse else {}))
+        latent_rows=per * gp, **({"with_lse": True} if with_lse else {}),
+        **({"ring": ring} if ring else {}))
     got = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, out_width), jnp.float32 if with_lse else q.dtype),

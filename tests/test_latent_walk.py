@@ -9,6 +9,9 @@ CONTEXTS  under, at and over ``index_topk``; a partly filled last page
 TIES      exact ties at the k-th score: the tokens the stable sort keeps
 SHARED    a shared head with a row that is no member; a dead row
 BLOCKS    several blocks a walk, several tiles of stacked rows
+HEAD      the two forms a call takes the shared head in (PR 56) have a file of
+          their own, ``tests/test_latent_walk_head.py`` (a file is one worker's
+          work under the driver's ``--dist loadfile``)
 PACKED    ``packed_attention``'s one-token rows through the same form
 ENGINE    the decode step on the kernel backend; the counter's ``form``
 INDEX     the indexer's scores by the same walk (PR 43): ``paged_index_scores``

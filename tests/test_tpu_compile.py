@@ -1076,6 +1076,76 @@ def test_the_width_2_latent_walk_compiles_for_v5e_at_joyais_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1e9
 
 
+def _latent_walk(one_chip, rows, heads, table, pool, layers, with_lse):
+    """``paged_latent_attention`` at a cell's shape: the kernel's VMEM and SMEM
+    scratch by shape (read off the traced call), and its compile for a
+    described v5e (which is what refuses a call over ``LATENT_VMEM_BYTES``)."""
+    from finchat_tpu.ops import paged_attention as pa
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def call(q, pages, keep, page_table, kv_len, layer):
+        return pa.paged_latent_attention(q, pages, keep, page_table, kv_len, layer, page_size=PAGE,
+                                         value_width=512, scale=192 ** -0.5, with_lse=with_lse)
+
+    args = (shape((rows, heads, 640)), shape((layers, pool, PAGE, 640)),
+            shape((rows, table * PAGE), jnp.bool_), shape((rows, table), jnp.int32),
+            shape((rows,), jnp.int32), shape((1,), jnp.int32))
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(call)(*args).jaxpr)
+    [eqn] = found
+    n_scratch = eqn.params["grid_mapping"].num_scratch_operands
+    scratch = [tuple(v.aval.shape) for v in eqn.params["jaxpr"].invars[-n_scratch:]]
+    jax.jit(call).lower(*args).compile()
+    return scratch
+
+
+@pytest.mark.parametrize("rows, heads, with_lse", [
+    (32, 64, True),  # JoyAI-LLM-Flash: a token and its draft, 2 x 32 heads, the log-sum-exp
+    (32, 32, False),  # Kimi-Linear
+], ids=["joyai", "kimi-linear"])
+def test_the_latent_walk_with_the_head_folded_compiles_for_v5e_inside_its_vmem(
+        one_chip, rows, heads, with_lse):
+    """The rule says ``folded`` at both shapes (PR 56): the call holds the
+    head's 32 pages resident (5 MiB), walks through a ring of
+    ``LATENT_RING_SLOTS`` blocks of 8 pages and keeps five SMEM words of ring
+    state — beside the stacked rows' state — and the chip's compiler takes it
+    under ``LATENT_VMEM_BYTES``."""
+    from finchat_tpu.ops import paged_attention as pa
+
+    assert pa.latent_head_form(rows, heads, 640, 512, 2) == "folded"
+    scratch = _latent_walk(one_chip, rows, heads, 256, 5120, 6, with_lse)
+    assert (32, PAGE, 640) in scratch and (pa.LATENT_RING_SLOTS, 8, PAGE, 640) in scratch
+    assert (5,) in scratch and (pa.LATENT_RING_SLOTS + 1, 1) in scratch
+    assert (rows * heads, 512) in scratch  # the stacked tiles stay: the MXU's better shape
+    import math
+
+    held = sum(math.prod(dims) * (2 if dims[-1] == 640 else 4) for dims in scratch if len(dims) > 1)
+    assert held < pa.LATENT_VMEM_BYTES // 2, held  # blocks, the mask and the update's values beside it
+
+
+def test_deepseeks_latent_walk_keeps_the_stacked_pass_and_its_scratch_for_v5e(one_chip):
+    """128 query rows a latent row: the rule says ``stacked``, and the call's
+    scratch is the listing it always had — own state, the stacked rows' state,
+    the chain's one SMEM word, two slots of 8 pages, two semaphores; no
+    resident head, no ring."""
+    from finchat_tpu.ops import paged_attention as pa
+
+    assert pa.latent_head_form(ROWS, 128, 640, 512, 2) == "stacked"
+    assert _latent_walk(one_chip, ROWS, 128, WIDTH, POOL, 5, False) == [
+        (128, 128), (128, 128), (128, 512), (2048, 128), (2048, 128), (2048, 512), (1,),
+        (2, 8, PAGE, 640), (2, 1)]
+
+
 def test_joyai_decode_step_compiles_for_v5e_drafting_and_verifying_in_one_program(one_chip):
     """The whole decode step at the cell's size (1 dense + 4 routed layers and
     the module, 32 slots, 64 of 256 experts held, a table of 256 pages): the
