@@ -30,6 +30,7 @@ would copy the multi-GB cache every token (measured ~22 ms/step, round 4).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from dataclasses import dataclass
 from functools import partial
@@ -46,6 +47,7 @@ from finchat_tpu.engine.kv_cache import (
 )
 from finchat_tpu.engine.sampler import distribution, draw, sample, verify_draft
 from finchat_tpu.models.llama import (
+    WINDOW,
     LlamaConfig,
     forward,
     lm_head,
@@ -83,10 +85,12 @@ NOT_CARRIED: dict[str, dict[str, str]] = {
         "mesh.* > 1": "the latent pool has no sharding rule",
     },
     # config.window: sliding-window layers' pages in a second pool, a bounded
-    # page list a row kept by the host (kv_cache.WindowPager)
+    # page list a row kept by the host (kv_cache.WindowPager: window / page_size
+    # + 2 pages a row, 3 where the window is ONE page) — as wide as the window
+    # layers' own K/V heads where attention's shape is a kind's (config.attn_kinds)
     "window pages": {
         "engine.spec_tokens": "verify_step walks one pool, and a rejected draft rewinds a row",
-        "mesh.* > 1": "the window pool has no sharding rule",
+        "mesh.* > 1": "the window pool (and a kind's own k / v stacks) has no sharding rule",
     },
     # config.mtp_layers: a next-token-prediction module that drafts on the
     # device; its block's latent pages, a row's pending draft and the hidden
@@ -277,15 +281,16 @@ def _attention_by_kind(full, window, config: LlamaConfig):
     for: ``swa_attention`` around a window layer; ``yoco_attention`` around the
     ONE cache a plan's full and cross layers walk, while a pattern's full
     layers, each walking pages of its own, stay under ``full``'s own scopes
-    (``paged_attention``), as every model's without window layers."""
-    from finchat_tpu.models.sambay import WINDOW
-
+    (``paged_attention``), as every model's without window layers. Each of the
+    two callbacks is built for ITS kind's K/V heads (``LlamaConfig.attn_kind``)."""
     def attention(q: Array, k: Array | None, v: Array | None, cache: Any, layer_idx: Array,
-                  kind: str):
+                  kind: str, sink: Array | None = None):
         pool, win_pool = cache
         if kind == WINDOW:
             with jax.named_scope("swa_attention"):
-                out, win_pool = window(q, k, v, win_pool, layer_idx)
+                # (``sink``: the layer's softmax sinks, where the kind has them)
+                out, win_pool = window(q, k, v, win_pool, layer_idx,
+                                       **({} if sink is None else {"sink": sink}))
         elif config.layer_plan:
             with jax.named_scope("yoco_attention"):
                 out, pool = full(q, k, v, pool, layer_idx)
@@ -464,7 +469,8 @@ def _paged_attention_fn(
                                  n_valid > 0)
     window_kw = {"window": window} if window else {}
 
-    def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
+    def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array,
+                  sink: Array | None = None):
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
         B, C = q.shape[:2]
@@ -504,13 +510,16 @@ def _paged_attention_fn(
                     (k_pages, v_pages, k_scales, v_scales), k, v,
                     page_table, start_pos, n_valid, page_size, layer_idx, n_kv,
                 )
-        with jax.named_scope("paged_attention"):
+        # (a window layer's walk reads under its caller's ``swa_attention`` alone, so
+        # that ``paged_attention`` names the walks over the pool that grows with the context)
+        with contextlib.nullcontext() if window else jax.named_scope("paged_attention"):
             out = paged_attention(
                 q, k_pages, v_pages, page_table, start_pos, start_pos + n_valid,
                 layer, page_size=page_size, n_kv=n_kv, backend=attn_backend,
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
                 shared=shared, scale=scale, **window_kw,
+                **({} if sink is None else {"sink": sink}),
             )
         return out, (k_pages, v_pages, k_scales, v_scales)
 
@@ -621,14 +630,14 @@ def prefill_step(
     # the rotary positions above stay absolute. Zero gaps = identity.
     attention = _paged_attention_fn(
         page_rows, start_pos - state.kv_gaps[slots], n_valid,
-        page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
+        page_size, config.attn_kind().n_kv_heads, attn_backend, scale=config.attention_scale,
         latent=_latent_shape(config),
     )
     if config.window:
         attention = _attention_by_kind(attention, _paged_attention_fn(
             state.win_table[slots], start_pos - state.win_gaps[slots], n_valid,
-            page_size, config.n_kv_heads, attn_backend, scale=config.attention_scale,
-            window=config.window), config)
+            page_size, config.attn_kind(WINDOW).n_kv_heads, attn_backend,
+            scale=config.attention_scale, window=config.window), config)
     # hidden states only, then project just each sequence's last valid row:
     # full-chunk fp32 logits would be [N, C, vocab] — 4.2 GB at
     # 64 x 128 x 128256 (an 8B model) — vs 33 MB for [N, vocab]
@@ -960,13 +969,13 @@ def decode_step(
     # reduce to the legacy absolute math bit-for-bit)
     attention = _paged_attention_fn(
         state.page_table, state.context_lens - state.kv_gaps, n_valid,
-        page_size, config.n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
+        page_size, config.attn_kind().n_kv_heads, attn_backend, decode=True, scale=config.attention_scale,
         latent=_latent_shape(config),
     )
     if config.window:
         attention = _attention_by_kind(attention, _paged_attention_fn(
             state.win_table, state.context_lens - state.win_gaps, n_valid,
-            page_size, config.n_kv_heads, attn_backend, decode=True,
+            page_size, config.attn_kind(WINDOW).n_kv_heads, attn_backend, decode=True,
             scale=config.attention_scale, window=config.window), config)
     # a mixer's state advances one token in every active slot, in place
     # (row i IS slot i, no gather: on a kernel backend ops/ssm_step.py's one
@@ -1198,7 +1207,8 @@ def _ragged_attention_fn(
     # (jit keys on the keywords a call passes: the other models' calls stay as they were)
     kernel_kw = {k: v for k, v in (("window", window), ("block_q", block_q)) if v}
 
-    def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array):
+    def attention(q: Array, k: Array, v: Array, cache: Any, layer_idx: Array,
+                  sink: Array | None = None):
         k_pages, v_pages, k_scales, v_scales = cache
         quantized = k_pages.dtype == jnp.int8  # static under trace
         T = q.shape[1]
@@ -1220,6 +1230,7 @@ def _ragged_attention_fn(
                 k_scales=k_scales if quantized else None,
                 v_scales=v_scales if quantized else None,
                 kv_gap=row_gap, scale=scale, **kernel_kw,
+                **({} if sink is None else {"sink": sink}),
             )
         return out[None], (k_pages, v_pages, k_scales, v_scales)
 
@@ -1344,15 +1355,17 @@ def ragged_mixed_step(
     block_q = 64 if T >= 2048 and config.cache_readers > 1 else 0
     attention = _ragged_attention_fn(
         page_rows, tok_row, tok_pos, row_kv_len, tok_live,
-        page_size, config.n_kv_heads, attn_backend, row_gap=row_gap, scale=config.attention_scale,
+        page_size, config.attn_kind().n_kv_heads, attn_backend, row_gap=row_gap,
+        scale=config.attention_scale,
         latent=latent, rows=packed_rows() if latent is not None else None, block_q=block_q,
         **({"pair": True} if config.mtp_layers else {}),
     )
     if config.window:
         attention = _attention_by_kind(attention, _ragged_attention_fn(
             state.win_table[row_slot], tok_row, tok_pos, row_kv_len, tok_valid,
-            page_size, config.n_kv_heads, attn_backend, row_gap=state.win_gaps[row_slot],
-            scale=config.attention_scale, window=config.window, block_q=block_q), config)
+            page_size, config.attn_kind(WINDOW).n_kv_heads, attn_backend,
+            row_gap=state.win_gaps[row_slot], scale=config.attention_scale,
+            window=config.window, block_q=block_q), config)
     ssm_rows = packed_rows() if config.has_state else None
     # hidden states only, then project only each row's sampling positions —
     # the [T, vocab] fp32 logits tensor would cost GBs at production shapes

@@ -7,7 +7,11 @@ servable on fixed TPU HBM:
 
 - Device side: ``k_pages``/``v_pages`` shaped ``[n_layers, num_pages,
   page_size, n_kv_heads * head_dim]`` — token-major pages with the KV heads
-  fused into the minor dim. This layout is chosen for Mosaic's DMA tiling
+  fused into the minor dim; each array as wide as ITS heads are (keys of 192
+  over values of 128 are arrays of two widths) and a pool as wide as the heads
+  of the KIND of layer that owns it (``LlamaConfig.kv_widths``: a model whose
+  window layers keep 8 K/V heads and whose full layers keep 4 has pools of two
+  page widths). This layout is chosen for Mosaic's DMA tiling
   rules (measured on v5e, round 4): a page's trailing dims
   ``(page_size, Hkv*hd)`` are tile-aligned, so the in-place decode append
   kernel (ops/kv_append.py) can RMW one whole page per sequence with legal
@@ -35,7 +39,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from finchat_tpu.models.llama import LlamaConfig
+from finchat_tpu.models.llama import WINDOW, LlamaConfig
 from finchat_tpu.utils.logging import get_logger
 from finchat_tpu.utils.metrics import METRICS
 
@@ -65,7 +69,8 @@ class PagedKVCache:
     When off, the scale leaves are kept as (1,1,1,1) placeholders so the
     engine state pytree structure is identical in both modes."""
 
-    # [L, P, page_size, Hkv * head_dim] (dtype or int8); for latent attention
+    # [L, P, page_size, Hkv * head_dim] and [.., Hkv * value_dim] (dtype or int8:
+    # LlamaConfig.kv_widths); for latent attention
     # the latent rows [.., latent_row] and the indexer's key rows [.., Di]
     k_pages: Any
     v_pages: Any
@@ -110,10 +115,11 @@ class PagedKVCache:
     def create_window(cls, config: LlamaConfig, num_pages: int,
                       page_size: int) -> "PagedKVCache":
         """The second pool of a model with sliding-window layers
-        (``config.n_window_layers`` deep): the same rows as the first pool's,
-        never quantized; a row's pages of it are the window's alone
+        (``config.n_window_layers`` deep): rows as wide as the WINDOW layers'
+        heads (``kv_widths``: the first pool's, unless the kinds differ), never
+        quantized; a row's pages of it are the window's alone
         (``WindowPager``)."""
-        k_row, v_row = config.kv_row_widths
+        k_row, v_row = config.kv_widths(WINDOW)
         shape = (config.n_window_layers, num_pages, page_size)
         return cls(
             k_pages=jnp.zeros((*shape, k_row), config.dtype),
@@ -145,7 +151,7 @@ def page_hbm_bytes(config: LlamaConfig, page_size: int, kv_quant: str = "",
     import numpy as np
 
     if kind == "window":
-        return (config.n_window_layers * page_size * sum(config.kv_row_widths)
+        return (config.n_window_layers * page_size * sum(config.kv_widths(WINDOW))
                 * np.dtype(config.dtype).itemsize)
     itemsize = 1 if kv_quant else np.dtype(config.dtype).itemsize
     per = config.n_attn_layers * page_size * sum(config.kv_row_widths) * itemsize

@@ -67,6 +67,17 @@ MOE_DENSE_TOKENS_MAX = 128
 
 
 @dataclass(frozen=True)
+class AttnKind:
+    """What of attention's shape is a KIND's (``LlamaConfig.attn_kinds``): the
+    K/V heads its layers keep a token, the base its q and k are rotated at
+    (None: not rotated), and whether its softmax has a SINK — one learned
+    logit a query head that takes probability and gives no value."""
+    n_kv_heads: int
+    rope_theta: float | None = 10_000.0
+    sink: bool = False
+
+
+@dataclass(frozen=True)
 class LlamaConfig:
     vocab_size: int = 260
     dim: int = 128
@@ -137,6 +148,21 @@ class LlamaConfig:
     # share the ``attn_*`` stacks; only LINEAR or MAMBA layers (or the mixer in
     # every layer, above) own state
     layer_pattern: tuple[str, ...] = ()
+    # attention's shape by KIND, where the FULL and the WINDOW layers of a
+    # pattern differ in it: ((kind, AttnKind), ...) names both. Each kind then
+    # has ``attn_k`` / ``attn_v`` stacks of its own (the WINDOW layers'
+    # ``swa_k`` / ``swa_v``, and ``swa_sink`` [Lw, H] float32 where they have
+    # a sink) and pages as wide as ITS heads (``kv_widths``); ``n_kv_heads``,
+    # ``rope_theta`` and ``rope_kinds`` are then not read for those layers.
+    # Empty = both kinds share the ``attn_*`` stacks: today's block
+    attn_kinds: tuple[tuple[str, AttnKind], ...] = ()
+    # the leading dims of a q and a k head that are rotated (dim i paired with
+    # i + rope_dim / 2); the others pass as they are. 0 = the whole head
+    rope_dim: int = 0
+    # a scalar on v before attention (1 = absent: nothing is emitted)
+    value_scale: float = 1.0
+    # the seeded sinks' standard deviation (a checkpoint brings its own)
+    sink_init_std: float = 1.0
     # RMSNorm over the whole width of q and of k, before the heads are split
     qk_norm: bool = False
     # RMSNorm over each HEAD of q and of k, after the split: one weight vector
@@ -178,6 +204,9 @@ class LlamaConfig:
     kv_lora_rank: int = 0
     qk_nope_dim: int = 0
     qk_rope_dim: int = 0  # the rotated part of a head's q and of the shared key
+    # a head's values where they are not as wide as its keys — latent
+    # attention's (required there), or K/V heads' (0 = ``head_dim``: keys of
+    # 192 over values of 128 are pages of two widths, ``kv_widths``)
     v_head_dim: int = 0
     # YaRN's per-frequency correction of the rotation (models/mla.py
     # ``RopeScaling``); None = plain frequencies
@@ -271,6 +300,23 @@ class LlamaConfig:
             raise ValueError(f"{WINDOW!r} layers of a layer_pattern stand beside {FULL!r} layers "
                              "alone: no mixer, linear or latent attention (a row's snapshot "
                              "would hold state and window pages of two blocks)")
+        if self.attn_kinds:
+            kinds = dict(self.attn_kinds)
+            if (set(kinds) != {FULL, WINDOW} or len(kinds) != len(self.attn_kinds)
+                    or self.layer_plan or not self.window or self.rope_kinds):
+                raise ValueError(
+                    f"attn_kinds names {FULL!r} and {WINDOW!r}, once each, for a layer_pattern "
+                    "with window layers, and says which kinds are rotated itself (no rope_kinds)")
+            if any(self.n_heads % shape.n_kv_heads for shape in kinds.values()):
+                raise ValueError("attn_kinds: a kind's K/V heads divide n_heads")
+            if kinds[FULL].sink:  # (a full layer's walk has a stacked pass to start too)
+                raise ValueError(f"attn_kinds: a sink is the {WINDOW!r} kind's softmax's")
+        if (self.rope_dim or self.value_scale != 1.0 or (self.v_head_dim and not self.kv_lora_rank)) \
+                and (self.layer_plan or self.kv_lora_rank):
+            raise ValueError("rope_dim, value_scale and a v_head_dim beside K/V heads are the "
+                             "llama block's: not a layer_plan's, nor latent attention's")
+        if self.rope_dim % 2 or self.rope_dim > self.head_dim:
+            raise ValueError("rope_dim: an even number of a head's leading dims")
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (over the whole width) and qk_head_norm (over each head): "
                              "one or the other")
@@ -428,18 +474,41 @@ class LlamaConfig:
         is padded so in HBM whatever the shape says)."""
         return -(-(self.kv_lora_rank + self.qk_rope_dim) // 128) * 128
 
+    def attn_kind(self, kind: str = FULL) -> AttnKind:
+        """Attention's shape in the layers of ``kind``: ``attn_kinds``' entry,
+        or the model's own (``n_kv_heads``; ``rope_theta`` where the kind is
+        rotated, ``rope_kinds``; no sink)."""
+        for name, shape in self.attn_kinds:
+            if name == kind:
+                return shape
+        rotated = self.rope_theta is not None and (not self.rope_kinds or kind in self.rope_kinds)
+        return AttnKind(self.n_kv_heads, self.rope_theta if rotated else None)
+
     @property
-    def kv_row_widths(self) -> tuple[int, int]:
-        """Columns of a token's row in each of the pool's two paged arrays —
-        THE place a page's width is decided (``PagedKVCache.create`` and
-        ``page_hbm_bytes`` read it): K and V heads side by side, or for latent
-        attention the latent row in the first array and the indexer's key row
-        in the second (without an indexer ONE lane tile, never read: a minor
-        dimension of 1 is padded to 128 lanes in HBM whatever the shape says,
-        and the append's slab copy takes whole tiles only)."""
+    def value_dim(self) -> int:
+        """A K/V head's values: ``v_head_dim``, or as wide as its keys."""
+        return self.v_head_dim or self.head_dim
+
+    def kv_widths(self, kind: str = FULL) -> tuple[int, int]:
+        """Columns of a token's row in each of the two paged arrays of the
+        pool that the layers of ``kind`` own — THE place a page's width is
+        decided (``PagedKVCache.create`` / ``create_window`` and
+        ``page_hbm_bytes`` read it): the kind's K heads side by side in the
+        first array and its V heads in the second — as wide as each is, so
+        keys of 192 over values of 128 are arrays of two widths —, or for
+        latent attention the latent row in the first array and the indexer's
+        key row in the second (without an indexer ONE lane tile, never read: a
+        minor dimension of 1 is padded to 128 lanes in HBM whatever the shape
+        says, and the append's slab copy takes whole tiles only)."""
         if self.kv_lora_rank:
             return self.latent_row, self.index_head_dim or 128
-        return (self.n_kv_heads * self.head_dim,) * 2
+        n = self.attn_kind(kind).n_kv_heads
+        return n * self.head_dim, n * self.value_dim
+
+    @property
+    def kv_row_widths(self) -> tuple[int, int]:
+        """``kv_widths`` of the FULL layers' pool (every model's first)."""
+        return self.kv_widths(FULL)
 
     @property
     def n_state_layers(self) -> int:
@@ -597,6 +666,37 @@ PRESETS: dict[str, LlamaConfig] = {
         leading_kinds=(WINDOW,),
         layer_pattern=(WINDOW, WINDOW, WINDOW, FULL), window=8,
     ),
+    # MiMo-V2-Flash (XiaomiMiMo, ``mimo_v2_flash``, 309B-A15B): attention's
+    # shape is a KIND's — full layers of 4 K/V heads rotated at 5e6, window
+    # layers (128 tokens) of 8 at 1e4 with a sink in the softmax —, keys of 192
+    # over values of 128 in both, the first 64 dims of a head rotated, values
+    # scaled by 0.707; a leading dense full layer, then 256 routed experts of
+    # 2,048 at 8 a token (sigmoid scores, a selection bias, no shared expert).
+    # The 47 layers behind the leading one (four window, one full, then seven
+    # times five window, one full) are their kinds spelled out, one period
+    "mimo-v2-flash": LlamaConfig(
+        vocab_size=152_576, dim=4096, n_layers=48, n_heads=64, n_kv_heads=4, head_dim=192,
+        v_head_dim=128, hidden_dim=2048, rope_theta=5_000_000.0, rope_dim=64, value_scale=0.707,
+        max_seq_len=32_768, n_experts=256, top_k_experts=8, moe_fused_glu=True,
+        moe_score="sigmoid", moe_select_bias=True, moe_bias_init_std=0.02,
+        leading_dense_layers=1, dense_hidden_dim=16_384, leading_kinds=(FULL,),
+        layer_pattern=(((WINDOW,) * 5 + (FULL,)) * 8)[1:], window=128,
+        attn_kinds=((FULL, AttnKind(4, 5_000_000.0)), (WINDOW, AttnKind(8, 10_000.0, sink=True))),
+    ),
+    # the same block at a size a test holds (tests/tiny_models.py builds it
+    # from the published keys): a leading dense full layer, two periods of
+    # (three window, one full), a window of 8 tokens, 4 of 16 experts held at
+    # 2 a token; the heads at their published widths (the kernels cut a key
+    # of 192 as lane tiles)
+    "mimo-tiny": LlamaConfig(
+        vocab_size=211, dim=64, n_layers=9, n_heads=16, n_kv_heads=2, head_dim=192,
+        v_head_dim=128, hidden_dim=32, rope_theta=5_000_000.0, rope_dim=64, value_scale=0.707,
+        max_seq_len=256, n_experts=4, moe_router_width=16, top_k_experts=2, moe_fused_glu=True,
+        moe_score="sigmoid", moe_select_bias=True, moe_bias_init_std=0.02,
+        leading_dense_layers=1, dense_hidden_dim=96, leading_kinds=(FULL,),
+        layer_pattern=(WINDOW, WINDOW, WINDOW, FULL), window=8,
+        attn_kinds=((FULL, AttnKind(2, 5_000_000.0)), (WINDOW, AttnKind(4, 10_000.0, sink=True))),
+    ),
 }
 
 
@@ -607,9 +707,13 @@ def n_params(config: LlamaConfig) -> int:
     if c.layer_plan:
         return sambay.n_params(c)
     d, hd = c.dim, c.head_dim
-    attn = d * (c.n_heads * hd) + 2 * d * (c.n_kv_heads * hd) + (c.n_heads * hd) * d
-    if c.kv_lora_rank:
-        attn = mla.n_attention_params(c)
+
+    def attn_of(kind: str) -> int:  # q, the kind's k and v, o, its sinks
+        a = c.attn_kind(kind)
+        return (d * c.n_heads * hd + d * a.n_kv_heads * (hd + c.value_dim)
+                + c.n_heads * c.value_dim * d + a.sink * c.n_heads)
+
+    attn = mla.n_attention_params(c) if c.kv_lora_rank else 0  # (else: by kind, below)
     mlp = 3 * d * c.hidden_dim
     if c.n_experts:
         # the held experts, the router at its whole width (and its selection
@@ -622,6 +726,9 @@ def n_params(config: LlamaConfig) -> int:
         attn += 2 * hd
     if c.attn_gate:
         attn += d * c.n_heads * hd
+    attention = c.n_kv_layers * attn
+    if not c.kv_lora_rank:
+        attention += sum(c.n_of(kind) * attn_of(kind) for kind in (FULL, WINDOW))
     norms = 4 * d if c.norm_both else 2 * d
     per_layer = mlp + norms
     # in/out projections, conv weight and bias, A_log, dt_bias, D, the gated
@@ -640,7 +747,7 @@ def n_params(config: LlamaConfig) -> int:
         r, d_k = c.gdn_gate_rank, c.gdn_heads * c.gdn_key_dim
         linear = (d * (c.gdn_conv_dim + 2 * r + c.gdn_heads) + r * (d_k + d_v) + d_v + d_v * d
                   + c.gdn_conv * c.gdn_conv_dim + c.gdn_heads + d_k + c.gdn_value_dim)
-    total = (c.vocab_size * d + c.n_scan_layers * per_layer + c.n_kv_layers * attn
+    total = (c.vocab_size * d + c.n_scan_layers * per_layer + attention
              + c.leading_dense_layers * (3 * d * c.dense_hidden_dim + norms)
              + c.n_of(LINEAR) * linear + c.n_of(MAMBA) * ssm + d)
     if not c.tie_embeddings:
@@ -688,7 +795,8 @@ def init_params(
     With a ``layer_pattern`` the stacks are by KIND (``_stack_kinds``): the
     ``attn_*`` leaves (``attn_{q,k}_norm`` with ``qk_norm`` or
     ``qk_head_norm``, ``attn_gate`` with ``attn_gate``) have the FULL and
-    WINDOW layers' depth, the ``gdn_*`` leaves the LINEAR layers', the MLP and
+    WINDOW layers' depth (with ``attn_kinds`` ``attn_k`` / ``attn_v`` the FULL
+    layers' alone, ``swa_k`` / ``swa_v`` / ``swa_sink`` the WINDOW layers'), the ``gdn_*`` leaves the LINEAR layers', the MLP and
     the norms (``ln_{attn,mlp}_out`` too with ``norm_both``) every layer's;
     without one this is the tree it always was.
 
@@ -730,16 +838,36 @@ def init_params(
     Ld_attn = c.leading_dense_layers - Ld_linear
     La = c.n_kv_layers - Ld_attn
 
-    def attention_leaves(depth: int, ks: Array) -> dict[str, Array]:
+    def attention_leaves(depth: int, ks: Array, leading: bool = False) -> dict[str, Array]:
         if c.kv_lora_rank:
             return mla.init_attention(c, ks[0], depth, rand_init)
         gate = {"attn_gate": rand_init("attn_gate", jax.random.fold_in(ks[3], 1),
                                        (depth, D, H * hd), D)} if c.attn_gate else {}
+        # k and v by KIND where ``attn_kinds`` says so (a kind's heads; the FULL
+        # layers' under the names they always had, the WINDOW layers' ``swa_*``
+        # with keys of their own, and their sinks): else ONE stack for both
+        stacks = [("attn", c.attn_kind(FULL), depth, lambda k: k)]
+        if c.attn_kinds:
+            stacks = [(own, c.attn_kind(kind),
+                       c.n_leading_of(kind) if leading else c.n_of(kind) - c.n_leading_of(kind),
+                       salted)
+                      for kind, own, salted in (
+                          (FULL, "attn", lambda k: k),
+                          (WINDOW, "swa", lambda k: jax.random.fold_in(k, 5)))]
+        kv = {}
+        for own, a, n, salted in stacks:
+            if not n:
+                continue
+            kv[f"{own}_k"] = rand_init(f"{own}_k", salted(ks[1]), (n, D, a.n_kv_heads * hd), D)
+            kv[f"{own}_v"] = rand_init(f"{own}_v", salted(ks[2]),
+                                       (n, D, a.n_kv_heads * c.value_dim), D)
+            if a.sink:
+                kv[f"{own}_sink"] = c.sink_init_std * jax.random.normal(
+                    salted(ks[0]), (n, H), jnp.float32)
         return {
             "attn_q": rand_init("attn_q", ks[0], (depth, D, H * hd), D),
-            "attn_k": rand_init("attn_k", ks[1], (depth, D, Hkv * hd), D),
-            "attn_v": rand_init("attn_v", ks[2], (depth, D, Hkv * hd), D),
-            "attn_o": rand_init("attn_o", ks[3], (depth, H * hd, D), H * hd),
+            **kv,
+            "attn_o": rand_init("attn_o", ks[3], (depth, H * c.value_dim, D), H * c.value_dim),
             **gate,
         }
 
@@ -757,7 +885,7 @@ def init_params(
         Ld, Fd = c.leading_dense_layers, c.dense_hidden_dim
         kd = jax.random.split(jax.random.fold_in(k_layers, 3), 7)
         params["dense_layers"] = {
-            **(attention_leaves(Ld_attn, kd) if Ld_attn else {}),
+            **(attention_leaves(Ld_attn, kd, leading=True) if Ld_attn else {}),
             **(gdn.init_params(c, jax.random.fold_in(kd[0], 2), Ld_linear, rand_init)
                if Ld_linear else {}),
             **norm_leaves(Ld),
@@ -872,13 +1000,19 @@ def _init_mtp(c: LlamaConfig, key: Array, rand_init: Callable) -> dict[str, Any]
             "layer": layer, "norm": jnp.ones((D,), c.dtype)}
 
 
-def _stack_kinds(name: str) -> tuple[str, ...] | None:
+def _stack_kinds(name: str, by_kind: bool = False) -> tuple[str, ...] | None:
     """Whose depth the stacked leaf ``name`` has under a ``layer_pattern``:
-    the layers of these kinds, or (None) every layer."""
+    the layers of these kinds, or (None) every layer. ``by_kind``
+    (``LlamaConfig.attn_kinds``): k and v are a kind's — ``attn_k`` / ``attn_v``
+    the FULL layers', ``swa_*`` the WINDOW layers'."""
     if name.startswith("gdn_"):
         return (LINEAR,)
     if name.startswith("ssm_"):
         return (MAMBA,)
+    if name.startswith("swa_"):
+        return (WINDOW,)
+    if by_kind and name in ("attn_k", "attn_v"):
+        return (FULL,)
     return (FULL, WINDOW) if name.startswith("attn_") else None
 
 
@@ -889,8 +1023,13 @@ def rms_norm(x: Array, weight: Array, eps: float) -> Array:
     return (x32 * rms).astype(x.dtype) * weight
 
 
-def rope(x: Array, positions: Array, theta: float) -> Array:
-    """Rotary position embedding, fp32 math. x: [B,S,H,D], positions: [B,S]."""
+def rope(x: Array, positions: Array, theta: float, rotated: int = 0) -> Array:
+    """Rotary position embedding, fp32 math. x: [B,S,H,D], positions: [B,S].
+    ``rotated`` > 0: the head's first ``rotated`` dims alone (dim i paired with
+    i + rotated / 2); the others pass as they are."""
+    if rotated and rotated < x.shape[-1]:
+        return jnp.concatenate(
+            [rope(x[..., :rotated], positions, theta), x[..., rotated:]], axis=-1)
     B, S, H, D = x.shape
     half = D // 2
     freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)  # [half]
@@ -899,8 +1038,8 @@ def rope(x: Array, positions: Array, theta: float) -> Array:
     sin = jnp.sin(angles)[:, :, None, :]
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
-    rotated = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
-    return rotated.astype(x.dtype)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
 
 
 class StackedLeaf(NamedTuple):
@@ -1187,7 +1326,8 @@ def _layer(
     c = config
     B, S, D = x.shape
     hq = c.n_heads // tp_size
-    hkv = c.n_kv_heads // tp_size
+    shape = c.attn_kind(kind)  # (read by the FULL and WINDOW layers of the llama block alone)
+    hkv = shape.n_kv_heads // tp_size
 
     def norm_in(x: Array, name: str) -> Array:
         return x if c.norm_after else rms_norm(x, layer_params[name], c.norm_eps)
@@ -1231,10 +1371,10 @@ def _layer(
         with jax.named_scope("attn_qkv"):
             h = scaled(h, c.attention_in_multiplier)
 
-            def heads(t: Array, n: int, norm: str = "") -> Array:
+            def heads(t: Array, n: int, norm: str = "", width: int = c.head_dim) -> Array:
                 if norm and c.qk_norm:  # over the whole width, before the split
                     t = rms_norm(t, layer_params[norm], c.norm_eps)
-                t = t.reshape(B, S, n, c.head_dim)
+                t = t.reshape(B, S, n, width)
                 if norm and c.qk_head_norm:  # over each head, one weight vector for all
                     t = rms_norm(t, layer_params[norm], c.norm_eps)
                 return t
@@ -1242,16 +1382,20 @@ def _layer(
             # q and k fenced flat: the heads' layout must not reach the weights
             q = heads(flat_fence(dense(h, layer_params["attn_q"], qm_backend=qm_backend)),
                       hq, "attn_q_norm")
-            k = heads(scaled(flat_fence(dense(h, layer_params["attn_k"], qm_backend=qm_backend)),
+            # (k and v by kind, ``attn_kinds``: a WINDOW layer's are ``swa_*``)
+            own = "swa" if c.attn_kinds and kind == WINDOW else "attn"
+            k = heads(scaled(flat_fence(dense(h, layer_params[f"{own}_k"], qm_backend=qm_backend)),
                              c.key_multiplier), hkv, "attn_k_norm")
-            v = heads(dense(h, layer_params["attn_v"], qm_backend=qm_backend), hkv)
-            if c.rope_theta is not None and (not c.rope_kinds or kind in c.rope_kinds):
-                q = rope(q, positions, c.rope_theta)
-                k = rope(k, positions, c.rope_theta)
+            v = heads(scaled(dense(h, layer_params[f"{own}_v"], qm_backend=qm_backend),
+                             c.value_scale), hkv, width=c.value_dim)
+            if shape.rope_theta is not None:
+                q = rope(q, positions, shape.rope_theta, c.rope_dim)
+                k = rope(k, positions, shape.rope_theta, c.rope_dim)
 
         # the attention callback opens its own scopes (engine/engine.py)
         attn_out, new_layer_cache = attention(
-            q, k, v, layer_cache, layer_idx, *((kind,) if c.window else ()))
+            q, k, v, layer_cache, layer_idx, *((kind,) if c.window else ()),
+            **({"sink": layer_params["swa_sink"]} if shape.sink else {}))
         with jax.named_scope("attn_o"):
             if c.attn_gate:
                 assert tp_axis is None, "manual-TP stage blocks have no output gate"
@@ -1398,6 +1542,7 @@ def forward(
     # period is longer than one layer or the experts' stacks must stay whole
     # (below), are indexed out of the stacks inside it
     by_index = len(pattern) > 1 or c.moe_sparse
+    by_kind = bool(c.attn_kinds)  # k and v stacked by kind (``_stack_kinds``)
 
     def pool_index(i, kind):  # the leading layers' pages come first in their kind's pool
         n_lead = c.n_leading_of(kind)
@@ -1426,9 +1571,10 @@ def forward(
             whole = ("moe_in", "moe_out") if c.moe_sparse else ()
             layer_params = {
                 name: StackedLeaf(leaf, among(None)) if name in whole else jax.tree.map(
-                    lambda a, i=among(_stack_kinds(name)): lax.dynamic_index_in_dim(
+                    lambda a, i=among(_stack_kinds(name, by_kind)): lax.dynamic_index_in_dim(
                         a, i, 0, keepdims=False), leaf)
-                for name, leaf in stacks.items() if among(_stack_kinds(name)) is not None}
+                for name, leaf in stacks.items()
+                if among(_stack_kinds(name, by_kind)) is not None}
             carry = one_layer(carry, layer_params, pool_index(among((kind,)), kind), kind)
         return carry, None
 
@@ -1442,7 +1588,7 @@ def forward(
     leading = c.kinds_of_leading
     for i, kind in enumerate(leading):
         def at(name):  # the layer's place in the leading stack ``name``, by kind as the scan's
-            kinds = _stack_kinds(name)
+            kinds = _stack_kinds(name, by_kind)
             if kinds is None:
                 return i
             return sum(leading[:i].count(k) for k in kinds) if kind in kinds else None
@@ -1612,7 +1758,11 @@ def make_causal_attention(backend: str, scale: float | None = None,
     into the cache (see ops/dispatch.py). ``scale``: the model's softmax
     scale (``LlamaConfig.attention_scale``; None = head_dim ** -0.5). With
     the ``config`` of a model with latent attention: its callback
-    (``mla.LatentAttentionFn``), dense over the sequence, the same selection."""
+    (``mla.LatentAttentionFn``), dense over the sequence, the same selection.
+    With one of a plan or of window layers: a WINDOW layer, and a layer whose
+    keys and values differ in width (``v_head_dim``), take ``ops/refs.py``'s
+    ``mha_reference`` on EVERY backend — the contiguous kernel has no window,
+    no sink and one head width; no served step comes here."""
     from finchat_tpu.ops.dispatch import causal_attention
 
     if config is not None and config.kv_lora_rank:
@@ -1632,7 +1782,7 @@ def make_causal_attention(backend: str, scale: float | None = None,
         from finchat_tpu.ops.refs import mha_reference
 
         def by_kind(q: Array, k: Array | None, v: Array | None, layer_cache: Any,
-                    layer_idx: Array, kind: str = FULL):
+                    layer_idx: Array, kind: str = FULL, sink: Array | None = None):
             # dense over the sequence: a plan's FULL layer leaves its K and V in
             # the cache's place, a CROSS layer reads them, a WINDOW layer masks
             if kind == CROSS:
@@ -1640,8 +1790,10 @@ def make_causal_attention(backend: str, scale: float | None = None,
             elif kind == FULL and config.layer_plan:
                 layer_cache = (k, v)
             if kind == WINDOW:
-                return mha_reference(q, k, v, causal=True, scale=scale,
-                                     window=config.window), layer_cache
+                return mha_reference(q, k, v, causal=True, scale=scale, window=config.window,
+                                     sink=sink), layer_cache
+            if k.shape[-1] != v.shape[-1]:  # (the contiguous kernel takes one width)
+                return mha_reference(q, k, v, causal=True, scale=scale), layer_cache
             return causal_attention(q, k, v, backend=backend, scale=scale), layer_cache
 
         return by_kind

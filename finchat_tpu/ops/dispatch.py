@@ -62,6 +62,7 @@ def paged_attention(
     shared: tuple[Array, Array] | None = None,  # decode: ``shared_head``'s
     scale: float | None = None,  # the softmax scale; None = D ** -0.5
     window: int = 0,  # > 0: a sliding-window layer (a query and the window - 1 before it)
+    sink: Array | None = None,  # [H] float32: the layer's softmax sink (``refs.mha_reference``)
 ) -> Array:
     """Paged-KV attention via the requested (or default) backend. An int8
     cache (engine kv_quant) is detected from the page dtype; the scale
@@ -83,7 +84,7 @@ def paged_attention(
         )
         return mha_reference(
             q, k_all, v_all, causal=True, q_offset=q_offset, kv_len=kv_len, scale=scale,
-            window=window,
+            window=window, sink=sink,
         )
     interpret = backend == "pallas-interpret"
     if quantized:
@@ -102,6 +103,7 @@ def paged_attention(
         q, k_pages, v_pages, page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
         **({"window": window} if window else {}),
+        **({"sink": sink} if sink is not None else {}),
     )
 
 
@@ -124,6 +126,7 @@ def ragged_paged_attention(
     scale: float | None = None,  # the softmax scale; None = D ** -0.5
     window: int = 0,  # > 0: a sliding-window layer (a query and the window - 1 before it)
     block_q: int = 0,  # > 0: the kernel's query block (0 = its own default)
+    sink: Array | None = None,  # [H] float32: the layer's softmax sink
 ) -> Array:
     """Ragged paged-KV attention (ops/ragged_paged_attention.py): prefill
     chunks, decode tokens, and spec verify blocks as rows of ONE packed
@@ -146,7 +149,7 @@ def ragged_paged_attention(
             page_size=page_size, n_kv=n_kv,
             k_scales=k_scales if quantized else None,
             v_scales=v_scales if quantized else None,
-            kv_gap=kv_gap, scale=scale, window=window,
+            kv_gap=kv_gap, scale=scale, window=window, sink=sink,
         )
     interpret = backend == "pallas-interpret"
     if quantized:
@@ -169,6 +172,7 @@ def ragged_paged_attention(
         page_size=page_size, n_kv=n_kv, scale=scale, interpret=interpret,
         kv_gap=kv_gap, **({"window": window} if window else {}),
         **({"block_q": block_q} if block_q else {}),
+        **({"sink": sink} if sink is not None else {}),
     )
 
 

@@ -9,7 +9,9 @@ place** through the scalar-prefetched page table, so per-step HBM traffic is
 the live KV bytes (ragged per sequence) and nothing else.
 
 Cache layout: ``[n_layers, P, page_size, Hkv*hd]`` — token-major pages,
-heads fused into the minor dim (see engine/kv_cache.py for why). The kernel
+heads fused into the minor dim (see engine/kv_cache.py for why); the K and
+the V array are as wide as the heads of each are (keys of 192 over values of
+128: 768 beside 512 columns at 4 heads; ``_key_lanes``). The kernel
 takes the FULL-depth cache plus a scalar-prefetched layer index, because the
 cache rides the model's layer scan as a carry; slicing one layer out with
 XLA would copy it.
@@ -185,6 +187,12 @@ from finchat_tpu.ops.flash_attention import (
 
 BLOCK_TOKENS = 512  # KV tokens per online-softmax update, where they fit
 SCORE_TILE_BYTES = 1 << 19  # one kv head's fp32 [rows, block] logit tile
+# the most stacked rows (the shared head's pass: every sequence's query heads of a tile)
+# that take ONE block update: more are taken in whole chunks of this many, so that the
+# pass's logit tile does not halve the block of the rows' own walks behind it (32 rows x
+# 16 query heads a K/V head: a 512-row tile held a block to 256 tokens, and the walks'
+# fixed work a block, not their copies, set the call's time: PERF.md section 6, PR 57)
+STACKED_ROWS = 256
 KV_BUFFER_BYTES = 8 << 20  # both slots of the K and the V block
 VMEM_BYTES = 31 << 19  # 15.5 MiB of the v5e's 16 MiB of scoped VMEM: blocks, state, buffers
 SUBLANES = 8  # rows of a float32 tile: the least a block update works on
@@ -249,11 +257,13 @@ def _heads_per_tile(group: int, block_q: int) -> int:
 
 
 def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
-                     max_pages: int, reserved: int = 0, whole_table: bool = False) -> int:
+                     max_pages: int, reserved: int = 0, whole_table: bool = False,
+                     v_width: int | None = None) -> int:
     """Pages copied and computed together: ``BLOCK_TOKENS`` tokens, halved
     while one tile's logits (``head_rows`` query rows: a KV head's, or those
     of the heads that share a tile) or the double-buffered K and V blocks
-    (``width = Hkv * hd`` elements a token) outgrow their VMEM budgets —
+    (``width = Hkv * hd`` elements a token in the K block, ``v_width`` in the V
+    block where that is another) outgrow their VMEM budgets —
     their own, and what the call's query and output blocks and its softmax
     state (``reserved`` bytes) leave of the whole: at 30 KV heads a token row
     is 7.5 KiB and a 128-query prefill block's state alone 5.6 MiB; never
@@ -271,7 +281,8 @@ def _pages_per_block(page_size: int, head_rows: int, width: int, itemsize: int,
     # an int8 block also stands dequantized beside its buffers, head by head
     # (float32, then the query dtype: 6 bytes an element; Mosaic keeps every
     # head's copy of the static unroll)
-    token_bytes = width * (4 * itemsize + (6 if itemsize == 1 else 0))
+    token_bytes = ((width + (width if v_width is None else v_width)) * 2 * itemsize
+                   + (6 * width if itemsize == 1 else 0))
 
     def over(tokens):
         return (head_rows * tokens * 4 > SCORE_TILE_BYTES
@@ -358,6 +369,9 @@ def _paged_kernel(
     chained: bool = False,
     with_lse: bool = False,
     ring: int = 0,
+    key_width: int = 0,
+    sink: bool = False,
+    stacked_chunks: int = 1,
 ):
     """One (sequence, query block): walk the row's live pages a block at a
     time. ``refs`` holds, in order: the scalar prefetch ``layer [1]``,
@@ -387,6 +401,19 @@ def _paged_kernel(
     wide acc; the other lanes hold other heads' values under this row's
     weights and are dropped at the end. The stacked queries are ``[tiles,
     shared_rows, pack * D]`` likewise, a sequence's 8 rows one tile.
+
+    With ``key_width`` (keys WIDER than values, and no whole number of lane
+    tiles: 192 over 128; ``pack`` 1) a K row holds its heads' ``key_width``
+    lanes side by side as they are — two heads are three lane tiles — and a
+    head's block is the two tiles that hold it, ``_key_lanes``; the query block
+    is ``[1, H, Bq, Dk]`` with ``Dk`` those 256 lanes, a head's query at the
+    lanes its keys have there and zeros at its neighbour's (``_pair_queries``),
+    so the product is the head's own. The value block, acc and the output stay
+    ``D`` wide. With ``sink`` (a softmax with a sink, at a call that traces no
+    stacked pass: a window layer's) behind the query block comes ``[H, 128]``
+    float32, each query head's sink logit on every lane: a walk's m / l / acc
+    start at (the sink, 1, 0) instead of (nothing, 0, 0) — the sink takes
+    probability and gives no value, and costs no pass.
 
     With ``latent_rows`` (``paged_latent_attention``: decode over a LATENT
     cache) there is ONE source, whose token row is key and value at once for
@@ -434,6 +461,8 @@ def _paged_kernel(
         member_ref, head_ref, q_ref, qs_ref, *refs = refs
     else:
         q_ref, *refs = refs
+    if sink:
+        sink_ref, *refs = refs
     if latent_rows:
         keep_ref, *refs = refs
     if index_heads:
@@ -533,7 +562,8 @@ def _paged_kernel(
         jax.lax.fori_loop(0, n_blocks, block, None)
         return (slot0 + n_blocks) % 2
 
-    def softmax(limit, causal, q_of, state, R, tiles=n_tiles, keep_of=None, block_of=None):
+    def softmax(limit, causal, q_of, state, R, tiles=n_tiles, keep_of=None, block_of=None,
+                chunks=1):
         """A walk's ``update``: the online softmax of ``R`` query rows a tile
         (``q_of(t)``, state in ``state``'s rows ``t*R .. (t+1)*R``) over a
         block; positions at or beyond ``limit`` are masked, and with
@@ -541,7 +571,9 @@ def _paged_kernel(
         latent form) those where ``keep_of(t, the block's first column)`` [R
         or 1, T] is 0. ``block_of(slot)`` (the latent form's resident head):
         the block's token rows [T, Dk] where they do not stand in the walk's
-        buffer."""
+        buffer. ``chunks``: a tile of heads' rows come in this many chunks of
+        ``R`` (``tiles`` counts the chunks: chunk ``t`` is of tile ``t //
+        chunks``)."""
         m_ref, l_ref, acc_ref = state
 
         def update(slot, first, j):
@@ -557,7 +589,7 @@ def _paged_kernel(
                     invalid = jnp.logical_or(invalid, kv_pos <= q_pos - window)
 
             for t in range(tiles):  # static unroll over tiles of kv heads
-                h0 = t * pack
+                h0 = t // chunks * pack
                 W = D if latent_rows else (min(h0 + pack, n_kv) - h0) * D  # the tile's lanes
                 q_blk = q_of(t, W)
                 masked = invalid
@@ -565,6 +597,10 @@ def _paged_kernel(
                     k_blk = block_of(slot) if block_of else buffers[0][slot].reshape(T, Dk)
                     v_blk = k_blk[:, :D]
                     masked = jnp.logical_or(invalid, keep_of(t, first + j * ppb) == 0)
+                elif key_width:  # the two lane tiles that hold the head's keys
+                    k0 = _key_lanes(h0, key_width, Dk)
+                    k_blk = buffers[0][slot, :, :, k0:k0 + Dk].reshape(T, Dk)
+                    v_blk = buffers[1][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
                 else:
                     k_blk = buffers[0][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
                     v_blk = buffers[1][slot, :, :, h0 * D:h0 * D + W].reshape(T, W)
@@ -629,8 +665,16 @@ def _paged_kernel(
 
     def reset(state):
         m_ref, l_ref, acc_ref = state
-        m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
-        l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
+        if sink:  # the sink is in the sum before any key: exp(sink - sink) = 1
+            if Bq == 1:  # a state row a query head
+                m_ref[:] = sink_ref[:]
+            else:  # a head's Bq rows: its sink on each
+                for h in range(n_kv * group):
+                    m_ref[h * Bq:(h + 1) * Bq] = jnp.broadcast_to(sink_ref[h:h + 1], (Bq, 128))
+            l_ref[:] = jnp.ones(l_ref.shape, jnp.float32)
+        else:
+            m_ref[:] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+            l_ref[:] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[:] = jnp.zeros(acc_ref.shape, jnp.float32)
 
     def own_walk(row):
@@ -675,6 +719,8 @@ def _paged_kernel(
                      for i in range(latent_rows // gp)], axis=0)
 
             stacked = dict(tiles=shared_rows // latent_rows, keep_of=stacked_keep)
+        elif stacked_chunks > 1:  # a tile of heads' stacked rows in whole chunks (STACKED_ROWS)
+            stacked = dict(tiles=n_tiles * stacked_chunks, chunks=stacked_chunks)
 
         # (folded: no prologue — the pass's units ride the rows' own walks, below)
         prologue = (lambda f: None) if ring else pl.when(jnp.logical_and(b == 0, n_shared > 0))
@@ -700,13 +746,20 @@ def _paged_kernel(
             if latent_rows:
                 def stacked_q(t, W):
                     return qs_ref[0, t * latent_rows:(t + 1) * latent_rows, :]
+            elif stacked_chunks > 1:  # a tile's stacked rows in whole chunks (STACKED_ROWS)
+                Rc = shared_rows // stacked_chunks
+
+                def stacked_q(t, W):
+                    rows = slice(t % stacked_chunks * Rc, (t % stacked_chunks + 1) * Rc)
+                    tile = t // stacked_chunks
+                    return qs_ref[tile, rows] if key_width else qs_ref[tile, rows, :W]
             else:
                 def stacked_q(t, W):
-                    return qs_ref[t, :, :W]
+                    return qs_ref[t] if key_width else qs_ref[t, :, :W]
             slot_ref[0] = walk(
                 head_ref[1], 0, n_shared, softmax(
                     n_shared * page_size, False, stacked_q, shared_state,
-                    latent_rows or shared_rows, **stacked), then=own_walk(0))
+                    latent_rows or shared_rows // stacked_chunks, **stacked), then=own_walk(0))
 
         if ring:
             # The latent form FOLDED (PERF.md section 6, PR 56). The stacked pass is MXU
@@ -827,7 +880,7 @@ def _paged_kernel(
         own_keep = dict(keep_of=lambda t, col: keep_block(col, b))
     elif pack == 1:
         def own_q(t, W):
-            return q_ref[0, t * group:(t + 1) * group].reshape(Rt, D)
+            return q_ref[0, t * group:(t + 1) * group].reshape(Rt, Dk)
     else:
         def own_q(t, W):
             return q_ref[0, t, :, :W]
@@ -892,7 +945,31 @@ def _block_diagonal(q: Array, n_kv: int, pack: int) -> Array:
         B, n_tiles, pack * group, pack * D)
 
 
-def _fit_block(bq: int, heads: int, head_dim: int, itemsize: int) -> int:
+def _key_lanes(head: int, key_width: int, tiles_width: int) -> int:
+    """Where the ``tiles_width`` lanes (whole lane tiles) that hold KV head
+    ``head``'s ``key_width`` keys start in a K row of heads side by side: two
+    heads of 192 are three tiles, the first head's keys the first two's leading
+    lanes, the second's the last two's trailing ones."""
+    return head // 2 * 2 * key_width + head % 2 * (2 * key_width - tiles_width)
+
+
+def _pair_queries(q: Array, n_kv: int) -> Array:
+    """Queries ``[..., H, Dq]`` against keys of ``Dq`` lanes that are no whole
+    number of lane tiles (192), as ``[..., H, Dk]`` over the two tiles that
+    ``_key_lanes`` cuts for the head's KV head: the query at the lanes its keys
+    have there — the leading ones for an even KV head, the trailing ones for an
+    odd one — and zeros at the lanes of the neighbour's keys."""
+    H, Dq = q.shape[-2:]
+    Dk = _round_up(Dq, 128)
+    assert n_kv % 2 == 0 and 2 * Dq % 128 == 0, (n_kv, Dq)
+    odd = (jnp.arange(H) // (H // n_kv)) % 2 == 1
+    pad = [(0, 0)] * (q.ndim - 1)
+    return jnp.where(odd[:, None], jnp.pad(q, pad + [(Dk - Dq, 0)]),
+                     jnp.pad(q, pad + [(0, Dk - Dq)]))
+
+
+def _fit_block(bq: int, heads: int, head_dim: int, itemsize: int,
+               value_dim: int | None = None) -> int:
     """The query block a chunk call may take: a FIT rule, read off the call's
     own shapes (no speed-up, no model's name). A query block's VMEM — the
     query and output blocks, twice each, and the softmax state — grows with
@@ -900,24 +977,35 @@ def _fit_block(bq: int, heads: int, head_dim: int, itemsize: int) -> int:
     beside it: 40 heads of 128 at a block of 128 are 13.1 MiB and the chip's
     compiler refuses the call by 1 MiB (PR 42). Such a call takes half the
     block; 32 heads of 128 (10.5 MiB) and fewer keep theirs, so every call
-    that compiled before this rule is traced as it was."""
-    while bq > 8 and heads * bq * (4 * itemsize * head_dim + 4 * (256 + head_dim)) \
+    that compiled before this rule is traced as it was. ``value_dim``: the
+    output block's and the acc's width where it is not the query block's."""
+    dv = head_dim if value_dim is None else value_dim
+    while bq > 8 and heads * bq * (2 * itemsize * (head_dim + dv) + 4 * (256 + dv)) \
             > BLOCK_STATE_BYTES:
         bq //= 2
     return bq
 
 
 def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
-                page_size, n_kv, scale, block_q, interpret, window=0):
+                page_size, n_kv, scale, block_q, interpret, window=0, sink=None):
     """The walk over ``sources`` = ``(k_pages, v_pages)`` or, for the int8
-    cache, ``(k_pages, v_pages, k_scales, v_scales)``."""
-    B, n_queries, H, D = q.shape
-    k_pages = sources[0]
+    cache, ``(k_pages, v_pages, k_scales, v_scales)``. ``sink`` [H] float32:
+    the layer's softmax sink (``_paged_kernel``)."""
+    B, n_queries, H, Dq = q.shape
+    k_pages, v_pages = sources[:2]
     assert H % n_kv == 0, (H, n_kv)
     assert k_pages.shape[2] == page_size, (k_pages.shape, page_size)
-    assert k_pages.shape[3] == n_kv * D, (k_pages.shape, n_kv, D)
+    assert k_pages.shape[3] == n_kv * Dq, (k_pages.shape, n_kv, Dq)
     group = H // n_kv
-    scale = scale if scale is not None else D ** -0.5
+    scale = scale if scale is not None else Dq ** -0.5
+    D = v_pages.shape[3] // n_kv  # a head's values: the output's width
+    # keys wider than values (192 over 128): a head's keys are cut as the two
+    # lane tiles that hold them, its queries laid over those (``_pair_queries``)
+    key_width = Dq if Dq != D else 0
+    if key_width:
+        assert len(sources) == 2, "no int8 form of keys wider than values"
+        q = _pair_queries(q, n_kv)
+    Dk = q.shape[-1]
 
     q_offset = jnp.asarray(q_offset, jnp.int32)
     kv_len = jnp.asarray(kv_len, jnp.int32)
@@ -926,11 +1014,12 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
 
     q, C = _pad_chunk(q)
     bq = _pick_block(C, block_q)
-    bq = _fit_block(bq, H, D, q.dtype.itemsize)
+    bq = _fit_block(bq, H, Dk, q.dtype.itemsize, D)
     nq = C // bq
     # a tile: the KV heads of one block update, their lanes of K, V and acc
-    pack = _heads_per_tile(group, bq)
-    n_tiles, Wt = -(-n_kv // pack), pack * D
+    # (keys cut as tiles of their own: a head a tile whatever its rows)
+    pack = 1 if key_width else _heads_per_tile(group, bq)
+    n_tiles, Wt, Wq = -(-n_kv // pack), pack * D, pack * Dk
     r_pad = _round_up(max(n_tiles * pack * group * bq, 8), 8)
     # decode over more than one row: the shared-head pass, each row's query
     # heads of a tile padded to whole 8-row tiles of the stacked block
@@ -938,11 +1027,12 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     # (a window's table holds no shared page: its walks are chained and start at column 0)
     chained = n_queries == 1 and B > 1
     shared_rows = B * gp if chained and not window else 0
+    assert sink is None or not shared_rows, "a sink starts a row's own walk: no stacked pass"
 
     if pack == 1:
-        q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, D]
-        q_spec = pl.BlockSpec((1, H, bq, D), lambda b, qi, *_: (b, 0, qi, 0))
-        q_bytes = H * max(bq, 8) * D
+        q_t = q.transpose(0, 2, 1, 3)  # [B, H, C, Dk]
+        q_spec = pl.BlockSpec((1, H, bq, Dk), lambda b, qi, *_: (b, 0, qi, 0))
+        q_bytes = H * max(bq, 8) * Dk
     else:
         q_t = _block_diagonal(q, n_kv, pack)  # [B, tiles, 8, Wt]
         q_spec = pl.BlockSpec((1, n_tiles, gp, Wt), lambda b, qi, *_: (b, 0, 0, 0))
@@ -951,17 +1041,22 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     state = [pltpu.VMEM((r_pad, 128), jnp.float32),
              pltpu.VMEM((r_pad, 128), jnp.float32),
              pltpu.VMEM((r_pad, Wt), jnp.float32)]
+    if sink is not None:  # a query head's sink on every lane (the kernel spreads it over a head's rows)
+        hp = r_pad if bq == 1 else _round_up(H, 8)  # (one token a row: a state row a head)
+        blocks.append(jnp.broadcast_to(
+            jnp.pad(jnp.asarray(sink, jnp.float32), (0, hp - H))[:, None], (hp, 128)))
+        in_specs.append(pl.BlockSpec((hp, 128), lambda b, qi, *_: (0, 0)))
     if shared_rows:
         if shared is None:  # a decode query sees what lies below its own position
             shared = shared_head(page_table, jnp.minimum(kv_len, q_offset + 1), page_size)
         prefetch += [jnp.asarray(x, jnp.int32) for x in shared]
         if pack == 1:
-            stacked = jnp.pad(q.reshape(B, n_kv, group, D),
+            stacked = jnp.pad(q.reshape(B, n_kv, group, Dk),
                               ((0, 0), (0, 0), (0, gp - group), (0, 0)))
         else:
             stacked = q_t
-        blocks.append(stacked.transpose(1, 0, 2, 3).reshape(n_tiles, shared_rows, Wt))
-        in_specs.append(pl.BlockSpec((n_tiles, shared_rows, Wt), lambda b, qi, *_: (0, 0, 0)))
+        blocks.append(stacked.transpose(1, 0, 2, 3).reshape(n_tiles, shared_rows, Wq))
+        in_specs.append(pl.BlockSpec((n_tiles, shared_rows, Wq), lambda b, qi, *_: (0, 0, 0)))
         state += [pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
                   pltpu.VMEM((n_tiles * shared_rows, 128), jnp.float32),
                   pltpu.VMEM((n_tiles * shared_rows, Wt), jnp.float32)]
@@ -970,11 +1065,14 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
     # what stands in VMEM beside the K and V buffers: the query and output
     # blocks (the pipeline keeps two of each) and the softmax state
     reserved = (2 * q.dtype.itemsize * (q_bytes + H * max(bq, 8) * D
-                                        + n_tiles * shared_rows * Wt)
+                                        + n_tiles * shared_rows * Wq)
                 + 4 * (r_pad + n_tiles * shared_rows) * (2 * 128 + Wt))
-    ppb = _pages_per_block(page_size, max(pack * group * bq, shared_rows), n_kv * D,
+    # (the stacked pass takes its rows STACKED_ROWS at a time where they are more)
+    chunks = shared_rows // STACKED_ROWS if shared_rows % STACKED_ROWS == 0 else 1
+    chunks = max(chunks, 1)
+    ppb = _pages_per_block(page_size, max(pack * group * bq, shared_rows // chunks), n_kv * Dq,
                            k_pages.dtype.itemsize, page_table.shape[1], reserved,
-                           whole_table=chained and window > 0)
+                           whole_table=chained and window > 0, v_width=n_kv * D)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
@@ -992,6 +1090,9 @@ def _paged_call(q, sources, page_table, q_offset, kv_len, layer, shared, *,
         block_q=bq, page_size=page_size, pages_per_block=ppb, n_kv=n_kv,
         group=group, pack=pack, scale=scale, quantized=len(sources) == 4,
         shared_rows=shared_rows, window=window, chained=chained,
+        **({"key_width": key_width} if key_width else {}),
+        **({"sink": True} if sink is not None else {}),
+        **({"stacked_chunks": chunks} if chunks > 1 else {}),
     )
     out_t = pl.pallas_call(
         kernel,
@@ -1054,8 +1155,11 @@ def paged_flash_attention(
     block_q: int = 128,
     interpret: bool = False,
     window: int = 0,
+    sink: Array | None = None,
 ) -> Array:
-    """Attention over the paged KV cache; returns [B, C, H, D].
+    """Attention over the paged KV cache; returns [B, C, H, Dv] — ``Dv`` the
+    values' width, which need not be the keys' (``_paged_kernel``'s
+    ``key_width``: K and V arrays of two widths).
 
     Causal with absolute positions (query row i of batch b is at
     ``q_offset[b] + i``); sequences with ``kv_len == 0`` produce zeros.
@@ -1070,11 +1174,15 @@ def paged_flash_attention(
     pass reads whole pages for every row, which a window does not allow — so
     a window's one-token call does not trace that pass, nor read ``shared``,
     and walks its table in the fewest blocks that fit (the module's list).
+
+    ``sink`` [H] float32 (a softmax with a sink; a call that traces no stacked
+    pass — a window layer's, or a chunk's): every query's sum starts with its
+    head's sink logit, which takes probability and gives no value.
     """
     return _paged_call(
         q, (k_pages, v_pages), page_table, q_offset, kv_len, layer, shared,
         page_size=page_size, n_kv=n_kv, scale=scale, block_q=block_q,
-        interpret=interpret, window=window)
+        interpret=interpret, window=window, sink=sink)
 
 
 @functools.partial(jax.jit, static_argnames=("page_size", "value_width", "scale", "interpret",

@@ -71,6 +71,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from finchat_tpu.ops.flash_attention import NEG_INF, _online_softmax_update, _round_up
+from finchat_tpu.ops.paged_attention import _key_lanes, _pair_queries
 
 TRASH_PAGE = 0
 
@@ -118,6 +119,7 @@ def ragged_paged_attention_ref(
     v_scales: Array | None = None,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
     window: int = 0,  # > 0: a sliding-window layer (``mha_reference``)
+    sink: Array | None = None,  # [H] float32: the layer's softmax sink (``mha_reference``)
 ) -> Array:
     """``jax.lax`` reference for the ragged kernel — the correctness oracle
     AND the CPU/tier-1 serving path (ops/dispatch.py backend "ref").
@@ -160,6 +162,7 @@ def ragged_paged_attention_ref(
     out = mha_reference(
         q[:, None], k_all, v_all, causal=True,
         q_offset=jnp.asarray(tok_pos, jnp.int32), kv_len=kv_tok, scale=scale, window=window,
+        sink=sink,
     )  # [T, 1, H, D]
     return out[:, 0]
 
@@ -177,25 +180,32 @@ def _ragged_kernel(
     q_ref,  # [H, Bq, D]
     k_ref,  # [1, 1, page_size, Hkv*D] — one physical page
     v_ref,
-    o_ref,  # [H, Bq, D]
-    # scratch
-    m_scr,  # [Rpad, 128] fp32
-    l_scr,
-    acc_scr,  # [Rpad, D] fp32
-    *,
+    *refs,  # (with ``sink``: [Rpad, 128] fp32, a state row's sink logit on every lane)
     block_q: int,
     page_size: int,
     n_kv: int,
     group: int,
     scale: float,
     window: int = 0,
+    key_width: int = 0,
+    sink: bool = False,
 ):
+    """``refs``: the output block ``o_ref`` [H, Bq, Dv] and the scratch
+    ``m_scr``, ``l_scr`` [Rpad, 128], ``acc_scr`` [Rpad, Dv] fp32. With
+    ``key_width`` (keys wider than values, ops/paged_attention.py
+    ``_key_lanes`` / ``_pair_queries``) the query block is ``Dk`` lanes wide,
+    a head's K block the two lane tiles that hold its keys; with ``sink`` a
+    block's m / l start at (the sink, 1)."""
+    if sink:
+        sink_ref, *refs = refs
+    o_ref, m_scr, l_scr, acc_scr = refs
     j = pl.program_id(0)
     p = pl.program_id(1)
     n_pages = pl.num_programs(1)
 
     Bq = block_q
-    D = q_ref.shape[-1]
+    D = o_ref.shape[-1]
+    Dk = q_ref.shape[-1]
     Rh = group * Bq  # scratch rows per kv head
     r = blk_row_ref[j]
     pos0 = pos0_ref[r]
@@ -205,8 +215,12 @@ def _ragged_kernel(
 
     @pl.when(p == 0)
     def _init():
-        m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
-        l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
+        if sink:  # the sink is in the sum before any key: exp(sink - sink) = 1
+            m_scr[:] = sink_ref[:]
+            l_scr[:] = jnp.ones(l_scr.shape, jnp.float32)
+        else:
+            m_scr[:] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+            l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
     page_start = p * page_size
@@ -228,8 +242,9 @@ def _ragged_kernel(
             invalid = invalid | (kv_pos <= q_pos - window)
 
         for h in range(n_kv):  # static unroll over kv heads
-            q_blk = q_ref[h * group:(h + 1) * group].reshape(Rh, D)
-            k_blk = k_ref[0, 0, :, h * D:(h + 1) * D]  # [PS, D] value slice
+            q_blk = q_ref[h * group:(h + 1) * group].reshape(Rh, Dk)
+            k0 = _key_lanes(h, key_width, Dk) if key_width else h * D
+            k_blk = k_ref[0, 0, :, k0:k0 + Dk]  # [PS, Dk] value slice
             v_blk = v_ref[0, 0, :, h * D:(h + 1) * D]
             r0 = h * Rh
 
@@ -404,20 +419,27 @@ def ragged_flash_attention(  # finchat-lint: hot
     interpret: bool = False,
     kv_gap: Array | None = None,  # [R] int32 — bounded-KV window offset
     window: int = 0,  # > 0: a sliding-window layer's mask beside the causal one
+    sink: Array | None = None,  # [H] float32: the layer's softmax sink
 ) -> Array:
     """Ragged paged attention over the native-dtype cache; returns
-    [T, H, D]. Same descriptor contract as ``ragged_paged_attention_ref``
+    [T, H, Dv] (``Dv`` the values' width: the V array's, which need not be
+    the keys'). Same descriptor contract as ``ragged_paged_attention_ref``
     (the oracle tests pin them against each other). ``kv_gap`` shifts a
     bounded row into compacted coordinates at the wrapper level
     (:func:`_compact_window`) — the kernel body is gap-oblivious: its
     page-bound and causal masks simply run on the compacted inputs."""
-    T, H, D = q.shape
+    T, H, Dq = q.shape
     R, max_pages = page_table.shape
     assert H % n_kv == 0, (H, n_kv)
     assert k_pages.shape[2] == page_size, (k_pages.shape, page_size)
-    assert k_pages.shape[3] == n_kv * D, (k_pages.shape, n_kv, D)
+    assert k_pages.shape[3] == n_kv * Dq, (k_pages.shape, n_kv, Dq)
     group = H // n_kv
-    scale = scale if scale is not None else D ** -0.5
+    scale = scale if scale is not None else Dq ** -0.5
+    D = v_pages.shape[3] // n_kv
+    key_width = Dq if Dq != D else 0  # keys wider than values: see the kernel
+    if key_width:
+        q = _pair_queries(q, n_kv)
+    Dk = q.shape[-1]
     tok_pos, kv_len = _compact_window(tok_row, tok_pos, kv_len, kv_gap, R)
 
     layer = jnp.asarray(layer, jnp.int32)
@@ -431,8 +453,8 @@ def ragged_flash_attention(  # finchat-lint: hot
     dest, blk_row, aln_start, pos0, q_len, NB, TALN = _aligned_layout(
         tok_row, tok_pos, T, R, block_q
     )
-    q_aln = jnp.zeros((TALN, H, D), q.dtype).at[dest].set(q, mode="drop")
-    q_t = q_aln.transpose(1, 0, 2)  # [H, TALN, D] — head-major blocks
+    q_aln = jnp.zeros((TALN, H, Dk), q.dtype).at[dest].set(q, mode="drop")
+    q_t = q_aln.transpose(1, 0, 2)  # [H, TALN, Dk] — head-major blocks
 
     r_pad = _round_up(max(H * block_q, 8), 8)
 
@@ -446,13 +468,20 @@ def ragged_flash_attention(  # finchat-lint: hot
         phys = jnp.where(needed, pt_ref[r, p], TRASH_PAGE)
         return (layer_ref[0], phys, 0, 0)
 
+    extra, extra_specs = [], []
+    if sink is not None:  # a state row's sink on every lane: row (head, query) as the acc's
+        rows = jnp.repeat(jnp.asarray(sink, jnp.float32), block_q)
+        extra.append(jnp.broadcast_to(
+            jnp.pad(rows, (0, r_pad - rows.shape[0]))[:, None], (r_pad, 128)))
+        extra_specs.append(pl.BlockSpec((r_pad, 128), lambda j, p, *_: (0, 0)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=7,
         grid=(NB, max_pages),
         in_specs=[
-            pl.BlockSpec((H, block_q, D), lambda j, p, *_: (0, j, 0)),
+            pl.BlockSpec((H, block_q, Dk), lambda j, p, *_: (0, j, 0)),
+            pl.BlockSpec((1, 1, page_size, n_kv * Dq), kv_index),
             pl.BlockSpec((1, 1, page_size, n_kv * D), kv_index),
-            pl.BlockSpec((1, 1, page_size, n_kv * D), kv_index),
+            *extra_specs,
         ],
         out_specs=pl.BlockSpec((H, block_q, D), lambda j, p, *_: (0, j, 0)),
         scratch_shapes=[
@@ -465,6 +494,8 @@ def ragged_flash_attention(  # finchat-lint: hot
         _ragged_kernel,
         block_q=block_q, page_size=page_size, n_kv=n_kv, group=group,
         scale=scale, window=window,
+        **({"key_width": key_width} if key_width else {}),
+        **({"sink": True} if sink is not None else {}),
     )
     o_t = pl.pallas_call(
         kernel,
@@ -472,7 +503,7 @@ def ragged_flash_attention(  # finchat-lint: hot
         out_shape=jax.ShapeDtypeStruct((H, TALN, D), q.dtype),
         interpret=interpret,
     )(layer, pt_pad, blk_row, aln_start, pos0, q_len, kv_pad, q_t,
-      k_pages, v_pages)
+      k_pages, v_pages, *extra)
     o_aln = o_t.transpose(1, 0, 2)  # [TALN, H, D]
     return jnp.take(o_aln, jnp.minimum(dest, TALN - 1), axis=0)
 

@@ -31,13 +31,14 @@ def gqa_repeat(kv: Array, n_heads: int) -> Array:
 def mha_reference(
     q: Array,  # [B, Sq, H, D]
     k: Array,  # [B, Sk, Hkv, D]
-    v: Array,  # [B, Sk, Hkv, D]
+    v: Array,  # [B, Sk, Hkv, Dv] — as wide as the keys, or not (the output is [.., Dv])
     *,
     causal: bool = True,
     q_offset: Array | int = 0,  # absolute position of q[0] within the kv axis
     kv_len: Array | None = None,  # [B] valid kv length (rest is padding)
     scale: float | None = None,
     window: int = 0,  # > 0: a query sees itself and the window - 1 positions before it
+    sink: Array | None = None,  # [H] float32: a logit a head that takes probability, gives no value
 ) -> Array:
     """Masked multi-head attention with GQA, fp32 softmax.
 
@@ -69,6 +70,10 @@ def mha_reference(
         mask = mask | (kv_pos >= kv_len[:, None, None, None])
 
     logits = jnp.where(mask, NEG_INF, logits)
-    weights = jax.nn.softmax(logits, axis=-1)
+    if sink is not None:  # one more logit in the softmax's sum, dropped before the values
+        column = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None, None], (B, H, Sq, 1))
+        weights = jax.nn.softmax(jnp.concatenate([logits, column], axis=-1), axis=-1)[..., :Sk]
+    else:
+        weights = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v)
     return out.astype(q.dtype)

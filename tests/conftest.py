@@ -132,7 +132,7 @@ LONGEST_FIRST = (
     "test_quant_serving", "test_quant_matmul", "test_quant", "test_hf_loader",
     "test_ragged_attention", "test_checkpoint_io", "test_mixed_step",
     "test_kimi_linear_engine", "test_kimi_linear_model", "test_kimi_linear", "test_joyai",
-    "test_latent_walk_head",
+    "test_latent_walk_head", "test_mimo_v2_flash", "test_mimo_v2_flash_serving",
 )
 
 

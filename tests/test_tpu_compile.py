@@ -1178,3 +1178,85 @@ def test_joyai_decode_step_compiles_for_v5e_drafting_and_verifying_in_one_progra
     memory = compiled.memory_analysis()
     in_place = sum(x.size * x.dtype.itemsize for x in (state.k_pages, state.v_pages))
     assert memory.alias_size_in_bytes >= in_place and memory.temp_size_in_bytes < 0.2e9
+
+
+# --- mimo-v2-flash (PR 57): K and V of two widths, pools of two page widths, a sink ---
+
+def test_mimo_v2_flash_decode_step_compiles_for_v5e_with_pools_of_two_page_widths(one_chip):
+    """The whole decode step at the cell's size (1 dense + 6 routed layers, 32
+    slots, 16 of 256 experts held): the paged kernel at keys of 192 over values
+    of 128 — a head's keys cut as two lane tiles, Mosaic's layout rules held —
+    twice under the full layers' own ``paged_attention`` (16 query rows a K/V
+    head, the shared-head pass) and five times under ``swa_attention`` over a
+    table of 3 columns with the sink block (no ``paged_attention`` inside it);
+    the touched-expert pass at ``[4096, 2 x 2048] x 16`` six times; the appends
+    in place in BOTH pools, K and V slabs of two widths; no weight copied or
+    staged before its matmul; temporaries under a tenth of a GB."""
+    file = _config_file("mimo-v2-flash")
+    compiled, state = _compiled_decode_step(one_chip, file)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line and " custom-call(" in line]
+    window = [c for c in calls if "/swa_attention/" in c]
+    full = [c for c in calls if "/swa_attention/" not in c]
+
+    def count(cs, kernel, inside_scan=None):
+        return sum(kernel in c.split(" = ")[0] and (inside_scan is None
+                                                    or ("/while/body/" in c) == inside_scan)
+                   for c in cs)
+
+    for kernel in ("paged_flash_attention", "paged_kv_append"):  # (outside the scan, inside it)
+        assert (count(window, kernel, False), count(window, kernel, True)) == (0, 5), kernel
+        assert (count(full, kernel, False), count(full, kernel, True)) == (1, 1), kernel
+    assert not [c for c in window if "/paged_attention/" in c]
+    assert all("/paged_attention/" in c for c in full if "paged_flash_attention" in c.split(" = ")[0])
+    assert count(calls, "moe_experts_step") == 6
+    assert state.k_pages.shape == (2, 5120, 128, 768) and state.v_pages.shape == (2, 5120, 128, 512)
+    assert state.win_table.shape == (32, 3) and state.win_k_pages.shape == (5, 109, 128, 1536)
+    assert state.win_v_pages.shape == (5, 109, 128, 1024)
+    memory = compiled.memory_analysis()
+    pools = sum(x.size * 2 for x in (state.k_pages, state.v_pages, state.win_k_pages,
+                                     state.win_v_pages))
+    assert memory.alias_size_in_bytes >= pools and memory.temp_size_in_bytes < 0.1e9
+    copies, staged = relayout_probe.weight_relayouts(relayout_probe.operations(text))
+    assert not copies and not staged, [o.line() for o in copies + staged]
+
+
+def test_mimo_v2_flash_ragged_round_compiles_for_v5e_and_fits_beside_the_model(one_chip):
+    """``ragged_mixed_step`` at the top bucket (8,192 tokens): the ragged kernel
+    at keys of 192 over values of 128 under ``swa_attention`` (with the sink)
+    and under the full layers' ``ragged_paged_attention``; arguments and
+    temporaries together 13.3 GB of the chip's 15.75 (the configuration's
+    ``memory.compiled``; with the whole vocabulary 14.51: ISSUE 57's fallback)."""
+    from finchat_tpu.engine import engine as E
+    from finchat_tpu.models.llama import init_params
+    from finchat_tpu.utils.config import EngineConfig
+    from perfbench.models import adapter
+
+    file = _config_file("mimo-v2-flash")
+    c, cfg = adapter(file).program_config(file), EngineConfig(**file["engine"])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def described(tree):
+        return jax.tree.map(lambda x: shape(x.shape, x.dtype), tree)
+
+    params = described(jax.eval_shape(lambda: init_params(c, jax.random.key(0))))
+    state = described(jax.eval_shape(lambda: E.create_state(c, cfg, cfg.max_seq_len // PAGE)))
+    T, B = 8192, cfg.max_seqs
+    compiled = E.ragged_mixed_step.lower(
+        params, state, shape((T,), jnp.int32), shape((T,), jnp.int32), shape((B,), jnp.int32),
+        shape((B,), jnp.int32), shape((B,), jnp.int32), shape((B,), bool), shape((B,), bool),
+        shape((B,), jnp.int32), shape((B,), jnp.float32), shape((B,), jnp.float32),
+        shape((B,), jnp.int32), config=c, page_size=PAGE, attn_backend="pallas",
+        qm_backend="ref", spec_width=0).compile()
+    text = compiled.as_text()
+    walks = [line for line in text.splitlines()
+             if "ragged_flash_attention" in line.split(" = ")[0] and " custom-call(" in line]
+    assert [w for w in walks if "/swa_attention/" in w] and [
+        w for w in walks if "/swa_attention/" not in w and "/ragged_paged_attention/" in w]
+    assert "ragged-dot" in text or "ragged_dot" in text
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.9e9
+    assert 13.0e9 < memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.6e9

@@ -1,6 +1,6 @@
 """The configurations' blocks at a size a test holds, built once: what
 tests/test_falcon_h1.py, test_olmo_hybrid.py, test_granite_hybrid.py,
-test_deepseek_v32.py, test_phi4_flash.py, test_trinity_mini.py, test_kimi_linear.py and test_joyai.py check against the plain references
+test_deepseek_v32.py, test_phi4_flash.py, test_trinity_mini.py, test_kimi_linear.py, test_joyai.py and test_mimo_v2_flash.py check against the plain references
 of ``perfbench/models``, and what tests/test_decode_pipeline.py serves, one
 for each kind of per-row memory the engine has.
 
@@ -33,11 +33,12 @@ from finchat_tpu.models.llama import (
 ADAPTERS = {"falcon_h1": "falcon_h1", "olmo_hybrid": "olmo_hybrid",
             "granite_hybrid": "granitemoehybrid", "deepseek_v32": "deepseek_v32",
             "phi4_flash": "phi4flash", "trinity_mini": "afmoe", "kimi_linear": "kimi_linear",
-            "joyai": "joyai_llm_flash", "joyai_repeats": "joyai_llm_flash"}
+            "joyai": "joyai_llm_flash", "joyai_repeats": "joyai_llm_flash",
+            "mimo_v2_flash": "mimo_v2_flash"}
 SHAPES = {"tiny": (8, 16, 4), "falcon_h1": (16, 12, 4), "olmo_hybrid": (16, 12, 4),
           "granite_hybrid": (16, 12, 4), "deepseek_v32": (16, 12, 4), "phi4_flash": (4, 8, 4),
           "trinity_mini": (4, 8, 4), "kimi_linear": (16, 12, 4), "joyai": (16, 12, 4),
-          "joyai_repeats": (16, 12, 4)}
+          "joyai_repeats": (16, 12, 4), "mimo_v2_flash": (4, 8, 4)}
 FILES: dict[str, dict] = {}
 
 # Falcon-H1's block at a size a test holds: head_dim 32 is not 64 / 4, two
@@ -193,6 +194,32 @@ FILES["joyai"] = {
 # trunk's hidden state through, so it proposes the token the trunk just emitted
 # — kept wherever the stream repeats a token, rejected elsewhere
 FILES["joyai_repeats"] = FILES["joyai"]
+
+# MiMo-V2-Flash's block at a size a test holds: ONE leading dense full layer,
+# then two whole periods of three sliding layers and a full one; 16 query heads
+# over 2 K/V heads in full layers and 4 in sliding ones, keys of 192 over
+# values of 128 (the published widths: the kernels cut such a key as lane
+# tiles), 64 dims rotated at two bases, a sink in the sliding layers, a window
+# of 8 tokens = two pages of 4; 16 routed experts of 32 at 2 a token (it routes
+# sparsely) of which 4 are held, no shared expert
+FILES["mimo_v2_flash"] = {
+    "model_type": "mimo_v2_flash", "hidden_size": 64, "intermediate_size": 96,
+    "moe_intermediate_size": 32, "n_routed_experts": 4, "num_experts_per_tok": 2,
+    "reduced": {"n_routed_experts": {"from": 16, "to": 4, "why": "a chip's share"}},
+    "n_shared_experts": None, "norm_topk_prob": True, "scoring_func": "sigmoid",
+    "n_group": 1, "topk_group": 1, "topk_method": "noaux_tc", "routed_scaling_factor": None,
+    "num_hidden_layers": 9, "hybrid_layer_pattern": [0, 1, 1, 1, 0, 1, 1, 1, 0],
+    "moe_layer_freq": [0, 1, 1, 1, 1, 1, 1, 1, 1],
+    "num_attention_heads": 16, "num_key_value_heads": 2, "head_dim": 192, "v_head_dim": 128,
+    "swa_num_attention_heads": 16, "swa_num_key_value_heads": 4, "swa_head_dim": 192,
+    "swa_v_head_dim": 128, "rope_theta": 5000000, "swa_rope_theta": 10000,
+    "partial_rotary_factor": 0.334, "attention_value_scale": 0.707, "attention_bias": False,
+    "sliding_window": 8, "sliding_window_size": 8, "attention_chunk_size": 8,
+    "add_swa_attention_sink_bias": True, "add_full_attention_sink_bias": False,
+    "hidden_act": "silu", "layernorm_epsilon": 1e-5, "max_position_embeddings": 262144,
+    "vocab_size": 211, "tie_word_embeddings": False,
+    "engine": {"max_seq_len": 256, "max_seqs": 4}, "dtype": "float32",
+}
 
 
 def repeats_the_last_token(params):
